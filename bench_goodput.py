@@ -164,12 +164,11 @@ def run_goodput(
                 # the three restart-latency levers, all on by default
                 # in the harness because they ARE the product defaults
                 # for preemption-heavy TPU fleets:
-                # - persistent XLA cache (recompile is avoidable)
+                # - persistent XLA cache (recompile is avoidable; the
+                #   launcher's default: one fixed directory)
                 # - prefork zygote (reimport is avoidable)
                 # - short failure grace (survivors of a peer kill are
                 #   wedged in collectives; SIGTERM buys nothing)
-                "--compile_cache_dir="
-                + os.path.join(workdir, "xla_cache"),
                 "--prefork",
                 "--failure_stop_timeout=0.5",
                 os.path.join(REPO, "scripts", "goodput_train.py"),

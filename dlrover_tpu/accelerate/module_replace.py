@@ -179,9 +179,66 @@ def select_attention(
     seq_size = (
         mesh_ctx.axis_size(AxisName.SEQUENCE) if mesh_ctx else 1
     )
-    if seq_size <= 1 or rules is None:
+    if rules is None:
         return inner
-    return _sp_under_shard_map(mesh_ctx, rules, inner, use_flash)
+    if seq_size > 1:
+        return _sp_under_shard_map(mesh_ctx, rules, inner, use_flash)
+    if use_flash and mesh_ctx is not None and mesh_ctx.mesh.size > 1:
+        # GSPMD cannot partition a Mosaic kernel: run flash per shard
+        # (batch over the data axes, heads over the tensor axis)
+        return _flash_under_shard_map(mesh_ctx, rules, inner)
+    return inner
+
+
+def _ambient_mesh(mesh):
+    """The mesh a nested ``shard_map`` must be built on: inside another
+    manual region (the pipe executor's partial-manual shard_map) that
+    is the AMBIENT abstract mesh — passing the concrete mesh trips
+    "context mesh should match" because pipe is already Manual."""
+    cur = jax.sharding.get_abstract_mesh()
+    if any("Manual" in str(t) for t in cur.axis_types):
+        return cur
+    return mesh
+
+
+def shard_mapped(fn, mesh_ctx: MeshContext, in_specs, out_specs):
+    """``fn`` run per shard of ``mesh_ctx``'s mesh — how a Pallas
+    kernel (which GSPMD cannot partition) joins a sharded program."""
+    from dlrover_tpu.parallel.sharding import shard_map_compat
+
+    return shard_map_compat(
+        fn,
+        mesh=_ambient_mesh(mesh_ctx.mesh),
+        in_specs=in_specs,
+        out_specs=out_specs,
+    )
+
+
+def _attention_specs(mesh_ctx: MeshContext, rules: LogicalAxisRules):
+    """(q spec, k/v spec) of the ``[B, S, heads, D]`` attention inputs
+    under the activation rule table."""
+    return tuple(
+        filter_spec_for_mesh(
+            rules.spec((BATCH, SEQ, heads, None)), mesh_ctx.mesh
+        )
+        for heads in (HEADS, KV_HEADS)
+    )
+
+
+def _flash_under_shard_map(mesh_ctx: MeshContext,
+                           rules: LogicalAxisRules,
+                           inner_attention):
+    q_spec, kv_spec = _attention_specs(mesh_ctx, rules)
+
+    def attention(q, k, v, causal: bool = True):
+        return shard_mapped(
+            partial(inner_attention, causal=causal),
+            mesh_ctx,
+            in_specs=(q_spec, kv_spec, kv_spec),
+            out_specs=q_spec,
+        )(q, k, v)
+
+    return attention
 
 
 def select_layer_executor(mesh_ctx: Optional[MeshContext]):
@@ -306,14 +363,8 @@ def _sp_under_shard_map(mesh_ctx: MeshContext,
         ulysses_attention,
     )
 
-    mesh = mesh_ctx.mesh
     seq_size = mesh_ctx.axis_size(AxisName.SEQUENCE)
-    q_spec = filter_spec_for_mesh(
-        rules.spec((BATCH, SEQ, HEADS, None)), mesh
-    )
-    kv_spec = filter_spec_for_mesh(
-        rules.spec((BATCH, SEQ, KV_HEADS, None)), mesh
-    )
+    q_spec, kv_spec = _attention_specs(mesh_ctx, rules)
 
     # inside the manual region the heads dim is already tensor-sharded
     # (HEADS/KV_HEADS -> tensor axis): Ulysses' all_to_all must divide
@@ -357,31 +408,11 @@ def _sp_under_shard_map(mesh_ctx: MeshContext,
                 block_q=tile_kwargs.get("block_q"),
                 block_k=tile_kwargs.get("block_k"),
             )
-        # inside another manual region (the pipe executor's
-        # partial-manual shard_map), the inner map must be built on
-        # the AMBIENT abstract mesh — passing the concrete mesh trips
-        # "context mesh should match" because pipe is already Manual
-        import jax as _jax
-
-        from dlrover_tpu.parallel.sharding import shard_map_compat
-
-        use_mesh = mesh
-        try:
-            cur = _jax.sharding.get_abstract_mesh()
-        except AttributeError:  # older jax: no abstract-mesh API
-            cur = None
-        if cur is not None and getattr(cur, "axis_names", ()):
-            if any(
-                "Manual" in str(t)
-                for t in getattr(cur, "axis_types", ())
-            ):
-                use_mesh = cur
-        sp = shard_map_compat(
+        return shard_mapped(
             fn,
-            mesh=use_mesh,
+            mesh_ctx,
             in_specs=(q_spec, kv_spec, kv_spec),
             out_specs=q_spec,
-        )
-        return sp(q, k, v)
+        )(q, k, v)
 
     return attention

@@ -14,7 +14,6 @@ the agent; elapsed time goes back to the master's
 rounds to isolate the straggler / fault node.
 """
 
-import functools
 import os
 import time
 
@@ -44,6 +43,9 @@ def run_health_check() -> float:
 
     import jax
     import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
 
     devices = jax.local_devices()
     if not devices:
@@ -53,29 +55,43 @@ def run_health_check() -> float:
 
     start = time.time()
 
-    # Per-chip matmul (MXU) + ICI allreduce across local chips.
-    @functools.partial(jax.pmap, axis_name="i")
-    def _payload(v):
-        y = v
-        for _ in range(_MATMUL_ROUNDS):
-            y = jnp.tanh(y @ v)
-        s = jax.lax.psum(jnp.sum(y), axis_name="i")
-        return y, s
+    mesh = Mesh(np.asarray(devices), ("i",))
+    per_chip = NamedSharding(mesh, P("i"))
 
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(
-        key, (n, _MATMUL_DIM, _MATMUL_DIM), dtype=jnp.bfloat16
+    def on_each_chip(body, out_specs):
+        return jax.jit(
+            jax.shard_map(
+                body, mesh=mesh, in_specs=P("i"), out_specs=out_specs
+            )
+        )
+
+    # Per-chip matmul (MXU) + ICI allreduce across local chips.
+    def _payload(v):  # this chip's [1, D, D] slice
+        y = v[0]
+        for _ in range(_MATMUL_ROUNDS):
+            y = jnp.tanh(y @ v[0])
+        return y[None], jax.lax.psum(jnp.sum(y), axis_name="i")
+
+    x = jax.device_put(
+        jax.random.normal(
+            jax.random.PRNGKey(0),
+            (n, _MATMUL_DIM, _MATMUL_DIM),
+            dtype=jnp.bfloat16,
+        ),
+        per_chip,
     )
-    out = _payload(x)
-    jax.block_until_ready(out)
+    jax.block_until_ready(on_each_chip(_payload, (P("i"), P()))(x))
 
     # Bandwidth probe: 16M-element (64MB fp32) allreduce, reference
     # ``bm_allreduce`` (node_check/utils.py:88).
-    big = jnp.ones((n, _ALLREDUCE_ELEMS // n), dtype=jnp.float32)
-    r = jax.pmap(
-        lambda v: jax.lax.psum(v, axis_name="i"), axis_name="i"
-    )(big)
-    jax.block_until_ready(r)
+    big = jax.device_put(
+        jnp.ones((n, _ALLREDUCE_ELEMS // n), dtype=jnp.float32), per_chip
+    )
+    jax.block_until_ready(
+        on_each_chip(
+            lambda v: jax.lax.psum(v, axis_name="i"), P()
+        )(big)
+    )
 
     elapsed = time.time() - start
     logger.info("node check passed in %.3fs", elapsed)

@@ -44,6 +44,7 @@ from dlrover_tpu.common.env import (
     preempt_drain_grace_s,
     reshard_enabled,
 )
+from dlrover_tpu.common.jax_env import export_compile_cache
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.observability.events import get_event_logger
 
@@ -90,6 +91,8 @@ class ElasticLaunchConfig:
     # extra env vars injected into every training process
     envs: Dict[str, str] = field(default_factory=dict)
     # persistent XLA compilation cache keeps post-restart warmup cheap
+    # ("" = the checkout's fixed default; $JAX_COMPILATION_CACHE_DIR,
+    # when set, always wins — common/jax_env.export_compile_cache)
     compile_cache_dir: str = ""
     # overlapped restart critical path in the workers (restore byte
     # prefetch + background AOT compile, trainer/restart_path.py);
@@ -335,10 +338,7 @@ class ElasticTrainingAgent:
                 "DLROVER_TPU_PREV_WORLD": str(self._last_world_size),
             }
         )
-        if self._config.compile_cache_dir:
-            env.setdefault(
-                "JAX_COMPILATION_CACHE_DIR", self._config.compile_cache_dir
-            )
+        export_compile_cache(env, self._config.compile_cache_dir)
         if not self._config.restart_overlap:
             env["DLROVER_TPU_RESTART_OVERLAP"] = "0"
         # deep-capture rendezvous point: agent and workers must agree
@@ -728,11 +728,7 @@ class ElasticTrainingAgent:
             )
             env = dict(os.environ)
             env.update(self._config.envs)
-            if self._config.compile_cache_dir:
-                env.setdefault(
-                    "JAX_COMPILATION_CACHE_DIR",
-                    self._config.compile_cache_dir,
-                )
+            export_compile_cache(env, self._config.compile_cache_dir)
             if pool.start(env=env):
                 self._zygote = pool
         try:

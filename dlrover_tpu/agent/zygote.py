@@ -117,14 +117,9 @@ def _fixup_jax_config(spawn_env: Dict[str, str]):
                 value = float(value)  # config is numeric
             except ValueError:
                 continue
-        try:
-            jax.config.update(cfg_key, value)
-        except Exception as e:  # noqa: BLE001 - best effort
-            print(
-                f"zygote: jax.config.update({cfg_key}) failed: {e}",
-                file=sys.stderr,
-                flush=True,
-            )
+        # not best effort: a fork left on the zygote's platform or
+        # cache directory would run on the wrong device or never hit
+        jax.config.update(cfg_key, value)
 
 
 def _run_child(argv: Sequence[str], env: Dict[str, str]) -> int:
@@ -207,30 +202,15 @@ class ZygoteServer:
                     file=sys.stderr,
                     flush=True,
                 )
-        jax = sys.modules.get("jax")
-        if jax is not None:
-            # a live backend would not survive fork — refuse to serve.
-            # The check reads a private attribute; if a jax upgrade
-            # moves it the guard must DEGRADE LOUDLY, not silently
-            # vanish (ADVICE-r4)
-            bridge = getattr(
-                getattr(jax, "_src", None), "xla_bridge", None
+        # a live backend would not survive fork (and on a TPU the
+        # zygote would hold the chip its workers need) — refuse to serve
+        from dlrover_tpu.common.jax_env import backend_initialized
+
+        if backend_initialized():
+            raise RuntimeError(
+                "zygote preload initialized a jax backend; "
+                "remove the offending preload module"
             )
-            backends = getattr(bridge, "_backends", None)
-            if bridge is None or backends is None:
-                print(
-                    "zygote: WARNING jax._src.xla_bridge._backends "
-                    "not found — cannot verify no backend was "
-                    "initialized by preload modules; forked workers "
-                    "may inherit a broken backend",
-                    file=sys.stderr,
-                    flush=True,
-                )
-            elif backends:
-                raise RuntimeError(
-                    "zygote preload initialized a jax backend; "
-                    "remove the offending preload module"
-                )
         print(
             f"zygote: ready ({len(modules)} modules in "
             f"{time.time() - t0:.1f}s)",

@@ -1,0 +1,127 @@
+"""Process-level JAX facts the launchers need WITHOUT touching a device.
+
+A TPU chip belongs to one process at a time, so every process in this
+repo that spawns chip-using children (the elastic launcher and agent,
+the zygote, the serving parents, ``chip_smoke.py``) must stay off the
+backend itself.  The helpers here let such a parent decide what it has
+to from the environment alone:
+
+- where the persistent XLA compile cache lives
+  (:func:`compile_cache_dir` / :func:`export_compile_cache`);
+- which platform its children will get (:func:`platform_from_env`);
+- whether anything in this process initialised a backend after all
+  (:func:`backend_initialized` — the guard the zygote and the tests
+  use).
+"""
+
+import os
+import sys
+from typing import Dict, MutableMapping
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: Root of everything the program caches on disk: a fixed, git-ignored
+#: directory inside the checkout.  The path is part of the compile
+#: cache's key, so it is never built from ``mkdtemp``, a pid or a time;
+#: and nothing outside what git would commit steers the program.
+CHECKOUT_CACHE_ROOT = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".cache",
+)
+
+
+def compile_cache_dir() -> str:
+    """The one persistent XLA compile cache directory of this checkout:
+    ``$JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else
+    ``<checkout>/.cache/jax_compile`` — the same path on every call and
+    from every entry point."""
+    return os.environ.get(COMPILE_CACHE_ENV, "").strip() or os.path.join(
+        CHECKOUT_CACHE_ROOT, "jax_compile"
+    )
+
+
+def export_compile_cache(
+    env: MutableMapping[str, str], override: str = ""
+) -> str:
+    """Point a child's environment at the compile cache.  A directory
+    the environment already names wins and nothing else is set; else
+    ``override`` (the launcher's ``--compile_cache_dir``) or the fixed
+    in-checkout path is exported.  JAX reads the variable at import, so
+    the child needs no code of its own.  Returns the directory."""
+    path = env.get(COMPILE_CACHE_ENV, "").strip()
+    if not path:
+        path = override or compile_cache_dir()
+        os.makedirs(path, exist_ok=True)
+        env[COMPILE_CACHE_ENV] = path
+    return path
+
+
+def platform_from_env() -> str:
+    """The platform JAX will pick in a process started with this
+    process's environment, read off ``JAX_PLATFORMS`` alone — first
+    entry, lowercased; ``""`` when unset (JAX then takes the best
+    backend installed: the TPU on a chip machine)."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
+
+
+def device_report() -> Dict[str, object]:
+    """The device as JAX reports it, for a ``device_report`` event or a
+    result line.  Initialises the backend: only a process that owns the
+    chip calls this."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
+class CompileMeter:
+    """Counts this process's persistent-compile-cache hits and misses
+    and sums the seconds spent in backend compiles, off JAX's own
+    monitoring events.  Create it before the first compile; touches no
+    device."""
+
+    _HIT = "/jax/compilation_cache/cache_hits"
+    _MISS = "/jax/compilation_cache/cache_misses"
+    _COMPILE = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self._counts = {self._HIT: 0, self._MISS: 0}
+        self._compile_s = 0.0
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration
+        )
+
+    def _on_event(self, event: str, **_kwargs):
+        if event in self._counts:
+            self._counts[event] += 1
+
+    def _on_duration(self, event: str, duration_secs: float, **_kwargs):
+        if event == self._COMPILE:
+            self._compile_s += duration_secs
+
+    def snapshot(self) -> Dict[str, float]:
+        return {
+            "cache_hits": self._counts[self._HIT],
+            "cache_misses": self._counts[self._MISS],
+            "compile_s": round(self._compile_s, 3),
+        }
+
+
+def backend_initialized() -> bool:
+    """Has THIS process initialised any JAX backend?  False when jax
+    was never imported.  A parent that answers True holds the chip and
+    its chip-using children will fail or hang."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return bool(xla_bridge.backends_are_initialized())

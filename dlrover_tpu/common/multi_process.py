@@ -29,10 +29,24 @@ _DEF_SOCKET_DIR = "/tmp/dlrover_tpu/sockets"
 _LEN = struct.Struct("<I")
 
 
+#: sockaddr_un.sun_path holds 108 bytes including the terminator
+_AF_UNIX_PATH_MAX = 107
+
+
 def _socket_path(name: str) -> str:
     root = os.getenv(SOCKET_DIR_ENV, _DEF_SOCKET_DIR)
     os.makedirs(root, exist_ok=True)
-    return os.path.join(root, f"{name}.sock")
+    path = os.path.join(root, f"{name}.sock")
+    if len(os.fsencode(path)) > _AF_UNIX_PATH_MAX:
+        # a deep socket dir (a pytest tmp_path under xdist, a long
+        # checkout path) must not fail the bind: every process maps the
+        # same (dir, name) to the same short path in the temp dir
+        import hashlib
+        import tempfile
+
+        digest = hashlib.sha1(os.fsencode(path)).hexdigest()[:24]
+        path = os.path.join(tempfile.gettempdir(), f"dlrover-{digest}.sock")
+    return path
 
 
 def _send_msg(sock: socket.socket, obj):

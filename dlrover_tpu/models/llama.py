@@ -250,8 +250,30 @@ def rms_norm(x, weight, eps: float):
     # elsewhere — see ops/fused.py.  Both paths scale in fp32 and
     # cast once, so values are identical across backends.
     from dlrover_tpu.ops.fused import rms_norm as _fused
+    from dlrover_tpu.ops.pallas_utils import use_interpret
+    from dlrover_tpu.parallel.mesh import get_mesh_context
 
-    return _fused(x, weight, eps)
+    ctx = get_mesh_context()
+    if (
+        ctx is None or ctx.mesh.size == 1 or x.ndim != 3
+        or use_interpret()  # off-TPU the fused op is plain XLA
+    ):
+        return _fused(x, weight, eps)
+    # GSPMD cannot partition a Mosaic kernel: run it per shard of the
+    # [batch, seq, embed] activation (the normalized dim is whole)
+    from jax.sharding import PartitionSpec
+
+    from dlrover_tpu.accelerate.module_replace import shard_mapped
+
+    spec = sh.filter_spec_for_mesh(
+        _current_rules().spec((sh.BATCH, sh.SEQ, sh.EMBED)), ctx.mesh
+    )
+    return shard_mapped(
+        lambda a, w: _fused(a, w, eps),
+        ctx,
+        in_specs=(spec, PartitionSpec()),
+        out_specs=spec,
+    )(x, weight)
 
 
 def rope_frequencies(cfg: LlamaConfig, positions):

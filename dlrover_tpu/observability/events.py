@@ -259,6 +259,12 @@ INSTANT_EVENTS = frozenset(
         # (slo_breach)
         "serving_health",
         "slo_breach",
+        # a chip-owning process (train worker, serving replica) names
+        # the device JAX gave it — and, for a replica, the paged-kernel
+        # backend it traced and its compiled-program census at exit —
+        # so a parent that must stay off JAX (``chip_smoke.py``) can
+        # refuse a run that landed on the wrong device
+        "device_report",
     }
 )
 
@@ -296,6 +302,7 @@ REQUIRED_INSTANT_LABELS: Dict[str, Tuple[str, ...]] = {
     # a serving verdict without the replica it names and the reason it
     # fired is exactly the "a node is slow" blip the observatory
     # exists to replace with "this is why"
+    "device_report": ("platform", "device_kind", "device_count"),
     "serving_health": ("replica", "verdict", "reason"),
     "slo_breach": ("replica", "reason", "value", "threshold"),
 }

@@ -38,59 +38,36 @@ PEAK_FLOPS_BY_KIND: Tuple[Tuple[str, float], ...] = (
     ("v2", 45e12),
 )
 
-#: the fallback when the kind is unknown (CPU CI, exotic plugin):
-#: the v5e number, so MFU is always populated — meaningless off-TPU,
-#: flagged by the loud warning below and the backend field in benches
-DEFAULT_PEAK_FLOPS = 197e12
-
 PEAK_FLOPS_ENV = "DLROVER_TPU_PEAK_FLOPS"
 
-#: unknown kinds warn ONCE per process, not once per step
-_warned_unknown_kinds = set()
-_warned_lock = threading.Lock()
 
-
-def peak_flops_for_kind(kind: str) -> Tuple[float, bool]:
-    """``(peak bf16 FLOP/s, known)`` for a ``device_kind`` string.
-    ``known=False`` means the table had no entry and the v5e fallback
-    was used (logged loudly, once per kind)."""
+def peak_flops_for_kind(kind: str) -> float:
+    """Peak bf16 FLOP/s for a ``device_kind`` string.  A kind the table
+    does not know RAISES ``LookupError``: an MFU against a guessed peak
+    is a wrong number, not a degraded one (set
+    ``DLROVER_TPU_PEAK_FLOPS`` for a chip the table has no row for)."""
     lowered = str(kind or "").lower()
     for pattern, peak in PEAK_FLOPS_BY_KIND:
         if pattern in lowered:
-            return peak, True
-    with _warned_lock:
-        if lowered not in _warned_unknown_kinds:
-            _warned_unknown_kinds.add(lowered)
-            logger.warning(
-                "unknown device kind %r: no peak-FLOPs table entry, "
-                "falling back to %.0fe12 (v5e) — MFU numbers are NOT "
-                "meaningful; set %s to the chip's real bf16 peak",
-                kind, DEFAULT_PEAK_FLOPS / 1e12, PEAK_FLOPS_ENV,
-            )
-    return DEFAULT_PEAK_FLOPS, False
+            return peak
+    raise LookupError(
+        f"unknown device kind {kind!r}: no peak-FLOPs table entry; "
+        f"set {PEAK_FLOPS_ENV} to the chip's real bf16 peak"
+    )
 
 
 def device_peak_flops(device=None) -> float:
     """Peak bf16 FLOP/s of ONE attached chip: the
-    ``DLROVER_TPU_PEAK_FLOPS`` override when set (malformed values
-    fall through, loudly), else the table entry for
-    ``jax.devices()[0].device_kind``."""
+    ``DLROVER_TPU_PEAK_FLOPS`` override when set (a malformed value
+    raises), else the table entry for ``jax.devices()[0].device_kind``
+    — raising for a kind the table does not know or when no backend
+    can be reached."""
     raw = os.getenv(PEAK_FLOPS_ENV, "")
     if raw:
-        try:
-            return float(raw)
-        except ValueError:
-            logger.warning(
-                "ignoring malformed %s=%r", PEAK_FLOPS_ENV, raw
-            )
+        return float(raw)
     if device is None:
-        try:
-            device = jax.devices()[0]
-        except Exception:  # noqa: BLE001 - no backend at all
-            return DEFAULT_PEAK_FLOPS
-    kind = getattr(device, "device_kind", "")
-    peak, _known = peak_flops_for_kind(kind)
-    return peak
+        device = jax.devices()[0]
+    return peak_flops_for_kind(getattr(device, "device_kind", ""))
 
 
 class AProfiler:

@@ -18,10 +18,13 @@ not code:
   compile-once invariant is untouched.
 - **Cache**: winners land in a JSON table keyed by
   ``(kernel, shape-bucket, dtype, device-kind)`` at
-  ``$DLROVER_TPU_AUTOTUNE_CACHE`` (default
-  ``~/.cache/dlrover_tpu/paged_autotune.json``).  Lookup order is
-  user cache -> checked-in ``ops/autotune_defaults.json`` (the
-  deterministic table CPU CI resolves against) -> shape heuristic.
+  ``$DLROVER_TPU_AUTOTUNE_CACHE`` (default: a git-ignored file inside
+  the checkout, next to the compile cache — nothing outside the
+  checkout steers tile sizes).  Lookup order is winner cache ->
+  checked-in ``ops/autotune_defaults.json`` (the deterministic table
+  CPU CI resolves against) -> shape heuristic.  The device bucket of a
+  TPU is its real ``device_kind`` (``"TPU v5 lite"`` ->
+  ``tpu-v5-lite``), so a row tuned on a chip is found on that chip.
 - Every tuning event is recorded on the timeline as a
   ``kernel_autotune`` span (labels ``kernel`` / ``best_config`` /
   ``candidates`` / ``best_us``, schema-linted) and publishes the
@@ -40,9 +43,6 @@ import jax
 import numpy as np
 
 CACHE_ENV = "DLROVER_TPU_AUTOTUNE_CACHE"
-_DEFAULT_CACHE = os.path.join(
-    os.path.expanduser("~"), ".cache", "dlrover_tpu", "paged_autotune.json"
-)
 _DEFAULTS_FILE = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "autotune_defaults.json"
 )
@@ -52,7 +52,11 @@ _MEMO: Dict[str, Dict[str, Any]] = {}
 
 
 def _cache_path() -> str:
-    return os.getenv(CACHE_ENV, "").strip() or _DEFAULT_CACHE
+    from dlrover_tpu.common.jax_env import CHECKOUT_CACHE_ROOT
+
+    return os.getenv(CACHE_ENV, "").strip() or os.path.join(
+        CHECKOUT_CACHE_ROOT, "paged_autotune.json"
+    )
 
 
 def _device_kind() -> str:
@@ -121,18 +125,16 @@ def _heuristic(
     dtype,
     window: int = 1,
 ) -> Dict[str, Any]:
-    """Untuned fallback.  Interpret mode: no row padding (padding is
-    pure overhead when there is no sublane tile to fill) and one page
-    per step.  Compiled TPU: tile-align the rows and stream the widest
-    legal span up to 4 pages, amortizing grid overhead."""
+    """Untuned fallback.  No per-head row padding either way: the
+    kernels run every head in one matmul and pad the TOTAL row count
+    to a sublane tile themselves.  Interpret mode streams one page per
+    step; compiled TPU streams the widest legal span up to 4 pages,
+    amortizing grid overhead."""
     from dlrover_tpu.ops.pallas_utils import use_interpret
-    from dlrover_tpu.ops.paged_kernels import sublane_tile
 
-    rows = group * (window if kernel == "verify" else 1)
+    q_rows = group * (window if kernel == "verify" else 1)
     if use_interpret():
-        return {"q_rows": rows, "kv_span": 1}
-    tile = sublane_tile(dtype)
-    q_rows = ((rows + tile - 1) // tile) * tile
+        return {"q_rows": q_rows, "kv_span": 1}
     span = 1
     for cand in (2, 4):
         if cand <= max_blocks and _span_is_legal(
@@ -254,9 +256,11 @@ def tune_kernel(
 
     ``run_fn(config)`` returns a zero-arg callable that executes the
     kernel once, *blocking until the result is ready* (the callable is
-    invoked once for warmup/compile before timing).  Candidates that
-    fail to compile are skipped, not fatal.  Returns ``(best_config,
-    report)`` where the report lists per-candidate microseconds.
+    invoked once for warmup/compile before timing).  A candidate that
+    fails to compile or run is skipped, and its report row carries the
+    reason under ``error``; no candidate surviving is fatal.  Returns
+    ``(best_config, report)`` where the report lists per-candidate
+    microseconds.
     """
     from dlrover_tpu.observability.events import get_event_logger
     from dlrover_tpu.observability.metrics import get_registry
@@ -286,7 +290,8 @@ def tune_kernel(
             best = config
     if best is None:
         raise RuntimeError(
-            f"autotune[{kernel}]: no candidate ran (tried {len(cands)})"
+            f"autotune[{kernel}]: no candidate ran (tried {len(cands)}): "
+            + "; ".join(str(row.get("error")) for row in report)
         )
     if save:
         _save_winner(key, best, best_us)

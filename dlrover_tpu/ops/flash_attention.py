@@ -36,6 +36,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.pallas_utils import use_interpret
+
 NEG_INF = -1e30
 
 
@@ -143,14 +145,6 @@ def _flash_fwd_kernel(
         lse_ref[0, 0] = m_scr[:, :1] + jnp.log(denom)
 
 
-def _use_interpret() -> bool:
-    # Hoisted to ops/pallas_utils.py so the paged kernels share one
-    # policy and one override env (DLROVER_TPU_PALLAS_INTERPRET).
-    from dlrover_tpu.ops.pallas_utils import use_interpret
-
-    return use_interpret()
-
-
 @functools.partial(
     jax.jit, static_argnames=("causal", "sm_scale", "block_q", "block_k")
 )
@@ -210,7 +204,7 @@ def _flash_fwd(
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
             pltpu.VMEM((block_q, d), jnp.float32),  # output accum
         ],
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(q, k, v)
 
 
@@ -479,7 +473,7 @@ def _flash_bwd(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(q, g, lse, delta, *glse_in, k, v)
 
     # GQA: fold per-q-head dk/dv back onto the kv heads
@@ -499,7 +493,7 @@ def _flash_bwd(
             (1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)
         ),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(q, g, lse, delta, *glse_in, k, v)
 
     return (

@@ -34,12 +34,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.pallas_utils import use_interpret
+
 _LANES = 128
 _ROWS = 8  # row block: one sublane tile
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------------------ RMSNorm
@@ -73,7 +71,7 @@ def _rms_fwd_pallas(x2, w, eps):
             pl.BlockSpec((_ROWS, d), lambda i: (i, 0)),
             pl.BlockSpec((_ROWS, 1), lambda i: (i, 0)),
         ),
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(x2, w)
     return y, rstd
 
@@ -108,7 +106,7 @@ def _rms_fwd(x, weight, eps: float):
     n = 1
     for s in lead:
         n *= s
-    if _use_interpret() or d % _LANES or n % _ROWS or n == 0:
+    if use_interpret() or d % _LANES or n % _ROWS or n == 0:
         # off-TPU (or misaligned) the plain form is already one fused
         # XLA loop; the kernel itself is covered via interpret in tests
         y, rstd = _rms_plain(x, weight, eps)

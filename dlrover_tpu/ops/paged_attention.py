@@ -54,11 +54,12 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 #: Backend selector for the decode-hot ops (decode + verify; prefill
-#: stays jnp).  ``auto`` picks the Pallas kernels whenever they can run
-#: (compiled on TPU, interpret mode elsewhere); ``jnp`` is the
+#: stays jnp).  ``auto`` picks the Pallas kernels on a TPU (compiled)
+#: and wherever interpret mode is explicitly forced; ``jnp`` is the
 #: kill-switch that pins the original gather-based reference
-#: byte-for-byte; ``pallas`` forces the kernels even if import fails
-#: (loudly).
+#: byte-for-byte; ``pallas`` forces the kernels anywhere.  A kernel
+#: that fails to import or lower raises — no backend gives way to the
+#: reference silently.
 PAGED_KERNEL_ENV = "DLROVER_TPU_PAGED_KERNEL"
 
 _VALID_BACKENDS = ("auto", "pallas", "jnp")
@@ -92,10 +93,6 @@ def paged_kernel_backend() -> str:
 
         if os.getenv(INTERPRET_ENV, "").strip().lower() not in _TRUE:
             return "jnp"
-    try:
-        from dlrover_tpu.ops import paged_kernels  # noqa: F401
-    except Exception:  # pragma: no cover - pallas unavailable
-        return "jnp"
     return "pallas"
 
 

@@ -26,22 +26,31 @@ block-by-block through the Pallas grid instead:
   lane's traffic — while ``pl.when`` skips their FLOPs.
 
 Layout contract (established in PR 13, unchanged): pools are
-``[num_blocks, block_size, KV, head_dim]`` with ``head_dim`` minormost
-and ``block_size`` on the sublane axis; block 0 is the null block and
-is garbage by design.  One grid step fetches one whole page —
-``[block_size, KV, head_dim]`` — and a static Python loop over the KV
-heads runs each head's GQA row-block against its slice, so a single
-page fetch serves every head.
+``[num_blocks, block_size, KV, head_dim]`` with ``head_dim`` minormost;
+block 0 is the null block and is garbage by design.  The kernels view
+a pool as ``[num_blocks, block_size * KV, head_dim]`` — merging the two
+middle axes keeps the chip's tiled memory order, so the reshape is a
+bitcast, not a copy (pinned in ``tests/test_tpu_compile.py``).  One
+grid step fetches one whole page as a ``[block_size * KV, head_dim]``
+tile (row ``t * KV + h`` = token ``t`` of KV head ``h``) and ONE matmul
+scores every query row against every row of the page; a head-match
+mask keeps each query row on its own KV head's columns.  That spends
+KV times the useful MXU work on a memory-bound op in exchange for a
+body Mosaic accepts: no per-head strided sublane read and no
+transposed mask (the per-head formulation compiled in interpret mode
+only).  Every index vector is built from an iota in the orientation it
+is used in.
 
-Tunables per kernel (see ``ops/autotune.py``): ``q_rows`` (padded
-query rows per KV head, a legal Mosaic sublane tile) and ``kv_span``
-(pool pages streamed per grid step; the pool is passed ``kv_span``
-times with staggered index maps, which is how a Pallas kernel widens
-its KV block without regathering).
+Tunables per kernel (see ``ops/autotune.py``): ``q_rows`` (query rows
+per KV head; the TOTAL row count is padded to a sublane tile here) and
+``kv_span`` (pool pages streamed per grid step; the pool is passed
+``kv_span`` times with staggered index maps, which is how a Pallas
+kernel widens its KV block without regathering).
 
 CPU CI runs these kernels in interpret mode
 (``ops/pallas_utils.use_interpret``); on TPU the same bodies lower to
-Mosaic unchanged.
+Mosaic (``tests/test_tpu_compile.py`` compiles them for a described
+v5e at head_dim 128, ``chip_smoke.py`` runs them on the chip).
 """
 
 from __future__ import annotations
@@ -83,30 +92,29 @@ def _iota_cols(n: int) -> jnp.ndarray:
     return lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
 
-def _online_update(m_scr, l_scr, acc_scr, rows, s_log, v, keep):
-    """One online-softmax step for scratch rows ``rows`` (static slice).
+def _online_update(m_scr, l_scr, acc_scr, s_log, v, keep):
+    """One online-softmax step over every query row at once.
 
-    ``s_log`` is fp32 ``[R, bs]`` raw logits, ``keep`` a bool mask of
-    the same shape, ``v`` fp32 ``[bs, D]`` with garbage rows already
-    zeroed.  Probabilities are re-zeroed after the exp so a row with no
-    visible keys accumulates ``l == 0`` (→ exact-zero output at
-    finalize) instead of the uniform-over-garbage a plain softmax
-    produces.
+    ``s_log`` is fp32 ``[R, C]`` raw logits, ``keep`` a bool mask of
+    the same shape, ``v`` ``[C, D]`` with garbage rows already zeroed.
+    Probabilities are re-zeroed after the exp so a row with no visible
+    keys accumulates ``l == 0`` (→ exact-zero output at finalize)
+    instead of the uniform-over-garbage a plain softmax produces.
     """
     s_log = jnp.where(keep, s_log, NEG_INF)
-    m_prev = m_scr[rows, :1]
+    m_prev = m_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s_log, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.where(keep, jnp.exp(s_log - m_new), 0.0)
-    l_new = l_scr[rows, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[rows] = acc_scr[rows] * alpha + lax.dot_general(
-        p,
+    l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+        p.astype(v.dtype),
         v,
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    m_scr[rows] = jnp.broadcast_to(m_new, m_scr[rows].shape)
-    l_scr[rows] = jnp.broadcast_to(l_new, l_scr[rows].shape)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
 def _init_state(m_scr, l_scr, acc_scr):
@@ -120,6 +128,42 @@ def _finalize(o_ref, m_scr, l_scr, acc_scr):
     o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
+def _logits(q_ref, k_ref, scale):
+    """fp32 ``[R, C]`` logits of every query row against every (token,
+    KV head) column of one page; operands stay in the pool dtype so a
+    bf16 pool feeds the MXU directly."""
+    return (
+        lax.dot_general(
+            q_ref[0],
+            k_ref[0],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        * scale
+    )
+
+
+def _page_geometry(n_rows: int, per_head: int, block_size: int, n_kv: int):
+    """Static index vectors of the one-matmul-per-page layout.
+
+    A page arrives as ``[block_size * KV, D]`` (row ``t * KV + h`` is
+    token ``t`` of KV head ``h`` — the pool's own memory order), so the
+    logits of *all* heads come out of one ``[R, D] x [D, bs*KV]``
+    matmul and a head-match mask keeps each query row on its own KV
+    head's columns.  Everything is built from iotas in the orientation
+    it is used in: no in-kernel transpose, no strided sublane read.
+    """
+    n_cols = block_size * n_kv
+    row = _iota_rows(n_rows)  # [R, 1]
+    col = _iota_cols(n_cols)  # [1, C]
+    row_head = lax.div(row, per_head)
+    row_in_head = lax.rem(row, per_head)
+    same_head = row_head == lax.rem(col, n_kv)  # [R, C]
+    col_tok = lax.div(col, n_kv)  # [1, C] token offset of each column
+    v_tok = lax.div(_iota_rows(n_cols), n_kv)  # [C, 1] same, sublane-major
+    return row_in_head, same_head, col_tok, v_tok
+
+
 # ---------------------------------------------------------------------------
 # decode: one query token per lane
 # ---------------------------------------------------------------------------
@@ -128,7 +172,7 @@ def _finalize(o_ref, m_scr, l_scr, acc_scr):
 def _decode_kernel(
     tables_ref,  # scalar prefetch [B, MB] — unused in body (index maps only)
     lens_ref,  # scalar prefetch [B]
-    q_ref,  # [1, KV*GP, D]
+    q_ref,  # [1, R, D]
     *rest,
     span: int,
     block_size: int,
@@ -136,7 +180,7 @@ def _decode_kernel(
     gp: int,
     scale: float,
 ):
-    k_refs = rest[:span]
+    k_refs = rest[:span]  # each [1, bs*KV, D]
     v_refs = rest[span : 2 * span]
     o_ref = rest[2 * span]
     m_scr, l_scr, acc_scr = rest[2 * span + 1 :]
@@ -155,38 +199,101 @@ def _decode_kernel(
     # no work (their pages were index-clamped, so no fresh copy either).
     @pl.when(j * span * block_size < seq_len)
     def _compute():
+        _, same_head, col_tok, v_tok = _page_geometry(
+            q_ref.shape[1], gp, block_size, n_kv
+        )
         for s in range(span):
             start = (j * span + s) * block_size
-            k_page = k_refs[s][0].astype(jnp.float32)  # [bs, KV, D]
-            v_page = v_refs[s][0].astype(jnp.float32)
-            col = start + _iota_cols(block_size)  # [1, bs]
-            keep = col < seq_len  # [1, bs]
+            keep = same_head & (start + col_tok < seq_len)  # [R, C]
             # Zero garbage V rows: 0 * NaN would poison the accumulator.
-            v_page = jnp.where(keep.T[:, :, None], v_page, 0.0)
-            for h in range(n_kv):
-                rows = slice(h * gp, (h + 1) * gp)
-                s_log = (
-                    lax.dot_general(
-                        q_ref[0, rows].astype(jnp.float32),
-                        k_page[:, h, :],
-                        (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    * scale
-                )  # [GP, bs]
-                _online_update(
-                    m_scr,
-                    l_scr,
-                    acc_scr,
-                    rows,
-                    s_log,
-                    v_page[:, h, :],
-                    jnp.broadcast_to(keep, s_log.shape),
-                )
+            v_page = v_refs[s][0]
+            v_page = jnp.where(
+                start + v_tok < seq_len, v_page, jnp.zeros_like(v_page)
+            )
+            _online_update(
+                m_scr,
+                l_scr,
+                acc_scr,
+                _logits(q_ref, k_refs[s], scale),
+                v_page,
+                keep,
+            )
 
     @pl.when(j == nj - 1)
     def _done():
         _finalize(o_ref, m_scr, l_scr, acc_scr)
+
+
+def _paged_call(
+    kernel,
+    qg: jnp.ndarray,  # [B, KV*rows_per_head, D]
+    k_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    scalars: jnp.ndarray,  # [B] int32: seq_lens (decode) / positions (verify)
+    *,
+    span: int,
+    last_block,  # (scalars, b) -> last valid block index of lane b
+) -> jnp.ndarray:
+    """Shared ``pallas_call`` plumbing of the two kernels: pad the query
+    rows to a sublane tile, view the pools as ``[N, bs*KV, D]`` (a
+    layout-preserving merge of the two middle axes) and stream ``span``
+    pages per grid step through staggered index maps."""
+    batch, n_rows, head_dim = qg.shape
+    n_blocks, block_size, n_kv, _ = k_pool.shape
+    max_blocks = block_tables.shape[1]
+    nj = -(-max_blocks // span)
+    rows_p = _round_up(n_rows, sublane_tile(qg.dtype))
+    if rows_p > n_rows:
+        qg = jnp.pad(qg, ((0, 0), (0, rows_p - n_rows), (0, 0)))
+    n_cols = block_size * n_kv
+    k_flat = k_pool.reshape(n_blocks, n_cols, head_dim)
+    v_flat = v_pool.reshape(n_blocks, n_cols, head_dim)
+
+    def _q_index(b, j, tables, scal):
+        del j, tables, scal
+        return (b, 0, 0)
+
+    def _page_index(b, j, tables, scal, s=0):
+        # Clamp to the lane's last valid block: grid steps past a short
+        # sequence re-request the same page, and the pipeline elides
+        # the copy (the per-lane early exit for traffic).
+        last = jnp.maximum(last_block(scal, b), 0)
+        idx = jnp.minimum(j * span + s, jnp.minimum(last, max_blocks - 1))
+        return (tables[b, idx], 0, 0)
+
+    kv_specs = [
+        pl.BlockSpec(
+            (1, n_cols, head_dim), functools.partial(_page_index, s=s)
+        )
+        for s in range(span)
+    ]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch, nj),
+        in_specs=[pl.BlockSpec((1, rows_p, head_dim), _q_index)]
+        + kv_specs
+        + kv_specs,
+        out_specs=pl.BlockSpec((1, rows_p, head_dim), _q_index),
+        scratch_shapes=[
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.VMEM((rows_p, head_dim), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, rows_p, head_dim), qg.dtype),
+        interpret=use_interpret(),
+    )(
+        block_tables.astype(jnp.int32),
+        scalars.astype(jnp.int32),
+        qg,
+        *([k_flat] * span),
+        *([v_flat] * span),
+    )
+    return out[:, :n_rows]
 
 
 def paged_decode_kernel(
@@ -216,48 +323,13 @@ def paged_decode_kernel(
         )
     span = max(1, min(int(config.get("kv_span", 1)), max_blocks))
     gp = max(int(config.get("q_rows", group)), group)
-    nj = -(-max_blocks // span)
 
     qg = q.reshape(batch, n_kv, group, head_dim)
     if gp > group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
     qg = qg.reshape(batch, n_kv * gp, head_dim)
 
-    def _q_index(b, j, tables, lens):
-        del j, tables, lens
-        return (b, 0, 0)
-
-    def _page_index(b, j, tables, lens, s=0):
-        # Clamp to the lane's last valid block: grid steps past a short
-        # sequence re-request the same page, and the pipeline elides
-        # the copy (the per-lane early exit for traffic).
-        last = jnp.maximum(lax.div(lens[b] + block_size - 1, block_size) - 1, 0)
-        idx = jnp.minimum(j * span + s, jnp.minimum(last, max_blocks - 1))
-        return (tables[b, idx], 0, 0, 0)
-
-    kv_specs = [
-        pl.BlockSpec(
-            (1, block_size, n_kv, head_dim),
-            functools.partial(_page_index, s=s),
-        )
-        for s in range(span)
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch, nj),
-        in_specs=[pl.BlockSpec((1, n_kv * gp, head_dim), _q_index)]
-        + kv_specs
-        + kv_specs,
-        out_specs=pl.BlockSpec((1, n_kv * gp, head_dim), _q_index),
-        scratch_shapes=[
-            pltpu.VMEM((n_kv * gp, 128), jnp.float32),
-            pltpu.VMEM((n_kv * gp, 128), jnp.float32),
-            pltpu.VMEM((n_kv * gp, head_dim), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
+    out = _paged_call(
         functools.partial(
             _decode_kernel,
             span=span,
@@ -266,17 +338,17 @@ def paged_decode_kernel(
             gp=gp,
             scale=head_dim**-0.5,
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, n_kv * gp, head_dim), q.dtype),
-        interpret=use_interpret(),
-    )(
-        block_tables.astype(jnp.int32),
-        seq_lens.astype(jnp.int32),
         qg,
-        *([k_pool] * span),
-        *([v_pool] * span),
+        k_pool,
+        v_pool,
+        block_tables,
+        seq_lens,
+        span=span,
+        last_block=lambda lens, b: lax.div(
+            lens[b] + block_size - 1, block_size
+        )
+        - 1,
     )
-
     out = out.reshape(batch, n_kv, gp, head_dim)[:, :, :group]
     return out.reshape(batch, n_heads, head_dim)
 
@@ -289,7 +361,7 @@ def paged_decode_kernel(
 def _verify_kernel(
     tables_ref,
     pos_ref,  # scalar prefetch [B] — position of each lane's first query
-    q_ref,  # [1, KV*WP, D]
+    q_ref,  # [1, R, D]
     *rest,
     span: int,
     block_size: int,
@@ -319,31 +391,27 @@ def _verify_kernel(
     def _compute():
         # Row r of a head's WP-row block is query offset r // group
         # (rows r >= window*group are padding and fully masked).
-        row = _iota_rows(wp)  # [WP, 1]
-        q_pos = pos + row // group
-        row_ok = row < window * group
+        row_in_head, same_head, col_tok, v_tok = _page_geometry(
+            q_ref.shape[1], wp, block_size, n_kv
+        )
+        q_pos = pos + lax.div(row_in_head, group)  # [R, 1]
+        row_ok = same_head & (row_in_head < window * group)
         for s in range(span):
             start = (j * span + s) * block_size
-            k_page = k_refs[s][0].astype(jnp.float32)
-            v_page = v_refs[s][0].astype(jnp.float32)
-            col = start + _iota_cols(block_size)  # [1, bs]
             # A key is garbage unless visible to at least the last query.
-            v_page = jnp.where((col <= horizon).T[:, :, None], v_page, 0.0)
-            keep = (col <= q_pos) & row_ok  # [WP, bs] causal window
-            for h in range(n_kv):
-                rows = slice(h * wp, (h + 1) * wp)
-                s_log = (
-                    lax.dot_general(
-                        q_ref[0, rows].astype(jnp.float32),
-                        k_page[:, h, :],
-                        (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    * scale
-                )
-                _online_update(
-                    m_scr, l_scr, acc_scr, rows, s_log, v_page[:, h, :], keep
-                )
+            v_page = v_refs[s][0]
+            v_page = jnp.where(
+                start + v_tok <= horizon, v_page, jnp.zeros_like(v_page)
+            )
+            keep = row_ok & (start + col_tok <= q_pos)  # causal window
+            _online_update(
+                m_scr,
+                l_scr,
+                acc_scr,
+                _logits(q_ref, k_refs[s], scale),
+                v_page,
+                keep,
+            )
 
     @pl.when(j == nj - 1)
     def _done():
@@ -380,7 +448,6 @@ def paged_verify_kernel(
         )
     span = max(1, min(int(config.get("kv_span", 1)), max_blocks))
     wp = max(int(config.get("q_rows", rows)), rows)
-    nj = -(-max_blocks // span)
 
     # [B, C, KV, G, D] -> [B, KV, C*G, D]: a head's K windows are
     # contiguous rows, padded to wp per head.
@@ -390,40 +457,7 @@ def paged_verify_kernel(
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, wp - rows), (0, 0)))
     qg = qg.reshape(batch, n_kv * wp, head_dim)
 
-    def _q_index(b, j, tables, pos):
-        del j, tables, pos
-        return (b, 0, 0)
-
-    def _page_index(b, j, tables, pos, s=0):
-        last = jnp.maximum(
-            lax.div(pos[b] + window - 1 + block_size, block_size) - 1, 0
-        )
-        idx = jnp.minimum(j * span + s, jnp.minimum(last, max_blocks - 1))
-        return (tables[b, idx], 0, 0, 0)
-
-    kv_specs = [
-        pl.BlockSpec(
-            (1, block_size, n_kv, head_dim),
-            functools.partial(_page_index, s=s),
-        )
-        for s in range(span)
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch, nj),
-        in_specs=[pl.BlockSpec((1, n_kv * wp, head_dim), _q_index)]
-        + kv_specs
-        + kv_specs,
-        out_specs=pl.BlockSpec((1, n_kv * wp, head_dim), _q_index),
-        scratch_shapes=[
-            pltpu.VMEM((n_kv * wp, 128), jnp.float32),
-            pltpu.VMEM((n_kv * wp, 128), jnp.float32),
-            pltpu.VMEM((n_kv * wp, head_dim), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
+    out = _paged_call(
         functools.partial(
             _verify_kernel,
             span=span,
@@ -434,17 +468,17 @@ def paged_verify_kernel(
             wp=wp,
             scale=head_dim**-0.5,
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, n_kv * wp, head_dim), q.dtype),
-        interpret=use_interpret(),
-    )(
-        block_tables.astype(jnp.int32),
-        positions.astype(jnp.int32),
         qg,
-        *([k_pool] * span),
-        *([v_pool] * span),
+        k_pool,
+        v_pool,
+        block_tables,
+        positions,
+        span=span,
+        last_block=lambda pos, b: lax.div(
+            pos[b] + window - 1 + block_size, block_size
+        )
+        - 1,
     )
-
     out = out.reshape(batch, n_kv, wp, head_dim)[:, :, :rows]
     out = out.reshape(batch, n_kv, window, group, head_dim)
     return out.transpose(0, 2, 1, 3, 4).reshape(

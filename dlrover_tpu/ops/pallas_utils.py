@@ -1,23 +1,25 @@
 """Shared plumbing for the repo's Pallas/Mosaic kernel families.
 
-Both kernel families (``ops/flash_attention.py`` dense flash and
-``ops/paged_kernels.py`` paged decode/verify) compile to Mosaic on TPU
-and fall back to Pallas *interpret mode* everywhere else, so CPU CI
-exercises the exact same kernel bodies the TPU runs — just slowly.
-That policy used to live as a private ``_use_interpret`` helper inside
-``flash_attention.py``; it is hoisted here so every kernel family
-answers the question the same way and honors the same override.
+Every kernel family (``ops/flash_attention.py`` dense flash,
+``ops/paged_kernels.py`` paged decode/verify, ``ops/fused.py`` RMSNorm
+and ``ops/quantization.py`` int8 quant / fused Adam) compiles to
+Mosaic on TPU and runs in Pallas *interpret mode* everywhere else, so
+CPU CI exercises the exact same kernel bodies the TPU runs — just
+slowly.  This module is the ONE place that decides which; no kernel
+file keeps a copy of the policy.
 
 Env contract (one env for all kernels):
 
-- ``DLROVER_TPU_PALLAS_INTERPRET=1|true|on``  -> force interpret mode,
-  even on a TPU host (useful for printf-debugging a kernel body).
-- ``DLROVER_TPU_PALLAS_INTERPRET=0|false|off`` -> force compiled mode;
-  on a non-TPU host Mosaic will refuse to lower and the call fails
-  loudly — this is a "prove I am on metal" switch, not a fast path.
-- unset -> interpret exactly when the default JAX backend is not TPU
-  (the original ``flash_attention._use_interpret`` behavior, preserved
-  byte-for-byte).
+- ``DLROVER_TPU_PALLAS_INTERPRET=1|true|on``  -> force interpret mode
+  off-TPU (how CPU CI reaches the paged kernels).  On a TPU backend
+  this RAISES: an interpreted kernel on the chip would make a broken
+  chip run look fine.
+- ``DLROVER_TPU_PALLAS_INTERPRET=0|false|off`` -> force compiled mode
+  without asking JAX for its backend; on a non-TPU host Mosaic will
+  refuse to lower and the call fails loudly.  This is also the switch
+  a compile-for-a-described-TPU rehearsal sets (the CPU is the default
+  backend there, but the lowering targets the described chip).
+- unset -> interpret exactly when the default JAX backend is not TPU.
 """
 
 from __future__ import annotations
@@ -40,8 +42,12 @@ def use_interpret() -> bool:
     next trace, not retroactively.
     """
     raw = os.getenv(INTERPRET_ENV, "").strip().lower()
-    if raw in _TRUE:
-        return True
     if raw in _FALSE:
         return False
-    return jax.default_backend() != "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    if raw in _TRUE and on_tpu:
+        raise RuntimeError(
+            f"{INTERPRET_ENV}={raw!r} on a TPU backend: Pallas kernels "
+            "never run interpreted on the chip"
+        )
+    return raw in _TRUE or not on_tpu

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.pallas_utils import use_interpret
+
 # quantization block: one scale per BLOCK elements
 BLOCK = 1024
 _LANES = 128
@@ -26,10 +28,6 @@ _SUBLANES = BLOCK // _LANES
 # multiple of 8 (or the whole array): handle 8 quant blocks per kernel
 # invocation so the scales block is a legal (8, 1)
 _GROUP = 8
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _quant_kernel(x_ref, q_ref, scale_ref, *, group: int):
@@ -86,7 +84,7 @@ def _quantize_2d(x):
                 memory_space=pltpu.SMEM,
             ),
         ),
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(x)
     return q, scales
 
@@ -111,7 +109,7 @@ def _dequantize_2d(q, scales):
         out_specs=pl.BlockSpec(
             (group * _SUBLANES, _LANES), lambda i: (i, 0)
         ),
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(q, scales)
 
 
@@ -199,7 +197,7 @@ def _fused_adam_2d(g2, mu_q, mu_s, nu_q, nu_s, bc1, bc2,
             data_spec,   # new nu int8
             scale_spec,  # new nu scales
         ),
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(g2, mu_q, mu_s, nu_q, nu_s, bc1, bc2)
 
 

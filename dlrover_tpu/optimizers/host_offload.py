@@ -26,10 +26,7 @@ Two storage backends for the host state:
   ``memory_kind="pinned_host"`` — resident in the **TPU host's** RAM
   and DMA'd over its PCIe by XLA-compiled transfer programs, with
   donation recycling the host buffers.  This is the XLA-memories
-  redesign of the reference's cudaMemcpy bucket loop, and the only
-  correct choice when the Python client is NOT the TPU host (a
-  remote/tunnel attachment would otherwise haul every chunk over the
-  network).
+  redesign of the reference's cudaMemcpy bucket loop.
 - ``numpy`` (default on CPU/tests): plain in-process numpy buffers,
   updated in place, with a sliding in-flight window overlapping
   transfers and compute.
@@ -80,10 +77,11 @@ _HOST_KIND_PROBED: Optional[bool] = None
 
 def _pinned_host_works() -> bool:
     """Whether this backend supports the ``pinned_host`` memory kind
-    (TPU yes; the CPU test mesh no).  Probed once: a failed probe
-    downgrades host shardings to plain device shardings so the SAME
-    code path runs — with identical math — where no second memory
-    space exists."""
+    (TPU yes; the CPU test mesh no).  Probed once.  Off-TPU a failed
+    probe downgrades host shardings to plain device shardings so the
+    SAME code path runs — with identical math — where no second memory
+    space exists.  On a TPU the probe failing is an error and RAISES:
+    "offloaded" state silently left in HBM is not an offload."""
     global _HOST_KIND_PROBED
     if _HOST_KIND_PROBED is None:
         from jax.sharding import SingleDeviceSharding
@@ -106,6 +104,8 @@ def _pinned_host_works() -> bool:
             jax.block_until_ready(fn(x))
             _HOST_KIND_PROBED = True
         except Exception:  # noqa: BLE001 - any failure means "no"
+            if jax.default_backend() == "tpu":
+                raise
             _HOST_KIND_PROBED = False
             logger.info(
                 "pinned_host memory kind unavailable; host-offload "

@@ -26,16 +26,7 @@ from jax import lax
 def device_varying(x, axis_name):
     """Mark a freshly-created array as device-varying over ``axis_name``
     (shard_map vma typing for scan carries)."""
-    try:
-        return lax.pcast(x, axis_name, to="varying")
-    except (AttributeError, TypeError):  # older jax
-        pass
-    try:
-        return lax.pvary(x, axis_name)
-    except AttributeError:
-        # pre-vma jax (<=0.4.x): replication typing does not exist,
-        # the array is already usable as a manual-region carry
-        return x
+    return lax.pcast(x, axis_name, to="varying")
 
 
 def seq_all_to_all(

@@ -26,34 +26,18 @@ from dlrover_tpu.parallel.mesh import AxisName
 
 def shard_map_compat(fn, mesh, in_specs, out_specs,
                      manual_axes=None, check=False):
-    """``shard_map`` across jax versions.
-
-    The modern API (``jax.shard_map`` with ``axis_names``/
-    ``check_vma``) when present; ``jax.experimental.shard_map``
-    (``auto``/``check_rep``) otherwise.  ``manual_axes``: the mesh
-    axes the body handles manually (None = all of them)."""
+    """``jax.shard_map`` with this repo's defaults (no vma check).
+    ``manual_axes``: the mesh axes the body handles manually (None =
+    all of them)."""
     import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = dict(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        return sm(fn, **kw)
-    from jax.experimental.shard_map import shard_map as legacy_sm
 
     kw = dict(
         mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
+        check_vma=check,
     )
     if manual_axes is not None:
-        auto = frozenset(mesh.axis_names) - set(manual_axes)
-        if auto:
-            kw["auto"] = auto
-    return legacy_sm(fn, **kw)
+        kw["axis_names"] = set(manual_axes)
+    return jax.shard_map(fn, **kw)
 
 
 # logical axis vocabulary used by model definitions

@@ -331,6 +331,20 @@ def worker_main() -> int:
     return _legacy_worker_loop(spec)
 
 
+def _worker_env(spec) -> Dict[str, str]:
+    """Environment of a spawned generation/serving worker.  The parent
+    never asks JAX which backend it has (on a TPU that would take the
+    chip the worker needs): the worker's platform is whatever the
+    environment this process was given says (``JAX_PLATFORMS``), and
+    its compile cache the one shared directory of the checkout."""
+    from dlrover_tpu.common.jax_env import export_compile_cache
+
+    env = dict(os.environ)
+    env[WORKER_SPEC_ENV] = json.dumps(spec)
+    export_compile_cache(env)
+    return env
+
+
 class CrossProcessGenerationEngine:
     """Trainer-side handle on the generation process.
 
@@ -373,16 +387,9 @@ class CrossProcessGenerationEngine:
             "max_new_tokens": int(max_new_tokens),
             "temperature": float(temperature),
         }
-        env = dict(os.environ)
-        env[WORKER_SPEC_ENV] = json.dumps(spec)
-        import jax
-
-        if jax.default_backend() == "cpu":
-            # tests / CPU: the worker must not grab a TPU runtime
-            env.setdefault("JAX_PLATFORMS", "cpu")
         self._proc = subprocess.Popen(
             [sys.executable, "-m", "dlrover_tpu.rl.generation_service"],
-            env=env,
+            env=_worker_env(spec),
         )
         ready = self._resp.get(timeout=start_timeout)
         if not ready.get("ready"):
@@ -880,6 +887,20 @@ def _serving_worker_loop(spec) -> int:
             ),
         )
 
+    from dlrover_tpu.common.jax_env import device_report
+    from dlrover_tpu.ops.paged_attention import paged_kernel_backend
+    from dlrover_tpu.ops.pallas_utils import use_interpret
+
+    device = device_report()
+    events.instant(
+        "device_report",
+        platform=device["platform"],
+        device_kind=device["device_kind"],
+        device_count=device["device_count"],
+        replica=tag,
+        kernel_backend=paged_kernel_backend(),
+        interpret=use_interpret(),
+    )
     # READY carries the per-block region size so the dispatcher can
     # size the ship arena without instantiating the model itself
     _respond(_KIND_READY, times=(float(block_bytes),))
@@ -1097,6 +1118,14 @@ def _serving_worker_loop(spec) -> int:
             logprobs=r.resume_logprobs,
         )
     _respond(_KIND_DRAINED, new_tokens=len(requeued))
+    events.instant(
+        "device_report",
+        platform=device["platform"],
+        device_kind=device["device_kind"],
+        device_count=device["device_count"],
+        replica=tag,
+        compile_counts=json.dumps(scheduler.compile_counts()),
+    )
     logger.info(
         "serving replica %s drained on %s: served %d, handed back %d",
         tag, drain["reason"], served, len(requeued),
@@ -1346,14 +1375,9 @@ class ServingEngine:
             if self._fleet and idx < self._n_prefill else "decode"
         )
         spec = dict(self._spec, replica=idx, role=role)
-        env = dict(os.environ)
-        env[WORKER_SPEC_ENV] = json.dumps(spec)
+        env = _worker_env(spec)
         if self._socket_dir:
             env[SOCKET_DIR_ENV] = self._socket_dir
-        import jax
-
-        if jax.default_backend() == "cpu":
-            env.setdefault("JAX_PLATFORMS", "cpu")
         proc = subprocess.Popen(
             [sys.executable, "-m", "dlrover_tpu.rl.generation_service"],
             env=env,

@@ -87,10 +87,7 @@ class InferenceBackend(metaclass=ABCMeta):
         the bucket satellite's regression meter (one per bucket, not
         one per distinct ``[B, P]``)."""
         fn = getattr(self, "_compiled_fn", None)
-        try:
-            return int(fn._cache_size())
-        except Exception:  # noqa: BLE001 - jax-version specific
-            return -1
+        return -1 if fn is None else int(fn._cache_size())
 
 
 class JitSamplerBackend(InferenceBackend):

@@ -745,10 +745,7 @@ class ContinuousBatchingScheduler:
         fused multi-token one when ``DLROVER_TPU_DECODE_STEPS>1``."""
 
         def n(f):
-            try:
-                return int(f._cache_size())
-            except Exception:  # noqa: BLE001 - jax-version specific
-                return -1
+            return int(f._cache_size())
 
         if (
             self._decode_multi_draft_jit is not None

@@ -456,7 +456,7 @@ class CheckpointEngine:
         restorable by ANY world size.  None = legacy world-locked
         format.
         """
-        if not self._snapshot_slot_free(step):
+        if not self.snapshot_slot_free(step):
             return False
         if not reshard_enabled():
             layouts = None  # kill-switch: today's format, byte for byte
@@ -464,7 +464,14 @@ class CheckpointEngine:
             return self._drain_snapshot(step, state, None, layouts)
         return self._launch_async_snapshot(step, state, None, layouts)
 
-    def _snapshot_slot_free(self, step: int) -> bool:
+    def snapshot_slot_free(self, step: int) -> bool:
+        """False (and the skip is counted) while the previous snapshot
+        is still draining.  Callers ask BEFORE they pay for a device
+        copy or a device->host pull of the state: a snapshot that will
+        be skipped must cost nothing (on a v5e a skipped staged
+        snapshot used to stall its step 3 s for 8 GB, and a skipped
+        "copy" snapshot held a second on-device state next to the one
+        still draining — out of HBM)."""
         if self._snapshot_thread is not None:
             if self._snapshot_thread.is_alive():
                 self._count_skip()
@@ -584,7 +591,7 @@ class CheckpointEngine:
             return True
         # async: the persist event must trail the shm write, so the
         # drain thread enqueues it
-        if not self._snapshot_slot_free(step):
+        if not self.snapshot_slot_free(step):
             return False
         if not reshard_enabled():
             layouts = None  # kill-switch: today's format, byte for byte

@@ -111,12 +111,11 @@ def coordination_client():
     backend, including CPU worlds where XLA multiprocess computations
     (and therefore every ``multihost_utils`` collective) are
     unavailable."""
-    try:
-        from jax._src import distributed
+    # jax.distributed exposes initialize/is_initialized/shutdown only;
+    # the client itself still lives on the private global state
+    from jax._src import distributed
 
-        return distributed.global_state.client
-    except Exception:  # noqa: BLE001 - private API drift across jax versions
-        return None
+    return distributed.global_state.client
 
 
 def control_plane_barrier(

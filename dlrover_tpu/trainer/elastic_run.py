@@ -31,6 +31,7 @@ from dlrover_tpu.agent.training import (
 from dlrover_tpu.common.comm import addr_connectable, wait_channel_ready
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.env import control_longpoll_enabled, get_free_port
+from dlrover_tpu.common.jax_env import compile_cache_dir, platform_from_env
 from dlrover_tpu.common.log import default_logger as logger
 
 
@@ -107,8 +108,11 @@ def parse_args(argv=None):
     )
     parser.add_argument(
         "--compile_cache_dir",
-        default=os.getenv("JAX_COMPILATION_CACHE_DIR", ""),
-        help="persistent XLA compile cache (keeps restarts cheap)",
+        default=compile_cache_dir(),
+        help="persistent XLA compile cache (keeps restarts cheap); "
+        "default: $JAX_COMPILATION_CACHE_DIR, else the fixed "
+        "<checkout>/.cache/jax_compile — the path is part of the "
+        "cache key, so it never moves between runs",
     )
     parser.add_argument(
         "--events_file",
@@ -218,8 +222,47 @@ def _build_entrypoint(args) -> List[str]:
     return [sys.executable, args.training_script, *script_args]
 
 
+def _check_nproc_fits_host(nproc: int):
+    """Refuse, at launch and with a message, a worker count the host's
+    chips cannot serve.  There is no per-worker chip binding: every
+    worker process asks for ALL chips of its host, and a TPU chip
+    belongs to one process — so a second worker on a TPU host fails or
+    hangs on chips the first one holds.  One process drives every chip
+    of its host (``--nproc_per_node=1``); CPU hosts (virtual devices)
+    take any count.
+
+    The launcher itself must stay off the backend, so the host is
+    probed in a throwaway subprocess that has exited — and released
+    the chip — before any worker starts."""
+    if nproc <= 1 or platform_from_env() == "cpu":
+        return
+    probe = subprocess.run(  # noqa: S603
+        [
+            sys.executable, "-c",
+            "import jax; "
+            "print(jax.default_backend(), jax.local_device_count())",
+        ],
+        capture_output=True, text=True, timeout=300,
+    )
+    if probe.returncode != 0:
+        raise SystemExit(
+            "could not probe this host's accelerator before starting "
+            f"{nproc} workers:\n{probe.stderr[-2000:]}"
+        )
+    backend, chips = probe.stdout.split()[-2:]
+    if backend == "tpu":
+        raise SystemExit(
+            f"--nproc_per_node={nproc} on a TPU host with {chips} "
+            "chip(s): workers are not bound to chips (each asks for all "
+            "of them, and a chip belongs to one process), so more than "
+            "one worker per host cannot start.  Use --nproc_per_node=1: "
+            "one worker process drives every chip of its host."
+        )
+
+
 def run(args) -> int:
     min_nodes, max_nodes = parse_nnodes(args.nnodes)
+    _check_nproc_fits_host(args.nproc_per_node)
     node_rank = args.node_rank
     if node_rank < 0:
         node_rank = int(os.getenv(NodeEnv.NODE_RANK, "0"))

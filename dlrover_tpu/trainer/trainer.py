@@ -375,6 +375,8 @@ class Trainer:
         )
         if not (to_storage or to_memory):
             return
+        if not self._engine.snapshot_slot_free(step):
+            return  # previous drain still running: skip, at no cost
         if self._snapshot_mode is None:
             self._snapshot_mode = self._resolve_snapshot_mode()
             logger.info("snapshot mode: %s", self._snapshot_mode)

@@ -307,8 +307,6 @@ class NodePod:
                 # a lone survivor must complete its shrunken round in
                 # seconds; joiners still get the full 60 s above
                 "--rdzv_waiting_timeout=1.5",
-                "--compile_cache_dir="
-                + os.path.join(self._workdir, "xla_cache"),
                 os.path.join(REPO, "scripts", "goodput_train.py"),
             ],
             stdout=log, stderr=subprocess.STDOUT, env=env,
@@ -794,8 +792,6 @@ def run_plan(
                 "--stop_timeout=2",
                 "--max_restarts=4",
                 "--failure_stop_timeout=0.5",
-                "--compile_cache_dir="
-                + os.path.join(workdir, "xla_cache"),
                 os.path.join(REPO, "scripts", "goodput_train.py"),
             ],
             stdout=log, stderr=subprocess.STDOUT, env=env,

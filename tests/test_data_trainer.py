@@ -29,8 +29,7 @@ from dlrover_tpu.parallel.mesh import destroy_parallel_mesh
 from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs
 
 
-# the producer must not import jax (a spawned child would re-init the
-# TPU plugin); it touches only the shm module
+# the producer does not import jax: it touches only the shm module
 _PRODUCER_SCRIPT = """
 import sys
 sys.path.insert(0, {repo!r})
@@ -187,6 +186,30 @@ class TestTrainer:
         t2 = self._build(tmp_path, max_steps=6, socket_dir=sock)
         start = t2._init_or_restore_state()
         assert start >= 4
+
+    @pytest.mark.parametrize("mode", ["staged", "copy"])
+    def test_skipped_snapshot_costs_no_device_work(
+        self, tmp_path, monkeypatch, mode
+    ):
+        """While the previous snapshot is still draining the next one
+        is skipped BEFORE the state is pulled to the host (staged) or
+        copied on the device (copy): on a v5e the late check stalled
+        every skipped step 3 s and ran "copy" mode out of HBM."""
+        t = self._build(
+            tmp_path, max_steps=2, socket_dir=str(tmp_path / "socks5"),
+            snapshot_mode=mode,
+        )
+        t._init_or_restore_state()
+        monkeypatch.setattr(
+            t._engine, "snapshot_slot_free", lambda step: False
+        )
+
+        def boom(*_a, **_k):
+            raise AssertionError("device work for a skipped snapshot")
+
+        monkeypatch.setattr(t, "_staged_device_get", boom)
+        t._snap_fn = boom
+        t._maybe_checkpoint(2)
 
     def test_replay_recorder_wired(self, tmp_path):
         """With replay_dir set, the Trainer ring-logs every batch and

@@ -554,8 +554,7 @@ def _pinned_host_supported():
 class TestPinnedHostBackend:
     """The XLA-memories backend: state chunks live in the TPU host's
     RAM as pinned_host jax arrays; transfers are compiled DMA, never
-    the Python client's bandwidth (critical under remote
-    attachments)."""
+    the Python client's bandwidth."""
 
     def test_matches_numpy_backend(self):
         params = _tree_params(jax.random.PRNGKey(3))

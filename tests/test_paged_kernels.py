@@ -107,12 +107,30 @@ def _tol(dtype):
     return 5e-5 if dtype == jnp.float32 else 6e-2
 
 
+#: (dtype, block_size, group, head_dim, kv): the tiny sweep, plus the
+#: shape the chip runs (head_dim 128, block_size 16, bf16 — GQA with 8
+#: KV heads and MHA with 32, what ``chip_smoke.py`` and
+#: ``tests/test_tpu_compile.py`` compile to Mosaic), small batch
+PARITY_CASES = [
+    pytest.param(dtype, bs, group, 8, 2,
+                 id=f"g{group}-bs{bs}-{jnp.dtype(dtype).name}")
+    for dtype in (jnp.float32, jnp.bfloat16)
+    for bs in (8, 16)
+    for group in (1, 2, 4)
+] + [
+    pytest.param(jnp.bfloat16, 16, 4, 128, 8, id="chip-gqa8-d128-bs16"),
+    pytest.param(jnp.bfloat16, 16, 1, 128, 32, id="chip-mha32-d128-bs16"),
+]
+
+
 class TestDecodeParity:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("block_size", [8, 16])
-    @pytest.mark.parametrize("group", [1, 2, 4])
-    def test_matches_jnp_reference(self, dtype, block_size, group):
-        c = _case(group, block_size, dtype)
+    @pytest.mark.parametrize(
+        "dtype,block_size,group,head_dim,kv", PARITY_CASES
+    )
+    def test_matches_jnp_reference(
+        self, dtype, block_size, group, head_dim, kv
+    ):
+        c = _case(group, block_size, dtype, head_dim=head_dim, kv=kv)
         ref = pa.paged_decode_attention(
             c["q"], c["k_pool"], c["v_pool"], c["tables"],
             c["seq_lens"], backend="jnp",
@@ -169,11 +187,13 @@ class TestDecodeParity:
 
 
 class TestVerifyParity:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("block_size", [8, 16])
-    @pytest.mark.parametrize("group", [1, 2, 4])
-    def test_matches_jnp_reference(self, dtype, block_size, group):
-        c = _case(group, block_size, dtype)
+    @pytest.mark.parametrize(
+        "dtype,block_size,group,head_dim,kv", PARITY_CASES
+    )
+    def test_matches_jnp_reference(
+        self, dtype, block_size, group, head_dim, kv
+    ):
+        c = _case(group, block_size, dtype, head_dim=head_dim, kv=kv)
         ref = pa.paged_verify_attention(
             c["qv"], c["k_pool"], c["v_pool"], c["tables"],
             c["positions"], backend="jnp",
@@ -274,14 +294,33 @@ class TestInterpretEnv:
         monkeypatch.setenv(INTERPRET_ENV, "off")
         assert use_interpret() is False
 
-    def test_flash_attention_uses_shared_helper(self, monkeypatch):
+    @pytest.mark.parametrize(
+        "module", ["flash_attention", "fused", "quantization",
+                   "paged_kernels"],
+    )
+    def test_every_kernel_family_uses_the_one_policy(self, module):
+        """No kernel file keeps its own copy of the interpret switch
+        (``fused`` and ``quantization`` used to, and a compile-for-TPU
+        rehearsal silently compiled the interpreter instead)."""
         import importlib
 
-        fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
-        monkeypatch.setenv(INTERPRET_ENV, "0")
-        assert fa._use_interpret() is False
+        from dlrover_tpu.ops import pallas_utils
+
+        mod = importlib.import_module(f"dlrover_tpu.ops.{module}")
+        assert mod.use_interpret is pallas_utils.use_interpret
+        assert not hasattr(mod, "_use_interpret")
+
+    def test_interpret_is_refused_on_a_tpu_backend(self, monkeypatch):
+        from dlrover_tpu.ops import pallas_utils
+
+        monkeypatch.setattr(
+            pallas_utils.jax, "default_backend", lambda: "tpu"
+        )
         monkeypatch.delenv(INTERPRET_ENV, raising=False)
-        assert fa._use_interpret() == (jax.default_backend() != "tpu")
+        assert use_interpret() is False
+        monkeypatch.setenv(INTERPRET_ENV, "1")
+        with pytest.raises(RuntimeError, match="never run interpreted"):
+            use_interpret()
 
 
 class TestAutotune:

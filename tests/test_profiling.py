@@ -42,24 +42,27 @@ from dlrover_tpu.observability.trace import OpAggregate, TraceReport
 
 class TestPeakFlopsTable:
     def test_known_kinds(self):
-        assert peak_flops_for_kind("TPU v5 lite") == (197e12, True)
-        assert peak_flops_for_kind("TPU v5e") == (197e12, True)
-        assert peak_flops_for_kind("TPU v5") == (459e12, True)
-        assert peak_flops_for_kind("TPU v4") == (275e12, True)
-        assert peak_flops_for_kind("TPU v3") == (123e12, True)
-        assert peak_flops_for_kind("TPU v6e") == (918e12, True)
+        assert peak_flops_for_kind("TPU v5 lite") == 197e12
+        assert peak_flops_for_kind("TPU v5e") == 197e12
+        assert peak_flops_for_kind("TPU v5") == 459e12
+        assert peak_flops_for_kind("TPU v4") == 275e12
+        assert peak_flops_for_kind("TPU v3") == 123e12
+        assert peak_flops_for_kind("TPU v6e") == 918e12
 
-    def test_unknown_kind_falls_back_loudly(self):
-        peak, known = peak_flops_for_kind("weird accelerator")
-        assert peak == 197e12
-        assert known is False
+    def test_unknown_kind_is_refused(self, monkeypatch):
+        with pytest.raises(LookupError, match="weird accelerator"):
+            peak_flops_for_kind("weird accelerator")
+        # no override, CPU device kind: no guessed peak either
+        monkeypatch.delenv("DLROVER_TPU_PEAK_FLOPS", raising=False)
+        with pytest.raises(LookupError):
+            device_peak_flops()
 
     def test_env_override_wins(self, monkeypatch):
         monkeypatch.setenv("DLROVER_TPU_PEAK_FLOPS", "123.5e12")
         assert device_peak_flops() == 123.5e12
         monkeypatch.setenv("DLROVER_TPU_PEAK_FLOPS", "not-a-number")
-        # malformed: falls through to the table (CPU kind -> default)
-        assert device_peak_flops() == 197e12
+        with pytest.raises(ValueError):  # malformed: refused, not skipped
+            device_peak_flops()
 
     def test_aprofiler_mfu_routes_through_table(self, monkeypatch):
         monkeypatch.setenv("DLROVER_TPU_PEAK_FLOPS", "4.0")
