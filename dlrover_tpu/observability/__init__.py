@@ -15,7 +15,3 @@ from dlrover_tpu.observability.profiler import AProfiler  # noqa: F401
 from dlrover_tpu.observability.status_server import (  # noqa: F401
     StatusServer,
 )
-from dlrover_tpu.observability.hlo_census import (  # noqa: F401
-    census_report,
-    gemm_census,
-)

@@ -32,9 +32,10 @@ a typo'd phase can never silently drop out of the ledger.
 import io
 import json
 import os
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional, Tuple
 
 from dlrover_tpu.common.log import default_logger as logger
@@ -73,6 +74,14 @@ def anchored_now(mono: Optional[float] = None) -> float:
 #: lost), and a rendezvous nested inside a restart charges
 #: rendezvous.
 PHASE_DATA_STALL = "data_stall"
+# the synchronous leg of a snapshot (trainer/trainer.py
+# ``_maybe_checkpoint``): the leaf-wise device->host pull in ``staged``
+# mode, the on-device copy in ``copy`` mode.  It runs on the training
+# thread between two step completions, so it lies INSIDE a
+# step_done-to-step_done ``step`` span and, like ``data_stall``, must
+# outrank it: the pull is loss, not useful time.  (``checkpoint_save``,
+# the asynchronous drain that follows, stays below ``step``.)
+PHASE_SNAPSHOT_PULL = "snapshot_pull"
 PHASE_STEP = "step"
 PHASE_PREEMPTION_DRAIN = "preemption_drain"
 PHASE_CHECKPOINT_RESTORE = "checkpoint_restore"
@@ -180,6 +189,7 @@ PHASE_TRAJECTORY = "trajectory"
 
 PHASES: Tuple[str, ...] = (
     PHASE_DATA_STALL,
+    PHASE_SNAPSHOT_PULL,
     PHASE_STEP,
     PHASE_PREEMPTION_DRAIN,
     PHASE_CHECKPOINT_RESTORE,
@@ -214,6 +224,36 @@ PHASES: Tuple[str, ...] = (
 
 #: Phases that count as useful training time in the ledger.
 USEFUL_PHASES = frozenset({PHASE_STEP})
+
+#: Leaf annotations (:meth:`EventLogger.leaf`): host phases that occur
+#: EVERY iteration of a hot loop.  They are written only to the
+#: profiler's trace (``jax.profiler.TraceAnnotation``), never as JSONL
+#: lines — their per-iteration sums ride as labels on the loop's one
+#: record (``serve_step``: ``admit_ms`` ... ``other_ms``).  A leaf may
+#: also carry a declared phase's name where the site reports the span
+#: after the fact with ``complete()`` (``snapshot_pull``).  Leaves of
+#: one thread never nest: a trace reader names an idle gap by the host
+#: event with the longest overlap, and an enclosing event would win
+#: every gap.
+LEAF_ANNOTATIONS = frozenset(
+    {
+        # rl/scheduler.py: admission + block growth
+        "sched.admit",
+        # host->device uploads + the jitted call returning
+        "sched.dispatch",
+        # blocking readbacks: the device is busy, the host is not the
+        # cause
+        "sched.wait",
+        # _append_token / _finish over the lanes
+        "sched.commit",
+        # the replica's loop AROUND the scheduler's step
+        # (rl/generation_service.py): weight adoption and the request
+        # ring drained into submit() before it, results flushed to the
+        # response ring (and the per-second stats row) after it
+        "sched.intake",
+        "sched.reply",
+    }
+)
 
 #: Wall clock covered by no span at all (monitor-detection gaps,
 #: wedged-in-collective survivors, scheduler noise).  Kept as its own
@@ -317,6 +357,10 @@ REQUIRED_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
     # onto devices) so a slow storage read and a saturated transfer
     # link stay distinguishable in the ledger
     PHASE_DATA_STALL: ("stage",),
+    # the synchronous snapshot leg, sized and timed like the checkpoint
+    # data-plane spans, plus WHICH leg it was (staged | copy): 8 GB at
+    # 3.5 GB/s and a 20 ms on-device copy are different stories
+    PHASE_SNAPSHOT_PULL: ("step", "bytes", "throughput_gbps", "mode"),
     # checkpoint data-plane spans carry their size and measured
     # bandwidth so throughput regressions surface in the ledger and
     # in bench_goodput's loss breakdown, not only in wall time
@@ -429,6 +473,47 @@ REQUIRED_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
 }
 
 
+#: Labels a span MAY carry beyond the required ones, for the phases
+#: whose label set is CLOSED (``scripts/check_event_schema.py`` refuses
+#: any other keyword at their emit sites).  ``serve_step``: the
+#: partition of the iteration's host time into the ``sched.*`` leaves
+#: (milliseconds; the five sum to the span's ``dur``) and the lane
+#: counts behind batch occupancy — written only under
+#: ``DLROVER_TPU_SERVE_OBS``, so they are optional.
+OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
+    PHASE_SERVE_STEP: (
+        "admit_ms",
+        "dispatch_ms",
+        "wait_ms",
+        "commit_ms",
+        "other_ms",
+        "lanes_decode",
+        "lanes_prefill",
+        "slots",
+    ),
+}
+
+_NO_ANNOTATION = nullcontext()
+_trace_annotation = None  # jax.profiler.TraceAnnotation, bound lazily
+
+
+def _annotation(name: str):
+    """A context manager that puts ``name`` on the host plane of an
+    open ``jax.profiler`` trace — the same ``.xplane.pb``, the same
+    clock as the device operations; a TraceMe no-op when no profiler
+    session is open.  Bound lazily and only in a process that has
+    ALREADY imported JAX: the agent, the master and the serving parent
+    must stay off it."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
 class EventLogger:
     """Append structured events to a JSONL timeline file.
 
@@ -469,6 +554,8 @@ class EventLogger:
         self._sid = 0
         # per-(thread, phase) open-span stack for begin/end pairing
         self._open: Dict[Tuple[int, str], List[dict]] = {}
+        # ... and the profiler annotation each open span entered
+        self._annotated: Dict[Tuple[int, str], list] = {}
         #: emits since the last rotation check (the size stat is not
         #: paid per line)
         self._emits_since_check = 0
@@ -577,8 +664,35 @@ class EventLogger:
             except OSError as e:
                 logger.warning("event emit failed: %s", e)
 
+    @staticmethod
+    def leaf(name: str):
+        """``with events.leaf("sched.commit"): ...`` — a phase that
+        occurs every iteration: ONLY the profiler annotation, no JSONL
+        line (``LEAF_ANNOTATIONS``).  Works on a disabled logger too:
+        an operator's profiler window shows the phases whether or not
+        an events file is configured."""
+        return _annotation(name)
+
+    def _annotate_begin(self, phase: str):
+        ann = _annotation(phase)
+        if ann is _NO_ANNOTATION:
+            return
+        ann.__enter__()
+        self._annotated.setdefault(
+            (threading.get_ident(), phase), []
+        ).append(ann)
+
+    def _annotate_end(self, phase: str):
+        stack = self._annotated.get((threading.get_ident(), phase))
+        if stack:
+            stack.pop().__exit__(None, None, None)
+
     def begin(self, phase: str, **labels) -> int:
-        """Open a span; returns the span id ``end`` pairs on."""
+        """Open a span; returns the span id ``end`` pairs on.  The
+        span also lands in an open profiler trace, under its phase
+        name, for as long as it stays open (begin and end on ONE
+        thread, as the pairing already asks)."""
+        self._annotate_begin(phase)
         if not self._path:
             return -1
         with self._lock:
@@ -592,6 +706,7 @@ class EventLogger:
         return sid
 
     def end(self, phase: str, sid: int = -1, **labels):
+        self._annotate_end(phase)
         if not self._path:
             return
         rec = self._record(phase, "E", **labels)

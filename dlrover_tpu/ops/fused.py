@@ -34,7 +34,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.pallas_utils import use_interpret
+from dlrover_tpu.ops.pallas_utils import named_kernel, use_interpret
 
 _LANES = 128
 _ROWS = 8  # row block: one sublane tile
@@ -56,24 +56,27 @@ def _rms_fwd_kernel(x_ref, w_ref, y_ref, rstd_ref, *, eps: float):
 def _rms_fwd_pallas(x2, w, eps):
     n, d = x2.shape
     grid = n // _ROWS
-    y, rstd = pl.pallas_call(
-        functools.partial(_rms_fwd_kernel, eps=eps),
-        out_shape=(
-            jax.ShapeDtypeStruct(x2.shape, x2.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+    return named_kernel(
+        "rmsnorm_fwd",
+        pl.pallas_call(
+            functools.partial(_rms_fwd_kernel, eps=eps),
+            out_shape=(
+                jax.ShapeDtypeStruct(x2.shape, x2.dtype),
+                jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            ),
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec((_ROWS, d), lambda i: (i, 0)),
+                pl.BlockSpec((d,), lambda i: (0,)),
+            ],
+            out_specs=(
+                pl.BlockSpec((_ROWS, d), lambda i: (i, 0)),
+                pl.BlockSpec((_ROWS, 1), lambda i: (i, 0)),
+            ),
+            interpret=use_interpret(),
+            name="rmsnorm_fwd",
         ),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((_ROWS, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((_ROWS, d), lambda i: (i, 0)),
-            pl.BlockSpec((_ROWS, 1), lambda i: (i, 0)),
-        ),
-        interpret=use_interpret(),
     )(x2, w)
-    return y, rstd
 
 
 def _rms_plain(x, weight, eps):

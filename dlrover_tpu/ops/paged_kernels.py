@@ -65,7 +65,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.pallas_utils import use_interpret
+from dlrover_tpu.ops.pallas_utils import named_kernel, use_interpret
 
 NEG_INF = -1e30
 
@@ -234,6 +234,7 @@ def _paged_call(
     *,
     span: int,
     last_block,  # (scalars, b) -> last valid block index of lane b
+    name: str,  # the kernel's name in a device trace
 ) -> jnp.ndarray:
     """Shared ``pallas_call`` plumbing of the two kernels: pad the query
     rows to a sublane tile, view the pools as ``[N, bs*KV, D]`` (a
@@ -281,11 +282,17 @@ def _paged_call(
             pltpu.VMEM((rows_p, head_dim), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, rows_p, head_dim), qg.dtype),
-        interpret=use_interpret(),
+    out = named_kernel(
+        name,
+        pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (batch, rows_p, head_dim), qg.dtype
+            ),
+            interpret=use_interpret(),
+            name=name,
+        ),
     )(
         block_tables.astype(jnp.int32),
         scalars.astype(jnp.int32),
@@ -348,6 +355,7 @@ def paged_decode_kernel(
             lens[b] + block_size - 1, block_size
         )
         - 1,
+        name="paged_decode",
     )
     out = out.reshape(batch, n_kv, gp, head_dim)[:, :, :group]
     return out.reshape(batch, n_heads, head_dim)
@@ -478,6 +486,7 @@ def paged_verify_kernel(
             pos[b] + window - 1 + block_size, block_size
         )
         - 1,
+        name="paged_verify",
     )
     out = out.reshape(batch, n_kv, wp, head_dim)[:, :, :rows]
     out = out.reshape(batch, n_kv, window, group, head_dim)

@@ -51,3 +51,24 @@ def use_interpret() -> bool:
             "never run interpreted on the chip"
         )
     return raw in _TRUE or not on_tpu
+
+
+def named_kernel(name: str, call):
+    """``call`` — the function a ``pl.pallas_call`` returns — under a
+    stable name in the device trace.
+
+    Pallas stages its kernel through an anonymous wrapper, so the
+    kernel's event on the trace's ``XLA Ops`` line is the compiler's
+    ``closed_call.N``, which no reduction can pick out.  XLA inlines a
+    nested ``jit`` and gives the CALL's name to the root of what it
+    inlined; with the custom call as that root (``call`` must be the
+    whole body — no slicing or reshaping after it in here) the event
+    reads ``<name>.N`` from run to run.  Pass the same ``name`` to
+    ``pl.pallas_call`` so the Mosaic kernel carries it too.  Nothing
+    about what the kernel computes changes."""
+
+    def kernel(*args):
+        return call(*args)
+
+    kernel.__name__ = kernel.__qualname__ = name
+    return jax.jit(kernel)

@@ -916,178 +916,186 @@ def _serving_worker_loop(spec) -> int:
             # requests never progress, no stats ever flow again
             time.sleep(0.05)
             continue
-        _adopt_weights()
-        if fault_sleep_s:
-            time.sleep(fault_sleep_s)  # injected SLO straggler
-        # admit everything queued on the ring (token-level admission
-        # happens inside the scheduler)
-        while True:
-            msg = req_ring.try_get()
-            if msg is None:
-                break
-            (req_id, plen, max_new, seed, ring_ver, wall_ns,
-             slo_i, tenant_h, ship_mode, ship_slot, first_tok,
-             n_ship, route_code, resume_len) = (
-                int(v) for v in msg["meta"]
-            )
-            if ring_ver != RING_SCHEMA_VERSION:
-                raise RingSchemaMismatch(ring_ver, "dispatch request")
-            try:
-                kwargs = dict(
-                    max_new=max_new,
-                    seed=seed,
-                    req_id=req_id,
-                    submit_wall=(
-                        wall_ns / 1e9 if wall_ns > 0 else None
-                    ),
-                    slo_class=(
-                        "interactive" if slo_i == 1 else "batch"
-                    ),
-                    tenant=(str(tenant_h) if tenant_h else ""),
-                    route=_ROUTE_NAMES.get(route_code,
-                                           "least_outstanding"),
+        # intake and reply are the loop's own host work around the
+        # scheduler's step: leaves on the profiler's clock, like the
+        # scheduler's sched.* phases (observability/events.py
+        # LEAF_ANNOTATIONS), so a replica's idle time between two
+        # steps has a name
+        with events.leaf("sched.intake"):
+            _adopt_weights()
+            if fault_sleep_s:
+                time.sleep(fault_sleep_s)  # injected SLO straggler
+            # admit everything queued on the ring (token-level admission
+            # happens inside the scheduler)
+            while True:
+                msg = req_ring.try_get()
+                if msg is None:
+                    break
+                (req_id, plen, max_new, seed, ring_ver, wall_ns,
+                 slo_i, tenant_h, ship_mode, ship_slot, first_tok,
+                 n_ship, route_code, resume_len) = (
+                    int(v) for v in msg["meta"]
                 )
-                if resume_len > 0:
-                    # a drained replica's hand-back: the tail rides
-                    # the prompt buffer past the prompt; re-prefill
-                    # reuses every cached [prompt|tail] block
-                    kwargs["resume_tokens"] = msg["prompt"][
-                        plen:plen + resume_len
-                    ]
-                    kwargs["resume_logprobs"] = msg["resume_lp"][
-                        :resume_len
-                    ]
-                if ship_mode == 1:
-                    # prefill-and-ship: remember which arena slot the
-                    # dispatcher reserved; the blocks stage there when
-                    # the prefill completes
-                    pending_ship[req_id] = ship_slot
-                elif ship_mode == 2:
-                    k_r, v_r = _read_shipped(ship_slot, n_ship)
-                    kwargs["shipped"] = {
-                        "k": k_r,
-                        "v": v_r,
-                        "first_token": first_tok,
-                    }
-                scheduler.submit(msg["prompt"][:plen], **kwargs)
-            except ValueError as e:
-                # belt-and-suspenders (the dispatcher validates at
-                # its own submit): a malformed ring message must not
-                # kill the replica — a dead replica cascades the
-                # request onto the survivors — and must be ANSWERED,
-                # or the caller blocks for the full request timeout
-                logger.error(
-                    "replica %s rejected request %d: %s",
-                    tag, req_id, e,
-                )
-                pending_ship.pop(req_id, None)
-                _respond(_KIND_REJECT, req_id=req_id)
+                if ring_ver != RING_SCHEMA_VERSION:
+                    raise RingSchemaMismatch(ring_ver, "dispatch request")
+                try:
+                    kwargs = dict(
+                        max_new=max_new,
+                        seed=seed,
+                        req_id=req_id,
+                        submit_wall=(
+                            wall_ns / 1e9 if wall_ns > 0 else None
+                        ),
+                        slo_class=(
+                            "interactive" if slo_i == 1 else "batch"
+                        ),
+                        tenant=(str(tenant_h) if tenant_h else ""),
+                        route=_ROUTE_NAMES.get(route_code,
+                                               "least_outstanding"),
+                    )
+                    if resume_len > 0:
+                        # a drained replica's hand-back: the tail rides
+                        # the prompt buffer past the prompt; re-prefill
+                        # reuses every cached [prompt|tail] block
+                        kwargs["resume_tokens"] = msg["prompt"][
+                            plen:plen + resume_len
+                        ]
+                        kwargs["resume_logprobs"] = msg["resume_lp"][
+                            :resume_len
+                        ]
+                    if ship_mode == 1:
+                        # prefill-and-ship: remember which arena slot the
+                        # dispatcher reserved; the blocks stage there when
+                        # the prefill completes
+                        pending_ship[req_id] = ship_slot
+                    elif ship_mode == 2:
+                        k_r, v_r = _read_shipped(ship_slot, n_ship)
+                        kwargs["shipped"] = {
+                            "k": k_r,
+                            "v": v_r,
+                            "first_token": first_tok,
+                        }
+                    scheduler.submit(msg["prompt"][:plen], **kwargs)
+                except ValueError as e:
+                    # belt-and-suspenders (the dispatcher validates at
+                    # its own submit): a malformed ring message must not
+                    # kill the replica — a dead replica cascades the
+                    # request onto the survivors — and must be ANSWERED,
+                    # or the caller blocks for the full request timeout
+                    logger.error(
+                        "replica %s rejected request %d: %s",
+                        tag, req_id, e,
+                    )
+                    pending_ship.pop(req_id, None)
+                    _respond(_KIND_REJECT, req_id=req_id)
         if scheduler.idle:
             time.sleep(0.002)
             continue
-        for res in scheduler.step():
-            served += 1
-            window_tokens += res.new_tokens
-            _flush_result(res)
-        if scheduler.shipped:
-            # prefill worker: stage each completed prefill's KV
-            # blocks in its reserved arena slot and hand the manifest
-            # to the dispatcher; the decode replica splices them in
-            for rec in scheduler.shipped:
-                slot = pending_ship.pop(rec["req_id"], -1)
-                if slot < 0:
-                    continue  # locally-submitted on a prefill role
-                t0 = time.perf_counter()
-                k_b = rec["k"].tobytes()
-                v_b = rec["v"].tobytes()
-                buf = _ship_buf()
-                base = slot * ship_slot_bytes
-                buf[base:base + len(k_b)] = k_b
-                half = base + ship_slot_bytes // 2
-                buf[half:half + len(v_b)] = v_b
-                ship_s = max(time.perf_counter() - t0, 1e-9)
-                nbytes = len(k_b) + len(v_b)
-                events.complete(
-                    "kv_ship",
-                    time.time() - ship_s,
-                    ship_s,
-                    blocks=int(rec["n_blocks"]),
-                    bytes=nbytes,
-                    throughput_gbps=round(nbytes / ship_s / 1e9, 3),
-                )
-                from dlrover_tpu.observability.metrics import (
-                    get_registry,
-                )
+        finished = scheduler.step()
+        with events.leaf("sched.reply"):
+            for res in finished:
+                served += 1
+                window_tokens += res.new_tokens
+                _flush_result(res)
+            if scheduler.shipped:
+                # prefill worker: stage each completed prefill's KV
+                # blocks in its reserved arena slot and hand the manifest
+                # to the dispatcher; the decode replica splices them in
+                for rec in scheduler.shipped:
+                    slot = pending_ship.pop(rec["req_id"], -1)
+                    if slot < 0:
+                        continue  # locally-submitted on a prefill role
+                    t0 = time.perf_counter()
+                    k_b = rec["k"].tobytes()
+                    v_b = rec["v"].tobytes()
+                    buf = _ship_buf()
+                    base = slot * ship_slot_bytes
+                    buf[base:base + len(k_b)] = k_b
+                    half = base + ship_slot_bytes // 2
+                    buf[half:half + len(v_b)] = v_b
+                    ship_s = max(time.perf_counter() - t0, 1e-9)
+                    nbytes = len(k_b) + len(v_b)
+                    events.complete(
+                        "kv_ship",
+                        time.time() - ship_s,
+                        ship_s,
+                        blocks=int(rec["n_blocks"]),
+                        bytes=nbytes,
+                        throughput_gbps=round(nbytes / ship_s / 1e9, 3),
+                    )
+                    from dlrover_tpu.observability.metrics import (
+                        get_registry,
+                    )
 
-                get_registry().inc_counter(
-                    "dlrover_tpu_serving_kv_shipped_blocks_total",
-                    int(rec["n_blocks"]),
-                    labels={"replica": tag},
+                    get_registry().inc_counter(
+                        "dlrover_tpu_serving_kv_shipped_blocks_total",
+                        int(rec["n_blocks"]),
+                        labels={"replica": tag},
+                    )
+                    window_tokens += rec["prompt_len"]
+                    _respond(
+                        _KIND_SHIP,
+                        req_id=rec["req_id"],
+                        tokens=np.asarray(
+                            [rec["first_token"]], np.int32
+                        ),
+                        ship_slot=slot,
+                        n_blocks=int(rec["n_blocks"]),
+                    )
+                scheduler.shipped.clear()
+            now = time.monotonic()
+            if now - window_t0 >= 1.0:
+                tps = window_tokens / (now - window_t0)
+                st = scheduler.stats()
+                record_serving(
+                    replica=tag,
+                    tokens_per_s=tps,
+                    queue_depth=scheduler.queue_depth,
+                    kv_blocks_used=scheduler.block_pool.used_blocks,
+                    kv_utilization=st["kv_utilization"],
+                    preemptions=st["preemptions"],
+                    prefix_hit_rate=st["prefix_hit_rate"],
+                    accepted_tokens_per_step=st["accepted_per_step"],
                 )
-                window_tokens += rec["prompt_len"]
+                # the dispatcher-side serving pane reads the same numbers
+                # off the response ring (best-effort); with the fleet
+                # layer on, the replica's shared-block key index and its
+                # cumulative prefix counters ride along — the affinity
+                # router's whole view, no extra RPC
+                stats_tokens = None
+                if fleet:
+                    digs = [
+                        _key_digest(k)
+                        for k in list(
+                            scheduler.block_pool._shared_by_key
+                        )[-(max_total - 1):]
+                    ]
+                    stats_tokens = np.asarray(
+                        [len(digs)] + digs, np.int32
+                    )
                 _respond(
-                    _KIND_SHIP,
-                    req_id=rec["req_id"],
-                    tokens=np.asarray(
-                        [rec["first_token"]], np.int32
+                    _KIND_STATS,
+                    tokens=stats_tokens,
+                    times=(
+                        tps,
+                        float(scheduler.queue_depth),
+                        float(scheduler.block_pool.used_blocks),
+                        float(st["kv_utilization"]),
+                        float(st["preemptions"]),
+                        float(st["prefix_hit_rate"]),
+                        float(st["accepted_per_step"]),
+                        (
+                            ttft_hist.quantile(0.99)
+                            if ttft_hist is not None else 0.0
+                        ),
+                        float(scheduler.block_pool.prefix_hits),
+                        float(scheduler.block_pool.prefix_queries),
+                        float(adoptions),
+                        float(meta_rpcs),
                     ),
-                    ship_slot=slot,
-                    n_blocks=int(rec["n_blocks"]),
                 )
-            scheduler.shipped.clear()
-        now = time.monotonic()
-        if now - window_t0 >= 1.0:
-            tps = window_tokens / (now - window_t0)
-            st = scheduler.stats()
-            record_serving(
-                replica=tag,
-                tokens_per_s=tps,
-                queue_depth=scheduler.queue_depth,
-                kv_blocks_used=scheduler.block_pool.used_blocks,
-                kv_utilization=st["kv_utilization"],
-                preemptions=st["preemptions"],
-                prefix_hit_rate=st["prefix_hit_rate"],
-                accepted_tokens_per_step=st["accepted_per_step"],
-            )
-            # the dispatcher-side serving pane reads the same numbers
-            # off the response ring (best-effort); with the fleet
-            # layer on, the replica's shared-block key index and its
-            # cumulative prefix counters ride along — the affinity
-            # router's whole view, no extra RPC
-            stats_tokens = None
-            if fleet:
-                digs = [
-                    _key_digest(k)
-                    for k in list(
-                        scheduler.block_pool._shared_by_key
-                    )[-(max_total - 1):]
-                ]
-                stats_tokens = np.asarray(
-                    [len(digs)] + digs, np.int32
-                )
-            _respond(
-                _KIND_STATS,
-                tokens=stats_tokens,
-                times=(
-                    tps,
-                    float(scheduler.queue_depth),
-                    float(scheduler.block_pool.used_blocks),
-                    float(st["kv_utilization"]),
-                    float(st["preemptions"]),
-                    float(st["prefix_hit_rate"]),
-                    float(st["accepted_per_step"]),
-                    (
-                        ttft_hist.quantile(0.99)
-                        if ttft_hist is not None else 0.0
-                    ),
-                    float(scheduler.block_pool.prefix_hits),
-                    float(scheduler.block_pool.prefix_queries),
-                    float(adoptions),
-                    float(meta_rpcs),
-                ),
-            )
-            window_tokens = 0
-            window_t0 = now
+                window_tokens = 0
+                window_t0 = now
 
     # drain: stop admitting, flush what finishes inside the grace
     # window (their compute is not thrown away), then hand the rest

@@ -76,6 +76,7 @@ from dlrover_tpu.common.env import (
     serve_obs_enabled,
 )
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.observability.events import EventLogger
 from dlrover_tpu.rl.kv_cache import (
     BlockPool,
     OutOfBlocksError,
@@ -92,6 +93,29 @@ SLO_BATCH = "batch"
 
 FINISH_EOS = "eos"
 FINISH_LENGTH = "length"
+
+
+class _HostPhase:
+    """One leaf phase of an iteration's host time: a profiler
+    annotation for its duration (``EventLogger.leaf`` — on the device
+    trace's clock when a ``jax.profiler`` window is open, a no-op
+    otherwise) and a running sum that ``step`` writes onto the
+    iteration's ``serve_step`` record.  Phases never nest."""
+
+    __slots__ = ("_leaf", "total_s", "_t0", "_ann")
+
+    def __init__(self, leaf: Callable):
+        self._leaf = leaf
+        self.total_s = 0.0
+
+    def __enter__(self):
+        self._ann = self._leaf()
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+
+    def __exit__(self, *exc):
+        self.total_s += time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
 
 
 def _empty_tokens() -> np.ndarray:
@@ -245,6 +269,24 @@ class ContinuousBatchingScheduler:
         self._serve_obs = serve_obs_enabled()
         self.replica = replica
         self._last_prefill_req = -1
+        # the partition of each iteration's host time (ISSUE 24): what
+        # the host does around the compiled programs, by leaf phase.
+        # ``wait`` is the blocking readbacks — the device is busy, the
+        # host is not the cause; what no phase covers is ``other``.
+        self._ph_admit = _HostPhase(
+            lambda: EventLogger.leaf("sched.admit")
+        )
+        self._ph_dispatch = _HostPhase(
+            lambda: EventLogger.leaf("sched.dispatch")
+        )
+        self._ph_wait = _HostPhase(
+            lambda: EventLogger.leaf("sched.wait")
+        )
+        self._ph_commit = _HostPhase(
+            lambda: EventLogger.leaf("sched.commit")
+        )
+        self._lanes_decode = 0
+        self._lanes_prefill = 0
         self._params = None
         self._decode_model = paged_decode_fn or partial(
             llama.paged_decode_step, cfg=model_cfg
@@ -1375,6 +1417,7 @@ class ContinuousBatchingScheduler:
         ]
         if not slots:
             return 0
+        self._lanes_prefill = len(slots)
         slot = slots[self._prefill_rr % len(slots)]
         self._prefill_rr += 1
         sl = self._slots[slot]
@@ -1382,49 +1425,57 @@ class ContinuousBatchingScheduler:
         self._last_prefill_req = req.req_id
         plen = sl.prefill_len
         start = sl.prefill_pos
-        chunk = sl.prefill_tokens[start:start + s.prefill_chunk]
-        real = chunk.size
-        if real < s.prefill_chunk:
-            chunk = np.pad(chunk, (0, s.prefill_chunk - real))
         jnp = self._jnp
-        self._pool, logits = self._prefill_jit(
-            self._params,
-            self._pool,
-            jnp.asarray(chunk[None], jnp.int32),
-            jnp.asarray(self._tables[slot]),
-            jnp.int32(start),
-        )
-        self.dispatches += 1
-        if self.draft and self._draft_params is not None:
-            # mirror the chunk into the DRAFT pool (same table/blocks,
-            # draft shapes) so the drafter decodes over a real prompt
-            # cache; a drafter adopted mid-prefill just drafts worse
-            # until the next prompt — emission never depends on it
-            self._draft_pool, _ = self._draft_prefill_jit(
-                self._draft_params,
-                self._draft_pool,
+        with self._ph_dispatch:
+            chunk = sl.prefill_tokens[start:start + s.prefill_chunk]
+            real = chunk.size
+            if real < s.prefill_chunk:
+                chunk = np.pad(chunk, (0, s.prefill_chunk - real))
+            self._pool, logits = self._prefill_jit(
+                self._params,
+                self._pool,
                 jnp.asarray(chunk[None], jnp.int32),
                 jnp.asarray(self._tables[slot]),
                 jnp.int32(start),
             )
             self.dispatches += 1
-        sl.prefill_pos += real
-        self.total_prefill_tokens += real
-        self.block_pool.note_filled(req.req_id, sl.prefill_pos)
-        self._share_filled_blocks(slot)
-        if sl.prefill_pos >= plen:
-            # sample the first new token from the last REAL prefill
-            # position's logits (it lives inside this chunk)
-            first_lp = None
+            if self.draft and self._draft_params is not None:
+                # mirror the chunk into the DRAFT pool (same
+                # table/blocks, draft shapes) so the drafter decodes
+                # over a real prompt cache; a drafter adopted
+                # mid-prefill just drafts worse until the next prompt
+                # — emission never depends on it
+                self._draft_pool, _ = self._draft_prefill_jit(
+                    self._draft_params,
+                    self._draft_pool,
+                    jnp.asarray(chunk[None], jnp.int32),
+                    jnp.asarray(self._tables[slot]),
+                    jnp.int32(start),
+                )
+                self.dispatches += 1
+        with self._ph_commit:
+            sl.prefill_pos += real
+            self.total_prefill_tokens += real
+            self.block_pool.note_filled(req.req_id, sl.prefill_pos)
+            self._share_filled_blocks(slot)
+        if sl.prefill_pos < plen:
+            return real
+        # sample the first new token from the last REAL prefill
+        # position's logits (it lives inside this chunk)
+        first_lp = None
+        with self._ph_dispatch:
             tok = self._sample_jit(
                 logits[0, plen - 1 - start],
                 jnp.asarray(self._keys[slot]),
                 jnp.int32(plen),
             )
+            self.dispatches += 1
+        with self._ph_wait:
             if self.capture_logprobs:
                 tok, first_lp = tok
                 first_lp = float(first_lp)
-            self.dispatches += 1
+            tok = int(tok)
+        with self._ph_commit:
             if self.role == "prefill":
                 # disaggregated split: the first token is sampled HERE
                 # (same (seed, position) rule as a local prefill, so
@@ -1472,36 +1523,40 @@ class ContinuousBatchingScheduler:
         ]
         if not decoding:
             return 0
+        self._lanes_decode = len(decoding)
         jnp = self._jnp
-        out = self._decode_jit(
-            self._params,
-            self._pool,
-            jnp.asarray(self._next_token),
-            jnp.asarray(self._tables),
-            jnp.asarray(self._positions),
-            jnp.asarray(self._active),
-            jnp.asarray(self._keys),
-        )
-        if self.capture_logprobs:
-            self._pool, nxt, lps = out
-            lps = np.asarray(lps)
-        else:
-            self._pool, nxt = out
-            lps = None
-        self.dispatches += 1
-        nxt = np.asarray(nxt)
-        sampled = 0
-        for slot in decoding:
-            self._positions[slot] += 1
-            self.block_pool.note_filled(
-                self._slots[slot].req.req_id,
-                int(self._positions[slot]),
+        with self._ph_dispatch:
+            out = self._decode_jit(
+                self._params,
+                self._pool,
+                jnp.asarray(self._next_token),
+                jnp.asarray(self._tables),
+                jnp.asarray(self._positions),
+                jnp.asarray(self._active),
+                jnp.asarray(self._keys),
             )
-            tok = int(nxt[slot])
-            sampled += 1
-            lp = float(lps[slot]) if lps is not None else None
-            if not self._append_token(slot, tok, finished, lp=lp):
-                self._next_token[slot] = tok
+            self.dispatches += 1
+        with self._ph_wait:
+            if self.capture_logprobs:
+                self._pool, nxt, lps = out
+                lps = np.asarray(lps)
+            else:
+                self._pool, nxt = out
+                lps = None
+            nxt = np.asarray(nxt)
+        sampled = 0
+        with self._ph_commit:
+            for slot in decoding:
+                self._positions[slot] += 1
+                self.block_pool.note_filled(
+                    self._slots[slot].req.req_id,
+                    int(self._positions[slot]),
+                )
+                tok = int(nxt[slot])
+                sampled += 1
+                lp = float(lps[slot]) if lps is not None else None
+                if not self._append_token(slot, tok, finished, lp=lp):
+                    self._next_token[slot] = tok
         return sampled
 
     def _decode_multi_once(self, finished: List[GenResult]) -> int:
@@ -1514,6 +1569,7 @@ class ContinuousBatchingScheduler:
         ]
         if not decoding:
             return 0
+        self._lanes_decode = len(decoding)
         K = self.decode_k
         temp = float(self.sched.temperature)
         jnp = self._jnp
@@ -1523,95 +1579,92 @@ class ContinuousBatchingScheduler:
             and self._draft_params is not None
         )
         lp_drafts = lp_ver = None
-        if draft_mode:
-            (self._pool, self._draft_pool, drafts, ver, n_match,
-             lp_ver) = self._decode_multi_draft_jit(
-                self._params,
-                self._draft_params,
-                self._pool,
-                self._draft_pool,
+        with self._ph_dispatch:
+            lanes = (
                 jnp.asarray(self._next_token),
                 jnp.asarray(self._tables),
                 jnp.asarray(self._positions),
                 jnp.asarray(self._active),
                 jnp.asarray(self._keys),
             )
-            lp_ver = np.asarray(lp_ver)
-        elif self.capture_logprobs:
-            (self._pool, drafts, ver, n_match, lp_drafts,
-             lp_ver) = self._decode_multi_jit(
-                self._params,
-                self._pool,
-                jnp.asarray(self._next_token),
-                jnp.asarray(self._tables),
-                jnp.asarray(self._positions),
-                jnp.asarray(self._active),
-                jnp.asarray(self._keys),
-            )
-            lp_drafts = np.asarray(lp_drafts)
-            lp_ver = np.asarray(lp_ver)
-        else:
-            self._pool, drafts, ver, n_match = self._decode_multi_jit(
-                self._params,
-                self._pool,
-                jnp.asarray(self._next_token),
-                jnp.asarray(self._tables),
-                jnp.asarray(self._positions),
-                jnp.asarray(self._active),
-                jnp.asarray(self._keys),
-            )
-        self.dispatches += 1
-        drafts = np.asarray(drafts)
-        ver = np.asarray(ver)
-        n_match = np.asarray(n_match)
-        sampled = 0
-        for slot in decoding:
-            sl = self._slots[slot]
-            remaining = sl.req.max_new - len(sl.generated)
             if draft_mode:
-                # separate drafter: ``ver`` is the policy's true
-                # conditioned stream at EVERY temperature (at temp 0
-                # it's the policy argmax); drafts only bound how far
-                # the window stays conditioned on matched prefixes
-                acc = min(int(n_match[slot]) + 1, K)
-                emitted = ver[slot]
-                emitted_lp = lp_ver
-            elif temp <= 0:
-                # drafts ARE the K=1 greedy stream (each draft step
-                # is the K=1 computation); the verify pass gates how
-                # far we trust the window, never what we emit
-                acc = max(1, int(n_match[slot]))
-                emitted = drafts[slot]
-                emitted_lp = lp_drafts
+                (self._pool, self._draft_pool, drafts, ver, n_match,
+                 lp_ver) = self._decode_multi_draft_jit(
+                    self._params,
+                    self._draft_params,
+                    self._pool,
+                    self._draft_pool,
+                    *lanes,
+                )
+            elif self.capture_logprobs:
+                (self._pool, drafts, ver, n_match, lp_drafts,
+                 lp_ver) = self._decode_multi_jit(
+                    self._params, self._pool, *lanes
+                )
             else:
-                # rejection-style: every emitted token is the
-                # real-rule sample conditioned on a prefix that
-                # matched the drafts it was scored against
-                acc = min(int(n_match[slot]) + 1, K)
-                emitted = ver[slot]
-                emitted_lp = lp_ver
-            acc = min(acc, remaining, K)
-            self.lane_windows += 1
-            kept_last = None
-            done = False
-            for j in range(acc):
-                tok = int(emitted[j])
-                self._positions[slot] += 1
-                self.block_pool.note_filled(
-                    sl.req.req_id, int(self._positions[slot])
+                self._pool, drafts, ver, n_match = (
+                    self._decode_multi_jit(
+                        self._params, self._pool, *lanes
+                    )
                 )
-                sampled += 1
-                self.accepted_tokens += 1
-                kept_last = tok
-                lp = (
-                    float(emitted_lp[slot, j])
-                    if emitted_lp is not None else None
-                )
-                if self._append_token(slot, tok, finished, lp=lp):
-                    done = True
-                    break
-            if not done and kept_last is not None:
-                self._next_token[slot] = kept_last
+            self.dispatches += 1
+        with self._ph_wait:
+            if lp_drafts is not None:
+                lp_drafts = np.asarray(lp_drafts)
+            if lp_ver is not None:
+                lp_ver = np.asarray(lp_ver)
+            drafts = np.asarray(drafts)
+            ver = np.asarray(ver)
+            n_match = np.asarray(n_match)
+        sampled = 0
+        with self._ph_commit:
+            for slot in decoding:
+                sl = self._slots[slot]
+                remaining = sl.req.max_new - len(sl.generated)
+                if draft_mode:
+                    # separate drafter: ``ver`` is the policy's true
+                    # conditioned stream at EVERY temperature (at temp 0
+                    # it's the policy argmax); drafts only bound how far
+                    # the window stays conditioned on matched prefixes
+                    acc = min(int(n_match[slot]) + 1, K)
+                    emitted = ver[slot]
+                    emitted_lp = lp_ver
+                elif temp <= 0:
+                    # drafts ARE the K=1 greedy stream (each draft step
+                    # is the K=1 computation); the verify pass gates how
+                    # far we trust the window, never what we emit
+                    acc = max(1, int(n_match[slot]))
+                    emitted = drafts[slot]
+                    emitted_lp = lp_drafts
+                else:
+                    # rejection-style: every emitted token is the
+                    # real-rule sample conditioned on a prefix that
+                    # matched the drafts it was scored against
+                    acc = min(int(n_match[slot]) + 1, K)
+                    emitted = ver[slot]
+                    emitted_lp = lp_ver
+                acc = min(acc, remaining, K)
+                self.lane_windows += 1
+                kept_last = None
+                done = False
+                for j in range(acc):
+                    tok = int(emitted[j])
+                    self._positions[slot] += 1
+                    self.block_pool.note_filled(
+                        sl.req.req_id, int(self._positions[slot])
+                    )
+                    sampled += 1
+                    self.accepted_tokens += 1
+                    kept_last = tok
+                    lp = (
+                        float(emitted_lp[slot, j])
+                        if emitted_lp is not None else None
+                    )
+                    if self._append_token(slot, tok, finished, lp=lp):
+                        done = True
+                        break
+                if not done and kept_last is not None:
+                    self._next_token[slot] = kept_last
         if self._events is not None and self._events.enabled:
             from dlrover_tpu.observability.events import anchored_now
 
@@ -1636,25 +1689,36 @@ class ContinuousBatchingScheduler:
             )
         t0 = time.monotonic()
         emit = self._events is not None and self._events.enabled
+        phases = (
+            self._ph_admit, self._ph_dispatch, self._ph_wait,
+            self._ph_commit,
+        )
+        for ph in phases:
+            ph.total_s = 0.0
+        self._lanes_decode = self._lanes_prefill = 0
         finished: List[GenResult] = []
         if self._adopt_finished:
             finished.extend(self._adopt_finished)
             self._adopt_finished.clear()
-        self._admit(finished)
+        with self._ph_admit:
+            self._admit(finished)
         pre_t0 = time.monotonic()
         hit_blocks = self._window_hit_blocks
         self._window_hit_blocks = 0
         pre = self._prefill_one(finished)
         pre_t1 = time.monotonic()
-        self._admit(finished)  # a first-token EOS may have freed a slot
-        self._ensure_blocks()
+        with self._ph_admit:
+            # a first-token EOS may have freed a slot
+            self._admit(finished)
+            self._ensure_blocks()
         dec_t0 = time.monotonic()
         if self._decode_multi_jit is not None:
             dec = self._decode_multi_once(finished)
         else:
             dec = self._decode_once(finished)
         dec_t1 = time.monotonic()
-        self._admit(finished)
+        with self._ph_admit:
+            self._admit(finished)
         self.iterations += 1
         if emit and (pre or dec):
             from dlrover_tpu.observability.events import anchored_now
@@ -1683,6 +1747,25 @@ class ContinuousBatchingScheduler:
                     new_tokens=dec,
                 )
             dur = max(time.monotonic() - t0, 1e-9)
+            if not self._serve_obs:
+                # SERVE_OBS=0 keeps the PR-14 record byte-for-byte
+                self._events.complete(
+                    "serve_step",
+                    anchored_now(t0),
+                    dur,
+                    tokens=pre,
+                    new_tokens=dec,
+                    throughput_tps=round((pre + dec) / dur, 2),
+                )
+                return finished
+            # the iteration's host time by leaf phase, in ms: the five
+            # sum to ``dur`` (``other`` is what no phase covers — the
+            # list scans, the two records above); the lane counts give
+            # batch occupancy.  Labels on the ONE record an iteration
+            # already writes, not lines of their own.
+            admit, dispatch, wait, commit = (
+                1e3 * ph.total_s for ph in phases
+            )
             self._events.complete(
                 "serve_step",
                 anchored_now(t0),
@@ -1690,6 +1773,16 @@ class ContinuousBatchingScheduler:
                 tokens=pre,
                 new_tokens=dec,
                 throughput_tps=round((pre + dec) / dur, 2),
+                admit_ms=round(admit, 4),
+                dispatch_ms=round(dispatch, 4),
+                wait_ms=round(wait, 4),
+                commit_ms=round(commit, 4),
+                other_ms=round(
+                    1e3 * dur - admit - dispatch - wait - commit, 4
+                ),
+                lanes_decode=self._lanes_decode,
+                lanes_prefill=self._lanes_prefill,
+                slots=self.sched.max_slots,
             )
         return finished
 

@@ -79,8 +79,9 @@ class ElasticTrainer:
         return self._client
 
     def step_done(self, steps: int = 1):
-        """Advance the global step; rank 0 reports progress."""
-        self.global_step += steps
+        """Advance the global step and close a ``step`` span back to
+        the previous call; rank 0 reports progress."""
+        self.advance(steps)
         if self._events.enabled:
             now_m = time.monotonic()
             now_w = anchored_now(now_m)
@@ -90,6 +91,14 @@ class ElasticTrainer:
                     "step", now_w - dur, dur, step=self.global_step
                 )
             self._step_mark = (now_w, now_m)
+
+    def advance(self, steps: int = 1):
+        """Advance the global step; rank 0 reports progress.  No
+        ``step`` span: for a loop that times its own steps
+        (``trainer/trainer.py`` — completion to completion, where the
+        loss reaches the host; a call here, right after the dispatch,
+        would time dispatch to dispatch)."""
+        self.global_step += steps
         if self.rank != 0:
             return
         now = time.time()

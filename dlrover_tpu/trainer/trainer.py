@@ -22,6 +22,10 @@ from typing import Callable, Iterable, Optional
 import jax
 
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.observability.events import (
+    anchored_now,
+    get_event_logger,
+)
 from dlrover_tpu.trainer.elastic.context import (
     init_distributed,
 )
@@ -31,6 +35,13 @@ from dlrover_tpu.trainer.fault_tolerance import (
     LossSpikeCapture,
     default_hang_action,
 )
+
+
+def _batch_tokens(batch) -> int:
+    """Elements of the batch's first leaf: batch x sequence for a
+    batch of token ids."""
+    leaves = jax.tree_util.tree_leaves(batch)
+    return int(leaves[0].size) if leaves else 0
 
 
 @dataclass
@@ -205,6 +216,11 @@ class Trainer:
         self._snapshot_mode = (
             None if args.snapshot_mode == "auto" else args.snapshot_mode
         )
+        # timeline: one ``step`` span per completed step (from
+        # _consume_metrics) and one ``snapshot_pull`` per snapshot's
+        # synchronous leg; both are no-ops without an events file
+        self._events = get_event_logger()
+        self._step_span_from = None  # perf_counter of the last step done
         # live attribution profiler (observability/attribution.py):
         # the continuous leg traces ONE step every
         # DLROVER_TPU_PROFILE_EVERY_N_STEPS (default 0 = off, zero
@@ -380,19 +396,48 @@ class Trainer:
         if self._snapshot_mode is None:
             self._snapshot_mode = self._resolve_snapshot_mode()
             logger.info("snapshot mode: %s", self._snapshot_mode)
+        # the SYNCHRONOUS leg, on the training thread: named from
+        # inside (a ``snapshot_pull`` span, and the same name on an
+        # open profiler trace) — the asynchronous drain that follows
+        # is the engine's ``checkpoint_save``
         if self._snapshot_mode == "staged":
-            # bounded memory: state is already on host, the engine
-            # drain is a pure shm memcpy
-            snap = self._staged_device_get(self.state)
-        else:
-            # snapshot an on-device COPY (cheap HBM->HBM) so the async
-            # device->host drain can proceed while subsequent train
-            # steps donate and overwrite self.state's buffers
-            if self._snap_fn is None:
-                self._snap_fn = jax.jit(
-                    lambda s: jax.tree_util.tree_map(jax.numpy.copy, s)
-                )
-            snap = self._snap_fn(self.state)
+            # the pull blocks on the step just dispatched in any case:
+            # wait for it HERE, so that the span — which the ledger
+            # charges as loss — holds the transfer alone and the step
+            # keeps its own compute time
+            jax.block_until_ready(self.state)
+        pull_t0 = time.monotonic()
+        with self._events.leaf("snapshot_pull"):
+            if self._snapshot_mode == "staged":
+                # bounded memory: state is already on host, the engine
+                # drain is a pure shm memcpy
+                snap = self._staged_device_get(self.state)
+            else:
+                # snapshot an on-device COPY (cheap HBM->HBM) so the
+                # async device->host drain can proceed while subsequent
+                # train steps donate and overwrite self.state's buffers
+                if self._snap_fn is None:
+                    self._snap_fn = jax.jit(
+                        lambda s: jax.tree_util.tree_map(
+                            jax.numpy.copy, s
+                        )
+                    )
+                snap = self._snap_fn(self.state)
+        if self._events.enabled:
+            pull_s = max(time.monotonic() - pull_t0, 1e-9)
+            nbytes = sum(
+                int(leaf.nbytes)
+                for leaf in jax.tree_util.tree_leaves(snap)
+            )
+            self._events.complete(
+                "snapshot_pull",
+                anchored_now(pull_t0),
+                pull_s,
+                step=step,
+                bytes=nbytes,
+                throughput_gbps=round(nbytes / pull_s / 1e9, 3),
+                mode=self._snapshot_mode,
+            )
         if to_storage:
             self._engine.save_to_storage(
                 step, snap, blocking=False, layouts=self._layouts
@@ -415,8 +460,23 @@ class Trainer:
     def _consume_metrics(self, step: int, metrics, batch) -> float:
         loss = float(metrics["loss"])  # syncs on step completion
         now = time.perf_counter()
-        dt = now - self._last_done
+        prev_done = self._last_done
+        dt = now - prev_done
         self._last_done = now
+        if self._events.enabled:
+            # step-done to step-done, as the ledger defines ``step``.
+            # The first completion has no step-done before it (its gap
+            # holds the compile), and a gap that a closed trace window
+            # or an eval reset is not one step's: neither is a span.
+            if self._step_span_from == prev_done:
+                self._events.complete(
+                    "step",
+                    anchored_now() - dt,
+                    dt,
+                    step=step,
+                    tokens=_batch_tokens(batch),
+                )
+            self._step_span_from = now
         if self._spikes is not None:
             self._spikes.observe(step, loss, batch)
         record = {"loss": loss, "step_time_s": dt}
@@ -713,10 +773,6 @@ class Trainer:
                         from dlrover_tpu.common.env import (
                             capture_steps,
                         )
-                        from dlrover_tpu.observability.events import (
-                            anchored_now,
-                        )
-
                         if pending is not None:
                             step_times.append(
                                 self._consume_metrics(*pending)
@@ -750,9 +806,14 @@ class Trainer:
                         device_batch = jax.device_put(
                             batch, batch_sharding
                         )
-                    self.state, metrics = self._fns.train_step(
-                        self.state, device_batch
-                    )
+                    # step boundaries on an open profiler trace (the
+                    # dispatch only: the loss is read a step later)
+                    with jax.profiler.StepTraceAnnotation(
+                        "train", step_num=step + 1
+                    ):
+                        self.state, metrics = self._fns.train_step(
+                            self.state, device_batch
+                        )
                     step += 1
                     if (
                         self._replay is not None
@@ -763,7 +824,9 @@ class Trainer:
                         == 0
                     ):
                         self._replay.commit(step, self.state)
-                    self.progress.step_done()
+                    # no span here: _consume_metrics times the step
+                    # where its loss reaches the host
+                    self.progress.advance()
                     self._hang.report_step(step)
                     if pending is not None:
                         step_times.append(

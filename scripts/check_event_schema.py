@@ -13,7 +13,13 @@ every call to an event-logger method (``span`` / ``begin`` / ``end`` /
   vocabulary must be greppable) drawn from the declared sets;
 - the labels ``REQUIRED_SPAN_LABELS`` demands for that phase are
   passed as keyword arguments at span-opening sites (``span`` /
-  ``begin`` / ``complete``).
+  ``begin`` / ``complete``);
+- a phase listed in ``OPTIONAL_SPAN_LABELS`` has a CLOSED label set:
+  its sites may pass the required and the optional labels, no others
+  (``serve_step``'s host-time partition: a typo'd ``admit_ms`` would
+  silently drop out of the metric that reads it);
+- ``leaf()`` — the profiler-only annotation of a per-iteration phase —
+  names a declared leaf (``LEAF_ANNOTATIONS``) or a declared phase.
 
 Usage: ``python scripts/check_event_schema.py [paths...]``
 (default: the package, scripts/, tests/ and bench*.py).  Exit 1 on any
@@ -30,12 +36,16 @@ sys.path.insert(0, REPO)
 
 from dlrover_tpu.observability.events import (  # noqa: E402
     INSTANT_EVENTS,
+    LEAF_ANNOTATIONS,
+    OPTIONAL_SPAN_LABELS,
     PHASES,
     REQUIRED_INSTANT_LABELS,
     REQUIRED_SPAN_LABELS,
 )
 
-EMIT_METHODS = {"span", "begin", "end", "complete", "instant"}
+EMIT_METHODS = {"span", "begin", "end", "complete", "instant", "leaf"}
+#: positional parameters of ``complete()`` that are not labels
+_COMPLETE_PARAMS = {"phase", "start_wall", "duration_s"}
 #: methods that OPEN a span and must carry the phase's required labels
 OPENING_METHODS = {"span", "begin", "complete"}
 
@@ -289,14 +299,17 @@ def check_file(path: str):
                 "literal from the declared schema, not an expression"
             )
             continue
-        declared = (
-            INSTANT_EVENTS if method == "instant" else set(PHASES)
-        )
+        if method == "instant":
+            declared, kind = INSTANT_EVENTS, "instant event"
+        elif method == "leaf":
+            declared = LEAF_ANNOTATIONS | set(PHASES)
+            kind = "leaf annotation or phase"
+        else:
+            declared, kind = set(PHASES), "phase"
         if phase not in declared:
             violations.append(
                 f"{where}: {method}({phase!r}) is not a declared "
-                f"{'instant event' if method == 'instant' else 'phase'}"
-                f" (declared: {sorted(declared)})"
+                f"{kind} (declared: {sorted(declared)})"
             )
             continue
         if method == "instant":
@@ -329,6 +342,19 @@ def check_file(path: str):
                     f"{where}: {method}({phase!r}) missing required "
                     f"label(s) {missing}"
                 )
+            if phase in OPTIONAL_SPAN_LABELS:
+                allowed = (
+                    set(REQUIRED_SPAN_LABELS.get(phase, ()))
+                    | set(OPTIONAL_SPAN_LABELS[phase])
+                    | _COMPLETE_PARAMS
+                )
+                unknown = sorted(kwargs - allowed)
+                if unknown:
+                    violations.append(
+                        f"{where}: {method}({phase!r}) passes "
+                        f"undeclared label(s) {unknown} (the phase's "
+                        "label set is closed: OPTIONAL_SPAN_LABELS)"
+                    )
             # retry-storm visibility: a control_wait span opened as a
             # retry pause must carry the attempt ordinal, or storms
             # collapse into indistinguishable blips on the timeline

@@ -16,6 +16,7 @@ file; compiles run in the test's own process for the same reason.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -152,6 +153,28 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     fn, shapes = CASES[case]()
     text = _compiled_text(fn, *shapes, sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("case,name", [
+    ("paged_decode_kv32", "paged_decode"),
+    ("paged_verify_w4_kv32", "paged_verify"),
+    ("rms_norm_fwd_bwd", "rmsnorm_fwd"),
+])
+def test_serving_kernels_keep_their_names(case, name, one_chip):
+    """A device trace names an operation by its HLO instruction: the
+    serving path's kernels are ``<name>.N`` there (``pallas_utils.
+    named_kernel``), not the ``closed_call.N`` Pallas's own wrapper
+    leaves, so a reduction can pick them out (``^paged_``)."""
+    fn, shapes = CASES[case]()
+    text = _compiled_text(fn, *shapes, sharding=one_chip)
+    calls = [
+        line.strip() for line in text.splitlines()
+        if "custom-call(" in line and "tpu_custom_call" in line
+    ]
+    assert calls
+    for line in calls:
+        assert re.match(rf"(ROOT )?%{name}(\.\d+)* = ", line), line
+    assert "closed_call" not in text
 
 
 @pytest.mark.parametrize("kernel", ["decode", "verify"])
