@@ -615,6 +615,42 @@ def prefill(
 
 # ----------------------------------------------- paged (block-table) decode
 
+# the leaves of ``layers`` that the serving programs below cast
+# (``proj``: ``w.astype(dt)``); the norm scales stay as they are —
+# ``rms_norm`` scales in fp32, and casting them would change the result
+_SERVING_MATMUL_LEAVES = (
+    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"
+)
+
+
+def serving_params(params: Dict, cfg: LlamaConfig) -> Dict:
+    """The tree the serving programs below compute on, made ONCE: every
+    leaf they cast on entry (``embed``, ``lm_head`` and the seven matmul
+    weights of ``layers``) in ``cfg.dtype``, everything else as given.
+    The programs' own ``.astype(dt)`` is then a no-op, so a caller that
+    serves many steps from unchanged weights (``rl/scheduler.py``) pays
+    the cast — at 7B widths more HBM traffic than the step's matmuls —
+    once per adoption, not once per step, with bit-identical results.
+
+    A leaf already in ``cfg.dtype`` is returned as the SAME array: a
+    float32-compute model, or a caller that already holds compute-dtype
+    weights, copies nothing.  Leaves are cast one at a time, so the
+    transient beside the copy is one leaf."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def cast(x):
+        return x if x.dtype == dt else x.astype(dt)
+
+    layers = dict(params["layers"])
+    for name in _SERVING_MATMUL_LEAVES:
+        layers[name] = cast(layers[name])
+    return {
+        **params,
+        "embed": cast(params["embed"]),
+        "layers": layers,
+        "lm_head": cast(params["lm_head"]),
+    }
+
 
 def _apply_rope_rows(x, cos, sin):
     """x: [B, 1, H, D] single position per row; cos/sin [B, D/2]

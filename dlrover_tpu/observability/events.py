@@ -179,6 +179,11 @@ PHASE_KERNEL_AUTOTUNE = "kernel_autotune"
 # zero-copy path is supposed to bound
 PHASE_WEIGHT_PUBLISH = "weight_publish"
 
+# the serving side of an adoption (rl/scheduler.py ``sync_weights``):
+# the scheduler's resident compute-dtype copy of the adopted weights
+# being made — once per adoption, so that no step program casts them
+PHASE_WEIGHT_CAST = "weight_cast"
+
 # one rollout round of the RLHF flywheel: prompts submitted, every
 # trajectory streamed back, the round's staleness verdicts settled
 PHASE_ROLLOUT_ROUND = "rollout_round"
@@ -218,6 +223,7 @@ PHASES: Tuple[str, ...] = (
     PHASE_CONTROL_WAIT,
     PHASE_KERNEL_AUTOTUNE,
     PHASE_WEIGHT_PUBLISH,
+    PHASE_WEIGHT_CAST,
     PHASE_ROLLOUT_ROUND,
     PHASE_TRAJECTORY,
 )
@@ -462,6 +468,11 @@ REQUIRED_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
     # it charged the trainer is unauditable — stall_s vs the step time
     # IS the flywheel's acceptance criterion
     PHASE_WEIGHT_PUBLISH: ("generation", "bytes", "stall_s"),
+    # whether the resident copy engaged, and what it holds: bytes of
+    # the adopted trees, bytes of the trees served from, and how many
+    # leaves differ (0: every leaf already had the compute dtype, or
+    # the step programs are not the llama ones — nothing was copied)
+    PHASE_WEIGHT_CAST: ("bytes_in", "bytes_out", "leaves_cast"),
     # the round's scoreboard: how many trajectories came back and how
     # many the staleness policy refused — together they are the
     # on-policy/off-policy budget actually spent
@@ -491,6 +502,9 @@ OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
         "lanes_prefill",
         "slots",
     ),
+    # the published generation, where the caller adopts one (the
+    # replica's first sync, from its own template, has none)
+    PHASE_WEIGHT_CAST: ("generation",),
 }
 
 _NO_ANNOTATION = nullcontext()

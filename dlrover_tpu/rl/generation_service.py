@@ -702,6 +702,12 @@ def _serving_worker_loop(spec) -> int:
     fault = (spec.get("faults") or {}).get(str(replica)) or {}
     fault_sleep_s = float(fault.get("sleep_s", 0.0))
     wedge_after = int(fault.get("wedge_after_tokens", 0))
+    # ``template`` is the weights this replica HOLDS, in the dtype they
+    # are published in (float32 masters): the target every shm adoption
+    # restores onto.  The step programs do not read it — the scheduler
+    # owns a resident copy in the model's compute dtype, made by
+    # ``sync_weights`` once per adoption (the same arrays where the
+    # dtypes already agree).
     if draft_cfg is not None:
         # draft mode: the publish segment carries ONE combined
         # {"policy", "draft"} tree, restored onto a combined template.
@@ -804,13 +810,15 @@ def _serving_worker_loop(spec) -> int:
             template, arrays, to_device=True, copy_host=True
         )
         jax.block_until_ready(template)
+        generation = gen if gen >= 0 else None
         if draft_cfg is not None and isinstance(template, dict) \
                 and "draft" in template:
             scheduler.sync_weights(
-                template["policy"], template["draft"]
+                template["policy"], template["draft"],
+                generation=generation,
             )
         else:
-            scheduler.sync_weights(template)
+            scheduler.sync_weights(template, generation=generation)
         version = step
         adoptions += 1
         del arrays

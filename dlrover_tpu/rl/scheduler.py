@@ -288,6 +288,20 @@ class ContinuousBatchingScheduler:
         self._lanes_decode = 0
         self._lanes_prefill = 0
         self._params = None
+        # which leaves the step programs cast is the llama programs'
+        # rule (``llama.serving_params``); injected programs get the
+        # tree ``sync_weights`` was given
+        self._serving_params = (
+            (lambda params: params)
+            if paged_decode_fn or paged_prefill_fn or paged_verify_fn
+            or verify_write_fn
+            else partial(llama.serving_params, cfg=model_cfg)
+        )
+        self._serving_draft_params = (
+            (lambda params: params)
+            if draft_cfg is None or draft_decode_fn or draft_prefill_fn
+            else partial(llama.serving_params, cfg=draft_cfg)
+        )
         self._decode_model = paged_decode_fn or partial(
             llama.paged_decode_step, cfg=model_cfg
         )
@@ -641,16 +655,60 @@ class ContinuousBatchingScheduler:
         )
 
     # ------------------------------------------------------------- API
-    def sync_weights(self, params, draft_params=None):
-        """Adopt the trainer's / publisher's current params (reference
-        swap; in-flight sequences continue on the new weights — the
-        vLLM-backend weight-refresh semantics).  ``draft_params`` is
-        the co-published DRAFT model (flywheel separate-drafter mode);
+    def sync_weights(self, params, draft_params=None, generation=None):
+        """Adopt the trainer's / publisher's current params (in-flight
+        sequences continue on the new weights — the vLLM-backend
+        weight-refresh semantics).  ``draft_params`` is the
+        co-published DRAFT model (flywheel separate-drafter mode);
         until the first draft publish arrives the scheduler falls back
-        to self-drafting."""
-        self._params = params
+        to self-drafting.
+
+        What is held where: the caller keeps ITS tree (the replica's
+        float32 restore target, the trainer's live state); the
+        scheduler keeps only ``llama.serving_params`` of it — a
+        resident copy in the model's compute dtype, made here once, so
+        that no step program casts weights again.  Where every leaf
+        already has that dtype the "copy" is the caller's arrays (a
+        reference swap, as before), and a scheduler built on injected
+        step programs serves the tree it was given: the cast rule
+        belongs to the llama programs.  The previous copy is dropped
+        BEFORE the new one is made, so an adoption never holds two.
+        One ``weight_cast`` span per call says what happened
+        (``generation``: the published generation, where the caller
+        adopts one)."""
+        t0 = time.monotonic()
+        self._params = None
+        self._params = self._serving_params(params)
+        given, served = [params], [self._params]
         if draft_params is not None:
-            self._draft_params = draft_params
+            self._draft_params = None
+            self._draft_params = self._serving_draft_params(
+                draft_params
+            )
+            given.append(draft_params)
+            served.append(self._draft_params)
+        # the casts are dispatched, not done: an adoption is rare, and
+        # the span should hold the copy it reports
+        self._jax.block_until_ready(served)
+        if self._events is not None and self._events.enabled:
+            from dlrover_tpu.observability.events import anchored_now
+
+            leaves_in = self._jax.tree_util.tree_leaves(given)
+            leaves_out = self._jax.tree_util.tree_leaves(served)
+            self._events.complete(
+                "weight_cast",
+                anchored_now(t0),
+                max(time.monotonic() - t0, 1e-9),
+                bytes_in=sum(int(x.nbytes) for x in leaves_in),
+                bytes_out=sum(int(x.nbytes) for x in leaves_out),
+                leaves_cast=sum(
+                    a is not b for a, b in zip(leaves_in, leaves_out)
+                ),
+                **(
+                    {} if generation is None
+                    else {"generation": int(generation)}
+                ),
+            )
 
     def submit(
         self,
