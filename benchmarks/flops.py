@@ -1,7 +1,10 @@
 """Operations and bytes a configuration needs, from its shapes alone.
 
 Kept with the benchmark so that no PR that claims a gain can move them.
-``cfg`` is a configuration file's dict (Hugging Face key names).
+``cfg`` is a configuration file's dict (Hugging Face key names).  The
+counts (``matmul_params``, ``total_params``, ``train_flops_per_token``)
+are the dense family's (``family_dense.py``); ``mfu_pct`` and
+``peak_for`` serve every family.
 """
 
 
@@ -43,9 +46,12 @@ def train_flops_per_token(cfg, seq):
     return 6 * matmul_params(cfg) + 3 * attn_fwd * cfg["num_hidden_layers"]
 
 
-def mfu_pct(cfg, seq, tokens_per_step, step_s, peak_flops_per_s, chips=1):
+def mfu_pct(flops_per_token, tokens_per_step, step_s, peak_flops_per_s,
+            chips=1):
+    """Model-free: the required FLOPs per token are the configuration's
+    family's count."""
     return (
-        100.0 * train_flops_per_token(cfg, seq) * tokens_per_step
+        100.0 * flops_per_token * tokens_per_step
         / step_s / (peak_flops_per_s * chips)
     )
 

@@ -8,8 +8,9 @@ file, hands them to the runner of the traffic file's ``kind``
 ``BENCHMARK.json`` lists for that cell: end-to-end metrics without a
 trace, per-layer metrics — one ``layer_metrics/<name>.json`` each, naming
 its reader — with one.  A later PR adds a cell, a configuration, a
-traffic mix or a per-layer metric by adding files and ``BENCHMARK.json``
-entries; nothing here names one.
+traffic mix, a per-layer metric or a model family (the module a
+configuration file names under ``family``) by adding files and
+``BENCHMARK.json`` entries; nothing here names one.
 
 The process that calls this never initialises a JAX backend: a parent
 that touched JAX would hold the chip its children need.  The platform
@@ -70,10 +71,14 @@ def load_cell(workload, data_root=REPO):
     require(workload in cells, f"no workload {workload!r} in BENCHMARK.json")
     entry = cells[workload]
     files = os.path.join(data_root, bench["paths"][0])
+    if files not in sys.path:  # a data root's own family modules
+        sys.path.append(files)
     config_entry = next(
         c for c in bench["configs"] if c["name"] == entry["config"]
     )
     config_path = os.path.join(data_root, config_entry["file"])
+    config = load_json(config_path)
+    family(config)  # a configuration that names none fails here, by name
 
     def listed(metric):
         return workload in metric.get("workloads", [workload])
@@ -91,8 +96,9 @@ def load_cell(workload, data_root=REPO):
     ]
     return {
         "name": workload,
+        "files": files,
         "chips": int(entry["chips"]),
-        "config": load_json(config_path),
+        "config": config,
         "config_path": config_path,
         "traffic": load_json(
             os.path.join(files, "traffic", entry["traffic"] + ".json")
@@ -103,20 +109,18 @@ def load_cell(workload, data_root=REPO):
     }
 
 
-def llama_kwargs(cfg, max_seq_len):
-    """Keyword arguments of the program's ``LlamaConfig`` from a
-    configuration file's (Hugging Face) keys; JSON-able."""
-    return dict(
-        vocab_size=cfg["vocab_size"],
-        dim=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        mlp_dim=cfg["intermediate_size"],
-        max_seq_len=max_seq_len,
-        rope_theta=cfg["rope_theta"],
-        norm_eps=cfg["rms_norm_eps"],
+def family(cfg):
+    """The module a configuration file names under ``family``: its
+    architecture's model keywords, worker and serving parts, plain
+    reference and operation counts (``family_dense.py`` says what a
+    family provides).  A configuration that names none is an error,
+    never the dense block by default."""
+    require(
+        isinstance(cfg.get("family"), str),
+        "the configuration file has no key 'family': it names the "
+        "module beside the harness that holds its architecture",
     )
+    return importlib.import_module(cfg["family"])
 
 
 def child_env(expect_platform, chips):
@@ -301,6 +305,7 @@ def run_cell(workload, seed, seconds, trace, expect_platform="tpu",
     try:
         env = child_env(expect_platform, cell["chips"])
         env["DLROVER_TPU_SOCKET_DIR"] = sandbox.socks
+        env["PYTHONPATH"] = cell["files"] + os.pathsep + env["PYTHONPATH"]
         ctx = runner(
             cell, int(seed), float(seconds), bool(trace), expect_platform,
             sandbox, env,

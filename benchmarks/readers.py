@@ -11,6 +11,7 @@ import statistics
 
 import flops
 import metrics as M
+from harness import family
 
 
 def _traffic(ctx):
@@ -49,9 +50,11 @@ def step_mfu(ctx):
         return None
     cell = ctx["cell"]
     peak = flops.peak_for(cell["peaks"], ctx["device_report"]["device_kind"])
+    cfg, seq = cell["config"], _traffic(ctx)["seq"]
     return flops.mfu_pct(
-        cell["config"], _traffic(ctx)["seq"], ctx["tokens_per_step"],
-        step_s, peak["bf16_flops_per_s"], chips=cell["chips"],
+        family(cfg).train_flops_per_token(cfg, seq),
+        ctx["tokens_per_step"], step_s, peak["bf16_flops_per_s"],
+        chips=cell["chips"],
     )
 
 

@@ -1,7 +1,8 @@
 """Child process of a ``rollout`` run, started after the engine has
-closed (the chip is free): the plain float32 reference's per-token
-logprobs of sampled requests' prompt + answer, on the same seeded
-weights, against the logprobs the serving plane captured while sampling.
+closed (the chip is free): the per-token logprobs that the plain
+float32 reference of the configuration's family gives for sampled
+requests' prompt + answer, on the same seeded weights, against the
+logprobs the serving plane captured while sampling.
 
     python reference_check.py <config.json> <seed> <sample.npz> <out.json> <platform>
 
@@ -23,7 +24,7 @@ def main(config_path, seed, sample_path, out_path, platform):
     import jax
     import numpy as np
 
-    import reference
+    import harness
 
     device = jax.devices()[0]
     if device.platform != platform:
@@ -31,9 +32,10 @@ def main(config_path, seed, sample_path, out_path, platform):
     with open(config_path) as f:
         cfg = json.load(f)
     sample = np.load(sample_path)
-    params = reference.seeded_params(cfg, int(seed))
+    fam = harness.family(cfg)
+    params = fam.seeded_params(cfg, int(seed))
     ref = np.asarray(
-        jax.jit(lambda p, t: reference.token_logprobs(p, t, cfg))(
+        jax.jit(lambda p, t: fam.token_logprobs(p, t, cfg))(
             params, sample["tokens"]
         )
     )

@@ -22,7 +22,7 @@ import time
 import numpy as np
 
 import metrics as M
-from harness import BENCH, attach_trace, llama_kwargs, require, tail
+from harness import BENCH, attach_trace, family, require, tail
 
 
 def stratum_length(spec, u):
@@ -239,7 +239,7 @@ def run_rollout(cell, seed, seconds, trace, expect_platform, sandbox, env):
             max_new_tokens=t["max_new"]["max"],
             temperature=t["temperature"],
             factory_kwargs=dict(
-                llama_kwargs(cfg, t["max_seq_len"]),
+                family(cfg).model_kwargs(cfg, t["max_seq_len"]),
                 dtype="bfloat16",
                 bench=dict(
                     config=cfg, seed=seed, run_dir=run_dir,

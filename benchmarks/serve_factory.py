@@ -1,13 +1,15 @@
 """The serving replica's model factory for the ``rollout`` cells — user
 code of the serving plane, named in the engine's spec like any factory.
 
-The model (``forward_fn``, ``cfg``) is the program's own
-``tiny_llama_factory`` at the widths the benchmark gives it.  Two things
-are the benchmark's: the weights — made ON THE DEVICE, in one jitted
-call, from the run's seed, so the parent neither generates 5.8 GB on the
-host nor publishes them through shm — and a side thread that does what
-only the process holding the chip can do: read its memory peak and, in a
-traced run, open a ``jax.profiler`` window when the runner asks.
+The model (``forward_fn``, ``cfg``) is the program's own, through the
+``serving_parts`` of the family module the configuration file names, at
+the widths the benchmark gives it.  Two things are the benchmark's: the
+weights — made ON THE DEVICE, in one jitted call, from the run's seed
+by the family's ``seeded_params``, so the parent neither generates
+5.8 GB on the host nor publishes them through shm — and a side thread
+that does what only the process holding the chip can do: read its
+memory peak and, in a traced run, open a ``jax.profiler`` window when
+the runner asks.
 """
 
 import os
@@ -52,12 +54,11 @@ def _side_thread(run_dir, trace_s):
 
 
 def factory(bench, **model_kwargs):
-    from dlrover_tpu.rl.generation_service import tiny_llama_factory
+    import harness
 
-    import reference
-
-    parts = tiny_llama_factory(**model_kwargs)
-    parts["params_template_fn"] = lambda: reference.seeded_params(
+    fam = harness.family(bench["config"])
+    parts = fam.serving_parts(**model_kwargs)
+    parts["params_template_fn"] = lambda: fam.seeded_params(
         bench["config"], bench["seed"]
     )
     threading.Thread(

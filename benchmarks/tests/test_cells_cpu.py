@@ -38,6 +38,7 @@ def values(line):
 @pytest.mark.parametrize("workload,devices", [
     ("tiny-train", 1),
     ("tiny-train-fsdp2tp2", 4),  # four virtual devices, data files only
+    ("rehearsal-train", 1),  # a family only its configuration names
 ])
 def test_train_kind(workload, devices):
     line = run(workload, trace=0)
@@ -86,13 +87,16 @@ def test_a_resume_outside_the_window_is_a_failed_attempt():
     assert (line["attempted"], line["failed"]) == (1, 1)
 
 
-@pytest.mark.parametrize("trace,names", [
-    (0, {"rollout_tokens_per_s", "setup_s"}),
-    (1, {"engine.overhead_ms", "sched.decode_step_ms",
-         "sched.prefill_share_pct", "sched.tpot_p95_ms"}),
+@pytest.mark.parametrize("workload,trace,names", [
+    ("tiny-rollout", 0, {"rollout_tokens_per_s", "setup_s"}),
+    ("tiny-rollout", 1, {"engine.overhead_ms", "sched.decode_step_ms",
+                         "sched.prefill_share_pct", "sched.tpot_p95_ms"}),
+    # a family only its configuration names, weights of its own seeding:
+    # served and checked through the family, or the logprobs disagree
+    ("rehearsal-rollout", 0, {"rollout_tokens_per_s", "setup_s"}),
 ])
-def test_rollout_kind(trace, names):
-    line = run("tiny-rollout", trace=trace)
+def test_rollout_kind(workload, trace, names):
+    line = run(workload, trace=trace)
     assert line["correct"], line["notes"]
     assert line["failed"] == 0 and line["attempted"] > 10
     assert set(values(line)) == names
@@ -100,6 +104,68 @@ def test_rollout_kind(trace, names):
     assert any("float32 reference" in n for n in line["notes"])
     # serving idle share: absent on the CPU, never estimated
     assert "busy_s" not in line["device"]
+
+
+def test_a_configuration_without_a_family_fails_by_name(tmp_path):
+    with pytest.raises(harness.CellFailed, match="'family'"):
+        harness.family({"hidden_size": 64})
+    # ... and so does its cell, before anything is started
+    with open(os.path.join(TINY, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    with open(os.path.join(TINY, "data", "configs", "tiny.json")) as f:
+        config = json.load(f)
+    del config["family"]
+    bench["paths"] = [os.path.join(TINY, "data")]
+    bench["configs"][0]["file"] = str(tmp_path / "nofamily.json")
+    with open(bench["configs"][0]["file"], "w") as f:
+        json.dump(config, f)
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    with pytest.raises(harness.CellFailed, match="'family'"):
+        harness.run_cell("tiny-train", 1, 1.0, 0, expect_platform="cpu",
+                         data_root=str(tmp_path))
+
+
+LOADS_EVERY_CELL = """
+import json, sys
+sys.path.insert(0, {bench!r})
+import harness
+for root in (harness.REPO, {tiny!r}):
+    with open(root + "/BENCHMARK.json") as f:
+        cells = json.load(f)["workloads"]
+    for w in cells:
+        harness.load_cell(w["name"], root)
+assert "jax" not in sys.modules and "dlrover_tpu" not in sys.modules
+"""
+
+
+def test_loading_a_cell_imports_neither_jax_nor_the_program():
+    """The harness's own process resolves a cell's family: seconds of
+    imports there would be set-up time of every run."""
+    proc = subprocess.run(
+        [sys.executable, "-c", LOADS_EVERY_CELL.format(bench=BENCH, tiny=TINY)],
+        capture_output=True, text=True,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_only_family_modules_name_a_model():
+    """The harness, runners, worker, factory, checks and readers name no
+    model and import nothing of the program's models: the family module
+    a configuration file names does."""
+    import glob
+    import re
+
+    named = re.compile(
+        r"dlrover_tpu\.models|llama|import reference|from reference"
+    )
+    for path in sorted(glob.glob(os.path.join(BENCH, "*.py"))):
+        name = os.path.basename(path)
+        if name.startswith("family_") or name == "reference.py":
+            continue
+        with open(path) as f:
+            hits = [ln for ln in f if named.search(ln)]
+        assert not hits, (name, hits)
 
 
 def test_the_command_line_refuses_to_measure_without_a_tpu():
@@ -119,7 +185,7 @@ def test_every_cell_of_the_benchmark_loads_from_data():
         bench = json.load(f)
     e2e = {m["name"] for m in bench["end_to_end"]}
     for w in bench["workloads"]:
-        cell = harness.load_cell(w["name"])
+        cell = harness.load_cell(w["name"])  # its family resolves
         assert cell["traffic"]["kind"] in harness.RUNNERS
         reported = {m["name"] for m in cell["end_to_end"]}
         assert "setup_s" in reported and len(reported) >= 2

@@ -139,7 +139,11 @@ def test_flops_of_the_two_configurations():
     assert per_token == pytest.approx(6 * 567_279_616 + 3 * 2 * 16_777_216)
     # a step that took exactly the required FLOPs / peak is 100 %
     step_s = per_token * 4096 / 197e12
-    assert flops.mfu_pct(mistral, 2048, 4096, step_s, 197e12) == pytest.approx(100)
+    assert flops.mfu_pct(per_token, 4096, step_s, 197e12) == pytest.approx(100)
+    # the same step on four chips' peak is a quarter of it
+    assert flops.mfu_pct(per_token, 4096, step_s, 197e12, chips=4) == (
+        pytest.approx(25)
+    )
     with pytest.raises(LookupError):
         flops.peak_for({"TPU v5 lite": {}}, "TPU v9")
 

@@ -43,10 +43,11 @@ def main(config_path, seed, batch, seq):
     import jax.numpy as jnp
     import numpy as np
 
-    import reference
+    import harness
 
     with open(config_path) as f:
         cfg = json.load(f)
+    reference = harness.family(cfg)
     params = reference.seeded_params(cfg, int(seed))
     tokens = np.random.default_rng(int(seed)).integers(
         0, cfg["vocab_size"], size=(int(batch), int(seq) + 1), dtype=np.int32

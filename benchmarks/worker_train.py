@@ -1,12 +1,14 @@
 """The benchmark's training worker: user code of the framework, started
 by ``python -m dlrover_tpu.run`` like any other.
 
-After ``examples/llama_pretrain.py`` (auto_accelerate + Trainer + flash
-checkpoint + elasticity, ``agd`` optimizer, uniformly random tokens).
-What differs, and why it lives here and not in the example: the model's
-widths come from a configuration FILE of the benchmark; the step times,
-the device, the memory peak, the reference loss and the profiler window
-are taken by a callback of the benchmark's own and written to
+After the pretraining example under ``examples/`` (auto_accelerate +
+Trainer + flash checkpoint + elasticity, ``agd`` optimizer, uniformly
+random tokens).  What differs, and why it lives here and not in the
+example: the model's widths come from a configuration FILE of the
+benchmark, and the model itself from the family module that file names;
+the step times, the device, the memory peak, the reference loss and the
+profiler window are taken by a callback of the benchmark's own and
+written to
 ``<run_dir>/worker.jsonl``; and the worker stops when the runner drops
 ``<run_dir>/stop`` (or at ``--stop_after_s``, so that an orphan ends).
 """
@@ -63,7 +65,7 @@ class Rows:
 
 
 def bench_callback(
-    args, cfg, model, rows, meter, device, training_args, first_batch
+    args, cfg, fam, forward, rows, meter, device, training_args, first_batch
 ):
     import jax
     import jax.numpy as jnp
@@ -95,16 +97,13 @@ def bench_callback(
             type, its attention) against the plain float32 reference's,
             and the reference's mean loss for the runner to hold the
             first step's logged loss against."""
-            import reference
-            from dlrover_tpu.models.llama import forward
-
             def program(p, t):
-                logp = jax.nn.log_softmax(forward(p, t[:, :-1], model), -1)
+                logp = jax.nn.log_softmax(forward(p, t[:, :-1]), -1)
                 return jnp.take_along_axis(logp, t[:, 1:, None], -1)[..., 0]
 
             t0 = time.time()
             ref = jax.jit(
-                lambda p, t: reference.token_logprobs(p, t, cfg)
+                lambda p, t: fam.token_logprobs(p, t, cfg)
             )(params, first_batch)
             got = jax.jit(program)(params, first_batch)
             rows.write(
@@ -187,16 +186,11 @@ def main():
         sys.exit(3)
 
     from dlrover_tpu.accelerate import auto_accelerate, load_strategy
-    from dlrover_tpu.models.llama import (
-        LlamaConfig,
-        init_params,
-        loss_fn,
-        param_logical_axes,
-    )
     from dlrover_tpu.optimizers import agd
     from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs
 
-    model = LlamaConfig(**harness.llama_kwargs(cfg, args.seq))
+    fam = harness.family(cfg)
+    parts = fam.train_parts(cfg, args.seq)
     devices = jax.devices()[: args.devices]
     strategy = None
     if args.fsdp or args.tensor:
@@ -209,10 +203,10 @@ def main():
             }
         )
     result = auto_accelerate(
-        loss_fn=lambda p, b: loss_fn(p, b, model),
+        loss_fn=parts["loss_fn"],
         optimizer=agd(args.lr),
-        init_params_fn=lambda rng: init_params(rng, model),
-        param_axes=param_logical_axes(model),
+        init_params_fn=parts["init_params_fn"],
+        param_axes=parts["param_axes"],
         load_strategy=strategy,
         devices=devices,
     )
@@ -232,7 +226,7 @@ def main():
         while True:
             yield {
                 "tokens": rng.integers(
-                    0, model.vocab_size,
+                    0, cfg["vocab_size"],
                     size=(args.batch, args.seq + 1),
                     dtype=np.int32,
                 )
@@ -247,7 +241,7 @@ def main():
         micro_batch_size=args.batch,
     )
     callback = bench_callback(
-        args, cfg, model, rows, meter, device, training_args,
+        args, cfg, fam, parts["forward"], rows, meter, device, training_args,
         next(batches(0))["tokens"],
     )
     trainer = Trainer(
