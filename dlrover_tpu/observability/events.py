@@ -501,6 +501,11 @@ OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
         "lanes_decode",
         "lanes_prefill",
         "slots",
+        # a model with per-lane state beside its pages: bytes of the
+        # state slabs resident, and lanes whose state was started from
+        # zero this iteration (0 / 0 for a model of keys and values)
+        "state_bytes",
+        "state_resets",
     ),
     # the published generation, where the caller adopts one (the
     # replica's first sync, from its own template, has none)

@@ -188,13 +188,27 @@ def _import_factory(path: str) -> Callable:
 
 def tiny_llama_factory(**cfg_kwargs):
     """Built-in factory: a llama sampler whose config comes from the
-    spec (tests / example).  Returns the worker contract:
-    ``forward_fn``, ``params_template_fn`` (inference-sharded params
-    the shm snapshot restores ONTO) and ``cfg`` (the model config the
-    serving scheduler builds its paged decode programs from).  A
-    ``draft`` sub-dict (flywheel speculative decode) adds
+    spec (tests / example).  Returns the serving worker contract — what
+    a factory must provide for the replica to serve a model:
+
+    - ``forward_fn(params, tokens) -> logits``: the whole-sequence
+      forward (the legacy single-worker loop samples with it);
+    - ``params_template_fn() -> tree``: the weights the replica HOLDS,
+      inference-sharded — the target every shm adoption restores onto,
+      and what the scheduler serves until the first publish;
+    - ``cfg``: the model config the scheduler builds its pool from
+      (``ContinuousBatchingScheduler`` says which attributes and which
+      optional ``lane_state()`` it reads);
+    - optionally ``paged_decode_fn`` / ``paged_prefill_fn`` /
+      ``paged_verify_fn``: the step programs, where they are not the
+      llama ones (signatures on the scheduler's class), and
+      ``serving_params_fn(tree) -> tree``: the copy of the held weights
+      those programs compute on (default: the tree as given).
+
+    A ``draft`` sub-dict (flywheel speculative decode) adds
     ``draft_cfg`` + ``draft_template_fn`` for the separately-published
-    drafter the scheduler runs K cheap steps of per verify."""
+    drafter the scheduler runs K cheap steps of per verify.
+    :func:`falcon_h1_factory` is the sibling for the hybrid block."""
     import jax
     import jax.numpy as jnp
 
@@ -235,6 +249,36 @@ def tiny_llama_factory(**cfg_kwargs):
             jax.random.PRNGKey(1), draft_cfg
         )
     return parts
+
+
+def falcon_h1_factory(**cfg_kwargs):
+    """Built-in factory of the hybrid block (``models/falcon_h1.py``:
+    Mamba-2 heads beside attention heads): the same worker contract as
+    :func:`tiny_llama_factory`, with the model's own step programs —
+    its prefill is told the lane and the count of real tokens, because
+    ``cfg.lane_state()`` declares a conv tail and a recurrent state a
+    lane — and its own ``serving_params_fn``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import falcon_h1
+
+    if isinstance(cfg_kwargs.get("dtype"), str):
+        # the spec rides through JSON: dtype arrives as a name
+        cfg_kwargs = dict(cfg_kwargs, dtype=jnp.dtype(cfg_kwargs["dtype"]))
+    cfg = falcon_h1.FalconH1Config(**cfg_kwargs)
+    return {
+        "forward_fn": partial(falcon_h1.forward, cfg=cfg),
+        "params_template_fn": lambda: falcon_h1.init_params(
+            jax.random.PRNGKey(0), cfg
+        ),
+        "cfg": cfg,
+        "paged_decode_fn": partial(falcon_h1.paged_decode_step, cfg=cfg),
+        "paged_prefill_fn": partial(falcon_h1.paged_prefill_chunk, cfg=cfg),
+        "serving_params_fn": partial(falcon_h1.serving_params, cfg=cfg),
+    }
 
 
 # --------------------------------------------------------------------------
@@ -680,6 +724,7 @@ def _serving_worker_loop(spec) -> int:
         paged_decode_fn=parts.get("paged_decode_fn"),
         paged_prefill_fn=parts.get("paged_prefill_fn"),
         paged_verify_fn=parts.get("paged_verify_fn"),
+        serving_params_fn=parts.get("serving_params_fn"),
         events=get_event_logger(),
         replica=tag,
         role=("prefill" if role == "prefill" else "unified"),

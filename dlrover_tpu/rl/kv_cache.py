@@ -83,6 +83,11 @@ class PagedCacheConfig:
     num_blocks: int  # pool size INCLUDING the null block
     block_size: int = 16
     dtype: object = jnp.bfloat16
+    # what a LANE keeps per layer beside its paged K/V, as the model
+    # declares it: ``((leaf, shape, dtype), ...)``.  Empty for a block
+    # whose only state is keys and values.
+    lane_state: Tuple[Tuple[str, Tuple[int, ...], object], ...] = ()
+    max_slots: int = 0  # lanes the ``lane_state`` slabs are made for
 
     @property
     def usable_blocks(self) -> int:
@@ -93,9 +98,47 @@ class PagedCacheConfig:
         return blocks_needed(n_tokens, self.block_size)
 
 
+def paged_cache_config(
+    model_cfg, num_blocks: int, block_size: int, max_slots: int
+) -> PagedCacheConfig:
+    """The cache a model asks for, from its own declaration — the ONE
+    place a model config is read for it (policy and draft pools
+    alike).  A serving model's config provides the paged K/V geometry
+    (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and MAY
+    provide ``lane_state() -> {leaf: (shape, dtype)}``: state a lane
+    keeps per layer that is no page of a sequence — it has no
+    positions, cannot be shared by prefix or rebuilt from blocks, and
+    every token overwrites it (a recurrent state, a convolution's
+    tail).  Such leaves live in the pool as ``[n_layers, max_slots,
+    *shape]`` slabs indexed by lane; :class:`BlockPool` never sees
+    them."""
+    declared = getattr(model_cfg, "lane_state", None)
+    leaves = tuple(
+        (name, tuple(shape), dtype)
+        for name, (shape, dtype) in (declared() if declared else {}).items()
+    )
+    clash = {"k", "v"} & {name for name, _, _ in leaves}
+    if clash:
+        raise ValueError(
+            f"lane_state leaf name(s) {sorted(clash)} are the paged "
+            "K/V pool's"
+        )
+    return PagedCacheConfig(
+        n_layers=model_cfg.n_layers,
+        n_kv_heads=model_cfg.n_kv_heads,
+        head_dim=model_cfg.head_dim,
+        num_blocks=num_blocks,
+        block_size=block_size,
+        dtype=model_cfg.dtype,
+        lane_state=leaves,
+        max_slots=max_slots,
+    )
+
+
 def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
-    """The device-side pool, stacked on the layer dim like the params
-    (``[L, num_blocks, block_size, KV, head_dim]``)."""
+    """The device-side pool, stacked on the layer dim like the params:
+    ``k``, ``v`` ``[L, num_blocks, block_size, KV, head_dim]`` and one
+    zeroed ``[L, max_slots, *shape]`` slab per ``lane_state`` leaf."""
     shape = (
         cfg.n_layers,
         cfg.num_blocks,
@@ -103,10 +146,24 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
         cfg.n_kv_heads,
         cfg.head_dim,
     )
-    return {
+    pool = {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
     }
+    for name, leaf_shape, dtype in cfg.lane_state:
+        pool[name] = jnp.zeros(
+            (cfg.n_layers, cfg.max_slots) + leaf_shape, dtype=dtype
+        )
+    return pool
+
+
+def lane_state_nbytes(pool: Dict[str, jnp.ndarray]) -> int:
+    """Bytes of the per-lane state slabs of a pool (0 for a pool of
+    keys and values only)."""
+    return sum(
+        int(leaf.nbytes) for name, leaf in pool.items()
+        if name not in ("k", "v")
+    )
 
 
 def prefix_block_keys(tokens, block_size: int) -> List[str]:
@@ -206,7 +263,7 @@ def insert_block_regions(
         v = lax.dynamic_update_index_in_dim(
             v, jnp.asarray(vr[:, j], v.dtype), i, axis=1
         )
-    return {"k": k, "v": v}
+    return {**pool, "k": k, "v": v}
 
 
 class OutOfBlocksError(RuntimeError):

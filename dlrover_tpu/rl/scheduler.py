@@ -80,10 +80,11 @@ from dlrover_tpu.observability.events import EventLogger
 from dlrover_tpu.rl.kv_cache import (
     BlockPool,
     OutOfBlocksError,
-    PagedCacheConfig,
     extract_block_regions,
     init_block_pool,
     insert_block_regions,
+    lane_state_nbytes,
+    paged_cache_config,
     pool_can_ever_hold,
     prefix_block_keys,
 )
@@ -228,10 +229,35 @@ class _Slot:
 class ContinuousBatchingScheduler:
     """The token-level serving loop over a paged KV cache.
 
-    ``model_cfg`` is a ``models.llama.LlamaConfig`` (or any config the
-    supplied ``paged_decode_fn`` / ``paged_prefill_fn`` /
-    ``paged_verify_fn`` accept — the same injection seam
-    ``KVCacheBackend`` uses)."""
+    What a model must provide (``models/llama.py`` and
+    ``models/falcon_h1.py`` are the two that do):
+
+    - ``model_cfg``: the paged K/V geometry as attributes
+      (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and,
+      optionally, ``lane_state() -> {leaf: (shape, dtype)}`` — state a
+      lane keeps per layer beside its pages (a recurrent state, a
+      convolution's tail).  ``rl/kv_cache.paged_cache_config`` reads
+      both; the scheduler owns the resulting pool, the state slabs
+      indexed by lane, the K/V by :class:`BlockPool`'s tables.
+    - the step programs, the llama ones unless injected:
+      ``paged_decode_fn(params, tokens, pool, tables, positions,
+      active) -> (logits [S, V], pool)``, which must leave an inactive
+      lane's state as it was, and ``paged_prefill_fn(params, chunk,
+      pool, table, start) -> (logits [1, C, V], pool)``.  For a model
+      WITH lane state the prefill program receives two more scalars,
+      ``(..., start, lane, real)``: the lane whose slab it continues
+      and how many tokens of the padded chunk are real — a recurrence
+      must start from zero at ``start == 0``, carry from chunk to
+      chunk of the same lane while other lanes decode in between, and
+      stop at the last real token.  Injected programs are given the
+      tree ``sync_weights`` was given.
+
+    A state that is no page cannot be reused by prefix, rolled back or
+    shipped, so for a model with lane state the scheduler never takes
+    a prefix hit and never shares a filled block (decided from the
+    declaration alone), and refuses at construction ``decode_k > 1``,
+    a draft model and the ``prefill`` role.  Preemption and resume
+    re-prefill from token 0 and are sound."""
 
     def __init__(
         self,
@@ -248,6 +274,7 @@ class ContinuousBatchingScheduler:
         draft_decode_fn: Optional[Callable] = None,
         draft_prefill_fn: Optional[Callable] = None,
         verify_write_fn: Optional[Callable] = None,
+        serving_params_fn: Optional[Callable] = None,
     ):
         import jax
         import jax.numpy as jnp
@@ -290,8 +317,9 @@ class ContinuousBatchingScheduler:
         self._params = None
         # which leaves the step programs cast is the llama programs'
         # rule (``llama.serving_params``); injected programs get the
-        # tree ``sync_weights`` was given
-        self._serving_params = (
+        # tree ``sync_weights`` was given, unless their model brings
+        # its own rule (``serving_params_fn``)
+        self._serving_params = serving_params_fn or (
             (lambda params: params)
             if paged_decode_fn or paged_prefill_fn or paged_verify_fn
             or verify_write_fn
@@ -348,7 +376,7 @@ class ContinuousBatchingScheduler:
         self.incremental = kv_incremental_enabled()
         self.grow_blocks = kv_grow_blocks()
         self.admit_watermark = kv_admit_watermark()
-        self.prefix_cache = (
+        self._prefix_cache_asked = (
             self.incremental and kv_prefix_cache_enabled()
         )
         self.decode_k = decode_steps()
@@ -382,30 +410,53 @@ class ContinuousBatchingScheduler:
         # no finished-list was threaded in (drained by step())
         self._adopt_finished: List[GenResult] = []
 
-        cache_cfg = PagedCacheConfig(
-            n_layers=model_cfg.n_layers,
-            n_kv_heads=model_cfg.n_kv_heads,
-            head_dim=model_cfg.head_dim,
-            num_blocks=s.num_blocks,
-            block_size=s.block_size,
-            dtype=model_cfg.dtype,
+        cache_cfg = paged_cache_config(
+            model_cfg, s.num_blocks, s.block_size, s.max_slots
         )
         self.pool_cfg = cache_cfg
+        # per-lane state beside the pages: no positions, no sharing, no
+        # rollback, nothing to ship — what cannot be sound is refused
+        # here, by name, and prefix reuse is off (``prefix_hits`` stays
+        # 0, ``prefix_hits_skipped`` counts the admissions that would
+        # have looked a prefix up)
+        self.lane_state = bool(cache_cfg.lane_state)
+        if self.lane_state:
+            leaves = ", ".join(name for name, _, _ in cache_cfg.lane_state)
+            for refused, why in (
+                (self.decode_k > 1,
+                 "multi-token decode (DLROVER_TPU_DECODE_STEPS > 1): a "
+                 "rejected draft would have to roll the state back"),
+                (draft_cfg is not None,
+                 "a draft model: its verify step cannot roll the "
+                 "policy's state back"),
+                (role == "prefill",
+                 "the prefill role: a shipped prefill carries K/V "
+                 "blocks only, the state at the end of the prompt "
+                 "would be lost"),
+            ):
+                if refused:
+                    raise ValueError(
+                        f"the model keeps per-lane state ({leaves}) "
+                        f"beside its paged K/V and cannot be served "
+                        f"with {why}"
+                    )
+        self.prefix_cache = (
+            self._prefix_cache_asked and not self.lane_state
+        )
+        self.prefix_hits_skipped = 0
+        self.state_resets = 0
+        self._step_state_resets = 0
         self.block_pool = BlockPool(cache_cfg)
         self._pool = init_block_pool(cache_cfg)
+        self.state_bytes = lane_state_nbytes(self._pool)
         # the draft pool mirrors the policy pool's GEOMETRY (same
         # block ids, tables, block size) with the DRAFT model's shapes
         # — one host-side allocator drives both
         self._draft_pool = None
         if self.draft:
             self._draft_pool = init_block_pool(
-                PagedCacheConfig(
-                    n_layers=draft_cfg.n_layers,
-                    n_kv_heads=draft_cfg.n_kv_heads,
-                    head_dim=draft_cfg.head_dim,
-                    num_blocks=s.num_blocks,
-                    block_size=s.block_size,
-                    dtype=draft_cfg.dtype,
+                paged_cache_config(
+                    draft_cfg, s.num_blocks, s.block_size, s.max_slots
                 )
             )
 
@@ -517,9 +568,11 @@ class ContinuousBatchingScheduler:
             n_match = jnp.sum(jnp.cumprod(eq, axis=1), axis=1)
             return pool, drafts, ver, n_match
 
-        def _prefill(params, pool, chunk, table, start):
+        def _prefill(params, pool, chunk, table, start, *lane_real):
+            # ``lane_real``: (lane, real) for a model with lane state,
+            # nothing for one whose chunk needs no more than its table
             logits, pool = self._prefill_model(
-                params, chunk, pool, table, start
+                params, chunk, pool, table, start, *lane_real
             )
             return pool, logits
 
@@ -818,7 +871,10 @@ class ContinuousBatchingScheduler:
                        # re-prefill deterministically instead
                        shipped=(
                            shipped
-                           if self.fleet and not resume.size else None
+                           if self.fleet and not resume.size
+                           # K/V blocks alone cannot seed a lane that
+                           # also keeps a state: prefill here instead
+                           and not self.lane_state else None
                        ),
                        route=str(route))
         )
@@ -886,6 +942,11 @@ class ContinuousBatchingScheduler:
             draft_active=int(
                 self.draft and self._draft_params is not None
             ),
+            # per-lane state beside the pages (0 / 0 / 0 for a model
+            # of keys and values only)
+            state_bytes=self.state_bytes,
+            state_resets=self.state_resets,
+            prefix_hits_skipped=self.prefix_hits_skipped,
         )
         return st
 
@@ -1061,6 +1122,10 @@ class ContinuousBatchingScheduler:
                 self.block_pool.acquire_prefix(plan["keys"])
                 if plan["keys"] else []
             )
+            if self.lane_state and self._prefix_cache_asked:
+                # the index was never asked: a hit would skip tokens
+                # whose state exists nowhere
+                self.prefix_hits_skipped += 1
             self.block_pool.allocate(
                 req.req_id,
                 plan["n_tokens"],
@@ -1495,8 +1560,16 @@ class ContinuousBatchingScheduler:
                 jnp.asarray(chunk[None], jnp.int32),
                 jnp.asarray(self._tables[slot]),
                 jnp.int32(start),
+                *(
+                    (jnp.int32(slot), jnp.int32(real))
+                    if self.lane_state else ()
+                ),
             )
             self.dispatches += 1
+            if self.lane_state and start == 0:
+                # the program starts this lane's state from zero
+                self._step_state_resets += 1
+                self.state_resets += 1
             if self.draft and self._draft_params is not None:
                 # mirror the chunk into the DRAFT pool (same
                 # table/blocks, draft shapes) so the drafter decodes
@@ -1754,6 +1827,7 @@ class ContinuousBatchingScheduler:
         for ph in phases:
             ph.total_s = 0.0
         self._lanes_decode = self._lanes_prefill = 0
+        self._step_state_resets = 0
         finished: List[GenResult] = []
         if self._adopt_finished:
             finished.extend(self._adopt_finished)
@@ -1841,6 +1915,8 @@ class ContinuousBatchingScheduler:
                 lanes_decode=self._lanes_decode,
                 lanes_prefill=self._lanes_prefill,
                 slots=self.sched.max_slots,
+                state_bytes=self.state_bytes,
+                state_resets=self._step_state_resets,
             )
         return finished
 

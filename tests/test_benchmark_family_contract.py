@@ -1,0 +1,147 @@
+"""The contract between the program and the benchmark, in tier-1: for
+every configuration of ``BENCHMARK.json``, the family module its file
+names resolves and provides its parts; its ``model_kwargs`` build the
+program's model object; at a tiny override of the sizes the program's
+forward and the family's plain reference agree per token on the
+family's seeded weights; and the family's counts are the size of that
+tree.  A program PR that renames what a family imports fails here and
+not on the chip.
+
+The cases are those of ``benchmarks/tests/test_family_contract.py``
+(which a benchmark PR keeps beside the harness, outside tier-1), made
+family-aware: a family without a training path (``train_parts`` raises
+``CellFailed``) skips the training cases instead of failing them.
+"""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import harness  # noqa: E402
+
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    CONFIGS = {c["name"]: c for c in json.load(_f)["configs"]}
+
+SEQ = 24
+#: float32 weights and compute on both sides; what differs is the
+#: order of the sums (the program scans over layers, chunks its scan)
+TOL = 2e-4
+#: sizes that keep a family's shape and fit a CPU test; every other key
+#: (each multiplier, eps, theta) is the configuration's own
+TINY = {
+    "family_dense": lambda cfg: dict(
+        hidden_size=64, intermediate_size=160, num_hidden_layers=2,
+        vocab_size=384, num_attention_heads=8,
+        num_key_value_heads=max(
+            8 // (cfg["num_attention_heads"] // cfg["num_key_value_heads"]),
+            1,
+        ),
+    ),
+    "family_falcon_h1": lambda cfg: dict(
+        hidden_size=64, intermediate_size=160, num_hidden_layers=2,
+        vocab_size=384, num_attention_heads=10, num_key_value_heads=2,
+        head_dim=16, mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16,
+        mamba_d_state=16, mamba_chunk_size=8,
+    ),
+}
+
+
+def tiny_cfg(name):
+    with open(os.path.join(REPO, CONFIGS[name]["file"])) as f:
+        cfg = json.load(f)
+    return dict(cfg, **TINY[cfg["family"]](cfg))
+
+
+def train_parts_or_skip(fam, cfg):
+    try:
+        return fam.train_parts(cfg, SEQ)
+    except harness.CellFailed as e:
+        pytest.skip(f"{cfg['family']} has no training path: {e}")
+
+
+@pytest.fixture(params=sorted(CONFIGS))
+def cfg(request):
+    return tiny_cfg(request.param)
+
+
+def test_the_family_resolves_and_provides_its_parts(cfg):
+    fam = harness.family(cfg)
+    for name in (
+        "model_kwargs", "train_parts", "serving_parts", "seeded_params",
+        "token_logprobs", "matmul_params", "total_params",
+    ):
+        assert callable(getattr(fam, name)), name
+    kwargs = fam.model_kwargs(cfg, SEQ)
+    assert json.loads(json.dumps(kwargs)) == kwargs  # rides through JSON
+    served = fam.serving_parts(**kwargs, dtype="bfloat16")  # as a rollout cell
+    assert {"forward_fn", "params_template_fn", "cfg"} <= set(served)
+    # the scheduler's side of the contract: K/V geometry as attributes
+    for attr in ("n_layers", "n_kv_heads", "head_dim", "dtype"):
+        assert hasattr(served["cfg"], attr), attr
+    assert served["cfg"].n_layers == cfg["num_hidden_layers"]
+
+
+def test_train_parts_build_the_serving_model(cfg):
+    fam = harness.family(cfg)
+    parts = train_parts_or_skip(fam, cfg)
+    assert {"model", "init_params_fn", "loss_fn", "param_axes",
+            "forward"} <= set(parts)
+    assert callable(fam.train_flops_per_token)
+    served = fam.serving_parts(**fam.model_kwargs(cfg, SEQ),
+                               dtype="bfloat16")
+    assert served["cfg"] == parts["model"]
+
+
+def test_the_counts_are_the_tree(cfg):
+    fam = harness.family(cfg)
+    params = fam.seeded_params(cfg, 2**31 + 5)
+    served = fam.serving_parts(**fam.model_kwargs(cfg, SEQ), dtype="float32")
+    # the reference's tree IS the program's: same leaves, same shapes
+    template = jax.eval_shape(served["params_template_fn"])
+    assert jax.tree_util.tree_map(
+        lambda a: a.shape, params
+    ) == jax.tree_util.tree_map(lambda a: a.shape, template)
+    n = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert n == fam.total_params(cfg)
+    assert 0 < fam.matmul_params(cfg) < n
+
+
+def test_program_and_reference_agree_per_token(cfg):
+    fam = harness.family(cfg)
+    params = fam.seeded_params(cfg, 2**31 + 5)
+    tokens = np.random.default_rng(7).integers(
+        0, cfg["vocab_size"], size=(2, SEQ + 1), dtype=np.int32
+    )
+    served = fam.serving_parts(**fam.model_kwargs(cfg, SEQ), dtype="float32")
+    with jax.default_matmul_precision("highest"):
+        got = jax.nn.log_softmax(
+            served["forward_fn"](params, tokens[:, :-1]).astype(jnp.float32),
+            -1,
+        )
+    got = jnp.take_along_axis(got, tokens[:, 1:, None], -1)[..., 0]
+    ref = fam.token_logprobs(params, tokens, cfg)
+    assert float(jnp.max(jnp.abs(got - ref))) < TOL
+
+
+def test_the_training_loss_is_the_reference_mean(cfg):
+    fam = harness.family(cfg)
+    parts = train_parts_or_skip(fam, cfg)
+    params = fam.seeded_params(cfg, 2**31 + 5)
+    tokens = np.random.default_rng(7).integers(
+        0, cfg["vocab_size"], size=(2, SEQ + 1), dtype=np.int32
+    )
+    ref = fam.token_logprobs(params, tokens, cfg)
+    loss = parts["loss_fn"](params, {"tokens": tokens})
+    loss = loss[0] if isinstance(loss, tuple) else loss
+    assert abs(float(loss) + float(jnp.mean(ref))) < 5e-3  # bf16 compute
+    assert fam.train_flops_per_token(cfg, SEQ) > 6 * fam.matmul_params(cfg)

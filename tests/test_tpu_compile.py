@@ -135,7 +135,30 @@ def _paged_case(kernel, kv_heads):
     return fn, (((LANES, WINDOW, H, D), BF16), pool, pool, tables, lens)
 
 
+def _ssm_case():
+    """The hybrid block's decode recurrence at Falcon-H1-34B widths and
+    the benchmark cell's geometry: 6 layers x 32 lanes of 32 heads x
+    128 x 256 float32 states, 2 groups."""
+    from dlrover_tpu.ops.ssm import ssm_decode_update
+
+    f32 = jnp.float32
+    layers, lanes, heads, p, n, groups = 6, 32, 32, 128, 256, 2
+
+    def fn(state, layer, x, dt, a, b, c, d):
+        return ssm_decode_update(
+            state, layer, x, dt, a, b, c, d, backend="pallas"
+        )
+
+    return fn, (
+        ((layers, lanes, heads, p, n), f32), ((), jnp.int32),
+        ((lanes, heads, p), f32), ((lanes, heads), f32), ((heads,), f32),
+        ((lanes, groups, n), f32), ((lanes, groups, n), f32),
+        ((heads,), f32),
+    )
+
+
 CASES = {
+    "ssm_decode_update": _ssm_case,
     "flash_fwd": lambda: _flash_case(H, backward=False),
     "flash_fwd_bwd_mha": lambda: _flash_case(H, backward=True),
     "flash_fwd_bwd_gqa8": lambda: _flash_case(8, backward=True),
@@ -159,6 +182,7 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     ("paged_decode_kv32", "paged_decode"),
     ("paged_verify_w4_kv32", "paged_verify"),
     ("rms_norm_fwd_bwd", "rmsnorm_fwd"),
+    ("ssm_decode_update", "ssm_decode_update"),
 ])
 def test_serving_kernels_keep_their_names(case, name, one_chip):
     """A device trace names an operation by its HLO instruction: the
@@ -264,3 +288,26 @@ def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
         destroy_parallel_mesh()  # the global mesh other tests see
     assert "tpu_custom_call" in text
     assert "all-gather" in text or "all-reduce" in text
+
+
+def test_ssm_state_is_updated_in_place(one_chip):
+    """The decode recurrence's kernel addresses one layer of the
+    stacked ``[layers, lanes, ...]`` state through its index maps and
+    aliases the buffer to its output: donated, nothing of the 0.8 GB is
+    copied and the program's temporaries stay far under one layer's
+    slab (134 MB)."""
+    fn, shapes = _ssm_case()
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        *[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes
+        ]
+    ).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = 6 * 32 * 32 * 128 * 256 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 32 * 2**20
+    assert not [
+        line for line in compiled.as_text().splitlines()
+        if " copy(" in line and "f32[6,32,32,128,256]" in line
+    ]
