@@ -434,7 +434,7 @@ def paged_prefill_chunk(
     its K/V to the null block.  Returns (logits [1, C, vocab], pool)."""
     from dlrover_tpu.ops.paged_attention import (
         paged_prefill_attention,
-        write_block_kv,
+        scan_layers_over_pool,
     )
 
     dt = cfg.dtype
@@ -453,9 +453,9 @@ def paged_prefill_chunk(
     offs = jnp.where(valid, positions % bs, 0)
     fresh = start_pos == 0
 
-    def body(carry, layer_in):
-        x, ssm_all, layer = carry
-        lp, k_pool, v_pool, conv = layer_in
+    def body(carry, layer_in, kv):
+        x, ssm_all = carry
+        lp, conv = layer_in
         h = rms_norm(x, lp["norm"], cfg.rms_norm_eps)
         z, xbc, dt_raw = _ssm_inputs(h, lp, cfg)
         tail = jnp.where(
@@ -472,7 +472,7 @@ def paged_prefill_chunk(
         state = jnp.where(
             fresh, 0.0,
             lax.dynamic_slice(
-                ssm_all, (layer, lane, 0, 0, 0),
+                ssm_all, (kv.layer, lane, 0, 0, 0),
                 (1, 1) + ssm_all.shape[2:],
             )[0],
         )
@@ -487,7 +487,7 @@ def paged_prefill_chunk(
         )
         ssm_all = lax.dynamic_update_slice(
             ssm_all, state[None].astype(ssm_all.dtype),
-            (layer, lane, 0, 0, 0),
+            (kv.layer, lane, 0, 0, 0),
         )
         y = _gated_norm(
             y.reshape(1, c, cfg.mamba_d_ssm), z, lp["ssm_norm"], cfg
@@ -497,22 +497,20 @@ def paged_prefill_chunk(
         )
         q, k, v = _qkv(h, lp, cfg)
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        k_pool, v_pool = write_block_kv(
-            k_pool, v_pool, k[0], v[0], blks, offs
-        )
+        kv = kv.write(k[0], v[0], blks, offs)
         attn = paged_prefill_attention(
-            q[0], k_pool, v_pool, block_table, start_pos
+            q[0], kv.k, kv.v, kv.tables(block_table), start_pos
         )
         a = _proj(
             attn.reshape(1, c, -1), lp["wo"], dt
         ) * jnp.asarray(cfg.attention_out_multiplier, dt)
         x = x + m + a
         x = x + _mlp(x, lp, cfg)
-        return (x, ssm_all, layer + 1), (k_pool, v_pool, conv)
+        return (x, ssm_all), conv, kv
 
-    (x, ssm_all, _), (new_k, new_v, new_conv) = lax.scan(
-        body, (x, pool["ssm"], jnp.int32(0)),
-        (params["layers"], pool["k"], pool["v"], pool["conv"]),
+    (x, ssm_all), new_conv, new_k, new_v = scan_layers_over_pool(
+        body, (x, pool["ssm"]), (params["layers"], pool["conv"]),
+        pool["k"], pool["v"],
     )
     return _logits(x, params, cfg), {
         "k": new_k, "v": new_v, "conv": new_conv, "ssm": ssm_all,
@@ -535,7 +533,7 @@ def paged_decode_step(
     depend on (lanes, pool geometry) only: compiled once."""
     from dlrover_tpu.ops.paged_attention import (
         paged_decode_attention,
-        write_block_kv,
+        scan_layers_over_pool,
     )
 
     dt = cfg.dtype
@@ -554,16 +552,16 @@ def paged_decode_step(
     off = jnp.where(active, positions % bs, 0)
     seq_lens = jnp.where(active, positions + 1, 1)
 
-    def body(carry, layer_in):
-        x, ssm_all, layer = carry
-        lp, k_pool, v_pool, conv = layer_in
+    def body(carry, layer_in, kv):
+        x, ssm_all = carry
+        lp, conv = layer_in
         h = rms_norm(x, lp["norm"], cfg.rms_norm_eps)
         z, xbc, dt_raw = _ssm_inputs(h[:, 0], lp, cfg)
         window = jnp.concatenate([conv, xbc[:, None]], axis=1)  # [B, K, Cd]
         xs, b, c = _split_xbc(_causal_conv(window, lp)[:, 0], cfg)
         conv = jnp.where(active[:, None, None], window[:, 1:], conv)
         y, ssm_all = ssm_decode_update(
-            ssm_all, layer, xs,
+            ssm_all, kv.layer, xs,
             jnp.where(
                 active[:, None], jax.nn.softplus(dt_raw + lp["dt_bias"]),
                 0.0,
@@ -579,22 +577,20 @@ def paged_decode_step(
         q, k, v = _qkv(h, lp, cfg)
         q = _apply_rope_rows(q, cos, sin)
         k = _apply_rope_rows(k, cos, sin)
-        k_pool, v_pool = write_block_kv(
-            k_pool, v_pool, k[:, 0], v[:, 0], blk, off
-        )
+        kv = kv.write(k[:, 0], v[:, 0], blk, off)
         attn = paged_decode_attention(
-            q[:, 0], k_pool, v_pool, block_tables, seq_lens
+            q[:, 0], kv.k, kv.v, kv.tables(block_tables), seq_lens
         )
         a = _proj(
             attn.reshape(n, 1, -1), lp["wo"], dt
         ) * jnp.asarray(cfg.attention_out_multiplier, dt)
         x = x + m[:, None] + a
         x = x + _mlp(x, lp, cfg)
-        return (x, ssm_all, layer + 1), (k_pool, v_pool, conv)
+        return (x, ssm_all), conv, kv
 
-    (x, ssm_all, _), (new_k, new_v, new_conv) = lax.scan(
-        body, (x, pool["ssm"], jnp.int32(0)),
-        (params["layers"], pool["k"], pool["v"], pool["conv"]),
+    (x, ssm_all), new_conv, new_k, new_v = scan_layers_over_pool(
+        body, (x, pool["ssm"]), (params["layers"], pool["conv"]),
+        pool["k"], pool["v"],
     )
     return _logits(x, params, cfg)[:, 0], {
         "k": new_k, "v": new_v, "conv": new_conv, "ssm": ssm_all,

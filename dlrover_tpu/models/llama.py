@@ -685,6 +685,12 @@ def paged_decode_step(
     so a freed block re-issued to another sequence is never gathered
     through a stale table.
 
+    The pool rides WHOLE in the layer scan's carry
+    (``ops/paged_attention.scan_layers_over_pool``, as in the three
+    programs below): layer ``l`` writes its 16 rows in place at block
+    ``l * num_blocks + id``.  Scanned in and out, the pool was sliced,
+    copied and re-stacked — all 1.5 GB of it, three times a step.
+
     The attention call dispatches per ``DLROVER_TPU_PAGED_KERNEL``
     (``ops/paged_attention.paged_kernel_backend``): the streamed Pallas
     decode kernel or the gather-based jnp reference.  The choice is
@@ -692,7 +698,7 @@ def paged_decode_step(
     under either backend."""
     from dlrover_tpu.ops.paged_attention import (
         paged_decode_attention,
-        write_block_kv,
+        scan_layers_over_pool,
     )
 
     dt = cfg.dtype
@@ -718,9 +724,7 @@ def paged_decode_step(
     off = jnp.where(active, positions % bs, 0)
     seq_lens = jnp.where(active, positions + 1, 1)
 
-    def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in
-
+    def body(x, lp, kv):
         def proj(a, w):
             return jnp.matmul(
                 a, w.astype(dt), preferred_element_type=jnp.float32
@@ -734,21 +738,19 @@ def paged_decode_step(
             proj(h, lp["wk"]).reshape(b, 1, nkv, hd), cos, sin
         )
         v = proj(h, lp["wv"]).reshape(b, 1, nkv, hd)
-        k_pool, v_pool = write_block_kv(
-            k_pool, v_pool, k[:, 0], v[:, 0], blk, off
-        )
+        kv = kv.write(k[:, 0], v[:, 0], blk, off)
         attn = paged_decode_attention(
-            q[:, 0], k_pool, v_pool, block_tables, seq_lens
+            q[:, 0], kv.k, kv.v, kv.tables(block_tables), seq_lens
         )
         x = x + proj(attn.reshape(b, 1, nh * hd), lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(proj(h, lp["w_gate"]))
         up = proj(h, lp["w_up"])
         x = x + proj(gate * up, lp["w_down"])
-        return x, (k_pool, v_pool)
+        return x, None, kv
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"])
+    x, _, new_k, new_v = scan_layers_over_pool(
+        body, x, params["layers"], pool["k"], pool["v"]
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum(
@@ -792,7 +794,10 @@ def paged_verify_step(
     The attention call dispatches per ``DLROVER_TPU_PAGED_KERNEL``:
     the fused Pallas verify kernel shares one paged-prefix pass across
     the window's C positions; the jnp reference re-gathers the pool."""
-    from dlrover_tpu.ops.paged_attention import paged_verify_attention
+    from dlrover_tpu.ops.paged_attention import (
+        paged_verify_attention,
+        scan_layers_over_pool,
+    )
 
     dt = cfg.dtype
     b, c = tokens.shape
@@ -804,9 +809,7 @@ def paged_verify_step(
     sin = sin.reshape(b, c, -1)
     safe_pos = jnp.where(active, positions, 0)
 
-    def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in
-
+    def body(x, lp, kv):
         def proj(a, w):
             return jnp.matmul(
                 a, w.astype(dt), preferred_element_type=jnp.float32
@@ -817,7 +820,7 @@ def paged_verify_step(
             proj(h, lp["wq"]).reshape(b, c, nh, hd), cos, sin
         )
         attn = paged_verify_attention(
-            q, k_pool, v_pool, block_tables, safe_pos
+            q, kv.k, kv.v, kv.tables(block_tables), safe_pos
         )
         x = x + proj(attn.reshape(b, c, nh * hd), lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -826,8 +829,8 @@ def paged_verify_step(
         x = x + proj(gate * up, lp["w_down"])
         return x, None
 
-    x, _ = lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"])
+    x, _ = scan_layers_over_pool(
+        body, x, params["layers"], pool["k"], pool["v"], read_only=True
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum(
@@ -862,7 +865,7 @@ def paged_verify_write_step(
     Returns (logits [B, C, vocab] fp32, pool)."""
     from dlrover_tpu.ops.paged_attention import (
         paged_verify_attention,
-        write_block_kv,
+        scan_layers_over_pool,
     )
 
     dt = cfg.dtype
@@ -888,9 +891,7 @@ def paged_verify_write_step(
     ).reshape(-1)
     offs = jnp.where(active[:, None], pos_grid % bs, 0).reshape(-1)
 
-    def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in
-
+    def body(x, lp, kv):
         def proj(a, w):
             return jnp.matmul(
                 a, w.astype(dt), preferred_element_type=jnp.float32
@@ -904,23 +905,22 @@ def paged_verify_write_step(
             proj(h, lp["wk"]).reshape(b, c, nkv, hd), cos, sin
         )
         v = proj(h, lp["wv"]).reshape(b, c, nkv, hd)
-        k_pool, v_pool = write_block_kv(
-            k_pool, v_pool,
+        kv = kv.write(
             k.reshape(b * c, nkv, hd), v.reshape(b * c, nkv, hd),
             blks, offs,
         )
         attn = paged_verify_attention(
-            q, k_pool, v_pool, block_tables, safe_pos
+            q, kv.k, kv.v, kv.tables(block_tables), safe_pos
         )
         x = x + proj(attn.reshape(b, c, nh * hd), lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(proj(h, lp["w_gate"]))
         up = proj(h, lp["w_up"])
         x = x + proj(gate * up, lp["w_down"])
-        return x, (k_pool, v_pool)
+        return x, None, kv
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"])
+    x, _, new_k, new_v = scan_layers_over_pool(
+        body, x, params["layers"], pool["k"], pool["v"]
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum(
@@ -947,7 +947,7 @@ def paged_prefill_chunk(
     Returns (logits [1, C, vocab], pool)."""
     from dlrover_tpu.ops.paged_attention import (
         paged_prefill_attention,
-        write_block_kv,
+        scan_layers_over_pool,
     )
 
     dt = cfg.dtype
@@ -969,9 +969,7 @@ def paged_prefill_chunk(
     )  # [C]
     offs = positions % bs
 
-    def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in
-
+    def body(x, lp, kv):
         def proj(a, w):
             return jnp.matmul(
                 a, w.astype(dt), preferred_element_type=jnp.float32
@@ -983,21 +981,19 @@ def paged_prefill_chunk(
             proj(h, lp["wk"]).reshape(b, c, nkv, hd), cos, sin
         )
         v = proj(h, lp["wv"]).reshape(b, c, nkv, hd)
-        k_pool, v_pool = write_block_kv(
-            k_pool, v_pool, k[0], v[0], blks, offs
-        )
+        kv = kv.write(k[0], v[0], blks, offs)
         attn = paged_prefill_attention(
-            q[0], k_pool, v_pool, block_table, start_pos
+            q[0], kv.k, kv.v, kv.tables(block_table), start_pos
         )
         x = x + proj(attn[None].reshape(b, c, nh * hd), lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(proj(h, lp["w_gate"]))
         up = proj(h, lp["w_up"])
         x = x + proj(gate * up, lp["w_down"])
-        return x, (k_pool, v_pool)
+        return x, None, kv
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"])
+    x, _, new_k, new_v = scan_layers_over_pool(
+        body, x, params["layers"], pool["k"], pool["v"]
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum(

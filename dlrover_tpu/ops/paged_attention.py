@@ -43,13 +43,26 @@ alias the same physical block id read-only — the gather is oblivious
 to aliasing, and no copy-on-write is needed because writers only ever
 touch a sequence's private tail blocks (``rl/kv_cache.py`` enforces
 the ownership discipline).
+
+Step-program contract (how a model's serving program walks its layers;
+:func:`scan_layers_over_pool` is the one place that does it): outside
+the programs the cache is stacked ``[L, num_blocks, block_size, KV,
+D]``; inside one, the pool rides WHOLE in the layer scan's carry,
+viewed ``[L * num_blocks, ...]`` (merging the two leading axes is
+free), and layer ``l`` offsets every block id it writes or reads by
+``l * num_blocks`` — its null block is block ``l * num_blocks``.  A
+pool is NEVER handed to ``lax.scan`` as a scanned input or taken back
+as a stacked output: XLA then slices each layer out, updates a copy
+and re-stacks it, i.e. moves the whole pool three times a step
+(``tests/test_tpu_compile.py`` pins the compiled programs).
 """
 
 import os
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 NEG_INF = -1e30
 
@@ -249,3 +262,80 @@ def write_block_kv(
     k_pool = k_pool.at[block_ids, offsets].set(k_new)
     v_pool = v_pool.at[block_ids, offsets].set(v_new)
     return k_pool, v_pool
+
+
+class LayerPool(NamedTuple):
+    """What one layer of a step program sees of the K/V cache: the
+    WHOLE pool, every layer's blocks in one ``[L * num_blocks,
+    block_size, KV, D]`` buffer, and where this layer's blocks start.
+    A block id of a table means ``base + id`` here; ``base`` itself is
+    the layer's null block."""
+
+    k: jnp.ndarray  # [L * num_blocks, block_size, KV, D]
+    v: jnp.ndarray
+    base: jnp.ndarray  # scalar int32: layer * num_blocks
+    layer: jnp.ndarray  # scalar int32
+
+    def tables(self, block_tables: jnp.ndarray) -> jnp.ndarray:
+        """A sequence's (or every lane's) table, addressing this
+        layer's blocks — what the ``paged_*_attention`` ops take."""
+        return block_tables + self.base
+
+    def write(
+        self,
+        k_new: jnp.ndarray,  # [N, KV, D]
+        v_new: jnp.ndarray,
+        block_ids: jnp.ndarray,  # [N] int32, ids of a TABLE (0 = null)
+        offsets: jnp.ndarray,  # [N] int32
+    ) -> "LayerPool":
+        """:func:`write_block_kv` into this layer's blocks."""
+        k, v = write_block_kv(
+            self.k, self.v, k_new, v_new, block_ids + self.base, offsets
+        )
+        return self._replace(k=k, v=v)
+
+
+def scan_layers_over_pool(
+    body: Callable,
+    carry,
+    xs,  # per-layer scanned inputs: params["layers"], small state
+    k_pool: jnp.ndarray,  # [L, num_blocks, block_size, KV, D]
+    v_pool: jnp.ndarray,
+    read_only: bool = False,
+):
+    """``lax.scan`` over a model's layers with the K/V pool in the
+    CARRY (the step-program contract of the module docstring).
+
+    ``body(carry, xs_l, kv: LayerPool) -> (carry, ys_l, kv)`` — or
+    ``-> (carry, ys_l)`` when ``read_only``: the flat pools are then
+    closed over and nothing of them is carried or returned.  Returns
+    ``(carry, ys, k_pool, v_pool)`` with the pools back in their
+    stacked shape (``(carry, ys)`` when ``read_only``)."""
+    n_layers, n_blocks = k_pool.shape[:2]
+    flat = (n_layers * n_blocks,) + k_pool.shape[2:]
+    k_flat, v_flat = k_pool.reshape(flat), v_pool.reshape(flat)
+
+    if read_only:
+
+        def step(c, xs_l):
+            carry, layer = c
+            kv = LayerPool(k_flat, v_flat, layer * n_blocks, layer)
+            carry, ys_l = body(carry, xs_l, kv)
+            return (carry, layer + 1), ys_l
+
+        (carry, _), ys = lax.scan(step, (carry, jnp.int32(0)), xs)
+        return carry, ys
+
+    def step(c, xs_l):
+        carry, k, v, layer = c
+        carry, ys_l, kv = body(
+            carry, xs_l, LayerPool(k, v, layer * n_blocks, layer)
+        )
+        return (carry, kv.k, kv.v, layer + 1), ys_l
+
+    (carry, k_flat, v_flat, _), ys = lax.scan(
+        step, (carry, k_flat, v_flat, jnp.int32(0)), xs
+    )
+    return (
+        carry, ys, k_flat.reshape(k_pool.shape), v_flat.reshape(v_pool.shape)
+    )

@@ -251,6 +251,19 @@ class ContinuousBatchingScheduler:
       chunk of the same lane while other lanes decode in between, and
       stop at the last real token.  Injected programs are given the
       tree ``sync_weights`` was given.
+    - how a step program walks its layers: the pool it is handed and
+      hands back is stacked, ``k``, ``v`` ``[L, num_blocks, block_size,
+      KV, D]``, and DONATED.  Inside, the program CARRIES the pool
+      through its layer loop, viewed ``[L * num_blocks, ...]``, and
+      layer ``l`` adds ``l * num_blocks`` to every block id it writes
+      or reads (its null block is block ``l * num_blocks``) —
+      ``ops/paged_attention.scan_layers_over_pool`` does all of it.  A
+      program NEVER passes a pool to ``lax.scan`` as a scanned input or
+      takes it back as a stacked output: XLA then slices, copies and
+      re-stacks the whole pool on every step, which was 14 of a 20 ms
+      decode step at 1.5 GB (``tests/test_tpu_compile.py`` pins the
+      six compiled programs; ``tests/test_pool_in_carry.py`` the
+      offsets).
 
     A state that is no page cannot be reused by prefix, rolled back or
     shipped, so for a model with lane state the scheduler never takes

@@ -15,8 +15,10 @@ load the TPU's library and pytest-xdist workers each import every test
 file; compiles run in the test's own process for the same reason.
 """
 
+import math
 import os
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -288,6 +290,138 @@ def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
         destroy_parallel_mesh()  # the global mesh other tests see
     assert "tpu_custom_call" in text
     assert "all-gather" in text or "all-reduce" in text
+
+
+def _llama_step_case(program):
+    """A llama step program at ``deepseek7b-rollout-c16``'s geometry:
+    DeepSeek-LLM-7B's widths (32 MHA heads of 128) at depth 5, the
+    resident bf16 serving copy, 16 lanes, 1152 blocks of 16, tables of
+    64 blocks, prefill chunk 128, verify window 4."""
+    from dlrover_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=102400, dim=4096, n_layers=5, n_heads=32,
+        n_kv_heads=32, mlp_dim=11008, max_seq_len=1024, dtype=BF16,
+    )
+    params = jax.eval_shape(
+        lambda: llama.serving_params(
+            llama.init_params(jax.random.PRNGKey(0), cfg), cfg
+        )
+    )
+    pool_shape = (5, 1152, 16, 32, 128)
+    i32 = jnp.int32
+    lanes = [((16, 64), i32), ((16,), i32), ((16,), jnp.bool_)]
+    if program == "prefill_chunk":
+        fn, rest = llama.paged_prefill_chunk, [
+            ((1, 128), i32), ((64,), i32), ((), i32),
+        ]
+    elif program == "decode":
+        fn, rest = llama.paged_decode_step, [((16,), i32)] + lanes
+    else:
+        fn = (
+            llama.paged_verify_step if program == "verify"
+            else llama.paged_verify_write_step
+        )
+        rest = [((16, WINDOW), i32)] + lanes
+    return partial(fn, cfg=cfg), params, pool_shape, {}, rest, 64 * 2**20
+
+
+def _falcon_h1_step_case(program):
+    """A Falcon-H1 step program at ``falconh1-34b-rollout-c32``'s
+    geometry: the 34B's widths at depth 6, bf16 weights, 32 lanes, 2304
+    blocks of 16 (4 KV heads), float32 lane state, prefill chunk 128."""
+    from dlrover_tpu.models import falcon_h1
+
+    cfg = falcon_h1.FalconH1Config(
+        vocab_size=261120, num_hidden_layers=6, max_seq_len=1024
+    )
+    params = jax.eval_shape(
+        lambda: falcon_h1.serving_params(
+            falcon_h1.init_params(jax.random.PRNGKey(0), cfg), cfg
+        )
+    )
+    pool_shape = (6, 2304, 16, 4, 128)
+    state = {
+        leaf: ((6, 32) + shape, dtype)
+        for leaf, (shape, dtype) in cfg.lane_state().items()
+    }
+    i32 = jnp.int32
+    if program == "prefill_chunk":
+        fn, rest = falcon_h1.paged_prefill_chunk, [
+            ((1, 128), i32), ((64,), i32), ((), i32), ((), i32), ((), i32),
+        ]
+    else:
+        fn, rest = falcon_h1.paged_decode_step, [
+            ((32,), i32), ((32, 64), i32), ((32,), i32), ((32,), jnp.bool_),
+        ]
+    # the prefill chunk's matmuls take each layer's larger matrices as
+    # buffers of their own (w_gate, w_up, w_down 210 MiB each, in_proj
+    # 90: 0.47 GiB live at once; 0.99 with the pool's copies before PR
+    # 28) — weights, not the pool, and not this pin's to forbid
+    temp_limit = (512 if program == "prefill_chunk" else 64) * 2**20
+    return partial(fn, cfg=cfg), params, pool_shape, state, rest, temp_limit
+
+
+STEP_PROGRAMS = {
+    "llama-decode": lambda: _llama_step_case("decode"),
+    "llama-prefill_chunk": lambda: _llama_step_case("prefill_chunk"),
+    "llama-verify_w4": lambda: _llama_step_case("verify"),
+    "llama-verify_write_w4": lambda: _llama_step_case("verify_write"),
+    "falcon_h1-decode": lambda: _falcon_h1_step_case("decode"),
+    "falcon_h1-prefill_chunk": lambda: _falcon_h1_step_case(
+        "prefill_chunk"
+    ),
+}
+
+_MOVES = re.compile(
+    r"= bf16\[([\d,]+)\][^ ]* (copy|dynamic-slice|dynamic-update-slice)\("
+)
+
+
+@pytest.mark.parametrize("program", sorted(STEP_PROGRAMS))
+def test_step_program_carries_the_pool_in_place(
+    program, one_chip, monkeypatch
+):
+    """A serving step program compiled at its cell's geometry with the
+    pool donated: the K/V pool rides in the layer scan's carry
+    (``ops/paged_attention.scan_layers_over_pool``), so the program
+    aliases it to its output, holds next to no temporaries and moves
+    neither the pool nor one layer of it — scanned in and out it was
+    sliced, copied and re-stacked every step (1.97 GiB of temporaries
+    and 9 GB moved a step at C's geometry, PR 28)."""
+    from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV
+
+    # the cells' backend: ``auto`` would read the sandbox's CPU
+    monkeypatch.setenv(PAGED_KERNEL_ENV, "pallas")
+    fn, params, pool_shape, state, rest, temp_limit = STEP_PROGRAMS[
+        program
+    ]()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = {"k": spec(pool_shape, BF16), "v": spec(pool_shape, BF16)}
+    pool.update({leaf: spec(*sd) for leaf, sd in state.items()})
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype), params
+    )
+    tokens, *after = [spec(*sd) for sd in rest]
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, tokens, pool, *after
+    ).compile()
+    mem = compiled.memory_analysis()
+    pool_elems = math.prod(pool_shape)
+    if program != "llama-verify_w4":  # read-only: it returns no pool
+        assert mem.alias_size_in_bytes >= 2 * pool_elems * 2  # k, v bf16
+    assert mem.temp_size_in_bytes < temp_limit
+    moved = [
+        line.strip()[:160]
+        for line in compiled.as_text().splitlines()
+        for m in [_MOVES.search(line)]
+        if m and math.prod(map(int, m.group(1).split(",")))
+        in (pool_elems, pool_elems // pool_shape[0])
+    ]
+    assert not moved, moved
 
 
 def test_ssm_state_is_updated_in_place(one_chip):
