@@ -654,57 +654,6 @@ def _failover_bench(budget: "BenchBudget" = None) -> dict:
     return out
 
 
-def _run_serving_bench(budget: "BenchBudget" = None) -> dict:
-    """Run scripts/bench_serving.py in a subprocess (its replica
-    workers each hold a jax runtime; isolation keeps them off this
-    process's backend) and return its extras + headline speedup:
-    continuous batching vs the sequential request loop, the QPS
-    latency sweep, replica scaling and the kill-mid-load leg."""
-    if os.getenv("DLROVER_BENCH_SKIP_SERVING"):
-        return {"skipped": True}
-    script = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        "scripts", "bench_serving.py",
-    )
-    out_file = os.path.join(
-        tempfile.mkdtemp(prefix="dlrover_bench_serving_"), "out.json"
-    )
-    timeout_s = 600
-    if budget is not None:
-        timeout_s = budget.cap_timeout(600, reserve_s=120)
-    cmd = [sys.executable, script, "--out", out_file]
-    if budget is not None and budget.tight(420):
-        cmd += ["--skip_replica_leg", "--requests", "12"]
-    try:
-        proc = subprocess.run(
-            cmd,
-            capture_output=True,
-            text=True,
-            timeout=timeout_s,
-        )
-        parsed = _read_result_file(out_file, proc.stdout)
-        if parsed is not None and parsed.get("value") is not None:
-            out = dict(parsed.get("extras", {}))
-            out["speedup_vs_sequential"] = parsed.get("value")
-            out["vs_serving_bar_2x"] = parsed.get("vs_baseline")
-            return out
-        if parsed is not None:  # the child died mid-run (early stub)
-            return {
-                "error": f"incomplete run (rc={proc.returncode})",
-                "partial": parsed.get("extras"),
-                "stderr_tail": proc.stderr[-500:],
-            }
-        return {
-            "error": f"no JSON output (rc={proc.returncode})",
-            "stderr_tail": proc.stderr[-500:],
-        }
-    except subprocess.TimeoutExpired as e:
-        # the killed child flushes a partial payload per sweep point
-        return {"error": str(e), "partial": _partial_extras(out_file)}
-    except Exception as e:  # noqa: BLE001
-        return {"error": str(e)}
-
-
 def _run_paged_kernels_bench(budget: "BenchBudget" = None) -> dict:
     """Run scripts/bench_paged_attention.py in a subprocess: decode +
     verify timings under both paged-attention backends (jnp gather
@@ -757,108 +706,6 @@ def _run_paged_kernels_bench(budget: "BenchBudget" = None) -> dict:
         # the killed child flushed a partial payload per sweep point
         # (run_sweep calls flush_fn after each point, not at the end)
         return {"error": str(e), "partial": _read_result_file(out_file, "")}
-    except Exception as e:  # noqa: BLE001
-        return {"error": str(e)}
-
-
-def _run_serving_observatory(budget: "BenchBudget" = None) -> dict:
-    """Run the serving-observatory leg (``bench_serving.py
-    --observatory``) in a subprocess: the ServingHealthEngine must
-    name an injected SLO straggler AND a wedged-mid-decode replica
-    with the right reason inside the interval bound, the timeline
-    must carry a complete preempt->resume request lifecycle through
-    the Perfetto export, and the tracing hot path must stay cheap."""
-    if os.getenv("DLROVER_BENCH_SKIP_SERVING"):
-        return {"skipped": True}
-    script = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        "scripts", "bench_serving.py",
-    )
-    out_file = os.path.join(
-        tempfile.mkdtemp(prefix="dlrover_bench_serving_obs_"),
-        "out.json",
-    )
-    timeout_s = 480
-    if budget is not None:
-        timeout_s = budget.cap_timeout(480, reserve_s=120)
-    cmd = [sys.executable, script, "--observatory", "--out", out_file]
-    if budget is not None and budget.tight(420):
-        cmd += ["--requests", "12"]
-    try:
-        proc = subprocess.run(
-            cmd, capture_output=True, text=True, timeout=timeout_s
-        )
-        parsed = _read_result_file(out_file, proc.stdout)
-        if parsed is not None:
-            obs = (parsed.get("extras") or {}).get("observatory")
-            if obs is not None:
-                det = obs.get("detection") or {}
-                return {
-                    **obs,
-                    "faults_named_in_time": bool(
-                        det.get("both_named")
-                        and det.get("within_3_intervals")
-                    ),
-                }
-            return {
-                "error": f"incomplete run (rc={proc.returncode})",
-                "stderr_tail": proc.stderr[-500:],
-            }
-        return {
-            "error": f"no JSON output (rc={proc.returncode})",
-            "stderr_tail": proc.stderr[-500:],
-        }
-    except subprocess.TimeoutExpired as e:
-        return {"error": str(e), "partial": _partial_extras(out_file)}
-    except Exception as e:  # noqa: BLE001
-        return {"error": str(e)}
-
-
-def _run_serving_fleet(budget: "BenchBudget" = None) -> dict:
-    """Run the fleet leg (``bench_serving.py --fleet``) in a
-    subprocess: open-loop traffic with ``DLROVER_TPU_SERVE_FLEET``
-    on vs off — the affinity hit-rate delta, the SLO-class lane
-    improvement (interactive p99 down, batch throughput held) and
-    the disaggregation decode-flatness delta."""
-    if os.getenv("DLROVER_BENCH_SKIP_SERVING"):
-        return {"skipped": True}
-    script = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        "scripts", "bench_serving.py",
-    )
-    out_file = os.path.join(
-        tempfile.mkdtemp(prefix="dlrover_bench_serving_fleet_"),
-        "out.json",
-    )
-    timeout_s = 600
-    env = dict(os.environ)
-    if budget is not None:
-        timeout_s = budget.cap_timeout(600, reserve_s=120)
-        # the leg scales its per-phase traffic duration from the
-        # budget env; hand it the time actually left for this leg
-        env[BUDGET_ENV] = str(int(max(30, timeout_s - 60)))
-    cmd = [sys.executable, script, "--fleet", "--out", out_file]
-    try:
-        proc = subprocess.run(
-            cmd, capture_output=True, text=True, timeout=timeout_s,
-            env=env,
-        )
-        parsed = _read_result_file(out_file, proc.stdout)
-        if parsed is not None:
-            fleet = (parsed.get("extras") or {}).get("fleet")
-            if fleet is not None and "disagg" in fleet:
-                return fleet
-            return {
-                "error": f"incomplete run (rc={proc.returncode})",
-                "partial": fleet,
-                "stderr_tail": proc.stderr[-500:],
-            }
-        return {
-            "error": f"no JSON output (rc={proc.returncode})",
-            "stderr_tail": proc.stderr[-500:],
-        }
-    except subprocess.TimeoutExpired as e:
-        return {"error": str(e), "partial": _partial_extras(out_file)}
     except Exception as e:  # noqa: BLE001
         return {"error": str(e)}
 
@@ -1029,15 +876,6 @@ def main(argv=None) -> int:
             extras["failover_bench_error"] = str(e)
         flush_partial(args.out, payload)
 
-        # inference plane: continuous batching vs the sequential
-        # request loop + replica scaling + kill-mid-load
-        # (scripts/bench_serving.py)
-        if budget.tight(180):
-            extras["serving"] = {"skipped": "budget"}
-        else:
-            extras["serving"] = _run_serving_bench(budget)
-        flush_partial(args.out, payload)
-
         # paged-attention kernel micro-bench: decode + verify, jnp
         # gather reference vs streamed Pallas kernels, ≥3 context
         # lengths; speedup ratio informational on CPU CI
@@ -1046,28 +884,6 @@ def main(argv=None) -> int:
             extras["paged_kernels"] = {"skipped": "budget"}
         else:
             extras["paged_kernels"] = _run_paged_kernels_bench(budget)
-        flush_partial(args.out, payload)
-
-        # serving observatory: injected straggler + wedge must be
-        # named with the right reason, plus the Perfetto lifecycle
-        # and tracing-overhead proofs (bench_serving.py --observatory
-        # owns the scenario — ONE definition)
-        if budget.tight(240):
-            extras["serving_observatory"] = {"skipped": "budget"}
-        else:
-            extras["serving_observatory"] = _run_serving_observatory(
-                budget
-            )
-        flush_partial(args.out, payload)
-
-        # fleet-level serving: prefix-affinity routing, SLO-class
-        # lanes and disaggregated prefill/decode, each measured as
-        # an on-vs-off delta on the same open-loop traffic
-        # (bench_serving.py --fleet owns the scenario)
-        if budget.tight(240):
-            extras["serving_fleet"] = {"skipped": "budget"}
-        else:
-            extras["serving_fleet"] = _run_serving_fleet(budget)
         flush_partial(args.out, payload)
 
         # RLHF flywheel: in-place publish stall vs the pickle hop,

@@ -195,30 +195,15 @@ def preempt_drain_grace_s() -> float:
     return env_float(PREEMPT_DRAIN_GRACE_ENV, 5.0)
 
 
-SERVING_ENV = "DLROVER_TPU_SERVING"
 GEN_TIMEOUT_ENV = "DLROVER_TPU_GEN_TIMEOUT_S"
 GEN_CLOSE_TIMEOUT_ENV = "DLROVER_TPU_GEN_CLOSE_TIMEOUT_S"
 GEN_BUCKETS_ENV = "DLROVER_TPU_GEN_BUCKETS"
-GEN_BATCHED_PREFILL_ENV = "DLROVER_TPU_GEN_BATCHED_PREFILL"
 SERVING_DRAIN_ENV = "DLROVER_TPU_SERVING_DRAIN_S"
 
 
-def serving_enabled() -> bool:
-    """Kill-switch for the continuous-batching inference plane
-    (``rl/scheduler.py`` + the multi-replica dispatcher in
-    ``rl/generation_service.py``).  ``DLROVER_TPU_SERVING=0``
-    reproduces today's single-worker request/queue loop exactly
-    (``make_generation_engine`` returns the legacy engine; pinned by
-    tests).  Default: enabled."""
-    return os.getenv(SERVING_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
-
-
 def gen_timeout_s() -> float:
-    """Per-request response timeout of the cross-process generation
-    engines (was a hard-coded 600 s in
-    ``CrossProcessGenerationEngine.generate``)."""
+    """Per-request response timeout of the cross-process serving
+    engine (``ServingEngine.result``)."""
     return env_float(GEN_TIMEOUT_ENV, 600.0)
 
 
@@ -250,15 +235,6 @@ def gen_buckets() -> tuple:
     return tuple(sorted(set(b for b in out if b > 0)))
 
 
-def gen_batched_prefill_enabled() -> bool:
-    """Kill-switch for ``KVCacheBackend``'s batched single-forward
-    prefill; ``0`` restores the one-token-at-a-time ``lax.scan``
-    prefill exactly."""
-    return os.getenv(GEN_BATCHED_PREFILL_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
-
-
 def serving_drain_grace_s() -> float:
     """How long a draining serving replica keeps stepping to flush
     responses before handing unfinished sequences back to the
@@ -266,44 +242,11 @@ def serving_drain_grace_s() -> float:
     return env_float(SERVING_DRAIN_ENV, 2.0)
 
 
-SERVE_OBS_ENV = "DLROVER_TPU_SERVE_OBS"
-
-
-def serve_obs_enabled() -> bool:
-    """Kill-switch for the serving observatory (ISSUE 16): per-request
-    lifecycle spans (``serve_request``/``queue_wait``/``admit``/
-    ``resume``), the per-replica TTFT/TBT/e2e/queue-wait SLO
-    histograms on ``/metrics``, and the ``ServingHealthEngine``
-    derivations (SLO-straggler score, dead-air watchdog, KV-pressure
-    streaks).  ``DLROVER_TPU_SERVE_OBS=0`` reproduces the PR-14
-    serving surfaces byte-for-byte — no new spans, gauges, histogram
-    series, or status keys (pinned by tests).  Default: enabled."""
-    return os.getenv(SERVE_OBS_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
-
-
-SERVE_FLEET_ENV = "DLROVER_TPU_SERVE_FLEET"
 FLEET_IMBALANCE_ENV = "DLROVER_TPU_FLEET_IMBALANCE_CAP"
 FLEET_INTERACTIVE_SLOTS_ENV = "DLROVER_TPU_FLEET_INTERACTIVE_SLOTS"
 FLEET_PREFILL_WORKERS_ENV = "DLROVER_TPU_FLEET_PREFILL_WORKERS"
 FLEET_SHIP_SLOTS_ENV = "DLROVER_TPU_FLEET_SHIP_SLOTS"
 FLEET_MIN_SHIP_PROMPT_ENV = "DLROVER_TPU_FLEET_MIN_SHIP_PROMPT"
-
-
-def serve_fleet_enabled() -> bool:
-    """Kill-switch for the fleet-level serving layer (ISSUE 17):
-    prefix-affinity routing in the dispatcher (per-replica shared-block
-    key index piggybacked on the STATS ring), SLO-class lanes with
-    per-tenant fair-share admission + class-aware preemption in the
-    scheduler, and the disaggregated prefill/decode split with shm KV
-    block shipping.  ``DLROVER_TPU_SERVE_FLEET=0`` reproduces the
-    PR-16 surfaces exactly: least-outstanding routing, single-class
-    FIFO admission, no ship spans, no fleet gauges (pinned by tests).
-    Default: enabled."""
-    return os.getenv(SERVE_FLEET_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def fleet_imbalance_cap() -> int:
@@ -344,25 +287,9 @@ def fleet_min_ship_prompt() -> int:
     return max(0, int(env_float(FLEET_MIN_SHIP_PROMPT_ENV, 0)))
 
 
-KV_INCREMENTAL_ENV = "DLROVER_TPU_KV_INCREMENTAL"
 KV_GROW_BLOCKS_ENV = "DLROVER_TPU_KV_GROW_BLOCKS"
 KV_ADMIT_WATERMARK_ENV = "DLROVER_TPU_KV_ADMIT_WATERMARK"
-KV_PREFIX_CACHE_ENV = "DLROVER_TPU_KV_PREFIX_CACHE"
 DECODE_STEPS_ENV = "DLROVER_TPU_DECODE_STEPS"
-
-
-def kv_incremental_enabled() -> bool:
-    """Kill-switch for the incremental-allocation serving discipline
-    (watermark admission + on-demand block growth + lowest-priority
-    sequence preemption + prefix caching in ``rl/scheduler.py`` /
-    ``rl/kv_cache.py``).  ``DLROVER_TPU_KV_INCREMENTAL=0`` reproduces
-    the PR-13 worst-case reservation admission byte-for-byte (admit
-    only when ``ceil((prompt + max_new) / block_size)`` blocks are
-    free; no growth, no preemption, no shared blocks — pinned by
-    tests).  Default: enabled."""
-    return os.getenv(KV_INCREMENTAL_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def kv_grow_blocks() -> int:
@@ -374,25 +301,13 @@ def kv_grow_blocks() -> int:
 
 
 def kv_admit_watermark() -> float:
-    """Watermark admission (incremental mode): a new sequence is
-    admitted only if, after its initial allocation, at least this
+    """Watermark admission: a new sequence is admitted only if,
+    after its initial allocation, at least this
     FRACTION of the usable pool stays free as growth headroom for the
     sequences already running.  0 = admit whenever the initial
     allocation fits (maximum admission, maximum preemption churn).
     The first sequence always admits regardless (progress)."""
     return min(max(env_float(KV_ADMIT_WATERMARK_ENV, 0.1), 0.0), 0.9)
-
-
-def kv_prefix_cache_enabled() -> bool:
-    """Prefix caching (incremental mode only): content-hash full
-    prompt blocks into a ref-counted shared-block index so requests
-    with a common prompt prefix map the same physical blocks.
-    ``DLROVER_TPU_KV_PREFIX_CACHE=0`` disables sharing while keeping
-    incremental allocation.  Default: enabled (inert unless
-    ``kv_incremental_enabled()``)."""
-    return os.getenv(KV_PREFIX_CACHE_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def decode_steps() -> int:
@@ -582,7 +497,6 @@ def control_snapshot_interval_s() -> float:
         return 20.0
 
 
-FLYWHEEL_ENV = "DLROVER_TPU_FLYWHEEL"
 FLYWHEEL_STALENESS_ENV = "DLROVER_TPU_FLYWHEEL_STALENESS"
 FLYWHEEL_MAX_LAG_ENV = "DLROVER_TPU_FLYWHEEL_MAX_LAG"
 FLYWHEEL_PUBLISH_EVERY_ENV = "DLROVER_TPU_FLYWHEEL_PUBLISH_EVERY"
@@ -590,23 +504,6 @@ FLYWHEEL_DRAFT_ENV = "DLROVER_TPU_FLYWHEEL_DRAFT"
 FLYWHEEL_LEND_QUEUE_ENV = "DLROVER_TPU_FLYWHEEL_LEND_QUEUE"
 FLYWHEEL_RECLAIM_QUEUE_ENV = "DLROVER_TPU_FLYWHEEL_RECLAIM_QUEUE"
 FLYWHEEL_MIN_TRAIN_ENV = "DLROVER_TPU_FLYWHEEL_MIN_TRAIN_WORLD"
-
-
-def flywheel_enabled() -> bool:
-    """Kill-switch for the zero-copy RLHF flywheel (ISSUE 20): the
-    in-place K-step weight publish into the shm snapshot segment
-    (generation-stamped header + replica-side adopt-if-changed), the
-    shm trajectory ring feeding rollouts back as ready training
-    batches, the separate published DRAFT model for speculative
-    decode, and the Brain's ``FlywheelOperator`` train/serve device
-    arbitration.  ``DLROVER_TPU_FLYWHEEL=0`` reproduces today's
-    separate planes byte-for-byte: unconditional ``get_step()``
-    adoption polling, self-drafting speculative decode, no trajectory
-    ring, no plane-labeled scale decisions (pinned by tests).
-    Default: enabled."""
-    return os.getenv(FLYWHEEL_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def flywheel_staleness_policy() -> str:
@@ -637,8 +534,7 @@ def flywheel_draft_enabled() -> bool:
     """Whether the flywheel trains + publishes a separate small DRAFT
     model for K-step speculative decode (the PR-14 residual; today
     the model drafts with itself).  Inert unless the serving factory
-    supplies draft-model parts.  Default: enabled (under
-    ``flywheel_enabled()``)."""
+    supplies draft-model parts.  Default: enabled."""
     return os.getenv(FLYWHEEL_DRAFT_ENV, "1").lower() not in (
         "0", "false", "off",
     )

@@ -92,8 +92,7 @@ class MasterServicer:
         self._telemetry = telemetry
         #: zero-arg callable returning the serving plane's status dict
         #: (``ServingEngine.status()``); None = no co-located serving
-        #: engine or DLROVER_TPU_SERVE_OBS=0 — the ``serving`` status
-        #: section is simply absent (pinned pre-16 shape)
+        #: engine — the ``serving`` status section is simply absent
         self._serving_status_fn = serving_status_fn
         #: the parked-wait cap scales with the pool: half the workers
         #: may park, so mutations always find a free one

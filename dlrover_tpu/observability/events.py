@@ -489,8 +489,8 @@ REQUIRED_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
 #: any other keyword at their emit sites).  ``serve_step``: the
 #: partition of the iteration's host time into the ``sched.*`` leaves
 #: (milliseconds; the five sum to the span's ``dur``) and the lane
-#: counts behind batch occupancy — written only under
-#: ``DLROVER_TPU_SERVE_OBS``, so they are optional.
+#: counts behind batch occupancy — every record the scheduler writes
+#: carries them; they stay optional for readers of older records.
 OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
     PHASE_SERVE_STEP: (
         "admit_ms",

@@ -478,12 +478,7 @@ def record_serving_latency(
     the quantile source for ``/status`` and the SLO-straggler
     derivation.  ``tbt_p99_s`` observations are the request-level
     per-token-gap p99 (one sample per request, not per token — the
-    series is a distribution over requests).  Inert when
-    ``DLROVER_TPU_SERVE_OBS=0`` (no series created).  Never raises."""
-    from dlrover_tpu.common.env import serve_obs_enabled
-
-    if not serve_obs_enabled():
-        return
+    series is a distribution over requests).  Never raises."""
     try:
         reg = get_registry()
         labels = {"replica": replica}

@@ -53,7 +53,7 @@ class StatusServer:
         #: health engine's
         self._telemetry = telemetry
         #: zero-arg serving-plane refresh hook (None = no co-located
-        #: serving engine or DLROVER_TPU_SERVE_OBS=0): lets a scrape
+        #: serving engine): lets a scrape
         #: pull the replica gauges/health current before rendering
         self._serving_refresh = serving_refresh
         self._httpd: Optional[ThreadingHTTPServer] = None

@@ -28,11 +28,6 @@ serialize/deserialize hops on either leg:
 - **Device arbitration** lives in
   ``master/flywheel_operator.FlywheelOperator`` (the Brain side);
   this module only exposes the plane gauges it consumes.
-
-``DLROVER_TPU_FLYWHEEL=0`` disables the layer wholesale: the engine
-strips capture/draft from its spec, never touches the generation
-segment, and this coordinator refuses to build — today's separate
-planes reproduce byte-for-byte.
 """
 
 import os
@@ -43,7 +38,6 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from dlrover_tpu.common.env import (
-    flywheel_enabled,
     flywheel_max_lag,
     flywheel_publish_every,
     flywheel_staleness_policy,
@@ -182,11 +176,6 @@ class FlywheelCoordinator:
     """The trainer-side hub of the flywheel: paced in-place weight
     publishes out, streamed trajectories in.
 
-    Construction requires ``DLROVER_TPU_FLYWHEEL`` enabled — with the
-    kill switch off the RLHF loop must run today's separate planes,
-    and a half-built coordinator would silently re-enable part of the
-    layer.
-
     The trajectory stream is an shm ring (the PR-4 substrate): the
     producer side (``offer_result`` — typically the thread collecting
     ``ServingEngine.result``) and the consumer side (``drain`` — the
@@ -205,11 +194,6 @@ class FlywheelCoordinator:
         journal_path: Optional[str] = None,
         create: bool = True,
     ):
-        if not flywheel_enabled():
-            raise RuntimeError(
-                "DLROVER_TPU_FLYWHEEL=0: the flywheel layer is "
-                "disabled; run the separate train/serve planes"
-            )
         from dlrover_tpu.observability.events import get_event_logger
         from dlrover_tpu.rl.generation_service import _Ring
 

@@ -1,5 +1,5 @@
-"""Cross-process generation: the legacy single-worker engine and the
-continuous-batching multi-replica serving plane.
+"""Cross-process generation: the continuous-batching multi-replica
+serving plane.
 
 Reference parity: ``atorch/atorch/rl/inference_backend/
 vllm_backend.py`` — actor weights are SHIPPED to a dedicated vLLM
@@ -19,23 +19,17 @@ serving engine, not pointer-shared — plus ``rl/ds_hybrid_engine/``
   ``restore_to_target`` device_puts every leaf onto them in one
   batched call (train-side layouts never leak into the generator).
 
-Two serving shapes share this module:
-
-- :class:`CrossProcessGenerationEngine` — the legacy single-worker
-  request/queue loop (one whole batch to completion per request).
-  ``DLROVER_TPU_SERVING=0`` pins exactly this path.
-- :class:`ServingEngine` — N replica workers, each running the
-  token-level continuous-batching scheduler (``rl/scheduler.py``)
-  over a paged KV cache, behind a dispatcher with per-replica
-  shm-ring request/response transport (the PR-4 zero-copy path —
-  prompts and sampled tokens never pickle through a socket).
-  Replicas are first-class elastic workloads: SIGUSR1/SIGTERM drains
-  a replica (unfinished sequences requeue onto survivors — sampling
-  is (seed, position)-pure, so a requeued tail is the same tail), a
-  SIGKILL'd replica's in-flight requests redispatch automatically,
-  and completions dedup by request id so every request finishes
-  exactly once.  ``make_generation_engine`` picks the shape from the
-  environment.
+:class:`ServingEngine` is the one serving shape: N replica workers,
+each running the token-level continuous-batching scheduler
+(``rl/scheduler.py``) over a paged KV cache, behind a dispatcher with
+per-replica shm-ring request/response transport (the PR-4 zero-copy
+path — prompts and sampled tokens never pickle through a socket).
+Replicas are first-class elastic workloads: SIGUSR1/SIGTERM drains a
+replica (unfinished sequences requeue onto survivors — sampling is
+(seed, position)-pure, so a requeued tail is the same tail), a
+SIGKILL'd replica's in-flight requests redispatch automatically, and
+completions dedup by request id so every request finishes exactly
+once.
 """
 
 import json
@@ -58,9 +52,6 @@ from dlrover_tpu.common.env import (
     fleet_ship_slots,
     gen_close_timeout_s,
     gen_timeout_s,
-    serve_fleet_enabled,
-    serve_obs_enabled,
-    serving_enabled,
 )
 from dlrover_tpu.common.log import default_logger as logger
 
@@ -78,8 +69,7 @@ _KIND_REJECT = 4
 # a prefill worker finished filling a request's KV blocks and staged
 # them in the ship arena: meta carries the slot + block count and
 # tokens[0] the first sampled token — the dispatcher relays the
-# manifest to a decode replica (disaggregated fleet only; never
-# emitted with DLROVER_TPU_SERVE_FLEET=0)
+# manifest to a decode replica (disaggregated fleet only)
 _KIND_SHIP = 5
 # a DRAINING replica hands one unfinished request back WITH its
 # generated-so-far tail (+ per-token logprobs): the dispatcher stores
@@ -102,7 +92,7 @@ _FINISH_NAMES = {v: k for k, v in _FINISH_CODES.items()}
 #: logprobs (NaN where unknown) — and response meta carries
 #: [req_id, kind, total_len, new_tokens, finish_code, weights_version,
 #: schema_version, ship_slot, n_blocks] plus a ``logprobs`` f4 vector
-#: (per sampled token, flywheel capture mode only; zeros otherwise).
+#: (per sampled token, capture mode only; zeros otherwise).
 #: ship_mode: 0 = serve
 #: locally, 1 = prefill-and-ship (the replica fills the KV blocks,
 #: stages them in the ship arena slot and answers _KIND_SHIP),
@@ -192,7 +182,7 @@ def tiny_llama_factory(**cfg_kwargs):
     a factory must provide for the replica to serve a model:
 
     - ``forward_fn(params, tokens) -> logits``: the whole-sequence
-      forward (the legacy single-worker loop samples with it);
+      forward (the reference a served tail is checked against);
     - ``params_template_fn() -> tree``: the weights the replica HOLDS,
       inference-sharded — the target every shm adoption restores onto,
       and what the scheduler serves until the first publish;
@@ -281,102 +271,14 @@ def falcon_h1_factory(**cfg_kwargs):
     }
 
 
-# --------------------------------------------------------------------------
-# legacy single-worker loop (DLROVER_TPU_SERVING=0 pins this path)
-# --------------------------------------------------------------------------
-
-
-def _legacy_worker_loop(spec) -> int:
-    import jax
-    import jax.numpy as jnp
-
-    from dlrover_tpu.agent.ckpt_shm import (
-        SharedMemoryHandler,
-        restore_to_target,
-    )
-    from dlrover_tpu.common.multi_process import SharedQueue
-    from dlrover_tpu.rl.inference import JitSamplerBackend
-
-    name = spec["name"]
-    factory = _import_factory(spec["factory"])
-    parts = factory(**spec.get("factory_kwargs", {}))
-    backend = JitSamplerBackend(
-        parts["forward_fn"],
-        max_new_tokens=int(spec["max_new_tokens"]),
-        temperature=float(spec.get("temperature", 1.0)),
-    )
-    template = parts["params_template_fn"]()
-
-    shm = SharedMemoryHandler(rank=0, name=name)
-    req = SharedQueue(f"{name}-req", create=False)
-    resp = SharedQueue(f"{name}-resp", create=False)
-    version = -1
-    handoff_s = 0.0
-    resp.put({"ready": True, "pid": os.getpid()})
-    logger.info("generation worker %s ready (pid %d)", name,
-                os.getpid())
-    while True:
-        msg = req.get()
-        cmd = msg.get("cmd")
-        if cmd == "stop":
-            resp.put({"stopped": True})
-            return 0
-        if cmd != "generate":
-            resp.put({"error": f"unknown cmd {cmd!r}"})
-            continue
-        # a bad request (ragged prompts, shape-mismatched publish)
-        # must answer {"error": ...}, not kill the worker — a dead
-        # worker leaves every later client call blocking to timeout
-        try:
-            # weight refresh: adopt the newest published snapshot.
-            # restore_to_target device_puts onto the TEMPLATE's
-            # shardings — this is where the train layout reshards to
-            # the inference layout (ref: ds_hybrid_engine's
-            # train<->infer repartition)
-            t0 = time.perf_counter()
-            step, arrays = shm.load_state(copy=False)
-            if step > version:
-                template = restore_to_target(
-                    template, arrays, to_device=True, copy_host=True
-                )
-                jax.block_until_ready(template)
-                backend.sync_weights(template)
-                version = step
-                handoff_s = time.perf_counter() - t0
-            del arrays
-            prompts = jnp.asarray(msg["prompts"])
-            rng = jax.random.PRNGKey(int(msg.get("seed", 0)))
-            t1 = time.perf_counter()
-            tokens = np.asarray(backend.generate(prompts, rng))
-            gen_s = max(time.perf_counter() - t1, 1e-9)
-            new_tokens = tokens.shape[1] - prompts.shape[1]
-            resp.put(
-                {
-                    "tokens": tokens,
-                    "version": version,
-                    "handoff_s": round(handoff_s, 6),
-                    "gen_s": round(gen_s, 6),
-                    "tokens_per_s": round(
-                        tokens.shape[0] * new_tokens / gen_s, 2
-                    ),
-                }
-            )
-        except Exception as e:  # noqa: BLE001 - per-request isolation
-            logger.error("generation request failed: %s", e)
-            resp.put({"error": f"{type(e).__name__}: {e}"})
-
-
 def worker_main() -> int:
     """Generation-process entry (``python -m
     dlrover_tpu.rl.generation_service``); spec arrives via env."""
-    spec = json.loads(os.environ[WORKER_SPEC_ENV])
-    if spec.get("mode") == "serve":
-        return _serving_worker_loop(spec)
-    return _legacy_worker_loop(spec)
+    return _serving_worker_loop(json.loads(os.environ[WORKER_SPEC_ENV]))
 
 
 def _worker_env(spec) -> Dict[str, str]:
-    """Environment of a spawned generation/serving worker.  The parent
+    """Environment of a spawned serving replica.  The parent
     never asks JAX which backend it has (on a TPU that would take the
     chip the worker needs): the worker's platform is whatever the
     environment this process was given says (``JAX_PLATFORMS``), and
@@ -387,146 +289,6 @@ def _worker_env(spec) -> Dict[str, str]:
     env[WORKER_SPEC_ENV] = json.dumps(spec)
     export_compile_cache(env)
     return env
-
-
-class CrossProcessGenerationEngine:
-    """Trainer-side handle on the generation process.
-
-    Same surface as the in-process backends (``sync_weights`` /
-    ``generate``) so PPO code swaps engines without edits; the
-    difference is that ``sync_weights`` PUBLISHES the policy through
-    shm (no pointer sharing) and ``generate`` is served by the worker
-    process.  ``last_stats`` carries the serving metrics of the most
-    recent call.
-    """
-
-    def __init__(
-        self,
-        factory: str,
-        max_new_tokens: int,
-        temperature: float = 1.0,
-        factory_kwargs: Optional[Dict] = None,
-        name: Optional[str] = None,
-        start_timeout: float = 300.0,
-    ):
-        from dlrover_tpu.agent.ckpt_shm import SharedMemoryHandler
-        from dlrover_tpu.common.multi_process import SharedQueue
-
-        self._name = name or f"gen-{os.getpid()}"
-        # trainer side hosts the meta service + queues (it outlives
-        # worker restarts)
-        self._shm = SharedMemoryHandler(
-            rank=0, name=self._name, host=True
-        )
-        self._req = SharedQueue(f"{self._name}-req", create=True)
-        self._resp = SharedQueue(f"{self._name}-resp", create=True)
-        self._version = 0
-        self.last_stats: Dict = {}
-        self.publish_s = 0.0
-
-        spec = {
-            "name": self._name,
-            "factory": factory,
-            "factory_kwargs": factory_kwargs or {},
-            "max_new_tokens": int(max_new_tokens),
-            "temperature": float(temperature),
-        }
-        self._proc = subprocess.Popen(
-            [sys.executable, "-m", "dlrover_tpu.rl.generation_service"],
-            env=_worker_env(spec),
-        )
-        ready = self._resp.get(timeout=start_timeout)
-        if not ready.get("ready"):
-            raise RuntimeError(f"generation worker failed: {ready}")
-        logger.info(
-            "cross-process generation engine %s up (worker pid %s)",
-            self._name, ready.get("pid"),
-        )
-
-    # ------------------------------------------------------------ API
-    def sync_weights(self, params) -> float:
-        """Publish the actor params through the shm substrate; the
-        worker adopts them before serving the next request.  Returns
-        the publish (snapshot) latency in seconds."""
-        self._version += 1
-        t0 = time.perf_counter()
-        self._shm.save_state(self._version, params)
-        self.publish_s = time.perf_counter() - t0
-        return self.publish_s
-
-    def generate(self, prompts, rng=None, seed: Optional[int] = None):
-        if seed is None:
-            seed = 0
-            if rng is not None:
-                import jax
-
-                seed = int(
-                    np.asarray(jax.random.key_data(rng)).ravel()[-1]
-                )
-        self._req.put(
-            {
-                "cmd": "generate",
-                "prompts": np.asarray(prompts),
-                "seed": int(seed),
-            }
-        )
-        out = self._get_response(timeout=gen_timeout_s())
-        if "error" in out:
-            raise RuntimeError(out["error"])
-        self.last_stats = {
-            k: out[k]
-            for k in ("version", "handoff_s", "gen_s", "tokens_per_s")
-        }
-        return out["tokens"]
-
-    def _get_response(self, timeout: float, poll: float = 2.0) -> Dict:
-        """Wait for the worker's response, watching the worker process:
-        a dead worker must fail the call IMMEDIATELY with its exit
-        code, not block the trainer for the full queue timeout
-        (ADVICE-r5: generate() after a worker crash hung 600 s)."""
-        import queue as _queue
-
-        deadline = time.time() + timeout
-        while True:
-            try:
-                return self._resp.get(
-                    timeout=min(poll, max(deadline - time.time(), 0.1))
-                )
-            except _queue.Empty:
-                rc = self._proc.poll()
-                if rc is not None:
-                    # the worker may have answered and THEN exited
-                    # (queue flush is async): drain once more before
-                    # declaring the request dead
-                    try:
-                        return self._resp.get(timeout=1.0)
-                    except _queue.Empty:
-                        pass
-                    raise RuntimeError(
-                        f"generation worker {self._name} died with "
-                        f"exit code {rc} while serving a request"
-                    ) from None
-                if time.time() >= deadline:
-                    raise TimeoutError(
-                        f"generation worker {self._name} gave no "
-                        f"response within {timeout}s"
-                    ) from None
-
-    def close(self):
-        timeout = gen_close_timeout_s()
-        try:
-            self._req.put({"cmd": "stop"})
-            self._resp.get(timeout=timeout)
-        except Exception:  # noqa: BLE001 - worker may be dead already
-            pass
-        if self._proc.poll() is None:
-            try:
-                self._proc.wait(timeout=timeout)
-            except subprocess.TimeoutExpired:
-                self._proc.kill()
-        self._shm.close(unlink=True)
-        self._req.close()
-        self._resp.close()
 
 
 # --------------------------------------------------------------------------
@@ -661,9 +423,11 @@ def _serving_worker_loop(spec) -> int:
         SharedMemoryHandler,
         restore_to_target,
     )
-    from dlrover_tpu.common.env import serve_fleet_enabled
     from dlrover_tpu.observability.events import get_event_logger
-    from dlrover_tpu.observability.metrics import record_serving
+    from dlrover_tpu.observability.metrics import (
+        Histogram,
+        record_serving,
+    )
     from dlrover_tpu.rl.kv_cache import region_nbytes_per_block
     from dlrover_tpu.rl.scheduler import (
         ContinuousBatchingScheduler,
@@ -673,8 +437,7 @@ def _serving_worker_loop(spec) -> int:
     name = spec["name"]
     replica = int(spec["replica"])
     tag = f"{name}-r{replica}"
-    fleet = serve_fleet_enabled()
-    role = str(spec.get("role", "unified")) if fleet else "unified"
+    role = str(spec.get("role", "unified"))
     if role == "prefill":
         # prefill workers are throughput devices — on a host shared
         # with decode replicas they must never steal CPU from a
@@ -703,10 +466,9 @@ def _serving_worker_loop(spec) -> int:
             "model config the paged decode programs build from)"
         )
     s = spec["sched"]
-    # flywheel layer (ISSUE 20): logprob capture (the trajectory
-    # stream's old_logp source) and the separately-published draft
-    # model — both absent from the spec under DLROVER_TPU_FLYWHEEL=0,
-    # so the scheduler compiles exactly the pre-flywheel programs
+    # logprob capture (the trajectory stream's old_logp source) and
+    # the separately-published draft model: what the engine was asked
+    # for through ``capture_logprobs=`` / a ``draft`` sub-dict
     fly = spec.get("flywheel") or {}
     draft_cfg = parts.get("draft_cfg")
     scheduler = ContinuousBatchingScheduler(
@@ -732,13 +494,8 @@ def _serving_worker_loop(spec) -> int:
         draft_cfg=draft_cfg,
     )
     events = get_event_logger()
-    serve_obs = serve_obs_enabled()
-    ttft_hist = None
-    if serve_obs:
-        from dlrover_tpu.observability.metrics import Histogram
-
-        ttft_hist = Histogram()
-    # chaos seam for the observatory bench (spec["faults"], keyed by
+    ttft_hist = Histogram()
+    # chaos seam of the health tests (spec["faults"], keyed by
     # replica index): "sleep_s" stalls every scheduler iteration (an
     # SLO straggler — slow but progressing), "wedge_after_tokens"
     # freezes the loop outright once N tokens were sampled (dead air —
@@ -834,8 +591,8 @@ def _serving_worker_loop(spec) -> int:
             if gen <= gen_seen:
                 return
         else:
-            # no generation segment (pre-flywheel publisher, or
-            # DLROVER_TPU_FLYWHEEL=0): the legacy meta-RPC probe
+            # no generation segment (a publisher that never bumps
+            # one): the meta-RPC probe
             meta_rpcs += 1
             try:
                 step = shm.get_step()
@@ -921,8 +678,7 @@ def _serving_worker_loop(spec) -> int:
             )
 
     def _flush_result(res):
-        if ttft_hist is not None:
-            ttft_hist.observe(res.stats.get("ttft_s", 0.0))
+        ttft_hist.observe(res.stats.get("ttft_s", 0.0))
         _respond(
             _KIND_RESULT,
             req_id=res.req_id,
@@ -1111,24 +867,19 @@ def _serving_worker_loop(spec) -> int:
                     accepted_tokens_per_step=st["accepted_per_step"],
                 )
                 # the dispatcher-side serving pane reads the same numbers
-                # off the response ring (best-effort); with the fleet
-                # layer on, the replica's shared-block key index and its
-                # cumulative prefix counters ride along — the affinity
-                # router's whole view, no extra RPC
-                stats_tokens = None
-                if fleet:
-                    digs = [
-                        _key_digest(k)
-                        for k in list(
-                            scheduler.block_pool._shared_by_key
-                        )[-(max_total - 1):]
-                    ]
-                    stats_tokens = np.asarray(
-                        [len(digs)] + digs, np.int32
-                    )
+                # off the response ring (best-effort); the replica's
+                # shared-block key index and its cumulative prefix
+                # counters ride along — the affinity router's whole
+                # view, no extra RPC
+                digs = [
+                    _key_digest(k)
+                    for k in list(
+                        scheduler.block_pool._shared_by_key
+                    )[-(max_total - 1):]
+                ]
                 _respond(
                     _KIND_STATS,
-                    tokens=stats_tokens,
+                    tokens=np.asarray([len(digs)] + digs, np.int32),
                     times=(
                         tps,
                         float(scheduler.queue_depth),
@@ -1137,10 +888,7 @@ def _serving_worker_loop(spec) -> int:
                         float(st["preemptions"]),
                         float(st["prefix_hit_rate"]),
                         float(st["accepted_per_step"]),
-                        (
-                            ttft_hist.quantile(0.99)
-                            if ttft_hist is not None else 0.0
-                        ),
+                        ttft_hist.quantile(0.99),
                         float(scheduler.block_pool.prefix_hits),
                         float(scheduler.block_pool.prefix_queries),
                         float(adoptions),
@@ -1260,8 +1008,8 @@ class _Replica:
 class ServingEngine:
     """The continuous-batching serving plane: N replicas behind a
     dispatcher.  ``submit``/``result`` is the streaming surface;
-    ``generate`` keeps the legacy whole-batch surface so PPO rollouts
-    and ``examples/generate.py --serve`` swap engines without edits.
+    ``generate`` is the whole-batch surface of the in-process backends
+    (``rl/inference.py``), so PPO rollouts swap engines without edits.
 
     Elasticity: ``drain_replica`` (SIGUSR1) / ``close`` (SIGTERM)
     drain; a replica that dies ANY way hands its uncompleted requests
@@ -1291,8 +1039,8 @@ class ServingEngine:
         capture_logprobs: bool = False,
     ):
         from dlrover_tpu.agent.ckpt_shm import SharedMemoryHandler
-        from dlrover_tpu.common.env import flywheel_enabled
         from dlrover_tpu.common.multi_process import SOCKET_DIR_ENV
+        from dlrover_tpu.observability.health import ServingHealthEngine
         from dlrover_tpu.observability.metrics import Histogram
 
         self._name = name or f"serve-{os.getpid()}"
@@ -1315,32 +1063,17 @@ class ServingEngine:
         self._lock = threading.Lock()
         self._closed = False
         self._latency = Histogram()
-        # serving observatory (ISSUE 16), pinned at construction:
-        # per-request SLO histograms in the registry, mirrored
-        # per-replica gauges, and the ServingHealthEngine derivations
-        # — all absent under DLROVER_TPU_SERVE_OBS=0
-        self._serve_obs = serve_obs_enabled()
-        self._health = None
-        if self._serve_obs:
-            from dlrover_tpu.observability.health import (
-                ServingHealthEngine,
-            )
-
-            self._health = ServingHealthEngine()
-        # flywheel layer (ISSUE 20), pinned at construction: logprob
-        # capture (the trajectory stream's old_logp), the co-published
-        # draft model (a "draft" sub-dict in factory_kwargs) and the
-        # generation side-segment fast path.  DLROVER_TPU_FLYWHEEL=0
-        # strips all three, reproducing the pre-flywheel plane.
-        self._flywheel = flywheel_enabled()
-        factory_kwargs = dict(factory_kwargs or {})
-        if not self._flywheel:
-            capture_logprobs = False
-            factory_kwargs.pop("draft", None)
+        # serving observatory: per-request SLO histograms in the
+        # registry, mirrored per-replica gauges, and the
+        # ServingHealthEngine derivations
+        self._health = ServingHealthEngine()
+        # logprob capture (the trajectory stream's old_logp) and the
+        # co-published draft model (a "draft" sub-dict in
+        # factory_kwargs) are what the caller asks for
+        factory_kwargs = factory_kwargs or {}
         self._capture = bool(capture_logprobs)
         self._draft_mode = bool(factory_kwargs.get("draft"))
         self._spec = {
-            "mode": "serve",
             "name": self._name,
             "factory": factory,
             "factory_kwargs": factory_kwargs,
@@ -1358,17 +1091,15 @@ class ServingEngine:
                 "eos_id": eos_id,
             },
         }
-        if self._flywheel and (self._capture or self._draft_mode):
+        if self._capture or self._draft_mode:
             self._spec["flywheel"] = {"capture": self._capture}
-        # fleet layer (ISSUE 17), pinned at construction: affinity
-        # routing + SLO lanes + optional prefill/decode split.  OFF
-        # (DLROVER_TPU_SERVE_FLEET=0) reproduces the PR-16 dispatcher
-        # exactly: least-outstanding, one class, no ship arena.
-        self._fleet = serve_fleet_enabled()
+        # fleet layer: affinity routing + SLO lanes + optional
+        # prefill/decode split
         self._imbalance_cap = fleet_imbalance_cap()
-        n_pref = fleet_prefill_workers() if self._fleet else 0
         # at least one decode replica must remain, whatever the env
-        self._n_prefill = max(0, min(n_pref, int(num_replicas) - 1))
+        self._n_prefill = max(
+            0, min(fleet_prefill_workers(), int(num_replicas) - 1)
+        )
         self._min_ship_prompt = fleet_min_ship_prompt()
         self._ship_nslots = fleet_ship_slots()
         self._ship_arena = None
@@ -1431,10 +1162,7 @@ class ServingEngine:
                 num_slots=8,
                 create=True,
             )
-        role = (
-            "prefill"
-            if self._fleet and idx < self._n_prefill else "decode"
-        )
+        role = "prefill" if idx < self._n_prefill else "decode"
         spec = dict(self._spec, replica=idx, role=role)
         env = _worker_env(spec)
         if self._socket_dir:
@@ -1496,8 +1224,8 @@ class ServingEngine:
         draft mode (a ``draft`` sub-dict in ``factory_kwargs``) the
         policy and the drafter co-publish as ONE combined tree —
         ``draft_params`` is then required every call, since replicas
-        restore onto a combined template.  With the flywheel layer on
-        the generation side-segment is bumped AFTER the save
+        restore onto a combined template.  The generation
+        side-segment is bumped AFTER the save
         completes, so replicas detect the new snapshot with one
         atomic-width load instead of a meta RPC per iteration — and a
         publisher killed mid-save never bumps it (replicas keep the
@@ -1518,8 +1246,7 @@ class ServingEngine:
         self._version += 1
         t0 = time.perf_counter()
         self._shm.save_state(self._version, params)
-        if self._flywheel:
-            self._shm.publish_generation(self._version)
+        self._shm.publish_generation(self._version)
         self.publish_s = time.perf_counter() - t0
         return self.publish_s
 
@@ -1530,7 +1257,7 @@ class ServingEngine:
         """Queue one prompt; returns the request id.  ``slo_class``
         ("interactive" gets the reserved decode-slot lanes and
         preempts last) and ``tenant`` (the fair-share key within a
-        class) only act with the fleet layer on.  ``resume_tokens``
+        class) steer the scheduler's lanes.  ``resume_tokens``
         (a previously generated tail — e.g. carried across an engine
         restart) makes the replica re-prefill [prompt|tail] through
         its block-hash cache and continue from there instead of
@@ -1567,17 +1294,19 @@ class ServingEngine:
                 f"prompt {prompt.size} + max_new {max_new} exceeds "
                 f"max_seq_len {self._max_seq_len}"
             )
-        # the replica scheduler's incremental-mode pool guard,
-        # enforced HERE with the SAME definition
+        # the replica scheduler's pool guard, enforced HERE with the
+        # SAME definition
         # (kv_cache.pool_can_ever_hold): a request whose worst case
         # exceeds a replica's whole pool would otherwise be refused
         # inside the worker — answered as a rejection, but only after
         # burning a dispatch — so fail it at the front door
-        from dlrover_tpu.common.env import kv_incremental_enabled
-        from dlrover_tpu.rl.kv_cache import pool_can_ever_hold
+        from dlrover_tpu.rl.kv_cache import (
+            pool_can_ever_hold,
+            prefix_block_keys,
+        )
 
         s = self._spec["sched"]
-        if kv_incremental_enabled() and not pool_can_ever_hold(
+        if not pool_can_ever_hold(
             int(s["num_blocks"]), int(s["block_size"]),
             prompt.size + max_new,
         ):
@@ -1586,18 +1315,14 @@ class ServingEngine:
                 f"the replica pool of {int(s['num_blocks']) - 1} "
                 "blocks"
             )
-        digests: tuple = ()
-        if getattr(self, "_fleet", False):
-            # the prompt's chain-key digests are the affinity
-            # router's match input — computed once, at the front door
-            from dlrover_tpu.rl.kv_cache import prefix_block_keys
-
-            digests = tuple(
-                _key_digest(k)
-                for k in prefix_block_keys(
-                    prompt, int(s["block_size"])
-                )[:64]
-            )
+        # the prompt's chain-key digests are the affinity router's
+        # match input — computed once, at the front door
+        digests = tuple(
+            _key_digest(k)
+            for k in prefix_block_keys(
+                prompt, int(s["block_size"])
+            )[:64]
+        )
         with self._lock:
             req_id = self._next_id
             self._next_id += 1
@@ -1648,8 +1373,8 @@ class ServingEngine:
         return res
 
     def generate(self, prompts, rng=None, seed: Optional[int] = None):
-        """Legacy whole-batch surface: [B, P] in, [B, P + max_new]
-        out.  Per-row sampling seeds derive from ``seed`` + row."""
+        """Whole-batch surface: [B, P] in, [B, P + max_new] out.
+        Per-row sampling seeds derive from ``seed`` + row."""
         if seed is None:
             seed = 0
             if rng is not None:
@@ -1764,27 +1489,24 @@ class ServingEngine:
                 continue
             if kind == _KIND_STATS:
                 rep.stats = _parse_stats(msg["times"], meta[6])
-                if self._fleet:
-                    # the piggybacked shared-block key index + the
-                    # fleet hit-rate deltas (cumulative counters so a
-                    # dropped STATS window loses nothing)
-                    k = int(msg["tokens"][0])
-                    rep.prefix_keys = {
-                        int(x) for x in msg["tokens"][1:1 + k]
-                    }
-                    hits = float(msg["times"][8])
-                    looks = float(msg["times"][9])
-                    ph, pl = rep.last_prefix
-                    if hits >= ph and looks >= pl:
-                        self._fleet_hits += hits - ph
-                        self._fleet_lookups += looks - pl
-                    rep.last_prefix = (hits, looks)
-                if self._serve_obs:
-                    rep.stats["ttft_p99_s"] = round(
-                        float(msg["times"][7]), 4
-                    )
-                    if self._health is not None:
-                        self._health.note_stats(rep.idx, rep.stats)
+                # the piggybacked shared-block key index + the fleet
+                # hit-rate deltas (cumulative counters so a dropped
+                # STATS window loses nothing)
+                k = int(msg["tokens"][0])
+                rep.prefix_keys = {
+                    int(x) for x in msg["tokens"][1:1 + k]
+                }
+                hits = float(msg["times"][8])
+                looks = float(msg["times"][9])
+                ph, pl = rep.last_prefix
+                if hits >= ph and looks >= pl:
+                    self._fleet_hits += hits - ph
+                    self._fleet_lookups += looks - pl
+                rep.last_prefix = (hits, looks)
+                rep.stats["ttft_p99_s"] = round(
+                    float(msg["times"][7]), 4
+                )
+                self._health.note_stats(rep.idx, rep.stats)
                 continue
             if kind == _KIND_SHIP:
                 # a prefill worker staged this request's KV blocks:
@@ -1795,9 +1517,8 @@ class ServingEngine:
                     (req_id, int(meta[7]), int(meta[8]),
                      int(msg["tokens"][0]))
                 )
-                if self._health is not None:
-                    # a ship IS the prefill worker's completion
-                    self._health.note_ship(rep.idx)
+                # a ship IS the prefill worker's completion
+                self._health.note_ship(rep.idx)
                 continue
             if kind == _KIND_REQUEUE:
                 # a draining replica handed this request back with
@@ -1868,34 +1589,24 @@ class ServingEngine:
                     "replica": rep.idx,
                 },
             )
-            if self._serve_obs:
-                from dlrover_tpu.observability.metrics import (
-                    record_serving_latency,
-                )
+            from dlrover_tpu.observability.metrics import (
+                record_serving_latency,
+            )
 
-                ttft = float(msg["times"][1])
-                tbt = float(msg["times"][4])
-                qwait = float(msg["times"][5])
-                record_serving_latency(
-                    replica=str(rep.idx),
-                    ttft_s=ttft,
-                    tbt_p99_s=tbt,
-                    e2e_s=latency,
-                    queue_wait_s=qwait,
-                )
-                if self._health is not None:
-                    self._health.note_result(
-                        rep.idx, ttft_s=ttft, tbt_p99_s=tbt,
-                        e2e_s=latency, queue_wait_s=qwait,
-                    )
+            slo = dict(
+                ttft_s=float(msg["times"][1]),
+                tbt_p99_s=float(msg["times"][4]),
+                e2e_s=latency,
+                queue_wait_s=float(msg["times"][5]),
+            )
+            record_serving_latency(replica=str(rep.idx), **slo)
+            self._health.note_result(rep.idx, **slo)
 
     def _retire_replica_series(self, rep: _Replica):
         """Zero-and-drop a dead/drained replica's per-replica series
         (the mirrored gauges AND the SLO histograms) from this
         process's registry: a frozen last value on ``/metrics`` reads
         as a live replica — absence reads as the death it is."""
-        if not self._serve_obs:
-            return
         try:
             from dlrover_tpu.observability.metrics import get_registry
 
@@ -1965,7 +1676,7 @@ class ServingEngine:
         least-loaded — affinity must never starve a replica — else
         the PR-13 least-outstanding rule.  Returns ``(replica,
         route_code)``."""
-        if not self._fleet or not req.digests or len(targets) < 2:
+        if not req.digests or len(targets) < 2:
             return least_outstanding(targets), 0
         floor = min(len(r.outstanding) for r in targets)
         best, best_depth = None, 0
@@ -2015,12 +1726,28 @@ class ServingEngine:
             r for r in self._replicas
             if r.alive and r.ready and not r.draining
         ]
-        if self._fleet and self._n_prefill:
-            prefill_alive = [r for r in alive if r.role == "prefill"]
-            targets = [r for r in alive if r.role != "prefill"]
-        else:
-            prefill_alive = []
-            targets = alive
+        prefill_alive = [r for r in alive if r.role == "prefill"]
+        targets = [r for r in alive if r.role != "prefill"]
+        if self._dispatch_q and not any(
+            r.alive for r in self._replicas
+        ):
+            # nothing is left (or starting) that could ever serve
+            # these: fail them now, not at the request timeout
+            with self._lock:
+                stranded = list(self._dispatch_q)
+                self._dispatch_q.clear()
+            for req_id in stranded:
+                self._complete(
+                    req_id,
+                    {
+                        "error": (
+                            f"request {req_id} cannot be served: no "
+                            "replica is alive (exit codes "
+                            f"{[r.proc.returncode for r in self._replicas]})"
+                        )
+                    },
+                )
+            moved += len(stranded)
         # relay staged manifests first: a parked manifest holds an
         # arena slot and its request's clock has been running since
         # submit — the decode replica splices the blocks and starts a
@@ -2111,60 +1838,57 @@ class ServingEngine:
                 kv_blocks_used=None,
                 p99_latency_s=self._latency.quantile(0.99),
             )
-            if self._fleet:
-                # fleet-level prefix hit rate: windowed over the
-                # STATS deltas accumulated since the last tick with
-                # lookups in it (an idle window keeps the last value
-                # instead of flapping to 0)
-                if self._fleet_lookups > 0:
-                    self._fleet_hit_rate = (
-                        self._fleet_hits / self._fleet_lookups
-                    )
-                    self._fleet_hits = 0.0
-                    self._fleet_lookups = 0.0
-                record_serving(
-                    replica="fleet",
-                    tokens_per_s=None,
-                    queue_depth=None,
-                    kv_blocks_used=None,
-                    prefix_hit_rate=self._fleet_hit_rate,
+            # fleet-level prefix hit rate: windowed over the STATS
+            # deltas accumulated since the last tick with lookups in
+            # it (an idle window keeps the last value instead of
+            # flapping to 0)
+            if self._fleet_lookups > 0:
+                self._fleet_hit_rate = (
+                    self._fleet_hits / self._fleet_lookups
                 )
-            if self._serve_obs:
-                # mirror each live replica's newest STATS into THIS
-                # process's registry so the engine's /metrics carries
-                # the fleet (the per-replica series retirement on
-                # death/drain acts here)
-                for rep in self._replicas:
-                    if not rep.alive or rep.drained or not rep.stats:
-                        continue
-                    st = rep.stats
-                    record_serving(
-                        replica=str(rep.idx),
-                        tokens_per_s=st.get("tokens_per_s"),
-                        queue_depth=st.get("queue_depth"),
-                        kv_blocks_used=st.get("kv_blocks_used"),
-                        kv_utilization=st.get("kv_utilization"),
-                        preemptions=st.get("preemptions"),
-                        prefix_hit_rate=st.get("prefix_hit_rate"),
-                        accepted_tokens_per_step=st.get(
-                            "accepted_per_step"
-                        ),
-                    )
-        if self._health is not None:
-            # internally throttled to the derivation interval
-            self._health.evaluate(
-                [
-                    {
-                        "idx": r.idx,
-                        "alive": r.alive,
-                        "drained": r.drained,
-                        "outstanding": len(r.outstanding),
-                        "role": r.role,
-                        **r.stats,
-                    }
-                    for r in self._replicas
-                ]
+                self._fleet_hits = 0.0
+                self._fleet_lookups = 0.0
+            record_serving(
+                replica="fleet",
+                tokens_per_s=None,
+                queue_depth=None,
+                kv_blocks_used=None,
+                prefix_hit_rate=self._fleet_hit_rate,
             )
+            # mirror each live replica's newest STATS into THIS
+            # process's registry so the engine's /metrics carries the
+            # fleet (the per-replica series retirement on death/drain
+            # acts here)
+            for rep in self._replicas:
+                if not rep.alive or rep.drained or not rep.stats:
+                    continue
+                st = rep.stats
+                record_serving(
+                    replica=str(rep.idx),
+                    tokens_per_s=st.get("tokens_per_s"),
+                    queue_depth=st.get("queue_depth"),
+                    kv_blocks_used=st.get("kv_blocks_used"),
+                    kv_utilization=st.get("kv_utilization"),
+                    preemptions=st.get("preemptions"),
+                    prefix_hit_rate=st.get("prefix_hit_rate"),
+                    accepted_tokens_per_step=st.get(
+                        "accepted_per_step"
+                    ),
+                )
+        # internally throttled to the derivation interval
+        self._health.evaluate(
+            [
+                {
+                    "idx": r.idx,
+                    "alive": r.alive,
+                    "drained": r.drained,
+                    "outstanding": len(r.outstanding),
+                    "role": r.role,
+                    **r.stats,
+                }
+                for r in self._replicas
+            ]
+        )
         return moved
 
     # --------------------------------------------------------- status
@@ -2191,26 +1915,20 @@ class ServingEngine:
         return merged.quantile(q) if merged is not None else 0.0
 
     def status(self) -> Dict:
-        """The serving pane: what ``scripts/top.py`` renders and the
-        bench snapshots.  With the observatory on, ``slo`` carries the
-        fleet quantiles off the registry histograms and ``health`` the
-        ServingHealthEngine's newest per-replica derivations; both
-        keys are ABSENT under DLROVER_TPU_SERVE_OBS=0 (pinned)."""
-        out = {
+        """The serving pane: what ``scripts/top.py`` renders.  ``slo``
+        carries the fleet quantiles off the registry histograms and
+        ``health`` the ServingHealthEngine's newest per-replica
+        derivations."""
+        return {
             "replicas": [
                 dict(
-                    dict(
-                        {
-                            "idx": r.idx,
-                            "alive": r.alive,
-                            "drained": r.drained,
-                            "outstanding": len(r.outstanding),
-                        },
-                        # the role column only exists when the fleet
-                        # layer could have split roles (OFF pins the
-                        # PR-16 row shape exactly)
-                        **({"role": r.role} if self._fleet else {}),
-                    ),
+                    {
+                        "idx": r.idx,
+                        "alive": r.alive,
+                        "drained": r.drained,
+                        "outstanding": len(r.outstanding),
+                        "role": r.role,
+                    },
                     **r.stats,
                 )
                 for r in self._replicas
@@ -2220,9 +1938,7 @@ class ServingEngine:
             "p50_latency_s": round(self._latency.quantile(0.5), 4),
             "p99_latency_s": round(self._latency.quantile(0.99), 4),
             "version": self._version,
-        }
-        if self._serve_obs:
-            out["slo"] = {
+            "slo": {
                 "ttft_p99_s": round(self._slo_quantile(
                     "dlrover_tpu_serving_ttft_seconds", 0.99
                 ), 4),
@@ -2235,14 +1951,12 @@ class ServingEngine:
                 "queue_wait_p99_s": round(self._slo_quantile(
                     "dlrover_tpu_serving_queue_wait_seconds", 0.99
                 ), 4),
-            }
-            if self._fleet:
-                out["slo"]["fleet_prefix_hit_rate"] = round(
+                "fleet_prefix_hit_rate": round(
                     self._fleet_hit_rate, 4
-                )
-            if self._health is not None:
-                out["health"] = self._health.snapshot()
-        return out
+                ),
+            },
+            "health": self._health.snapshot(),
+        }
 
     def close(self):
         if self._closed:
@@ -2271,35 +1985,6 @@ class ServingEngine:
             except Exception:  # noqa: BLE001 - already gone is fine
                 pass
         self._shm.close(unlink=True)
-
-
-def make_generation_engine(
-    factory: str,
-    max_new_tokens: int,
-    **kwargs,
-):
-    """The serving-plane selector: :class:`ServingEngine` (continuous
-    batching, multi-replica) unless ``DLROVER_TPU_SERVING=0`` pins the
-    legacy single-worker request/queue loop.  Extra kwargs route to
-    whichever engine is chosen (unknown ones are dropped for the
-    legacy engine, whose surface is frozen)."""
-    if serving_enabled():
-        return ServingEngine(factory, max_new_tokens, **kwargs)
-    legacy_keys = (
-        "temperature", "factory_kwargs", "name", "start_timeout",
-    )
-    legacy_kwargs = {
-        k: v for k, v in kwargs.items() if k in legacy_keys
-    }
-    dropped = sorted(set(kwargs) - set(legacy_kwargs))
-    if dropped:
-        logger.info(
-            "DLROVER_TPU_SERVING=0: legacy engine ignores %s",
-            dropped,
-        )
-    return CrossProcessGenerationEngine(
-        factory, max_new_tokens, **legacy_kwargs
-    )
 
 
 if __name__ == "__main__":

@@ -13,8 +13,7 @@ duals:
   The prefill is a single batched forward that fills the whole cache
   in one call when the model provides one (``models.llama.prefill``);
   models without a prefill fn fall back to feeding the prompt one
-  token at a time through ``lax.scan``
-  (``DLROVER_TPU_GEN_BATCHED_PREFILL=0`` forces the scan path).
+  token at a time through ``lax.scan``.
 
 Both expose ``generate(params, prompts, rng)`` and take their weights
 directly from the live train state (``sync_weights`` is a pointer
@@ -42,10 +41,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.common.env import (
-    gen_batched_prefill_enabled,
-    gen_buckets,
-)
+from dlrover_tpu.common.env import gen_buckets
 
 
 def bucket_len(plen: int, buckets: Tuple[int, ...]) -> int:
@@ -160,8 +156,6 @@ class KVCacheBackend(InferenceBackend):
                 if default_model
                 else None
             )
-        if not gen_batched_prefill_enabled():
-            prefill_fn = None
         self._prefill = prefill_fn
         self._generate = jax.jit(self._build())
         self._compiled_fn = self._generate

@@ -14,18 +14,12 @@ exactly once.
 Block 0 is the NULL block: never allocated, the scatter/gather target
 for inactive lanes and unwritten table entries (always masked).
 
-Two allocation disciplines share the pool (the scheduler picks via
-``DLROVER_TPU_KV_INCREMENTAL``):
+Allocation is incremental (vLLM-style): admit on prompt blocks + a
+small headroom, :meth:`extend` the table on demand at decode time, and
+let the scheduler preempt the lowest-priority sequence when the pool
+runs dry.
 
-- **reservation** (PR-13, the kill-switch path): :meth:`allocate`
-  reserves a sequence's worst case up front, so decode can never die
-  of exhaustion — at the price of reserved-but-unfilled capacity;
-- **incremental** (vLLM-style): admit on prompt blocks + a small
-  headroom, :meth:`extend` the table on demand at decode time, and
-  let the scheduler preempt the lowest-priority sequence when the
-  pool runs dry.
-
-**Prefix caching** rides the incremental discipline: a FULL prompt
+**Prefix caching**: a FULL prompt
 block is content-addressed by a chained hash of its tokens
 (:func:`prefix_block_keys`) and registered in a ref-counted
 shared-block index, so N requests with a common system-prompt prefix
@@ -37,8 +31,7 @@ LRU cache (content retained for future hits) and is evicted back to
 the free list only under allocation pressure, oldest first.
 
 Accounting (the observatory's ``kv_blocks_used`` /
-``kv_utilization`` gauges and the fragmentation / hit-rate lines in
-``scripts/bench_serving.py`` read these):
+``kv_utilization`` gauges read these):
 
 - ``used_blocks`` / ``free_blocks`` — pool occupancy;
 - ``internal_fragmentation()`` — reserved-but-unfilled token slots as
@@ -46,8 +39,8 @@ Accounting (the observatory's ``kv_blocks_used`` /
   paging keeps bounded at < ``block_size`` tokens/sequence where the
   dense slab wastes ``max_len - len`` per sequence);
 - ``utilization()`` — filled cache positions as a share of the whole
-  pool's capacity (the number reservation admission caps far below
-  1.0 and incremental admission pushes toward it);
+  pool's capacity (the number incremental admission pushes toward
+  1.0);
 - ``prefix_hits`` / ``prefix_queries`` — shared-block lookups.
 """
 
@@ -68,7 +61,7 @@ def pool_can_ever_hold(num_blocks: int, block_size: int,
                        n_tokens: int) -> bool:
     """Can a pool of ``num_blocks`` (INCLUDING its null block 0) ever
     hold one sequence of ``n_tokens``?  The ONE definition of the
-    incremental-mode worst-case admission guard — the scheduler's
+    worst-case admission guard — the scheduler's
     ``submit`` and the serving dispatcher's ``submit`` must agree, or
     an oversized request slips past the dispatcher and kills the
     replica whose scheduler then refuses it."""
@@ -268,8 +261,8 @@ def insert_block_regions(
 
 class OutOfBlocksError(RuntimeError):
     """The pool cannot satisfy an allocation — admission control
-    should have checked :meth:`BlockPool.can_allocate` first (or, in
-    incremental mode, preempted a running sequence)."""
+    should have checked :meth:`BlockPool.can_allocate` first, or
+    preempted a running sequence."""
 
 
 class DoubleFreeError(RuntimeError):
@@ -524,11 +517,9 @@ class BlockPool:
         prefix_blocks: Optional[List[int]] = None,
     ) -> List[int]:
         """Reserve blocks for ``n_tokens`` cache positions (plus
-        ``extra_blocks`` growth headroom).  Under reservation
-        admission the scheduler passes the worst case (prompt +
-        max_new) so decode can never die of pool exhaustion
-        mid-flight; under incremental admission it passes the prompt
-        plus a small headroom and grows on demand via :meth:`extend`.
+        ``extra_blocks`` growth headroom): the scheduler passes the
+        prompt plus a small headroom and grows on demand via
+        :meth:`extend`.
         ``prefix_blocks`` (already acquired via
         :meth:`acquire_prefix`) become the leading table entries; only
         the remainder is newly allocated."""

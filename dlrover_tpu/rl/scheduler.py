@@ -2,9 +2,9 @@
 
 Reference parity: Orca's iteration-level scheduling + vLLM's block
 tables — the serving loop the reference system gets from its vLLM
-backend.  The repo's request/queue loop (``generation_service``'s
-single worker) serves one whole batch to completion before admitting
-the next request; here scheduling happens at TOKEN granularity:
+backend.  A whole-batch backend (``rl/inference.py``) serves one
+batch to completion before the next; here scheduling happens at TOKEN
+granularity:
 
 - the batch is ``max_slots`` fixed LANES, each holding (or not) one
   live sequence — an active-mask, never a shape change;
@@ -22,23 +22,19 @@ the next request; here scheduling happens at TOKEN granularity:
   SAME iteration — mixed-length traffic never waits for the longest
   sequence in a batch (the dense-batch pathology this replaces).
 
-Allocation disciplines (``DLROVER_TPU_KV_INCREMENTAL``, default on):
-
-- **incremental** (vLLM-style): admission reserves only the prompt's
-  blocks plus ``DLROVER_TPU_KV_GROW_BLOCKS`` headroom and is gated by
-  a free-pool watermark (``DLROVER_TPU_KV_ADMIT_WATERMARK``); block
-  tables grow on demand at decode time, and when the pool runs dry
-  the LOWEST-PRIORITY running sequence (fewest tokens generated,
-  youngest admission) is PREEMPTED — its blocks freed, the request
-  requeued at the queue head carrying its generated tail, so it
-  re-prefills and resumes deterministically (sampling is a pure
-  function of (seed, position), so the final tokens are identical —
-  pinned by test).  Prefix caching rides this mode: full prompt
-  blocks are content-hashed into the pool's ref-counted shared-block
-  index, so a repeated system prompt maps the same physical blocks.
-- **reservation** (``=0``, the PR-13 kill-switch path): admission
-  reserves the worst case (prompt + max_new) up front — no growth, no
-  preemption, no sharing; byte-for-byte the old behavior.
+Allocation is incremental (vLLM-style): admission reserves only the
+prompt's blocks plus ``DLROVER_TPU_KV_GROW_BLOCKS`` headroom and is
+gated by a free-pool watermark (``DLROVER_TPU_KV_ADMIT_WATERMARK``);
+block tables grow on demand at decode time, and when the pool runs dry
+the LOWEST-PRIORITY running sequence (fewest tokens generated,
+youngest admission) is PREEMPTED — its blocks freed, the request
+requeued at the queue head carrying its generated tail, so it
+re-prefills and resumes deterministically (sampling is a pure
+function of (seed, position), so the final tokens are identical —
+pinned by test).  Full prompt blocks are content-hashed into the
+pool's ref-counted shared-block index, so a repeated system prompt
+maps the same physical blocks — unless the model declares per-lane
+state (``lane_state()``), which cannot be shared by prefix.
 
 Multi-token decode (``DLROVER_TPU_DECODE_STEPS=K``, default 1): one
 fused compiled program runs K greedy self-drafting decode steps plus
@@ -70,10 +66,6 @@ from dlrover_tpu.common.env import (
     fleet_interactive_slots,
     kv_admit_watermark,
     kv_grow_blocks,
-    kv_incremental_enabled,
-    kv_prefix_cache_enabled,
-    serve_fleet_enabled,
-    serve_obs_enabled,
 )
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.observability.events import EventLogger
@@ -148,8 +140,7 @@ class GenRequest:
     resume_logprobs: np.ndarray = field(
         default_factory=_empty_logprobs
     )
-    # request-tracing state (ISSUE 16; inert when
-    # DLROVER_TPU_SERVE_OBS=0).  ``submit_wall`` is the wall-clock
+    # request-tracing state.  ``submit_wall`` is the wall-clock
     # anchor that rode the dispatcher→replica ring (0 = in-process
     # submit, fall back to this process's anchored clock); the rest
     # survive preemption so the serve_request span tells the request's
@@ -159,9 +150,8 @@ class GenRequest:
     hit_blocks: int = 0
     queue_wait_s: float = 0.0
     token_times: List[float] = field(default_factory=list)
-    # fleet-serving lanes (ISSUE 17; inert when
-    # DLROVER_TPU_SERVE_FLEET=0): the SLO class steers admission
-    # order, the reserved-slot quota, and preemption rank; the tenant
+    # serving lanes: the SLO class steers admission order, the
+    # reserved-slot quota, and preemption rank; the tenant
     # key drives weighted fair-share within a class.  ``shipped`` is
     # the disaggregated-decode adoption payload (prefilled KV block
     # regions + the first sampled token) — consumed at admission,
@@ -302,11 +292,8 @@ class ContinuousBatchingScheduler:
         if s.prefill_chunk < 1 or s.max_slots < 1:
             raise ValueError("prefill_chunk and max_slots must be >= 1")
         self._events = events
-        # request-lifecycle tracing (ISSUE 16): pinned at construction
-        # like the allocation discipline — a scheduler never changes
-        # observability personality mid-flight.  ``replica`` labels the
-        # serve_request spans with where the request actually ran.
-        self._serve_obs = serve_obs_enabled()
+        # ``replica`` labels the serve_request spans with where the
+        # request actually ran
         self.replica = replica
         self._last_prefill_req = -1
         # the partition of each iteration's host time (ISSUE 24): what
@@ -352,9 +339,7 @@ class ContinuousBatchingScheduler:
         self._verify_model = paged_verify_fn or partial(
             llama.paged_verify_step, cfg=model_cfg
         )
-        # flywheel extensions (ISSUE 20): both OFF by default — the
-        # no-flag construction compiles exactly the closures above, so
-        # DLROVER_TPU_FLYWHEEL=0 callers reproduce today's programs.
+        # flywheel extensions, both off unless asked for.
         # ``capture_logprobs``: every sampled token also returns its
         # actor logprob (log-softmax of the RAW fp32 logits — the
         # trainer's ``token_logprobs`` semantics, so streamed tails
@@ -384,27 +369,19 @@ class ContinuousBatchingScheduler:
             or partial(llama.paged_verify_write_step, cfg=model_cfg)
         )
 
-        # allocation/decode discipline (env-pinned at construction so
-        # a scheduler never changes personality mid-flight)
-        self.incremental = kv_incremental_enabled()
+        # tuning values, read once at construction
         self.grow_blocks = kv_grow_blocks()
         self.admit_watermark = kv_admit_watermark()
-        self._prefix_cache_asked = (
-            self.incremental and kv_prefix_cache_enabled()
-        )
         self.decode_k = decode_steps()
-        # fleet lanes (ISSUE 17) — pinned at construction like the
-        # allocation discipline.  ``role``: "unified" (default) serves
-        # prefill+decode in place; "prefill" stops at prefill
-        # completion and parks the filled block regions + first token
-        # on ``self.shipped`` for the worker loop to ship out.
-        self.fleet = serve_fleet_enabled()
+        # ``role``: "unified" (default) serves prefill+decode in place;
+        # "prefill" stops at prefill completion and parks the filled
+        # block regions + first token on ``self.shipped`` for the
+        # worker loop to ship out.
         if role not in ("unified", "prefill"):
             raise ValueError(f"unknown scheduler role {role!r}")
-        self.role = role if self.fleet else "unified"
-        self.interactive_slots = (
-            min(fleet_interactive_slots(), s.max_slots - 1)
-            if self.fleet else 0
+        self.role = role
+        self.interactive_slots = min(
+            fleet_interactive_slots(), s.max_slots - 1
         )
         self.shipped: List[Dict] = []
         self.shipped_out = 0
@@ -453,9 +430,7 @@ class ContinuousBatchingScheduler:
                         f"beside its paged K/V and cannot be served "
                         f"with {why}"
                     )
-        self.prefix_cache = (
-            self._prefix_cache_asked and not self.lane_state
-        )
+        self.prefix_cache = not self.lane_state
         self.prefix_hits_skipped = 0
         self.state_resets = 0
         self._step_state_resets = 0
@@ -831,14 +806,14 @@ class ContinuousBatchingScheduler:
                 f"prompt {prompt.size} + max_new {max_new} exceeds "
                 f"max_seq_len {self.sched.max_seq_len}"
             )
-        if self.incremental and not pool_can_ever_hold(
+        if not pool_can_ever_hold(
             self.pool_cfg.num_blocks,
             self.pool_cfg.block_size,
             prompt.size + max_new,
         ):
-            # under incremental allocation a lone sequence must be
-            # able to run to its budget after preempting everyone
-            # else; a worst case bigger than the whole pool can't
+            # a lone sequence must be able to run to its budget after
+            # preempting everyone else; a worst case bigger than the
+            # whole pool can't
             raise ValueError(
                 f"prompt {prompt.size} + max_new {max_new} needs "
                 f"{self.pool_cfg.blocks_for(prompt.size + max_new)} "
@@ -884,7 +859,7 @@ class ContinuousBatchingScheduler:
                        # re-prefill deterministically instead
                        shipped=(
                            shipped
-                           if self.fleet and not resume.size
+                           if not resume.size
                            # K/V blocks alone cannot seed a lane that
                            # also keeps a state: prefill here instead
                            and not self.lane_state else None
@@ -946,7 +921,6 @@ class ContinuousBatchingScheduler:
             grown_blocks=self.grown_blocks,
             dispatches=self.dispatches,
             decode_steps=self.decode_k,
-            incremental=int(self.incremental),
             accepted_tokens=self.accepted_tokens,
             lane_windows=self.lane_windows,
             accepted_per_step=round(
@@ -989,17 +963,6 @@ class ContinuousBatchingScheduler:
         )
         plen = int(prefill_tokens.size)
         total = int(req.prompt.size) + int(req.max_new)
-        if not self.incremental:
-            # PR-13 reservation admission: the worst case must fit
-            if not self.block_pool.can_allocate(total):
-                return None
-            return {
-                "prefill_tokens": prefill_tokens,
-                "n_tokens": total,
-                "extra": 0,
-                "keys": [],
-                "peek_hits": 0,
-            }
         keys: List[str] = []
         peek = peek_lru = 0
         if self.prefix_cache and req.shipped is None:
@@ -1042,21 +1005,17 @@ class ContinuousBatchingScheduler:
         }
 
     def _pick_next_index(self) -> Optional[int]:
-        """Which queued request admits next.  Fleet OFF: index 0 —
-        the PR-14 FIFO head-of-line rule exactly (pinned by tests).
-        Fleet ON (SLO-class lanes): interactive before batch; while
-        interactive work is in flight, batch admission is capped so
-        ``interactive_slots`` decode slots stay reserved for the
-        interactive lane (an idle interactive lane does NOT strand
-        slots — batch fills every slot until the next interactive
-        arrival, which admission then favors and which class-aware
-        preemption can make room for); within a class the tenant with
-        the fewest active slots wins (weighted fair share), FIFO
-        breaking tenant ties."""
+        """Which queued request admits next (SLO-class lanes):
+        interactive before batch; while interactive work is in flight,
+        batch admission is capped so ``interactive_slots`` decode
+        slots stay reserved for the interactive lane (an idle
+        interactive lane does NOT strand slots — batch fills every
+        slot until the next interactive arrival, which admission then
+        favors and which class-aware preemption can make room for);
+        within a class the tenant with the fewest active slots wins
+        (weighted fair share), FIFO breaking tenant ties."""
         if not self._queue:
             return None
-        if not self.fleet:
-            return 0
         active_cls: Dict[str, int] = {}
         active_tenant: Dict = {}
         for sl in self._slots:
@@ -1120,8 +1079,8 @@ class ContinuousBatchingScheduler:
             req = self._queue[qi]
             plan = self._admissible(req)
             if plan is None:
-                # head-of-line (and, fleet on, pool-blocked pick):
-                # later (smaller) requests must not starve it forever
+                # the pool-blocked pick: later (smaller) requests
+                # must not starve it forever
                 return
             admit_t0 = time.monotonic()
             self._queue.pop(qi)
@@ -1135,7 +1094,7 @@ class ContinuousBatchingScheduler:
                 self.block_pool.acquire_prefix(plan["keys"])
                 if plan["keys"] else []
             )
-            if self.lane_state and self._prefix_cache_asked:
+            if self.lane_state:
                 # the index was never asked: a hit would skip tokens
                 # whose state exists nowhere
                 self.prefix_hits_skipped += 1
@@ -1183,8 +1142,7 @@ class ContinuousBatchingScheduler:
             self.block_pool.note_filled(req.req_id, sl.prefill_pos)
             self._window_hit_blocks += n_hit
             req.hit_blocks += n_hit
-            if self._serve_obs:
-                self._trace_admit(req, admit_t0)
+            self._trace_admit(req, admit_t0)
 
     def _adopt(self, slot: int, req: GenRequest, plan: Dict,
                admit_t0: float,
@@ -1231,8 +1189,7 @@ class ContinuousBatchingScheduler:
                 self.block_pool.share_block(
                     req.req_id, idx, keys[idx]
                 )
-        if self._serve_obs:
-            self._trace_admit(req, admit_t0)
+        self._trace_admit(req, admit_t0)
         first = int(payload["first_token"])
         self._next_token[slot] = first
         self._append_token(
@@ -1288,51 +1245,45 @@ class ContinuousBatchingScheduler:
         tokens = np.concatenate(
             [req.prompt, np.asarray(sl.generated, np.int32)]
         )
+        gaps = [
+            req.token_times[i + 1] - req.token_times[i]
+            for i in range(len(req.token_times) - 1)
+        ]
         stats = {
             "ttft_s": round(
                 max(sl.first_token_t - req.submit_t, 0.0), 6
             ),
+            "tbt_p99_s": round(
+                float(np.percentile(gaps, 99)) if gaps else 0.0, 6
+            ),
+            "queue_wait_s": round(req.queue_wait_s, 6),
+            "preempts": req.preempts,
+            "prefix_hit_blocks": req.hit_blocks,
         }
-        if self._serve_obs:
-            gaps = [
-                req.token_times[i + 1] - req.token_times[i]
-                for i in range(len(req.token_times) - 1)
-            ]
-            tbt_p99 = (
-                float(np.percentile(gaps, 99)) if gaps else 0.0
+        if self._events is not None and self._events.enabled:
+            from dlrover_tpu.observability.events import anchored_now
+
+            end_wall = anchored_now(now)
+            start_wall = (
+                req.submit_wall if req.submit_wall > 0.0
+                else anchored_now(req.submit_t)
             )
-            stats.update(
-                tbt_p99_s=round(tbt_p99, 6),
-                queue_wait_s=round(req.queue_wait_s, 6),
+            self._events.complete(
+                "serve_request",
+                start_wall,
+                max(end_wall - start_wall, 1e-9),
+                req_id=req.req_id,
+                replica=self.replica,
+                prompt_tokens=int(req.prompt.size),
+                gen_tokens=len(sl.generated),
+                ttft_s=stats["ttft_s"],
+                tbt_p99_s=stats["tbt_p99_s"],
                 preempts=req.preempts,
                 prefix_hit_blocks=req.hit_blocks,
+                route=req.route,
+                slo_class=req.slo_class,
+                finish_reason=reason,
             )
-            if self._events is not None and self._events.enabled:
-                from dlrover_tpu.observability.events import (
-                    anchored_now,
-                )
-
-                end_wall = anchored_now(now)
-                start_wall = (
-                    req.submit_wall if req.submit_wall > 0.0
-                    else anchored_now(req.submit_t)
-                )
-                self._events.complete(
-                    "serve_request",
-                    start_wall,
-                    max(end_wall - start_wall, 1e-9),
-                    req_id=req.req_id,
-                    replica=self.replica,
-                    prompt_tokens=int(req.prompt.size),
-                    gen_tokens=len(sl.generated),
-                    ttft_s=stats["ttft_s"],
-                    tbt_p99_s=stats["tbt_p99_s"],
-                    preempts=req.preempts,
-                    prefix_hit_blocks=req.hit_blocks,
-                    route=req.route,
-                    slo_class=req.slo_class,
-                    finish_reason=reason,
-                )
         finished.append(
             GenResult(
                 req_id=req.req_id,
@@ -1398,16 +1349,13 @@ class ContinuousBatchingScheduler:
             from dlrover_tpu.observability.events import anchored_now
 
             dur = max(time.monotonic() - t0, 1e-9)
-            extra = (
-                {"req_id": req.req_id} if self._serve_obs else {}
-            )
             self._events.complete(
                 "preempt",
                 anchored_now(t0),
                 dur,
                 blocks_freed=n_blocks,
                 tokens_generated=int(resume.size),
-                **extra,
+                req_id=req.req_id,
             )
         logger.info(
             "preempted seq %d (pool dry): freed %d block(s), "
@@ -1416,44 +1364,33 @@ class ContinuousBatchingScheduler:
         )
 
     def _pick_victim(self, exclude: int) -> Optional[int]:
-        """Lowest-priority live sequence: fewest tokens generated,
-        tie broken youngest-admission-first.  Fleet on, the rule is
-        CLASS-AWARE first: every batch lane outranks every interactive
-        lane as a victim (batch preempts before interactive, never the
-        reverse at equal KV pressure — pinned by test); within a class
-        the PR-14 rule applies unchanged."""
+        """Lowest-priority live sequence, CLASS-AWARE first: every
+        batch lane outranks every interactive lane as a victim (batch
+        preempts before interactive, never the reverse at equal KV
+        pressure — pinned by test); within a class fewest tokens
+        generated, tie broken youngest-admission-first."""
         candidates = [
             i for i, sl in enumerate(self._slots)
             if sl.req is not None and i != exclude
         ]
         if not candidates:
             return None
-        if self.fleet:
-            return min(
-                candidates,
-                key=lambda i: (
-                    0 if self._slots[i].req.slo_class
-                    != SLO_INTERACTIVE else 1,
-                    len(self._slots[i].generated),
-                    -self._slots[i].admit_seq,
-                ),
-            )
         return min(
             candidates,
             key=lambda i: (
+                0 if self._slots[i].req.slo_class
+                != SLO_INTERACTIVE else 1,
                 len(self._slots[i].generated),
                 -self._slots[i].admit_seq,
             ),
         )
 
     def _ensure_blocks(self):
-        """Incremental mode: before a decode window, every decoding
-        lane must own blocks covering its next K write positions —
-        grow on demand, preempt the lowest-priority lane when the pool
-        (free + evictable shared) runs dry.  Oldest lanes grow first
+        """Before a decode window, every decoding lane must own
+        blocks covering its next K write positions — grow on demand,
+        preempt the lowest-priority lane when the pool (free +
+        evictable shared) runs dry.  Oldest lanes grow first
         so pressure lands on the youngest."""
-        if not self.incremental:
-            return
         cfgp = self.pool_cfg
         order = sorted(
             (
@@ -1513,10 +1450,9 @@ class ContinuousBatchingScheduler:
             sl.logprobs.append(
                 float(lp) if lp is not None else float("nan")
             )
-        if self._serve_obs:
-            # per-token timestamps fold into ONE tbt_p99_s label at
-            # finish — the only per-token tracing cost
-            sl.req.token_times.append(time.monotonic())
+        # per-token timestamps fold into ONE tbt_p99_s label at
+        # finish — the only per-token tracing cost
+        sl.req.token_times.append(time.monotonic())
         self.total_new_tokens += 1
         eos = self.sched.eos_id
         if eos is not None and int(token) == int(eos):
@@ -1869,20 +1805,15 @@ class ContinuousBatchingScheduler:
             from dlrover_tpu.observability.events import anchored_now
 
             if pre:
-                # request labels on the iteration-level prefill span
-                # (one chunk serves exactly one slot) — gated so
-                # SERVE_OBS=0 keeps the PR-14 record byte-for-byte
-                req_label = (
-                    {"req_id": self._last_prefill_req}
-                    if self._serve_obs else {}
-                )
+                # one chunk serves exactly one slot: the span names
+                # its request
                 self._events.complete(
                     "prefill",
                     anchored_now(pre_t0),
                     pre_t1 - pre_t0,
                     tokens=pre,
                     prefix_hit_blocks=hit_blocks,
-                    **req_label,
+                    req_id=self._last_prefill_req,
                 )
             if dec:
                 self._events.complete(
@@ -1892,17 +1823,6 @@ class ContinuousBatchingScheduler:
                     new_tokens=dec,
                 )
             dur = max(time.monotonic() - t0, 1e-9)
-            if not self._serve_obs:
-                # SERVE_OBS=0 keeps the PR-14 record byte-for-byte
-                self._events.complete(
-                    "serve_step",
-                    anchored_now(t0),
-                    dur,
-                    tokens=pre,
-                    new_tokens=dec,
-                    throughput_tps=round((pre + dec) / dur, 2),
-                )
-                return finished
             # the iteration's host time by leaf phase, in ms: the five
             # sum to ``dur`` (``other`` is what no phase covers — the
             # list scans, the two records above); the lane counts give

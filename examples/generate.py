@@ -33,9 +33,7 @@ def parse_args():
     p.add_argument(
         "--serve", action="store_true",
         help="server mode: the continuous-batching multi-replica "
-        "plane (rl/generation_service.make_generation_engine; "
-        "DLROVER_TPU_SERVING=0 falls back to the legacy "
-        "single-worker loop)",
+        "plane (rl/generation_service.ServingEngine)",
     )
     p.add_argument("--replicas", type=int, default=2)
     p.add_argument(
@@ -53,15 +51,13 @@ def serve_main(args) -> int:
     transport, dispatcher, drain-safe completion."""
     import numpy as np
 
-    from dlrover_tpu.rl.generation_service import (
-        make_generation_engine,
-    )
+    from dlrover_tpu.rl.generation_service import ServingEngine
 
     cfg_kw = dict(
         vocab_size=512, dim=64, n_layers=2, n_heads=4,
         n_kv_heads=2, mlp_dim=128, max_seq_len=128, remat="none",
     )
-    engine = make_generation_engine(
+    engine = ServingEngine(
         factory="dlrover_tpu.rl.generation_service:tiny_llama_factory",
         max_new_tokens=args.max_new,
         temperature=args.temperature,
@@ -75,33 +71,24 @@ def serve_main(args) -> int:
     )
     try:
         rng = np.random.default_rng(0)
-        if hasattr(engine, "submit"):  # continuous-batching plane
-            ids = [
-                engine.submit(
-                    rng.integers(
-                        0, cfg_kw["vocab_size"],
-                        (int(rng.integers(4, 17)),),
-                    ),
-                    seed=i,
-                )
-                for i in range(args.requests)
-            ]
-            for rid in ids:
-                res = engine.result(rid)
-                print(
-                    f"req {rid} [{res['finish_reason']}, replica "
-                    f"{res['replica']}, {res['latency_s']:.3f}s]: "
-                    + " ".join(map(str, res["tokens"].tolist()))
-                )
-            print("serving status:", engine.status())
-        else:  # DLROVER_TPU_SERVING=0 legacy loop
-            prompts = rng.integers(
-                0, cfg_kw["vocab_size"], (args.requests, 8)
-            ).astype(np.int32)
-            out = engine.generate(prompts, seed=0)
-            for row in out:
-                print(" ".join(map(str, row.tolist())))
-            print("stats:", engine.last_stats)
+        ids = [
+            engine.submit(
+                rng.integers(
+                    0, cfg_kw["vocab_size"],
+                    (int(rng.integers(4, 17)),),
+                ),
+                seed=i,
+            )
+            for i in range(args.requests)
+        ]
+        for rid in ids:
+            res = engine.result(rid)
+            print(
+                f"req {rid} [{res['finish_reason']}, replica "
+                f"{res['replica']}, {res['latency_s']:.3f}s]: "
+                + " ".join(map(str, res["tokens"].tolist()))
+            )
+        print("serving status:", engine.status())
     finally:
         engine.close()
     return 0

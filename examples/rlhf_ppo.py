@@ -112,11 +112,9 @@ def main():
         # vllm_backend.py) — no in-process pointer sharing
         import dataclasses
 
-        from dlrover_tpu.rl.generation_service import (
-            CrossProcessGenerationEngine,
-        )
+        from dlrover_tpu.rl.generation_service import ServingEngine
 
-        backend = CrossProcessGenerationEngine(
+        backend = ServingEngine(
             factory=(
                 "dlrover_tpu.rl.generation_service:"
                 "tiny_llama_factory"
@@ -129,6 +127,9 @@ def main():
                 if isinstance(v, (int, float, str, bool))
             },
             max_new_tokens=args.max_new,
+            num_replicas=1,
+            max_slots=args.batch,
+            max_seq_len=args.prompt_len + args.max_new,
         )
     else:
         backend = KVCacheBackend(cfg, max_new_tokens=args.max_new)
@@ -159,11 +160,12 @@ def main():
             flush=True,
         )
     if args.cross_process:
-        s = backend.last_stats
+        s = backend.status()
         print(
-            f"generation service: {s['tokens_per_s']:.1f} tok/s, "
-            f"weight handoff {s['handoff_s'] * 1e3:.1f} ms "
-            f"(publish {backend.publish_s * 1e3:.1f} ms), "
+            f"generation service: {s['completed']} rollouts, "
+            f"{args.max_new / max(s['p50_latency_s'], 1e-9):.1f} tok/s "
+            f"a rollout at the median, weight handoff: publish "
+            f"{backend.publish_s * 1e3:.1f} ms, "
             f"policy version {s['version']}",
             flush=True,
         )

@@ -1,20 +1,16 @@
 """Shared bench-model factory (ISSUE 20, satellite 2).
 
-``bench_serving.py``, ``bench_flywheel.py`` and the chaos harness all
-need "the tiny llama the benches run" — and three hand-copied config
-dicts drift (a vocab bump in one file silently changes another leg's
-tokens/s baseline).  This module is the single source of truth: every
-bench builds its model through ``bench_cfg_kwargs()`` /
-``bench_model()``, with knobs for the few axes legs legitimately vary
-(vocab for EOS-modal workloads, dtype for memory-shape studies, size
-for the drafter).
+"The tiny llama the benches run": ``bench_flywheel.py``'s legs build
+their model through ``bench_cfg_kwargs()`` / ``bench_model()``, with
+overrides for the few axes a leg legitimately varies (size for the
+publish-at-scale leg and for the drafter).
 
 Import as ``from _bench_models import ...`` (the scripts directory is
 on ``sys.path`` when any bench runs) — this is bench plumbing, not
 library surface, hence the underscore.
 """
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 #: the canonical bench model — identical across every bench leg that
 #: does not explicitly override a knob
@@ -35,26 +31,10 @@ BASE_CFG_KW: Dict = dict(
 DRAFT_OVERRIDES: Dict = dict(dim=16, n_layers=1, mlp_dim=32)
 
 
-def bench_cfg_kwargs(
-    vocab_size: Optional[int] = None,
-    dim: Optional[int] = None,
-    n_layers: Optional[int] = None,
-    mlp_dim: Optional[int] = None,
-    max_seq_len: Optional[int] = None,
-    dtype: Optional[str] = None,
-    **overrides,
-) -> Dict:
-    """The bench model's ``LlamaConfig`` kwargs, with knob overrides.
+def bench_cfg_kwargs(**overrides) -> Dict:
+    """The bench model's ``LlamaConfig`` kwargs, with overrides.
     Returns a fresh dict each call — callers mutate freely."""
-    kw = dict(BASE_CFG_KW)
-    for key, val in dict(
-        vocab_size=vocab_size, dim=dim, n_layers=n_layers,
-        mlp_dim=mlp_dim, max_seq_len=max_seq_len, dtype=dtype,
-    ).items():
-        if val is not None:
-            kw[key] = val
-    kw.update(overrides)
-    return kw
+    return {**BASE_CFG_KW, **overrides}
 
 
 def draft_cfg_kwargs(**overrides) -> Dict:

@@ -156,8 +156,8 @@ DECLARED_METRICS = {
     "dlrover_tpu_serving_preemptions",
     "dlrover_tpu_serving_prefix_hit_rate",
     "dlrover_tpu_serving_accepted_tokens_per_step",
-    # per-request SLO histograms (ISSUE 16, record_serving_latency,
-    # behind DLROVER_TPU_SERVE_OBS): dispatcher-side
+    # per-request SLO histograms (ISSUE 16, record_serving_latency):
+    # dispatcher-side
     # time-to-first-token, request-level time-between-tokens p99,
     # end-to-end latency, and scheduler queue wait — rendered as
     # _bucket/_sum/_count families on /metrics
@@ -168,8 +168,8 @@ DECLARED_METRICS = {
     # per-replica health verdict gauge (ServingHealthEngine):
     # 1 ok .. 0.1 dead_air, mirroring dlrover_tpu_node_health
     "dlrover_tpu_serving_health",
-    # disaggregated prefill/decode (ISSUE 17, DLROVER_TPU_SERVE_FLEET
-    # + DLROVER_TPU_FLEET_PREFILL_WORKERS): KV blocks a prefill worker
+    # disaggregated prefill/decode (ISSUE 17,
+    # DLROVER_TPU_FLEET_PREFILL_WORKERS): KV blocks a prefill worker
     # filled and shipped through the shm block arena for a decode
     # replica to adopt — each increment pairs with a kv_ship span
     "dlrover_tpu_serving_kv_shipped_blocks_total",

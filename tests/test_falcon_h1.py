@@ -310,8 +310,7 @@ def test_a_preempted_sequence_reproduces_its_tokens(params):
         )
 
 
-def test_a_common_prefix_is_prefilled_for_each_request(params, monkeypatch):
-    monkeypatch.setenv("DLROVER_TPU_KV_PREFIX_CACHE", "1")
+def test_a_common_prefix_is_prefilled_for_each_request(params):
     shared = prompts_of((16,), seed=2)[0]  # four full blocks of 4
     tails = prompts_of((5, 7), seed=4)
     prompts = [np.concatenate([shared, t]) for t in tails]
@@ -342,8 +341,7 @@ def _build(monkeypatch, env=None, **kw):
 @pytest.mark.parametrize("case,env,kw,why", [
     ("decode_k", {"DLROVER_TPU_DECODE_STEPS": "3"}, {}, "roll the state back"),
     ("draft", {}, {"draft_cfg": llama.LlamaConfig.tiny()}, "draft model"),
-    ("prefill_role", {"DLROVER_TPU_SERVE_FLEET": "1"},
-     {"role": "prefill"}, "K/V\\s+blocks only"),
+    ("prefill_role", {}, {"role": "prefill"}, "K/V\\s+blocks only"),
 ])
 def test_unsound_combinations_are_refused_at_construction(
     monkeypatch, case, env, kw, why

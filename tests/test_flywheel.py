@@ -5,9 +5,9 @@ and the machinery they ride:
 
 - the generation side-segment (publish/peek, torn publish never
   advances it, restart-safe re-attach);
-- logprob capture through the scheduler and the serving engine, and
-  the ``DLROVER_TPU_FLYWHEEL=0`` pins at scheduler, engine and
-  trainer level;
+- logprob capture through the scheduler and the serving engine:
+  on when asked for, and without it the plain programs and empty
+  logprobs;
 - the trajectory stream: exactly-once by req-id (journal survives a
   consumer restart), staleness drop/tag, schema versioning;
 - the Brain arbiter: sustain/cooldown/hysteresis, the min-train-world
@@ -164,7 +164,7 @@ class TestGenerationSegment:
 
 
 # --------------------------------------------------------------------------
-# scheduler-level: logprob capture + the FLYWHEEL=0 closure pin
+# scheduler-level: logprob capture, and the plain closures without it
 # --------------------------------------------------------------------------
 class TestSchedulerCapture:
     @pytest.mark.timeout(600)
@@ -242,9 +242,9 @@ class TestSchedulerCapture:
 
 
 # --------------------------------------------------------------------------
-# engine-level: kill switch pins + capture plumbing (no replicas)
+# engine-level: capture and draft plumbing (no replicas)
 # --------------------------------------------------------------------------
-class TestEngineKillSwitch:
+class TestEngineCaptureAndDraft:
     def _engine(self, name: str, **kw):
         from dlrover_tpu.rl.generation_service import ServingEngine
 
@@ -260,27 +260,33 @@ class TestEngineKillSwitch:
             **kw,
         )
 
-    def test_flywheel_off_strips_capture_draft_and_generation(
-        self, monkeypatch
-    ):
-        monkeypatch.setenv("DLROVER_TPU_FLYWHEEL", "0")
-        eng = self._engine(
-            f"flyoff-{os.getpid()}",
-            capture_logprobs=True,
+    def test_capture_and_draft_are_only_what_was_asked_for(self):
+        """An engine asked for neither: the worker spec carries no
+        flywheel key and no draft model (the replica compiles the
+        plain programs), nothing is captured — and its publishes bump
+        the generation segment all the same, so its replicas adopt
+        without a meta RPC.  Asked for a draft alone, the spec says so
+        with capture off."""
+        eng = self._engine(f"flyplain-{os.getpid()}")
+        try:
+            assert eng._capture is False and not eng._draft_mode
+            assert "flywheel" not in eng._spec
+            assert "draft" not in eng._spec["factory_kwargs"]
+            assert eng._shm.peek_generation() == -1
+            cfg, params = _tiny_params()
+            eng.sync_weights(params)
+            assert eng._shm.peek_generation() == 1
+        finally:
+            eng.close()
+        drafted = self._engine(
+            f"flydr-{os.getpid()}",
             extra_cfg={"draft": dict(CFG_KW, dim=8)},
         )
         try:
-            # byte-for-byte pin: the worker spec carries NO flywheel
-            # key, no draft model, no capture — today's plane exactly
-            assert eng._capture is False
-            assert "flywheel" not in eng._spec
-            assert "draft" not in eng._spec["factory_kwargs"]
-            cfg, params = _tiny_params()
-            eng.sync_weights(params)
-            # and the generation segment is never touched
-            assert eng._shm.peek_generation() == -1
+            assert drafted._spec["flywheel"] == {"capture": False}
+            assert drafted._spec["factory_kwargs"]["draft"]["dim"] == 8
         finally:
-            eng.close()
+            drafted.close()
 
     def test_flywheel_on_publishes_generation(self):
         eng = self._engine(
@@ -315,13 +321,6 @@ class TestEngineKillSwitch:
                 eng2.sync_weights(params, draft_params=params)
         finally:
             eng2.close()
-
-    def test_coordinator_refuses_when_disabled(self, monkeypatch):
-        from dlrover_tpu.rl.flywheel import FlywheelCoordinator
-
-        monkeypatch.setenv("DLROVER_TPU_FLYWHEEL", "0")
-        with pytest.raises(RuntimeError, match="FLYWHEEL"):
-            FlywheelCoordinator(engine=None, max_total=32)
 
 
 # --------------------------------------------------------------------------
@@ -809,14 +808,12 @@ class TestTrainerBridge:
         )
 
     @pytest.mark.timeout(600)
-    def test_make_experience_identical_under_either_kill_switch(
-        self, monkeypatch
-    ):
-        """Trainer-level FLYWHEEL=0 pin: the legacy rollout path
-        reads no flywheel state — identical buffers either way."""
+    def test_make_experience_is_reproducible(self):
+        """The in-process rollout path reads no state of the process
+        beside its arguments: two trainers built alike, given the same
+        prompts and key, fill identical buffers."""
 
-        def run(env_val):
-            monkeypatch.setenv("DLROVER_TPU_FLYWHEEL", env_val)
+        def run():
             trainer = self._trainer()
             prompts = np.tile(
                 np.arange(4, dtype=np.int32)[None], (4, 1)
@@ -826,9 +823,8 @@ class TestTrainerBridge:
             )
             return trainer.buffer._items
 
-        buf_on = run("1")
-        buf_off = run("0")
-        assert len(buf_on) == len(buf_off)
-        for a, b in zip(buf_on, buf_off):
+        first, second = run(), run()
+        assert len(first) == len(second) > 0
+        for a, b in zip(first, second):
             for k in a:
                 np.testing.assert_array_equal(a[k], b[k])

@@ -7,8 +7,8 @@ Contracts pinned here, all on the CPU:
   no-ops in a process that never imported JAX;
 - the serving scheduler partitions each iteration's host time into the
   ``sched.*`` leaves and writes the sums, with the lane counts, as
-  labels on the ``serve_step`` record it already wrote — and not at all
-  under ``DLROVER_TPU_SERVE_OBS=0``;
+  labels on the ``serve_step`` record it already wrote — on every
+  record, and on nothing else the iteration writes;
 - ``trainer/trainer.py`` emits one ``step`` span per completed step
   (completion to completion) and one ``snapshot_pull`` span per
   snapshot's synchronous leg, which the goodput ledger charges as loss;
@@ -52,8 +52,7 @@ PARTS = ("admit_ms", "dispatch_ms", "wait_ms", "commit_ms", "other_ms")
 LEAVES = ("sched.admit", "sched.dispatch", "sched.wait", "sched.commit")
 
 
-def _scheduler(events_path, monkeypatch, serve_obs="1", decode_steps="1"):
-    monkeypatch.setenv("DLROVER_TPU_SERVE_OBS", serve_obs)
+def _scheduler(events_path, monkeypatch, decode_steps="1"):
     monkeypatch.setenv("DLROVER_TPU_DECODE_STEPS", decode_steps)
     sch = ContinuousBatchingScheduler(
         CFG,
@@ -129,18 +128,29 @@ def test_lane_counts_are_consistent_with_the_slots(tmp_path, monkeypatch):
     assert max(e["labels"]["lanes_decode"] for e in steps) == SLOTS
 
 
-def test_labels_absent_with_serve_obs_off(tmp_path, monkeypatch):
-    """SERVE_OBS=0 keeps the record's labels exactly PR 14's."""
+def test_every_serve_step_record_has_the_closed_label_set(
+    tmp_path, monkeypatch
+):
+    """One ``serve_step`` record an iteration that did work, each with
+    exactly the required labels and the optional ones of
+    ``OPTIONAL_SPAN_LABELS`` (what ``benchmarks/`` reads: the five
+    parts, the lane counts, the state counters), and the iteration's
+    own ``prefill`` / ``decode`` records carry none of them."""
     path = tmp_path / "events.jsonl"
-    sch = _scheduler(path, monkeypatch, serve_obs="0")
+    sch = _scheduler(path, monkeypatch)
     _submit(sch)
     list(sch.run())
     steps = _serve_steps(path)
-    assert steps
+    assert len(steps) == sch.iterations
+    want = {"tokens", "new_tokens", "throughput_tps"} | set(
+        ev.OPTIONAL_SPAN_LABELS[ev.PHASE_SERVE_STEP]
+    )
     for e in steps:
-        assert set(e["labels"]) == {
-            "tokens", "new_tokens", "throughput_tps",
-        }, e
+        assert set(e["labels"]) == want, e
+        assert e["labels"]["state_bytes"] == 0  # keys and values only
+    for e in ev.read_events(str(path)):
+        if e["name"] in ("prefill", "decode"):
+            assert not set(e["labels"]) & set(PARTS), e
 
 
 # ----------------------------------------------------------- the logger

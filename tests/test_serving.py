@@ -13,7 +13,10 @@ The contracts pinned here (ISSUE 14 acceptance):
 - block churn leaks nothing;
 - drain (SIGUSR1/SIGTERM) and crash (SIGKILL) both complete every
   request exactly once on the survivors;
-- ``DLROVER_TPU_SERVING=0`` pins the legacy single-worker loop.
+- the whole-batch surface (``generate`` / ``sync_weights``) an RLHF
+  trainer uses: a publish reaches it, an unchanged version adopts
+  nothing, a stopped replica trips the request timeout, and a call
+  fails at once when no replica is left alive.
 """
 
 import os
@@ -223,9 +226,8 @@ class TestSchedulerParity:
 
 class TestIncrementalAllocation:
     """ISSUE 15 tentpole: watermark admission + on-demand growth +
-    lowest-priority preemption + deterministic resume, the
-    ``DLROVER_TPU_KV_INCREMENTAL=0`` kill-switch, and prefix-cached
-    shared blocks."""
+    lowest-priority preemption + deterministic resume, and
+    prefix-cached shared blocks."""
 
     @pytest.mark.parametrize("temp", [0.0, 0.8])
     def test_churn_at_pool_exhaustion_exact_tails(
@@ -246,7 +248,6 @@ class TestIncrementalAllocation:
             ),
         )
         sch.sync_weights(PARAMS)
-        assert sch.incremental
         ids = [
             sch.submit(p, max_new=12, seed=50 + i)
             for i, p in enumerate(PROMPTS)
@@ -261,55 +262,111 @@ class TestIncrementalAllocation:
             ref = unbatched_reference(p, 12, 50 + i, temp=temp)
             np.testing.assert_array_equal(res[ids[i]].tokens, ref)
 
-    def test_kill_switch_reproduces_reservation_admission(
+    def test_pool_at_half_of_worst_case_demand_completes_everything(
         self, monkeypatch
     ):
-        """``DLROVER_TPU_KV_INCREMENTAL=0``: worst-case reservation
-        at admission (the PR-13 discipline byte-for-byte) — the full
-        prompt+budget block count is held from admission on, nothing
-        grows, nothing preempts, nothing is shared, and a request
-        whose worst case can't fit STAYS QUEUED instead of raising."""
-        monkeypatch.setenv("DLROVER_TPU_KV_INCREMENTAL", "0")
-        sch = _scheduler(temp=0.0)
-        assert not sch.incremental
-        rid = sch.submit(PROMPTS[0], max_new=6, seed=50)
-        sch.step()
-        # worst case reserved up front: ceil((3 + 6) / 4) = 3 blocks
-        assert len(sch.block_pool.blocks_of(rid)) == 3
+        """More requests than lanes on a pool HALF of what the lanes'
+        worst cases (prompt + budget) add up to, under the default
+        admission watermark: every request completes with the
+        reference's tail, through ONE compiled decode program, nothing
+        leaks — and more lanes run at once than their worst cases
+        would fit (admission holds a lane's prompt, not its budget).
+        A request whose own worst case exceeds the pool is refused at
+        ``submit``."""
+        monkeypatch.setenv("DLROVER_TPU_KV_GROW_BLOCKS", "1")
+        slots, bs, max_new = 4, 4, 16
+        worst = -(-(8 + max_new) // bs)  # blocks, longest prompt
+        sch = ContinuousBatchingScheduler(
+            CFG,
+            SchedulerConfig(
+                max_slots=slots, block_size=bs,
+                num_blocks=slots * worst // 2 + 1,
+                max_seq_len=64, prefill_chunk=8, temperature=0.0,
+            ),
+        )
+        sch.sync_weights(PARAMS)
+        rng = np.random.default_rng(23)
+        prompts = [
+            rng.integers(0, 97, (int(rng.integers(4, 9)),)).astype(
+                np.int32
+            )
+            for _ in range(10)
+        ]
+        ids = [
+            sch.submit(p, max_new=max_new, seed=500 + i)
+            for i, p in enumerate(prompts)
+        ]
+        res, most_lanes = {}, 0
+        while len(res) < len(ids):
+            for r in sch.step():
+                res[r.req_id] = r
+            most_lanes = max(most_lanes, sch.active_count)
+        for i, p in enumerate(prompts):
+            np.testing.assert_array_equal(
+                res[ids[i]].tokens,
+                unbatched_reference(p, max_new, 500 + i, temp=0.0),
+            )
+        st = sch.stats()
+        assert sch.compile_counts()["decode"] == 1
+        assert st["used_blocks"] == 0  # nothing leaked
+        assert st["grown_blocks"] > 0, st
+        assert most_lanes > (slots * worst // 2) // worst, most_lanes
+        with pytest.raises(ValueError, match="blocks > pool"):
+            sch.submit(np.arange(1, 30, dtype=np.int32), max_new=24)
+
+    def test_shared_system_prompt_hit_count_and_blocks_saved(self):
+        """Eight requests behind one 32-token system prompt (four full
+        blocks of 8) that an earlier request left in the index: every
+        admission takes all four blocks from it — 32 hits of 32
+        lookups — prefills only its own tail, and serves the
+        reference's tokens."""
+        rng = np.random.default_rng(31)
+        system = rng.integers(0, 97, (32,)).astype(np.int32)
+        prompts = [
+            np.concatenate([
+                system,
+                rng.integers(
+                    0, 97, (int(rng.integers(2, 7)),)
+                ).astype(np.int32),
+            ])
+            for _ in range(8)
+        ]
+        sch = ContinuousBatchingScheduler(
+            CFG,
+            SchedulerConfig(
+                max_slots=4, block_size=8, num_blocks=128,
+                max_seq_len=64, prefill_chunk=8, temperature=0.0,
+            ),
+        )
+        sch.sync_weights(PARAMS)
+        sch.submit(system, max_new=2, seed=0)
+        sch.run()
+        before = sch.stats()
+        assert before["prefix_hits"] == 0
+        ids = [
+            sch.submit(p, max_new=4, seed=900 + i)
+            for i, p in enumerate(prompts)
+        ]
         res = {r.req_id: r for r in sch.run()}
         st = sch.stats()
-        assert st["preemptions"] == 0
-        assert st["grown_blocks"] == 0
-        assert st["prefix_queries"] == 0  # sharing fully inert
-        np.testing.assert_array_equal(
-            res[rid].tokens,
-            unbatched_reference(PROMPTS[0], 6, 50, temp=0.0),
+        assert st["prefix_hits"] == 4 * len(prompts)
+        assert (
+            st["prefix_queries"] - before["prefix_queries"]
+            == 4 * len(prompts)
         )
-        # a worst case bigger than the pool queues forever (PR-13
-        # semantics) where incremental mode rejects at submit
-        tiny = ContinuousBatchingScheduler(
-            CFG,
-            SchedulerConfig(
-                max_slots=2, block_size=4, num_blocks=5,
-                max_seq_len=64, prefill_chunk=3, temperature=0.0,
-            ),
+        # blocks saved: nobody prefilled the system prompt again
+        assert (
+            st["total_prefill_tokens"] - before["total_prefill_tokens"]
+            == sum(p.size - system.size for p in prompts)
         )
-        tiny.sync_weights(PARAMS)
-        tiny.submit(PROMPTS[1], max_new=12, seed=0)  # needs 5 > 4
-        for _ in range(4):
-            tiny.step()
-        assert tiny.queue_depth == 1  # still queued, never admitted
-        monkeypatch.delenv("DLROVER_TPU_KV_INCREMENTAL")
-        inc = ContinuousBatchingScheduler(
-            CFG,
-            SchedulerConfig(
-                max_slots=2, block_size=4, num_blocks=5,
-                max_seq_len=64, prefill_chunk=3, temperature=0.0,
-            ),
+        assert [res[i].stats["prefix_hit_blocks"] for i in ids] == (
+            [4] * len(prompts)
         )
-        inc.sync_weights(PARAMS)
-        with pytest.raises(ValueError, match="blocks > pool"):
-            inc.submit(PROMPTS[1], max_new=12, seed=0)
+        for i, p in enumerate(prompts):
+            np.testing.assert_array_equal(
+                res[ids[i]].tokens,
+                unbatched_reference(p, 4, 900 + i, temp=0.0),
+            )
 
     def test_prefix_cache_shares_blocks_exactly(self, monkeypatch):
         """Sequential requests with a common 16-token system prompt:
@@ -336,17 +393,6 @@ class TestIncrementalAllocation:
         # requests 2 and 3 skipped the shared blocks' prefill: far
         # fewer prompt tokens prefilled than 3 full prompts
         assert st["total_prefill_tokens"] < 3 * prompts[0].size
-        # kill-switch: no sharing machinery at all
-        monkeypatch.setenv("DLROVER_TPU_KV_PREFIX_CACHE", "0")
-        off = _scheduler(temp=0.0)
-        assert not off.prefix_cache
-        rid = off.submit(prompts[0], max_new=5, seed=70)
-        res = {r.req_id: r for r in off.run()}
-        np.testing.assert_array_equal(
-            res[rid].tokens,
-            unbatched_reference(prompts[0], 5, 70, temp=0.0),
-        )
-        assert off.stats()["prefix_queries"] == 0
 
     def test_preempted_drain_hand_back_carries_resume(self,
                                                       monkeypatch):
@@ -469,12 +515,10 @@ class TestDispatcherTieBreak:
         for order in ([c, a, b], [b, a, c], [a, c, b]):
             assert least_outstanding(order).idx == 0
 
-    def test_engine_submit_rejects_pool_exceeding_request(
-        self, monkeypatch
-    ):
-        """Dispatcher-side mirror of the scheduler's incremental-mode
-        pool guard: a request whose worst case exceeds a replica's
-        whole pool must fail at ``ServingEngine.submit`` — raised in
+    def test_engine_submit_rejects_pool_exceeding_request(self):
+        """Dispatcher-side mirror of the scheduler's pool guard: a
+        request whose worst case exceeds a replica's whole pool must
+        fail at ``ServingEngine.submit`` — raised in
         the worker loop it would kill the replica and the on-death
         redispatch would then cascade it onto the survivors."""
         import threading
@@ -491,16 +535,10 @@ class TestDispatcherTieBreak:
         eng._dispatch_q = deque()
         eng._next_id = 0
         eng._spec = {"sched": {"num_blocks": 5, "block_size": 4}}
-        monkeypatch.delenv(
-            "DLROVER_TPU_KV_INCREMENTAL", raising=False
-        )
         prompt = np.arange(1, 8, dtype=np.int32)  # needs 5 > 4 blocks
         with pytest.raises(ValueError, match="replica pool"):
             eng.submit(prompt, max_new=12)
-        # reservation kill-switch keeps PR-13 semantics: accepted,
-        # queues at the replica instead of raising
-        monkeypatch.setenv("DLROVER_TPU_KV_INCREMENTAL", "0")
-        assert eng.submit(prompt, max_new=12) == 0
+        assert eng.submit(prompt, max_new=8) == 0  # 4 blocks: fits
 
     def test_dispatcher_fails_rejected_request_immediately(self):
         """A replica-side REJECT (belt-and-suspenders for env skew /
@@ -624,6 +662,36 @@ class TestShapeBuckets:
             )
         assert bucketed.compile_count() == 1  # all in the 8-bucket
 
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    def test_kv_cache_scan_prefill_equals_the_batched_one(
+        self, monkeypatch, temperature
+    ):
+        """A model that brings no prefill function feeds its prompt
+        one position at a time through ``lax.scan`` (what a backend
+        is told by ``prefill_fn=None``, and by nothing else): the
+        same tokens as the one batched prefill forward."""
+        from dlrover_tpu.rl.inference import KVCacheBackend
+
+        monkeypatch.delenv("DLROVER_TPU_GEN_BUCKETS", raising=False)
+        prompts = jnp.asarray(
+            np.random.default_rng(4).integers(0, 97, (2, 6)),
+            jnp.int32,
+        )
+        rng = jax.random.PRNGKey(1)
+        batched = KVCacheBackend(
+            CFG, max_new_tokens=5, temperature=temperature
+        )
+        scanned = KVCacheBackend(
+            CFG, max_new_tokens=5, temperature=temperature,
+            prefill_fn=None,
+        )
+        assert batched._prefill is not None
+        assert scanned._prefill is None
+        np.testing.assert_array_equal(
+            np.asarray(scanned.generate(prompts, rng, PARAMS)),
+            np.asarray(batched.generate(prompts, rng, PARAMS)),
+        )
+
 
 @pytest.fixture(scope="class")
 def serving_engine(tmp_path_factory):
@@ -732,126 +800,163 @@ class TestServingEngineElastic:
         assert status["replicas"][2]["alive"] is True
 
 
-class TestServingKillSwitch:
-    def test_serving0_pins_legacy(self, monkeypatch):
-        """DLROVER_TPU_SERVING=0: the factory returns the legacy
-        single-worker engine and its outputs still exactly match the
-        in-process sampler (the byte-for-byte surface pin)."""
-        monkeypatch.setenv("DLROVER_TPU_SERVING", "0")
+@pytest.fixture(scope="class")
+def one_replica_engine(tmp_path_factory):
+    """A one-replica engine with a registry of its own: what an RLHF
+    trainer holds (``examples/rlhf_ppo.py --cross_process``)."""
+    from dlrover_tpu.observability.metrics import (
+        MetricsRegistry,
+        set_default_registry,
+    )
+    from dlrover_tpu.rl.generation_service import ServingEngine
+
+    os.environ["DLROVER_TPU_SOCKET_DIR"] = str(
+        tmp_path_factory.mktemp("sk1")
+    )
+    reg = MetricsRegistry(
+        path=str(tmp_path_factory.mktemp("reg1") / "m.prom")
+    )
+    set_default_registry(reg)
+    eng = ServingEngine(
+        factory="dlrover_tpu.rl.generation_service:tiny_llama_factory",
+        factory_kwargs=SERVE_CFG_KW,
+        max_new_tokens=4,
+        temperature=0.0,
+        name=f"serve-one-{os.getpid()}",
+        num_replicas=1,
+        max_slots=4,
+        block_size=4,
+        num_blocks=64,
+        max_seq_len=48,
+        prefill_chunk=8,
+    )
+    yield eng, reg
+    eng.close()
+    set_default_registry(MetricsRegistry())
+
+
+def _adoptions(eng, seed0, timeout=60.0):
+    """Serve until a replica STATS row (sent once a second, while the
+    replica steps) has reached ``status()``; returns the row."""
+    stamp = eng._replicas[0].stats
+    deadline = time.monotonic() + timeout
+    i = 0
+    while time.monotonic() < deadline:
+        rid = eng.submit(
+            np.array([4, 8, 15, 16], np.int32), max_new=4,
+            seed=seed0 + i,
+        )
+        eng.result(rid, timeout=180.0)
+        i += 1
+        if eng._replicas[0].stats is not stamp:
+            return eng.status()["replicas"][0]
+    raise AssertionError("no STATS row reached the dispatcher")
+
+
+class TestServingEngineWholeBatchSurface:
+    """``generate`` / ``sync_weights`` on one engine session, in the
+    order a trainer meets them; the kill comes last."""
+
+    def test_a_publish_reaches_generate(self, one_replica_engine):
+        """Two different policies published through shm: greedy
+        generations equal a local sampler's with the same weights
+        (exact cross-process weight fidelity), version by version."""
         from dlrover_tpu.rl.generation_service import (
-            CrossProcessGenerationEngine,
-            make_generation_engine,
             tiny_llama_factory,
         )
         from dlrover_tpu.rl.inference import JitSamplerBackend
 
-        eng = make_generation_engine(
-            factory=(
-                "dlrover_tpu.rl.generation_service:"
-                "tiny_llama_factory"
-            ),
-            max_new_tokens=4,
-            temperature=0.0,
-            factory_kwargs=SERVE_CFG_KW,
-            name="gen-ks",
-            num_replicas=2,  # serving-only kwarg: must be dropped
+        eng, _ = one_replica_engine
+        cfg = llama.LlamaConfig(**SERVE_CFG_KW)
+        local = JitSamplerBackend(
+            tiny_llama_factory(**SERVE_CFG_KW)["forward_fn"],
+            max_new_tokens=4, temperature=0.0,
         )
-        try:
-            assert isinstance(eng, CrossProcessGenerationEngine)
-            cfg = llama.LlamaConfig(**SERVE_CFG_KW)
-            params = llama.init_params(jax.random.PRNGKey(5), cfg)
+        prompts = np.array([[5, 9, 2], [11, 3, 7]], np.int32)
+        for i, key in enumerate((1, 42)):
+            params = llama.init_params(jax.random.PRNGKey(key), cfg)
             eng.sync_weights(params)
-            prompts = np.array(
-                [[5, 9, 2], [11, 3, 7]], np.int32
-            )
-            got = eng.generate(prompts, seed=0)
-            parts = tiny_llama_factory(**SERVE_CFG_KW)
-            local = JitSamplerBackend(
-                parts["forward_fn"], max_new_tokens=4,
-                temperature=0.0,
-            )
-            want = np.asarray(
-                local.generate(
+            assert eng.publish_s > 0
+            np.testing.assert_array_equal(
+                eng.generate(prompts, seed=0),
+                np.asarray(local.generate(
                     jnp.asarray(prompts), jax.random.PRNGKey(0),
                     params=params,
-                )
+                )),
             )
-            np.testing.assert_array_equal(got, want)
+            assert eng.status()["version"] == i + 1
 
-            # satellite: the response timeout is the env knob now —
-            # a STOPPED (not dead) worker trips it, not the old
-            # hard-coded 600 s
-            monkeypatch.setenv("DLROVER_TPU_GEN_TIMEOUT_S", "2")
-            eng._proc.send_signal(signal.SIGSTOP)
+    def test_an_unchanged_version_adopts_nothing(
+        self, one_replica_engine
+    ):
+        """No new publish: the replica's adoption count stays where
+        the two publishes left it, over a second of traffic and more,
+        and finding that out costs no meta RPC (the generation
+        side-segment); the same request gives the same tokens."""
+        eng, _ = one_replica_engine
+        row = _adoptions(eng, seed0=2000)
+        assert row["adoptions"] == 2, row
+        prompts = np.array([[1, 2]], np.int32)
+        first = eng.generate(prompts, seed=0)
+        again = _adoptions(eng, seed0=3000)
+        assert again["adoptions"] == 2, again
+        assert again["meta_rpcs"] == row["meta_rpcs"], (row, again)
+        np.testing.assert_array_equal(
+            first, eng.generate(prompts, seed=0)
+        )
+
+    def test_status_of_a_one_replica_engine(self, one_replica_engine):
+        """The pane has the SLO quantiles and the health rows
+        whatever the fleet's size, and the replica's SLO series are
+        in the registry."""
+        eng, reg = one_replica_engine
+        status = eng.status()
+        assert set(status) == {
+            "replicas", "queue_depth", "completed", "p50_latency_s",
+            "p99_latency_s", "version", "slo", "health",
+        }
+        assert status["replicas"][0]["role"] == "decode"
+        assert status["slo"]["ttft_p99_s"] > 0
+        assert status["completed"] >= 6
+        assert reg.histogram(
+            "dlrover_tpu_serving_ttft_seconds",
+            labels={"replica": "0"},
+        ).count == status["completed"]
+
+    def test_a_stopped_replica_trips_the_request_timeout(
+        self, one_replica_engine, monkeypatch
+    ):
+        """``DLROVER_TPU_GEN_TIMEOUT_S`` is what a call waits for a
+        replica that is alive and silent (SIGSTOP), not 600 s; the
+        request is served once the replica runs again."""
+        eng, _ = one_replica_engine
+        proc = eng._replicas[0].proc
+        monkeypatch.setenv("DLROVER_TPU_GEN_TIMEOUT_S", "2")
+        proc.send_signal(signal.SIGSTOP)
+        try:
             t0 = time.monotonic()
             with pytest.raises(TimeoutError, match="within 2"):
-                eng.generate(prompts, seed=0)
+                eng.generate(np.array([[5, 9, 2]], np.int32), seed=0)
             assert time.monotonic() - t0 < 30
-            eng._proc.send_signal(signal.SIGCONT)
-            monkeypatch.delenv("DLROVER_TPU_GEN_TIMEOUT_S")
         finally:
-            eng.close()
+            proc.send_signal(signal.SIGCONT)
+        monkeypatch.delenv("DLROVER_TPU_GEN_TIMEOUT_S")
+        assert eng.generate(
+            np.array([[5, 9, 2]], np.int32), seed=0
+        ).shape == (1, 7)
 
-
-class TestBenchServingSmoke:
-    def test_bench_beats_sequential_2x(self, tmp_path):
-        """The ISSUE-14 acceptance bar: continuous batching >= 2x the
-        sequential request loop's tokens/s on mixed-length concurrent
-        load (in-process legs; the replica legs run in the full
-        bench).  Also pins the partial-flush artifact contract."""
-        import json
-        import subprocess
-
-        out = tmp_path / "serving.json"
-        script = os.path.join(
-            os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__))
-            ),
-            "scripts", "bench_serving.py",
-        )
-        proc = subprocess.run(
-            [
-                sys.executable, script,
-                "--out", str(out),
-                "--requests", "12",
-                "--qps", "30",
-                "--skip_replica_leg",
-            ],
-            capture_output=True,
-            text=True,
-            timeout=420,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        )
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-        payload = json.loads(out.read_text())
-        extras = payload["extras"]
-        assert payload["value"] >= 2.0, extras
-        assert extras["continuous"]["tokens_per_s"] >= (
-            2.0 * extras["sequential"]["tokens_per_s"]
-        )
-        # one compiled decode program at steady state, in the bench
-        # too — the no-retrace guarantee under real traffic
-        assert extras["continuous"]["compile_counts"]["decode"] == 1
-        # the sweep flushed into the artifact (partial-flush contract)
-        assert extras["qps_sweep"][0]["offered_qps"] == 30.0
-        # ISSUE-15 satellite pin: on the pool-constrained workload
-        # (pool at 50% of worst-case demand), incremental admission
-        # sustains AT LEAST reservation admission's tokens/s — with
-        # every completed tail still exactly the unbatched reference
-        # in BOTH disciplines
-        util = extras["utilization"]
-        assert util["incremental"]["tokens_per_s"] >= (
-            util["reservation"]["tokens_per_s"]
-        ), util
-        assert util["incremental"]["tails_exact"], util
-        assert util["reservation"]["tails_exact"], util
-        assert util["incremental"]["mean_kv_utilization"] > (
-            util["reservation"]["mean_kv_utilization"]
-        ), util
-        # prefix leg: the shared-block cache actually hit, exactly
-        pfx = extras["prefix"]
-        assert pfx["prefix_cached"]["prefix_hit_rate"] > 0.3, pfx
-        assert pfx["prefix_cached"]["tails_exact"], pfx
+    def test_a_killed_replica_fails_the_call_at_once(
+        self, one_replica_engine
+    ):
+        """The only replica SIGKILLed: ``generate`` raises with what
+        happened now, not after the 600 s request timeout."""
+        eng, _ = one_replica_engine
+        eng.kill_replica(0)
+        eng._replicas[0].proc.wait(timeout=30)
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError, match="no replica is alive"):
+            eng.generate(np.array([[1, 2]], np.int32), seed=0)
+        assert time.monotonic() - t0 < 30
 
 
 class TestTopServingPane:

@@ -1,19 +1,19 @@
-"""Fleet-level serving (ISSUE 17): SLO-class lanes, disaggregated
-KV block shipping, and the `DLROVER_TPU_SERVE_FLEET=0` kill-switch.
+"""Fleet-level serving (ISSUE 17): SLO-class lanes and disaggregated
+KV block shipping.
 
 The contracts pinned here (ISSUE 17 acceptance):
 
 - class-aware preemption evicts batch lanes before interactive ones
-  at equal KV pressure, never the reverse; fleet OFF keeps the exact
-  PR-14 victim rule;
+  at equal KV pressure, never the reverse; within a class the victim
+  is the lane with the fewest generated tokens, the youngest first;
 - shipped block regions are bitwise the prefill worker's pool
   content, so a decode continuation over an adopted prefill equals
   the lone-scheduler reference token for token;
 - adoption never retraces the decode program
   (``compile_counts()["decode"] == 1`` stays true across it);
-- `DLROVER_TPU_SERVE_FLEET=0` reproduces the PR-16 surfaces exactly:
-  FIFO head-of-line admission, single class, no roles, shipped
-  payloads dropped at submit.
+- an interactive request is admitted before every batch request that
+  was queued when it arrived; what a prefill worker ships is what the
+  decode side adopts, one for one.
 """
 
 import os
@@ -88,7 +88,7 @@ def _slot_of(sch, slo_class):
 
 
 class TestClassAwarePreemption:
-    """The victim rule: fleet ON is class-aware, OFF is PR-14."""
+    """The victim rule: class first, then the PR-14 rule."""
 
     def _age_batch_then_admit_interactive(self):
         """Batch lane with a long generated tail, interactive lane
@@ -106,13 +106,10 @@ class TestClassAwarePreemption:
         assert sch._slots[_slot_of(sch, "batch")].generated
         return sch
 
-    def test_fleet_on_victim_is_batch_not_interactive(
-        self, monkeypatch
-    ):
-        """Fleet ON: the interactive lane has FEWER generated tokens
-        (the PR-14 victim), but the batch lane must be evicted —
+    def test_victim_is_batch_not_interactive(self):
+        """The interactive lane has FEWER generated tokens (the
+        within-class victim), but the batch lane must be evicted —
         batch outranks interactive as a victim, never the reverse."""
-        monkeypatch.setenv("DLROVER_TPU_SERVE_FLEET", "1")
         sch = self._age_batch_then_admit_interactive()
         b, i = _slot_of(sch, "batch"), _slot_of(sch, "interactive")
         assert len(sch._slots[i].generated) < len(
@@ -120,31 +117,40 @@ class TestClassAwarePreemption:
         )
         assert sch._pick_victim(exclude=-1) == b
 
-    def test_fleet_off_pins_pr14_victim_rule(self, monkeypatch):
-        """Fleet OFF: same traffic, and the fewest-generated lane
-        (here the younger request) is the victim again — the PR-16
-        behavior byte for byte."""
-        monkeypatch.setenv("DLROVER_TPU_SERVE_FLEET", "0")
-        sch = self._age_batch_then_admit_interactive()
-        slots = [
-            (i, sl) for i, sl in enumerate(sch._slots)
-            if sl.req is not None
-        ]
+    def test_within_a_class_the_victim_is_fewest_generated(self):
+        """Three batch lanes of different ages: the victim is the one
+        with the fewest generated tokens, and between two lanes that
+        tie, the one admitted last."""
+        sch = _scheduler(max_slots=3)
+        sch.submit(np.array([5, 9, 2], np.int32), max_new=12, seed=1)
+        for _ in range(5):  # an old lane with a tail
+            sch.step()
+        sch.submit(np.array([7, 1], np.int32), max_new=12, seed=2)
+        sch.submit(np.array([8, 4], np.int32), max_new=12, seed=3)
+        for _ in range(2):  # both prefilled in turn, then decoding
+            sch.step()
+        lanes = {
+            sl.req.req_id: (i, sl)
+            for i, sl in enumerate(sch._slots) if sl.req is not None
+        }
+        assert len(lanes) == 3
+        old, mid, young = (lanes[r] for r in sorted(lanes))
+        assert len(old[1].generated) > len(mid[1].generated)
         expect = min(
-            slots,
+            (mid, young),
             key=lambda t: (len(t[1].generated), -t[1].admit_seq),
         )[0]
         assert sch._pick_victim(exclude=-1) == expect
+        if len(mid[1].generated) == len(young[1].generated):
+            assert expect == young[0]  # the tie goes to the youngest
+        # the chosen lane itself is never offered
+        assert sch._pick_victim(exclude=expect) != expect
 
-    def test_fleet_on_preemption_churn_matches_reference(
-        self, monkeypatch
-    ):
+    def test_preemption_churn_matches_reference(self, monkeypatch):
         """Mixed-class traffic through a pool small enough to force
         preemption: every tail still equals the lone-sequence greedy
         reference (restart-from-prompt is deterministic), and batch
         lanes actually got preempted."""
-        monkeypatch.setenv("DLROVER_TPU_SERVE_FLEET", "1")
-        monkeypatch.setenv("DLROVER_TPU_KV_INCREMENTAL", "1")
         monkeypatch.setenv("DLROVER_TPU_KV_GROW_BLOCKS", "1")
         monkeypatch.setenv("DLROVER_TPU_KV_ADMIT_WATERMARK", "0")
         sch = _scheduler(max_slots=4, num_blocks=9)
@@ -212,16 +218,13 @@ class TestKVBlockShipping:
                     got[:, untouched], before[name][:, untouched]
                 )
 
-    def test_adopted_decode_matches_reference_compile_once(
-        self, monkeypatch
-    ):
+    def test_adopted_decode_matches_reference_compile_once(self):
         """End-to-end disaggregation in-process: a prefill-role
         scheduler fills and ships the KV blocks, a second scheduler
         adopts them and decodes.  The adopted tail equals the
         lone-scheduler greedy reference (the ship is invisible), and
         the decode program of the adopting scheduler stays at ONE
         compile even while local requests interleave."""
-        monkeypatch.setenv("DLROVER_TPU_SERVE_FLEET", "1")
         prompt = np.array(
             [11, 3, 7, 8, 1, 2, 9, 30, 31], np.int32
         )
@@ -263,33 +266,84 @@ class TestKVBlockShipping:
         assert dec.compile_counts()["decode"] == 1
 
 
-class TestFleetKillSwitch:
-    """`DLROVER_TPU_SERVE_FLEET=0` pins the PR-16 scheduler surfaces."""
-
-    def test_off_pins_fifo_admission_and_drops_fleet_state(
-        self, monkeypatch
-    ):
-        monkeypatch.setenv("DLROVER_TPU_SERVE_FLEET", "0")
-        sch = _scheduler(role="prefill")  # role request is IGNORED
-        assert sch.role == "unified"
-        assert sch.interactive_slots == 0
-        sch.submit(np.array([5, 9, 2], np.int32), max_new=2, seed=1,
-                   slo_class="batch")
-        sch.submit(np.array([7, 1], np.int32), max_new=2, seed=2,
-                   slo_class="interactive")
-        # head-of-line FIFO: the interactive request does NOT jump
-        assert sch._pick_next_index() == 0
-        # a shipped payload is dropped at submit — no adoption path
-        sch.submit(
-            np.array([1, 2, 3], np.int32), max_new=2, seed=3,
-            shipped={"k": None, "v": None, "first_token": 0},
+    def test_every_shipped_prefill_is_adopted_with_its_tail(self):
+        """Five prompts through a prefill worker and into a decode
+        scheduler of two lanes: ``shipped_out == shipped_in``, every
+        adopted tail is the reference's, the full prompt blocks that
+        came in are indexed for later prompts, and nothing is left in
+        either pool."""
+        rng = np.random.default_rng(11)
+        prompts = [
+            rng.integers(0, 97, (int(rng.integers(5, 14)),)).astype(
+                np.int32
+            )
+            for _ in range(5)
+        ]
+        pre = _scheduler(role="prefill", max_slots=2)
+        ids = [
+            pre.submit(p, max_new=6, seed=5 + i)
+            for i, p in enumerate(prompts)
+        ]
+        for _ in range(60):
+            pre.step()
+            if len(pre.shipped) == len(prompts):
+                break
+        assert pre.shipped_out == len(prompts)
+        assert pre.idle and pre.block_pool.used_blocks == 0
+        shipped = {rec["req_id"]: rec for rec in pre.shipped}
+        dec = _scheduler(role="unified", max_slots=2)
+        for rid, p in zip(ids, prompts):
+            rec = shipped[rid]
+            assert rec["n_blocks"] == -(-p.size // 4)
+            dec.submit(
+                p, max_new=6, seed=5 + rid, req_id=rid,
+                shipped={k: rec[k] for k in ("k", "v", "first_token")},
+            )
+        res = {r.req_id: r for r in dec.run()}
+        assert dec.shipped_in == pre.shipped_out == len(prompts)
+        for rid, p in zip(ids, prompts):
+            np.testing.assert_array_equal(
+                res[rid].tokens, unbatched_reference(p, 6)
+            )
+        assert dec.compile_counts()["decode"] == 1
+        assert dec.stats()["total_prefill_tokens"] == 0
+        assert dec.block_pool.used_blocks == 0
+        assert dec.block_pool.cached_shared_blocks == sum(
+            p.size // 4 for p in prompts
         )
-        assert all(r.shipped is None for r in sch._queue)
-        res = sch.run()
-        assert len(res) == 3 and sch.shipped_in == 0
 
-    def test_on_admits_interactive_first(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_SERVE_FLEET", "1")
+
+class TestAdmissionLanes:
+    def test_interactive_is_admitted_before_queued_batch(self):
+        """Two lanes busy with batch work and three more batch
+        requests queued when an interactive request arrives: it is
+        admitted before all three (its ``admit_seq`` is the next one
+        given out), and everything still completes."""
+        sch = _scheduler(max_slots=2)
+        for i in range(5):
+            sch.submit(np.array([5, 9, 2 + i], np.int32), max_new=6,
+                       seed=i, slo_class="batch", tenant="bulk")
+        sch.step()
+        assert sch.active_count == 2 and sch.queue_depth == 3
+        chat = sch.submit(np.array([7, 1], np.int32), max_new=6,
+                          seed=9, slo_class="interactive",
+                          tenant="chat")
+        order = []
+        done = []
+        while not sch.idle:
+            done.extend(sch.step())
+            for sl in sorted(
+                (sl for sl in sch._slots if sl.req is not None),
+                key=lambda sl: sl.admit_seq,
+            ):
+                if sl.req.req_id not in order:
+                    order.append(sl.req.req_id)
+        assert order[:2] == [0, 1]
+        assert order[2] == chat, order
+        assert sorted(order) == sorted(r.req_id for r in done)
+        assert len(done) == 6 and sch._queued_interactive == 0
+
+    def test_on_admits_interactive_first(self):
         sch = _scheduler()
         sch.submit(np.array([5, 9, 2], np.int32), max_new=2, seed=1,
                    slo_class="batch", tenant="bulk")

@@ -1,6 +1,6 @@
 """The serving plane explains itself (ISSUE 16): per-request
-lifecycle tracing, TTFT/TBT SLO histograms, the replica-health
-observatory, and the ``DLROVER_TPU_SERVE_OBS=0`` kill-switch.
+lifecycle tracing, TTFT/TBT SLO histograms, and the replica-health
+observatory.
 
 Contracts pinned here:
 
@@ -9,8 +9,7 @@ Contracts pinned here:
   tells its WHOLE life (queue_wait -> admit -> preempt -> resume ->
   serve_request, one req_id) that survives the Perfetto export;
 - ``record_serving_latency`` fills per-replica log-bucketed
-  histograms rendered as ``_bucket``/``_sum``/``_count`` — and stays
-  inert with the observatory off;
+  histograms rendered as ``_bucket``/``_sum``/``_count``;
 - ``retire_series`` drops a dead replica's gauges AND histograms (a
   frozen last value reads as a live replica), and the dispatcher
   actually calls it when a replica dies;
@@ -18,9 +17,10 @@ Contracts pinned here:
   naming both versions instead of misparsing it;
 - ``ServingHealthEngine`` derives slo_straggler / dead_air /
   kv_pressure / preempt_storm verdicts with streak+cooldown
-  discipline and emits the labeled instants;
-- ``DLROVER_TPU_SERVE_OBS=0`` reproduces the PR-14 surfaces exactly
-  (scheduler spans, request stats, engine status keys).
+  discipline and emits the labeled instants — through the engine, a
+  replica slowed and a replica wedged by the ``faults=`` seam are both
+  named within three derivation intervals;
+- every ``prefill`` and ``preempt`` record names its request.
 """
 
 import json
@@ -81,11 +81,9 @@ PR14_STATUS_KEYS = {
 }
 
 
-def _traced_scheduler(events_path, monkeypatch, num_blocks=64,
-                      max_slots=4, max_new_default=64, serve_obs="1"):
-    """A scheduler with the timeline on; ``serve_obs`` is pinned at
-    construction, so the env is set before the constructor runs."""
-    monkeypatch.setenv("DLROVER_TPU_SERVE_OBS", serve_obs)
+def _traced_scheduler(events_path, num_blocks=64, max_slots=4,
+                      max_new_default=64):
+    """A scheduler with the timeline on."""
     sch = ContinuousBatchingScheduler(
         CFG,
         SchedulerConfig(
@@ -108,14 +106,12 @@ def _by_name(events):
 
 
 class TestRequestTracing:
-    def test_serve_request_spans_carry_full_label_set(
-        self, tmp_path, monkeypatch
-    ):
+    def test_serve_request_spans_carry_full_label_set(self, tmp_path):
         """Every completed request produces one ``serve_request`` X
         record with the whole identity + SLO + efficiency label set,
         plus labeled queue_wait/admit children sharing its req_id."""
         ev = tmp_path / "events.jsonl"
-        sch = _traced_scheduler(ev, monkeypatch)
+        sch = _traced_scheduler(ev)
         ids = [
             sch.submit(
                 np.arange(2 + i, dtype=np.int32), max_new=5,
@@ -150,8 +146,8 @@ class TestRequestTracing:
             }
             assert set(ids) <= child_ids, f"{child} missing req_ids"
 
-    def test_result_stats_gain_slo_keys(self, tmp_path, monkeypatch):
-        sch = _traced_scheduler(tmp_path / "e.jsonl", monkeypatch)
+    def test_result_stats_gain_slo_keys(self, tmp_path):
+        sch = _traced_scheduler(tmp_path / "e.jsonl")
         rid = sch.submit(
             np.array([3, 1, 4], np.int32), max_new=6, seed=7
         )
@@ -171,12 +167,10 @@ class TestRequestTracing:
         some request must trace queue_wait -> admit -> preempt ->
         resume -> serve_request under ONE req_id, and the file must
         survive the Perfetto export."""
-        monkeypatch.setenv("DLROVER_TPU_KV_INCREMENTAL", "1")
         monkeypatch.setenv("DLROVER_TPU_KV_GROW_BLOCKS", "1")
         ev = tmp_path / "events.jsonl"
         sch = _traced_scheduler(
-            ev, monkeypatch, num_blocks=26, max_slots=8,
-            max_new_default=24,
+            ev, num_blocks=26, max_slots=8, max_new_default=24,
         )
         rng = np.random.default_rng(29)
         for i in range(12):
@@ -227,49 +221,53 @@ class TestRequestTracing:
         )
 
 
-class TestServeObsOffPin:
-    def test_scheduler_surfaces_match_pr14(
+class TestIterationRecordsNameTheirRequest:
+    def test_prefill_and_preempt_records_carry_req_id(
         self, tmp_path, monkeypatch
     ):
-        """SERVE_OBS=0: no lifecycle spans, no req_id on prefill /
-        preempt records, no new stats keys — the PR-14 timeline."""
-        monkeypatch.setenv("DLROVER_TPU_KV_INCREMENTAL", "1")
+        """Under pool pressure every ``prefill`` chunk and every
+        ``preempt`` names a request that was submitted; the chunks of
+        a request add up to what was prefilled for it (its prompt,
+        once more with its tail after each preemption), and its
+        ``preempt`` records to the ``preempts`` its result reports."""
         monkeypatch.setenv("DLROVER_TPU_KV_GROW_BLOCKS", "1")
         ev = tmp_path / "events.jsonl"
         sch = _traced_scheduler(
-            ev, monkeypatch, num_blocks=26, max_slots=8,
-            max_new_default=24, serve_obs="0",
+            ev, num_blocks=26, max_slots=8, max_new_default=24,
         )
         rng = np.random.default_rng(29)
-        for i in range(8):
+        ids = [
             sch.submit(
                 rng.integers(
                     0, 97, (int(rng.integers(4, 10)),)
                 ).astype(np.int32),
                 max_new=24, seed=300 + i,
             )
-        results = list(sch.run())
-        assert len(results) == 8
-        for r in results:
-            for key in ("tbt_p99_s", "queue_wait_s", "preempts",
-                        "prefix_hit_blocks"):
-                assert key not in r.stats, (key, r.stats)
-        events = read_events(str(ev))
-        names = {e.get("name") for e in events}
-        assert not names & {
-            "serve_request", "queue_wait", "admit", "resume",
-        }, names
-        # the pre-existing spans still flow, anonymously
-        assert "prefill" in names and "preempt" in names
-        for e in events:
-            assert "req_id" not in (e.get("labels") or {}), e
+            for i in range(8)
+        ]
+        results = {r.req_id: r for r in sch.run()}
+        assert set(results) == set(ids)
+        names = _by_name(read_events(str(ev)))
+        assert names.get("prefill") and names.get("preempt")
+        chunks, evictions = {}, {}
+        for e in names["prefill"]:
+            rid = e["labels"]["req_id"]
+            chunks[rid] = chunks.get(rid, 0) + e["labels"]["tokens"]
+        for e in names["preempt"]:
+            rid = e["labels"]["req_id"]
+            evictions[rid] = evictions.get(rid, 0) + 1
+        assert set(chunks) == set(ids)
+        assert set(evictions) <= set(ids)
+        assert sum(chunks.values()) == sch.total_prefill_tokens
+        for rid, res in results.items():
+            assert res.stats["preempts"] == evictions.get(rid, 0)
+            prompt = res.tokens.size - res.new_tokens
+            assert chunks[rid] >= prompt
+            assert (chunks[rid] > prompt) == (rid in evictions)
 
 
 class TestSLOHistograms:
-    def test_record_serving_latency_fills_histograms(
-        self, tmp_path, monkeypatch
-    ):
-        monkeypatch.setenv("DLROVER_TPU_SERVE_OBS", "1")
+    def test_record_serving_latency_fills_histograms(self, tmp_path):
         reg = MetricsRegistry(path=str(tmp_path / "m.prom"))
         set_default_registry(reg)
         try:
@@ -300,22 +298,6 @@ class TestSLOHistograms:
                 "dlrover_tpu_serving_ttft_seconds",
                 labels={"replica": "1"},
             ).count == 1
-        finally:
-            set_default_registry(MetricsRegistry())
-
-    def test_inert_when_observatory_off(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_SERVE_OBS", "0")
-        reg = MetricsRegistry(path=str(tmp_path / "m.prom"))
-        set_default_registry(reg)
-        try:
-            record_serving_latency(
-                replica="0", ttft_s=0.1, tbt_p99_s=0.01, e2e_s=1.0,
-                queue_wait_s=0.01,
-            )
-            assert not reg.histogram_series(
-                "dlrover_tpu_serving_ttft_seconds"
-            )
-            assert "dlrover_tpu_serving" not in reg.render_text()
         finally:
             set_default_registry(MetricsRegistry())
 
@@ -651,13 +633,11 @@ class TestServingHealthEngine:
 
 @pytest.fixture(scope="module")
 def obs_engine(tmp_path_factory):
-    """A 2-replica serving session with the observatory ON and a
-    private default registry (the dispatcher records into the
-    process-wide default)."""
+    """A 2-replica serving session with a private default registry
+    (the dispatcher records into the process-wide default)."""
     os.environ["DLROVER_TPU_SOCKET_DIR"] = str(
         tmp_path_factory.mktemp("socks_obs")
     )
-    prev_obs = os.environ.pop("DLROVER_TPU_SERVE_OBS", None)
     reg = MetricsRegistry(
         path=str(tmp_path_factory.mktemp("reg") / "m.prom")
     )
@@ -680,13 +660,11 @@ def obs_engine(tmp_path_factory):
     yield eng, reg
     eng.close()
     set_default_registry(MetricsRegistry())
-    if prev_obs is not None:
-        os.environ["DLROVER_TPU_SERVE_OBS"] = prev_obs
 
 
 @pytest.mark.heavy
 class TestServingEngineObservatory:
-    """One observatory-on engine session: SLO surfaces while serving,
+    """One engine session: SLO surfaces while serving,
     then the kill-one-replica series-retirement regression."""
 
     def test_status_gains_slo_and_health(self, obs_engine):
@@ -765,128 +743,105 @@ class TestServingEngineObservatory:
 
 
 @pytest.mark.heavy
-class TestServeObsOffEngine:
-    def test_engine_status_pins_pr14_keys(
-        self, tmp_path, tmp_path_factory
+class TestEngineNamesInjectedFaults:
+    def test_slowed_and_wedged_replicas_are_named_in_time(
+        self, tmp_path_factory, monkeypatch
     ):
-        """SERVE_OBS=0 end-to-end: the engine's status is EXACTLY the
-        PR-14 key set and no serving SLO series exist."""
-        # short dir: the socket path must fit the AF_UNIX limit
-        os.environ["DLROVER_TPU_SOCKET_DIR"] = str(
-            tmp_path_factory.mktemp("sk0")
-        )
-        prev_obs = os.environ.get("DLROVER_TPU_SERVE_OBS")
-        os.environ["DLROVER_TPU_SERVE_OBS"] = "0"
-        reg = MetricsRegistry(path=str(tmp_path / "m.prom"))
-        set_default_registry(reg)
+        """Four replicas, one sleeping 0.1 s in every iteration (slow
+        but progressing) and one wedged after 24 tokens (alive,
+        outstanding work, no progress), both through the engine's
+        ``faults=`` seam: the ServingHealthEngine NAMES both with the
+        right reason within three derivation intervals of the first
+        breach it saw, and once the wedged replica is killed every
+        request still completes exactly once on the survivors."""
         from dlrover_tpu.rl.generation_service import ServingEngine
 
-        eng = None
+        straggler, wedged = 2, 3
+        for key, val in (
+            ("DLROVER_TPU_SOCKET_DIR",
+             str(tmp_path_factory.mktemp("skf"))),
+            ("DLROVER_TPU_SERVING_DERIVE_S", "0.25"),
+            ("DLROVER_TPU_SERVING_DEAD_AIR_S", "1.0"),
+            ("DLROVER_TPU_SERVING_SUSTAIN", "2"),
+            ("DLROVER_TPU_SERVING_SLO_RATIO", "2.0"),
+            ("DLROVER_TPU_SERVING_COOLDOWN_S", "5"),
+        ):
+            monkeypatch.setenv(key, val)
+        eng = ServingEngine(
+            factory=(
+                "dlrover_tpu.rl.generation_service:"
+                "tiny_llama_factory"
+            ),
+            factory_kwargs=SERVE_CFG_KW,
+            max_new_tokens=12,
+            temperature=0.0,
+            name=f"serve-faults-{os.getpid()}",
+            num_replicas=4,
+            max_slots=8,
+            block_size=8,
+            num_blocks=128,
+            max_seq_len=64,
+            prefill_chunk=8,
+            # the sleep is on from the replica's start; the wedge
+            # trips only past the warm-up's token budget
+            faults={
+                straggler: {"sleep_s": 0.1},
+                wedged: {"wedge_after_tokens": 24},
+            },
+        )
+        expect = {straggler: "slo_straggler", wedged: "dead_air"}
+        first_streak, named = {}, {}
+        rng = np.random.default_rng(17)
+        prompts = [
+            rng.integers(0, 97, (int(rng.integers(3, 21)),)).astype(
+                np.int32
+            )
+            for _ in range(12)
+        ]
         try:
-            eng = ServingEngine(
-                factory=(
-                    "dlrover_tpu.rl.generation_service:"
-                    "tiny_llama_factory"
-                ),
-                factory_kwargs=SERVE_CFG_KW,
-                max_new_tokens=6,
-                temperature=0.0,
-                name=f"serve-legacy-{os.getpid()}",
-                num_replicas=1,
-                max_slots=4,
-                block_size=4,
-                num_blocks=64,
-                max_seq_len=48,
-                prefill_chunk=8,
-            )
-            rid = eng.submit(
-                np.array([4, 8, 15, 16], np.int32), max_new=6,
-                seed=42,
-            )
-            res = eng.result(rid, timeout=180.0)
-            assert "error" not in res
-            status = eng.status()
-            assert set(status) == PR14_STATUS_KEYS, set(status)
-            assert not reg.histogram_series(
-                "dlrover_tpu_serving_ttft_seconds"
-            )
-            assert "dlrover_tpu_serving_ttft" not in reg.render_text()
+            # warm-up: every replica's compile out of the SLO windows
+            # (8 x 2 tokens stay under the wedge's budget wherever
+            # they land), then start the derivations clean
+            for rid in [
+                eng.submit(p, max_new=2, seed=13000 + i)
+                for i, p in enumerate(prompts[:8])
+            ]:
+                eng.result(rid, timeout=300.0)
+            eng._health.reset()
+            ids = [
+                eng.submit(p, max_new=12, seed=1000 + i)
+                for i, p in enumerate(prompts)
+            ]
+            deadline = time.monotonic() + 90.0
+            while len(named) < 2 and time.monotonic() < deadline:
+                health = eng.status()["health"]
+                for row in health["replicas"]:
+                    idx, reason = row["replica"], expect.get(
+                        row["replica"]
+                    )
+                    if reason is None or idx in named:
+                        continue
+                    if reason in (row.get("streaks") or {}):
+                        first_streak.setdefault(
+                            idx, health["derivations"]
+                        )
+                    if row["verdict"] == reason:
+                        named[idx] = (
+                            row["why"],
+                            health["derivations"]
+                            - first_streak.get(
+                                idx, health["derivations"]
+                            ),
+                        )
+                time.sleep(0.05)
+            assert set(named) == {straggler, wedged}, named
+            for idx, (why, gap) in named.items():
+                assert why.startswith(expect[idx]), (idx, why)
+                assert gap <= 3, (idx, gap)
+            eng.kill_replica(wedged)
+            res = [eng.result(rid, timeout=300.0) for rid in ids]
+            assert len(res) == len(ids)
+            assert all(r["new_tokens"] == 12 for r in res)
+            assert eng.status()["completed"] == len(ids) + 8
         finally:
-            if eng is not None:
-                eng.close()
-            set_default_registry(MetricsRegistry())
-            if prev_obs is None:
-                os.environ.pop("DLROVER_TPU_SERVE_OBS", None)
-            else:
-                os.environ["DLROVER_TPU_SERVE_OBS"] = prev_obs
-
-
-@pytest.mark.heavy
-class TestBenchObservatorySmoke:
-    def test_observatory_leg_names_faults_and_stays_cheap(
-        self, tmp_path
-    ):
-        """The ISSUE-16 acceptance bar, end to end: the bench's
-        ``--observatory`` leg must NAME both injected faults with the
-        right reason (sleep-faulted replica -> slo_straggler, wedged
-        replica -> dead_air) within 3 derivation intervals, produce a
-        Perfetto-exportable preempted lifecycle, and keep the tracing
-        hot path under the 2% tokens/s budget — flushing the artifact
-        after every phase."""
-        import subprocess
-        import tempfile
-
-        out = tmp_path / "obs.json"
-        script = os.path.join(
-            os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__))
-            ),
-            "scripts", "bench_serving.py",
-        )
-        proc = subprocess.run(
-            [
-                sys.executable, script,
-                "--out", str(out),
-                "--requests", "12",
-                "--observatory",
-            ],
-            capture_output=True,
-            text=True,
-            timeout=420,
-            env=dict(
-                os.environ,
-                JAX_PLATFORMS="cpu",
-                # the conftest socket dir embeds this test's (long)
-                # name — the replica ring sockets would overflow the
-                # AF_UNIX path limit
-                DLROVER_TPU_SOCKET_DIR=tempfile.mkdtemp(
-                    prefix="obs-sk-"
-                ),
-            ),
-        )
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-        payload = json.loads(out.read_text())
-        assert payload["value"] == 1.0, payload
-        obs = payload["extras"]["observatory"]
-
-        det = obs["detection"]
-        assert det["both_named"], det
-        assert det["within_3_intervals"], det
-        assert {d["reason"] for d in det["named"]} == {
-            "slo_straggler", "dead_air",
-        }
-        for d in det["named"]:
-            assert d["why"].startswith(d["reason"]), d
-        # exactly-once still holds across the wedged replica's kill
-        assert det["completed"] == det["requests"], det
-
-        life = obs["lifecycle"]
-        assert life["complete_lifecycles"] >= 1, life
-        assert os.path.exists(life["trace_file"])
-
-        # the <2% acceptance bar is for the recorded bench artifact
-        # on real hardware; sub-second CPU passes swing a few percent
-        # either way run to run, so tier-1 only rejects a gross
-        # regression (a per-token hot-path blowup shows double digits)
-        ovh = obs["overhead"]
-        assert ovh["overhead_frac"] < 0.10, ovh
+            eng.close()
