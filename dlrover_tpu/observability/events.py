@@ -247,8 +247,9 @@ LEAF_ANNOTATIONS = frozenset(
         "sched.admit",
         # host->device uploads + the jitted call returning
         "sched.dispatch",
-        # blocking readbacks: the device is busy, the host is not the
-        # cause
+        # blocking readbacks of what the PREVIOUS iteration dispatched,
+        # this iteration's programs already queued behind it: the
+        # device is busy, the host is not the cause
         "sched.wait",
         # _append_token / _finish over the lanes
         "sched.commit",
@@ -501,6 +502,11 @@ OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
         "lanes_decode",
         "lanes_prefill",
         "slots",
+        # the run-ahead decode loop: lanes of this iteration's decode
+        # step dispatched before their previous token had been read,
+        # and lane-steps computed past an EOS and discarded
+        "lanes_ahead",
+        "overrun_tokens",
         # a model with per-lane state beside its pages: bytes of the
         # state slabs resident, and lanes whose state was started from
         # zero this iteration (0 / 0 for a model of keys and values)

@@ -912,6 +912,12 @@ def _serving_worker_loop(spec) -> int:
         for res in scheduler.step():
             served += 1
             _flush_result(res)
+    # the decode loop runs one step ahead: what is still in flight is
+    # committed here, so a request it completes is answered and the
+    # tails handed back below are whole
+    for res in scheduler.settle("drain"):
+        served += 1
+        _flush_result(res)
     requeued = scheduler.drain()
     for r in requeued:
         # hand each unfinished request back WITH its generated tail

@@ -46,6 +46,21 @@ temperatures acceptance is rejection-style (every emitted token is
 sampled from its true conditional).  Host dispatch drops by up to K×
 on the CPU-bound path — the ``dispatches`` counter measures it.
 
+One step ahead: the lanes' current tokens live ON THE DEVICE, a
+``[max_slots]`` vector the decode program takes and returns, so step
+n+1 is dispatched from step n's output before the host has read it.
+An iteration enqueues its prefill chunk and its decode step and only
+then reads and commits what the PREVIOUS iteration dispatched: the
+commit loop, admission and the replica's ring traffic run beside the
+program instead of between two programs.  What the host decides
+without the tokens: positions, tables, and finish by length (a lane
+whose in-flight token is its ``max_new``-th sits the next step out).
+Finish by EOS is learnt one step late; that lane's extra step is
+computed and discarded.  Where the loop may not run ahead it first
+commits what is in flight — multi-token decode and a draft model,
+the ``prefill`` role, a preemption, ``sync_weights``, ``drain`` —
+decided by what the code can see, never by a switch.
+
 Determinism: each request's tokens are sampled with
 ``fold_in(PRNGKey(seed), position)`` — a function of (seed, position)
 only, independent of which slot/iteration served it.  The same
@@ -214,6 +229,89 @@ class _Slot:
     generated: List[int] = field(default_factory=list)
     logprobs: List[float] = field(default_factory=list)
     first_token_t: float = 0.0
+    # tokens sampled for this lane on the device that the host has not
+    # read yet (0-2: the first token, or a decode step, or both)
+    ahead: int = 0
+
+
+@dataclass
+class _InFlight:
+    """One dispatch whose samples the host has not read: a decode step
+    (``toks`` / ``lps`` are ``[max_slots]``) or a prompt's first token
+    (scalars).  ``lanes`` holds ``(slot, the _Slot it ran for, the
+    lane's position after it)``: a slot that holds another ``_Slot``
+    at commit left by EOS in between, and its sample is discarded."""
+
+    lanes: List
+    toks: object
+    lps: object
+    first: bool = False
+    iteration: int = 0  # the ``step()`` that dispatched it
+
+
+def sample_rows(logits, keys, sample_pos, temp: float):
+    """logits [S, V]; keys [S, 2] request base keys; sample_pos [S]
+    the OUTPUT position each token will occupy — the (seed,
+    position)-only sampling contract."""
+    import jax
+    import jax.numpy as jnp
+
+    if temp <= 0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    folded = jax.vmap(jax.random.fold_in)(keys, sample_pos)
+    return jax.vmap(
+        lambda k, l: jax.random.categorical(k, l / temp)
+    )(folded, logits).astype(jnp.int32)
+
+
+def logprob_rows(logits, toks):
+    """Actor logprob of each sampled token: log-softmax of the RAW
+    fp32 logits (temperature-free — the trainer's ``token_logprobs``
+    contract), gathered at the token."""
+    import jax
+    import jax.numpy as jnp
+
+    lp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+    return jnp.take_along_axis(
+        lp, toks[..., None].astype(jnp.int32), axis=-1
+    )[..., 0]
+
+
+def decode_program(decode_model, temp: float, capture_logprobs: bool,
+                   max_blocks: int):
+    """The plain decode step as the scheduler jits it (the pool, the
+    second argument, donated): ``(params, pool, tokens, lanes, keys)
+    -> (pool, tokens'[, logprobs])``.
+
+    ``tokens`` is the lanes' current-token vector and ``tokens'`` the
+    next one (an inactive lane keeps its entry), so a step is called
+    with the previous step's OUTPUT and needs no value from the host.
+    ``lanes`` is the host's one upload a step, int32 ``[S, max_blocks
+    + 2]``: the block tables, then the positions, then the active
+    mask.  ``keys`` ``[S, 2]`` changes only at admission."""
+    import jax.numpy as jnp
+
+    def _step(params, pool, tokens, lanes, keys):
+        tables = lanes[:, :max_blocks]
+        positions = lanes[:, max_blocks]
+        active = lanes[:, max_blocks + 1] != 0
+        logits, pool = decode_model(
+            params, tokens, pool, tables, positions, active
+        )
+        nxt = sample_rows(logits, keys, positions + 1, temp)
+        return pool, jnp.where(active, nxt, tokens), logits, nxt
+
+    def _decode(params, pool, tokens, lanes, keys):
+        pool, tokens, _, _ = _step(params, pool, tokens, lanes, keys)
+        return pool, tokens
+
+    def _decode_lp(params, pool, tokens, lanes, keys):
+        pool, tokens, logits, nxt = _step(
+            params, pool, tokens, lanes, keys
+        )
+        return pool, tokens, logprob_rows(logits, nxt)
+
+    return _decode_lp if capture_logprobs else _decode
 
 
 class ContinuousBatchingScheduler:
@@ -298,8 +396,10 @@ class ContinuousBatchingScheduler:
         self._last_prefill_req = -1
         # the partition of each iteration's host time (ISSUE 24): what
         # the host does around the compiled programs, by leaf phase.
-        # ``wait`` is the blocking readbacks — the device is busy, the
-        # host is not the cause; what no phase covers is ``other``.
+        # ``wait`` is the blocking readback of what the PREVIOUS
+        # iteration dispatched, while this iteration's programs are
+        # already queued behind it — the device is busy, the host is
+        # not the cause; what no phase covers is ``other``.
         self._ph_admit = _HostPhase(
             lambda: EventLogger.leaf("sched.admit")
         )
@@ -314,6 +414,9 @@ class ContinuousBatchingScheduler:
         )
         self._lanes_decode = 0
         self._lanes_prefill = 0
+        self._lanes_ahead = 0
+        self._step_overrun = 0
+        self._step_commits = 0
         self._params = None
         # which leaves the step programs cast is the llama programs'
         # rule (``llama.serving_params``); injected programs get the
@@ -453,8 +556,26 @@ class ContinuousBatchingScheduler:
         self._tables = np.zeros((S, MB), np.int32)
         self._positions = np.zeros((S,), np.int32)
         self._active = np.zeros((S,), bool)
+        # the lanes' current tokens: ON THE DEVICE for the plain decode
+        # loop (each step's output is the next step's input; prefill
+        # and adoption write a lane's first token into it), on the host
+        # for the multi-token window, which reads tokens back before
+        # it knows the next positions anyway
+        self._tokens_dev = jnp.zeros((S,), jnp.int32)
         self._next_token = np.zeros((S,), np.int32)
-        self._keys = np.zeros((S, 2), np.uint32)
+        # each lane's request key, ON THE DEVICE: made there at
+        # admission (``_set_key``) and never read back — reading it
+        # would wait for every program queued before it
+        self._keys = jnp.zeros((S, 2), jnp.uint32)
+        # dispatches whose samples the host has not read, oldest first
+        self._inflight: List[_InFlight] = []
+        # why this scheduler may never dispatch ahead of its commits
+        # (None: it may): the K-step window's positions depend on how
+        # many drafts were accepted.  (A prefill worker never decodes
+        # and reads its one sample at once: ``_prefill_one``.)
+        self._sync_cause: Optional[str] = (
+            "multi_token" if self.decode_k > 1 else None
+        )
         self._slots = [_Slot() for _ in range(S)]
         self._queue: List[GenRequest] = []
         # queued interactive requests, maintained at every queue
@@ -482,19 +603,18 @@ class ContinuousBatchingScheduler:
         self.accepted_tokens = 0  # multi-token decode: tokens kept
         self.lane_windows = 0  # multi-token decode: (lane, window)s
         self._window_hit_blocks = 0  # prefix hits since last emit
+        # the run-ahead loop's census: decode steps dispatched before
+        # their lanes' previous tokens were read, decode steps that
+        # first had to commit (by cause), lane-steps computed past an
+        # EOS and discarded
+        self.ahead_steps = 0
+        self.sync_steps: Dict[str, int] = {}
+        self.overrun_tokens = 0
 
         temp = float(s.temperature)
 
-        def _sample_rows(logits, keys, sample_pos):
-            """logits [S, V]; keys [S, 2] request base keys;
-            sample_pos [S] the OUTPUT position each token will occupy
-            — the (seed, position)-only sampling contract."""
-            if temp <= 0:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            folded = jax.vmap(jax.random.fold_in)(keys, sample_pos)
-            return jax.vmap(
-                lambda k, l: jax.random.categorical(k, l / temp)
-            )(folded, logits).astype(jnp.int32)
+        _sample_rows = partial(sample_rows, temp=temp)
+        _lp_rows = logprob_rows
 
         def _sample_grid(logits, keys, sample_pos):
             """logits [S, K, V]; sample_pos [S, K] — the K-window
@@ -511,14 +631,6 @@ class ContinuousBatchingScheduler:
                     lambda k, l: jax.random.categorical(k, l / temp)
                 )
             )(folded, logits).astype(jnp.int32)
-
-        def _decode(params, pool, tokens, tables, positions, active,
-                    keys):
-            logits, pool = self._decode_model(
-                params, tokens, pool, tables, positions, active
-            )
-            nxt = _sample_rows(logits, keys, positions + 1)
-            return pool, nxt
 
         K = self.decode_k
 
@@ -564,29 +676,17 @@ class ContinuousBatchingScheduler:
             )
             return pool, logits
 
-        def _sample_one(logits_row, key, sample_pos):
-            return _sample_rows(
-                logits_row[None], key[None], sample_pos[None]
+        def _sample_one(logits_row, keys, sample_pos, tokens, lane):
+            """A prompt's first token, sampled from its last chunk's
+            logits and written into the lanes' token vector at
+            ``lane`` on the device: the decode step dispatched next
+            reads it there; the host reads the scalar a commit later."""
+            tok = _sample_rows(
+                logits_row[None], keys[lane][None], sample_pos[None]
             )[0]
+            return tokens.at[lane].set(tok), tok
 
         CAP = self.capture_logprobs
-
-        def _lp_rows(logits, toks):
-            """Actor logprob of each sampled token: log-softmax of
-            the RAW fp32 logits (temperature-free — the trainer's
-            ``token_logprobs`` contract), gathered at the token."""
-            lp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-            return jnp.take_along_axis(
-                lp, toks[..., None].astype(jnp.int32), axis=-1
-            )[..., 0]
-
-        def _decode_lp(params, pool, tokens, tables, positions,
-                       active, keys):
-            logits, pool = self._decode_model(
-                params, tokens, pool, tables, positions, active
-            )
-            nxt = _sample_rows(logits, keys, positions + 1)
-            return pool, nxt, _lp_rows(logits, nxt)
 
         def _decode_multi_lp(params, pool, tokens, tables, positions,
                              active, keys):
@@ -666,14 +766,28 @@ class ContinuousBatchingScheduler:
             )
             return dpool, logits
 
-        def _sample_one_lp(logits_row, key, sample_pos):
+        def _sample_one_lp(logits_row, keys, sample_pos, tokens, lane):
             tok = _sample_rows(
-                logits_row[None], key[None], sample_pos[None]
+                logits_row[None], keys[lane][None], sample_pos[None]
             )
-            return tok[0], _lp_rows(logits_row[None], tok)[0]
+            return (tokens.at[lane].set(tok[0]), tok[0],
+                    _lp_rows(logits_row[None], tok)[0])
 
+        def _set_token(tokens, lane, tok):
+            # an adopted prefill's first token arrives as a host value
+            return tokens.at[lane].set(tok)
+
+        def _set_key(keys, lane, seed):
+            key = jax.random.key_data(jax.random.PRNGKey(seed))
+            return keys.at[lane].set(key.reshape(-1)[:2])
+
+        # the token vector (argument 2) is NOT donated: the host reads
+        # step n's output after step n+1 has taken it as its input
         self._decode_jit = jax.jit(
-            _decode_lp if CAP else _decode, donate_argnums=(1,)
+            decode_program(
+                self._decode_model, temp, CAP, s.max_blocks_per_seq
+            ),
+            donate_argnums=(1,),
         )
         self._decode_multi_jit = (
             jax.jit(
@@ -694,6 +808,8 @@ class ContinuousBatchingScheduler:
         self._sample_jit = jax.jit(
             _sample_one_lp if CAP else _sample_one
         )
+        self._set_token_jit = jax.jit(_set_token)
+        self._set_key_jit = jax.jit(_set_key)
 
     # ------------------------------------------------------------- API
     def sync_weights(self, params, draft_params=None, generation=None):
@@ -716,7 +832,10 @@ class ContinuousBatchingScheduler:
         BEFORE the new one is made, so an adoption never holds two.
         One ``weight_cast`` span per call says what happened
         (``generation``: the published generation, where the caller
-        adopts one)."""
+        adopts one).  A step in flight is committed first (its program
+        holds the old copy alive); what finishes by that comes out of
+        the next ``step()``."""
+        self._commit_first("sync_weights", self._adopt_finished)
         t0 = time.monotonic()
         self._params = None
         self._params = self._serving_params(params)
@@ -880,7 +999,21 @@ class ContinuousBatchingScheduler:
 
     @property
     def idle(self) -> bool:
-        return not self._queue and self.active_count == 0
+        """Nothing queued, nothing in a lane, and nothing dispatched
+        or finished that a ``step()`` has yet to hand out."""
+        return not (
+            self._queue or self.active_count or self._inflight
+            or self._adopt_finished
+        )
+
+    def settle(self, cause: str = "settle") -> List[GenResult]:
+        """Commit every dispatch still in flight and return what has
+        finished outside a ``step()``: for a caller that must see the
+        lanes' ``generated`` whole, or will not call ``step()`` again
+        (the replica's loop before it drains)."""
+        self._commit_first(cause, self._adopt_finished)
+        out, self._adopt_finished = self._adopt_finished, []
+        return out
 
     def compile_counts(self) -> Dict[str, int]:
         """Compiled-program census: decode must stay at 1 across any
@@ -934,6 +1067,10 @@ class ContinuousBatchingScheduler:
             state_bytes=self.state_bytes,
             state_resets=self.state_resets,
             prefix_hits_skipped=self.prefix_hits_skipped,
+            # the run-ahead loop (``step``): how often it engaged
+            ahead_steps=self.ahead_steps,
+            sync_steps=dict(self.sync_steps),
+            overrun_tokens=self.overrun_tokens,
         )
         return st
 
@@ -1110,10 +1247,7 @@ class ContinuousBatchingScheduler:
             self._tables[slot] = row
             self._positions[slot] = 0
             self._active[slot] = False  # decoding starts post-prefill
-            key = self._jax.random.PRNGKey(req.seed)
-            self._keys[slot] = np.asarray(
-                self._jax.random.key_data(key), np.uint32
-            ).reshape(-1)[:2]
+            self._set_key(slot, req.seed)
             n_hit = len(hit_ids)
             self._admit_counter += 1
             sl = _Slot(
@@ -1144,6 +1278,13 @@ class ContinuousBatchingScheduler:
             req.hit_blocks += n_hit
             self._trace_admit(req, admit_t0)
 
+    def _set_key(self, slot: int, seed: int):
+        # ``np.int64``: what ``PRNGKey`` makes of a Python int, so a
+        # seed past 32 bits wraps as it does there
+        self._keys = self._set_key_jit(
+            self._keys, np.int32(slot), np.int64(seed)
+        )
+
     def _adopt(self, slot: int, req: GenRequest, plan: Dict,
                admit_t0: float,
                finished: Optional[List[GenResult]]):
@@ -1171,10 +1312,7 @@ class ContinuousBatchingScheduler:
         )
         self._positions[slot] = plen
         self._active[slot] = True
-        key = self._jax.random.PRNGKey(req.seed)
-        self._keys[slot] = np.asarray(
-            self._jax.random.key_data(key), np.uint32
-        ).reshape(-1)[:2]
+        self._set_key(slot, req.seed)
         self._admit_counter += 1
         sl = _Slot(req=req, phase="decode", prefill_len=plen,
                    admit_seq=self._admit_counter)
@@ -1192,6 +1330,9 @@ class ContinuousBatchingScheduler:
         self._trace_admit(req, admit_t0)
         first = int(payload["first_token"])
         self._next_token[slot] = first
+        self._tokens_dev = self._set_token_jit(
+            self._tokens_dev, np.int32(slot), np.int32(first)
+        )
         self._append_token(
             slot, first,
             self._adopt_finished if finished is None else finished,
@@ -1385,12 +1526,17 @@ class ContinuousBatchingScheduler:
             ),
         )
 
-    def _ensure_blocks(self):
+    def _ensure_blocks(self) -> bool:
         """Before a decode window, every decoding lane must own
         blocks covering its next K write positions — grow on demand,
         preempt the lowest-priority lane when the pool (free +
         evictable shared) runs dry.  Oldest lanes grow first
-        so pressure lands on the youngest."""
+        so pressure lands on the youngest.
+
+        Returns False, having preempted nobody, when the pool is dry
+        while a dispatch is in flight: a victim's ``generated`` must
+        be whole before it is requeued (and the commit may free the
+        blocks that are short), so the caller commits and asks again."""
         cfgp = self.pool_cfg
         order = sorted(
             (
@@ -1404,6 +1550,8 @@ class ContinuousBatchingScheduler:
             if sl.req is None:
                 continue  # preempted while an older lane grew
             req = sl.req
+            if len(sl.generated) + sl.ahead >= req.max_new:
+                continue  # its last token is in flight: no more writes
             total = int(req.prompt.size) + int(req.max_new)
             need_tokens = min(
                 int(self._positions[slot]) + self.decode_k, total
@@ -1422,6 +1570,8 @@ class ContinuousBatchingScheduler:
                     self.block_pool.extend(req.req_id, want)
                     self.grown_blocks += want
                 except OutOfBlocksError:
+                    if self._inflight:
+                        return False
                     victim = self._pick_victim(exclude=slot)
                     if victim is None:
                         raise OutOfBlocksError(
@@ -1436,6 +1586,7 @@ class ContinuousBatchingScheduler:
                 self._tables[slot] = self.block_pool.table_row(
                     req.req_id, self.sched.max_blocks_per_seq
                 )
+        return True
 
     def _append_token(self, slot: int, token: int,
                       finished: List[GenResult],
@@ -1497,20 +1648,25 @@ class ContinuousBatchingScheduler:
         self._last_prefill_req = req.req_id
         plen = sl.prefill_len
         start = sl.prefill_pos
-        jnp = self._jnp
+        # every upload is a value the host does not touch again: the
+        # program reads it after this call has returned, and on the
+        # CPU backend an upload may alias the numpy buffer it was
+        # given (``_tables`` is mutated while the chunk is in flight)
         with self._ph_dispatch:
             chunk = sl.prefill_tokens[start:start + s.prefill_chunk]
             real = chunk.size
             if real < s.prefill_chunk:
                 chunk = np.pad(chunk, (0, s.prefill_chunk - real))
+            chunk = np.array(chunk[None], np.int32)
+            table = self._tables[slot].copy()
             self._pool, logits = self._prefill_jit(
                 self._params,
                 self._pool,
-                jnp.asarray(chunk[None], jnp.int32),
-                jnp.asarray(self._tables[slot]),
-                jnp.int32(start),
+                chunk,
+                table,
+                np.int32(start),
                 *(
-                    (jnp.int32(slot), jnp.int32(real))
+                    (np.int32(slot), np.int32(real))
                     if self.lane_state else ()
                 ),
             )
@@ -1528,9 +1684,9 @@ class ContinuousBatchingScheduler:
                 self._draft_pool, _ = self._draft_prefill_jit(
                     self._draft_params,
                     self._draft_pool,
-                    jnp.asarray(chunk[None], jnp.int32),
-                    jnp.asarray(self._tables[slot]),
-                    jnp.int32(start),
+                    chunk,
+                    table,
+                    np.int32(start),
                 )
                 self.dispatches += 1
         with self._ph_commit:
@@ -1541,27 +1697,27 @@ class ContinuousBatchingScheduler:
         if sl.prefill_pos < plen:
             return real
         # sample the first new token from the last REAL prefill
-        # position's logits (it lives inside this chunk)
-        first_lp = None
+        # position's logits (it lives inside this chunk) into the
+        # lanes' token vector; its value reaches the host with a later
+        # commit, the decode step dispatched next reads it on the device
         with self._ph_dispatch:
-            tok = self._sample_jit(
+            self._tokens_dev, tok, *lp = self._sample_jit(
                 logits[0, plen - 1 - start],
-                jnp.asarray(self._keys[slot]),
-                jnp.int32(plen),
+                self._keys,
+                np.int32(plen),
+                self._tokens_dev,
+                np.int32(slot),
             )
             self.dispatches += 1
-        with self._ph_wait:
-            if self.capture_logprobs:
-                tok, first_lp = tok
-                first_lp = float(first_lp)
-            tok = int(tok)
-        with self._ph_commit:
-            if self.role == "prefill":
-                # disaggregated split: the first token is sampled HERE
-                # (same (seed, position) rule as a local prefill, so
-                # the decode continuation is bit-identical), then the
-                # filled block tiles ship out and the slot frees — a
-                # prefill worker never decodes
+        if self.role == "prefill":
+            # disaggregated split: the first token is sampled HERE
+            # (same (seed, position) rule as a local prefill, so the
+            # decode continuation is bit-identical) and shipped as a
+            # host value with the filled block tiles; the slot frees —
+            # a prefill worker never decodes
+            with self._ph_wait:
+                tok = int(tok)
+            with self._ph_commit:
                 n_ship = self.pool_cfg.blocks_for(plen)
                 ids = self.block_pool.blocks_of(req.req_id)[:n_ship]
                 k_region, v_region = extract_block_regions(
@@ -1570,7 +1726,7 @@ class ContinuousBatchingScheduler:
                 self.shipped.append(
                     {
                         "req_id": req.req_id,
-                        "first_token": int(tok),
+                        "first_token": tok,
                         "n_blocks": n_ship,
                         "prompt_len": plen,
                         "k": k_region,
@@ -1584,60 +1740,114 @@ class ContinuousBatchingScheduler:
                 self._positions[slot] = 0
                 self._active[slot] = False
                 self._slots[slot] = _Slot()
-                return real
-            sl.phase = "decode"
-            self._positions[slot] = plen
-            self._active[slot] = True
-            self._next_token[slot] = int(tok)
-            if self._append_token(slot, int(tok), finished,
-                                  lp=first_lp):
-                pass  # finished on its very first token
+            return real
+        self._track(_InFlight([(slot, sl, plen)], tok,
+                              lp[0] if lp else None, first=True))
+        sl.phase = "decode"
+        sl.ahead = 1
+        self._positions[slot] = plen
+        self._active[slot] = True
+        if self._sync_cause is not None:
+            # in lockstep the first token is read at once
+            self._commit_inflight(finished)
         return real
 
-    def _decode_once(self, finished: List[GenResult]) -> int:
-        """One decode iteration over every active lane; returns the
-        number of tokens sampled."""
-        decoding = [
-            i for i, sl in enumerate(self._slots)
+    def _track(self, rec: _InFlight):
+        """Queue a dispatch for a later commit and start its samples
+        on their way to the host, so that the reads neither queue
+        behind each other nor wait for the commit to ask."""
+        for out in (rec.toks, rec.lps):
+            if out is not None:
+                out.copy_to_host_async()
+        rec.iteration = self.iterations
+        self._inflight.append(rec)
+
+    def _dispatch_decode(self) -> int:
+        """Enqueue one decode step over every lane that still has a
+        token to sample, from the device's own token vector; returns
+        the number of lanes it runs.  Nothing is read back here:
+        positions advance by one, and a lane whose tokens in flight
+        complete its ``max_new`` sits out until they are committed."""
+        S, MB = self.sched.max_slots, self.sched.max_blocks_per_seq
+        lanes = [
+            (slot, sl) for slot, sl in enumerate(self._slots)
             if sl.phase == "decode"
+            and len(sl.generated) + sl.ahead < sl.req.max_new
         ]
-        if not decoding:
+        if not lanes:
             return 0
-        self._lanes_decode = len(decoding)
-        jnp = self._jnp
+        self._lanes_decode = len(lanes)
+        self._lanes_ahead = sum(sl.ahead > 0 for _, sl in lanes)
+        self.ahead_steps += self._lanes_ahead > 0
         with self._ph_dispatch:
-            out = self._decode_jit(
+            # ONE upload a step, built fresh: the host mutates its
+            # tables and positions while the program is in flight
+            packed = np.empty((S, MB + 2), np.int32)
+            packed[:, :MB] = self._tables
+            packed[:, MB] = self._positions
+            packed[:, MB + 1] = 0
+            packed[[slot for slot, _ in lanes], MB + 1] = 1
+            self._pool, self._tokens_dev, *lps = self._decode_jit(
                 self._params,
                 self._pool,
-                jnp.asarray(self._next_token),
-                jnp.asarray(self._tables),
-                jnp.asarray(self._positions),
-                jnp.asarray(self._active),
-                jnp.asarray(self._keys),
+                self._tokens_dev,
+                packed,
+                self._keys,
             )
             self.dispatches += 1
-        with self._ph_wait:
-            if self.capture_logprobs:
-                self._pool, nxt, lps = out
-                lps = np.asarray(lps)
-            else:
-                self._pool, nxt = out
-                lps = None
-            nxt = np.asarray(nxt)
-        sampled = 0
-        with self._ph_commit:
-            for slot in decoding:
+            rec = _InFlight([], self._tokens_dev, lps[0] if lps else None)
+            for slot, sl in lanes:
+                sl.ahead += 1
                 self._positions[slot] += 1
-                self.block_pool.note_filled(
-                    self._slots[slot].req.req_id,
-                    int(self._positions[slot]),
+                rec.lanes.append((slot, sl, int(self._positions[slot])))
+            self._track(rec)
+        return len(lanes)
+
+    def _commit_inflight(self, finished: List[GenResult],
+                         before: Optional[int] = None) -> int:
+        """Read and commit the dispatches in flight, oldest first —
+        all of them, or those of iterations ``before`` the given one;
+        returns the number of decode tokens appended.  A lane whose
+        slot has changed hands since the dispatch ended by EOS one
+        step earlier: its sample is counted as overrun and dropped."""
+        n = sum(
+            before is None or rec.iteration < before
+            for rec in self._inflight
+        )
+        self._step_commits += n
+        sampled = 0
+        for rec in self._inflight[:n]:
+            with self._ph_wait:
+                toks = np.asarray(rec.toks).tolist()
+                lps = (
+                    None if rec.lps is None
+                    else np.asarray(rec.lps).tolist()
                 )
-                tok = int(nxt[slot])
-                sampled += 1
-                lp = float(lps[slot]) if lps is not None else None
-                if not self._append_token(slot, tok, finished, lp=lp):
+            with self._ph_commit:
+                for slot, sl, pos in rec.lanes:
+                    if self._slots[slot] is not sl:
+                        self.overrun_tokens += 1
+                        self._step_overrun += 1
+                        continue
+                    sl.ahead -= 1
+                    if rec.first:
+                        tok, lp = toks, lps
+                    else:
+                        tok = toks[slot]
+                        lp = None if lps is None else lps[slot]
+                        self.block_pool.note_filled(sl.req.req_id, pos)
+                        sampled += 1
                     self._next_token[slot] = tok
+                    self._append_token(slot, tok, finished, lp=lp)
+        del self._inflight[:n]
         return sampled
+
+    def _commit_first(self, cause: str, finished: List[GenResult]):
+        """Where the loop may not run ahead: commit what is in flight
+        before going on, and count the occasion by its cause."""
+        if self._inflight:
+            self.sync_steps[cause] = self.sync_steps.get(cause, 0) + 1
+            self._commit_inflight(finished)
 
     def _decode_multi_once(self, finished: List[GenResult]) -> int:
         """One fused K-step decode window (drafts + verify in ONE
@@ -1650,6 +1860,9 @@ class ContinuousBatchingScheduler:
         if not decoding:
             return 0
         self._lanes_decode = len(decoding)
+        self.sync_steps["multi_token"] = (
+            self.sync_steps.get("multi_token", 0) + 1
+        )
         K = self.decode_k
         temp = float(self.sched.temperature)
         jnp = self._jnp
@@ -1660,13 +1873,14 @@ class ContinuousBatchingScheduler:
         )
         lp_drafts = lp_ver = None
         with self._ph_dispatch:
-            lanes = (
-                jnp.asarray(self._next_token),
-                jnp.asarray(self._tables),
-                jnp.asarray(self._positions),
-                jnp.asarray(self._active),
-                jnp.asarray(self._keys),
-            )
+            # copies: the commit loop below mutates these while, on the
+            # CPU backend, an upload may still alias the host's buffer
+            lanes = tuple(
+                jnp.asarray(a.copy()) for a in (
+                    self._next_token, self._tables, self._positions,
+                    self._active,
+                )
+            ) + (self._keys,)
             if draft_mode:
                 (self._pool, self._draft_pool, drafts, ver, n_match,
                  lp_ver) = self._decode_multi_draft_jit(
@@ -1759,9 +1973,10 @@ class ContinuousBatchingScheduler:
         return sampled
 
     def step(self) -> List[GenResult]:
-        """One scheduler iteration: admit -> one prefill chunk ->
-        (grow/preempt) -> one decode window.  Returns the sequences
-        that finished."""
+        """One scheduler iteration: admit -> dispatch one prefill
+        chunk -> (grow/preempt) -> dispatch one decode step -> read
+        and commit what the PREVIOUS iteration dispatched (the K-step
+        window reads its own).  Returns the sequences that finished."""
         if self._params is None:
             raise RuntimeError(
                 "sync_weights() before step() — the scheduler has no "
@@ -1776,6 +1991,8 @@ class ContinuousBatchingScheduler:
         for ph in phases:
             ph.total_s = 0.0
         self._lanes_decode = self._lanes_prefill = 0
+        self._lanes_ahead = self._step_overrun = 0
+        self._step_commits = 0
         self._step_state_resets = 0
         finished: List[GenResult] = []
         if self._adopt_finished:
@@ -1789,19 +2006,29 @@ class ContinuousBatchingScheduler:
         pre = self._prefill_one(finished)
         pre_t1 = time.monotonic()
         with self._ph_admit:
-            # a first-token EOS may have freed a slot
+            # in lockstep a first-token EOS may have freed a slot
             self._admit(finished)
-            self._ensure_blocks()
+            grown = self._ensure_blocks()
+        if not grown:
+            # the pool is dry: a preemption needs the lanes whole
+            self._commit_first("preempt", finished)
+            with self._ph_admit:
+                self._ensure_blocks()
         dec_t0 = time.monotonic()
         if self._decode_multi_jit is not None:
             dec = self._decode_multi_once(finished)
         else:
-            dec = self._decode_once(finished)
+            if self._sync_cause is not None:
+                self._commit_first(self._sync_cause, finished)
+            self._dispatch_decode()
+            # what earlier iterations dispatched is read only now, with
+            # this iteration's programs queued behind it on the device
+            dec = self._commit_inflight(finished, before=self.iterations)
         dec_t1 = time.monotonic()
         with self._ph_admit:
             self._admit(finished)
         self.iterations += 1
-        if emit and (pre or dec):
+        if emit and (pre or self._lanes_decode or self._step_commits):
             from dlrover_tpu.observability.events import anchored_now
 
             if pre:
@@ -1815,7 +2042,7 @@ class ContinuousBatchingScheduler:
                     prefix_hit_blocks=hit_blocks,
                     req_id=self._last_prefill_req,
                 )
-            if dec:
+            if dec or self._lanes_decode:
                 self._events.complete(
                     "decode",
                     anchored_now(dec_t0),
@@ -1847,6 +2074,8 @@ class ContinuousBatchingScheduler:
                 ),
                 lanes_decode=self._lanes_decode,
                 lanes_prefill=self._lanes_prefill,
+                lanes_ahead=self._lanes_ahead,
+                overrun_tokens=self._step_overrun,
                 slots=self.sched.max_slots,
                 state_bytes=self.state_bytes,
                 state_resets=self._step_state_resets,
@@ -1870,8 +2099,12 @@ class ContinuousBatchingScheduler:
         tail).  Each handed-back request carries its generated tail
         as ``resume_tokens``, so an in-process requeue resumes instead
         of regenerating (cross-process dispatchers resubmit the
-        original prompt; both are deterministic-identical)."""
+        original prompt; both are deterministic-identical).  A step in
+        flight is committed first, so every tail is whole; what
+        FINISHES by that commit comes out of ``settle()`` or the next
+        ``step()``, not of the hand-back."""
         self.draining = True
+        self._commit_first("drain", self._adopt_finished)
         requeue: List[GenRequest] = list(self._queue)
         self._queue.clear()
         self._queued_interactive = 0

@@ -366,7 +366,7 @@ def test_an_inactive_lane_comes_out_of_decode_untouched(params):
     before = {k: np.asarray(sch._pool[k][:, lane]) for k in ("conv", "ssm")}
     assert np.abs(before["ssm"]).max() > 0
     # a decode step alone (no chunk of the prefilling lane in between)
-    sch._decode_once([])
+    assert sch._dispatch_decode() == 1
     for k in before:
         np.testing.assert_array_equal(np.asarray(sch._pool[k][:, lane]),
                                       before[k])

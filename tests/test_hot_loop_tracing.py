@@ -120,10 +120,24 @@ def test_lane_counts_are_consistent_with_the_slots(tmp_path, monkeypatch):
         assert labels["slots"] == SLOTS
         assert 0 <= labels["lanes_decode"] <= SLOTS
         assert 0 <= labels["lanes_prefill"] <= SLOTS
-        # one token a decoding lane (K = 1, no lane finishes early
-        # without having sampled)
-        assert labels["new_tokens"] == labels["lanes_decode"]
+        # the run-ahead loop's census rides on the same record
+        assert 0 <= labels["lanes_ahead"] <= labels["lanes_decode"]
+        assert labels["overrun_tokens"] == 0  # no EOS in this traffic
         assert (labels["tokens"] > 0) == (labels["lanes_prefill"] > 0)
+    # one token a decoding lane (K = 1), committed an iteration after
+    # its dispatch: ``new_tokens`` counts the tokens an iteration
+    # COMMITS, ``lanes_decode`` the lanes it dispatches
+    assert sum(e["labels"]["new_tokens"] for e in steps) == sum(
+        e["labels"]["lanes_decode"] for e in steps
+    )
+    # the loop ran ahead: every lane of every decode step but the very
+    # first was dispatched before its previous token had been read
+    assert sum(e["labels"]["lanes_ahead"] for e in steps) == sum(
+        e["labels"]["lanes_decode"] for e in steps
+    )
+    assert sch.stats()["ahead_steps"] == sum(
+        e["labels"]["lanes_ahead"] > 0 for e in steps
+    )
     # seven requests on four slots: the batch was full at some point
     assert max(e["labels"]["lanes_decode"] for e in steps) == SLOTS
 

@@ -292,6 +292,27 @@ def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
     assert "all-gather" in text or "all-reduce" in text
 
 
+def _scheduler_decode(model_step, lanes, max_blocks=64):
+    """The decode step as the scheduler jits it
+    (``rl/scheduler.decode_program``, logprobs captured as in the
+    cells): the lanes' token vector in and out, ONE packed upload of
+    tables, positions and active mask, the keys resident — in the
+    argument order of this file's harness (pool third, donated)."""
+    from dlrover_tpu.rl.scheduler import decode_program
+
+    prog = decode_program(model_step, 1.0, True, max_blocks)
+    rest = [
+        ((lanes,), jnp.int32), ((lanes, max_blocks + 2), jnp.int32),
+        ((lanes, 2), jnp.uint32),
+    ]
+    return (
+        lambda params, tokens, pool, packed, keys: prog(
+            params, pool, tokens, packed, keys
+        ),
+        rest,
+    )
+
+
 def _llama_step_case(program):
     """A llama step program at ``deepseek7b-rollout-c16``'s geometry:
     DeepSeek-LLM-7B's widths (32 MHA heads of 128) at depth 5, the
@@ -316,7 +337,10 @@ def _llama_step_case(program):
             ((1, 128), i32), ((64,), i32), ((), i32),
         ]
     elif program == "decode":
-        fn, rest = llama.paged_decode_step, [((16,), i32)] + lanes
+        fn, rest = _scheduler_decode(
+            partial(llama.paged_decode_step, cfg=cfg), 16
+        )
+        return fn, params, pool_shape, {}, rest, 64 * 2**20
     else:
         fn = (
             llama.paged_verify_step if program == "verify"
@@ -351,9 +375,10 @@ def _falcon_h1_step_case(program):
             ((1, 128), i32), ((64,), i32), ((), i32), ((), i32), ((), i32),
         ]
     else:
-        fn, rest = falcon_h1.paged_decode_step, [
-            ((32,), i32), ((32, 64), i32), ((32,), i32), ((32,), jnp.bool_),
-        ]
+        fn, rest = _scheduler_decode(
+            partial(falcon_h1.paged_decode_step, cfg=cfg), 32
+        )
+        return fn, params, pool_shape, state, rest, 64 * 2**20
     # the prefill chunk's matmuls take each layer's larger matrices as
     # buffers of their own (w_gate, w_up, w_down 210 MiB each, in_proj
     # 90: 0.47 GiB live at once; 0.99 with the pool's copies before PR
