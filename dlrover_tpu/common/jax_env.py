@@ -7,13 +7,15 @@ backend itself.  The helpers here let such a parent decide what it has
 to from the environment alone:
 
 - where the persistent XLA compile cache lives
-  (:func:`compile_cache_dir` / :func:`export_compile_cache`);
+  (:func:`compile_cache_dir` / :func:`export_compile_cache`), and which
+  short compiles it keeps all the same (:func:`kept_in_compile_cache`);
 - which platform its children will get (:func:`platform_from_env`);
 - whether anything in this process initialised a backend after all
   (:func:`backend_initialized` — the guard the zygote and the tests
   use).
 """
 
+import contextlib
 import os
 import sys
 from typing import Dict, MutableMapping
@@ -56,6 +58,24 @@ def export_compile_cache(
         os.makedirs(path, exist_ok=True)
         env[COMPILE_CACHE_ENV] = path
     return path
+
+
+@contextlib.contextmanager
+def kept_in_compile_cache():
+    """Whatever compiles inside is written to the persistent cache
+    however short its compile: JAX keeps nothing that compiled in under
+    a second, which is right for a one-off eager op and wrong for a
+    program that EVERY process of a kind compiles at its start.  The
+    process's threshold is put back on the way out."""
+    import jax
+
+    name = "jax_persistent_cache_min_compile_time_secs"
+    usual = getattr(jax.config, name)
+    jax.config.update(name, 0.0)
+    try:
+        yield
+    finally:
+        jax.config.update(name, usual)
 
 
 def platform_from_env() -> str:

@@ -45,8 +45,10 @@ from dlrover_tpu.models.llama import (
     _apply_rope_rows,
     apply_rope,
     dot_product_attention,
+    qkv_heads,
     rms_norm,
     rope_frequencies,
+    serving_copy,
 )
 from dlrover_tpu.ops.ssm import ssd_chunk_scan, ssm_decode_update
 
@@ -151,9 +153,9 @@ class FalconH1Config:
 
 # ---------------------------------------------------------------- params
 
-_MATMUL_LEAVES = (
-    "in_proj", "out_proj", "wq", "wk", "wv", "wo",
-    "w_gate", "w_up", "w_down",
+# of the serving copy, which holds ``wq``, ``wk``, ``wv`` fused
+_SERVING_MATMUL_LEAVES = (
+    "in_proj", "out_proj", "wqkv", "wo", "w_gate", "w_up", "w_down"
 )
 
 
@@ -232,25 +234,17 @@ def init_params(key, cfg: FalconH1Config) -> Dict:
 
 def serving_params(params: Dict, cfg: FalconH1Config) -> Dict:
     """The tree the serving programs compute on: the embedding, the
-    head and the nine matrices of a layer in ``cfg.dtype``, the small
-    float32 leaves (norms, conv, ``dt_bias``, ``A_log``, ``D``) as
-    given.  A leaf already in that dtype is returned as the same
-    array, so a checkpoint published in the compute dtype is served
-    without a copy."""
-    dt = jnp.dtype(cfg.dtype)
-
-    def cast(x):
-        return x if x.dtype == dt else x.astype(dt)
-
-    layers = dict(params["layers"])
-    for name in _MATMUL_LEAVES:
-        layers[name] = cast(layers[name])
-    return {
-        **params,
-        "embed": cast(params["embed"]),
-        "layers": layers,
-        "lm_head": cast(params["lm_head"]),
-    }
+    head and the matrices of a layer in ``cfg.dtype``, with ``wq``,
+    ``wk`` and ``wv`` held as ONE leaf ``wqkv`` ``[L, D, (heads + 2 *
+    kv_heads) * head_dim]`` that one matmul reads in place (the three
+    are not in the returned tree); the small float32 leaves (norms,
+    conv, ``dt_bias``, ``A_log``, ``D``) as given.  Made by
+    ``llama.serving_copy`` in one jitted program; every leaf that needs
+    neither cast nor fusion is returned as the same array, so of a
+    checkpoint published in the compute dtype only the fused leaf is
+    new, and a tree that is already a serving copy comes back as it
+    is."""
+    return serving_copy(params, cfg.dtype, _SERVING_MATMUL_LEAVES)
 
 
 # ---------------------------------------------------------------- pieces
@@ -337,18 +331,11 @@ def _mlp(x, lp, cfg: FalconH1Config):
 
 def _qkv(h, lp, cfg: FalconH1Config):
     dt = cfg.dtype
-    lead = h.shape[:-1]
-    h = h * jnp.asarray(cfg.attention_in_multiplier, dt)
-    q = _proj(h, lp["wq"], dt).reshape(
-        lead + (cfg.num_attention_heads, cfg.head_dim)
+    q, k, v = qkv_heads(
+        h * jnp.asarray(cfg.attention_in_multiplier, dt), lp, dt,
+        cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
     )
-    k = (
-        _proj(h, lp["wk"], dt) * jnp.asarray(cfg.key_multiplier, dt)
-    ).reshape(lead + (cfg.num_key_value_heads, cfg.head_dim))
-    v = _proj(h, lp["wv"], dt).reshape(
-        lead + (cfg.num_key_value_heads, cfg.head_dim)
-    )
-    return q, k, v
+    return q, k * jnp.asarray(cfg.key_multiplier, dt), v
 
 
 def _logits(x, params, cfg: FalconH1Config):

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.common.jax_env import kept_in_compile_cache
 from dlrover_tpu.parallel import sharding as sh
 
 
@@ -615,41 +616,121 @@ def prefill(
 
 # ----------------------------------------------- paged (block-table) decode
 
-# the leaves of ``layers`` that the serving programs below cast
-# (``proj``: ``w.astype(dt)``); the norm scales stay as they are —
-# ``rms_norm`` scales in fp32, and casting them would change the result
-_SERVING_MATMUL_LEAVES = (
-    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"
-)
+# the leaves of the serving copy's ``layers`` that the serving programs
+# below cast (``proj``: ``w.astype(dt)``; ``wqkv`` stands for the three
+# of ``_QKV_LEAVES``); the norm scales stay as they are — ``rms_norm``
+# scales in fp32, and casting them would change the result
+_SERVING_MATMUL_LEAVES = ("wqkv", "wo", "w_gate", "w_up", "w_down")
+_QKV_LEAVES = ("wq", "wk", "wv")
+
+
+@partial(jax.jit, static_argnames="dtype")
+def _cast_and_fuse(work: Dict, dtype) -> Dict:
+    """ONE program for everything a serving copy has to write: every
+    leaf of ``work`` in ``dtype``, and where its ``layers`` hold ``wq``,
+    ``wk``, ``wv``, the three as one leaf ``wqkv`` (q's columns, then
+    k's, then v's).  One compile and one dispatch an adoption where a
+    cast and a ``concatenate`` a leaf were a program each, and nothing
+    is left beside the result: compiled for the chip at 7B widths it
+    holds no temporary bytes (``tests/test_tpu_compile.py``).  Nothing
+    is donated: the given leaves are the caller's.  Jitted at module
+    level, so a second copy of the same shapes compiles nothing."""
+    out = jax.tree_util.tree_map(lambda x: x.astype(dtype), work)
+    layers = out["layers"]
+    if all(name in layers for name in _QKV_LEAVES):
+        layers["wqkv"] = jnp.concatenate(
+            [layers.pop(name) for name in _QKV_LEAVES], axis=-1
+        )
+    return out
+
+
+def serving_copy(params: Dict, dtype, matmul_leaves) -> Dict:
+    """``params`` as a model's serving programs want them resident:
+    ``embed``, ``lm_head`` and the ``matmul_leaves`` of ``layers`` in
+    ``dtype``, and ``wq``, ``wk``, ``wv`` as ONE leaf ``wqkv`` ``[L, D,
+    (n_heads + 2 * n_kv_heads) * head_dim]`` in their place.  Only the
+    leaves that need either go through ``_cast_and_fuse``, in one call;
+    every other leaf is passed around it and stays the caller's array,
+    and a tree with nothing to do comes back as it is.  Shared with
+    ``models/falcon_h1.py``, whose blocks name the leaves alike."""
+    dt = jnp.dtype(dtype)
+    layers = params["layers"]
+    names = [
+        name for name in matmul_leaves
+        if name in layers and layers[name].dtype != dt
+    ]
+    if "wqkv" not in layers:
+        names += _QKV_LEAVES
+    work = {
+        name: params[name] for name in ("embed", "lm_head")
+        if params[name].dtype != dt
+    }
+    if not (work or names):
+        return params
+    work["layers"] = {name: layers[name] for name in names}
+    # every serving process compiles this program at its start, in about
+    # half a second: kept in the persistent cache, a replica's second
+    # start loads it (the five eager casts it replaces were five
+    # compiles a start)
+    with kept_in_compile_cache():
+        done = _cast_and_fuse(work, dt)
+    kept = {
+        name: leaf for name, leaf in layers.items() if name not in names
+    }
+    return {**params, **done, "layers": {**kept, **done["layers"]}}
 
 
 def serving_params(params: Dict, cfg: LlamaConfig) -> Dict:
-    """The tree the serving programs below compute on, made ONCE: every
-    leaf they cast on entry (``embed``, ``lm_head`` and the seven matmul
-    weights of ``layers``) in ``cfg.dtype``, everything else as given.
-    The programs' own ``.astype(dt)`` is then a no-op, so a caller that
-    serves many steps from unchanged weights (``rl/scheduler.py``) pays
-    the cast — at 7B widths more HBM traffic than the step's matmuls —
-    once per adoption, not once per step, with bit-identical results.
+    """The tree the serving programs below compute on, made ONCE.
 
-    A leaf already in ``cfg.dtype`` is returned as the SAME array: a
-    float32-compute model, or a caller that already holds compute-dtype
-    weights, copies nothing.  Leaves are cast one at a time, so the
-    transient beside the copy is one leaf."""
-    dt = jnp.dtype(cfg.dtype)
+    Dtype: every leaf they cast on entry (``embed``, ``lm_head`` and the
+    matmul weights of ``layers``) in ``cfg.dtype``, everything else as
+    given.  The programs' own ``.astype(dt)`` is then a no-op, so a
+    caller that serves many steps from unchanged weights
+    (``rl/scheduler.py``) pays the cast — at 7B widths more HBM traffic
+    than the step's matmuls — once per adoption, not once per step.
 
-    def cast(x):
-        return x if x.dtype == dt else x.astype(dt)
+    Layout: ``wq``, ``wk`` and ``wv`` are held as one leaf ``wqkv`` and
+    are NOT in the returned tree.  Cut out of the stacked ``[L, D, D]``
+    leaves one by one, each was materialised in a buffer of its own and
+    copied into another layout before its matmul, in every layer of
+    every step program (a fifth of a decode step's device time at 7B
+    widths); the fused leaf is read in place by one matmul, like ``wo``.
+    The programs take either tree and give the same result
+    (``qkv_heads``); checkpoints, published policies and training keep
+    the three leaves.
 
-    layers = dict(params["layers"])
-    for name in _SERVING_MATMUL_LEAVES:
-        layers[name] = cast(layers[name])
-    return {
-        **params,
-        "embed": cast(params["embed"]),
-        "layers": layers,
-        "lm_head": cast(params["lm_head"]),
-    }
+    The copy is ONE jitted program (``serving_copy``), cast and fusion
+    in one pass.  Every leaf that needs neither is returned as the SAME
+    array, and so is the whole of a tree that is already a serving
+    copy."""
+    return serving_copy(params, cfg.dtype, _SERVING_MATMUL_LEAVES)
+
+
+def qkv_heads(h, lp, dt, nh: int, nkv: int, hd: int):
+    """``h [..., D]`` -> ``q [..., nh, hd]``, ``k`` and ``v`` ``[...,
+    nkv, hd]`` in ``dt``, before the rope: one matmul and a split where
+    ``lp`` is a layer of the serving copy (``wqkv``), three where it is
+    a layer of the training tree — chosen by the tree's keys at trace
+    time, with the same result."""
+
+    def proj(w):
+        return jnp.matmul(
+            h, w.astype(dt), preferred_element_type=jnp.float32
+        ).astype(dt)
+
+    if "wqkv" in lp:
+        q, k, v = jnp.split(
+            proj(lp["wqkv"]), (nh * hd, (nh + nkv) * hd), axis=-1
+        )
+    else:
+        q, k, v = (proj(lp[name]) for name in _QKV_LEAVES)
+    lead = h.shape[:-1]
+    return (
+        q.reshape(lead + (nh, hd)),
+        k.reshape(lead + (nkv, hd)),
+        v.reshape(lead + (nkv, hd)),
+    )
 
 
 def _apply_rope_rows(x, cos, sin):
@@ -731,13 +812,9 @@ def paged_decode_step(
             ).astype(dt)
 
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _apply_rope_rows(
-            proj(h, lp["wq"]).reshape(b, 1, nh, hd), cos, sin
-        )
-        k = _apply_rope_rows(
-            proj(h, lp["wk"]).reshape(b, 1, nkv, hd), cos, sin
-        )
-        v = proj(h, lp["wv"]).reshape(b, 1, nkv, hd)
+        q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
+        q = _apply_rope_rows(q, cos, sin)
+        k = _apply_rope_rows(k, cos, sin)
         kv = kv.write(k[:, 0], v[:, 0], blk, off)
         attn = paged_decode_attention(
             q[:, 0], kv.k, kv.v, kv.tables(block_tables), seq_lens
@@ -816,9 +893,8 @@ def paged_verify_step(
             ).astype(dt)
 
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _apply_rope_grid(
-            proj(h, lp["wq"]).reshape(b, c, nh, hd), cos, sin
-        )
+        q, _, _ = qkv_heads(h, lp, dt, nh, nkv, hd)
+        q = _apply_rope_grid(q, cos, sin)
         attn = paged_verify_attention(
             q, kv.k, kv.v, kv.tables(block_tables), safe_pos
         )
@@ -898,13 +974,9 @@ def paged_verify_write_step(
             ).astype(dt)
 
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _apply_rope_grid(
-            proj(h, lp["wq"]).reshape(b, c, nh, hd), cos, sin
-        )
-        k = _apply_rope_grid(
-            proj(h, lp["wk"]).reshape(b, c, nkv, hd), cos, sin
-        )
-        v = proj(h, lp["wv"]).reshape(b, c, nkv, hd)
+        q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
+        q = _apply_rope_grid(q, cos, sin)
+        k = _apply_rope_grid(k, cos, sin)
         kv = kv.write(
             k.reshape(b * c, nkv, hd), v.reshape(b * c, nkv, hd),
             blks, offs,
@@ -976,11 +1048,8 @@ def paged_prefill_chunk(
             ).astype(dt)
 
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = apply_rope(proj(h, lp["wq"]).reshape(b, c, nh, hd), cos, sin)
-        k = apply_rope(
-            proj(h, lp["wk"]).reshape(b, c, nkv, hd), cos, sin
-        )
-        v = proj(h, lp["wv"]).reshape(b, c, nkv, hd)
+        q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         kv = kv.write(k[0], v[0], blks, offs)
         attn = paged_prefill_attention(
             q[0], kv.k, kv.v, kv.tables(block_table), start_pos

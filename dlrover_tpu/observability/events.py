@@ -514,8 +514,11 @@ OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
         "state_resets",
     ),
     # the published generation, where the caller adopts one (the
-    # replica's first sync, from its own template, has none)
-    PHASE_WEIGHT_CAST: ("generation",),
+    # replica's first sync, from its own template, has none); the given
+    # leaves that the serving copy holds fused into another (``wq``,
+    # ``wk``, ``wv`` -> ``wqkv``: 3 where it was made, 0 where the tree
+    # came fused or is served as given)
+    PHASE_WEIGHT_CAST: ("generation", "leaves_fused"),
 }
 
 _NO_ANNOTATION = nullcontext()

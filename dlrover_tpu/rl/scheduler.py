@@ -339,6 +339,19 @@ class ContinuousBatchingScheduler:
       chunk of the same lane while other lanes decode in between, and
       stop at the last real token.  Injected programs are given the
       tree ``sync_weights`` was given.
+    - optionally ``serving_params(tree) -> tree`` (``llama``'s unless
+      ``serving_params_fn`` is injected): the copy the scheduler keeps
+      resident and hands to every step program, made once an adoption
+      (both models: by one jitted program, ``llama.serving_copy``;
+      leaves that need no work stay the caller's arrays).  It may cast
+      leaves to the compute dtype AND re-lay them out — both models
+      hold ``wq``, ``wk``, ``wv`` as one leaf ``wqkv``, which a step
+      program reads in place where it cut the three out of the layer
+      stack and transposed them (``tests/test_tpu_compile.py`` pins the
+      compiled programs) — so its leaves need not line up with the
+      given tree's.  A model's step programs accept the training tree and
+      its serving copy and give the same result on both; nothing but
+      the scheduler's resident copy has the serving layout.
     - how a step program walks its layers: the pool it is handed and
       hands back is stacked, ``k``, ``v`` ``[L, num_blocks, block_size,
       KV, D]``, and DONATED.  Inside, the program CARRIES the pool
@@ -418,10 +431,10 @@ class ContinuousBatchingScheduler:
         self._step_overrun = 0
         self._step_commits = 0
         self._params = None
-        # which leaves the step programs cast is the llama programs'
-        # rule (``llama.serving_params``); injected programs get the
-        # tree ``sync_weights`` was given, unless their model brings
-        # its own rule (``serving_params_fn``)
+        # what the step programs want resident (dtype, fused ``wqkv``)
+        # is the llama programs' rule (``llama.serving_params``);
+        # injected programs get the tree ``sync_weights`` was given,
+        # unless their model brings its own rule (``serving_params_fn``)
         self._serving_params = serving_params_fn or (
             (lambda params: params)
             if paged_decode_fn or paged_prefill_fn or paged_verify_fn
@@ -822,13 +835,16 @@ class ContinuousBatchingScheduler:
 
         What is held where: the caller keeps ITS tree (the replica's
         float32 restore target, the trainer's live state); the
-        scheduler keeps only ``llama.serving_params`` of it — a
-        resident copy in the model's compute dtype, made here once, so
-        that no step program casts weights again.  Where every leaf
-        already has that dtype the "copy" is the caller's arrays (a
-        reference swap, as before), and a scheduler built on injected
-        step programs serves the tree it was given: the cast rule
-        belongs to the llama programs.  The previous copy is dropped
+        scheduler keeps only the model's ``serving_params`` of it — a
+        resident copy in the model's compute dtype and serving layout
+        (``wq``, ``wk``, ``wv`` fused into ``wqkv``), made here once
+        by the model's one jitted copy program (``llama.serving_copy``),
+        so that no step program casts weights or cuts and transposes a
+        projection again.  A leaf that already has that dtype and
+        layout is the caller's array (a tree that is already a serving
+        copy is a reference swap), and a scheduler built on injected
+        step programs serves the tree it was given: the rule belongs
+        to the model's programs.  The previous copy is dropped
         BEFORE the new one is made, so an adoption never holds two.
         One ``weight_cast`` span per call says what happened
         (``generation``: the published generation, where the caller
@@ -853,8 +869,14 @@ class ContinuousBatchingScheduler:
         if self._events is not None and self._events.enabled:
             from dlrover_tpu.observability.events import anchored_now
 
-            leaves_in = self._jax.tree_util.tree_leaves(given)
-            leaves_out = self._jax.tree_util.tree_leaves(served)
+            flatten = self._jax.tree_util.tree_flatten_with_path
+            paths_in, leaves_in = zip(*flatten(given)[0])
+            paths_out, leaves_out = zip(*flatten(served)[0])
+            # a serving copy may re-lay leaves out (``wqkv``), so the
+            # two lists do not line up: a served leaf was copied where
+            # it is none of the given arrays, and a given leaf was
+            # fused where the served tree has none under its name
+            given_ids = {id(x) for x in leaves_in}
             self._events.complete(
                 "weight_cast",
                 anchored_now(t0),
@@ -862,8 +884,9 @@ class ContinuousBatchingScheduler:
                 bytes_in=sum(int(x.nbytes) for x in leaves_in),
                 bytes_out=sum(int(x.nbytes) for x in leaves_out),
                 leaves_cast=sum(
-                    a is not b for a, b in zip(leaves_in, leaves_out)
+                    id(x) not in given_ids for x in leaves_out
                 ),
+                leaves_fused=len(set(paths_in) - set(paths_out)),
                 **(
                     {} if generation is None
                     else {"generation": int(generation)}

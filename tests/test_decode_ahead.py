@@ -12,6 +12,7 @@ keep a recurrent state beside their pages.
 import json
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ if BENCH not in sys.path:
 
 import reference_falcon_h1 as R  # noqa: E402
 
-from dlrover_tpu.models import llama  # noqa: E402
+from dlrover_tpu.models import falcon_h1, llama  # noqa: E402
 from dlrover_tpu.observability import events as ev  # noqa: E402
 from dlrover_tpu.rl.generation_service import falcon_h1_factory  # noqa: E402
 from dlrover_tpu.rl.scheduler import (  # noqa: E402
@@ -373,3 +374,73 @@ def test_records_agree_with_the_counters(model, tmp_path):
         s["lanes_decode"] for s in steps
     )
     assert sch.compile_counts()["decode"] == 1
+
+
+# ------------------------------------------------------ the serving copy
+
+
+def _three_leaf_programs(model):
+    """The model's step programs injected WITHOUT its ``serving_params``
+    rule: the scheduler then serves the tree it is given, ``wq``,
+    ``wk``, ``wv`` apart, through the programs' three-projection
+    branch."""
+    if model.name == "dense":
+        return {
+            kw: partial(getattr(llama, fn), cfg=model.cfg)
+            for kw, fn in (
+                ("paged_decode_fn", "paged_decode_step"),
+                ("paged_prefill_fn", "paged_prefill_chunk"),
+                ("paged_verify_fn", "paged_verify_step"),
+            )
+        }
+    return {
+        k: v for k, v in model.kw.items() if k != "serving_params_fn"
+    }
+
+
+def test_run_ahead_serves_the_same_from_the_fused_copy(model):
+    """The loop one step ahead on the scheduler's own serving copy
+    (``wqkv``: one projection and a split) against the same loop on the
+    training tree: every request's tokens equal and its logprobs to
+    float32 rounding — in the float32 of this file there is no cast, so
+    the copy is the fusion alone, and the CPU's float32 matmul blocks a
+    ``[D, 3D]`` product otherwise than three ``[D, D]`` ones (in the
+    cells' bfloat16 the programs agree to the bit:
+    ``tests/test_serving_weight_cast.py``, ``tests/test_falcon_h1.py``)."""
+    fused = model.scheduler()
+    assert "wqkv" in fused._params["layers"]
+    assert not {"wq", "wk", "wv"} & set(fused._params["layers"])
+    apart = ContinuousBatchingScheduler(
+        model.cfg, SchedulerConfig(**SCHED), capture_logprobs=True,
+        **_three_leaf_programs(model),
+    )
+    apart.sync_weights(model.params[0])
+    assert apart._params is model.params[0]
+    results = []
+    for sch in (fused, apart):
+        model.submit(sch)
+        results.append(by_id(sch.run()))
+        assert sch.stats()["ahead_steps"] > 0
+    assert_same(*results, atol=5e-6)
+
+
+def test_an_adoption_with_a_step_in_flight_compiles_no_second_copy(model):
+    """``sync_weights`` mid-run: the step in flight is committed, the
+    previous serving copy dropped, and the new one made by the program
+    the first adoption compiled — the module-level jitted copy has
+    nothing new to trace for a tree of the same shapes."""
+    from dlrover_tpu.common.jax_env import CompileMeter
+
+    sch = model.scheduler()
+    model.submit(sch)
+    for _ in range(4):
+        sch.step()
+    assert sch._inflight
+    first = sch._params["layers"]["wqkv"]
+    programs = llama._cast_and_fuse._cache_size()
+    meter = CompileMeter()
+    sch.sync_weights(model.params[1])
+    assert meter.snapshot()["compile_s"] == 0
+    assert llama._cast_and_fuse._cache_size() == programs
+    assert sch._params["layers"]["wqkv"] is not first
+    assert len(sch.run()) == len(TRAFFIC)

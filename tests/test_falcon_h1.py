@@ -11,6 +11,7 @@ in its pool.  Every multiplier of the tiny configuration is away from 1.
 import json
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -387,7 +388,177 @@ def test_serve_step_carries_the_state_labels(params, tmp_path):
     assert sum(e["labels"]["state_resets"] for e in steps) == 2
 
 
-# ------------------------------------------ (k) the dense block unchanged
+# ------------------------------------------------- (k) the serving copy
+
+
+def _tiny(kv_heads, dtype=jnp.bfloat16):
+    cfg = falcon_h1.FalconH1Config.tiny(
+        num_key_value_heads=kv_heads, dtype=dtype
+    )
+    return cfg, falcon_h1.init_params(jax.random.PRNGKey(kv_heads), cfg)
+
+
+@pytest.mark.parametrize("kv_heads", [2, 4], ids=["gqa", "mha"])
+def test_serving_params_holds_q_k_v_as_one_fused_leaf(kv_heads):
+    """``wqkv`` ``[L, D, (heads + 2 * kv_heads) * head_dim]`` in place of
+    ``wq``, ``wk``, ``wv``; the caller's tree untouched; idempotent; a
+    tree already in the compute dtype shares every other leaf."""
+    cfg, tree = _tiny(kv_heads)
+    keys = set(tree["layers"])
+    served = falcon_h1.serving_params(tree, cfg)
+    assert set(tree["layers"]) == keys
+    assert set(served["layers"]) == keys - {"wq", "wk", "wv"} | {"wqkv"}
+    q, kv = 4 * cfg.head_dim, kv_heads * cfg.head_dim
+    assert served["layers"]["wqkv"].shape == (2, 64, q + 2 * kv)
+    for name, lo, hi in (
+        ("wq", 0, q), ("wk", q, q + kv), ("wv", q + kv, q + 2 * kv)
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(
+                served["layers"]["wqkv"][..., lo:hi].astype(jnp.float32)
+            ),
+            np.asarray(
+                tree["layers"][name].astype(cfg.dtype).astype(jnp.float32)
+            ),
+        )
+    for name in ("in_proj", "wo", "w_down"):
+        assert served["layers"][name].dtype == cfg.dtype
+    for name in ("norm", "conv_w", "dt_bias", "A_log", "D"):
+        assert served["layers"][name] is tree["layers"][name]
+    assert falcon_h1.serving_params(served, cfg) is served
+    leaves = jax.tree_util.tree_leaves
+    # the seeded tree of cell F: already bfloat16, q/k/v apart
+    bf16 = jax.tree_util.tree_map(lambda x: x, served)
+    w = bf16["layers"].pop("wqkv")
+    bf16["layers"].update(
+        wq=w[..., :q], wk=w[..., q:q + kv], wv=w[..., q + kv:]
+    )
+    ids = {id(x) for x in leaves(bf16)}
+    fresh = [
+        x for x in leaves(falcon_h1.serving_params(bf16, cfg))
+        if id(x) not in ids
+    ]
+    assert [x.shape for x in fresh] == [w.shape]
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
+@pytest.mark.parametrize("kv_heads", [2, 4], ids=["gqa", "mha"])
+def test_step_programs_are_bitwise_equal_on_the_serving_copy(
+    program, kv_heads
+):
+    """The float32 tree (three projections, cast inside) against its
+    serving copy (one fused projection and a split, ``key_multiplier``
+    on the k third): the same logits, K/V pool, conv tails and states to
+    the bit, in the cells' bfloat16."""
+    from dlrover_tpu.rl.kv_cache import init_block_pool, paged_cache_config
+
+    cfg, tree = _tiny(kv_heads)
+    lanes, bs, mb = 3, 4, 4
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+
+    def pool():  # donated by neither call, but rebuilt to be sure
+        zero = init_block_pool(paged_cache_config(cfg, 16, bs, lanes))
+        return {
+            name: jax.random.normal(k, zero[name].shape, zero[name].dtype)
+            for name, k in zip(("k", "v", "conv", "ssm"), keys)
+        }
+
+    tables = jnp.asarray(
+        1 + np.arange(lanes * mb).reshape(lanes, mb), jnp.int32
+    )
+    tokens = jnp.asarray(
+        np.random.default_rng(5).integers(0, 256, (lanes, 8)), jnp.int32
+    )
+    if program == "decode":
+        fn = lambda p: falcon_h1.paged_decode_step(  # noqa: E731
+            p, tokens[:, 0], pool(), tables,
+            jnp.asarray([5, 0, 9], jnp.int32),
+            jnp.asarray([True, False, True]), cfg,
+        )
+    else:
+        fn = lambda p: falcon_h1.paged_prefill_chunk(  # noqa: E731
+            p, tokens[:1], pool(), tables[0], jnp.int32(4),
+            jnp.int32(1), jnp.int32(6), cfg,
+        )
+    fn = jax.jit(fn)
+    given, served = fn(tree), fn(falcon_h1.serving_params(tree, cfg))
+    assert jax.tree_util.tree_structure(
+        given
+    ) == jax.tree_util.tree_structure(served)
+    for a, b in zip(
+        jax.tree_util.tree_leaves(given), jax.tree_util.tree_leaves(served)
+    ):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(
+            np.asarray(a.astype(jnp.float32)),
+            np.asarray(b.astype(jnp.float32)),
+        )
+
+
+@pytest.mark.parametrize(
+    "dtype,cast", [(jnp.float32, 9), (jnp.bfloat16, 1)],
+    ids=["float32-tree", "bf16-tree"],
+)
+def test_weight_cast_says_what_the_hybrid_copy_made(tmp_path, dtype, cast):
+    """One ``weight_cast`` span an adoption: a float32 tree has its nine
+    matrices, embedding and head written (nine leaves, q/k/v as one);
+    cell F's tree, already bfloat16, only the fused leaf; a second
+    adoption of the same shapes compiles nothing and a serving copy is
+    served as given."""
+    from dlrover_tpu.observability.events import EventLogger, read_events
+
+    cfg, tree = _tiny(2)
+    matrices = (
+        "in_proj", "out_proj", "wq", "wk", "wv", "wo", "w_gate", "w_up",
+        "w_down",
+    )
+    tree = {
+        **tree,
+        "embed": tree["embed"].astype(dtype),
+        "lm_head": tree["lm_head"].astype(dtype),
+        "layers": {
+            **tree["layers"],
+            **{k: tree["layers"][k].astype(dtype) for k in matrices},
+        },
+    }
+    path = str(tmp_path / "events.jsonl")
+    sch = ContinuousBatchingScheduler(
+        cfg, SchedulerConfig(**SCHED),
+        paged_decode_fn=partial(falcon_h1.paged_decode_step, cfg=cfg),
+        paged_prefill_fn=partial(falcon_h1.paged_prefill_chunk, cfg=cfg),
+        serving_params_fn=partial(falcon_h1.serving_params, cfg=cfg),
+        events=EventLogger(path),
+    )
+    sch.sync_weights(tree)
+    programs = llama._cast_and_fuse._cache_size()
+    sch.sync_weights(tree, generation=2)
+    assert llama._cast_and_fuse._cache_size() == programs
+    served = sch._params
+    sch.sync_weights(served)
+    assert sch._params is served
+    nbytes = lambda t: sum(  # noqa: E731
+        x.nbytes for x in jax.tree_util.tree_leaves(t)
+    )
+    labels = [
+        e["labels"] for e in read_events(path)
+        if e.get("name") == "weight_cast"
+    ]
+    made = dict(
+        bytes_in=nbytes(tree), bytes_out=nbytes(served), leaves_cast=cast,
+        leaves_fused=3,
+    )
+    assert labels == [
+        made,
+        dict(made, generation=2),
+        dict(
+            bytes_in=nbytes(served), bytes_out=nbytes(served),
+            leaves_cast=0, leaves_fused=0,
+        ),
+    ]
+    assert (nbytes(tree) == nbytes(served)) == (dtype == jnp.bfloat16)
+
+
+# ------------------------------------------ (l) the dense block unchanged
 
 
 def test_a_model_without_lane_state_has_a_pool_of_k_and_v():

@@ -15,6 +15,7 @@ load the TPU's library and pytest-xdist workers each import every test
 file; compiles run in the test's own process for the same reason.
 """
 
+import functools
 import math
 import os
 import re
@@ -403,10 +404,41 @@ _MOVES = re.compile(
 )
 
 
+@pytest.fixture(scope="module")
+def compiled_step(one_chip):
+    """``program -> (compiled, params, pool_shape, temp_limit)``: a
+    serving step program at its cell's geometry (serving tree from the
+    model's ``serving_params``, Pallas backend, pool donated), compiled
+    once for the pins below."""
+    from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    @functools.cache
+    def build(program):
+        fn, params, pool_shape, state, rest, temp_limit = STEP_PROGRAMS[
+            program
+        ]()
+        pool = {"k": spec(pool_shape, BF16), "v": spec(pool_shape, BF16)}
+        pool.update({leaf: spec(*sd) for leaf, sd in state.items()})
+        tokens, *after = [spec(*sd) for sd in rest]
+        # the cells' backend: ``auto`` would read the sandbox's CPU
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv(PAGED_KERNEL_ENV, "pallas")
+            compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+                jax.tree_util.tree_map(
+                    lambda a: spec(a.shape, a.dtype), params
+                ),
+                tokens, pool, *after,
+            ).compile()
+        return compiled, params, pool_shape, temp_limit
+
+    return build
+
+
 @pytest.mark.parametrize("program", sorted(STEP_PROGRAMS))
-def test_step_program_carries_the_pool_in_place(
-    program, one_chip, monkeypatch
-):
+def test_step_program_carries_the_pool_in_place(program, compiled_step):
     """A serving step program compiled at its cell's geometry with the
     pool donated: the K/V pool rides in the layer scan's carry
     (``ops/paged_attention.scan_layers_over_pool``), so the program
@@ -414,26 +446,7 @@ def test_step_program_carries_the_pool_in_place(
     neither the pool nor one layer of it — scanned in and out it was
     sliced, copied and re-stacked every step (1.97 GiB of temporaries
     and 9 GB moved a step at C's geometry, PR 28)."""
-    from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV
-
-    # the cells' backend: ``auto`` would read the sandbox's CPU
-    monkeypatch.setenv(PAGED_KERNEL_ENV, "pallas")
-    fn, params, pool_shape, state, rest, temp_limit = STEP_PROGRAMS[
-        program
-    ]()
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    pool = {"k": spec(pool_shape, BF16), "v": spec(pool_shape, BF16)}
-    pool.update({leaf: spec(*sd) for leaf, sd in state.items()})
-    params = jax.tree_util.tree_map(
-        lambda a: spec(a.shape, a.dtype), params
-    )
-    tokens, *after = [spec(*sd) for sd in rest]
-    compiled = jax.jit(fn, donate_argnums=(2,)).lower(
-        params, tokens, pool, *after
-    ).compile()
+    compiled, _, pool_shape, temp_limit = compiled_step(program)
     mem = compiled.memory_analysis()
     pool_elems = math.prod(pool_shape)
     if program != "llama-verify_w4":  # read-only: it returns no pool
@@ -447,6 +460,122 @@ def test_step_program_carries_the_pool_in_place(
         in (pool_elems, pool_elems // pool_shape[0])
     ]
     assert not moved, moved
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+_BF16_RESULT = re.compile(
+    r"^(?:ROOT )?%[\w.\-]+ = bf16\[([\d,]+)\][^ ]* ([\w\-]+)\("
+)
+# a name for a buffer that is already there, not a buffer of its own
+_VIEWS = {"parameter", "get-tuple-element", "bitcast"}
+
+
+def _materialised_bf16(text):
+    """``(elements, opcode, line)`` of every bfloat16 array that an
+    instruction OUTSIDE a fusion body produces: a buffer the program
+    writes (a fusion's result, a ``copy``, a ``dynamic-slice``), where
+    an instruction inside a fusion body is a value in flight."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    out, inside_fusion = [], False
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside_fusion = head.group(1) in fused
+            continue
+        m = None if inside_fusion else _BF16_RESULT.match(line.strip())
+        if m and m.group(2) not in _VIEWS:
+            out.append((
+                math.prod(map(int, m.group(1).split(","))), m.group(2),
+                line.strip()[:160],
+            ))
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(STEP_PROGRAMS))
+def test_step_program_reads_the_qkv_projection_in_place(
+    program, compiled_step
+):
+    """The serving copy holds ``wq``, ``wk``, ``wv`` as one leaf
+    ``wqkv``, and the compiled program reads a layer of it inside the
+    matmul's fusion, like ``wo``: it writes no buffer the size of one
+    layer's ``wq``, ``wk``, ``wv`` or ``wqkv``.  Held apart, each was
+    cut out of the ``[L, D, D]`` stack into a buffer of its own
+    (``constant_dynamic-slice_fusion``) and copied into another layout
+    (``copy``) before its matmul, in every layer of every decode step
+    and prefill chunk: 21 % of C's device time, 7 % of F's (ledger,
+    PR 30).  The verify programs (64 rows) read the three in place
+    before, too, and pass on either layout."""
+    compiled, params, pool_shape, _ = compiled_step(program)
+    _, heads, dim = params["layers"]["wo"].shape  # [L, heads * hd, D]
+    kv = pool_shape[3] * pool_shape[4]  # kv_heads * hd
+    sizes = {dim * heads, dim * kv, dim * (heads + 2 * kv)}
+    buffers = _materialised_bf16(compiled.as_text())
+    assert buffers, "the reader found no instruction at all"
+    written = [b for b in buffers if b[0] in sizes]
+    assert not written, written
+
+
+def _copy_case(cell):
+    """``(work, dtype)`` of the one program that writes a replica's
+    serving copy (``llama._cast_and_fuse``) in a cell: C's float32
+    template has every matrix cast and q/k/v fused; F's tree is
+    bfloat16 as published, so only its q/k/v go through."""
+    from dlrover_tpu.models import falcon_h1, llama
+
+    if cell == "deepseek7b-rollout-c16":
+        cfg = llama.LlamaConfig(
+            vocab_size=102400, dim=4096, n_layers=5, n_heads=32,
+            n_kv_heads=32, mlp_dim=11008, max_seq_len=1024, dtype=BF16,
+        )
+        tree = jax.eval_shape(
+            lambda: llama.init_params(jax.random.PRNGKey(0), cfg)
+        )
+        names = llama._QKV_LEAVES + llama._SERVING_MATMUL_LEAVES[1:]
+        return {
+            "embed": tree["embed"], "lm_head": tree["lm_head"],
+            "layers": {k: tree["layers"][k] for k in names},
+        }
+    cfg = falcon_h1.FalconH1Config(
+        vocab_size=261120, num_hidden_layers=6, max_seq_len=1024
+    )
+    tree = jax.eval_shape(
+        lambda: falcon_h1.init_params(jax.random.PRNGKey(0), cfg)
+    )
+    return {
+        "layers": {
+            k: jax.ShapeDtypeStruct(tree["layers"][k].shape, BF16)
+            for k in llama._QKV_LEAVES
+        }
+    }
+
+
+@pytest.mark.parametrize(
+    "cell,out_bytes",
+    [
+        ("deepseek7b-rollout-c16", 3_701_473_792),
+        ("falconh1-34b-rollout-c32", 6 * 5120 * 3584 * 2),
+    ],
+)
+def test_the_serving_copy_is_one_program_without_temporaries(
+    cell, out_bytes, one_chip
+):
+    """A replica's serving copy is written by ONE compiled program: at
+    the cell's size it returns the copy's bytes (C: 3.70 GB, every
+    matrix in bfloat16 with ``wqkv`` ``[5, 4096, 12288]``; F: the fused
+    leaf alone, 0.22 GB) and holds nothing beside them — the casts'
+    intermediates live in the outputs' own allocation — so an adoption
+    peaks at the template plus the copy."""
+    from dlrover_tpu.models import llama
+
+    work = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        _copy_case(cell),
+    )
+    compiled = llama._cast_and_fuse.lower(work, jnp.dtype(BF16)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == out_bytes
+    assert mem.temp_size_in_bytes == 0
+    assert mem.alias_size_in_bytes == 0  # nothing donated
 
 
 def test_ssm_state_is_updated_in_place(one_chip):
