@@ -17,7 +17,30 @@ dict ``cfg``:
    ``serve_factory.factory`` completes with seeded weights;
 4. the plain reference, which imports nothing of the program:
    ``seeded_params(cfg, seed)``, ``token_logprobs(params, tokens,
-   cfg) -> [n, L-1]`` — here ``reference.py``'s;
+   cfg) -> [n, L-1]`` — here ``reference.py``'s.  A family whose model
+   makes a discrete choice inside (a router's top-k) MAY also provide
+   ``token_logprobs_forced(params, tokens, cfg, served) -> (logprobs
+   [n, L-1] float32, slack [n, L-1] float32)``: ``served`` holds what
+   the served side's replies carried under ``per_token``, each name an
+   array ``[n, L, ...]`` (``rollout_cell.py``'s docstring; in
+   ``sample.npz`` ``served_<name>``).  The float32 reference takes, at
+   every position and layer, the choices the served side made in place
+   of its own, computes gates and everything else itself, and reports
+   per position how far — under ITS OWN float32 selection scores on
+   that forced path — the worst choice taken lies below the best one
+   left out: 0 where the taken set is a valid choice of its scores,
+   ``inf`` where a row is malformed (an id out of range, a duplicate,
+   -1 at a computed position, a wrong count).  ``reference_check.py``
+   calls it where the module has it (and fails where the sample then
+   holds no ``served_*`` array); the cell's traffic file must then hold
+   ``routing_slack_max`` beside ``logprob_tol``, set like it from sound
+   runs and controls on the chip.  Such a family's configuration file
+   states under ``assumed``: ``routing_slack`` — the unit of the slack
+   and why it is that — and ``served_arrays`` — ``{name: {"dtype",
+   "per_position": [...]}}``, each array's shape after the position
+   axis, a key of the file an entry
+   (``tests/test_family_contract.py`` builds its filler from it; the
+   rehearsal: ``tests/tiny/data/family_rehearsal_sparse.py``);
 5. the counts: ``matmul_params(cfg)``, ``total_params(cfg)``,
    ``train_flops_per_token(cfg, seq)`` — here ``flops.py``'s.  For
    sparse experts these count the parameters a token is multiplied

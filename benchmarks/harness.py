@@ -16,7 +16,13 @@ Every number a run's ``correct`` held to a limit (a ``rollout`` cell:
 the largest difference of a served token's logprob from the family's
 float32 reference against the traffic file's ``logprob_tol``; a
 ``train`` cell: that of the first batch and the first loss's) rides
-last in the result line, under ``compared``, beside its limit.
+last in the result line, under ``compared``, beside its limit.  Where a
+``rollout`` cell's family provides ``token_logprobs_forced`` (a model
+with a router: ``family_dense.py``, point 4), the program's replies
+carry ``result["per_token"]``, the reference is forced onto those
+choices (``sample.npz``: ``served_<name>``), and a second number rides
+there: the largest slack of a served choice under the reference's own
+scores against the traffic file's ``routing_slack_max``.
 
 The process that calls this never initialises a JAX backend: a parent
 that touched JAX would hold the chip its children need.  The platform

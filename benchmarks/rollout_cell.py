@@ -6,6 +6,23 @@ The engine call and the per-request checks are copied from
 This process is the serving PARENT: it never initialises a JAX backend;
 the replica it spawns owns the chip, and the reference check runs in a
 child of its own after the replica has exited.
+
+**What a reply may carry for the reference check (the contract a
+program with a router is built to; ISSUE 36).**  Beside ``tokens`` and
+``logprobs`` a reply's ``result`` MAY hold ``per_token``: a dict ``name
+-> array`` whose first axis is the request's positions
+(``result["tokens"].size``, prompt and answer).  Row ``j`` is what the
+program decided while it COMPUTED position ``j`` — for a router, the
+experts it sent that position to, ``[positions, layers, k]`` — by the
+prefill program for a prompt's positions as by the decode program for
+the answer's; a position never computed, such as the last new token,
+holds -1 in an integer array and NaN in a float one.  The harness asks
+the engine for nothing new: a program that has such arrays returns them
+whenever logprobs are captured.  ``reference_check`` writes each name
+to ``sample.npz`` as ``served_<name>`` ``[n, max_seq_len, ...]``, padded
+the same way, and a family with ``token_logprobs_forced``
+(``family_dense.py``, point 4) is forced onto them.  A reply without
+the key adds nothing: ``sample.npz`` is then the file it always was.
 """
 
 import contextlib
@@ -173,29 +190,59 @@ def check_request(row):
     )
 
 
+def write_sample(path, sample, width):
+    """``sample.npz`` of the served requests ``sample``, every array
+    padded to ``width`` positions: ``reference_check.py``'s input.  Each
+    array a reply holds under ``per_token`` rides as ``served_<name>``
+    (this module's docstring); -1 or NaN pads it, as it marks a position
+    the program never computed."""
+    tokens = np.zeros((len(sample), width), np.int32)
+    logprobs = np.full((len(sample), width), np.nan, np.float32)
+    served = {}
+    for i, r in enumerate(sample):
+        res = r["result"]
+        tokens[i, : res["tokens"].size] = res["tokens"]
+        logprobs[i, : r["max_new"]] = res["logprobs"]
+        for name, rows in res.get("per_token", {}).items():
+            rows = np.asarray(rows)
+            require(
+                rows.dtype.kind in "if"
+                and rows.shape[:1] == (res["tokens"].size,),
+                f"per_token[{name!r}] of request {r['idx']}: {rows.dtype} "
+                f"{rows.shape} is not one int or float row a position "
+                f"({res['tokens'].size})",
+            )
+            if name not in served:
+                served[name] = np.full(
+                    (len(sample), width) + rows.shape[1:],
+                    -1 if rows.dtype.kind == "i" else np.nan, rows.dtype,
+                )
+            served[name][i, : rows.shape[0]] = rows
+    np.savez(
+        path, tokens=tokens, logprobs=logprobs,
+        prompt_len=np.array([r["prompt"].size for r in sample]),
+        new_tokens=np.array([r["max_new"] for r in sample]),
+        **{f"served_{name}": a for name, a in served.items()},
+    )
+
+
 def reference_check(cell, seed, rows, sandbox, env, expect_platform, notes,
                     compared):
     """The plain reference over a seeded sample of the served requests,
-    in a child process (this one must stay off the backend); the number
-    it holds to the traffic file's limit goes into ``compared``."""
+    in a child process (this one must stay off the backend); each number
+    it holds to a limit of the traffic file goes into ``compared``: the
+    largest difference of an answer token's logprob (``logprob_tol``)
+    and, where the family forces the reference onto the served side's
+    routing, the largest slack of a served choice under the reference's
+    own scores (``routing_slack_max``; no default)."""
     t = cell["traffic"]
     sample = random.Random(seed).sample(
         sorted(rows, key=lambda r: r["idx"]), min(t["reference_sample"],
                                                   len(rows))
     )
-    width = t["max_seq_len"]
-    tokens = np.zeros((len(sample), width), np.int32)
-    logprobs = np.full((len(sample), width), np.nan, np.float32)
-    for i, r in enumerate(sample):
-        tokens[i, : r["result"]["tokens"].size] = r["result"]["tokens"]
-        logprobs[i, : r["max_new"]] = r["result"]["logprobs"]
     path = os.path.join(sandbox.run_dir, "sample.npz")
     out = os.path.join(sandbox.run_dir, "reference.json")
-    np.savez(
-        path, tokens=tokens, logprobs=logprobs,
-        prompt_len=np.array([r["prompt"].size for r in sample]),
-        new_tokens=np.array([r["max_new"] for r in sample]),
-    )
+    write_sample(path, sample, t["max_seq_len"])
     proc = sandbox.popen(
         [sys.executable, os.path.join(BENCH, "reference_check.py"),
          cell["config_path"], str(seed), path, out, expect_platform],
@@ -212,7 +259,7 @@ def reference_check(cell, seed, rows, sandbox, env, expect_platform, notes,
         )
         return False
     got = M.read_jsonl(out)[0]
-    notes.append(
+    note = (
         f"served logprobs vs float32 reference over {got['compared']} "
         f"tokens of {len(sample)} requests: max |diff| "
         f"{got['max_abs_diff']:.4f} (tolerance {t['logprob_tol']})"
@@ -220,7 +267,23 @@ def reference_check(cell, seed, rows, sandbox, env, expect_platform, notes,
     compared["logprob_max_abs_diff"] = {
         "value": got["max_abs_diff"], "limit": t["logprob_tol"]
     }
-    return got["max_abs_diff"] <= t["logprob_tol"]
+    ok = got["max_abs_diff"] <= t["logprob_tol"]
+    if "max_routing_slack" in got:  # the family forced the reference
+        limit = t.get("routing_slack_max")
+        note += (
+            f"; the reference forced onto the served routing at "
+            f"{got['routed_positions']} positions, "
+            f"{got['positions_off_own_topk']} of them off its own top-k: "
+            f"max slack {got['max_routing_slack']:.4f} "
+            + ("(the traffic file has no key 'routing_slack_max')"
+               if limit is None else f"(limit {limit})")
+        )
+        compared["routing_slack_max"] = {
+            "value": got["max_routing_slack"], "limit": limit
+        }
+        ok = ok and limit is not None and got["max_routing_slack"] <= limit
+    notes.append(note)
+    return ok
 
 
 def run_rollout(cell, seed, seconds, trace, expect_platform, sandbox, env):

@@ -15,6 +15,14 @@ family, which a ``model_config`` PR adds for its CPU rehearsal anyway —
 and a family without a training path (``train_parts`` raises
 ``CellFailed``) skips the training cases instead of failing them.
 
+**A family that forces its reference** (``token_logprobs_forced``,
+``family_dense.py`` point 4; ISSUE 36) is held to that function's shape
+contract at the tiny sizes, on filler choices built from its
+configuration's ``assumed.served_arrays``; a family without it skips
+the case.  The tiny tree's own configurations of such families (the
+sparse rehearsal, whose program parts do not exist yet) are held to the
+same case, so that it runs before a benchmark configuration has one.
+
 Each check runs in an interpreter of its own: it initialises a JAX
 backend, and the cell rehearsals of this directory, which may share a
 pytest process with it, check that THEIR process never does.
@@ -36,6 +44,7 @@ with open(os.path.join(harness.REPO, "BENCHMARK.json")) as _f:
     CONFIGS = json.load(_f)["configs"]
 
 TINY_CONFIGS = os.path.join(BENCH, "tests", "tiny", "data", "configs")
+sys.path.append(os.path.dirname(TINY_CONFIGS))  # the rehearsal families
 SEQ = 24
 #: float32 weights, float32 compute on both sides; what differs is the
 #: order of the sums (the program scans over layers, chunks its scan)
@@ -47,6 +56,7 @@ CHECKS = [
     "the_counts_are_the_tree",
     "program_and_reference_agree_per_token",
     "the_training_loss_is_the_reference_mean",
+    "a_forced_reference_returns_two_arrays_of_one_shape",
 ]
 
 
@@ -93,9 +103,9 @@ def tiny_cfg(name):
     return dict(cfg, **sizes)
 
 
-@pytest.mark.parametrize("config", [c["name"] for c in CONFIGS])
-@pytest.mark.parametrize("check", CHECKS)
-def test_contract(check, config):
+def run_check(check, config):
+    """``config``: a name in ``BENCHMARK.json``, or the path of a tiny
+    configuration, which is taken as it is."""
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), check, config],
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
@@ -104,6 +114,32 @@ def test_contract(check, config):
     if proc.returncode == SKIPPED:
         pytest.skip(proc.stderr.strip().splitlines()[-1])
     assert proc.returncode == 0, proc.stderr[-3000:]
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in CONFIGS])
+@pytest.mark.parametrize("check", CHECKS)
+def test_contract(check, config):
+    run_check(check, config)
+
+
+def forcing_tiny_configs():
+    """The tiny tree's configurations whose family forces (importing a
+    family imports neither JAX nor the program)."""
+    return [
+        name for name in sorted(os.listdir(TINY_CONFIGS))
+        if hasattr(
+            harness.family(harness.load_json(os.path.join(TINY_CONFIGS, name))),
+            "token_logprobs_forced",
+        )
+    ]
+
+
+@pytest.mark.parametrize("config", forcing_tiny_configs())
+def test_a_forcing_family_of_the_tiny_tree(config):
+    run_check(
+        "a_forced_reference_returns_two_arrays_of_one_shape",
+        os.path.join(TINY_CONFIGS, config),
+    )
 
 
 def train_parts_or_skip(fam, cfg):
@@ -195,5 +231,36 @@ def the_training_loss_is_the_reference_mean(cfg):
     assert fam.train_flops_per_token(cfg, SEQ) > 6 * fam.matmul_params(cfg)
 
 
+def a_forced_reference_returns_two_arrays_of_one_shape(cfg):
+    import jax.numpy as jnp
+    import numpy as np
+
+    fam = harness.family(cfg)
+    if not hasattr(fam, "token_logprobs_forced"):
+        print(f"{cfg['family']} makes no choice to force", file=sys.stderr)
+        sys.exit(SKIPPED)
+    tokens = np.random.default_rng(7).integers(
+        0, cfg["vocab_size"], size=(2, SEQ + 1), dtype=np.int32
+    )
+    params = fam.seeded_params(cfg, 2**31 + 5)
+    # filler: every choice 0.  Whatever the family makes of that (here a
+    # duplicate, so an infinite slack), the two arrays have their shape
+    served = {
+        name: np.zeros(
+            tokens.shape + tuple(cfg[key] for key in spec["per_position"]),
+            spec["dtype"],
+        )
+        for name, spec in cfg["assumed"]["served_arrays"].items()
+    }
+    logprobs, slack = fam.token_logprobs_forced(params, tokens, cfg, served)
+    for got in (logprobs, slack):
+        assert got.shape == (2, SEQ) and got.dtype == jnp.float32, got
+    assert bool(jnp.all(jnp.isfinite(logprobs)))
+    assert fam.token_logprobs(params, tokens, cfg).shape == (2, SEQ)
+
+
 if __name__ == "__main__":
-    globals()[sys.argv[1]](tiny_cfg(sys.argv[2]))
+    globals()[sys.argv[1]](
+        harness.load_json(sys.argv[2]) if sys.argv[2].endswith(".json")
+        else tiny_cfg(sys.argv[2])
+    )
