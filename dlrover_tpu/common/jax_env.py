@@ -13,12 +13,17 @@ to from the environment alone:
 - whether anything in this process initialised a backend after all
   (:func:`backend_initialized` — the guard the zygote and the tests
   use).
+
+One helper is for the process that OWNS the chip:
+:func:`pinned_host_works`, whether a compiled program can place arrays
+in ``pinned_host`` memory (the host-offloaded optimizer's state, the
+trainer's staged snapshot).
 """
 
 import contextlib
 import os
 import sys
-from typing import Dict, MutableMapping
+from typing import Dict, MutableMapping, Optional
 
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
@@ -76,6 +81,54 @@ def kept_in_compile_cache():
         yield
     finally:
         jax.config.update(name, usual)
+
+
+_PINNED_HOST_PROBED: Optional[bool] = None
+
+
+def pinned_host_works() -> bool:
+    """Whether this backend supports the ``pinned_host`` memory kind
+    (TPU yes; the CPU test mesh no).  Probed once.  Off-TPU a failed
+    probe downgrades host shardings to plain device shardings so the
+    SAME code path runs — with identical math — where no second memory
+    space exists.  On a TPU the probe failing is an error and RAISES:
+    "offloaded" state silently left in HBM is not an offload.
+    Initialises the backend: only a process that owns the chip calls
+    this."""
+    global _PINNED_HOST_PROBED
+    if _PINNED_HOST_PROBED is None:
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import SingleDeviceSharding
+
+        from dlrover_tpu.common.log import default_logger as logger
+
+        try:
+            dev = SingleDeviceSharding(jax.devices()[0])
+            host = dev.with_memory_kind("pinned_host")
+            x = jax.device_put(jnp.zeros((8,)), host)
+            # the users move between memory spaces INSIDE jit
+            # (annotate_device_placement) — CPU accepts the plain
+            # device_put above but cannot lower the in-program form,
+            # so the probe must exercise it
+            fn = jax.jit(
+                lambda a: jax.device_put(
+                    jax.device_put(a, dev) + 1.0, host
+                ),
+                in_shardings=host,
+                out_shardings=host,
+            )
+            jax.block_until_ready(fn(x))
+            _PINNED_HOST_PROBED = True
+        except Exception:  # noqa: BLE001 - any failure means "no"
+            if jax.default_backend() == "tpu":
+                raise
+            _PINNED_HOST_PROBED = False
+            logger.info(
+                "pinned_host memory kind unavailable; host shardings "
+                "fall back to device memory"
+            )
+    return _PINNED_HOST_PROBED
 
 
 def platform_from_env() -> str:

@@ -365,9 +365,14 @@ REQUIRED_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
     # link stay distinguishable in the ledger
     PHASE_DATA_STALL: ("stage",),
     # the synchronous snapshot leg, sized and timed like the checkpoint
-    # data-plane spans, plus WHICH leg it was (staged | copy): 8 GB at
-    # 3.5 GB/s and a 20 ms on-device copy are different stories
-    PHASE_SNAPSHOT_PULL: ("step", "bytes", "throughput_gbps", "mode"),
+    # data-plane spans, plus WHICH leg it was (staged | copy) and where
+    # the one snapshot program put the copy (pinned_host | device): 8 GB
+    # through the host link and a 20 ms on-device copy are different
+    # stories, and a staged snapshot that stayed on the device (a
+    # backend without in-program pinned_host) is a third
+    PHASE_SNAPSHOT_PULL: (
+        "step", "bytes", "throughput_gbps", "mode", "memory_kind",
+    ),
     # checkpoint data-plane spans carry their size and measured
     # bandwidth so throughput regressions surface in the ledger and
     # in bench_goodput's loss breakdown, not only in wall time

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dlrover_tpu.common.jax_env import pinned_host_works
 from dlrover_tpu.common.log import default_logger as logger
 
 # 64M elements = 256 MB per fp32 chunk buffer; the update transient is
@@ -70,48 +71,6 @@ OFFLOAD_QUANT_ENV = "DLROVER_TPU_OFFLOAD_QUANT"
 
 def _buffered_enabled() -> bool:
     return os.getenv(OFFLOAD_BUFFERED_ENV, "1") != "0"
-
-
-_HOST_KIND_PROBED: Optional[bool] = None
-
-
-def _pinned_host_works() -> bool:
-    """Whether this backend supports the ``pinned_host`` memory kind
-    (TPU yes; the CPU test mesh no).  Probed once.  Off-TPU a failed
-    probe downgrades host shardings to plain device shardings so the
-    SAME code path runs — with identical math — where no second memory
-    space exists.  On a TPU the probe failing is an error and RAISES:
-    "offloaded" state silently left in HBM is not an offload."""
-    global _HOST_KIND_PROBED
-    if _HOST_KIND_PROBED is None:
-        from jax.sharding import SingleDeviceSharding
-
-        try:
-            dev = SingleDeviceSharding(jax.devices()[0])
-            host = dev.with_memory_kind("pinned_host")
-            x = jax.device_put(jnp.zeros((8,)), host)
-            # the fused path moves between memory spaces INSIDE jit
-            # (annotate_device_placement) — CPU accepts the plain
-            # device_put above but cannot lower the in-program form,
-            # so the probe must exercise it
-            fn = jax.jit(
-                lambda a: jax.device_put(
-                    jax.device_put(a, dev) + 1.0, host
-                ),
-                in_shardings=host,
-                out_shardings=host,
-            )
-            jax.block_until_ready(fn(x))
-            _HOST_KIND_PROBED = True
-        except Exception:  # noqa: BLE001 - any failure means "no"
-            if jax.default_backend() == "tpu":
-                raise
-            _HOST_KIND_PROBED = False
-            logger.info(
-                "pinned_host memory kind unavailable; host-offload "
-                "shardings fall back to device memory"
-            )
-    return _HOST_KIND_PROBED
 
 
 class OffloadState(NamedTuple):
@@ -365,7 +324,7 @@ class HostOffloadAdamW:
         from jax.sharding import SingleDeviceSharding
 
         dev = SingleDeviceSharding(jax.devices()[0])
-        if _pinned_host_works():
+        if pinned_host_works():
             host = dev.with_memory_kind("pinned_host")
         else:
             host = dev

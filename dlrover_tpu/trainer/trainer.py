@@ -15,7 +15,9 @@ progress reporting, hang detection, loss-spike capture, and metrics.
 
 import json
 import os
+import threading
 import time
+from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Optional
 
@@ -44,6 +46,84 @@ def _batch_tokens(batch) -> int:
     return int(leaves[0].size) if leaves else 0
 
 
+class _HostLeaf:
+    """One leaf of a staged snapshot as the drain sees it: shape, dtype
+    and — when the drain asks, one leaf at a time — its bytes.  The
+    pinned host tree outlives its drain (the next snapshot recycles
+    it) and a ``jax.Array`` keeps every host copy it hands out, so the
+    bytes are read through a throwaway handle on the same buffers: the
+    copy dies with the handle, and a snapshot's peak host memory stays
+    at the tree plus the drain's two leaves."""
+
+    def __init__(self, array):
+        self._array = array
+        self.shape = array.shape
+        self.dtype = array.dtype
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+
+        array = self._array
+        handle = jax.make_array_from_single_device_arrays(
+            array.shape,
+            array.sharding,
+            [shard.data for shard in array.addressable_shards],
+        )
+        return np.asarray(handle, dtype=dtype)
+
+
+def _in_thread(name: str, fn) -> Future:
+    """``fn()`` on a daemon thread of its own; what it returns or
+    raises is the Future's."""
+    done = Future()
+
+    def run():
+        try:
+            done.set_result(fn())
+        except BaseException as e:  # noqa: BLE001 - result() raises it
+            done.set_exception(e)
+
+    threading.Thread(target=run, name=name, daemon=True).start()
+    return done
+
+
+def _snapshot_shardings(state, to_host: bool):
+    """Where the snapshot program puts each leaf of ``state``: the
+    leaf's own sharding — in ``pinned_host`` memory when ``to_host``
+    (no device memory is spent, and every chip of a sharded state
+    copies its own shards), else as it is."""
+
+    def place(leaf):
+        sharding = getattr(leaf, "sharding", None)
+        if to_host and sharding is not None:
+            return sharding.with_memory_kind("pinned_host")
+        return sharding
+
+    return jax.tree_util.tree_map(place, state)
+
+
+def _compile_snapshot_copy(state, shardings, recycled):
+    """THE snapshot program, compiled: a copy of the ``state`` tree
+    (arrays, or their shapes with shardings) onto ``shardings``, with
+    ``recycled`` — the previous snapshot's host tree, or ``None`` —
+    donated, so that the copy lands in the same buffers."""
+
+    def snapshot_copy(state, recycled):
+        del recycled  # its buffers are the outputs'
+        return jax.tree_util.tree_map(jax.numpy.copy, state)
+
+    return (
+        jax.jit(
+            snapshot_copy,
+            out_shardings=shardings,
+            donate_argnums=1,
+            keep_unused=True,
+        )
+        .lower(state, recycled)
+        .compile()
+    )
+
+
 @dataclass
 class TrainingArgs:
     max_steps: int
@@ -62,10 +142,11 @@ class TrainingArgs:
     capture_loss_spikes: bool = False
     spike_dir: str = ""
     metrics_port: int = 0  # 0 = no exporter daemon
-    # snapshot buffering: "auto" picks "copy" (one on-device state
-    # copy, non-blocking drain — transient 2x state HBM) when it fits,
-    # "staged" (leaf-wise device->host, extra HBM = one leaf, but the
-    # step blocks for the transfer) near HBM capacity
+    # snapshot buffering: ONE compiled copy of the state either way.
+    # "auto" picks "copy" (the copy stays on the device, non-blocking
+    # drain — transient 2x state HBM) when it fits, "staged" (the copy
+    # lands in pinned host memory, no extra HBM, but the step blocks
+    # for the transfer) near HBM capacity
     snapshot_mode: str = "auto"
     # host-side sparse embedding tables ({name: KvTable-like}) saved
     # alongside the dense state at every storage-tier step via
@@ -213,6 +294,13 @@ class Trainer:
             else None
         )
         self._snap_fn = None
+        self._snap_prepared = False
+        self._shm_ready = None  # Future of the shm slots' preallocation
+        self._snap_shardings = None
+        self._snap_memory_kind = "device"
+        # a staged snapshot's pinned host tree (first a Future of its
+        # allocation): recycled by the next snapshot, never re-allocated
+        self._snap_host = None
         self._snapshot_mode = (
             None if args.snapshot_mode == "auto" else args.snapshot_mode
         )
@@ -325,14 +413,17 @@ class Trainer:
                             step,
                         )
         self.progress.global_step = start_step
+        if self._engine is not None:
+            self._prepare_snapshots()
         return start_step
 
     # ------------------------------------------------------------- save
     def _resolve_snapshot_mode(self) -> str:
         """"copy" when a second on-device state fits comfortably,
         "staged" otherwise (round-2 advisor: the full jnp.copy is a 2x
-        HBM transient — fatal near capacity; the staged path trades
-        step blocking for bounded memory)."""
+        HBM transient — fatal near capacity; the staged path, whose
+        copy lands in host memory, trades step blocking for bounded
+        device memory)."""
         mode = self._args.snapshot_mode
         if mode != "auto":
             return mode
@@ -364,19 +455,137 @@ class Trainer:
         fits = 2 * state_bytes <= 0.8 * device_memory_bytes()
         return "copy" if fits else "staged"
 
-    @staticmethod
-    def _staged_device_get(state):
-        """Leaf-wise synchronous device->host: zero extra HBM, at the
-        cost of blocking the step for the full transfer.  Runs inline
-        on the training thread, so no later train step can donate the
-        buffers mid-pull — no on-device pinning copy is needed."""
-        import numpy as np
+    def _snapshot_shardings(self):
+        """Each leaf's own sharding, in ``pinned_host`` memory for a
+        ``staged`` snapshot and in device memory for ``copy``.  A
+        backend without in-program ``pinned_host`` (the CPU tests)
+        degrades to the device's own, so the SAME program runs."""
+        from dlrover_tpu.common.jax_env import pinned_host_works
 
-        return jax.tree_util.tree_map(np.asarray, state)
+        to_host = self._snapshot_mode == "staged" and pinned_host_works()
+        self._snap_memory_kind = "pinned_host" if to_host else "device"
+        return _snapshot_shardings(self.state, to_host)
+
+    def _prepare_snapshots(self):
+        """Resolve the snapshot mode and where the snapshot lands."""
+        if self._snap_prepared:
+            return
+        self._snap_prepared = True
+        if self._snapshot_mode is None:
+            self._snapshot_mode = self._resolve_snapshot_mode()
+            logger.info("snapshot mode: %s", self._snapshot_mode)
+        self._snap_shardings = self._snapshot_shardings()
+
+    def _make_room_for_snapshots(self):
+        """For a snapshot that lands in pinned host memory, start making
+        room for it, once, on two helper threads: one allocates the
+        pinned host tree (page-locking 8 GB takes a v5e's host ~12 s,
+        which must not be the first snapshot's stall), the other faults
+        in the two shm slots (the first drains, slower from pinned
+        leaves than from numpy, must end well before the next snapshot,
+        or it is skipped).  Both stall whatever else the process does
+        on the host meanwhile (program loads took 10 s longer beside
+        them): so they start when the first step is in flight — every
+        program is loaded, the training thread mostly waits for the
+        device.  Every later snapshot recycles the tree."""
+        if (
+            self._snap_memory_kind != "pinned_host"
+            or self._snap_host is not None
+        ):
+            return
+        shapes = jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
+            self.state,
+        )
+        zeros = jax.jit(
+            lambda: jax.tree_util.tree_map(
+                lambda s: jax.numpy.zeros(s.shape, s.dtype), shapes
+            ),
+            out_shardings=self._snap_shardings,
+        )
+        self._snap_host = _in_thread(
+            "snapshot-host-room", lambda: jax.block_until_ready(zeros())
+        )
+        self._shm_ready = _in_thread(
+            "snapshot-shm-room",
+            lambda: self._engine.preallocate_like(shapes),
+        )
+
+    def _snapshot_program(self):
+        """ONE compiled copy of the state tree, whatever the mode
+        (built once, compiled once, ahead of the first pull's span):
+        the next train step donates and overwrites ``self.state``'s
+        buffers, the copy stays.  Its second argument is the previous
+        snapshot's host tree, donated: the copy lands in the same
+        buffers (``None`` where the copy stays on the device and dies
+        with its drain).  Kept in the persistent compile cache however
+        short its compile, so a restarted worker loads it."""
+        self._prepare_snapshots()  # (a state set by hand: do it now)
+        self._make_room_for_snapshots()
+        if isinstance(self._snap_host, Future):
+            self._snap_host = self._snap_host.result()
+        if self._snap_fn is None:
+            from dlrover_tpu.common.jax_env import kept_in_compile_cache
+
+            if self._shm_ready is not None:
+                self._shm_ready.result()
+            with kept_in_compile_cache():
+                self._snap_fn = _compile_snapshot_copy(
+                    self.state, self._snap_shardings, self._snap_host
+                )
+        return self._snap_fn
+
+    def _pull_snapshot(self, step: int):
+        """The SYNCHRONOUS leg of a snapshot, on the training thread:
+        one run of the snapshot program, named from inside (a
+        ``snapshot_pull`` span, and the same name on an open profiler
+        trace).  Returns the tree for the engine's drain — the
+        asynchronous ``checkpoint_save`` that follows.  The caller has
+        seen the slot free: the previous drain has ended, and its host
+        tree may be recycled."""
+        snap_fn = self._snapshot_program()
+        if self._snapshot_mode == "staged":
+            # the pull's copies run in series behind the step just
+            # dispatched in any case: wait for it HERE, so that the
+            # span — which the ledger charges as loss — holds the
+            # transfer alone and the step keeps its own compute time
+            jax.block_until_ready(self.state)
+        pull_t0 = time.monotonic()
+        with self._events.leaf("snapshot_pull"):
+            # the previous snapshot's drain has ended (the slot is
+            # free): its host tree is donated, the copy lands there
+            snap = snap_fn(self.state, self._snap_host)
+            if self._snapshot_mode == "staged":
+                # the host copy is whole when the span ends: the drain
+                # reads host memory, and the next step's gap pays
+                # nothing for this snapshot ("copy" returns at the
+                # dispatch, its transfer hides beside the next steps)
+                jax.block_until_ready(snap)
+        if self._events.enabled:
+            pull_s = max(time.monotonic() - pull_t0, 1e-9)
+            nbytes = sum(
+                int(leaf.nbytes)
+                for leaf in jax.tree_util.tree_leaves(snap)
+            )
+            self._events.complete(
+                "snapshot_pull",
+                anchored_now(pull_t0),
+                pull_s,
+                step=step,
+                bytes=nbytes,
+                throughput_gbps=round(nbytes / pull_s / 1e9, 3),
+                mode=self._snapshot_mode,
+                memory_kind=self._snap_memory_kind,
+            )
+        if self._snap_memory_kind == "pinned_host":
+            self._snap_host = snap
+            snap = jax.tree_util.tree_map(_HostLeaf, snap)
+        return snap
 
     def _maybe_checkpoint(self, step: int):
         if self._engine is None:
             return
+        self._make_room_for_snapshots()  # a step is in flight
         from dlrover_tpu.trainer.drain import drain_requested
 
         draining = drain_requested()
@@ -393,51 +602,7 @@ class Trainer:
             return
         if not self._engine.snapshot_slot_free(step):
             return  # previous drain still running: skip, at no cost
-        if self._snapshot_mode is None:
-            self._snapshot_mode = self._resolve_snapshot_mode()
-            logger.info("snapshot mode: %s", self._snapshot_mode)
-        # the SYNCHRONOUS leg, on the training thread: named from
-        # inside (a ``snapshot_pull`` span, and the same name on an
-        # open profiler trace) — the asynchronous drain that follows
-        # is the engine's ``checkpoint_save``
-        if self._snapshot_mode == "staged":
-            # the pull blocks on the step just dispatched in any case:
-            # wait for it HERE, so that the span — which the ledger
-            # charges as loss — holds the transfer alone and the step
-            # keeps its own compute time
-            jax.block_until_ready(self.state)
-        pull_t0 = time.monotonic()
-        with self._events.leaf("snapshot_pull"):
-            if self._snapshot_mode == "staged":
-                # bounded memory: state is already on host, the engine
-                # drain is a pure shm memcpy
-                snap = self._staged_device_get(self.state)
-            else:
-                # snapshot an on-device COPY (cheap HBM->HBM) so the
-                # async device->host drain can proceed while subsequent
-                # train steps donate and overwrite self.state's buffers
-                if self._snap_fn is None:
-                    self._snap_fn = jax.jit(
-                        lambda s: jax.tree_util.tree_map(
-                            jax.numpy.copy, s
-                        )
-                    )
-                snap = self._snap_fn(self.state)
-        if self._events.enabled:
-            pull_s = max(time.monotonic() - pull_t0, 1e-9)
-            nbytes = sum(
-                int(leaf.nbytes)
-                for leaf in jax.tree_util.tree_leaves(snap)
-            )
-            self._events.complete(
-                "snapshot_pull",
-                anchored_now(pull_t0),
-                pull_s,
-                step=step,
-                bytes=nbytes,
-                throughput_gbps=round(nbytes / pull_s / 1e9, 3),
-                mode=self._snapshot_mode,
-            )
+        snap = self._pull_snapshot(step)
         if to_storage:
             self._engine.save_to_storage(
                 step, snap, blocking=False, layouts=self._layouts
@@ -902,13 +1067,23 @@ class Trainer:
             if self._exporter is not None:
                 self._exporter.stop()
             if self._engine is not None:
-                # final snapshot + persist (blocking: the engine pulls
-                # device state itself).  An async drain from the last
-                # in-loop snapshot may still be running — join it first
-                # or the save slot is busy and the persist never comes.
+                # final snapshot + persist, blocking, through the same
+                # snapshot program: a leaf-wise host copy of the state
+                # BESIDE a staged snapshot's host tree does not fit a
+                # host that the job's snapshots already fill.  An async
+                # drain from the last in-loop snapshot may still be
+                # running — join it first or the save slot is busy and
+                # the persist never comes.
                 self._engine.wait_for_snapshot(timeout=600)
-                if self._engine.save_to_storage(step, self.state):
-                    self._engine.wait_for_persist(step, timeout=600)
+                if self._engine.snapshot_slot_free(step):
+                    saved = self._engine.save_to_storage(
+                        step, self._pull_snapshot(step)
+                    )
+                    # the last snapshot is in shm: give the host tree
+                    # back before the persist takes its share of memory
+                    self._snap_host = None
+                    if saved:
+                        self._engine.wait_for_persist(step, timeout=600)
                 if self._sparse_mgr is not None:
                     # join in-flight async writes FIRST: the final step
                     # may equal the last interval step, and two writers

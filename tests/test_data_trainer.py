@@ -130,7 +130,8 @@ class TestPrefetch:
 
 class TestTrainer:
     def _build(self, tmp_path, max_steps, socket_dir,
-               snapshot_mode="auto", sparse_tables=None, **extra_args):
+               snapshot_mode="auto", sparse_tables=None, strategy=None,
+               **extra_args):
         os.environ["DLROVER_TPU_SOCKET_DIR"] = socket_dir
         cfg = LlamaConfig.tiny(remat="none")
         result = auto_accelerate(
@@ -138,12 +139,14 @@ class TestTrainer:
             optimizer=optax.adamw(1e-3),
             init_params_fn=lambda rng: init_params(rng, cfg),
             param_axes=param_logical_axes(cfg),
-            load_strategy=load_strategy({"data": 8, "remat": "none"}),
+            load_strategy=load_strategy(
+                dict(strategy or {"data": 8}, remat="none")
+            ),
         )
         tokens = np.ones((8, 17), dtype=np.int32)
 
         def data_iter():
-            for _ in range(4):
+            for _ in range(max(4, max_steps)):
                 yield {"tokens": tokens}
 
         args = TrainingArgs(
@@ -172,7 +175,8 @@ class TestTrainer:
         assert start >= 4  # at least the last storage save
 
     def test_staged_snapshot_mode_resumes(self, tmp_path):
-        """The bounded-memory (leaf-wise device->host) snapshot path
+        """The bounded-device-memory snapshot path (the one compiled
+        copy, landing in host memory where the backend has it)
         produces checkpoints a fresh trainer restores from (round-2
         advisor: the full-copy snapshot is a 2x HBM transient; staged
         is the near-capacity alternative)."""
@@ -192,9 +196,9 @@ class TestTrainer:
         self, tmp_path, monkeypatch, mode
     ):
         """While the previous snapshot is still draining the next one
-        is skipped BEFORE the state is pulled to the host (staged) or
-        copied on the device (copy): on a v5e the late check stalled
-        every skipped step 3 s and ran "copy" mode out of HBM."""
+        is skipped BEFORE the one snapshot program is built or run,
+        whatever the mode: on a v5e the late check stalled every
+        skipped step 3 s and ran "copy" mode out of HBM."""
         t = self._build(
             tmp_path, max_steps=2, socket_dir=str(tmp_path / "socks5"),
             snapshot_mode=mode,
@@ -207,9 +211,177 @@ class TestTrainer:
         def boom(*_a, **_k):
             raise AssertionError("device work for a skipped snapshot")
 
-        monkeypatch.setattr(t, "_staged_device_get", boom)
+        monkeypatch.setattr(t, "_snapshot_program", boom)
         t._snap_fn = boom
         t._maybe_checkpoint(2)
+
+    def test_staged_snapshot_survives_the_donating_step(
+        self, tmp_path, monkeypatch
+    ):
+        """The snapshot is the state AT its step, bit for bit, although
+        the next train step donates and overwrites the state's buffers
+        before the drain has read a byte."""
+        t = self._build(
+            tmp_path, max_steps=4, socket_dir=str(tmp_path / "socks6"),
+            snapshot_mode="staged",
+        )
+        t._init_or_restore_state()
+        batch = {"tokens": jnp.ones((8, 17), jnp.int32)}
+        t.state, _ = t._fns.train_step(t.state, batch)
+        want = jax.device_get(t.state)
+        held = []
+        drain = t._engine.save_to_memory
+        monkeypatch.setattr(
+            t._engine, "save_to_memory",
+            lambda step, snap, **kw: held.append((step, snap, kw)),
+        )
+        t._maybe_checkpoint(2)
+        for _ in range(2):  # the state's buffers are donated, twice
+            t.state, _ = t._fns.train_step(t.state, batch)
+        jax.block_until_ready(t.state)
+        (step, snap, kw), = held
+        assert drain(step, snap, **dict(kw, blocking=True))
+        got_step, arrays = t._engine._shm_handler.load_state(copy=True)
+        assert got_step == 2
+        flat, _ = jax.tree_util.tree_flatten_with_path(want)
+        assert len(flat) == len(arrays)
+        for path, leaf in flat:
+            got = arrays[jax.tree_util.keystr(path)]
+            assert got.dtype == leaf.dtype and got.shape == leaf.shape
+            assert got.tobytes() == np.asarray(leaf).tobytes(), path
+        # the trained state has moved on: the test compared a snapshot
+        moved = jax.device_get(t.state)["params"]["embed"]
+        assert moved.tobytes() != np.asarray(
+            want["params"]["embed"]
+        ).tobytes()
+        t._engine.close()
+
+    def test_snapshot_program_compiles_once(self, tmp_path):
+        """Three snapshots, ONE compile of ONE program: built at the
+        first snapshot and called as it is after."""
+        import jax.monitoring
+
+        compiled = []
+
+        def listener(event, _secs, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiled.append(kw.get("fun_name"))
+
+        t = self._build(
+            tmp_path, max_steps=6, socket_dir=str(tmp_path / "socks7"),
+            snapshot_mode="staged",
+        )
+        t._init_or_restore_state()
+        batch = {"tokens": jnp.ones((8, 17), jnp.int32)}
+        programs = []
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        try:
+            for step in range(1, 7):
+                t.state, _ = t._fns.train_step(t.state, batch)
+                if step % 2 == 0:
+                    t._maybe_checkpoint(step)
+                    programs.append(t._snap_fn)
+                    assert t._engine.wait_for_snapshot(timeout=60)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listener)
+        assert t._engine.skipped_snapshots == 0
+        assert isinstance(programs[0], jax.stages.Compiled)
+        assert all(fn is programs[0] for fn in programs)
+        assert [n for n in compiled if n and "snapshot_copy" in n] == [
+            "jit(snapshot_copy)"
+        ]
+        t._engine.close()
+
+    @pytest.mark.parametrize(
+        "mode,probe,kind",
+        [
+            ("staged", True, "pinned_host"),
+            ("staged", False, None),
+            ("copy", True, None),
+        ],
+    )
+    def test_snapshot_lands_where_the_mode_and_the_backend_say(
+        self, tmp_path, monkeypatch, mode, probe, kind
+    ):
+        """``staged`` asks for each leaf's OWN sharding in
+        ``pinned_host`` memory where the backend has it in-program;
+        where it has not (this CPU), and in ``copy`` mode, for the
+        device's own.  Read off the requested shardings: nothing is
+        lowered."""
+        from dlrover_tpu.common import jax_env
+
+        t = self._build(
+            tmp_path, max_steps=2, socket_dir=str(tmp_path / "socks8"),
+            snapshot_mode=mode, strategy={"data": 2, "fsdp": 4},
+        )
+        t._init_or_restore_state()
+        monkeypatch.setattr(jax_env, "pinned_host_works", lambda: probe)
+        asked = t._snapshot_shardings()
+        leaves = jax.tree_util.tree_leaves(t.state)
+        placed = jax.tree_util.tree_leaves(asked)
+        assert len(placed) == len(leaves)
+        for leaf, sharding in zip(leaves, placed):
+            if kind is None:
+                assert sharding == leaf.sharding
+            else:
+                assert sharding.memory_kind == kind
+                assert sharding == leaf.sharding.with_memory_kind(kind)
+        assert t._snap_memory_kind == (kind or "device")
+        t._engine.close()
+
+    @pytest.mark.parametrize("sharded", [False, True])
+    def test_host_leaf_hands_the_drain_bytes_and_keeps_none(self, sharded):
+        """What the drain gets for a leaf of a recycled host tree:
+        shape, dtype, and on ``np.asarray`` the bytes — read through a
+        throwaway handle, so no transfer is launched up front
+        (``copy_to_host_async`` is not offered) and no host copy is
+        left on the array the trainer keeps."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from dlrover_tpu.trainer.trainer import _HostLeaf
+
+        mesh = jax.make_mesh((8,), ("x",))
+        sharding = NamedSharding(
+            mesh, PartitionSpec("x") if sharded else PartitionSpec()
+        ).with_memory_kind("pinned_host")
+        want = np.arange(64 * 3, dtype=np.float32).reshape(64, 3)
+        array = jax.device_put(want, sharding)
+        assert array.sharding.memory_kind == "pinned_host"
+        leaf = _HostLeaf(array)
+        assert leaf.shape == (64, 3) and leaf.dtype == np.float32
+        assert not hasattr(leaf, "copy_to_host_async")
+        got = np.asarray(leaf)
+        assert got.tobytes() == want.tobytes()
+        assert array._npy_value is None
+
+    def test_sharded_state_snapshots_and_restores_through_staged(
+        self, tmp_path
+    ):
+        """A state sharded over the mesh (fsdp 4 x data 2) takes the
+        same program, each leaf under its own sharding, and a fresh
+        trainer restores it bit for bit."""
+        sock = str(tmp_path / "socks9")
+        mesh = {"data": 2, "fsdp": 4}
+        t1 = self._build(
+            tmp_path, max_steps=4, socket_dir=sock,
+            snapshot_mode="staged", strategy=mesh,
+        )
+        assert t1.train()["final_step"] == 4
+        assert any(
+            not leaf.sharding.is_fully_replicated
+            for leaf in jax.tree_util.tree_leaves(t1.state)
+        )
+        t2 = self._build(
+            tmp_path, max_steps=6, socket_dir=sock,
+            snapshot_mode="staged", strategy=mesh,
+        )
+        assert t2._init_or_restore_state() == 4
+        for a, b in zip(
+            jax.tree_util.tree_leaves(jax.device_get(t1.state)),
+            jax.tree_util.tree_leaves(jax.device_get(t2.state)),
+        ):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        assert t2._engine.skipped_snapshots == 0
 
     def test_replay_recorder_wired(self, tmp_path):
         """With replay_dir set, the Trainer ring-logs every batch and

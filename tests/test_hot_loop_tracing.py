@@ -21,6 +21,7 @@ import subprocess
 import sys
 import textwrap
 import threading
+import time
 
 import numpy as np
 import optax
@@ -40,6 +41,7 @@ from dlrover_tpu.rl.scheduler import (  # noqa: E402
     ContinuousBatchingScheduler,
     SchedulerConfig,
 )
+from dlrover_tpu.trainer.callbacks import TrainerCallback  # noqa: E402
 from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs  # noqa: E402
 
 CFG = llama.LlamaConfig.tiny(
@@ -239,7 +241,7 @@ def test_snapshot_pull_outranks_step_in_the_ledger():
         x("step", 100.0, 1.0, step=1),
         x("step", 101.0, 4.0, step=2),
         x("snapshot_pull", 102.0, 3.0, step=2, bytes=1,
-          throughput_gbps=1.0, mode="staged"),
+          throughput_gbps=1.0, mode="staged", memory_kind="pinned_host"),
         x("checkpoint_save", 105.0, 0.5, step=2, bytes=1,
           throughput_gbps=1.0),
         x("step", 105.0, 1.0, step=3),
@@ -269,12 +271,20 @@ def _build_trainer(tmp_path, snapshot_mode):
         for _ in range(8):
             yield {"tokens": tokens}
 
+    class Unhurried(TrainerCallback):
+        """A tiny step takes milliseconds, a drain's thread tens of
+        them: leave each snapshot's drain two slow steps to end in, or
+        the next snapshot is skipped as the slot is busy."""
+
+        def on_step_end(self, step, metrics):
+            time.sleep(0.1)
+
     args = TrainingArgs(
         max_steps=6, checkpoint_dir=str(tmp_path / "ckpt"),
         save_memory_interval=2, save_storage_interval=100,
         log_interval=100, micro_batch_size=8, snapshot_mode=snapshot_mode,
     )
-    return Trainer(result, args, data_iter)
+    return Trainer(result, args, data_iter, callbacks=[Unhurried()])
 
 
 @pytest.fixture(scope="module")
@@ -304,6 +314,7 @@ def profiled(tmp_path_factory):
             summary = trainer.train()
         finally:
             jax.profiler.stop_trace()
+        skipped = trainer._engine.skipped_snapshots
         # the copy leg, on the trained state, outside the window
         trainer._engine = None
         copy = _build_trainer(tmp_path / "copy", "copy")
@@ -330,6 +341,7 @@ def profiled(tmp_path_factory):
         "summary": summary,
         "host_events": names,
         "events": ev.read_events(str(path)),
+        "skipped_snapshots": skipped,
     }
 
 
@@ -369,7 +381,8 @@ def test_ledger_over_the_trainers_events_has_useful_time(profiled):
 
 
 @pytest.mark.parametrize("mode,steps", [
-    ("staged", [2, 4, 6]),
+    # the job's final blocking save is a pull of its own, at step 6
+    ("staged", [2, 4, 6, 6]),
     ("copy", [2]),
 ])
 def test_snapshot_pull_span_per_snapshot(profiled, mode, steps):
@@ -385,6 +398,9 @@ def test_snapshot_pull_span_per_snapshot(profiled, mode, steps):
         assert labels["throughput_gbps"] == pytest.approx(
             labels["bytes"] / e["dur"] / 1e9, rel=0.05, abs=2e-3
         )
+        # where the one program put the copy: this backend has no
+        # in-program pinned_host, so both modes stay on the device
+        assert labels["memory_kind"] == "device"
     # ... and a drain behind each staged pull, off the training thread
     if mode == "staged":
         drains = [
@@ -392,6 +408,47 @@ def test_snapshot_pull_span_per_snapshot(profiled, mode, steps):
             if e["name"] == "checkpoint_save" and e["ph"] in ("X", "B")
         ]
         assert set(steps) <= set(drains)
+
+
+def test_every_pull_has_its_drain_and_none_is_skipped(profiled):
+    """A skipped snapshot is a different result, not a faster one: the
+    trainer's run skipped none, and every ``snapshot_pull`` is followed
+    by ONE ``checkpoint_save`` of the same step with the same
+    ``bytes``."""
+    assert profiled["skipped_snapshots"] == 0
+    spans = sorted(
+        (
+            e for e in profiled["events"]
+            if e["ph"] == "X"
+            and e["name"] in ("snapshot_pull", "checkpoint_save")
+        ),
+        key=lambda e: e["wall"],
+    )
+    pulls = [e for e in spans if e["name"] == "snapshot_pull"]
+    assert [
+        (e["labels"]["mode"], e["labels"]["step"]) for e in pulls
+    ] == [
+        ("staged", 2), ("staged", 4), ("staged", 6),
+        ("staged", 6),  # the job's final blocking save
+        ("copy", 2),
+    ]
+    drains = [
+        e for e in spans
+        if e["name"] == "checkpoint_save"
+        # (the saver's shm -> storage write is a checkpoint_save too)
+        and e["labels"].get("stage") != "persist"
+    ]
+    for pull, until in zip(pulls, pulls[1:] + [None]):
+        mine = [
+            d for d in drains
+            if d["wall"] >= pull["wall"]
+            and (until is None or d["wall"] < until["wall"])
+        ]
+        # between two pulls ONE drain, the first pull's
+        assert len(mine) == 1, (pull, mine)
+        for d in mine:
+            assert d["labels"]["step"] == pull["labels"]["step"]
+            assert d["labels"]["bytes"] == pull["labels"]["bytes"]
 
 
 # -------------------------------------------------------------- the lint
@@ -433,12 +490,17 @@ def test_lint_enforces_snapshot_pull_labels(tmp_path):
     bad.write_text(
         "def f(events):\n"
         "    events.complete('snapshot_pull', 0.0, 1.0, step=1,\n"
-        "                    bytes=1, throughput_gbps=1.0)\n"
+        "                    bytes=1, throughput_gbps=1.0,\n"
+        "                    memory_kind='device')\n"
         "    events.complete('snapshot_pull', 0.0, 1.0, step=1,\n"
         "                    bytes=1, throughput_gbps=1.0,\n"
         "                    mode='staged')\n"
+        "    events.complete('snapshot_pull', 0.0, 1.0, step=1,\n"
+        "                    bytes=1, throughput_gbps=1.0,\n"
+        "                    mode='staged', memory_kind='pinned_host')\n"
     )
     proc = _lint(bad)
     assert proc.returncode == 1, proc.stdout + proc.stderr
-    assert "event_schema_violations=1" in proc.stdout, proc.stdout
+    assert "event_schema_violations=2" in proc.stdout, proc.stdout
     assert "missing required label(s) ['mode']" in proc.stdout
+    assert "missing required label(s) ['memory_kind']" in proc.stdout

@@ -599,3 +599,74 @@ def test_ssm_state_is_updated_in_place(one_chip):
         line for line in compiled.as_text().splitlines()
         if " copy(" in line and "f32[6,32,32,128,256]" in line
     ]
+
+
+def _train_state_shapes():
+    """The training cell's state (``mistral-7b-v0.1`` at depth 2, fp32
+    masters + two ``agd`` moments): 8.38 GB in 38 leaves."""
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.optimizers import agd
+
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+        mlp_dim=14336, max_seq_len=2048,
+    )
+
+    def init():
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        return {
+            "params": params,
+            "opt_state": agd(3e-5).init(params),
+            "step": jnp.zeros((), jnp.int32),
+        }
+
+    return jax.eval_shape(init)
+
+
+@pytest.mark.parametrize("mode", ["staged", "copy"])
+def test_the_snapshot_program_spends_no_device_memory(mode, one_chip):
+    """The trainer's ONE snapshot program at the training cell's size.
+    ``staged``: every byte of the copy is a host output (8.38 GB in
+    ``pinned_host`` memory), the recycled host tree is aliased to it
+    whole, and the device holds NOTHING beside the state it reads: no
+    relayout copy of any leaf (a leaf is up to 0.52 GB; 5.43 GiB of the
+    chip belong to the step's temporaries).  ``copy``: the same
+    program with device outputs, a second state and no temporaries."""
+    from dlrover_tpu.trainer import trainer
+
+    def spec(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            tree,
+        )
+
+    shapes = _train_state_shapes()
+    state = spec(shapes, one_chip)
+    state_bytes = sum(
+        math.prod(a.shape) * a.dtype.itemsize
+        for a in jax.tree_util.tree_leaves(shapes)
+    )
+    assert state_bytes == 8_380_465_160
+    to_host = mode == "staged"
+    shardings = trainer._snapshot_shardings(state, to_host)
+    recycled = None
+    if to_host:
+        assert {
+            s.memory_kind for s in jax.tree_util.tree_leaves(shardings)
+        } == {"pinned_host"}
+        recycled = spec(shapes, one_chip.with_memory_kind("pinned_host"))
+    compiled = trainer._compile_snapshot_copy(state, shardings, recycled)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.6 * 2**30  # the issue's line
+    assert mem.temp_size_in_bytes == 0           # what the compiler gives
+    padded = 8_380_466_176  # each leaf rounded up to its tiling
+    if to_host:
+        assert mem.host_output_size_in_bytes == padded
+        assert mem.host_alias_size_in_bytes == padded
+        assert mem.output_size_in_bytes < 4096  # the tuple's pointers
+        assert mem.host_temp_size_in_bytes == 0
+        # one asynchronous copy a leaf, straight from the argument
+        assert compiled.as_text().count("copy-start(") == 38
+    else:
+        assert mem.output_size_in_bytes >= padded
+        assert mem.host_output_size_in_bytes == 0
