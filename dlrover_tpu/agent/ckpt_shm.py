@@ -300,6 +300,13 @@ class SharedMemoryHandler:
                 )
             except FileNotFoundError:
                 return None
+            except ValueError:
+                # another process has created the segment's file and
+                # not yet sized it (``mmap`` refuses an empty file): for
+                # a reader that is "not there yet", like a missing one
+                if create:
+                    raise
+                return None
             except FileExistsError:
                 # a restarted publisher re-attaches the live segment
                 self._gen = SharedMemory(self._gen_name, create=False)

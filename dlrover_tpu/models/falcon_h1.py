@@ -338,6 +338,7 @@ def _qkv(h, lp, cfg: FalconH1Config):
     return q, k * jnp.asarray(cfg.key_multiplier, dt), v
 
 
+@jax.named_scope("head")
 def _logits(x, params, cfg: FalconH1Config):
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return jnp.einsum(
@@ -346,6 +347,7 @@ def _logits(x, params, cfg: FalconH1Config):
     ) * cfg.lm_head_multiplier
 
 
+@jax.named_scope("embed")
 def _embed(params, tokens, cfg: FalconH1Config):
     return params["embed"].astype(cfg.dtype)[tokens] * jnp.asarray(
         cfg.embedding_multiplier, cfg.dtype
@@ -402,6 +404,7 @@ def forward(params: Dict, tokens: jnp.ndarray, cfg: FalconH1Config):
 # ------------------------------------------------------- serving programs
 
 
+@jax.named_scope("prefill")
 def paged_prefill_chunk(
     params: Dict,
     tokens: jnp.ndarray,  # [1, C] one sequence's prompt chunk, padded
@@ -432,67 +435,75 @@ def paged_prefill_chunk(
     valid = steps < real
     positions = start_pos + steps
     x = _embed(params, tokens, cfg)
-    cos, sin = rope_frequencies(cfg, positions)
-    blk_idx = positions // bs
-    blks = jnp.where(
-        valid & (blk_idx < mb), block_table[jnp.minimum(blk_idx, mb - 1)], 0
-    )
-    offs = jnp.where(valid, positions % bs, 0)
+    with jax.named_scope("attn"):
+        cos, sin = rope_frequencies(cfg, positions)
+        blk_idx = positions // bs
+        blks = jnp.where(
+            valid & (blk_idx < mb),
+            block_table[jnp.minimum(blk_idx, mb - 1)], 0,
+        )
+        offs = jnp.where(valid, positions % bs, 0)
     fresh = start_pos == 0
 
     def body(carry, layer_in, kv):
         x, ssm_all = carry
         lp, conv = layer_in
-        h = rms_norm(x, lp["norm"], cfg.rms_norm_eps)
-        z, xbc, dt_raw = _ssm_inputs(h, lp, cfg)
-        tail = jnp.where(
-            fresh, 0.0, lax.dynamic_index_in_dim(conv, lane, 0, False)
-        )
-        window = jnp.concatenate([tail, xbc[0]], axis=0)  # [K-1+C, Cd]
-        xs, b, cc = _split_xbc(_causal_conv(window, lp), cfg)
-        # the inputs of the last K-1 REAL tokens (reaching back into the
-        # old tail where the chunk holds fewer)
-        conv = lax.dynamic_update_index_in_dim(
-            conv, lax.dynamic_slice_in_dim(window, real, k_taps - 1, 0),
-            lane, 0,
-        )
-        state = jnp.where(
-            fresh, 0.0,
-            lax.dynamic_slice(
-                ssm_all, (kv.layer, lane, 0, 0, 0),
-                (1, 1) + ssm_all.shape[2:],
-            )[0],
-        )
-        y, state = ssd_chunk_scan(
-            xs[None],
-            jnp.where(
-                valid[:, None], jax.nn.softplus(dt_raw[0] + lp["dt_bias"]),
-                0.0,
-            )[None],
-            -jnp.exp(lp["A_log"]), b[None], cc[None], lp["D"], state,
-            cfg.mamba_chunk_size,
-        )
-        ssm_all = lax.dynamic_update_slice(
-            ssm_all, state[None].astype(ssm_all.dtype),
-            (kv.layer, lane, 0, 0, 0),
-        )
-        y = _gated_norm(
-            y.reshape(1, c, cfg.mamba_d_ssm), z, lp["ssm_norm"], cfg
-        )
-        m = _proj(y.astype(dt), lp["out_proj"], dt) * jnp.asarray(
-            cfg.ssm_out_multiplier, dt
-        )
-        q, k, v = _qkv(h, lp, cfg)
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        kv = kv.write(k[0], v[0], blks, offs)
-        attn = paged_prefill_attention(
-            q[0], kv.k, kv.v, kv.tables(block_table), start_pos
-        )
-        a = _proj(
-            attn.reshape(1, c, -1), lp["wo"], dt
-        ) * jnp.asarray(cfg.attention_out_multiplier, dt)
-        x = x + m + a
-        x = x + _mlp(x, lp, cfg)
+        with jax.named_scope("attn"):  # the ONE norm of both mixers
+            h = rms_norm(x, lp["norm"], cfg.rms_norm_eps)
+        with jax.named_scope("ssm"):
+            z, xbc, dt_raw = _ssm_inputs(h, lp, cfg)
+            tail = jnp.where(
+                fresh, 0.0, lax.dynamic_index_in_dim(conv, lane, 0, False)
+            )
+            window = jnp.concatenate([tail, xbc[0]], axis=0)  # [K-1+C, Cd]
+            xs, b, cc = _split_xbc(_causal_conv(window, lp), cfg)
+            # the inputs of the last K-1 REAL tokens (reaching back into
+            # the old tail where the chunk holds fewer)
+            conv = lax.dynamic_update_index_in_dim(
+                conv,
+                lax.dynamic_slice_in_dim(window, real, k_taps - 1, 0),
+                lane, 0,
+            )
+            state = jnp.where(
+                fresh, 0.0,
+                lax.dynamic_slice(
+                    ssm_all, (kv.layer, lane, 0, 0, 0),
+                    (1, 1) + ssm_all.shape[2:],
+                )[0],
+            )
+            y, state = ssd_chunk_scan(
+                xs[None],
+                jnp.where(
+                    valid[:, None],
+                    jax.nn.softplus(dt_raw[0] + lp["dt_bias"]),
+                    0.0,
+                )[None],
+                -jnp.exp(lp["A_log"]), b[None], cc[None], lp["D"], state,
+                cfg.mamba_chunk_size,
+            )
+            ssm_all = lax.dynamic_update_slice(
+                ssm_all, state[None].astype(ssm_all.dtype),
+                (kv.layer, lane, 0, 0, 0),
+            )
+            y = _gated_norm(
+                y.reshape(1, c, cfg.mamba_d_ssm), z, lp["ssm_norm"], cfg
+            )
+            m = _proj(y.astype(dt), lp["out_proj"], dt) * jnp.asarray(
+                cfg.ssm_out_multiplier, dt
+            )
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(h, lp, cfg)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            kv = kv.write(k[0], v[0], blks, offs)
+            attn = paged_prefill_attention(
+                q[0], kv.k, kv.v, kv.tables(block_table), start_pos
+            )
+            a = _proj(
+                attn.reshape(1, c, -1), lp["wo"], dt
+            ) * jnp.asarray(cfg.attention_out_multiplier, dt)
+            x = x + m + a
+        with jax.named_scope("mlp"):
+            x = x + _mlp(x, lp, cfg)
         return (x, ssm_all), conv, kv
 
     (x, ssm_all), new_conv, new_k, new_v = scan_layers_over_pool(
@@ -504,6 +515,7 @@ def paged_prefill_chunk(
     }
 
 
+@jax.named_scope("decode")
 def paged_decode_step(
     params: Dict,
     tokens: jnp.ndarray,  # [B] current token per lane
@@ -527,52 +539,59 @@ def paged_decode_step(
     n = tokens.shape[0]
     bs, mb = pool["k"].shape[2], block_tables.shape[1]
     x = _embed(params, tokens, cfg)[:, None]  # [B, 1, D]
-    cos, sin = rope_frequencies(cfg, positions)
-    blk_idx = positions // bs
-    blk = jnp.where(
-        active & (blk_idx < mb),
-        jnp.take_along_axis(
-            block_tables, jnp.minimum(blk_idx, mb - 1)[:, None], axis=1
-        )[:, 0],
-        0,
-    )
-    off = jnp.where(active, positions % bs, 0)
-    seq_lens = jnp.where(active, positions + 1, 1)
+    with jax.named_scope("attn"):
+        cos, sin = rope_frequencies(cfg, positions)
+        blk_idx = positions // bs
+        blk = jnp.where(
+            active & (blk_idx < mb),
+            jnp.take_along_axis(
+                block_tables, jnp.minimum(blk_idx, mb - 1)[:, None], axis=1
+            )[:, 0],
+            0,
+        )
+        off = jnp.where(active, positions % bs, 0)
+        seq_lens = jnp.where(active, positions + 1, 1)
 
     def body(carry, layer_in, kv):
         x, ssm_all = carry
         lp, conv = layer_in
-        h = rms_norm(x, lp["norm"], cfg.rms_norm_eps)
-        z, xbc, dt_raw = _ssm_inputs(h[:, 0], lp, cfg)
-        window = jnp.concatenate([conv, xbc[:, None]], axis=1)  # [B, K, Cd]
-        xs, b, c = _split_xbc(_causal_conv(window, lp)[:, 0], cfg)
-        conv = jnp.where(active[:, None, None], window[:, 1:], conv)
-        y, ssm_all = ssm_decode_update(
-            ssm_all, kv.layer, xs,
-            jnp.where(
-                active[:, None], jax.nn.softplus(dt_raw + lp["dt_bias"]),
-                0.0,
-            ),
-            -jnp.exp(lp["A_log"]), b, c, lp["D"],
-        )
-        y = _gated_norm(
-            y.reshape(n, cfg.mamba_d_ssm), z, lp["ssm_norm"], cfg
-        )
-        m = _proj(y.astype(dt), lp["out_proj"], dt) * jnp.asarray(
-            cfg.ssm_out_multiplier, dt
-        )
-        q, k, v = _qkv(h, lp, cfg)
-        q = _apply_rope_rows(q, cos, sin)
-        k = _apply_rope_rows(k, cos, sin)
-        kv = kv.write(k[:, 0], v[:, 0], blk, off)
-        attn = paged_decode_attention(
-            q[:, 0], kv.k, kv.v, kv.tables(block_tables), seq_lens
-        )
-        a = _proj(
-            attn.reshape(n, 1, -1), lp["wo"], dt
-        ) * jnp.asarray(cfg.attention_out_multiplier, dt)
-        x = x + m[:, None] + a
-        x = x + _mlp(x, lp, cfg)
+        with jax.named_scope("attn"):  # the ONE norm of both mixers
+            h = rms_norm(x, lp["norm"], cfg.rms_norm_eps)
+        with jax.named_scope("ssm"):
+            z, xbc, dt_raw = _ssm_inputs(h[:, 0], lp, cfg)
+            # [B, K, Cd]
+            window = jnp.concatenate([conv, xbc[:, None]], axis=1)
+            xs, b, c = _split_xbc(_causal_conv(window, lp)[:, 0], cfg)
+            conv = jnp.where(active[:, None, None], window[:, 1:], conv)
+            y, ssm_all = ssm_decode_update(
+                ssm_all, kv.layer, xs,
+                jnp.where(
+                    active[:, None],
+                    jax.nn.softplus(dt_raw + lp["dt_bias"]),
+                    0.0,
+                ),
+                -jnp.exp(lp["A_log"]), b, c, lp["D"],
+            )
+            y = _gated_norm(
+                y.reshape(n, cfg.mamba_d_ssm), z, lp["ssm_norm"], cfg
+            )
+            m = _proj(y.astype(dt), lp["out_proj"], dt) * jnp.asarray(
+                cfg.ssm_out_multiplier, dt
+            )
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(h, lp, cfg)
+            q = _apply_rope_rows(q, cos, sin)
+            k = _apply_rope_rows(k, cos, sin)
+            kv = kv.write(k[:, 0], v[:, 0], blk, off)
+            attn = paged_decode_attention(
+                q[:, 0], kv.k, kv.v, kv.tables(block_tables), seq_lens
+            )
+            a = _proj(
+                attn.reshape(n, 1, -1), lp["wo"], dt
+            ) * jnp.asarray(cfg.attention_out_multiplier, dt)
+            x = x + m[:, None] + a
+        with jax.named_scope("mlp"):
+            x = x + _mlp(x, lp, cfg)
         return (x, ssm_all), conv, kv
 
     (x, ssm_all), new_conv, new_k, new_v = scan_layers_over_pool(

@@ -342,22 +342,27 @@ def _layer_forward(
             a, w.astype(dt), preferred_element_type=jnp.float32
         ).astype(dt)
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = proj(h, lp["wq"]).reshape(b, s, nh, hd)
-    k = proj(h, lp["wk"]).reshape(b, s, nkv, hd)
-    v = proj(h, lp["wv"]).reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    q = sh.apply_sharding_constraint(
-        q, (sh.BATCH, sh.SEQ, sh.HEADS, None), _current_rules()
-    )
-    attn = attention_fn(q, k, v, causal=True)
-    x = x + proj(attn.reshape(b, s, nh * hd), lp["wo"])
+    # device scopes (observability/events.py DEVICE_SCOPES): the
+    # backward and the checkpoint's replay keep these names on their
+    # paths, so a device trace splits the step by the model's part
+    with jax.named_scope("attn"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = proj(h, lp["wq"]).reshape(b, s, nh, hd)
+        k = proj(h, lp["wk"]).reshape(b, s, nkv, hd)
+        v = proj(h, lp["wv"]).reshape(b, s, nkv, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        q = sh.apply_sharding_constraint(
+            q, (sh.BATCH, sh.SEQ, sh.HEADS, None), _current_rules()
+        )
+        attn = attention_fn(q, k, v, causal=True)
+        x = x + proj(attn.reshape(b, s, nh * hd), lp["wo"])
 
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    gate = jax.nn.silu(proj(h, lp["w_gate"]))
-    up = proj(h, lp["w_up"])
-    x = x + proj(gate * up, lp["w_down"])
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        gate = jax.nn.silu(proj(h, lp["w_gate"]))
+        up = proj(h, lp["w_up"])
+        x = x + proj(gate * up, lp["w_down"])
     return x
 
 
@@ -412,15 +417,16 @@ def forward_hidden(
     # to move the fsdp axis from dim -1 (table layout) to dim 0 (batch
     # layout) through the gather — an involuntary full remat.  Voluntarily
     # all-gather the (small) table's embed dim first; vocab stays sharded.
-    table = sh.apply_sharding_constraint(
-        params["embed"].astype(dt), (sh.VOCAB, None), _current_rules()
-    )
-    x = table[tokens]
-    x = sh.apply_sharding_constraint(
-        x, (sh.BATCH, sh.SEQ, sh.EMBED), _current_rules()
-    )
-    positions = jnp.arange(s)
-    cos, sin = rope_frequencies(cfg, positions)
+    with jax.named_scope("embed"):
+        table = sh.apply_sharding_constraint(
+            params["embed"].astype(dt), (sh.VOCAB, None), _current_rules()
+        )
+        x = table[tokens]
+        x = sh.apply_sharding_constraint(
+            x, (sh.BATCH, sh.SEQ, sh.EMBED), _current_rules()
+        )
+    with jax.named_scope("attn"):
+        cos, sin = rope_frequencies(cfg, jnp.arange(s))
 
     block = partial(_layer_forward, cfg, attention_fn)
     if cfg.remat == "full":
@@ -450,7 +456,8 @@ def forward_hidden(
     )
     for seg in segments:
         x = execute_layers(block, seg, x, cos, sin)
-    return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("head_loss"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def forward(
@@ -461,12 +468,13 @@ def forward(
 ) -> jnp.ndarray:
     """tokens [B, S] int32 -> logits [B, S, vocab] (fp32)."""
     x = forward_hidden(params, tokens, cfg, attention_fn)
-    logits = jnp.einsum(
-        "bsd,dv->bsv",
-        x,
-        params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head_loss"):
+        logits = jnp.einsum(
+            "bsd,dv->bsv",
+            x,
+            params["lm_head"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
     return logits
 
 
@@ -746,6 +754,7 @@ def _apply_rope_rows(x, cos, sin):
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
 
 
+@jax.named_scope("decode")
 def paged_decode_step(
     params: Dict,
     tokens: jnp.ndarray,  # [B] current token per slot
@@ -787,23 +796,26 @@ def paged_decode_step(
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     bs = pool["k"].shape[2]
     mb = block_tables.shape[1]
-    x = params["embed"].astype(dt)[tokens][:, None]  # [B, 1, D]
-    cos, sin = rope_frequencies(cfg, positions)  # [B, hd/2]
-    # a position past the table (a multi-token draft window running
-    # beyond the sequence's budget) must write to the null block — a
-    # clamped gather would alias the LAST real block and scribble
-    # draft garbage over real K/V
-    blk_idx = positions // bs
-    blk = jnp.where(
-        active & (blk_idx < mb),
-        jnp.take_along_axis(
-            block_tables, jnp.minimum(blk_idx, mb - 1)[:, None],
-            axis=1,
-        )[:, 0],
-        0,
-    )
-    off = jnp.where(active, positions % bs, 0)
-    seq_lens = jnp.where(active, positions + 1, 1)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens][:, None]  # [B, 1, D]
+    # the rope tables and the write routing: attention's, once a step
+    with jax.named_scope("attn"):
+        cos, sin = rope_frequencies(cfg, positions)  # [B, hd/2]
+        # a position past the table (a multi-token draft window running
+        # beyond the sequence's budget) must write to the null block — a
+        # clamped gather would alias the LAST real block and scribble
+        # draft garbage over real K/V
+        blk_idx = positions // bs
+        blk = jnp.where(
+            active & (blk_idx < mb),
+            jnp.take_along_axis(
+                block_tables, jnp.minimum(blk_idx, mb - 1)[:, None],
+                axis=1,
+            )[:, 0],
+            0,
+        )
+        off = jnp.where(active, positions % bs, 0)
+        seq_lens = jnp.where(active, positions + 1, 1)
 
     def body(x, lp, kv):
         def proj(a, w):
@@ -811,29 +823,32 @@ def paged_decode_step(
                 a, w.astype(dt), preferred_element_type=jnp.float32
             ).astype(dt)
 
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
-        q = _apply_rope_rows(q, cos, sin)
-        k = _apply_rope_rows(k, cos, sin)
-        kv = kv.write(k[:, 0], v[:, 0], blk, off)
-        attn = paged_decode_attention(
-            q[:, 0], kv.k, kv.v, kv.tables(block_tables), seq_lens
-        )
-        x = x + proj(attn.reshape(b, 1, nh * hd), lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(proj(h, lp["w_gate"]))
-        up = proj(h, lp["w_up"])
-        x = x + proj(gate * up, lp["w_down"])
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
+            q = _apply_rope_rows(q, cos, sin)
+            k = _apply_rope_rows(k, cos, sin)
+            kv = kv.write(k[:, 0], v[:, 0], blk, off)
+            attn = paged_decode_attention(
+                q[:, 0], kv.k, kv.v, kv.tables(block_tables), seq_lens
+            )
+            x = x + proj(attn.reshape(b, 1, nh * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            gate = jax.nn.silu(proj(h, lp["w_gate"]))
+            up = proj(h, lp["w_up"])
+            x = x + proj(gate * up, lp["w_down"])
         return x, None, kv
 
     x, _, new_k, new_v = scan_layers_over_pool(
         body, x, params["layers"], pool["k"], pool["v"]
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, params["lm_head"].astype(dt),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(dt),
+            preferred_element_type=jnp.float32,
+        )
     return logits[:, 0], {"k": new_k, "v": new_v}
 
 
@@ -850,6 +865,7 @@ def _apply_rope_grid(x, cos, sin):
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
 
 
+@jax.named_scope("verify")
 def paged_verify_step(
     params: Dict,
     tokens: jnp.ndarray,  # [B, C]: window of C tokens per lane
@@ -880,11 +896,14 @@ def paged_verify_step(
     b, c = tokens.shape
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     pos_grid = positions[:, None] + jnp.arange(c)[None]  # [B, C]
-    x = params["embed"].astype(dt)[tokens]  # [B, C, D]
-    cos, sin = rope_frequencies(cfg, pos_grid.reshape(-1))
-    cos = cos.reshape(b, c, -1)
-    sin = sin.reshape(b, c, -1)
-    safe_pos = jnp.where(active, positions, 0)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]  # [B, C, D]
+    # the rope tables and the write routing: attention's, once a step
+    with jax.named_scope("attn"):
+        cos, sin = rope_frequencies(cfg, pos_grid.reshape(-1))
+        cos = cos.reshape(b, c, -1)
+        sin = sin.reshape(b, c, -1)
+        safe_pos = jnp.where(active, positions, 0)
 
     def body(x, lp, kv):
         def proj(a, w):
@@ -892,30 +911,34 @@ def paged_verify_step(
                 a, w.astype(dt), preferred_element_type=jnp.float32
             ).astype(dt)
 
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, _, _ = qkv_heads(h, lp, dt, nh, nkv, hd)
-        q = _apply_rope_grid(q, cos, sin)
-        attn = paged_verify_attention(
-            q, kv.k, kv.v, kv.tables(block_tables), safe_pos
-        )
-        x = x + proj(attn.reshape(b, c, nh * hd), lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(proj(h, lp["w_gate"]))
-        up = proj(h, lp["w_up"])
-        x = x + proj(gate * up, lp["w_down"])
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q, _, _ = qkv_heads(h, lp, dt, nh, nkv, hd)
+            q = _apply_rope_grid(q, cos, sin)
+            attn = paged_verify_attention(
+                q, kv.k, kv.v, kv.tables(block_tables), safe_pos
+            )
+            x = x + proj(attn.reshape(b, c, nh * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            gate = jax.nn.silu(proj(h, lp["w_gate"]))
+            up = proj(h, lp["w_up"])
+            x = x + proj(gate * up, lp["w_down"])
         return x, None
 
     x, _ = scan_layers_over_pool(
         body, x, params["layers"], pool["k"], pool["v"], read_only=True
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, params["lm_head"].astype(dt),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(dt),
+            preferred_element_type=jnp.float32,
+        )
     return logits
 
 
+@jax.named_scope("verify")
 def paged_verify_write_step(
     params: Dict,
     tokens: jnp.ndarray,  # [B, C]: window of C tokens per lane
@@ -950,22 +973,25 @@ def paged_verify_write_step(
     bs = pool["k"].shape[2]
     mb = block_tables.shape[1]
     pos_grid = positions[:, None] + jnp.arange(c)[None]  # [B, C]
-    x = params["embed"].astype(dt)[tokens]  # [B, C, D]
-    cos, sin = rope_frequencies(cfg, pos_grid.reshape(-1))
-    cos = cos.reshape(b, c, -1)
-    sin = sin.reshape(b, c, -1)
-    safe_pos = jnp.where(active, positions, 0)
-    # per-(lane, offset) write routing — flattened to [B*C] for the
-    # scatter; inactive lanes and past-table positions hit block 0
-    blk_idx = pos_grid // bs  # [B, C]
-    blks = jnp.where(
-        active[:, None] & (blk_idx < mb),
-        jnp.take_along_axis(
-            block_tables, jnp.minimum(blk_idx, mb - 1), axis=1
-        ),
-        0,
-    ).reshape(-1)
-    offs = jnp.where(active[:, None], pos_grid % bs, 0).reshape(-1)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]  # [B, C, D]
+    # the rope tables and the write routing: attention's, once a step
+    with jax.named_scope("attn"):
+        cos, sin = rope_frequencies(cfg, pos_grid.reshape(-1))
+        cos = cos.reshape(b, c, -1)
+        sin = sin.reshape(b, c, -1)
+        safe_pos = jnp.where(active, positions, 0)
+        # per-(lane, offset) write routing — flattened to [B*C] for the
+        # scatter; inactive lanes and past-table positions hit block 0
+        blk_idx = pos_grid // bs  # [B, C]
+        blks = jnp.where(
+            active[:, None] & (blk_idx < mb),
+            jnp.take_along_axis(
+                block_tables, jnp.minimum(blk_idx, mb - 1), axis=1
+            ),
+            0,
+        ).reshape(-1)
+        offs = jnp.where(active[:, None], pos_grid % bs, 0).reshape(-1)
 
     def body(x, lp, kv):
         def proj(a, w):
@@ -973,35 +999,39 @@ def paged_verify_write_step(
                 a, w.astype(dt), preferred_element_type=jnp.float32
             ).astype(dt)
 
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
-        q = _apply_rope_grid(q, cos, sin)
-        k = _apply_rope_grid(k, cos, sin)
-        kv = kv.write(
-            k.reshape(b * c, nkv, hd), v.reshape(b * c, nkv, hd),
-            blks, offs,
-        )
-        attn = paged_verify_attention(
-            q, kv.k, kv.v, kv.tables(block_tables), safe_pos
-        )
-        x = x + proj(attn.reshape(b, c, nh * hd), lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(proj(h, lp["w_gate"]))
-        up = proj(h, lp["w_up"])
-        x = x + proj(gate * up, lp["w_down"])
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
+            q = _apply_rope_grid(q, cos, sin)
+            k = _apply_rope_grid(k, cos, sin)
+            kv = kv.write(
+                k.reshape(b * c, nkv, hd), v.reshape(b * c, nkv, hd),
+                blks, offs,
+            )
+            attn = paged_verify_attention(
+                q, kv.k, kv.v, kv.tables(block_tables), safe_pos
+            )
+            x = x + proj(attn.reshape(b, c, nh * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            gate = jax.nn.silu(proj(h, lp["w_gate"]))
+            up = proj(h, lp["w_up"])
+            x = x + proj(gate * up, lp["w_down"])
         return x, None, kv
 
     x, _, new_k, new_v = scan_layers_over_pool(
         body, x, params["layers"], pool["k"], pool["v"]
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, params["lm_head"].astype(dt),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(dt),
+            preferred_element_type=jnp.float32,
+        )
     return logits, {"k": new_k, "v": new_v}
 
 
+@jax.named_scope("prefill")
 def paged_prefill_chunk(
     params: Dict,
     tokens: jnp.ndarray,  # [1, C] one sequence's prompt chunk
@@ -1027,19 +1057,22 @@ def paged_prefill_chunk(
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     bs = pool["k"].shape[2]
     positions = start_pos + jnp.arange(c)  # [C]
-    x = params["embed"].astype(dt)[tokens]  # [1, C, D]
-    cos, sin = rope_frequencies(cfg, positions)
-    # a padded chunk tail can run past the table: route those writes
-    # to the null block explicitly — a clamped gather would alias the
-    # sequence's LAST real block and let pad garbage race real K/V
-    blk_idx = positions // bs
-    mb = block_table.shape[0]
-    blks = jnp.where(
-        blk_idx < mb,
-        block_table[jnp.minimum(blk_idx, mb - 1)],
-        0,
-    )  # [C]
-    offs = positions % bs
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]  # [1, C, D]
+    # the rope tables and the write routing: attention's, once a step
+    with jax.named_scope("attn"):
+        cos, sin = rope_frequencies(cfg, positions)
+        # a padded chunk tail can run past the table: route those writes
+        # to the null block explicitly — a clamped gather would alias the
+        # sequence's LAST real block and let pad garbage race real K/V
+        blk_idx = positions // bs
+        mb = block_table.shape[0]
+        blks = jnp.where(
+            blk_idx < mb,
+            block_table[jnp.minimum(blk_idx, mb - 1)],
+            0,
+        )  # [C]
+        offs = positions % bs
 
     def body(x, lp, kv):
         def proj(a, w):
@@ -1047,28 +1080,31 @@ def paged_prefill_chunk(
                 a, w.astype(dt), preferred_element_type=jnp.float32
             ).astype(dt)
 
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        kv = kv.write(k[0], v[0], blks, offs)
-        attn = paged_prefill_attention(
-            q[0], kv.k, kv.v, kv.tables(block_table), start_pos
-        )
-        x = x + proj(attn[None].reshape(b, c, nh * hd), lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(proj(h, lp["w_gate"]))
-        up = proj(h, lp["w_up"])
-        x = x + proj(gate * up, lp["w_down"])
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k, v = qkv_heads(h, lp, dt, nh, nkv, hd)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            kv = kv.write(k[0], v[0], blks, offs)
+            attn = paged_prefill_attention(
+                q[0], kv.k, kv.v, kv.tables(block_table), start_pos
+            )
+            x = x + proj(attn[None].reshape(b, c, nh * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            gate = jax.nn.silu(proj(h, lp["w_gate"]))
+            up = proj(h, lp["w_up"])
+            x = x + proj(gate * up, lp["w_down"])
         return x, None, kv
 
     x, _, new_k, new_v = scan_layers_over_pool(
         body, x, params["layers"], pool["k"], pool["v"]
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, params["lm_head"].astype(dt),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(dt),
+            preferred_element_type=jnp.float32,
+        )
     return logits, {"k": new_k, "v": new_v}
 
 
@@ -1102,18 +1138,20 @@ def loss_fn(
         from dlrover_tpu.ops.fused import fused_linear_cross_entropy
 
         hidden = forward_hidden(params, inputs, cfg, attention_fn)
-        return fused_linear_cross_entropy(
-            hidden,
-            params["lm_head"],
-            targets,
-            mask,
-            chunk_rows=cfg.ce_chunk_rows,
-        )
+        with jax.named_scope("head_loss"):
+            return fused_linear_cross_entropy(
+                hidden,
+                params["lm_head"],
+                targets,
+                mask,
+                chunk_rows=cfg.ce_chunk_rows,
+            )
     logits = forward(params, inputs, cfg, attention_fn)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(
-        logp, targets[..., None], axis=-1
-    ).squeeze(-1)
-    if mask is not None:
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-    return jnp.mean(nll)
+    with jax.named_scope("head_loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(
+            logp, targets[..., None], axis=-1
+        ).squeeze(-1)
+        if mask is not None:
+            return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+        return jnp.mean(nll)

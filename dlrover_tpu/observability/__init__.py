@@ -11,7 +11,6 @@ from dlrover_tpu.observability.metrics import (  # noqa: F401
     MetricsRegistry,
 )
 from dlrover_tpu.observability.health import HealthEngine  # noqa: F401
-from dlrover_tpu.observability.profiler import AProfiler  # noqa: F401
 from dlrover_tpu.observability.status_server import (  # noqa: F401
     StatusServer,
 )

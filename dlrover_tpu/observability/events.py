@@ -262,6 +262,43 @@ LEAF_ANNOTATIONS = frozenset(
     }
 )
 
+#: Device scopes: the names ``jax.named_scope`` puts on the path
+#: (``op_name``) of the HLO instructions of the two hot programs, so a
+#: device trace says which part of the model an operation belongs to.
+#: A scope is trace-time metadata: it changes no instruction and has no
+#: switch.  ROLES wrap a whole serving step program (one compiled
+#: program may hold two: the K-step window drafts with ``decode`` and
+#: scores with ``verify``); PARTS lie inside a role, or directly in the
+#: train step.  A backward pass and a ``jax.checkpoint`` replay are not
+#: scopes: JAX writes them onto the path itself (``transpose(`` and
+#: ``rematted_computation``), and a trace reader takes them from there.
+#: Entered with a string literal (linted, like the leaves above).
+DEVICE_SCOPE_ROLES = frozenset({"prefill", "decode", "verify"})
+DEVICE_SCOPE_PARTS = frozenset(
+    {
+        # the token gather
+        "embed",
+        # attention norm, q/k/v projections, RoPE, the K/V write, the
+        # attention call, ``wo`` and its residual
+        "attn",
+        # MLP norm, gate / up / down and the residual
+        "mlp",
+        # a hybrid block's state-space heads (models/falcon_h1.py):
+        # in-projection, conv, state update or chunked scan,
+        # out-projection
+        "ssm",
+        # final norm + logits of a serving step program
+        "head",
+        # final norm + logits + cross-entropy of the train step
+        "head_loss",
+        # optimizer.update, apply_updates, global_norm
+        "optimizer",
+        # the sampler and the logprob of what it drew
+        "sample",
+    }
+)
+DEVICE_SCOPES = DEVICE_SCOPE_ROLES | DEVICE_SCOPE_PARTS
+
 #: Wall clock covered by no span at all (monitor-detection gaps,
 #: wedged-in-collective survivors, scheduler noise).  Kept as its own
 #: ledger bucket so the losses still sum exactly to ``wall − useful``.

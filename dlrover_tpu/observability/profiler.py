@@ -1,27 +1,26 @@
-"""Profiling utilities: FLOPs census + XLA trace capture.
+"""Profiling utilities: the peak-FLOPs table + XLA trace capture.
 
-Reference parity: ``AProfiler`` (``atorch/atorch/utils/prof.py:38`` —
-FLOPs/MACs census by monkey-patching torch.nn.functional) and the
-xpu_timer kernel-timing role.  JAX gives both analytically: the
-compiled computation's cost analysis reports exact FLOPs/bytes, and
-``jax.profiler`` captures device traces for tensorboard — no symbol
-interposition needed (SURVEY.md §5.1 TPU equivalent).
+Reference parity: the xpu_timer kernel-timing role
+(``atorch/atorch/utils/prof.py:38`` took its FLOPs census by
+monkey-patching torch.nn.functional).  JAX needs no symbol
+interposition: ``jax.profiler`` captures device traces
+(SURVEY.md §5.1 TPU equivalent), and the trainer's live attribution
+takes its FLOPs from ``Trainer._flops_fn_from`` and its peak from
+:func:`device_peak_flops`.
 """
 
 import contextlib
 import os
 import threading
-import time
-from collections import deque
-from typing import Callable, Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 
 from dlrover_tpu.common.log import default_logger as logger
 
 #: Per-device-kind peak bf16 FLOP/s (per chip).  ONE table behind
-#: every MFU number in the repo — ``AProfiler.mfu``, ``bench_mfu``'s
-#: candidate scoring, and the observatory's per-node
+#: every MFU number in the repo — ``bench_mfu``'s candidate scoring
+#: and the observatory's per-node
 #: ``dlrover_tpu_node_mfu`` gauge all route through
 #: :func:`peak_flops_for_kind` so the bench and the live job can never
 #: disagree about what "peak" means.  Matching is by substring on the
@@ -68,92 +67,6 @@ def device_peak_flops(device=None) -> float:
     if device is None:
         device = jax.devices()[0]
     return peak_flops_for_kind(getattr(device, "device_kind", ""))
-
-
-class AProfiler:
-    """FLOPs/memory census of a jitted function + step timing.
-
-    ``registry`` must expose ``observe_duration`` (the
-    ``MetricsRegistry`` contract).  A registry without it is rejected
-    at CONSTRUCTION — ``step()`` used to discover the mismatch only
-    when it tried to record, which silently lost every sample until
-    then."""
-
-    #: step-time window (ring — the old list paid O(n) ``pop(0)``)
-    STEP_WINDOW = 1024
-
-    def __init__(self, registry=None):
-        if registry is not None and not callable(
-            getattr(registry, "observe_duration", None)
-        ):
-            raise TypeError(
-                "AProfiler registry must provide observe_duration() "
-                f"(got {type(registry).__name__}); pass a "
-                "MetricsRegistry or None"
-            )
-        self._registry = registry
-        self._step_times = deque(maxlen=self.STEP_WINDOW)
-
-    def cost_analysis(self, fn: Callable, *args, **kwargs) -> Dict:
-        """Exact compiled-cost census (replaces the reference's
-        monkey-patched per-op accounting)."""
-        lowered = jax.jit(fn).lower(*args, **kwargs)
-        compiled = lowered.compile()
-        costs = compiled.cost_analysis()
-        if isinstance(costs, list):  # old jax returns [dict]
-            costs = costs[0] if costs else {}
-        result = {
-            "flops": float(costs.get("flops", 0.0)),
-            "bytes_accessed": float(costs.get("bytes accessed", 0.0)),
-        }
-        try:
-            mem = compiled.memory_analysis()
-            result["output_bytes"] = float(
-                getattr(mem, "output_size_in_bytes", 0)
-            )
-            result["temp_bytes"] = float(
-                getattr(mem, "temp_size_in_bytes", 0)
-            )
-        except Exception:  # noqa: BLE001
-            pass
-        return result
-
-    def model_flops_per_token(self, num_params: int) -> float:
-        """The 6N rule of thumb for transformer training FLOPs."""
-        return 6.0 * num_params
-
-    @contextlib.contextmanager
-    def step(self, name: str = "train_step"):
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            # a raising step still took its time — drop the sample
-            # and the window under-reports exactly the bad steps
-            elapsed = time.perf_counter() - start
-            self._step_times.append(elapsed)
-            if self._registry is not None:
-                self._registry.observe_duration(name, elapsed)
-
-    def mean_step_time(self) -> float:
-        if not self._step_times:
-            return 0.0
-        return sum(self._step_times) / len(self._step_times)
-
-    def mfu(self, flops_per_step: float,
-            peak_flops: Optional[float] = None) -> float:
-        """Model FLOPs utilization vs peak.  ``peak_flops`` defaults
-        to the attached chip's table entry
-        (:func:`device_peak_flops`: ``DLROVER_TPU_PEAK_FLOPS``
-        override → ``device_kind`` table → loud v5e fallback) — the
-        hard-coded ``197e12`` default used to make every non-v5e
-        number silently wrong."""
-        t = self.mean_step_time()
-        if t <= 0:
-            return 0.0
-        if peak_flops is None:
-            peak_flops = device_peak_flops()
-        return flops_per_step / t / peak_flops
 
 
 @contextlib.contextmanager

@@ -201,16 +201,19 @@ def build_train_step(
             )
         else:
             loss, grads = _loss_and_grad(params, batch)
-        updates, new_opt_state = optimizer.update(
-            grads, state["opt_state"], params
-        )
-        new_params = optax.apply_updates(params, updates)
+        # a device scope (observability/events.py DEVICE_SCOPES): the
+        # model's parts are named where the loss is written
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state["opt_state"], params
+            )
+            new_params = optax.apply_updates(params, updates)
+            grad_norm = optax.global_norm(grads)
         new_state = {
             "step": state["step"] + 1,
             "params": new_params,
             "opt_state": new_opt_state,
         }
-        grad_norm = optax.global_norm(grads)
         return new_state, {"loss": loss, "grad_norm": grad_norm}
 
     train_step = jax.jit(

@@ -289,6 +289,7 @@ def decode_program(decode_model, temp: float, capture_logprobs: bool,
     ``lanes`` is the host's one upload a step, int32 ``[S, max_blocks
     + 2]``: the block tables, then the positions, then the active
     mask.  ``keys`` ``[S, 2]`` changes only at admission."""
+    import jax
     import jax.numpy as jnp
 
     def _step(params, pool, tokens, lanes, keys):
@@ -298,8 +299,12 @@ def decode_program(decode_model, temp: float, capture_logprobs: bool,
         logits, pool = decode_model(
             params, tokens, pool, tables, positions, active
         )
-        nxt = sample_rows(logits, keys, positions + 1, temp)
-        return pool, jnp.where(active, nxt, tokens), logits, nxt
+        # device scopes (observability/events.py DEVICE_SCOPES): the
+        # model's step program names its own role and parts; what this
+        # module adds after it is the ``sample`` part of the same role
+        with jax.named_scope("decode"), jax.named_scope("sample"):
+            nxt = sample_rows(logits, keys, positions + 1, temp)
+            return pool, jnp.where(active, nxt, tokens), logits, nxt
 
     def _decode(params, pool, tokens, lanes, keys):
         pool, tokens, _, _ = _step(params, pool, tokens, lanes, keys)
@@ -309,7 +314,8 @@ def decode_program(decode_model, temp: float, capture_logprobs: bool,
         pool, tokens, logits, nxt = _step(
             params, pool, tokens, lanes, keys
         )
-        return pool, tokens, logprob_rows(logits, nxt)
+        with jax.named_scope("decode"), jax.named_scope("sample"):
+            return pool, tokens, logprob_rows(logits, nxt)
 
     return _decode_lp if capture_logprobs else _decode
 
@@ -629,6 +635,8 @@ class ContinuousBatchingScheduler:
         _sample_rows = partial(sample_rows, temp=temp)
         _lp_rows = logprob_rows
 
+        @jax.named_scope("verify")
+        @jax.named_scope("sample")
         def _sample_grid(logits, keys, sample_pos):
             """logits [S, K, V]; sample_pos [S, K] — the K-window
             version of ``_sample_rows`` (same contract per cell)."""
@@ -660,7 +668,8 @@ class ContinuousBatchingScheduler:
                 logits, pool = self._decode_model(
                     params, tok, pool, tables, pos, active
                 )
-                d = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("decode"), jax.named_scope("sample"):
+                    d = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 drafts.append(d)
                 tok, pos = d, pos + 1
             drafts = jnp.stack(drafts, axis=1)  # [S, K]
@@ -694,10 +703,11 @@ class ContinuousBatchingScheduler:
             logits and written into the lanes' token vector at
             ``lane`` on the device: the decode step dispatched next
             reads it there; the host reads the scalar a commit later."""
-            tok = _sample_rows(
-                logits_row[None], keys[lane][None], sample_pos[None]
-            )[0]
-            return tokens.at[lane].set(tok), tok
+            with jax.named_scope("prefill"), jax.named_scope("sample"):
+                tok = _sample_rows(
+                    logits_row[None], keys[lane][None], sample_pos[None]
+                )[0]
+                return tokens.at[lane].set(tok), tok
 
         CAP = self.capture_logprobs
 
@@ -713,9 +723,10 @@ class ContinuousBatchingScheduler:
                 logits, pool = self._decode_model(
                     params, tok, pool, tables, pos, active
                 )
-                d = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("decode"), jax.named_scope("sample"):
+                    d = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    lps.append(_lp_rows(logits, d))
                 drafts.append(d)
-                lps.append(_lp_rows(logits, d))
                 tok, pos = d, pos + 1
             drafts = jnp.stack(drafts, axis=1)  # [S, K]
             lp_drafts = jnp.stack(lps, axis=1)  # [S, K]
@@ -729,7 +740,8 @@ class ContinuousBatchingScheduler:
             ver = _sample_grid(
                 vlogits, keys, positions[:, None] + 1 + steps[None]
             )
-            lp_ver = _lp_rows(vlogits, ver)
+            with jax.named_scope("verify"), jax.named_scope("sample"):
+                lp_ver = _lp_rows(vlogits, ver)
             eq = (ver == drafts).astype(jnp.int32)
             n_match = jnp.sum(jnp.cumprod(eq, axis=1), axis=1)
             return pool, drafts, ver, n_match, lp_drafts, lp_ver
@@ -751,7 +763,8 @@ class ContinuousBatchingScheduler:
                 dlogits, dpool = self._draft_decode_model(
                     draft_params, tok, dpool, tables, pos, active
                 )
-                d = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("decode"), jax.named_scope("sample"):
+                    d = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
                 drafts.append(d)
                 tok, pos = d, pos + 1
             drafts = jnp.stack(drafts, axis=1)  # [S, K]
@@ -765,10 +778,11 @@ class ContinuousBatchingScheduler:
             ver = _sample_grid(
                 vlogits, keys, positions[:, None] + 1 + steps[None]
             )
-            lp_ver = (
-                _lp_rows(vlogits, ver) if CAP
-                else jnp.zeros(ver.shape, jnp.float32)
-            )
+            with jax.named_scope("verify"), jax.named_scope("sample"):
+                lp_ver = (
+                    _lp_rows(vlogits, ver) if CAP
+                    else jnp.zeros(ver.shape, jnp.float32)
+                )
             eq = (ver == drafts).astype(jnp.int32)
             n_match = jnp.sum(jnp.cumprod(eq, axis=1), axis=1)
             return pool, dpool, drafts, ver, n_match, lp_ver
@@ -780,11 +794,12 @@ class ContinuousBatchingScheduler:
             return dpool, logits
 
         def _sample_one_lp(logits_row, keys, sample_pos, tokens, lane):
-            tok = _sample_rows(
-                logits_row[None], keys[lane][None], sample_pos[None]
-            )
-            return (tokens.at[lane].set(tok[0]), tok[0],
-                    _lp_rows(logits_row[None], tok)[0])
+            with jax.named_scope("prefill"), jax.named_scope("sample"):
+                tok = _sample_rows(
+                    logits_row[None], keys[lane][None], sample_pos[None]
+                )
+                return (tokens.at[lane].set(tok[0]), tok[0],
+                        _lp_rows(logits_row[None], tok)[0])
 
         def _set_token(tokens, lane, tok):
             # an adopted prefill's first token arrives as a host value

@@ -19,7 +19,11 @@ every call to an event-logger method (``span`` / ``begin`` / ``end`` /
   (``serve_step``'s host-time partition: a typo'd ``admit_ms`` would
   silently drop out of the metric that reads it);
 - ``leaf()`` — the profiler-only annotation of a per-iteration phase —
-  names a declared leaf (``LEAF_ANNOTATIONS``) or a declared phase.
+  names a declared leaf (``LEAF_ANNOTATIONS``) or a declared phase;
+- ``named_scope()`` — the name ``jax.named_scope`` puts on the device
+  operations of a step program — is a string literal of
+  ``DEVICE_SCOPES``: the trace readers attribute device time by these
+  names, and a typo'd scope would fall into "unscoped".
 
 Usage: ``python scripts/check_event_schema.py [paths...]``
 (default: the package, scripts/, tests/ and bench*.py).  Exit 1 on any
@@ -35,6 +39,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from dlrover_tpu.observability.events import (  # noqa: E402
+    DEVICE_SCOPES,
     INSTANT_EVENTS,
     LEAF_ANNOTATIONS,
     OPTIONAL_SPAN_LABELS,
@@ -280,6 +285,15 @@ def check_file(path: str):
                 "declared dlrover_tpu_ metric (add it to "
                 "DECLARED_METRICS or fix the typo)"
             )
+            continue
+        if func.attr == "named_scope":
+            _, scope = _literal_phase(node)
+            if scope not in DEVICE_SCOPES:
+                violations.append(
+                    f"{os.path.relpath(path, REPO)}:{node.lineno}: "
+                    f"named_scope({scope!r}) is not a string literal "
+                    f"of DEVICE_SCOPES ({sorted(DEVICE_SCOPES)})"
+                )
             continue
         if func.attr not in EMIT_METHODS:
             continue

@@ -108,6 +108,27 @@ class TestGenerationSegment:
         finally:
             h.close(unlink=True)
 
+    def test_unsized_segment_reads_as_not_yet_published(self):
+        """A publisher creates the segment's file and sizes it a moment
+        later: a reader that attaches in between finds an empty file
+        (``mmap`` refuses it), which is "not there yet", not an error —
+        and it sees the generation once the publisher is through."""
+        name = f"flyempty-{os.getpid()}"
+        h = SharedMemoryHandler(rank=0, name=name, host=True)
+        path = os.path.join("/dev/shm", h._gen_name)
+        try:
+            os.close(os.open(path, os.O_CREAT | os.O_EXCL | os.O_RDWR, 0o600))
+            assert os.path.getsize(path) == 0
+            assert h.peek_generation() == -1
+            assert h.peek_generation() == -1  # and attaches nothing
+            os.unlink(path)
+            h.publish_generation(5)
+            assert h.peek_generation() == 5
+        finally:
+            if os.path.exists(path):
+                os.unlink(path)
+            h.close(unlink=True)
+
     @pytest.mark.timeout(300)
     def test_torn_publish_serves_previous_generation(self):
         """Satellite 3: a publisher SIGKILLed inside ``save_state``

@@ -33,7 +33,6 @@ from dlrover_tpu.observability.events import (
 from dlrover_tpu.observability.health import HealthEngine
 from dlrover_tpu.observability.metrics import MetricsRegistry
 from dlrover_tpu.observability.profiler import (
-    AProfiler,
     device_peak_flops,
     peak_flops_for_kind,
 )
@@ -63,17 +62,6 @@ class TestPeakFlopsTable:
         monkeypatch.setenv("DLROVER_TPU_PEAK_FLOPS", "not-a-number")
         with pytest.raises(ValueError):  # malformed: refused, not skipped
             device_peak_flops()
-
-    def test_aprofiler_mfu_routes_through_table(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_PEAK_FLOPS", "4.0")
-        p = AProfiler()
-        with p.step():
-            pass
-        p._step_times.clear()
-        p._step_times.append(1.0)
-        assert p.mfu(2.0) == pytest.approx(0.5)
-        # explicit peak still wins over the env/table
-        assert p.mfu(2.0, peak_flops=8.0) == pytest.approx(0.25)
 
     def test_bench_mfu_uses_the_same_table(self, monkeypatch):
         import bench_mfu
