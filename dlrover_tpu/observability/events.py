@@ -543,6 +543,9 @@ OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
         "other_ms",
         "lanes_decode",
         "lanes_prefill",
+        # 0 or 1: this iteration's prefill chunk was its prompt's last
+        # and ran the head (one row of it) and the first token's sample
+        "prefill_heads",
         "slots",
         # the run-ahead decode loop: lanes of this iteration's decode
         # step dispatched before their previous token had been read,

@@ -320,6 +320,69 @@ def decode_program(decode_model, temp: float, capture_logprobs: bool,
     return _decode_lp if capture_logprobs else _decode
 
 
+def prefill_programs(prefill_model, temp: float, capture_logprobs: bool,
+                     lane_state: bool):
+    """The two programs a prompt's chunks go through, as the scheduler
+    jits them (the pool, the second argument, donated).  A chunk's
+    logits are read in ONE place: the row of the prompt's last token,
+    which seeds the first sampled token.  So the head runs there only,
+    for any ``prefill_model`` of the contract, injected ones included:
+
+    - ``prefill(params, pool, chunk, table, start, lane, real) ->
+      pool``: a chunk that is not its prompt's last.  The model's
+      logits are dropped INSIDE the program, so the compiler removes
+      the final norm and the ``lm_head`` matmul with them.
+    - ``last(params, pool, tokens, keys, chunk, table, start, lane,
+      real) -> (pool, tokens', tok[, logprob])``: the prompt's last
+      chunk.  The row read is ``real - 1`` and the token's output
+      position ``start + real``, both traced: one compiled program for
+      every prompt length.  The row is cut from the model's ``[1, C,
+      vocab]`` logits inside the program, as a masked sum over the
+      chunk's rows: exact, and the compiler fuses it into the
+      ``lm_head`` product, whose result is then ONE row — the other
+      127 are never written (``tests/test_tpu_compile.py`` pins it; a
+      ``dynamic_slice`` is left behind the whole product).  The first
+      token is sampled by the (seed, position) rule and written into
+      the lanes' token vector at ``lane`` on the device: the decode
+      step dispatched next reads it there; the host reads the scalar a
+      commit later.
+
+    ``lane`` and ``real`` reach the model only where it keeps per-lane
+    state (``lane_state``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def _model(params, pool, chunk, table, start, lane, real):
+        extra = (lane, real) if lane_state else ()
+        return prefill_model(params, chunk, pool, table, start, *extra)
+
+    def _prefill(params, pool, *chunk):
+        _, pool = _model(params, pool, *chunk)
+        return pool
+
+    def _prefill_last(params, pool, tokens, keys, *chunk):
+        start, lane, real = chunk[2:]
+        logits, pool = _model(params, pool, *chunk)
+        with jax.named_scope("prefill"), jax.named_scope("head"):
+            # one row of [1, C, V], as a masked sum over C: it fuses
+            # into the product, which then writes that row alone
+            rows = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            logits = jnp.sum(
+                jnp.where(rows == real - 1, logits, 0.0), axis=1
+            )
+        with jax.named_scope("prefill"), jax.named_scope("sample"):
+            tok = sample_rows(
+                logits, keys[lane][None], (start + real)[None], temp
+            )
+            out = (pool, tokens.at[lane].set(tok[0]), tok[0])
+            if capture_logprobs:
+                out += (logprob_rows(logits, tok)[0],)
+            return out
+
+    return _prefill, _prefill_last
+
+
 class ContinuousBatchingScheduler:
     """The token-level serving loop over a paged KV cache.
 
@@ -344,7 +407,13 @@ class ContinuousBatchingScheduler:
       must start from zero at ``start == 0``, carry from chunk to
       chunk of the same lane while other lanes decode in between, and
       stop at the last real token.  Injected programs are given the
-      tree ``sync_weights`` was given.
+      tree ``sync_weights`` was given.  A chunk's logits are read for
+      ONE row, the prompt's last token's, so the scheduler jits the
+      prefill program twice (:func:`prefill_programs`): a chunk that
+      is not its prompt's last runs it with the logits dropped inside
+      the program (the compiler removes the head), the last chunk with
+      that row cut from them and the first token's sample behind it —
+      a model provides nothing more for it.
     - optionally ``serving_params(tree) -> tree`` (``llama``'s unless
       ``serving_params_fn`` is injected): the copy the scheduler keeps
       resident and hands to every step program, made once an adoption
@@ -615,6 +684,9 @@ class ContinuousBatchingScheduler:
         # counters the serving gauges/bench read
         self.total_new_tokens = 0
         self.total_prefill_tokens = 0
+        self.prefill_chunks = 0
+        self.prefill_heads = 0
+        self._step_prefill_heads = 0
         self.iterations = 0
         self.preemptions = 0
         self.grown_blocks = 0
@@ -632,14 +704,13 @@ class ContinuousBatchingScheduler:
 
         temp = float(s.temperature)
 
-        _sample_rows = partial(sample_rows, temp=temp)
         _lp_rows = logprob_rows
 
         @jax.named_scope("verify")
         @jax.named_scope("sample")
         def _sample_grid(logits, keys, sample_pos):
             """logits [S, K, V]; sample_pos [S, K] — the K-window
-            version of ``_sample_rows`` (same contract per cell)."""
+            version of ``sample_rows`` (same contract per cell)."""
             if temp <= 0:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
             folded = jax.vmap(
@@ -689,25 +760,6 @@ class ContinuousBatchingScheduler:
             eq = (ver == drafts).astype(jnp.int32)
             n_match = jnp.sum(jnp.cumprod(eq, axis=1), axis=1)
             return pool, drafts, ver, n_match
-
-        def _prefill(params, pool, chunk, table, start, *lane_real):
-            # ``lane_real``: (lane, real) for a model with lane state,
-            # nothing for one whose chunk needs no more than its table
-            logits, pool = self._prefill_model(
-                params, chunk, pool, table, start, *lane_real
-            )
-            return pool, logits
-
-        def _sample_one(logits_row, keys, sample_pos, tokens, lane):
-            """A prompt's first token, sampled from its last chunk's
-            logits and written into the lanes' token vector at
-            ``lane`` on the device: the decode step dispatched next
-            reads it there; the host reads the scalar a commit later."""
-            with jax.named_scope("prefill"), jax.named_scope("sample"):
-                tok = _sample_rows(
-                    logits_row[None], keys[lane][None], sample_pos[None]
-                )[0]
-                return tokens.at[lane].set(tok), tok
 
         CAP = self.capture_logprobs
 
@@ -787,20 +839,6 @@ class ContinuousBatchingScheduler:
             n_match = jnp.sum(jnp.cumprod(eq, axis=1), axis=1)
             return pool, dpool, drafts, ver, n_match, lp_ver
 
-        def _draft_prefill(dparams, dpool, chunk, table, start):
-            logits, dpool = self._draft_prefill_model(
-                dparams, chunk, dpool, table, start
-            )
-            return dpool, logits
-
-        def _sample_one_lp(logits_row, keys, sample_pos, tokens, lane):
-            with jax.named_scope("prefill"), jax.named_scope("sample"):
-                tok = _sample_rows(
-                    logits_row[None], keys[lane][None], sample_pos[None]
-                )
-                return (tokens.at[lane].set(tok[0]), tok[0],
-                        _lp_rows(logits_row[None], tok)[0])
-
         def _set_token(tokens, lane, tok):
             # an adopted prefill's first token arrives as a host value
             return tokens.at[lane].set(tok)
@@ -828,14 +866,24 @@ class ContinuousBatchingScheduler:
             jax.jit(_decode_multi_draft, donate_argnums=(2, 3))
             if self.draft else None
         )
+        # a prompt's chunks: every chunk but the last runs a program
+        # without a head, the last one a program with the head's one
+        # row and the first token's sample (``prefill_programs``); the
+        # draft mirror fills its pool and reads no logits at all
         self._draft_prefill_jit = (
-            jax.jit(_draft_prefill, donate_argnums=(1,))
+            jax.jit(
+                prefill_programs(
+                    self._draft_prefill_model, temp, CAP, False
+                )[0],
+                donate_argnums=(1,),
+            )
             if self.draft else None
         )
-        self._prefill_jit = jax.jit(_prefill, donate_argnums=(1,))
-        self._sample_jit = jax.jit(
-            _sample_one_lp if CAP else _sample_one
+        prefill, prefill_last = prefill_programs(
+            self._prefill_model, temp, CAP, self.lane_state
         )
+        self._prefill_jit = jax.jit(prefill, donate_argnums=(1,))
+        self._prefill_last_jit = jax.jit(prefill_last, donate_argnums=(1,))
         self._set_token_jit = jax.jit(_set_token)
         self._set_key_jit = jax.jit(_set_key)
 
@@ -1057,7 +1105,10 @@ class ContinuousBatchingScheduler:
         """Compiled-program census: decode must stay at 1 across any
         admission/eviction/growth/preemption traffic (asserted by
         tier-1).  ``decode`` reports the ACTIVE decode program — the
-        fused multi-token one when ``DLROVER_TPU_DECODE_STEPS>1``."""
+        fused multi-token one when ``DLROVER_TPU_DECODE_STEPS>1``.
+        ``prefill`` is the chunk program without a head (0 while every
+        prompt has fit one chunk) and ``sample`` the last-chunk
+        program: the head's one row and the first token's sample."""
 
         def n(f):
             return int(f._cache_size())
@@ -1074,7 +1125,7 @@ class ContinuousBatchingScheduler:
         return {
             "decode": n(active_decode),
             "prefill": n(self._prefill_jit),
-            "sample": n(self._sample_jit),
+            "sample": n(self._prefill_last_jit),
         }
 
     def stats(self) -> Dict:
@@ -1088,6 +1139,10 @@ class ContinuousBatchingScheduler:
             iterations=self.iterations,
             total_new_tokens=self.total_new_tokens,
             total_prefill_tokens=self.total_prefill_tokens,
+            # chunks dispatched, and how many of them ran the head (a
+            # prompt's last: one a prompt prefilled, re-prefills too)
+            prefill_chunks=self.prefill_chunks,
+            prefill_heads=self.prefill_heads,
             preemptions=self.preemptions,
             grown_blocks=self.grown_blocks,
             dispatches=self.dispatches,
@@ -1695,20 +1750,35 @@ class ContinuousBatchingScheduler:
             real = chunk.size
             if real < s.prefill_chunk:
                 chunk = np.pad(chunk, (0, s.prefill_chunk - real))
-            chunk = np.array(chunk[None], np.int32)
-            table = self._tables[slot].copy()
-            self._pool, logits = self._prefill_jit(
-                self._params,
-                self._pool,
-                chunk,
-                table,
+            args = (
+                np.array(chunk[None], np.int32),
+                self._tables[slot].copy(),
                 np.int32(start),
-                *(
-                    (np.int32(slot), np.int32(real))
-                    if self.lane_state else ()
-                ),
+                np.int32(slot),
+                np.int32(real),
             )
+            # the chunk's logits are read in one place, the row of the
+            # prompt's last token: the head runs on the last chunk
+            # only, for that row, and the first new token is sampled in
+            # the same program into the lanes' token vector — its value
+            # reaches the host with a later commit, the decode step
+            # dispatched next reads it on the device
+            last = start + real >= plen
+            if last:
+                self._pool, self._tokens_dev, tok, *lp = (
+                    self._prefill_last_jit(
+                        self._params, self._pool, self._tokens_dev,
+                        self._keys, *args,
+                    )
+                )
+            else:
+                self._pool = self._prefill_jit(
+                    self._params, self._pool, *args
+                )
             self.dispatches += 1
+            self.prefill_chunks += 1
+            self.prefill_heads += last
+            self._step_prefill_heads += last
             if self.lane_state and start == 0:
                 # the program starts this lane's state from zero
                 self._step_state_resets += 1
@@ -1719,12 +1789,8 @@ class ContinuousBatchingScheduler:
                 # over a real prompt cache; a drafter adopted
                 # mid-prefill just drafts worse until the next prompt
                 # — emission never depends on it
-                self._draft_pool, _ = self._draft_prefill_jit(
-                    self._draft_params,
-                    self._draft_pool,
-                    chunk,
-                    table,
-                    np.int32(start),
+                self._draft_pool = self._draft_prefill_jit(
+                    self._draft_params, self._draft_pool, *args
                 )
                 self.dispatches += 1
         with self._ph_commit:
@@ -1732,21 +1798,8 @@ class ContinuousBatchingScheduler:
             self.total_prefill_tokens += real
             self.block_pool.note_filled(req.req_id, sl.prefill_pos)
             self._share_filled_blocks(slot)
-        if sl.prefill_pos < plen:
+        if not last:
             return real
-        # sample the first new token from the last REAL prefill
-        # position's logits (it lives inside this chunk) into the
-        # lanes' token vector; its value reaches the host with a later
-        # commit, the decode step dispatched next reads it on the device
-        with self._ph_dispatch:
-            self._tokens_dev, tok, *lp = self._sample_jit(
-                logits[0, plen - 1 - start],
-                self._keys,
-                np.int32(plen),
-                self._tokens_dev,
-                np.int32(slot),
-            )
-            self.dispatches += 1
         if self.role == "prefill":
             # disaggregated split: the first token is sampled HERE
             # (same (seed, position) rule as a local prefill, so the
@@ -2031,7 +2084,7 @@ class ContinuousBatchingScheduler:
         self._lanes_decode = self._lanes_prefill = 0
         self._lanes_ahead = self._step_overrun = 0
         self._step_commits = 0
-        self._step_state_resets = 0
+        self._step_state_resets = self._step_prefill_heads = 0
         finished: List[GenResult] = []
         if self._adopt_finished:
             finished.extend(self._adopt_finished)
@@ -2112,6 +2165,7 @@ class ContinuousBatchingScheduler:
                 ),
                 lanes_decode=self._lanes_decode,
                 lanes_prefill=self._lanes_prefill,
+                prefill_heads=self._step_prefill_heads,
                 lanes_ahead=self._lanes_ahead,
                 overrun_tokens=self._step_overrun,
                 slots=self.sched.max_slots,

@@ -24,7 +24,7 @@ import os
 import re
 import subprocess
 import sys
-from functools import partial
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -360,28 +360,113 @@ def test_serving_program_heavy_instructions_carry_one_role_and_part(
     assert loose <= _PLUMBING, loose - _PLUMBING
 
 
-def test_the_prompts_first_token_is_sampled_under_prefill():
-    """``_sample_jit``, a program of its own: the first token of a
-    prompt, from its last chunk's logits."""
-    from dlrover_tpu.rl.scheduler import (
-        ContinuousBatchingScheduler,
-        SchedulerConfig,
+def _prefill_programs(name):
+    """``(cfg, chunk without head, last chunk)`` as the scheduler jits
+    them (``rl/scheduler.prefill_programs``, logprobs captured as in
+    the cells), lowered."""
+    from dlrover_tpu.rl.scheduler import prefill_programs
+
+    if name == "llama":
+        cfg, mod, lane_state = LLAMA, llama, False
+        pool = _pool(cfg, cfg.n_kv_heads, cfg.head_dim, cfg.n_layers)
+    else:
+        cfg, mod, lane_state = FALCON, falcon_h1, True
+        pool = _pool(
+            cfg, cfg.num_key_value_heads, cfg.head_dim,
+            cfg.num_hidden_layers, state=cfg.lane_state().items(),
+        )
+    params = jax.eval_shape(
+        lambda: mod.serving_params(
+            mod.init_params(jax.random.PRNGKey(0), cfg), cfg
+        )
+    )
+    model = partial(mod.paged_prefill_chunk, cfg=cfg)
+    prefill, last = prefill_programs(model, 1.0, True, lane_state)
+    chunk = (
+        _spec((1, CHUNK)), _spec((MAX_BLOCKS,)), _spec(()), _spec(()),
+        _spec(()),
+    )
+    return (
+        cfg,
+        jax.jit(prefill).lower(params, pool, *chunk),
+        jax.jit(last).lower(
+            params, pool, _spec((LANES,)), _spec((LANES, 2), jnp.uint32),
+            *chunk,
+        ),
     )
 
-    sch = ContinuousBatchingScheduler(
-        LLAMA,
-        SchedulerConfig(
-            max_slots=2, block_size=4, num_blocks=16, max_seq_len=32,
-            prefill_chunk=8,
-        ),
-        capture_logprobs=True,
-    )
-    ops = operations(sch._sample_jit.lower(
-        _spec((LLAMA.vocab_size,), jnp.float32), _spec((2, 2), jnp.uint32),
-        _spec(()), _spec((2,)), _spec(()),
-    ))
-    keys = {readers_scopes.classify(path)[:2] for _, path in ops}
-    assert keys == {("prefill", "sample")}
+
+@pytest.fixture(scope="module")
+def prefill_lowered():
+    return cache(_prefill_programs)
+
+
+def _parts(lowered):
+    return {readers_scopes.classify(path)[:2] for _, path in operations(lowered)}
+
+
+@pytest.mark.parametrize("name", ["llama", "falcon_h1"])
+def test_a_chunk_that_is_not_the_last_has_no_head(name, prefill_lowered):
+    """Its logits are dropped inside the program, and with them goes
+    everything that made them: no operation under ``prefill`` /
+    ``head``, no ``lm_head`` among the arguments, nothing of the
+    vocabulary's width computed."""
+    cfg, prefill, _ = prefill_lowered(name)
+    parts = _parts(prefill)
+    assert ("prefill", "mlp") in parts and ("prefill", "attn") in parts
+    assert not {p for p in parts if p[1] in ("head", "sample")}, parts
+    text = prefill.as_text()
+    assert cfg.vocab_size == 256  # no other width of the tiny models
+    assert "x256xf32>" not in text and "<256xf32>" not in text
+    assert not [
+        line for line in text.splitlines()
+        if "stablehlo.dot_general" in line and "x256x" in line
+    ]
+
+
+@pytest.mark.parametrize("name", ["llama", "falcon_h1"])
+def test_the_last_chunks_head_hands_on_one_row(name, prefill_lowered):
+    """As written, the last chunk's program holds the model's whole
+    head and a masked sum that leaves one row of it, all under
+    ``prefill`` / ``head``; the sum is the last that sees the chunk's
+    width, so the compiler can fuse it into the product (that it does,
+    and writes one row, is pinned on the compiled program:
+    ``tests/test_tpu_compile.py``)."""
+    _, _, last = prefill_lowered(name)
+    under_head = {
+        primitive(path) for _, path in operations(last)
+        if readers_scopes.classify(path)[:2] == ("prefill", "head")
+    }
+    assert {"dot_general", "select_n", "reduce_sum"} <= under_head, under_head
+    wide = [
+        line for line in last.as_text().split("func.func")[1].splitlines()
+        if f"<1x{CHUNK}x256xf32>" in line
+    ]
+    assert "stablehlo.dot_general" in wide[0], wide[0]
+    assert "stablehlo.reduce" in wide[-1], wide[-1]
+    assert wide[-1].endswith("-> tensor<1x256xf32>"), wide[-1]
+
+
+@pytest.mark.parametrize("name", ["llama", "falcon_h1"])
+def test_the_prompts_first_token_is_sampled_under_prefill(
+    name, prefill_lowered
+):
+    """The first token of a prompt, from the one row of its last
+    chunk's head, in the last chunk's own program: the sampler and the
+    logprob lie under ``prefill`` / ``sample``, the head's row under
+    ``prefill`` / ``head``, and no part lies under another role."""
+    _, _, last = prefill_lowered(name)
+    ops, parts = operations(last), _parts(last)
+    assert {("prefill", "head"), ("prefill", "sample")} <= parts
+    assert {role for role, _ in parts} <= {"prefill", "-"}
+    # what only the sampler's random bits are made of
+    drawn = [
+        path for _, path in ops
+        if primitive(path) in ("xor", "shift_right_logical")
+    ]
+    assert drawn
+    for path in drawn:
+        assert readers_scopes.classify(path)[:2] == ("prefill", "sample")
 
 
 # ------------------------------------------------------------- the lint

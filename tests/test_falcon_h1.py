@@ -573,16 +573,21 @@ def test_a_model_without_lane_state_has_a_pool_of_k_and_v():
     st = sch.stats()
     assert (st["state_bytes"], st["state_resets"],
             st["prefix_hits_skipped"]) == (0, 0, 0)
-    # its prefill program is called with the chunk's table and start only
+    # its prefill program is called with the chunk's table and start
+    # only, in both programs its chunks go through (one without a head,
+    # one for a prompt's last chunk)
     seen = []
-    real = sch._prefill_model
+    chunk = partial(llama.paged_prefill_chunk, cfg=parts["cfg"])
 
     def spy(*args):
         seen.append(len(args))
-        return real(*args)
+        return chunk(*args)
 
-    sch._prefill_model = spy
+    sch = ContinuousBatchingScheduler(
+        parts["cfg"], SchedulerConfig(**SCHED), paged_prefill_fn=spy
+    )
     sch.sync_weights(parts["params_template_fn"]())
     sch.submit(np.arange(5, dtype=np.int32), max_new=2)
+    sch.submit(np.arange(11, dtype=np.int32), max_new=2)
     sch.run()
-    assert seen == [5]
+    assert seen == [5, 5]

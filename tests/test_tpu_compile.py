@@ -314,6 +314,35 @@ def _scheduler_decode(model_step, lanes, max_blocks=64):
     )
 
 
+def _scheduler_prefill(model_chunk, lanes, lane_state, last):
+    """A prompt's chunk as the scheduler jits it
+    (``rl/scheduler.prefill_programs``, logprobs captured as in the
+    cells): the program without a head of a chunk that is not the last,
+    or the last chunk's, with the head's one row and the first token's
+    sample — in the argument order of this file's harness."""
+    from dlrover_tpu.rl.scheduler import prefill_programs
+
+    prefill, prefill_last = prefill_programs(
+        model_chunk, 1.0, True, lane_state
+    )
+    i32 = jnp.int32
+    rest = [((1, 128), i32), ((64,), i32), ((), i32), ((), i32), ((), i32)]
+    if not last:
+        return (
+            lambda params, chunk, pool, *rest: prefill(
+                params, pool, chunk, *rest
+            ),
+            rest,
+        )
+    return (
+        lambda params, chunk, pool, table, start, lane, real, tokens, keys:
+        prefill_last(
+            params, pool, tokens, keys, chunk, table, start, lane, real
+        ),
+        rest + [((lanes,), i32), ((lanes, 2), jnp.uint32)],
+    )
+
+
 def _llama_step_case(program):
     """A llama step program at ``deepseek7b-rollout-c16``'s geometry:
     DeepSeek-LLM-7B's widths (32 MHA heads of 128) at depth 5, the
@@ -340,6 +369,12 @@ def _llama_step_case(program):
     elif program == "decode":
         fn, rest = _scheduler_decode(
             partial(llama.paged_decode_step, cfg=cfg), 16
+        )
+        return fn, params, pool_shape, {}, rest, 64 * 2**20
+    elif program in ("prefill_nohead", "prefill_last"):
+        fn, rest = _scheduler_prefill(
+            partial(llama.paged_prefill_chunk, cfg=cfg), 16, False,
+            program == "prefill_last",
         )
         return fn, params, pool_shape, {}, rest, 64 * 2**20
     else:
@@ -375,6 +410,12 @@ def _falcon_h1_step_case(program):
         fn, rest = falcon_h1.paged_prefill_chunk, [
             ((1, 128), i32), ((64,), i32), ((), i32), ((), i32), ((), i32),
         ]
+    elif program in ("prefill_nohead", "prefill_last"):
+        fn, rest = _scheduler_prefill(
+            partial(falcon_h1.paged_prefill_chunk, cfg=cfg), 32, True,
+            program == "prefill_last",
+        )
+        return fn, params, pool_shape, state, rest, 512 * 2**20
     else:
         fn, rest = _scheduler_decode(
             partial(falcon_h1.paged_decode_step, cfg=cfg), 32
@@ -397,6 +438,14 @@ STEP_PROGRAMS = {
     "falcon_h1-prefill_chunk": lambda: _falcon_h1_step_case(
         "prefill_chunk"
     ),
+    # what the scheduler runs of the chunk (ISSUE 41): no head on a
+    # chunk that is not its prompt's last, one row and the sample on it
+    "llama-prefill_nohead": lambda: _llama_step_case("prefill_nohead"),
+    "llama-prefill_last": lambda: _llama_step_case("prefill_last"),
+    "falcon_h1-prefill_nohead": lambda: _falcon_h1_step_case(
+        "prefill_nohead"
+    ),
+    "falcon_h1-prefill_last": lambda: _falcon_h1_step_case("prefill_last"),
 }
 
 _MOVES = re.compile(
@@ -463,15 +512,15 @@ def test_step_program_carries_the_pool_in_place(program, compiled_step):
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
-_BF16_RESULT = re.compile(
-    r"^(?:ROOT )?%[\w.\-]+ = bf16\[([\d,]+)\][^ ]* ([\w\-]+)\("
+_RESULT = re.compile(
+    r"^(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\][^ ]* ([\w\-]+)\("
 )
 # a name for a buffer that is already there, not a buffer of its own
 _VIEWS = {"parameter", "get-tuple-element", "bitcast"}
 
 
-def _materialised_bf16(text):
-    """``(elements, opcode, line)`` of every bfloat16 array that an
+def _materialised(text, dtype="bf16"):
+    """``(elements, opcode, line)`` of every ``dtype`` array that an
     instruction OUTSIDE a fusion body produces: a buffer the program
     writes (a fusion's result, a ``copy``, a ``dynamic-slice``), where
     an instruction inside a fusion body is a value in flight."""
@@ -482,10 +531,10 @@ def _materialised_bf16(text):
         if head:
             inside_fusion = head.group(1) in fused
             continue
-        m = None if inside_fusion else _BF16_RESULT.match(line.strip())
-        if m and m.group(2) not in _VIEWS:
+        m = None if inside_fusion else _RESULT.match(line.strip())
+        if m and m.group(1) == dtype and m.group(3) not in _VIEWS:
             out.append((
-                math.prod(map(int, m.group(1).split(","))), m.group(2),
+                math.prod(map(int, m.group(2).split(","))), m.group(3),
                 line.strip()[:160],
             ))
     return out
@@ -509,10 +558,52 @@ def test_step_program_reads_the_qkv_projection_in_place(
     _, heads, dim = params["layers"]["wo"].shape  # [L, heads * hd, D]
     kv = pool_shape[3] * pool_shape[4]  # kv_heads * hd
     sizes = {dim * heads, dim * kv, dim * (heads + 2 * kv)}
-    buffers = _materialised_bf16(compiled.as_text())
+    buffers = _materialised(compiled.as_text())
     assert buffers, "the reader found no instruction at all"
     written = [b for b in buffers if b[0] in sizes]
     assert not written, written
+
+
+@pytest.mark.parametrize("model", ["llama", "falcon_h1"])
+def test_the_chunks_head_runs_only_where_it_is_read(model, compiled_step):
+    """The scheduler reads ONE row of a prompt's chunks: the last
+    token's.  The program of a chunk that is not the last computes
+    nothing of the vocabulary's width — ``lm_head`` is not even an
+    argument.  The last chunk's cuts that row from the model's ``[1,
+    128, vocab]`` logits, and the compiler moves the cut before the
+    product: it writes one float32 row of logits where the model's own
+    form writes 128 (52 MB at C's vocabulary, 134 MB at F's), in the
+    text and in ``memory_analysis()`` — the model needs no one-row
+    form of its own."""
+    whole, params, _, _ = compiled_step(f"{model}-prefill_chunk")
+    nohead, _, _, _ = compiled_step(f"{model}-prefill_nohead")
+    last, _, _, _ = compiled_step(f"{model}-prefill_last")
+    dim, vocab = params["lm_head"].shape
+    logits = 128 * vocab * 4
+
+    def rows_of_logits(compiled):
+        return {
+            elements // vocab
+            for elements, _, line in _materialised(compiled.as_text(), "f32")
+            if elements % vocab == 0 and f"{vocab}]" in line
+        }
+
+    def written(compiled):
+        mem = compiled.memory_analysis()
+        return (
+            mem.temp_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes
+        )
+
+    assert 128 in rows_of_logits(whole)  # the reader reads
+    assert written(whole) >= logits
+    assert f"[{dim},{vocab}]" in whole.as_text()
+    assert f"[{dim},{vocab}]" not in nohead.as_text()
+    assert not rows_of_logits(nohead)
+    assert f"[{dim},{vocab}]" in last.as_text()
+    assert rows_of_logits(last) == {1}
+    for program in (nohead, last):
+        assert written(whole) - written(program) > 0.9 * logits
 
 
 def _copy_case(cell):
