@@ -287,6 +287,10 @@ DEVICE_SCOPE_PARTS = frozenset(
         # in-projection, conv, state update or chunked scan,
         # out-projection
         "ssm",
+        # a learned sparse attention's indexer (models/keye_vl2.py):
+        # index projections, the index-key write, the index scores
+        # and the exact top-k
+        "indexer",
         # final norm + logits of a serving step program
         "head",
         # final norm + logits + cross-entropy of the train step

@@ -21,6 +21,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 
 def grouped_gemm(
@@ -45,3 +46,207 @@ def sort_tokens_by_expert(
     order = jnp.argsort(expert_ids, stable=True)
     group_sizes = jnp.bincount(expert_ids, length=num_experts)
     return order, group_sizes
+
+
+# ---------------------------------------------------------------------------
+# the serving plane's expert layer: one fused kernel over row tiles
+# ---------------------------------------------------------------------------
+#
+# ``lax.ragged_dot`` is three XLA custom calls a layer that carry no
+# ``jax.named_scope`` path into a device trace (``ragged-dot-none``) and
+# take one layer's ``[E, K, N]`` stack as an operand of its own: inside a
+# layer scan the compiler SLICES the stack out of ``[L, E, K, N]`` first,
+# 1.2 GB copied a layer at 128 experts of 2048 x 768.  The form below
+# reads the expert matrices where they lie, ``[L * E, K, N]`` with the
+# layer as an offset into the leading axis (what the K/V pool does with
+# its blocks), and runs gate, up and down of a row tile in one kernel.
+#
+# Rows are sorted by expert with every expert's rows starting at a
+# multiple of the tile (``tile_aligned_layout``), so a tile belongs to
+# ONE expert, whose three matrices the pipeline fetches through a
+# scalar-prefetched ``tile -> expert`` map (not again while consecutive
+# tiles stay with an expert); tiles past the last used one do nothing.
+# No capacity: the padded buffer holds every assignment whatever the
+# load, ``N * k + E * (tile - 1)`` rows at most.
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def expert_tile(n_assignments: int, num_experts: int, dtype) -> int:
+    """Rows a tile: twice the mean load of an expert rounded up to a
+    power of two, between the dtype's sublane tile and 256 (a decode
+    step's 16 x 8 assignments over 128 experts: 16 rows; a 2048-row
+    chunk's: 256)."""
+    from dlrover_tpu.ops.paged_kernels import sublane_tile
+
+    want = 2 * -(-n_assignments // num_experts)
+    tile = 1 << max(want - 1, 0).bit_length()
+    return int(min(max(tile, sublane_tile(dtype)), 256))
+
+
+def tile_aligned_layout(expert_ids: jnp.ndarray, num_experts: int, tile: int):
+    """Where ``expert_ids [A]`` (one expert an assignment) go in a
+    buffer of ``P = round_up(A + E * (tile - 1), tile)`` rows sorted by
+    expert, each expert's rows starting at a multiple of ``tile``:
+
+    ``src [P]`` the assignment a row holds and ``valid [P]`` whether it
+    holds one; ``dest [A]`` the row of each assignment; ``tile_expert
+    [P / tile]`` the expert of each tile (a tile past the last used one
+    names the last used tile's, so nothing is fetched for it) and
+    ``n_tiles [1]`` how many tiles hold rows."""
+    n = expert_ids.shape[0]
+    rows = _round_up(n + num_experts * (tile - 1), tile)
+    order, sizes = sort_tokens_by_expert(expert_ids, num_experts)
+    sizes = sizes.astype(jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes  # of a group among the sorted
+    padded = -(-sizes // tile) * tile
+    ends = jnp.cumsum(padded)  # of a group in the buffer
+    n_tiles = ends[-1] // tile
+    tiles = jnp.arange(rows // tile, dtype=jnp.int32)
+    at = jnp.minimum(tiles, jnp.maximum(n_tiles - 1, 0))
+    tile_expert = jnp.searchsorted(ends, at * tile, side="right").astype(
+        jnp.int32
+    )
+    tile_expert = jnp.minimum(tile_expert, num_experts - 1)
+    # a row's expert is its tile's: what a row needs of its expert is
+    # gathered a TILE and repeated (a gather of one int32 a row costs
+    # ~10 ns a row on the chip, six of them 2.3 ms at 49152 rows)
+    first = (ends - padded)[tile_expert]  # the expert's first row
+    local = (
+        (tiles * tile - first)[:, None] + jnp.arange(tile, dtype=jnp.int32)
+    )  # [tiles, tile]: the row's rank among its expert's
+    valid = (local < sizes[tile_expert][:, None]) & (tiles < n_tiles)[:, None]
+    src = order[
+        jnp.clip(starts[tile_expert][:, None] + local, 0, n - 1).reshape(-1)
+    ].astype(jnp.int32)
+    valid = valid.reshape(-1)
+    rank = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32)
+    )
+    dest = rank + (ends - padded - starts)[expert_ids]
+    return src, valid, dest, tile_expert, n_tiles.reshape(1)
+
+
+def _expert_ffn_kernel(tile_expert_ref, n_tiles_ref, x_ref, wg_ref, wu_ref,
+                       wd_ref, o_ref):
+    del tile_expert_ref  # the index maps read it
+    used = pl.program_id(0) < n_tiles_ref[0]
+
+    @pl.when(used)
+    def _compute():
+        x = x_ref[...]
+        gate = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        o_ref[...] = jnp.dot(
+            act, wd_ref[0], preferred_element_type=jnp.float32
+        ).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(used))
+    def _skip():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def expert_ffn_tiles(
+    rows: jnp.ndarray,  # [P, D] the tile-aligned buffer
+    w_gate: jnp.ndarray,  # [G, D, F] every group's matrix (G >= E)
+    w_up: jnp.ndarray,
+    w_down: jnp.ndarray,  # [G, F, D]
+    tile_group: jnp.ndarray,  # [P / tile] int32: the GROUP of each tile
+    n_tiles: jnp.ndarray,  # [1] int32
+    tile: int,
+) -> jnp.ndarray:
+    """``W_down^g (silu(W_gate^g r) * W_up^g r)`` for every row ``r`` of
+    every used tile, ``g`` the tile's group: one Pallas kernel, named
+    ``moe_expert_ffn`` in a device trace, a grid step a tile.  Float32
+    accumulation, the activation rounded once to the rows' dtype."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dlrover_tpu.ops.pallas_utils import named_kernel, use_interpret
+
+    n_rows, d = rows.shape
+    f = w_gate.shape[-1]
+
+    def row_index(i, groups, used):
+        del groups
+        return (jnp.minimum(i, jnp.maximum(used[0] - 1, 0)), 0)
+
+    def group_index(i, groups, used):
+        del used
+        return (groups[i], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_rows // tile,),
+        in_specs=[
+            pl.BlockSpec((tile, d), row_index),
+            pl.BlockSpec((1, d, f), group_index),
+            pl.BlockSpec((1, d, f), group_index),
+            pl.BlockSpec((1, f, d), group_index),
+        ],
+        out_specs=pl.BlockSpec((tile, d), lambda i, groups, used: (i, 0)),
+    )
+    # three matrices of an expert, double-buffered, and the tiles
+    weights = 3 * d * f * w_gate.dtype.itemsize
+    room = 2 * weights + 8 * tile * max(d, f) * 4 + (4 << 20)
+    return named_kernel(
+        "moe_expert_ffn",
+        pl.pallas_call(
+            _expert_ffn_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_rows, d), rows.dtype),
+            interpret=use_interpret(),
+            name="moe_expert_ffn",
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=int(max(room, 32 << 20))
+            ),
+        ),
+    )(tile_group.astype(jnp.int32), n_tiles.astype(jnp.int32), rows,
+      w_gate, w_up, w_down)
+
+
+def expert_ffn(
+    x: jnp.ndarray,  # [N, D]
+    expert_ids: jnp.ndarray,  # [N, k] int32
+    gates: jnp.ndarray,  # [N, k] float32, a row's weights
+    w_gate: jnp.ndarray,  # [G, D, F]: group ``first_group + e`` is
+    w_up: jnp.ndarray,  # expert e's (the stacks of EVERY layer, read
+    w_down: jnp.ndarray,  # in place; ``first_group = layer * E``)
+    first_group,  # scalar int32
+    num_experts: int,
+    backend: str,
+) -> jnp.ndarray:
+    """The routed experts' weighted sum ``[N, D]`` (float32) with every
+    one of the ``N * k`` assignments computed: ``pallas`` — the tiled
+    kernel above; ``jnp`` — the same layout through ``ragged_dot`` on
+    the layer's own slice of the stacks."""
+    n, k = expert_ids.shape
+    flat = expert_ids.reshape(-1)
+    if backend != "pallas":
+        order, sizes = sort_tokens_by_expert(flat, num_experts)
+        rows = x[order // k]
+
+        def take(w):  # this layer's experts of the stacks
+            return jax.lax.dynamic_slice_in_dim(
+                w, first_group, num_experts, 0
+            )
+
+        act = jax.nn.silu(
+            grouped_gemm(rows, take(w_gate), sizes)
+        ) * grouped_gemm(rows, take(w_up), sizes)
+        out = grouped_gemm(act, take(w_down), sizes).astype(jnp.float32)
+        out = jnp.zeros_like(out).at[order].set(out)
+    else:
+        tile = expert_tile(n * k, num_experts, x.dtype)
+        src, _, dest, tile_expert, n_tiles = tile_aligned_layout(
+            flat, num_experts, tile
+        )
+        # a row that holds no assignment holds SOME token's row: the
+        # kernel works row by row and only ``dest`` rows are read back
+        out = expert_ffn_tiles(
+            x[src // k], w_gate, w_up, w_down, tile_expert + first_group,
+            n_tiles, tile,
+        )[dest].astype(jnp.float32)
+    return (out * gates.reshape(-1)[:, None]).reshape(n, k, -1).sum(1)

@@ -58,7 +58,7 @@ and re-stacks it, i.e. moves the whole pool three times a step
 """
 
 import os
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -109,7 +109,7 @@ def paged_kernel_backend() -> str:
     return "pallas"
 
 
-def _gather_pool(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
+def gather_sequence(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     """``[num_blocks, bs, KV, D]`` gathered by ``[..., max_blocks]``
     tables -> ``[..., max_blocks * bs, KV, D]`` (the logical
     contiguous view of each sequence's paged cache)."""
@@ -142,8 +142,8 @@ def paged_decode_attention(
     b, nh, d = q.shape
     nkv = k_pool.shape[2]
     group = nh // nkv
-    k = _gather_pool(k_pool, block_tables)  # [B, T, KV, D]
-    v = _gather_pool(v_pool, block_tables)
+    k = gather_sequence(k_pool, block_tables)  # [B, T, KV, D]
+    v = gather_sequence(v_pool, block_tables)
     t = k.shape[1]
     qg = q.reshape(b, nkv, group, d)
     logits = jnp.einsum(
@@ -178,8 +178,8 @@ def paged_prefill_attention(
     c, nh, d = q.shape
     nkv = k_pool.shape[2]
     group = nh // nkv
-    k = _gather_pool(k_pool, block_table)  # [T, KV, D]
-    v = _gather_pool(v_pool, block_table)
+    k = gather_sequence(k_pool, block_table)  # [T, KV, D]
+    v = gather_sequence(v_pool, block_table)
     t = k.shape[0]
     qg = q.reshape(c, nkv, group, d)
     logits = jnp.einsum(
@@ -223,8 +223,8 @@ def paged_verify_attention(
     b, c, nh, d = q.shape
     nkv = k_pool.shape[2]
     group = nh // nkv
-    k = _gather_pool(k_pool, block_tables)  # [B, T, KV, D]
-    v = _gather_pool(v_pool, block_tables)
+    k = gather_sequence(k_pool, block_tables)  # [B, T, KV, D]
+    v = gather_sequence(v_pool, block_tables)
     t = k.shape[1]
     qg = q.reshape(b, c, nkv, group, d)
     logits = jnp.einsum(
@@ -264,6 +264,277 @@ def write_block_kv(
     return k_pool, v_pool
 
 
+# ---------------------------------------------------------------------------
+# learned sparse attention: an indexer scores every cached token from a
+# paged INDEX-KEY cache beside the K/V pool, an EXACT top-k picks the
+# token rows, and attention reads those rows alone
+# ---------------------------------------------------------------------------
+
+
+def gather_index_keys(
+    ik_pool: jnp.ndarray,  # [num_blocks, block_size * Di] index keys
+    tables: jnp.ndarray,  # [..., max_blocks] int32
+    width: int,  # Di
+) -> jnp.ndarray:
+    """The index keys of each table's sequence, ``[..., max_blocks *
+    block_size, Di]`` (position ``s`` at row ``s``)."""
+    keys = ik_pool[tables]
+    return keys.reshape(keys.shape[:-2] + (-1, width))
+
+
+def decode_index_scores(
+    qi: jnp.ndarray,  # [B, Hi, Di] one index query per lane and head
+    w: jnp.ndarray,  # [B, Hi] float32 head weights
+    keys: jnp.ndarray,  # [B, T, Di] each lane's cached index keys
+    seq_lens: jnp.ndarray,  # [B] int32: valid positions per lane
+) -> jnp.ndarray:
+    """Index scores of each lane's query against its cached index keys
+    (:func:`gather_index_keys`): ``I[b, s] = sum_h w[b, h] * relu(qi[b,
+    h] . ik[s])``, float32 ``[B, T]``, ``-inf`` at ``s >= seq_lens[b]``
+    (the null block and unwritten cells never score)."""
+    s = jnp.einsum(
+        "bhd,btd->bht", qi, keys, preferred_element_type=jnp.float32
+    )
+    score = jnp.einsum("bh,bht->bt", w.astype(jnp.float32), jax.nn.relu(s))
+    valid = jnp.arange(keys.shape[1])[None] < seq_lens[:, None]
+    return jnp.where(valid, score, -jnp.inf)
+
+
+def prefill_index_scores(
+    qi: jnp.ndarray,  # [C, Hi, Di] a chunk's index queries
+    w: jnp.ndarray,  # [C, Hi] float32
+    keys: jnp.ndarray,  # [T, Di] ONE sequence's cached index keys
+    start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
+    backend: Optional[str] = None,
+) -> jnp.ndarray:
+    """As :func:`decode_index_scores` for a chunk of one sequence:
+    float32 ``[C, T]``, ``-inf`` above the causal diagonal.  One head
+    at a time, so that what is held is ``[C, T]`` and not ``[C, Hi,
+    T]`` (2 GB at a 2048-row chunk over 16 k keys) — in a tile of fast
+    memory under the Pallas backend where the shapes tile
+    (``ops/paged_kernels.index_scores_kernel``), else in a scan."""
+    c, t = qi.shape[0], keys.shape[0]
+    if (
+        (backend or paged_kernel_backend()) == "pallas"
+        and c % min(256, c) == 0 and t % min(512, t) == 0
+    ):
+        from dlrover_tpu.ops.paged_kernels import index_scores_kernel
+
+        return index_scores_kernel(qi, w, keys, start_pos)
+
+    def one_head(acc, head):
+        q_h, w_h = head
+        s = jnp.einsum(
+            "cd,td->ct", q_h, keys, preferred_element_type=jnp.float32
+        )
+        return acc + w_h[:, None] * jax.nn.relu(s), None
+
+    score, _ = lax.scan(
+        one_head, jnp.zeros((c, t), jnp.float32),
+        (jnp.moveaxis(qi, 1, 0), jnp.moveaxis(w.astype(jnp.float32), 1, 0)),
+    )
+    visible = jnp.arange(t)[None] <= (start_pos + jnp.arange(c))[:, None]
+    return jnp.where(visible, score, -jnp.inf)
+
+
+def exact_topk_rows(
+    scores: jnp.ndarray,  # [B, T] float32
+    k: int,
+    block_tables: jnp.ndarray,  # [B, max_blocks] int32, T / max_blocks a block
+) -> jnp.ndarray:
+    """The pool ROWS (``block * block_size + offset``, through each
+    lane's table) of the ``k`` positions of largest score, exactly
+    (``lax.approx_max_k`` below recall 1 would be an approximate answer
+    where the model's is exact), equal scores lowest position first,
+    int32 ``[B, k]``.  ONE stable sort by descending score that carries
+    each position's row along: ``table[ids // bs] * bs + ids % bs``
+    after a ``top_k`` is a gather of one int32 a selected row (~10 ns
+    each on the chip: 0.33 ms a layer at 16 lanes x 2048, as long as
+    fetching the rows themselves).  Where fewer than ``k`` scores are
+    finite the tail names ``-inf`` positions: the caller knows the
+    count."""
+    b, t = scores.shape
+    bs = t // block_tables.shape[1]
+    rows = (
+        block_tables[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)
+    ).reshape(b, t)
+    _, rows = lax.sort((-scores, rows), dimension=1, is_stable=True,
+                       num_keys=1)
+    return rows[:, :k]
+
+
+def _order_keys(scores: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 whose unsigned order is the floats' order
+    (``-0.0`` as ``0.0``: equal scores are ONE value to the tie rule,
+    as they are to a sort)."""
+    bits = lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.int32
+    )
+    keys = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return lax.bitcast_convert_type(keys, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
+def exact_topk_mask(scores: jnp.ndarray, k: int) -> jnp.ndarray:
+    """``[C, T]`` float32 -> bool ``[C, T]``: row by row the ``k``
+    positions of largest score (:func:`exact_topk_rows`' rule) as a
+    mask — every finite score where a row has at most ``k`` of them.
+    The ``k``-th largest value of a row
+    is found on an order-preserving integer image of the scores, two
+    bits a pass (16 counting passes of three thresholds each, no sort:
+    a sort of ``[2048, 16384]`` is ~100 passes of twice the bytes);
+    scores equal to it are taken lowest position first until the row
+    holds ``k``.  Nothing is searched where no row has more than ``k``
+    finite scores (a prompt's first chunk), and the tie rule's running
+    count is taken only where some row has more scores AT its ``k``-th
+    value than it has room for."""
+    finite = jnp.isfinite(scores)
+
+    def search(_):
+        keys = _order_keys(scores)
+
+        def two_bits(i, thr):
+            shift = (30 - 2 * i).astype(jnp.uint32)
+            best = thr
+            for step in (1, 2, 3):  # ascending: the last that holds wins
+                cand = thr | (jnp.uint32(step) << shift)
+                enough = jnp.sum(keys >= cand, -1, keepdims=True) >= k
+                best = jnp.where(enough, cand, best)
+            return best
+
+        thr = lax.fori_loop(
+            0, 16, two_bits, jnp.zeros(keys.shape[:-1] + (1,), jnp.uint32)
+        )
+        above = keys > thr
+        equal = keys == thr
+        room = k - jnp.sum(above, -1, keepdims=True)
+        crowded = jnp.any(jnp.sum(equal, -1, keepdims=True) > room)
+        taken = lax.cond(
+            crowded,
+            lambda: above | (equal & (jnp.cumsum(equal, -1) <= room)),
+            lambda: above | equal,
+        )
+        return taken & finite
+
+    return lax.cond(
+        jnp.any(jnp.sum(finite, -1) > k), search, lambda _: finite, None
+    )
+
+
+def sparse_rows_decode_attention(
+    q: jnp.ndarray,  # [B, H, D] one query token per lane
+    k_pool: jnp.ndarray,  # [num_blocks, block_size, KV, D]
+    v_pool: jnp.ndarray,
+    rows: jnp.ndarray,  # [B, K] int32 pool rows (exact_topk_rows)
+    counts: jnp.ndarray,  # [B] int32: how many of a lane's K are real
+    backend: Optional[str] = None,
+) -> jnp.ndarray:
+    """Single-token GQA attention over the SELECTED token rows of each
+    lane's paged cache (:func:`exact_topk_rows`): the first
+    ``counts[b]`` of a lane's rows count, the rest are masked (and
+    routed to the null block).  Returns ``[B, H, D]``.
+
+    The rows are fetched by one gather a pool (a token's KV heads are
+    contiguous: ``KV * D`` elements a row) into ``[B, K, KV, D]``; the
+    attention over them is the streamed decode kernel on that buffer,
+    named ``sparse_paged_decode`` in a device trace, or the jnp
+    reference."""
+    b, nh, d = q.shape
+    n_blocks, bs, nkv, _ = k_pool.shape
+    n_sel = rows.shape[1]
+    real = jnp.arange(n_sel)[None] < counts[:, None]
+    rows = jnp.where(real, rows, 0)  # the rest to the null block
+    flat = (n_blocks * bs, nkv, d)
+    k = k_pool.reshape(flat)[rows]  # [B, K, KV, D]
+    v = v_pool.reshape(flat)[rows]
+    if (backend or paged_kernel_backend()) == "pallas":
+        from dlrover_tpu.ops.paged_kernels import sparse_decode_kernel
+
+        return sparse_decode_kernel(q, k, v, counts)
+    group = nh // nkv
+    qg = q.reshape(b, nkv, group, d)
+    logits = jnp.einsum(
+        "bkgd,btkd->bkgt", qg, k, preferred_element_type=jnp.float32
+    ) * (d**-0.5)
+    logits = jnp.where(real[:, None, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    probs = jnp.where(counts[:, None, None, None] > 0, probs, 0.0)
+    out = jnp.einsum(
+        "bkgt,btkd->bkgd", probs.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    ).astype(v.dtype)
+    return out.reshape(b, nh, d)
+
+
+def selected_prefill_attention(
+    q: jnp.ndarray,  # [C, H, D] chunk of query tokens, one sequence
+    k: jnp.ndarray,  # [T, KV, D] the sequence's cached keys, by position
+    v: jnp.ndarray,
+    taken: jnp.ndarray,  # [C, T] bool: the keys each query reads
+    start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
+    kv_len: jnp.ndarray,  # scalar int32: keys past it are never read
+    key_block: int = 1024,
+    backend: Optional[str] = None,
+) -> jnp.ndarray:
+    """Chunked-prefill attention where query ``i`` reads exactly the
+    keys ``taken[i]`` marks (a selection inside the causal mask; the
+    chunk's K/V already written, and gathered by position:
+    :func:`gather_sequence`).  Key blocks of ``key_block`` with a
+    running softmax, up to ``kv_len`` only: the logits of 2048 queries
+    and 32 heads against 16 k keys would be 4 GB at once, and a chunk
+    early in its prompt has few keys.  Returns ``[C, H, D]``.
+
+    Under the Pallas backend, where the shapes tile, the flash form of
+    the same sum (``ops/paged_kernels.selected_prefill_kernel``, which
+    also skips the key blocks above the chunk's causal reach); else
+    this one, in plain XLA."""
+    c, nh, d = q.shape
+    t, nkv = k.shape[:2]
+    group = nh // nkv
+    if (
+        (backend or paged_kernel_backend()) == "pallas"
+        and c % min(512, c) == 0 and t % min(512, t) == 0
+    ):
+        from dlrover_tpu.ops.paged_kernels import selected_prefill_kernel
+
+        return selected_prefill_kernel(q, k, v, taken, start_pos, kv_len)
+    kb = min(key_block, t)
+    if t % kb:
+        raise ValueError(f"{t} cached positions in key blocks of {kb}")
+    qg = q.reshape(c, nkv, group, d)
+
+    def one_block(j, state):
+        m, l, acc = state
+        k_j = lax.dynamic_slice_in_dim(k, j * kb, kb, 0)
+        v_j = lax.dynamic_slice_in_dim(v, j * kb, kb, 0)
+        keep = lax.dynamic_slice_in_dim(taken, j * kb, kb, 1)
+        keep = keep[:, None, None]  # [C, 1, 1, kb]
+        s = jnp.einsum(
+            "ckgd,tkd->ckgt", qg, k_j, preferred_element_type=jnp.float32
+        ) * (d**-0.5)
+        s = jnp.where(keep, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, -1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
+        l = l * alpha + jnp.sum(p, -1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "ckgt,tkd->ckgd", p.astype(v.dtype), v_j,
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l, acc
+
+    lead = (c, nkv, group)
+    _, l, acc = lax.fori_loop(
+        0, (jnp.minimum(kv_len, t) + kb - 1) // kb, one_block,
+        (
+            jnp.full(lead, NEG_INF, jnp.float32),
+            jnp.zeros(lead, jnp.float32),
+            jnp.zeros(lead + (d,), jnp.float32),
+        ),
+    )
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.astype(v.dtype).reshape(c, nh, d)
+
+
 class LayerPool(NamedTuple):
     """What one layer of a step program sees of the K/V cache: the
     WHOLE pool, every layer's blocks in one ``[L * num_blocks,
@@ -275,6 +546,10 @@ class LayerPool(NamedTuple):
     v: jnp.ndarray
     base: jnp.ndarray  # scalar int32: layer * num_blocks
     layer: jnp.ndarray  # scalar int32
+    # what a model pages beside K and V (``paged_leaves()`` of its
+    # config: an index key a token), ``{leaf: [L * num_blocks,
+    # block_size * width]}``; empty for a block of keys and values only
+    paged: Dict[str, jnp.ndarray] = {}
 
     def tables(self, block_tables: jnp.ndarray) -> jnp.ndarray:
         """A sequence's (or every lane's) table, addressing this
@@ -294,6 +569,88 @@ class LayerPool(NamedTuple):
         )
         return self._replace(k=k, v=v)
 
+    def write_leaf(
+        self,
+        name: str,
+        rows: jnp.ndarray,  # [N, ...] one token's row per write
+        block_ids: jnp.ndarray,  # [N] int32, ids of a TABLE (0 = null)
+        offsets: jnp.ndarray,  # [N] int32
+    ) -> "LayerPool":
+        """:func:`write_block_kv`'s sibling for a further paged leaf:
+        the same cells of the same blocks, in this layer.  The leaf is
+        ``[L * num_blocks, block_size * width]`` (a block's rows side
+        by side, ``rl/kv_cache.init_block_pool``): row ``i`` goes to
+        ``[offsets[i] * width, (offsets[i] + 1) * width)`` of its
+        block's row, one scatter of ``width``-wide windows."""
+        leaf = self.paged[name]
+        rows = rows.reshape(rows.shape[0], -1).astype(leaf.dtype)
+        leaf = lax.scatter(
+            leaf,
+            jnp.stack(
+                [block_ids + self.base, offsets * rows.shape[1]], -1
+            ),
+            rows,
+            lax.ScatterDimensionNumbers(
+                update_window_dims=(1,),
+                inserted_window_dims=(0,),
+                scatter_dims_to_operand_dims=(0, 1),
+            ),
+            mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+        )
+        return self._replace(paged={**self.paged, name: leaf})
+
+    def write_rows(
+        self,
+        k_new: jnp.ndarray,  # [N, KV, D]
+        v_new: jnp.ndarray,
+        block_ids: jnp.ndarray,  # [N] int32, ids of a TABLE (0 = null)
+        offsets: jnp.ndarray,  # [N] int32
+    ) -> "LayerPool":
+        """:meth:`write` over the pool seen as token ROWS, ``row =
+        block * block_size + offset`` (the view the selected-rows gather
+        reads): the same cells, as the one-index scatter the compiler
+        rewrites :func:`write_block_kv`'s two-index one into — written
+        so here, the operation keeps its scope path into a device
+        trace, which the rewritten one loses (1.4 % of the device's time
+        at a 2048-row chunk)."""
+        bs = self.k.shape[1]
+        rows = (block_ids + self.base) * bs + offsets
+        flat = (-1,) + self.k.shape[2:]
+        return self._replace(
+            k=self.k.reshape(flat).at[rows].set(k_new).reshape(self.k.shape),
+            v=self.v.reshape(flat).at[rows].set(v_new).reshape(self.v.shape),
+        )
+
+    def write_leaf_run(
+        self,
+        name: str,
+        rows: jnp.ndarray,  # [C, ...] positions start .. start + C - 1
+        block_table: jnp.ndarray,  # [max_blocks] int32: ONE sequence's
+        start: jnp.ndarray,  # scalar int32
+    ) -> "LayerPool":
+        """:meth:`write_leaf` for a RUN of one sequence's positions (a
+        prefill chunk): the blocks the run touches are read, overlaid
+        with its rows and written back whole — ``C / block_size + 1``
+        whole-row scatters where row-wise writes would be ``C`` windows
+        into a minor axis, which the compiler runs one after the other.
+        Positions past the table go to the null block."""
+        leaf = self.paged[name]
+        rows = rows.reshape(rows.shape[0], -1).astype(leaf.dtype)
+        c, width = rows.shape
+        bs, mb = leaf.shape[1] // width, block_table.shape[0]
+        at = start // bs + jnp.arange(-(-c // bs) + 1)  # table entries
+        blocks = jnp.where(
+            at < mb, block_table[jnp.minimum(at, mb - 1)], 0
+        ) + self.base
+        rel = (at[:, None] * bs + jnp.arange(bs)[None]) - start
+        mine = ((rel >= 0) & (rel < c))[..., None]
+        new = jnp.where(
+            mine, rows[jnp.clip(rel, 0, c - 1)],
+            leaf[blocks].reshape(-1, bs, width),
+        )
+        leaf = leaf.at[blocks].set(new.reshape(-1, bs * width))
+        return self._replace(paged={**self.paged, name: leaf})
+
 
 def scan_layers_over_pool(
     body: Callable,
@@ -302,6 +659,7 @@ def scan_layers_over_pool(
     k_pool: jnp.ndarray,  # [L, num_blocks, block_size, KV, D]
     v_pool: jnp.ndarray,
     read_only: bool = False,
+    paged: Optional[Dict[str, jnp.ndarray]] = None,
 ):
     """``lax.scan`` over a model's layers with the K/V pool in the
     CARRY (the step-program contract of the module docstring).
@@ -310,16 +668,27 @@ def scan_layers_over_pool(
     ``-> (carry, ys_l)`` when ``read_only``: the flat pools are then
     closed over and nothing of them is carried or returned.  Returns
     ``(carry, ys, k_pool, v_pool)`` with the pools back in their
-    stacked shape (``(carry, ys)`` when ``read_only``)."""
+    stacked shape (``(carry, ys)`` when ``read_only``).
+
+    ``paged``: the leaves a model pages beside K and V, ``{leaf: [L,
+    num_blocks, block_size * width]}``.  They ride in the carry the same
+    way (``kv.paged``, written by ``kv.write_leaf``) and come back
+    stacked as a fifth result; a program that passes none carries
+    nothing more than before."""
     n_layers, n_blocks = k_pool.shape[:2]
-    flat = (n_layers * n_blocks,) + k_pool.shape[2:]
-    k_flat, v_flat = k_pool.reshape(flat), v_pool.reshape(flat)
+
+    def flatten(pool):
+        return pool.reshape((n_layers * n_blocks,) + pool.shape[2:])
+
+    k_flat, v_flat = flatten(k_pool), flatten(v_pool)
+    stacked = paged or {}
+    more = {name: flatten(leaf) for name, leaf in stacked.items()}
 
     if read_only:
 
         def step(c, xs_l):
             carry, layer = c
-            kv = LayerPool(k_flat, v_flat, layer * n_blocks, layer)
+            kv = LayerPool(k_flat, v_flat, layer * n_blocks, layer, more)
             carry, ys_l = body(carry, xs_l, kv)
             return (carry, layer + 1), ys_l
 
@@ -327,15 +696,20 @@ def scan_layers_over_pool(
         return carry, ys
 
     def step(c, xs_l):
-        carry, k, v, layer = c
+        carry, k, v, leaves, layer = c
         carry, ys_l, kv = body(
-            carry, xs_l, LayerPool(k, v, layer * n_blocks, layer)
+            carry, xs_l, LayerPool(k, v, layer * n_blocks, layer, leaves)
         )
-        return (carry, kv.k, kv.v, layer + 1), ys_l
+        return (carry, kv.k, kv.v, kv.paged, layer + 1), ys_l
 
-    (carry, k_flat, v_flat, _), ys = lax.scan(
-        step, (carry, k_flat, v_flat, jnp.int32(0)), xs
+    (carry, k_flat, v_flat, more, _), ys = lax.scan(
+        step, (carry, k_flat, v_flat, more, jnp.int32(0)), xs
     )
-    return (
+    out = (
         carry, ys, k_flat.reshape(k_pool.shape), v_flat.reshape(v_pool.shape)
+    )
+    if paged is None:
+        return out
+    return out + (
+        {name: more[name].reshape(stacked[name].shape) for name in more},
     )

@@ -361,6 +361,260 @@ def paged_decode_kernel(
     return out.reshape(batch, n_heads, head_dim)
 
 
+def sparse_decode_kernel(
+    q: jnp.ndarray,  # [B, H, D]
+    k_rows: jnp.ndarray,  # [B, K, KV, D] the selected token rows
+    v_rows: jnp.ndarray,
+    counts: jnp.ndarray,  # [B] int32: rows of a lane that are real
+    *,
+    page: int = 256,
+) -> jnp.ndarray:
+    """Decode attention over a lane's SELECTED rows, already gathered
+    (``ops/paged_attention.sparse_rows_decode_attention``): the decode
+    kernel above on the gathered buffer, viewed as each lane's own
+    ``K / page`` pages of ``page`` tokens (a reshape of contiguous
+    rows), under the name ``sparse_paged_decode``.  Rows past
+    ``counts`` are masked as positions past ``seq_lens`` are, and a
+    lane's pages past them are neither fetched nor computed."""
+    batch, n_heads, head_dim = q.shape
+    _, n_sel, n_kv, _ = k_rows.shape
+    group = n_heads // n_kv
+    page = int(np.gcd(n_sel, page))
+    pages = n_sel // page
+    shape = (batch * pages, page, n_kv, head_dim)
+    tables = jnp.arange(batch * pages, dtype=jnp.int32).reshape(batch, pages)
+    qg = q.reshape(batch, n_kv * group, head_dim)
+    out = _paged_call(
+        functools.partial(
+            _decode_kernel,
+            span=1,
+            block_size=page,
+            n_kv=n_kv,
+            gp=group,
+            scale=head_dim**-0.5,
+        ),
+        qg,
+        k_rows.reshape(shape),
+        v_rows.reshape(shape),
+        tables,
+        counts,
+        span=1,
+        last_block=lambda lens, b: lax.div(lens[b] + page - 1, page) - 1,
+        name="sparse_paged_decode",
+    )
+    return out.reshape(batch, n_heads, head_dim)
+
+
+def _index_scores_kernel(
+    start_ref,  # scalar prefetch [1]: the chunk's first position
+    q_ref,  # [Hi, BQ, Di] index queries, a head the leading axis
+    w_ref,  # [Hi, BQ, 1] float32 head weights
+    k_ref,  # [BK, Di] index keys
+    o_ref,  # [BQ, BK] float32
+    *,
+    block_q: int,
+    block_k: int,
+):
+    i, j = pl.program_id(0), pl.program_id(1)
+    row = start_ref[0] + i * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0
+    )
+    col = j * block_k + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1
+    )
+
+    @pl.when(j * block_k <= start_ref[0] + (i + 1) * block_q - 1)
+    def _compute():
+        acc = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(q_ref.shape[0]):
+            s = lax.dot_general(
+                q_ref[h], k_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc = acc + w_ref[h] * jnp.maximum(s, 0.0)
+        o_ref[...] = jnp.where(col <= row, acc, -jnp.inf)
+
+    @pl.when(j * block_k > start_ref[0] + (i + 1) * block_q - 1)
+    def _above():  # wholly above the causal diagonal
+        o_ref[...] = jnp.full((block_q, block_k), -jnp.inf, jnp.float32)
+
+
+def index_scores_kernel(
+    qi: jnp.ndarray,  # [C, Hi, Di] a chunk's index queries
+    w: jnp.ndarray,  # [C, Hi] float32 head weights
+    keys: jnp.ndarray,  # [T, Di] the sequence's index keys by position
+    start_pos: jnp.ndarray,  # scalar int32
+    *,
+    block_q: int = 256,
+    block_k: int = 512,
+) -> jnp.ndarray:
+    """``ops/paged_attention.prefill_index_scores``'s Pallas form
+    (``index_scores`` in a device trace): ``I[t, s] = sum_h w[t, h] *
+    relu(qi[t, h] . ik[s])`` in float32, ``-inf`` above the causal
+    diagonal, a ``block_q x block_k`` tile a grid step with the heads'
+    sum held in fast memory — the XLA form reads and writes the whole
+    ``[C, T]`` float32 accumulator once a head."""
+    c, heads, d = qi.shape
+    t = keys.shape[0]
+    bq, bk = min(block_q, c), min(block_k, t)
+    if c % bq or t % bk:
+        raise ValueError(f"a chunk of {c} x {t} keys in blocks {bq} x {bk}")
+
+    def last_block(i, start):
+        return (start[0] + (i + 1) * bq - 1) // bk
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(c // bq, t // bk),
+        in_specs=[
+            pl.BlockSpec((heads, bq, d), lambda i, j, start: (0, i, 0)),
+            pl.BlockSpec((heads, bq, 1), lambda i, j, start: (0, i, 0)),
+            pl.BlockSpec(
+                (bk, d),
+                lambda i, j, start: (jnp.minimum(j, last_block(i, start)), 0),
+            ),
+        ],
+        out_specs=pl.BlockSpec((bq, bk), lambda i, j, start: (i, j)),
+    )
+    return named_kernel(
+        "index_scores",
+        pl.pallas_call(
+            functools.partial(
+                _index_scores_kernel, block_q=bq, block_k=bk
+            ),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((c, t), jnp.float32),
+            interpret=use_interpret(),
+            name="index_scores",
+        ),
+    )(
+        jnp.reshape(start_pos, (1,)).astype(jnp.int32),
+        jnp.swapaxes(qi, 0, 1),
+        jnp.swapaxes(w.astype(jnp.float32), 0, 1)[..., None],
+        keys,
+    )
+
+
+def _selected_prefill_kernel(
+    bounds_ref,  # scalar prefetch [2]: the chunk's first position, kv_len
+    q_ref,  # [1, BQ, D]
+    k_ref,  # [1, BK, D]
+    v_ref,
+    keep_ref,  # [BQ, BK] int8: the keys each query reads
+    o_ref,  # [1, BQ, D]
+    m_scr,
+    l_scr,
+    acc_scr,
+    *,
+    block_q: int,
+    block_k: int,
+    scale: float,
+):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        _init_state(m_scr, l_scr, acc_scr)
+
+    # the last key any row of this query block may read: causal, and
+    # nothing past what is cached (blocks past it were index-clamped)
+    last = jnp.minimum(
+        bounds_ref[1] - 1, bounds_ref[0] + (i + 1) * block_q - 1
+    )
+
+    @pl.when(j * block_k <= last)
+    def _compute():
+        _online_update(
+            m_scr, l_scr, acc_scr, _logits(q_ref, k_ref, scale), v_ref[0],
+            keep_ref[...].astype(jnp.int32) != 0,
+        )
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _done():
+        _finalize(o_ref, m_scr, l_scr, acc_scr)
+
+
+def selected_prefill_kernel(
+    q: jnp.ndarray,  # [C, H, D] a chunk's queries
+    k: jnp.ndarray,  # [T, KV, D] the sequence's keys by position
+    v: jnp.ndarray,
+    taken: jnp.ndarray,  # [C, T] bool: the keys each query reads
+    start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
+    kv_len: jnp.ndarray,  # scalar int32: keys past it are never read
+    *,
+    block_q: int = 512,
+    block_k: int = 512,
+) -> jnp.ndarray:
+    """Chunked-prefill GQA attention where query ``i`` reads exactly
+    the keys ``taken[i]`` marks (``ops/paged_attention.
+    selected_prefill_attention``'s Pallas form, ``sparse_prefill`` in a
+    device trace): a flash forward whose mask is data.  A grid step is
+    one head's ``block_q`` queries against ``block_k`` keys; key blocks
+    past the query block's causal reach or past ``kv_len`` are neither
+    fetched nor computed, so a chunk early in its prompt costs its own
+    keys.  The logits never leave the chip's fast memory (the XLA form
+    writes and re-reads ``[C, H, key_block]`` float32 three times a key
+    block: 0.8 GB a block at 2048 x 32)."""
+    c, n_heads, d = q.shape
+    t, n_kv, _ = k.shape
+    group = n_heads // n_kv
+    bq, bk = min(block_q, c), min(block_k, t)
+    if c % bq or t % bk:
+        raise ValueError(f"a chunk of {c} x {t} keys in blocks {bq} x {bk}")
+
+    def last_block(i, bounds):
+        last = jnp.minimum(bounds[1] - 1, bounds[0] + (i + 1) * bq - 1)
+        return jnp.maximum(last, 0) // bk
+
+    def q_index(h, i, j, bounds):
+        del j, bounds
+        return (h, i, 0)
+
+    def kv_index(h, i, j, bounds):
+        return (h // group, jnp.minimum(j, last_block(i, bounds)), 0)
+
+    def keep_index(h, i, j, bounds):
+        del h
+        return (i, jnp.minimum(j, last_block(i, bounds)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_heads, c // bq, t // bk),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), q_index),
+            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((bq, bk), keep_index),
+        ],
+        out_specs=pl.BlockSpec((1, bq, d), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
+    )
+    out = named_kernel(
+        "sparse_prefill",
+        pl.pallas_call(
+            functools.partial(
+                _selected_prefill_kernel, block_q=bq, block_k=bk,
+                scale=d**-0.5,
+            ),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_heads, c, d), q.dtype),
+            interpret=use_interpret(),
+            name="sparse_prefill",
+        ),
+    )(
+        jnp.stack([start_pos, kv_len]).astype(jnp.int32),
+        jnp.swapaxes(q, 0, 1),
+        jnp.swapaxes(k, 0, 1),
+        jnp.swapaxes(v, 0, 1),
+        taken.astype(jnp.int8),
+    )
+    return jnp.swapaxes(out, 0, 1)
+
+
 # ---------------------------------------------------------------------------
 # verify: K speculative query positions per lane share one prefix pass
 # ---------------------------------------------------------------------------

@@ -32,6 +32,7 @@ completions dedup by request id so every request finishes exactly
 once.
 """
 
+import contextlib
 import json
 import os
 import signal
@@ -271,6 +272,36 @@ def falcon_h1_factory(**cfg_kwargs):
     }
 
 
+def keye_vl2_factory(**cfg_kwargs):
+    """Built-in factory of the decoder with sparse experts and a
+    learned top-k indexer (``models/keye_vl2.py``): the same worker
+    contract, with the model's own step programs — they page an index
+    key beside K and V (``cfg.paged_leaves()``) and return the experts
+    every position was sent to (``cfg.per_token_outputs()``) — and its
+    own ``serving_params_fn``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import keye_vl2
+
+    if isinstance(cfg_kwargs.get("dtype"), str):
+        # the spec rides through JSON: dtype arrives as a name
+        cfg_kwargs = dict(cfg_kwargs, dtype=jnp.dtype(cfg_kwargs["dtype"]))
+    cfg = keye_vl2.KeyeVL2Config(**cfg_kwargs)
+    return {
+        "forward_fn": partial(keye_vl2.forward, cfg=cfg),
+        "params_template_fn": lambda: keye_vl2.init_params(
+            jax.random.PRNGKey(0), cfg
+        ),
+        "cfg": cfg,
+        "paged_decode_fn": partial(keye_vl2.paged_decode_step, cfg=cfg),
+        "paged_prefill_fn": partial(keye_vl2.paged_prefill_chunk, cfg=cfg),
+        "serving_params_fn": partial(keye_vl2.serving_params, cfg=cfg),
+    }
+
+
 def worker_main() -> int:
     """Generation-process entry (``python -m
     dlrover_tpu.rl.generation_service``); spec arrives via env."""
@@ -345,6 +376,27 @@ def _resp_spec(max_total: int):
             #         prefix_hits_total, prefix_lookups_total,
             #         adoptions_total, meta_rpcs_total
             "times": ((12,), "<f8"),
+        }
+    )
+
+
+def _per_token_spec(max_total: int, leaves: Dict):
+    """The ring a replica's per-position arrays ride to the dispatcher
+    on, one message a RESULT and just before it: ``leaves`` is the
+    scheduler's ``per_token`` (``{name: (shape, dtype)}``), each array
+    padded to ``max_total`` positions."""
+    from dlrover_tpu.data.shm_dataloader import BatchSpec
+
+    return BatchSpec(
+        {
+            "meta": ((2,), "<i8"),  # req_id, positions
+            **{
+                name: (
+                    (max_total,) + tuple(shape),
+                    np.dtype(dtype).newbyteorder("<").str,
+                )
+                for name, (shape, dtype) in sorted(leaves.items())
+            },
         }
     )
 
@@ -540,6 +592,13 @@ def _serving_worker_loop(spec) -> int:
     # spec + this pool's per-block region size, so a staged [L,
     # n_blocks, block_size, KV, head_dim] pair round-trips bitwise
     block_bytes = region_nbytes_per_block(scheduler._pool)
+    if scheduler.pool_cfg.paged_leaves and spec.get("ship_arena"):
+        raise ValueError(
+            "a disaggregated fleet's ship arena carries K and V only: "
+            "the model also pages "
+            + ", ".join(scheduler.pool_cfg.paged_names[2:])
+            + ", which a shipped prefill would lose"
+        )
     import math as _math
 
     ship_slot_bytes = 2 * block_bytes * _math.ceil(
@@ -677,8 +736,33 @@ def _serving_worker_loop(spec) -> int:
                 "dispatcher to drain", tag,
             )
 
+    # what the model's programs return a position (a router's
+    # experts; the scheduler's ``per_token``, empty for a model without
+    # or with logprobs not captured) rides a ring of its own, which
+    # the dispatcher creates from READY's description of it
+    pt_ring = None
+
+    def _put_per_token(res):
+        nonlocal pt_ring
+        if pt_ring is None:
+            pt_ring = _Ring(f"{tag}-pt")
+        msg = {"meta": np.asarray([res.req_id, res.tokens.size], np.int64)}
+        for name, (shape, dtype) in scheduler.per_token.items():
+            dt = np.dtype(dtype)
+            buf = np.full(
+                (max_total,) + tuple(shape),
+                -1 if dt.kind == "i" else np.nan, dt,
+            )
+            buf[: res.tokens.size] = res.per_token[name]
+            msg[name] = buf
+        while not pt_ring.try_put(msg, timeout=5.0):
+            if os.getppid() != parent_pid:
+                return
+
     def _flush_result(res):
         ttft_hist.observe(res.stats.get("ttft_s", 0.0))
+        if scheduler.per_token:
+            _put_per_token(res)
         _respond(
             _KIND_RESULT,
             req_id=res.req_id,
@@ -712,7 +796,21 @@ def _serving_worker_loop(spec) -> int:
     )
     # READY carries the per-block region size so the dispatcher can
     # size the ship arena without instantiating the model itself
-    _respond(_KIND_READY, times=(float(block_bytes),))
+    _respond(
+        _KIND_READY, times=(float(block_bytes),),
+        # a model with per-position outputs describes them here (the
+        # bytes of a JSON object, one an int32): the dispatcher makes
+        # their ring from it
+        tokens=(
+            np.frombuffer(
+                json.dumps(
+                    {n: [list(sh), str(dt)]
+                     for n, (sh, dt) in scheduler.per_token.items()}
+                ).encode(), np.uint8,
+            ).astype(np.int32)
+            if scheduler.per_token else None
+        ),
+    )
     logger.info("serving replica %s ready (pid %d)", tag, os.getpid())
     served = 0
     window_tokens = 0
@@ -1007,6 +1105,9 @@ class _Replica:
         self.drained = False  # clean-handshake confirmation arrived
         self.stats: Dict = {}  # newest _KIND_STATS payload
         self.block_bytes = 0  # per-block region size (READY payload)
+        # the ring of the model's per-position arrays (READY payload),
+        # None for a model without
+        self.pt_ring: Optional[_Ring] = None
         self.prefix_keys: set = set()  # newest STATS key-index digest
         self.last_prefix = (0.0, 0.0)  # cumulative (hits, lookups)
 
@@ -1136,26 +1237,28 @@ class ServingEngine:
         )
 
     # ----------------------------------------------------- lifecycle
-    def _spawn(self, idx: int) -> _Replica:
-        import contextlib
-
+    @contextlib.contextmanager
+    def _pinned_dir(self):
+        """A ring's handshake dict lives under the socket directory
+        this engine was built with, whatever the caller's is now."""
         from dlrover_tpu.common.multi_process import SOCKET_DIR_ENV
 
-        @contextlib.contextmanager
-        def pinned_dir():
-            old = os.environ.get(SOCKET_DIR_ENV)
-            if self._socket_dir:
-                os.environ[SOCKET_DIR_ENV] = self._socket_dir
-            try:
-                yield
-            finally:
-                if old is None:
-                    os.environ.pop(SOCKET_DIR_ENV, None)
-                else:
-                    os.environ[SOCKET_DIR_ENV] = old
+        old = os.environ.get(SOCKET_DIR_ENV)
+        if self._socket_dir:
+            os.environ[SOCKET_DIR_ENV] = self._socket_dir
+        try:
+            yield
+        finally:
+            if old is None:
+                os.environ.pop(SOCKET_DIR_ENV, None)
+            else:
+                os.environ[SOCKET_DIR_ENV] = old
+
+    def _spawn(self, idx: int) -> _Replica:
+        from dlrover_tpu.common.multi_process import SOCKET_DIR_ENV
 
         tag = f"{self._name}-r{idx}"
-        with pinned_dir():
+        with self._pinned_dir():
             req_ring = _Ring(
                 f"{tag}-req",
                 spec=_req_spec(self._max_seq_len),
@@ -1188,6 +1291,18 @@ class ServingEngine:
             rep.block_bytes = int(float(msg["times"][0]))
         except Exception:  # noqa: BLE001 - pre-v3 payload shape
             rep.block_bytes = 0
+        described = int(msg["meta"][2])
+        if described and rep.pt_ring is None:
+            leaves = json.loads(
+                msg["tokens"][:described].astype(np.uint8).tobytes()
+            )
+            with self._pinned_dir():
+                rep.pt_ring = _Ring(
+                    f"{self._name}-r{rep.idx}-pt",
+                    spec=_per_token_spec(self._max_seq_len, leaves),
+                    num_slots=4,
+                    create=True,
+                )
         if (
             self._n_prefill
             and self._ship_arena is None
@@ -1583,6 +1698,14 @@ class ServingEngine:
                 result["logprobs"] = (
                     msg["logprobs"][: int(meta[3])].copy()
                 )
+            if rep.pt_ring is not None:
+                # put before the RESULT, so it is there
+                rows = rep.pt_ring.try_get()
+                if rows is not None and int(rows["meta"][0]) == req_id:
+                    result["per_token"] = {
+                        name: a[:total].copy()
+                        for name, a in rows.items() if name != "meta"
+                    }
             self._complete(
                 req_id,
                 {
@@ -1984,6 +2107,8 @@ class ServingEngine:
         for rep in self._replicas:
             rep.req_ring.close(unlink=True)
             rep.resp_ring.close(unlink=True)
+            if rep.pt_ring is not None:
+                rep.pt_ring.close(unlink=True)
         if self._ship_arena is not None:
             try:
                 self._ship_arena.close()

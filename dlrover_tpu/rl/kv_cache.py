@@ -45,6 +45,7 @@ Accounting (the observatory's ``kv_blocks_used`` /
 """
 
 import hashlib
+import math
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -81,6 +82,17 @@ class PagedCacheConfig:
     # whose only state is keys and values.
     lane_state: Tuple[Tuple[str, Tuple[int, ...], object], ...] = ()
     max_slots: int = 0  # lanes the ``lane_state`` slabs are made for
+    # what a TOKEN keeps per layer beside its K and V, as the model
+    # declares it, same form: a leaf that lives in the same blocks
+    # under the same tables (an index key a token).  Empty for a block
+    # whose pages are keys and values only.
+    paged_leaves: Tuple[Tuple[str, Tuple[int, ...], object], ...] = ()
+
+    @property
+    def paged_names(self) -> Tuple[str, ...]:
+        """Every leaf of the pool that is paged: ``k``, ``v`` and the
+        model's further ones — what a block ship carries."""
+        return ("k", "v") + tuple(name for name, _, _ in self.paged_leaves)
 
     @property
     def usable_blocks(self) -> int:
@@ -104,17 +116,33 @@ def paged_cache_config(
     every token overwrites it (a recurrent state, a convolution's
     tail).  Such leaves live in the pool as ``[n_layers, max_slots,
     *shape]`` slabs indexed by lane; :class:`BlockPool` never sees
-    them."""
-    declared = getattr(model_cfg, "lane_state", None)
-    leaves = tuple(
-        (name, tuple(shape), dtype)
-        for name, (shape, dtype) in (declared() if declared else {}).items()
+    them.
+
+    It MAY also provide ``paged_leaves() -> {leaf: (shape, dtype)}``:
+    what a TOKEN keeps per layer beside its K and V — the opposite
+    kind: it has a position, so it lives in the pool as ``[n_layers,
+    num_blocks, block_size * prod(shape)]`` in the SAME blocks under the
+    same tables as ``k`` and ``v``, is shared by prefix with its block,
+    shipped with it and freed with it (a learned sparse attention's
+    index key).  :class:`BlockPool` hands out block ids and never knew
+    what a block holds."""
+
+    def declared(method):
+        method = getattr(model_cfg, method, None)
+        return tuple(
+            (name, tuple(shape), dtype)
+            for name, (shape, dtype) in (method() if method else {}).items()
+        )
+
+    leaves, paged = declared("lane_state"), declared("paged_leaves")
+    names = [name for name, _, _ in leaves + paged]
+    clash = sorted(
+        {n for n in names if names.count(n) > 1} | ({"k", "v"} & set(names))
     )
-    clash = {"k", "v"} & {name for name, _, _ in leaves}
     if clash:
         raise ValueError(
-            f"lane_state leaf name(s) {sorted(clash)} are the paged "
-            "K/V pool's"
+            f"lane_state / paged_leaves leaf name(s) {clash} are taken "
+            "(``k`` and ``v`` are the paged K/V pool's)"
         )
     return PagedCacheConfig(
         n_layers=model_cfg.n_layers,
@@ -125,13 +153,17 @@ def paged_cache_config(
         dtype=model_cfg.dtype,
         lane_state=leaves,
         max_slots=max_slots,
+        paged_leaves=paged,
     )
 
 
 def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
     """The device-side pool, stacked on the layer dim like the params:
-    ``k``, ``v`` ``[L, num_blocks, block_size, KV, head_dim]`` and one
-    zeroed ``[L, max_slots, *shape]`` slab per ``lane_state`` leaf."""
+    ``k``, ``v`` ``[L, num_blocks, block_size, KV, head_dim]``, one
+    zeroed ``[L, max_slots, *shape]`` slab per ``lane_state`` leaf and
+    one zeroed ``[L, num_blocks, block_size * prod(shape)]`` per
+    ``paged_leaves`` leaf (token ``t`` of a block at ``[t * width, (t +
+    1) * width)`` of its row)."""
     shape = (
         cfg.n_layers,
         cfg.num_blocks,
@@ -147,16 +179,22 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
         pool[name] = jnp.zeros(
             (cfg.n_layers, cfg.max_slots) + leaf_shape, dtype=dtype
         )
+    for name, leaf_shape, dtype in cfg.paged_leaves:
+        # a block's rows side by side in ONE minor axis: a minor axis
+        # of a token's own width (64) is one the device pads or lays
+        # out blocks-minor, and every program then copies the leaf
+        pool[name] = jnp.zeros(
+            shape[:2] + (cfg.block_size * math.prod(leaf_shape),),
+            dtype=dtype,
+        )
     return pool
 
 
-def lane_state_nbytes(pool: Dict[str, jnp.ndarray]) -> int:
+def lane_state_nbytes(pool: Dict[str, jnp.ndarray],
+                      cfg: PagedCacheConfig) -> int:
     """Bytes of the per-lane state slabs of a pool (0 for a pool of
-    keys and values only)."""
-    return sum(
-        int(leaf.nbytes) for name, leaf in pool.items()
-        if name not in ("k", "v")
-    )
+    pages only)."""
+    return sum(int(pool[name].nbytes) for name, _, _ in cfg.lane_state)
 
 
 def prefix_block_keys(tokens, block_size: int) -> List[str]:
@@ -175,23 +213,28 @@ def prefix_block_keys(tokens, block_size: int) -> List[str]:
     return keys
 
 
-def region_nbytes_per_block(pool: Dict[str, jnp.ndarray]) -> int:
-    """Bytes one block occupies in ONE stream (k or v) across all
-    layers — the unit the ship-arena slot sizing is quoted in.  Both
-    ends of a ship must agree on this number (same model config =>
-    same pool shape), and it is derived from the pool itself so a
-    dtype or head-dim change can never desynchronize them."""
-    return int(pool["k"].nbytes // pool["k"].shape[1])
+def region_nbytes_per_block(pool: Dict[str, jnp.ndarray],
+                            leaf: str = "k") -> int:
+    """Bytes one block occupies in ONE stream (``k``, the same as
+    ``v``; or a further paged ``leaf``) across all layers — the unit
+    the ship-arena slot sizing is quoted in.  Both ends of a ship must
+    agree on this number (same model config => same pool shape), and it
+    is derived from the pool itself so a dtype or head-dim change can
+    never desynchronize them."""
+    return int(pool[leaf].nbytes // pool[leaf].shape[1])
 
 
 def extract_block_regions(
-    pool: Dict[str, jnp.ndarray], block_ids: Sequence[int]
+    pool: Dict[str, jnp.ndarray], block_ids: Sequence[int],
+    leaves: Sequence[str] = ("k", "v"),
 ):
     """Pull the contiguous ``[L, n_blocks, block_size, KV, head_dim]``
     tiles for ``block_ids`` out of the device pool as host numpy
     arrays (k and v) — the prefill side of a KV block ship.  Full
     blocks are immutable, so the copy is a consistent snapshot; the
-    bytes are bit-exact pool content (no dtype round trip).
+    bytes are bit-exact pool content (no dtype round trip).  ``leaves``
+    (``PagedCacheConfig.paged_names`` for a model that pages more than
+    K and V) names what is pulled: one region a leaf, in that order.
 
     Blocks are pulled one at a time with a *traced* index
     (``dynamic_index_in_dim``) so the gather compiles once per pool
@@ -201,39 +244,35 @@ def extract_block_regions(
     import numpy as np
     from jax import lax
 
-    tiles = [
-        (
-            np.asarray(
-                lax.dynamic_index_in_dim(
-                    pool["k"], jnp.int32(b), axis=1, keepdims=False
+    return tuple(
+        np.stack(
+            [
+                np.asarray(
+                    lax.dynamic_index_in_dim(
+                        pool[name], jnp.int32(b), axis=1, keepdims=False
+                    )
                 )
-            ),
-            np.asarray(
-                lax.dynamic_index_in_dim(
-                    pool["v"], jnp.int32(b), axis=1, keepdims=False
-                )
-            ),
+                for b in block_ids
+            ],
+            axis=1,
         )
-        for b in block_ids
-    ]
-    return (
-        np.stack([t[0] for t in tiles], axis=1),
-        np.stack([t[1] for t in tiles], axis=1),
+        for name in leaves
     )
 
 
 def insert_block_regions(
     pool: Dict[str, jnp.ndarray],
     block_ids: Sequence[int],
-    k_region,
-    v_region,
+    *regions,
+    leaves: Sequence[str] = ("k", "v"),
 ) -> Dict[str, jnp.ndarray]:
     """Splice shipped block tiles into the receiving pool at
     ``block_ids`` (freshly allocated there) — the decode side of a KV
     block ship.  Returns the updated pool dict.  The regions must be
     the ``[L, n, block_size, KV, head_dim]`` layout
-    :func:`extract_block_regions` produced; dtype is preserved so the
-    inserted blocks are bitwise-identical attention inputs.
+    :func:`extract_block_regions` produced, one a name of ``leaves``
+    and in that order; dtype is preserved so the inserted blocks are
+    bitwise-identical attention inputs.
 
     Blocks are spliced one at a time with a *traced* index
     (``dynamic_update_index_in_dim``) so the scatter compiles once per
@@ -244,19 +283,20 @@ def insert_block_regions(
     import numpy as np
     from jax import lax
 
-    k = pool["k"]
-    v = pool["v"]
-    kr = np.asarray(k_region)
-    vr = np.asarray(v_region)
-    for j, bid in enumerate(block_ids):
-        i = jnp.int32(bid)
-        k = lax.dynamic_update_index_in_dim(
-            k, jnp.asarray(kr[:, j], k.dtype), i, axis=1
+    if len(regions) != len(leaves):
+        raise ValueError(
+            f"{len(regions)} region(s) for the leaves {tuple(leaves)}"
         )
-        v = lax.dynamic_update_index_in_dim(
-            v, jnp.asarray(vr[:, j], v.dtype), i, axis=1
-        )
-    return {**pool, "k": k, "v": v}
+    out = dict(pool)
+    for name, region in zip(leaves, regions):
+        leaf, region = out[name], np.asarray(region)
+        for j, bid in enumerate(block_ids):
+            leaf = lax.dynamic_update_index_in_dim(
+                leaf, jnp.asarray(region[:, j], leaf.dtype),
+                jnp.int32(bid), axis=1,
+            )
+        out[name] = leaf
+    return out
 
 
 class OutOfBlocksError(RuntimeError):

@@ -194,6 +194,14 @@ class GenResult:
     # the flywheel's streamed ``old_logp``, eliminating the trainer's
     # recompute forward over the rollout
     logprobs: np.ndarray = field(default_factory=_empty_logprobs)
+    # what the model's step programs decided at every position they
+    # COMPUTED (``per_token_outputs()`` of its config: a router's
+    # experts), ``{name: [P + new, ...]}``, whenever logprobs are
+    # captured: row ``j`` by the prefill program for a prompt position
+    # as by the decode program for an answer's; a position never
+    # computed here (the last new token; a shipped prefill's prompt)
+    # holds -1 (NaN in a float array).  Empty for a model without.
+    per_token: Dict[str, np.ndarray] = field(default_factory=dict)
 
 
 @dataclass(frozen=True)
@@ -232,6 +240,10 @@ class _Slot:
     # tokens sampled for this lane on the device that the host has not
     # read yet (0-2: the first token, or a decode step, or both)
     ahead: int = 0
+    # the model's per-position outputs as they came off the programs,
+    # ``(first position, rows, {name: array [>= rows, ...]})``: a
+    # prefill chunk's stay on the device until the request finishes
+    rows: List = field(default_factory=list)
 
 
 @dataclass
@@ -247,6 +259,8 @@ class _InFlight:
     lps: object
     first: bool = False
     iteration: int = 0  # the ``step()`` that dispatched it
+    # a decode step's per-position outputs, ``{name: [max_slots, ...]}``
+    rows: Optional[Dict] = None
 
 
 def sample_rows(logits, keys, sample_pos, temp: float):
@@ -278,10 +292,12 @@ def logprob_rows(logits, toks):
 
 
 def decode_program(decode_model, temp: float, capture_logprobs: bool,
-                   max_blocks: int):
+                   max_blocks: int, per_token: bool = False):
     """The plain decode step as the scheduler jits it (the pool, the
     second argument, donated): ``(params, pool, tokens, lanes, keys)
-    -> (pool, tokens'[, logprobs])``.
+    -> (pool, tokens'[, logprobs[, rows]])``.  ``per_token``: the model
+    returns a third value, its per-position outputs ``{name: [S,
+    ...]}``, which ride last where logprobs are captured.
 
     ``tokens`` is the lanes' current-token vector and ``tokens'`` the
     next one (an inactive lane keeps its entry), so a step is called
@@ -296,7 +312,7 @@ def decode_program(decode_model, temp: float, capture_logprobs: bool,
         tables = lanes[:, :max_blocks]
         positions = lanes[:, max_blocks]
         active = lanes[:, max_blocks + 1] != 0
-        logits, pool = decode_model(
+        logits, pool, *rows = decode_model(
             params, tokens, pool, tables, positions, active
         )
         # device scopes (observability/events.py DEVICE_SCOPES): the
@@ -304,24 +320,27 @@ def decode_program(decode_model, temp: float, capture_logprobs: bool,
         # module adds after it is the ``sample`` part of the same role
         with jax.named_scope("decode"), jax.named_scope("sample"):
             nxt = sample_rows(logits, keys, positions + 1, temp)
-            return pool, jnp.where(active, nxt, tokens), logits, nxt
+            return (
+                pool, jnp.where(active, nxt, tokens), logits, nxt, rows
+            )
 
     def _decode(params, pool, tokens, lanes, keys):
-        pool, tokens, _, _ = _step(params, pool, tokens, lanes, keys)
+        pool, tokens, _, _, _ = _step(params, pool, tokens, lanes, keys)
         return pool, tokens
 
     def _decode_lp(params, pool, tokens, lanes, keys):
-        pool, tokens, logits, nxt = _step(
+        pool, tokens, logits, nxt, rows = _step(
             params, pool, tokens, lanes, keys
         )
         with jax.named_scope("decode"), jax.named_scope("sample"):
-            return pool, tokens, logprob_rows(logits, nxt)
+            out = (pool, tokens, logprob_rows(logits, nxt))
+            return out + tuple(rows) if per_token else out
 
     return _decode_lp if capture_logprobs else _decode
 
 
 def prefill_programs(prefill_model, temp: float, capture_logprobs: bool,
-                     lane_state: bool):
+                     lane_state: bool, per_token: bool = False):
     """The two programs a prompt's chunks go through, as the scheduler
     jits them (the pool, the second argument, donated).  A chunk's
     logits are read in ONE place: the row of the prompt's last token,
@@ -348,7 +367,10 @@ def prefill_programs(prefill_model, temp: float, capture_logprobs: bool,
       commit later.
 
     ``lane`` and ``real`` reach the model only where it keeps per-lane
-    state (``lane_state``)."""
+    state (``lane_state``).  ``per_token``: the model returns a third
+    value, its per-position outputs ``{name: [C, ...]}``; where
+    logprobs are captured both programs return it last (``prefill``
+    then ``(pool, rows)``)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -357,13 +379,15 @@ def prefill_programs(prefill_model, temp: float, capture_logprobs: bool,
         extra = (lane, real) if lane_state else ()
         return prefill_model(params, chunk, pool, table, start, *extra)
 
+    keep_rows = per_token and capture_logprobs
+
     def _prefill(params, pool, *chunk):
-        _, pool = _model(params, pool, *chunk)
-        return pool
+        _, pool, *rows = _model(params, pool, *chunk)
+        return (pool, rows[0]) if keep_rows else pool
 
     def _prefill_last(params, pool, tokens, keys, *chunk):
         start, lane, real = chunk[2:]
-        logits, pool = _model(params, pool, *chunk)
+        logits, pool, *extra = _model(params, pool, *chunk)
         with jax.named_scope("prefill"), jax.named_scope("head"):
             # one row of [1, C, V], as a masked sum over C: it fuses
             # into the product, which then writes that row alone
@@ -378,7 +402,7 @@ def prefill_programs(prefill_model, temp: float, capture_logprobs: bool,
             out = (pool, tokens.at[lane].set(tok[0]), tok[0])
             if capture_logprobs:
                 out += (logprob_rows(logits, tok)[0],)
-            return out
+            return out + tuple(extra) if keep_rows else out
 
     return _prefill, _prefill_last
 
@@ -386,8 +410,8 @@ def prefill_programs(prefill_model, temp: float, capture_logprobs: bool,
 class ContinuousBatchingScheduler:
     """The token-level serving loop over a paged KV cache.
 
-    What a model must provide (``models/llama.py`` and
-    ``models/falcon_h1.py`` are the two that do):
+    What a model must provide (``models/llama.py``,
+    ``models/falcon_h1.py`` and ``models/keye_vl2.py`` do):
 
     - ``model_cfg``: the paged K/V geometry as attributes
       (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and,
@@ -395,7 +419,18 @@ class ContinuousBatchingScheduler:
       lane keeps per layer beside its pages (a recurrent state, a
       convolution's tail).  ``rl/kv_cache.paged_cache_config`` reads
       both; the scheduler owns the resulting pool, the state slabs
-      indexed by lane, the K/V by :class:`BlockPool`'s tables.
+      indexed by lane, the K/V by :class:`BlockPool`'s tables.  Also
+      optionally ``paged_leaves() -> {leaf: (shape, dtype)}`` — what a
+      TOKEN keeps per layer beside its K and V (an index key): a
+      further leaf in the same blocks under the same tables, shared by
+      prefix, shipped and freed with its block — and
+      ``per_token_outputs() -> {name: (shape, dtype)}`` — what the
+      step programs return for every row they compute beside the
+      logits (a router's experts), as a third value ``{name: [rows,
+      *shape]}``; while logprobs are captured it rides into
+      ``GenResult.per_token``, and such a model takes no prefix hit
+      (a shared block has no rows).  Either refuses a K-step window
+      and a draft model at construction.
     - the step programs, the llama ones unless injected:
       ``paged_decode_fn(params, tokens, pool, tables, positions,
       active) -> (logits [S, V], pool)``, which must leave an inactive
@@ -621,13 +656,43 @@ class ContinuousBatchingScheduler:
                         f"beside its paged K/V and cannot be served "
                         f"with {why}"
                     )
-        self.prefix_cache = not self.lane_state
+        # what the model pages beside K and V (an index key a token)
+        # rides in the same blocks: shared by prefix, shipped, freed
+        # with them.  What its programs return a POSITION (a router's
+        # experts) exists only for positions computed here: while
+        # logprobs are captured a prefix hit is not taken — its
+        # positions would have no rows — and counted like the lane
+        # state's.  The K-step window and a draft model are refused by
+        # name: neither ``paged_verify_step`` nor the draft loop has a
+        # selection over index keys, and the window's rollback would
+        # have to drop rows
+        per_token = getattr(model_cfg, "per_token_outputs", None)
+        self.per_token: Dict = dict(
+            per_token() if per_token and self.capture_logprobs else {}
+        )
+        if cache_cfg.paged_leaves or per_token:
+            leaves = ", ".join(cache_cfg.paged_names[2:]) or "none"
+            for refused, why in (
+                (self.decode_k > 1,
+                 "multi-token decode (DLROVER_TPU_DECODE_STEPS > 1): "
+                 "the window's verify program reads K and V only"),
+                (draft_cfg is not None,
+                 "a draft model: its verify-and-write step reads and "
+                 "writes K and V only"),
+            ):
+                if refused:
+                    raise ValueError(
+                        f"the model pages more than K and V ({leaves}) "
+                        f"or returns per-position outputs and cannot be "
+                        f"served with {why}"
+                    )
+        self.prefix_cache = not self.lane_state and not self.per_token
         self.prefix_hits_skipped = 0
         self.state_resets = 0
         self._step_state_resets = 0
         self.block_pool = BlockPool(cache_cfg)
         self._pool = init_block_pool(cache_cfg)
-        self.state_bytes = lane_state_nbytes(self._pool)
+        self.state_bytes = lane_state_nbytes(self._pool, cache_cfg)
         # the draft pool mirrors the policy pool's GEOMETRY (same
         # block ids, tables, block size) with the DRAFT model's shapes
         # — one host-side allocator drives both
@@ -701,6 +766,22 @@ class ContinuousBatchingScheduler:
         self.ahead_steps = 0
         self.sync_steps: Dict[str, int] = {}
         self.overrun_tokens = 0
+        # a model with an indexer / a router: what a decode step's
+        # lanes selected and how its rows fell on the experts (the
+        # ``serve_step`` labels ``sel_rows``, ``index_bytes``,
+        # ``experts_hit``, ``expert_rows_max``, ``expert_rows_mean``),
+        # and their sums over the run
+        self._index_row_bytes = sum(
+            int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for _, shape, dtype in cache_cfg.paged_leaves
+        ) * cache_cfg.n_layers
+        self._step_sel_rows = self._step_index_bytes = 0
+        self._step_experts: Dict = {}
+        self.sel_rows = self.index_bytes = 0
+        self.expert_totals = dict(
+            steps=0, experts_hit=0.0, expert_rows_max=0,
+            expert_rows_mean=0.0,
+        )
 
         temp = float(s.temperature)
 
@@ -851,7 +932,8 @@ class ContinuousBatchingScheduler:
         # step n's output after step n+1 has taken it as its input
         self._decode_jit = jax.jit(
             decode_program(
-                self._decode_model, temp, CAP, s.max_blocks_per_seq
+                self._decode_model, temp, CAP, s.max_blocks_per_seq,
+                per_token is not None,
             ),
             donate_argnums=(1,),
         )
@@ -880,7 +962,8 @@ class ContinuousBatchingScheduler:
             if self.draft else None
         )
         prefill, prefill_last = prefill_programs(
-            self._prefill_model, temp, CAP, self.lane_state
+            self._prefill_model, temp, CAP, self.lane_state,
+            per_token is not None,
         )
         self._prefill_jit = jax.jit(prefill, donate_argnums=(1,))
         self._prefill_last_jit = jax.jit(prefill_last, donate_argnums=(1,))
@@ -1165,6 +1248,10 @@ class ContinuousBatchingScheduler:
             sync_steps=dict(self.sync_steps),
             overrun_tokens=self.overrun_tokens,
         )
+        if self.pool_cfg.paged_leaves:
+            st.update(sel_rows=self.sel_rows, index_bytes=self.index_bytes)
+        if self.expert_totals["steps"]:
+            st.update(self.expert_totals)
         return st
 
     # ------------------------------------------------------ scheduling
@@ -1324,9 +1411,9 @@ class ContinuousBatchingScheduler:
                 self.block_pool.acquire_prefix(plan["keys"])
                 if plan["keys"] else []
             )
-            if self.lane_state:
+            if not self.prefix_cache:
                 # the index was never asked: a hit would skip tokens
-                # whose state exists nowhere
+                # whose state (or per-position rows) exists nowhere
                 self.prefix_hits_skipped += 1
             self.block_pool.allocate(
                 req.req_id,
@@ -1397,8 +1484,10 @@ class ContinuousBatchingScheduler:
             req.req_id, plan["n_tokens"], extra_blocks=plan["extra"]
         )
         ids = self.block_pool.blocks_of(req.req_id)[:n_ship]
+        names = self.pool_cfg.paged_names
         self._pool = insert_block_regions(
-            self._pool, ids, payload["k"], payload["v"]
+            self._pool, ids, *(payload[name] for name in names),
+            leaves=names,
         )
         self._tables[slot] = self.block_pool.table_row(
             req.req_id, s.max_blocks_per_seq
@@ -1530,6 +1619,7 @@ class ContinuousBatchingScheduler:
                     np.asarray(sl.logprobs, np.float32)
                     if self.capture_logprobs else _empty_logprobs()
                 ),
+                per_token=self._per_token_rows(sl, tokens.size),
             )
         )
         self.block_pool.free(req.req_id)
@@ -1540,6 +1630,56 @@ class ContinuousBatchingScheduler:
         self._positions[slot] = 0
         self._active[slot] = False
         self._slots[slot] = _Slot()
+
+    def _per_token_rows(self, sl: _Slot, n: int) -> Dict[str, np.ndarray]:
+        """The request's per-position arrays ``{name: [n, ...]}`` from
+        what its programs returned (``sl.rows``); -1 / NaN where a
+        position was never computed in this slot."""
+        out = {}
+        for name, (shape, dtype) in self.per_token.items():
+            dt = np.dtype(dtype)
+            full = np.full(
+                (n,) + tuple(shape), -1 if dt.kind == "i" else np.nan, dt
+            )
+            for start, count, rows in sl.rows:
+                full[start:start + count] = np.asarray(rows[name])[:count]
+            out[name] = full
+        return out
+
+    def _note_selection(self, cached: int):
+        """One decode lane's share of the step's ``sel_rows`` /
+        ``index_bytes`` labels (a model with an indexer only)."""
+        topk = getattr(self.cfg, "topk", None)
+        if topk is None or not self.pool_cfg.paged_leaves:
+            return
+        self._step_sel_rows += min(cached, int(topk))
+        self._step_index_bytes += cached * self._index_row_bytes
+
+    def _note_experts(self, rows: Dict[str, np.ndarray], slots: List[int]):
+        """The expert load of one committed decode step, from the ids
+        it returned ``[S, layers, k]``: distinct experts with a row
+        (mean over layers), the fullest expert's rows (max over
+        layers) and the mean rows an expert (of all of them)."""
+        ids = rows.get("experts")
+        n_experts = getattr(self.cfg, "num_experts", None)
+        if ids is None or not n_experts or not slots:
+            return
+        ids = ids[slots]  # [lanes, layers, k]
+        counts = np.stack([
+            np.bincount(ids[:, layer].reshape(-1), minlength=n_experts)
+            for layer in range(ids.shape[1])
+        ])
+        self._step_experts = dict(
+            experts=int(n_experts),
+            experts_hit=round(float((counts > 0).sum(1).mean()), 3),
+            expert_rows_max=int(counts.max()),
+            expert_rows_mean=round(
+                ids.shape[0] * ids.shape[2] / n_experts, 4
+            ),
+        )
+        for key in ("experts_hit", "expert_rows_max", "expert_rows_mean"):
+            self.expert_totals[key] += self._step_experts[key]
+        self.expert_totals["steps"] += 1
 
     def _preempt(self, slot: int):
         """Evict the sequence in ``slot`` (pool pressure): free its
@@ -1772,9 +1912,16 @@ class ContinuousBatchingScheduler:
                     )
                 )
             else:
-                self._pool = self._prefill_jit(
+                self._pool, *lp = self._as_tuple(self._prefill_jit(
                     self._params, self._pool, *args
-                )
+                ))
+            if self.per_token:
+                # the chunk's rows stay on the device until the
+                # request finishes; only their copy is started
+                rows = lp.pop()
+                for leaf in rows.values():
+                    leaf.copy_to_host_async()
+                sl.rows.append((start, real, rows))
             self.dispatches += 1
             self.prefill_chunks += 1
             self.prefill_heads += last
@@ -1811,17 +1958,16 @@ class ContinuousBatchingScheduler:
             with self._ph_commit:
                 n_ship = self.pool_cfg.blocks_for(plen)
                 ids = self.block_pool.blocks_of(req.req_id)[:n_ship]
-                k_region, v_region = extract_block_regions(
-                    self._pool, ids
-                )
+                names = self.pool_cfg.paged_names
                 self.shipped.append(
                     {
                         "req_id": req.req_id,
                         "first_token": tok,
                         "n_blocks": n_ship,
                         "prompt_len": plen,
-                        "k": k_region,
-                        "v": v_region,
+                        **dict(zip(names, extract_block_regions(
+                            self._pool, ids, names
+                        ))),
                     }
                 )
                 self.shipped_out += 1
@@ -1843,11 +1989,15 @@ class ContinuousBatchingScheduler:
             self._commit_inflight(finished)
         return real
 
+    @staticmethod
+    def _as_tuple(out):
+        return out if isinstance(out, tuple) else (out,)
+
     def _track(self, rec: _InFlight):
         """Queue a dispatch for a later commit and start its samples
         on their way to the host, so that the reads neither queue
         behind each other nor wait for the commit to ask."""
-        for out in (rec.toks, rec.lps):
+        for out in (rec.toks, rec.lps, *(rec.rows or {}).values()):
             if out is not None:
                 out.copy_to_host_async()
         rec.iteration = self.iterations
@@ -1886,8 +2036,12 @@ class ContinuousBatchingScheduler:
                 self._keys,
             )
             self.dispatches += 1
-            rec = _InFlight([], self._tokens_dev, lps[0] if lps else None)
+            rec = _InFlight(
+                [], self._tokens_dev, lps[0] if lps else None,
+                rows=lps[1] if self.per_token else None,
+            )
             for slot, sl in lanes:
+                self._note_selection(int(self._positions[slot]) + 1)
                 sl.ahead += 1
                 self._positions[slot] += 1
                 rec.lanes.append((slot, sl, int(self._positions[slot])))
@@ -1914,7 +2068,15 @@ class ContinuousBatchingScheduler:
                     None if rec.lps is None
                     else np.asarray(rec.lps).tolist()
                 )
+                rows = {
+                    name: np.asarray(leaf)
+                    for name, leaf in (rec.rows or {}).items()
+                }
             with self._ph_commit:
+                if rows:
+                    self._note_experts(
+                        rows, [slot for slot, _, _ in rec.lanes]
+                    )
                 for slot, sl, pos in rec.lanes:
                     if self._slots[slot] is not sl:
                         self.overrun_tokens += 1
@@ -1928,6 +2090,12 @@ class ContinuousBatchingScheduler:
                         lp = None if lps is None else lps[slot]
                         self.block_pool.note_filled(sl.req.req_id, pos)
                         sampled += 1
+                        if rows:
+                            # the step computed position ``pos - 1``
+                            sl.rows.append((
+                                pos - 1, 1,
+                                {n: a[slot][None] for n, a in rows.items()},
+                            ))
                     self._next_token[slot] = tok
                     self._append_token(slot, tok, finished, lp=lp)
         del self._inflight[:n]
@@ -2085,6 +2253,8 @@ class ContinuousBatchingScheduler:
         self._lanes_ahead = self._step_overrun = 0
         self._step_commits = 0
         self._step_state_resets = self._step_prefill_heads = 0
+        self._step_sel_rows = self._step_index_bytes = 0
+        self._step_experts = {}
         finished: List[GenResult] = []
         if self._adopt_finished:
             finished.extend(self._adopt_finished)
@@ -2171,8 +2341,22 @@ class ContinuousBatchingScheduler:
                 slots=self.sched.max_slots,
                 state_bytes=self.state_bytes,
                 state_resets=self._step_state_resets,
+                **self._selection_labels(),
             )
+        self.sel_rows += self._step_sel_rows
+        self.index_bytes += self._step_index_bytes
         return finished
+
+    def _selection_labels(self) -> Dict:
+        """The ``serve_step`` labels of a model with an indexer or a
+        router; none for a model without (its record is as it was)."""
+        out = dict(self._step_experts)
+        if self.pool_cfg.paged_leaves:
+            out.update(
+                sel_rows=self._step_sel_rows,
+                index_bytes=self._step_index_bytes,
+            )
+        return out
 
     def run(self, max_iterations: int = 1_000_000) -> List[GenResult]:
         """Drive until idle (offline / bench mode)."""

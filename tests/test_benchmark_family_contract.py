@@ -53,6 +53,17 @@ TINY = {
         head_dim=16, mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16,
         mamba_d_state=16, mamba_chunk_size=8,
     ),
+    # ``topk`` below SEQ: the forward and the reference both select
+    "family_keye_vl2": lambda cfg: dict(
+        hidden_size=64, num_hidden_layers=2, vocab_size=384,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        moe_intermediate_size=32, num_experts=8, num_local_experts=8,
+        num_experts_per_tok=2,
+        sa_config=dict(
+            cfg["sa_config"], indexer_head_dim=8, indexer_num_heads=2,
+            topk=16,
+        ),
+    ),
 }
 
 

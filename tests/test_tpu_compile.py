@@ -160,7 +160,55 @@ def _ssm_case():
     )
 
 
+def _sparse_prefill_case():
+    """A 2048-row chunk's attention over the keys a selection marks, at
+    Keye-VL-2.0's widths (32 / 4 heads of 128) against 8192 cached
+    positions."""
+    from dlrover_tpu.ops.paged_kernels import selected_prefill_kernel
+
+    kv = ((8192, 4, D), BF16)
+    return selected_prefill_kernel, (
+        ((2048, 32, D), BF16), kv, kv, ((2048, 8192), jnp.bool_),
+        ((), jnp.int32), ((), jnp.int32),
+    )
+
+
+def _index_scores_case():
+    """A 2048-row chunk's index scores at Keye-VL-2.0's indexer (16
+    heads of 64) against 8192 cached index keys."""
+    from dlrover_tpu.ops.paged_kernels import index_scores_kernel
+
+    return index_scores_kernel, (
+        ((2048, 16, 64), BF16), ((2048, 16), jnp.float32),
+        ((8192, 64), BF16), ((), jnp.int32),
+    )
+
+
+def _expert_ffn_case(rows):
+    """The routed experts' fused gate / up / down over row tiles at
+    Keye-VL-2.0's widths: 128 experts of 2048 x 768 in the stacks of 5
+    layers, ``rows`` x 8 assignments (a decode step's 16 rows; a 2048-
+    row chunk's)."""
+    from dlrover_tpu.ops.grouped_gemm import expert_ffn
+
+    def fn(x, ids, gates, w_gate, w_up, w_down, layer):
+        return expert_ffn(
+            x, ids, gates, w_gate, w_up, w_down, layer * 128, 128, "pallas"
+        )
+
+    w = ((640, 2048, 768), BF16)
+    return fn, (
+        ((rows, 2048), BF16), ((rows, 8), jnp.int32),
+        ((rows, 8), jnp.float32), w, w, ((640, 768, 2048), BF16),
+        ((), jnp.int32),
+    )
+
+
 CASES = {
+    "sparse_prefill": _sparse_prefill_case,
+    "index_scores": _index_scores_case,
+    "moe_expert_ffn_decode": lambda: _expert_ffn_case(16),
+    "moe_expert_ffn_chunk": lambda: _expert_ffn_case(2048),
     "ssm_decode_update": _ssm_case,
     "flash_fwd": lambda: _flash_case(H, backward=False),
     "flash_fwd_bwd_mha": lambda: _flash_case(H, backward=True),
@@ -186,6 +234,8 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     ("paged_verify_w4_kv32", "paged_verify"),
     ("rms_norm_fwd_bwd", "rmsnorm_fwd"),
     ("ssm_decode_update", "ssm_decode_update"),
+    ("sparse_prefill", "sparse_prefill"),
+    ("index_scores", "index_scores"),
 ])
 def test_serving_kernels_keep_their_names(case, name, one_chip):
     """A device trace names an operation by its HLO instruction: the
@@ -293,7 +343,7 @@ def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
     assert "all-gather" in text or "all-reduce" in text
 
 
-def _scheduler_decode(model_step, lanes, max_blocks=64):
+def _scheduler_decode(model_step, lanes, max_blocks=64, per_token=False):
     """The decode step as the scheduler jits it
     (``rl/scheduler.decode_program``, logprobs captured as in the
     cells): the lanes' token vector in and out, ONE packed upload of
@@ -301,7 +351,7 @@ def _scheduler_decode(model_step, lanes, max_blocks=64):
     argument order of this file's harness (pool third, donated)."""
     from dlrover_tpu.rl.scheduler import decode_program
 
-    prog = decode_program(model_step, 1.0, True, max_blocks)
+    prog = decode_program(model_step, 1.0, True, max_blocks, per_token)
     rest = [
         ((lanes,), jnp.int32), ((lanes, max_blocks + 2), jnp.int32),
         ((lanes, 2), jnp.uint32),
@@ -314,7 +364,8 @@ def _scheduler_decode(model_step, lanes, max_blocks=64):
     )
 
 
-def _scheduler_prefill(model_chunk, lanes, lane_state, last):
+def _scheduler_prefill(model_chunk, lanes, lane_state, last, chunk=128,
+                       max_blocks=64, per_token=False):
     """A prompt's chunk as the scheduler jits it
     (``rl/scheduler.prefill_programs``, logprobs captured as in the
     cells): the program without a head of a chunk that is not the last,
@@ -323,10 +374,13 @@ def _scheduler_prefill(model_chunk, lanes, lane_state, last):
     from dlrover_tpu.rl.scheduler import prefill_programs
 
     prefill, prefill_last = prefill_programs(
-        model_chunk, 1.0, True, lane_state
+        model_chunk, 1.0, True, lane_state, per_token
     )
     i32 = jnp.int32
-    rest = [((1, 128), i32), ((64,), i32), ((), i32), ((), i32), ((), i32)]
+    rest = [
+        ((1, chunk), i32), ((max_blocks,), i32), ((), i32), ((), i32),
+        ((), i32),
+    ]
     if not last:
         return (
             lambda params, chunk, pool, *rest: prefill(
@@ -429,7 +483,44 @@ def _falcon_h1_step_case(program):
     return partial(fn, cfg=cfg), params, pool_shape, state, rest, temp_limit
 
 
+def _keye_vl2_step_case(program):
+    """A Keye-VL-2.0 step program at ``keye-vl2-rollout-c16-ctx16k``'s
+    geometry: the published widths (128 experts of 768, top-8; a 16 x
+    64 indexer, top 2048) at depth 5, bf16 weights, 16 lanes, 18240
+    blocks of 16 (4 KV heads), tables of 1024 blocks, the index key a
+    third paged leaf ``[5, 18240, 16 * 64]``, prefill chunk 2048; the
+    experts each position chose ride out with the logprobs."""
+    from dlrover_tpu.models import keye_vl2
+
+    cfg = keye_vl2.KeyeVL2Config(num_hidden_layers=5, max_seq_len=16384)
+    params = jax.eval_shape(
+        lambda: keye_vl2.serving_params(
+            keye_vl2.init_params(jax.random.PRNGKey(0), cfg), cfg
+        )
+    )
+    pool_shape = (5, 18240, 16, 4, 128)
+    paged = {"ik": ((5, 18240, 16 * 64), BF16)}
+    if program == "decode":
+        fn, rest = _scheduler_decode(
+            partial(keye_vl2.paged_decode_step, cfg=cfg), 16, 1024, True
+        )
+        return fn, params, pool_shape, paged, rest, 64 * 2**20
+    fn, rest = _scheduler_prefill(
+        partial(keye_vl2.paged_prefill_chunk, cfg=cfg), 16, False,
+        program == "prefill_last", 2048, 1024, True,
+    )
+    # a chunk's index scores, their order keys and the selection mask
+    # are [2048, 16384] each (128 MiB float32): 0.72 GiB of them live
+    # at once, none of it the pool or a weight
+    return fn, params, pool_shape, paged, rest, 1024 * 2**20
+
+
 STEP_PROGRAMS = {
+    "keye_vl2-decode": lambda: _keye_vl2_step_case("decode"),
+    "keye_vl2-prefill_nohead": lambda: _keye_vl2_step_case(
+        "prefill_nohead"
+    ),
+    "keye_vl2-prefill_last": lambda: _keye_vl2_step_case("prefill_last"),
     "llama-decode": lambda: _llama_step_case("decode"),
     "llama-prefill_chunk": lambda: _llama_step_case("prefill_chunk"),
     "llama-verify_w4": lambda: _llama_step_case("verify"),
@@ -540,7 +631,11 @@ def _materialised(text, dtype="bf16"):
     return out
 
 
-@pytest.mark.parametrize("program", sorted(STEP_PROGRAMS))
+@pytest.mark.parametrize("program", [
+    # a 2048-row chunk of 32 heads of 128 IS as many elements as ``wq``
+    # [2048, 4096]: the pin by size cannot tell them apart there
+    p for p in sorted(STEP_PROGRAMS) if not p.startswith("keye_vl2-prefill")
+])
 def test_step_program_reads_the_qkv_projection_in_place(
     program, compiled_step
 ):
@@ -604,6 +699,47 @@ def test_the_chunks_head_runs_only_where_it_is_read(model, compiled_step):
     assert rows_of_logits(last) == {1}
     for program in (nohead, last):
         assert written(whole) - written(program) > 0.9 * logits
+
+
+@pytest.mark.parametrize(
+    "program", ["decode", "prefill_nohead", "prefill_last"]
+)
+def test_sparse_block_reads_its_experts_and_index_keys_in_place(
+    program, compiled_step
+):
+    """The block with routed experts and an indexer, at its cell's
+    geometry: the index-key leaf rides in the layer scan's carry beside
+    K and V (aliased, never copied: stored a block's keys side by side,
+    ``[L, blocks, 16 * 64]`` — a 64-wide minor axis made every program
+    copy the leaf in and out, 0.37 GB a call and as much a layer in a
+    chunk); no layer's ``[128, 2048, 768]`` expert stack is cut out of
+    ``[5, 128, ...]`` (1.2 GB a layer before the experts were read at
+    ``layer * 128`` of the flattened stacks); and the two kernels carry
+    their names."""
+    compiled, params, pool_shape, _ = compiled_step(f"keye_vl2-{program}")
+    text = compiled.as_text()
+    ik_elems = 5 * 18240 * 16 * 64
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (
+        2 * math.prod(pool_shape) + ik_elems
+    ) * 2
+    stack = math.prod(params["layers"]["w_gate"].shape[1:])
+    moved = [
+        line for elements, op, line in _materialised(text)
+        if elements in (ik_elems, ik_elems // 5, stack)
+        # the leaf's in-place scatter is a fusion with its shape too:
+        # a MOVE is named for what it does
+        and re.match(r"(ROOT )?%(copy|dynamic-slice|slice)", line)
+    ]
+    assert not moved, moved
+    def kernel(name):  # an instruction of that name, not a path
+        return re.search(rf"%{name}(\.\d+)* = ", text) is not None
+
+    assert kernel("moe_expert_ffn")
+    assert kernel("sparse_paged_decode") == (program == "decode")
+    assert kernel("sparse_prefill") == (program != "decode")
+    assert kernel("index_scores") == (program != "decode")
+    assert "ragged-dot" not in text
 
 
 def _copy_case(cell):
