@@ -61,6 +61,7 @@ def _build_for_strategy(
         mesh_ctx=mesh_ctx,
         rules=rules,
         num_micro_steps=strategy.num_micro_steps,
+        remat=strategy.remat,
     )
     return fns, mesh_ctx, rules
 

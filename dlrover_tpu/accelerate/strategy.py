@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Tuple
 
 from dlrover_tpu.accelerate.analyser import ModelProfile, fits_in_memory
 from dlrover_tpu.parallel.mesh import AxisName
+from dlrover_tpu.parallel.remat import AUTO
 
 
 @dataclass(frozen=True)
@@ -29,7 +30,12 @@ class Strategy:
     seq: int = 1
     expert: int = 1
     pipe: int = 1
-    remat: str = "full"
+    # what the model's scanned block keeps for its backward: a NAMED
+    # policy (parallel/remat.py: a rung of the ladder or "dots") is
+    # what the step is traced under, unless the model's config names
+    # its own; "auto" = resolved from the compiled step's memory at the
+    # trainer's first batch (TrainStepFns.resolve_remat)
+    remat: str = AUTO
     num_micro_steps: int = 1
     # GPipe microbatch count when pipe > 1 (0 -> auto: 2 x pipe)
     pipe_microbatches: int = 0

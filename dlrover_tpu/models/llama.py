@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.common.jax_env import kept_in_compile_cache
+from dlrover_tpu.parallel import remat as rematlib
 from dlrover_tpu.parallel import sharding as sh
 
 
@@ -42,8 +43,12 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # remat policy for the scanned block: "none" | "full" | "dots"
-    remat: str = "full"
+    # what the scanned block keeps for its backward: a NAMED policy
+    # (a rung of parallel/remat.py's ladder — "full" | "flash" | "qkv"
+    # | "matmuls" | "none" — or "dots") is obeyed; "auto" leaves it to
+    # the strategy, and where that names none either to the rung
+    # resolved from the compiled step's memory ("full" until resolved)
+    remat: str = rematlib.AUTO
     # fused-CE row-chunk size (peak logits memory = chunk x vocab fp32;
     # larger chunks = fewer scan trips, bigger lm-head matmuls)
     ce_chunk_rows: int = 512
@@ -355,14 +360,24 @@ def _layer_forward(
         q = sh.apply_sharding_constraint(
             q, (sh.BATCH, sh.SEQ, sh.HEADS, None), _current_rules()
         )
-        attn = attention_fn(q, k, v, causal=True)
-        x = x + proj(attn.reshape(b, s, nh * hd), lp["wo"])
+        # the values a remat rung may keep (parallel/remat.py): named
+        # where the backward reads them, so q and k after RoPE
+        q = rematlib.keep(q, rematlib.ATTN_Q)
+        k = rematlib.keep(k, rematlib.ATTN_K)
+        v = rematlib.keep(v, rematlib.ATTN_V)
+        attn = rematlib.keep(
+            attention_fn(q, k, v, causal=True), rematlib.ATTN_OUT
+        )
+        x = rematlib.keep(
+            x + proj(attn.reshape(b, s, nh * hd), lp["wo"]),
+            rematlib.ATTN_RESID,
+        )
 
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(proj(h, lp["w_gate"]))
-        up = proj(h, lp["w_up"])
-        x = x + proj(gate * up, lp["w_down"])
+        gate = rematlib.keep(proj(h, lp["w_gate"]), rematlib.MLP_GATE)
+        up = rematlib.keep(proj(h, lp["w_up"]), rematlib.MLP_UP)
+        x = x + proj(jax.nn.silu(gate) * up, lp["w_down"])
     return x
 
 
@@ -428,14 +443,14 @@ def forward_hidden(
     with jax.named_scope("attn"):
         cos, sin = rope_frequencies(cfg, jnp.arange(s))
 
-    block = partial(_layer_forward, cfg, attention_fn)
-    if cfg.remat == "full":
-        block = jax.checkpoint(block)
-    elif cfg.remat == "dots":
-        block = jax.checkpoint(
-            block,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-        )
+    # what the block keeps for its backward, resolved at trace time
+    # like the attention kernel: this config's own NAMED policy, else
+    # the strategy's, else the rung resolved from the compiled step's
+    # memory (parallel/remat.py; the step is traced inside its scope)
+    policy, source = rematlib.select(cfg.remat)
+    block = rematlib.checkpointed(
+        partial(_layer_forward, cfg, attention_fn), policy
+    )
 
     # strategy-selected layer executor: lax.scan normally, the GPipe
     # shard_map pipeline when the mesh runs pipe > 1 (module-replace
@@ -456,6 +471,9 @@ def forward_hidden(
     )
     for seg in segments:
         x = execute_layers(block, seg, x, cos, sin)
+    rematlib.report(
+        policy, source, cfg.n_layers, x.size * x.dtype.itemsize
+    )
     with jax.named_scope("head_loss"):
         return rms_norm(x, params["final_norm"], cfg.norm_eps)
 

@@ -326,6 +326,15 @@ UNATTRIBUTED = "unattributed"
 #: caused, and a failover-resumed action keeps the SAME decision id.
 INSTANT_EVENTS = frozenset(
     {
+        # before a trainer's first step (trainer/trainer.py
+        # ``_resolve_remat``): what the model's scanned block keeps for
+        # its backward and how that was decided — ``policy`` (a rung of
+        # parallel/remat.py's ladder or ``dots``), ``source`` (config |
+        # strategy | resolved | default), ``layers``,
+        # ``kept_bytes_per_layer``, and for a resolved rung the
+        # compiled step's ``step_bytes`` against the device's
+        # ``limit_bytes`` after ``rungs_tried`` compiles
+        "remat_plan",
         "preemption_signal",
         "job_start",
         "job_end",

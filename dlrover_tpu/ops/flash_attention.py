@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.ops.pallas_utils import use_interpret
+from dlrover_tpu.parallel import remat
 
 NEG_INF = -1e30
 
@@ -511,6 +512,10 @@ def _flash_attention_hsd(q, k, v, causal, sm_scale, block_q, block_k):
 
 def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+    # named for the remat ladder: a checkpoint policy that keeps the
+    # two does not replay ``_flash_fwd`` to rebuild the residuals
+    out = remat.keep(out, remat.ATTN_OUT)
+    lse = remat.keep(lse, remat.ATTN_LSE)
     return out, (q, k, v, out, lse)
 
 

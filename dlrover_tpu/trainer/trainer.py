@@ -309,6 +309,9 @@ class Trainer:
         # synchronous leg; both are no-ops without an events file
         self._events = get_event_logger()
         self._step_span_from = None  # perf_counter of the last step done
+        # the remat policy the step runs under, once the first batch's
+        # shape has resolved it: a label of every ``step`` span
+        self._remat = None
         # live attribution profiler (observability/attribution.py):
         # the continuous leg traces ONE step every
         # DLROVER_TPU_PROFILE_EVERY_N_STEPS (default 0 = off, zero
@@ -640,6 +643,7 @@ class Trainer:
                     dt,
                     step=step,
                     tokens=_batch_tokens(batch),
+                    **({"remat": self._remat} if self._remat else {}),
                 )
             self._step_span_from = now
         if self._spikes is not None:
@@ -712,6 +716,20 @@ class Trainer:
     #: train step (jax's call cache does not serve explicit
     #: ``.lower().compile()``); past this state size the duplicate
     #: compile is only worth it when a persistent compilation cache
+    def _resolve_remat(self, batch):
+        """Before the first step, now that the batch's shape is known:
+        what the model's scanned block keeps for its backward, resolved
+        from the compiled step's memory where nobody named it
+        (``TrainStepFns.resolve_remat``), and ONE ``remat_plan`` record
+        of what runs.  A restart resolves again, from the same shapes
+        to the same rung."""
+        plan = self._fns.resolve_remat(batch)
+        if plan is None:
+            return
+        self._remat = plan.policy
+        logger.info("remat plan: %s", plan.labels())
+        self._events.instant("remat_plan", **plan.labels())
+
     #: can answer it — otherwise the trace-summed fallback carries
     #: the number
     COST_ANALYSIS_MAX_STATE_BYTES = 2 << 30
@@ -905,6 +923,8 @@ class Trainer:
                 for batch in epoch_iter:
                     if step >= self._args.max_steps:
                         break
+                    if step == start_step:
+                        self._resolve_remat(batch)
                     open_mode = None
                     if tracing_left == 0:
                         # priority: a deep-capture request beats the

@@ -140,6 +140,12 @@ TRAIN_VARIANTS = {
     "remat_full-plain_ce": ("full", False),
     "remat_none-fused_ce": ("none", True),
     "remat_none-plain_ce": ("none", False),
+    # a rung of the ladder (parallel/remat.py) keeps named values and
+    # replays the rest of the block: what it replays still carries
+    # ``rematted_computation`` and its part
+    "remat_flash-fused_ce": ("flash", True),
+    "remat_qkv-fused_ce": ("qkv", True),
+    "remat_matmuls-fused_ce": ("matmuls", True),
 }
 TRAIN_PARTS = ("embed", "attn", "mlp", "head_loss", "optimizer")
 
@@ -177,8 +183,10 @@ def train_ops():
 def test_train_step_part_and_its_directions(variant, part, train_ops):
     """Each part is on the program, forward and (but for the optimizer)
     backward; the replay is there exactly where a ``jax.checkpoint``
-    region is replayed: the scanned block under ``remat: full``, the
-    chunked cross-entropy's logits when the loss is fused."""
+    region is replayed: the scanned block under every remat policy but
+    ``none`` (under ``matmuls`` the two norms and ``silu * up`` are
+    what is left of it), the chunked cross-entropy's logits when the
+    loss is fused."""
     remat, fused = TRAIN_VARIANTS[variant]
     directions = {
         d for _, path in train_ops[variant]
@@ -188,7 +196,7 @@ def test_train_step_part_and_its_directions(variant, part, train_ops):
     want = {"fwd"}
     if part != "optimizer":
         want.add("bwd")
-    if (part in ("attn", "mlp") and remat == "full") or (
+    if (part in ("attn", "mlp") and remat != "none") or (
         part == "head_loss" and fused
     ):
         want.add("recompute")

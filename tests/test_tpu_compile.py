@@ -897,3 +897,63 @@ def test_the_snapshot_program_spends_no_device_memory(mode, one_chip):
     else:
         assert mem.output_size_in_bytes >= padded
         assert mem.host_output_size_in_bytes == 0
+
+
+V5E_BYTES_LIMIT = 16_909_336_064  # memory_stats()["bytes_limit"] of a v5e
+
+
+@pytest.mark.parametrize(
+    "limit, policy, tried, replays_flash",
+    [
+        # the training cell on its chip: everything the backward reads
+        # fits beside 7.8 GiB of state, so nothing of the block is replayed
+        (V5E_BYTES_LIMIT, "none", 1, 0),
+        # half a GiB less and the richest rung is out: the named set stays
+        (V5E_BYTES_LIMIT - (512 << 20), "matmuls", 2, 0),
+    ],
+)
+def test_training_cells_remat_rung_is_resolved_from_compiled_memory(
+    limit, policy, tried, replays_flash, topo, monkeypatch
+):
+    """``TrainStepFns.resolve_remat`` at the training cell's shapes
+    (``mistral-7b-v0.1`` at depth 2, batch 2 x 2048, ``agd``), the step
+    compiled for the described chip under the ladder's rungs: which rung
+    the compiled bytes admit, and that a kept attention output takes the
+    ``_flash_fwd`` replay off the backward."""
+    from dlrover_tpu.accelerate import auto_accelerate
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.optimizers import agd
+    from dlrover_tpu.parallel import remat
+    from dlrover_tpu.parallel.mesh import destroy_parallel_mesh
+
+    monkeypatch.setenv("DLROVER_TPU_FLASH_ATTENTION", "1")
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+        mlp_dim=14336, max_seq_len=2048,
+    )
+    try:
+        fns = auto_accelerate(
+            loss_fn=lambda p, b: llama.loss_fn(p, b, cfg),
+            optimizer=agd(3e-5),
+            init_params_fn=lambda rng: llama.init_params(rng, cfg),
+            param_axes=llama.param_logical_axes(cfg),
+            devices=[topo.devices[0]],
+        ).fns
+        batch = {"tokens": jax.ShapeDtypeStruct((2, 2049), jnp.int32)}
+        plan = fns.resolve_remat(batch, limit_bytes=limit)
+    finally:
+        destroy_parallel_mesh()
+    assert (plan.policy, plan.source, plan.rungs_tried) == (
+        policy, "resolved", tried)
+    assert plan.layers == 2
+    assert 14.2e9 < plan.step_bytes <= limit - remat.RESERVE_BYTES
+    if policy == "matmuls":
+        # input 32 MiB + out 32 + lse 0.5 + q 32 + k, v 8 + 8 + the
+        # residual 32 + gate, up 2 x 112 MiB
+        assert plan.kept_bytes_per_layer == 386_400_256
+    text = fns.train_step._compiled.as_text()
+    assert text.count("jit(_flash_fwd)/pallas_call") == 1
+    assert text.count(
+        "rematted_computation/attn/jit(_flash_fwd)"
+    ) == replays_flash
+    assert "jit(_train_step)/" in text
