@@ -291,6 +291,12 @@ DEVICE_SCOPE_PARTS = frozenset(
         # index projections, the index-key write, the index scores
         # and the exact top-k
         "indexer",
+        # the two kinds of attention layer of one model
+        # (models/trinity.py), entered INSIDE ``attn``: a layer that
+        # reads a window of keys from blocks of its own, and a layer
+        # that reads every cached position
+        "window",
+        "full",
         # final norm + logits of a serving step program
         "head",
         # final norm + logits + cross-entropy of the train step

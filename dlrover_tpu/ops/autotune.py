@@ -128,15 +128,17 @@ def _heuristic(
     """Untuned fallback.  No per-head row padding either way: the
     kernels run every head in one matmul and pad the TOTAL row count
     to a sublane tile themselves.  Interpret mode streams one page per
-    step; compiled TPU streams the widest legal span up to 4 pages,
-    amortizing grid overhead."""
+    step; compiled TPU streams the widest legal span up to 4 pages (16
+    where a table holds 256 blocks or more), amortizing grid overhead."""
     from dlrover_tpu.ops.pallas_utils import use_interpret
 
     q_rows = group * (window if kernel == "verify" else 1)
     if use_interpret():
         return {"q_rows": q_rows, "kv_span": 1}
     span = 1
-    for cand in (2, 4):
+    # a table of thousands of pages (a 32 k-token context) is a grid of
+    # thousands of steps a lane at 4 pages a step: wider there
+    for cand in (2, 4) + ((8, 16) if max_blocks >= 256 else ()):
         if cand <= max_blocks and _span_is_legal(
             cand, block_size, max_blocks, dtype
         ):

@@ -17,7 +17,7 @@ bf16) vs the dense one-hot dispatch: forward 20.0 -> 14.5 ms (1.4x),
 forward+backward 36.8 -> 21.7 ms (1.7x) — while also being dropless.
 """
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -217,32 +217,63 @@ def expert_ffn(
     first_group,  # scalar int32
     num_experts: int,
     backend: str,
+    first_expert: int = 0,
+    held: Optional[int] = None,
 ) -> jnp.ndarray:
     """The routed experts' weighted sum ``[N, D]`` (float32) with every
     one of the ``N * k`` assignments computed: ``pallas`` — the tiled
     kernel above; ``jnp`` — the same layout through ``ragged_dot`` on
-    the layer's own slice of the stacks."""
+    the layer's own slice of the stacks.
+
+    ``held``: this chip's SHARE of the layer (expert parallelism
+    without its exchange).  ``expert_ids`` still name experts among all
+    ``num_experts``; the stacks hold the ``held`` experts
+    ``first_expert .. first_expert + held - 1`` alone (group
+    ``first_group + e - first_expert``), the assignments that fall on
+    them are computed and every other one contributes zero — what the
+    chips holding the other experts would add is theirs to add.  The
+    rows are sorted as before, with one further group behind the held
+    ones that takes the absent assignments and that no tile computes.
+    Without ``held`` every expert is here and nothing changes."""
     n, k = expert_ids.shape
     flat = expert_ids.reshape(-1)
+    groups = num_experts
+    if held is not None:
+        here = (flat >= first_expert) & (flat < first_expert + held)
+        flat = jnp.where(here, flat - first_expert, held)
+        groups = held + 1
     if backend != "pallas":
-        order, sizes = sort_tokens_by_expert(flat, num_experts)
+        order, sizes = sort_tokens_by_expert(flat, groups)
         rows = x[order // k]
+        if held is not None:
+            sizes = sizes[:held]  # rows past their sum: no group's
 
         def take(w):  # this layer's experts of the stacks
             return jax.lax.dynamic_slice_in_dim(
-                w, first_group, num_experts, 0
+                w, first_group, sizes.shape[0], 0
             )
 
         act = jax.nn.silu(
             grouped_gemm(rows, take(w_gate), sizes)
         ) * grouped_gemm(rows, take(w_up), sizes)
         out = grouped_gemm(act, take(w_down), sizes).astype(jnp.float32)
+        if held is not None:
+            out = jnp.where(
+                (jnp.arange(n * k) < jnp.sum(sizes))[:, None], out, 0.0
+            )
         out = jnp.zeros_like(out).at[order].set(out)
     else:
+        # the mean load of an expert is over ALL of them, held or not
         tile = expert_tile(n * k, num_experts, x.dtype)
         src, _, dest, tile_expert, n_tiles = tile_aligned_layout(
-            flat, num_experts, tile
+            flat, groups, tile
         )
+        if held is not None:
+            # the tiles of the group behind the held ones are past the
+            # last used tile: never fetched, never computed, zeros
+            absent = jnp.sum(flat == held, dtype=jnp.int32)
+            n_tiles = n_tiles - (absent + tile - 1) // tile
+            tile_expert = jnp.minimum(tile_expert, held - 1)
         # a row that holds no assignment holds SOME token's row: the
         # kernel works row by row and only ``dest`` rows are read back
         out = expert_ffn_tiles(

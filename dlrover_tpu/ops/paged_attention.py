@@ -125,8 +125,15 @@ def paged_decode_attention(
     block_tables: jnp.ndarray,  # [B, max_blocks] int32 block ids
     seq_lens: jnp.ndarray,  # [B] int32: valid positions per sequence
     backend: Optional[str] = None,  # None -> DLROVER_TPU_PAGED_KERNEL
+    first: Optional[jnp.ndarray] = None,  # [B] int32
+    name: str = "paged_decode",
 ) -> jnp.ndarray:
     """Single-token GQA attention over each sequence's paged prefix.
+
+    ``first``: table positions before ``first[b]`` are masked as well
+    (a layer with a window, whose table starts at the block holding the
+    window's edge: :func:`window_table_view`); ``name``: the Pallas
+    kernel's name in a device trace.
 
     Returns ``[B, H, D]``.  fp32 logits/softmax accumulation (the MXU
     contract the dense kernels follow); masked lanes contribute
@@ -138,7 +145,9 @@ def paged_decode_attention(
     if (backend or paged_kernel_backend()) == "pallas":
         from dlrover_tpu.ops.paged_kernels import paged_decode_kernel
 
-        return paged_decode_kernel(q, k_pool, v_pool, block_tables, seq_lens)
+        return paged_decode_kernel(
+            q, k_pool, v_pool, block_tables, seq_lens, first=first, name=name
+        )
     b, nh, d = q.shape
     nkv = k_pool.shape[2]
     group = nh // nkv
@@ -150,6 +159,8 @@ def paged_decode_attention(
         "bkgd,btkd->bkgt", qg, k, preferred_element_type=jnp.float32
     ) * (d**-0.5)
     valid = jnp.arange(t)[None] < seq_lens[:, None]  # [B, T]
+    if first is not None:
+        valid = valid & (jnp.arange(t)[None] >= first[:, None])
     logits = jnp.where(valid[:, None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     # Empty lanes (seq_lens == 0) have every key masked; softmax over
@@ -193,6 +204,81 @@ def paged_prefill_attention(
         "ckgt,tkd->ckgd",
         probs.astype(v.dtype),
         v,
+        preferred_element_type=jnp.float32,
+    ).astype(v.dtype)
+    return out.reshape(c, nh, d)
+
+
+def window_table_view(
+    ring: jnp.ndarray,  # [..., W] int32: a lane's ring over window blocks
+    first_block: jnp.ndarray,  # [...] int32: the first block that counts
+    n_blocks: Optional[int] = None,  # entries of the view (default W)
+) -> jnp.ndarray:
+    """A lane's table over the blocks of the layers WITH a window, in
+    position order from ``first_block``: the ring holds the block of
+    logical index ``b`` at entry ``b % W`` (``rl/kv_cache.WindowBlocks``),
+    so entry ``j`` of the view is ``ring[(first_block + j) % W]`` —
+    position ``first_block * block_size + r`` at row ``r`` of the view's
+    sequence.  Entries past ``W`` (a view padded to a kernel's key
+    block) name the null block."""
+    w = ring.shape[-1]
+    j = jnp.arange(n_blocks or w, dtype=jnp.int32)
+    idx = (first_block[..., None] + j) % w
+    return jnp.where(
+        j < w, jnp.take_along_axis(ring, idx, axis=-1), 0
+    ).astype(jnp.int32)
+
+
+def gather_heads_by_position(
+    pool: jnp.ndarray,  # [num_blocks, bs, KV, D]
+    table: jnp.ndarray,  # [n] int32: ONE sequence's blocks in position order
+) -> jnp.ndarray:
+    """``[KV, n * bs, D]``: the sequence's rows by position, a KV head
+    the leading axis (what :func:`paged_chunk_attention` reads)."""
+    g = pool[table]  # [n, bs, KV, D]
+    return jnp.moveaxis(g.reshape((-1,) + g.shape[2:]), 1, 0)
+
+
+def paged_chunk_attention(
+    q: jnp.ndarray,  # [C, H, D] chunk of query tokens, one sequence
+    k: jnp.ndarray,  # [KV, T, D] its keys by position: row r is position
+    v: jnp.ndarray,  # ``key0 + r`` (:func:`gather_heads_by_position`)
+    start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
+    key0: jnp.ndarray,  # scalar int32: the position of row 0
+    window: Optional[int] = None,
+    backend: Optional[str] = None,
+    name: str = "paged_prefill",
+) -> jnp.ndarray:
+    """Chunked-prefill attention of a LONG context: query position
+    ``t`` reads the keys ``s <= t`` and, with ``window``, ``s > t -
+    window`` only (the chunk's K/V already written).  Returns ``[C, H,
+    D]``.  The Pallas form streams key blocks with a running softmax
+    and skips those wholly outside the mask
+    (``ops/paged_kernels.chunk_prefill_kernel``); the jnp form holds
+    the whole ``[C, H, T]`` logits, which only small shapes allow
+    (:func:`paged_prefill_attention`'s gather and matmuls at 2048 rows
+    x 48 heads x 32 k keys would be 12.9 GB of float32)."""
+    if (backend or paged_kernel_backend()) == "pallas":
+        from dlrover_tpu.ops.paged_kernels import chunk_prefill_kernel
+
+        return chunk_prefill_kernel(
+            q, k, v, start_pos, key0, window=window, name=name
+        )
+    c, nh, d = q.shape
+    nkv, t, _ = k.shape
+    qg = q.reshape(c, nkv, nh // nkv, d)
+    logits = jnp.einsum(
+        "ckgd,ktd->ckgt", qg, k, preferred_element_type=jnp.float32
+    ) * (d**-0.5)
+    q_pos = (start_pos + jnp.arange(c))[:, None]
+    k_pos = (key0 + jnp.arange(t))[None]
+    visible = k_pos <= q_pos
+    if window is not None:
+        visible = visible & (k_pos > q_pos - window)
+    logits = jnp.where(visible[:, None, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum(
+        "ckgt,ktd->ckgd", probs.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     ).astype(v.dtype)
     return out.reshape(c, nh, d)

@@ -302,6 +302,37 @@ def keye_vl2_factory(**cfg_kwargs):
     }
 
 
+def trinity_factory(**cfg_kwargs):
+    """Built-in factory of the decoder with window and full attention
+    layers and a share of its routed experts (``models/trinity.py``):
+    the same worker contract, with the model's own step programs — its
+    config declares the window layers to the cache
+    (``cfg.layer_windows()``), so they take a lane's two tables side by
+    side, and they return the experts every position was sent to
+    (``cfg.per_token_outputs()``) — and its own ``serving_params_fn``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import trinity
+
+    if isinstance(cfg_kwargs.get("dtype"), str):
+        # the spec rides through JSON: dtype arrives as a name
+        cfg_kwargs = dict(cfg_kwargs, dtype=jnp.dtype(cfg_kwargs["dtype"]))
+    cfg = trinity.TrinityConfig(**cfg_kwargs)
+    return {
+        "forward_fn": partial(trinity.forward, cfg=cfg),
+        "params_template_fn": lambda: trinity.init_params(
+            jax.random.PRNGKey(0), cfg
+        ),
+        "cfg": cfg,
+        "paged_decode_fn": partial(trinity.paged_decode_step, cfg=cfg),
+        "paged_prefill_fn": partial(trinity.paged_prefill_chunk, cfg=cfg),
+        "serving_params_fn": partial(trinity.serving_params, cfg=cfg),
+    }
+
+
 def worker_main() -> int:
     """Generation-process entry (``python -m
     dlrover_tpu.rl.generation_service``); spec arrives via env."""
@@ -793,6 +824,10 @@ def _serving_worker_loop(spec) -> int:
         replica=tag,
         kernel_backend=paged_kernel_backend(),
         interpret=use_interpret(),
+        # the cache as the program sized it: every leaf of the pool
+        # (``k``, ``v``; ``wk``, ``wv`` of the layers with a window;
+        # lane state; further paged leaves) and their bytes together
+        **scheduler.pool_report(),
     )
     # READY carries the per-block region size so the dispatcher can
     # size the ship arena without instantiating the model itself
@@ -1038,6 +1073,7 @@ def _serving_worker_loop(spec) -> int:
         device_count=device["device_count"],
         replica=tag,
         compile_counts=json.dumps(scheduler.compile_counts()),
+        pool_stats=json.dumps(scheduler.block_pool.stats()),
     )
     logger.info(
         "serving replica %s drained on %s: served %d, handed back %d",

@@ -30,6 +30,16 @@ shared block whose last holder frees it moves to a ref-count-gated
 LRU cache (content retained for future hits) and is evicted back to
 the free list only under allocation pressure, oldest first.
 
+**Layers of two kinds**: a model MAY declare a window a layer
+(:func:`paged_cache_config`).  Layers without one keep the pool and the
+tables above, to the byte; layers WITH one keep only the positions a
+step can still read, in blocks of their own under a second table a
+lane — a ring that :class:`WindowBlocks`, owned by the
+:class:`BlockPool`, fills as the lane advances and empties behind the
+window.  At 16 lanes of 32 k positions, one full and four window layers
+of 4096 cost 2.39 + 1.62 GB where one table for all five would cost
+11.9.
+
 Accounting (the observatory's ``kv_blocks_used`` /
 ``kv_utilization`` gauges read these):
 
@@ -87,6 +97,36 @@ class PagedCacheConfig:
     # under the same tables (an index key a token).  Empty for a block
     # whose pages are keys and values only.
     paged_leaves: Tuple[Tuple[str, Tuple[int, ...], object], ...] = ()
+    # a window a layer, as the model declares it (``None``: the layer
+    # keeps every position).  Empty for a model whose layers all do.
+    # Layers WITH a window have blocks of their own (``wk``, ``wv``)
+    # under a second table a lane of ``window_table_blocks`` entries,
+    # sized by :func:`window_table_blocks`
+    layer_windows: Tuple[Optional[int], ...] = ()
+    window_table_blocks: int = 0
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(w is not None for w in self.layer_windows)
+
+    @property
+    def n_full_layers(self) -> int:
+        """Layers of the ``k`` / ``v`` pool: those that keep every
+        position (all of them for a model that declares no window)."""
+        return self.n_layers - self.n_window_layers
+
+    @property
+    def window(self) -> Optional[int]:
+        """The window of the layers that have one."""
+        return next((w for w in self.layer_windows if w is not None), None)
+
+    @property
+    def window_blocks(self) -> int:
+        """Blocks of one window layer, the null block included: every
+        lane's allotment, which no lane can exceed."""
+        if not self.n_window_layers:
+            return 0
+        return self.max_slots * self.window_table_blocks + 1
 
     @property
     def paged_names(self) -> Tuple[str, ...]:
@@ -103,8 +143,20 @@ class PagedCacheConfig:
         return blocks_needed(n_tokens, self.block_size)
 
 
+def window_table_blocks(window: int, prefill_chunk: int,
+                        block_size: int) -> int:
+    """Entries of a lane's table over the window layers' blocks: a
+    step that writes positions ``[start, end)`` reads none before
+    ``start - window + 1``, and the widest step is a prefill chunk, so
+    a lane never holds more than ``window - 1 + prefill_chunk``
+    positions there — that many blocks, and one more because the span
+    need not start at a block's first token (4096 / 2048 / 16: 385)."""
+    return blocks_needed(window - 1 + prefill_chunk, block_size) + 1
+
+
 def paged_cache_config(
-    model_cfg, num_blocks: int, block_size: int, max_slots: int
+    model_cfg, num_blocks: int, block_size: int, max_slots: int,
+    prefill_chunk: int = 0,
 ) -> PagedCacheConfig:
     """The cache a model asks for, from its own declaration — the ONE
     place a model config is read for it (policy and draft pools
@@ -125,7 +177,21 @@ def paged_cache_config(
     same tables as ``k`` and ``v``, is shared by prefix with its block,
     shipped with it and freed with it (a learned sparse attention's
     index key).  :class:`BlockPool` hands out block ids and never knew
-    what a block holds."""
+    what a block holds.
+
+    And it MAY provide ``layer_windows() -> (window | None, ...)``, one
+    entry a layer: a layer with a window reads, at query position
+    ``t``, the keys ``t - window < s <= t`` only, so its pages behind
+    the window are dead.  Layers without one keep the pool above and
+    its tables, to the byte; layers WITH one get blocks of their own,
+    ``wk``, ``wv`` ``[window layers, max_slots * W + 1, block_size, KV,
+    D]`` with ``W`` = :func:`window_table_blocks` (from the window,
+    ``prefill_chunk`` and ``block_size``: nothing else sizes it), and a
+    SECOND table a lane of ``W`` entries used as a ring — the block of
+    positions ``[b * block_size, (b + 1) * block_size)`` sits at entry
+    ``b % W`` — which :class:`WindowBlocks` (owned by the
+    :class:`BlockPool`) fills as the lane advances and empties behind
+    the window.  Such blocks are never shared by prefix or shipped."""
 
     def declared(method):
         method = getattr(model_cfg, method, None)
@@ -144,6 +210,34 @@ def paged_cache_config(
             f"lane_state / paged_leaves leaf name(s) {clash} are taken "
             "(``k`` and ``v`` are the paged K/V pool's)"
         )
+    windows = getattr(model_cfg, "layer_windows", None)
+    windows = tuple(windows()) if windows else ()
+    table_blocks = 0
+    if not any(w is not None for w in windows):
+        windows = ()
+    else:
+        if len(windows) != model_cfg.n_layers:
+            raise ValueError(
+                f"layer_windows() names {len(windows)} layers of "
+                f"{model_cfg.n_layers}"
+            )
+        sizes = {int(w) for w in windows if w is not None}
+        if len(sizes) != 1 or min(sizes) < 1 or prefill_chunk < 1:
+            raise ValueError(
+                f"layer_windows(): one window >= 1 for the layers that "
+                f"have one (got {sorted(sizes)}) and the prefill chunk "
+                f"that sizes their tables (got {prefill_chunk})"
+            )
+        if all(w is not None for w in windows) or paged or leaves:
+            raise ValueError(
+                "layer_windows(): at least one layer keeps every "
+                "position (the sequence's pool and tables are its), and "
+                "a model with windows declares no lane_state / "
+                "paged_leaves"
+            )
+        table_blocks = window_table_blocks(
+            min(sizes), prefill_chunk, block_size
+        )
     return PagedCacheConfig(
         n_layers=model_cfg.n_layers,
         n_kv_heads=model_cfg.n_kv_heads,
@@ -154,6 +248,8 @@ def paged_cache_config(
         lane_state=leaves,
         max_slots=max_slots,
         paged_leaves=paged,
+        layer_windows=windows,
+        window_table_blocks=table_blocks,
     )
 
 
@@ -163,9 +259,12 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
     zeroed ``[L, max_slots, *shape]`` slab per ``lane_state`` leaf and
     one zeroed ``[L, num_blocks, block_size * prod(shape)]`` per
     ``paged_leaves`` leaf (token ``t`` of a block at ``[t * width, (t +
-    1) * width)`` of its row)."""
+    1) * width)`` of its row).  Where the model declares windows,
+    ``k``, ``v`` hold the layers WITHOUT one (in layer order) and
+    ``wk``, ``wv`` ``[window layers, window_blocks, block_size, KV,
+    head_dim]`` the others'."""
     shape = (
-        cfg.n_layers,
+        cfg.n_full_layers,
         cfg.num_blocks,
         cfg.block_size,
         cfg.n_kv_heads,
@@ -175,6 +274,10 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
     }
+    if cfg.n_window_layers:
+        wshape = (cfg.n_window_layers, cfg.window_blocks) + shape[2:]
+        pool["wk"] = jnp.zeros(wshape, dtype=cfg.dtype)
+        pool["wv"] = jnp.zeros(wshape, dtype=cfg.dtype)
     for name, leaf_shape, dtype in cfg.lane_state:
         pool[name] = jnp.zeros(
             (cfg.n_layers, cfg.max_slots) + leaf_shape, dtype=dtype
@@ -319,6 +422,99 @@ class _SeqAlloc:
     shared_prefix: int = 0  # leading blocks held via the shared index
 
 
+class WindowBlocks:
+    """Host-side accounting of the blocks of the layers WITH a window
+    (one id names a block in every such layer, as a pool block id does
+    in every layer without).  A sequence holds the blocks of the
+    positions a step may still read or is about to write, and nothing
+    behind its window: :meth:`advance` is called before every step
+    with the first position the step can read and the end of what it
+    writes, gives back every block wholly before the first, and takes
+    blocks up to the second.  A lane's table is a RING of
+    ``table_blocks`` entries: the block of logical index ``b`` (its
+    positions are ``[b * block_size, (b + 1) * block_size)``) sits at
+    entry ``b % table_blocks``; an entry whose block was given back
+    names the null block 0.  The live span never exceeds the ring
+    (:func:`window_table_blocks`), so two live blocks never meet in one
+    entry, and the pool holds ``max_slots`` rings: it cannot run dry.
+
+    LIFO free list, as :class:`BlockPool`'s: a block given back is
+    re-issued first — to whichever lane asks next, so a stale entry
+    reads ANOTHER sequence's keys, never zeros."""
+
+    def __init__(self, cfg: PagedCacheConfig):
+        self.cfg = cfg
+        self.table_blocks = cfg.window_table_blocks
+        self._free: List[int] = list(range(cfg.window_blocks - 1, 0, -1))
+        # seq -> [first live logical block, one past the last, ring]
+        self._seqs: Dict[int, list] = {}
+        self.allocated = 0  # blocks taken, ever
+        self.released = 0  # blocks given back BEHIND a live window
+        self.peak_live = 0
+
+    @property
+    def live_blocks(self) -> int:
+        return self.cfg.window_blocks - 1 - len(self._free)
+
+    def advance(self, seq_id: int, first_read: int, end_write: int) -> bool:
+        """Before a step of ``seq_id`` that reads no position before
+        ``first_read`` and writes up to ``end_write`` (exclusive);
+        whether the sequence's ring changed."""
+        bs, ring_len = self.cfg.block_size, self.table_blocks
+        lo = max(int(first_read), 0) // bs
+        hi = blocks_needed(end_write, bs)
+        if hi - lo > ring_len:
+            raise ValueError(
+                f"seq {seq_id}: positions [{first_read}, {end_write}) "
+                f"span {hi - lo} blocks > the lane's allotment of "
+                f"{ring_len} over the window layers"
+            )
+        st = self._seqs.get(seq_id)
+        if st is None:
+            st = self._seqs[seq_id] = [lo, lo, [0] * ring_len]
+        first, last, ring = st
+        for b in range(first, min(lo, last)):
+            self._free.append(ring[b % ring_len])
+            ring[b % ring_len] = 0
+            self.released += 1
+        first = max(first, lo)
+        for b in range(max(last, first), hi):
+            ring[b % ring_len] = self._free.pop()
+            self.allocated += 1
+        changed = (first, max(last, hi)) != (st[0], st[1])
+        st[0], st[1] = first, max(last, hi)
+        self.peak_live = max(self.peak_live, self.live_blocks)
+        return changed
+
+    def live_range(self, seq_id: int) -> Tuple[int, int]:
+        """Logical blocks ``[first, end)`` the sequence holds."""
+        first, last, _ = self._seqs[seq_id]
+        return first, last
+
+    def table_row(self, seq_id: int) -> List[int]:
+        """The sequence's ring, ``table_blocks`` entries (zeros for a
+        sequence that holds nothing yet)."""
+        st = self._seqs.get(seq_id)
+        return list(st[2]) if st else [0] * self.table_blocks
+
+    def free(self, seq_id: int) -> int:
+        st = self._seqs.pop(seq_id, None)
+        if st is None:
+            return 0
+        first, last, ring = st
+        for b in range(first, last):
+            self._free.append(ring[b % self.table_blocks])
+        return last - first
+
+    def stats(self) -> Dict[str, int]:
+        return {
+            "window_blocks_live": self.live_blocks,
+            "window_blocks_peak": self.peak_live,
+            "window_blocks_allocated": self.allocated,
+            "window_blocks_released": self.released,
+        }
+
+
 class BlockPool:
     """Host-side block accounting (free list + per-sequence tables +
     the ref-counted shared-block index).
@@ -346,6 +542,11 @@ class BlockPool:
         self.peak_used = 0
         self.prefix_hits = 0  # full-block lookups answered shared
         self.prefix_queries = 0  # full-block lookups attempted
+        # the blocks of the layers with a window, where the model has
+        # any: a second allocator, freed with the sequence
+        self.window: Optional[WindowBlocks] = (
+            WindowBlocks(cfg) if cfg.n_window_layers else None
+        )
 
     # ---------------------------------------------------------- queries
     @property
@@ -419,7 +620,13 @@ class BlockPool:
         return self.prefix_hits / self.prefix_queries
 
     def stats(self) -> Dict[str, float]:
+        more = {}
+        if self.window is not None:
+            # the same count under the name that tells the two kinds
+            # apart in a report
+            more = dict(self.window.stats(), full_blocks_live=self.used_blocks)
         return {
+            **more,
             "used_blocks": self.used_blocks,
             "free_blocks": self.free_blocks,
             "cached_shared_blocks": self.cached_shared_blocks,
@@ -608,6 +815,8 @@ class BlockPool:
         intact for future prefix hits).  Raises
         :class:`DoubleFreeError` if any block would land on the free
         list twice."""
+        if self.window is not None:
+            self.window.free(seq_id)
         alloc = self._seqs.pop(seq_id, None)
         if alloc is None:
             return 0

@@ -411,7 +411,8 @@ class ContinuousBatchingScheduler:
     """The token-level serving loop over a paged KV cache.
 
     What a model must provide (``models/llama.py``,
-    ``models/falcon_h1.py`` and ``models/keye_vl2.py`` do):
+    ``models/falcon_h1.py``, ``models/keye_vl2.py`` and
+    ``models/trinity.py`` do):
 
     - ``model_cfg``: the paged K/V geometry as attributes
       (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and,
@@ -430,7 +431,27 @@ class ContinuousBatchingScheduler:
       *shape]}``; while logprobs are captured it rides into
       ``GenResult.per_token``, and such a model takes no prefix hit
       (a shared block has no rows).  Either refuses a K-step window
-      and a draft model at construction.
+      and a draft model at construction.  And optionally
+      ``layer_windows() -> (window | None, ...)``, one entry a layer
+      (``models/trinity.py``): a layer WITH a window reads the keys ``t
+      - window < s <= t`` only, so its blocks are its own kind — the
+      pool then holds ``k``, ``v`` for the layers without one (under
+      :class:`BlockPool`'s tables, unchanged) and ``wk``, ``wv`` for the
+      others, ``max_slots`` rings of ``W`` blocks and a null block a
+      layer, ``W`` sized by ``rl/kv_cache.window_table_blocks`` from the
+      window, ``prefill_chunk`` and ``block_size`` and nothing else.
+      A lane's SECOND table is that ring: before every chunk and
+      every decode step the scheduler brings it up to the step
+      (``_lane_tables`` -> ``WindowBlocks.advance``: blocks wholly
+      behind the window given back, blocks up to the step's last write
+      taken) and uploads it BEHIND the sequence's table, one row
+      ``[max_blocks + W]`` a lane; the model's step programs split the
+      row at ``ceil(model_cfg.max_seq_len / block_size)``, which must be
+      the scheduler's.  Such a model takes no prefix hit (a shared block
+      would need the window layers' blocks kept with its refcount) and
+      refuses at construction, by name, a K-step window, a draft model
+      and the ``prefill`` role (a ship carries the sequence's blocks
+      only); preemption and resume re-prefill from token 0.
     - the step programs, the llama ones unless injected:
       ``paged_decode_fn(params, tokens, pool, tables, positions,
       active) -> (logits [S, V], pool)``, which must leave an inactive
@@ -627,7 +648,8 @@ class ContinuousBatchingScheduler:
         self._adopt_finished: List[GenResult] = []
 
         cache_cfg = paged_cache_config(
-            model_cfg, s.num_blocks, s.block_size, s.max_slots
+            model_cfg, s.num_blocks, s.block_size, s.max_slots,
+            s.prefill_chunk,
         )
         self.pool_cfg = cache_cfg
         # per-lane state beside the pages: no positions, no sharing, no
@@ -686,7 +708,40 @@ class ContinuousBatchingScheduler:
                         f"or returns per-position outputs and cannot be "
                         f"served with {why}"
                     )
-        self.prefix_cache = not self.lane_state and not self.per_token
+        # layers with a window keep their blocks under a second table a
+        # lane (``WindowBlocks``): a block there is given back once the
+        # window has passed it, so it can be neither shared by prefix
+        # (a shared block would need the window layers' blocks kept
+        # with its refcount) nor shipped, and a K-step window or a draft
+        # model would read or write through the sequence's table alone
+        self.window = cache_cfg.window
+        if self.window is not None:
+            for refused, why in (
+                (self.decode_k > 1,
+                 "multi-token decode (DLROVER_TPU_DECODE_STEPS > 1): the "
+                 "window's verify program reads one table a lane"),
+                (draft_cfg is not None,
+                 "a draft model: its pool mirrors one table a lane"),
+                (role == "prefill",
+                 "the prefill role: a shipped prefill carries the "
+                 "sequence's blocks, not the window layers'"),
+                (getattr(model_cfg, "max_seq_len", s.max_seq_len)
+                 != s.max_seq_len,
+                 f"max_seq_len {s.max_seq_len}: the model's step "
+                 f"programs split a lane's two tables at its own "
+                 f"({getattr(model_cfg, 'max_seq_len', None)})"),
+            ):
+                if refused:
+                    raise ValueError(
+                        f"the model's layers with a window "
+                        f"({self.window}) keep their blocks under a "
+                        f"second table a lane and cannot be served with "
+                        f"{why}"
+                    )
+        self.prefix_cache = (
+            not self.lane_state and not self.per_token
+            and self.window is None
+        )
         self.prefix_hits_skipped = 0
         self.state_resets = 0
         self._step_state_resets = 0
@@ -707,6 +762,11 @@ class ContinuousBatchingScheduler:
         # host mirrors of the fixed-shape device inputs
         S, MB = s.max_slots, s.max_blocks_per_seq
         self._tables = np.zeros((S, MB), np.int32)
+        # each lane's ring over the window layers' blocks, uploaded
+        # behind its table (no column for a model without windows)
+        self._wtables = np.zeros(
+            (S, cache_cfg.window_table_blocks), np.int32
+        )
         self._positions = np.zeros((S,), np.int32)
         self._active = np.zeros((S,), bool)
         # the lanes' current tokens: ON THE DEVICE for the plain decode
@@ -777,6 +837,13 @@ class ContinuousBatchingScheduler:
         ) * cache_cfg.n_layers
         self._step_sel_rows = self._step_index_bytes = 0
         self._step_experts: Dict = {}
+        # a model with windows: the rows a decode step's kernels had to
+        # read, by kind of layer, and a chunk's shape (``serve_step``
+        # labels ``kv_rows_window`` / ``kv_rows_full``; ``prefill``
+        # span labels ``rows`` / ``kv_len``)
+        self._step_kv_rows = [0, 0]
+        self._step_chunk: Dict = {}
+        self._window_counts = (0, 0)
         self.sel_rows = self.index_bytes = 0
         self.expert_totals = dict(
             steps=0, experts_hit=0.0, expert_rows_max=0,
@@ -932,7 +999,8 @@ class ContinuousBatchingScheduler:
         # step n's output after step n+1 has taken it as its input
         self._decode_jit = jax.jit(
             decode_program(
-                self._decode_model, temp, CAP, s.max_blocks_per_seq,
+                self._decode_model, temp, CAP,
+                s.max_blocks_per_seq + cache_cfg.window_table_blocks,
                 per_token is not None,
             ),
             donate_argnums=(1,),
@@ -1209,6 +1277,19 @@ class ContinuousBatchingScheduler:
             "decode": n(active_decode),
             "prefill": n(self._prefill_jit),
             "sample": n(self._prefill_last_jit),
+        }
+
+    def pool_report(self) -> Dict:
+        """The device-side pool as it was made: ``pool`` — a JSON
+        object ``{leaf: shape}`` — and ``pool_bytes``, for the replica's
+        ``device_report``."""
+        import json
+
+        return {
+            "pool": json.dumps(
+                {name: list(a.shape) for name, a in self._pool.items()}
+            ),
+            "pool_bytes": int(sum(a.nbytes for a in self._pool.values())),
         }
 
     def stats(self) -> Dict:
@@ -1627,6 +1708,7 @@ class ContinuousBatchingScheduler:
         # zero the table row: a freed block re-issued to another
         # sequence must never be gathered through this lane again
         self._tables[slot] = 0
+        self._wtables[slot] = 0
         self._positions[slot] = 0
         self._active[slot] = False
         self._slots[slot] = _Slot()
@@ -1669,13 +1751,25 @@ class ContinuousBatchingScheduler:
             np.bincount(ids[:, layer].reshape(-1), minlength=n_experts)
             for layer in range(ids.shape[1])
         ])
+        share = {}
+        held = getattr(self.cfg, "held_experts", None)
+        if held is not None:
+            # this chip's share of the layer: the load is counted over
+            # the experts that live here, beside every assignment made
+            first = int(getattr(self.cfg, "first_expert", 0))
+            share = dict(expert_rows=int(counts.sum()))
+            counts, n_held = counts[:, first:first + held], int(held)
+            share["expert_rows_local"] = int(counts.sum())
+        else:
+            n_held = int(n_experts)
         self._step_experts = dict(
-            experts=int(n_experts),
+            experts=n_held,
             experts_hit=round(float((counts > 0).sum(1).mean()), 3),
             expert_rows_max=int(counts.max()),
             expert_rows_mean=round(
                 ids.shape[0] * ids.shape[2] / n_experts, 4
             ),
+            **share,
         )
         for key in ("experts_hit", "expert_rows_max", "expert_rows_mean"):
             self.expert_totals[key] += self._step_experts[key]
@@ -1715,6 +1809,7 @@ class ContinuousBatchingScheduler:
         if req.slo_class == SLO_INTERACTIVE:
             self._queued_interactive += 1
         self._tables[slot] = 0
+        self._wtables[slot] = 0
         self._positions[slot] = 0
         self._active[slot] = False
         self._slots[slot] = _Slot()
@@ -1892,7 +1987,7 @@ class ContinuousBatchingScheduler:
                 chunk = np.pad(chunk, (0, s.prefill_chunk - real))
             args = (
                 np.array(chunk[None], np.int32),
-                self._tables[slot].copy(),
+                self._lane_tables(slot, start, start + s.prefill_chunk),
                 np.int32(start),
                 np.int32(slot),
                 np.int32(real),
@@ -1924,6 +2019,7 @@ class ContinuousBatchingScheduler:
                 sl.rows.append((start, real, rows))
             self.dispatches += 1
             self.prefill_chunks += 1
+            self._step_chunk = dict(rows=int(real), kv_len=int(start + real))
             self.prefill_heads += last
             self._step_prefill_heads += last
             if self.lane_state and start == 0:
@@ -1989,6 +2085,28 @@ class ContinuousBatchingScheduler:
             self._commit_inflight(finished)
         return real
 
+    def _advance_window(self, slot: int, start: int, end: int):
+        """Bring ``slot``'s ring over the window layers' blocks up to a
+        step that writes positions ``[start, end)``: blocks wholly
+        behind ``start``'s window given back, blocks up to ``end``
+        taken (nothing for a model without windows).  A block given
+        back here may be re-issued at once: the programs already queued
+        read it before anything dispatched later writes it."""
+        if self.window is None:
+            return
+        req_id = self._slots[slot].req.req_id
+        blocks = self.block_pool.window
+        if blocks.advance(req_id, start - self.window + 1, end):
+            self._wtables[slot] = blocks.table_row(req_id)
+
+    def _lane_tables(self, slot: int, start: int, end: int) -> np.ndarray:
+        """The table row a step program of ``slot`` that writes
+        positions ``[start, end)`` is handed, a fresh array: the
+        sequence's table and, behind it, its ring over the window
+        layers' blocks (:meth:`_advance_window`)."""
+        self._advance_window(slot, start, end)
+        return np.concatenate([self._tables[slot], self._wtables[slot]])
+
     @staticmethod
     def _as_tuple(out):
         return out if isinstance(out, tuple) else (out,)
@@ -2010,6 +2128,7 @@ class ContinuousBatchingScheduler:
         positions advance by one, and a lane whose tokens in flight
         complete its ``max_new`` sits out until they are committed."""
         S, MB = self.sched.max_slots, self.sched.max_blocks_per_seq
+        MW = MB + self._wtables.shape[1]
         lanes = [
             (slot, sl) for slot, sl in enumerate(self._slots)
             if sl.phase == "decode"
@@ -2023,11 +2142,18 @@ class ContinuousBatchingScheduler:
         with self._ph_dispatch:
             # ONE upload a step, built fresh: the host mutates its
             # tables and positions while the program is in flight
-            packed = np.empty((S, MB + 2), np.int32)
+            if self.window is not None:
+                for slot, _ in lanes:
+                    pos = int(self._positions[slot])
+                    self._advance_window(slot, pos, pos + 1)
+                    self._step_kv_rows[0] += min(pos + 1, self.window)
+                    self._step_kv_rows[1] += pos + 1
+            packed = np.empty((S, MW + 2), np.int32)
             packed[:, :MB] = self._tables
-            packed[:, MB] = self._positions
-            packed[:, MB + 1] = 0
-            packed[[slot for slot, _ in lanes], MB + 1] = 1
+            packed[:, MB:MW] = self._wtables
+            packed[:, MW] = self._positions
+            packed[:, MW + 1] = 0
+            packed[[slot for slot, _ in lanes], MW + 1] = 1
             self._pool, self._tokens_dev, *lps = self._decode_jit(
                 self._params,
                 self._pool,
@@ -2255,6 +2381,8 @@ class ContinuousBatchingScheduler:
         self._step_state_resets = self._step_prefill_heads = 0
         self._step_sel_rows = self._step_index_bytes = 0
         self._step_experts = {}
+        self._step_kv_rows = [0, 0]
+        self._step_chunk = {}
         finished: List[GenResult] = []
         if self._adopt_finished:
             finished.extend(self._adopt_finished)
@@ -2302,6 +2430,7 @@ class ContinuousBatchingScheduler:
                     tokens=pre,
                     prefix_hit_blocks=hit_blocks,
                     req_id=self._last_prefill_req,
+                    **(self._step_chunk if self.window is not None else {}),
                 )
             if dec or self._lanes_decode:
                 self._events.complete(
@@ -2356,6 +2485,21 @@ class ContinuousBatchingScheduler:
                 sel_rows=self._step_sel_rows,
                 index_bytes=self._step_index_bytes,
             )
+        if self.window is not None:
+            # rows the decode kernels had to read, summed over the lanes
+            # that decoded and the layers of each kind; the window
+            # layers' blocks taken and given back since the last record
+            cfgp, blocks = self.pool_cfg, self.block_pool.window
+            taken, released = self._window_counts
+            self._window_counts = (blocks.allocated, blocks.released)
+            out.update(
+                kv_rows_window=self._step_kv_rows[0] * cfgp.n_window_layers,
+                kv_rows_full=self._step_kv_rows[1] * cfgp.n_full_layers,
+                window_blocks_live=blocks.live_blocks,
+                window_blocks_taken=blocks.allocated - taken,
+                window_blocks_released=blocks.released - released,
+                full_blocks_live=self.block_pool.used_blocks,
+            )
         return out
 
     def run(self, max_iterations: int = 1_000_000) -> List[GenResult]:
@@ -2393,6 +2537,7 @@ class ContinuousBatchingScheduler:
                 continue
             self.block_pool.free(sl.req.req_id)
             self._tables[slot] = 0
+            self._wtables[slot] = 0
             self._positions[slot] = 0
             self._active[slot] = False
             sl.req.resume_tokens = np.asarray(sl.generated, np.int32)

@@ -64,6 +64,15 @@ TINY = {
             topk=16,
         ),
     ),
+    # a window below SEQ; 2 routed experts held of the router's 2 x 8
+    # (``deployment`` stays the configuration's: eight chips a layer)
+    "family_trinity": lambda cfg: dict(
+        hidden_size=64, num_hidden_layers=5, num_dense_layers=1,
+        num_expert_layers=4, vocab_size=384, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, intermediate_size=128,
+        moe_intermediate_size=32, num_experts=2, num_experts_per_tok=2,
+        sliding_window=16,
+    ),
 }
 
 

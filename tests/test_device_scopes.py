@@ -117,7 +117,11 @@ def test_the_reader_repeats_the_programs_closed_set():
     # ``indexer`` (PR 42) is entered INSIDE ``attn``: the reader's set,
     # which a program PR may not edit, counts its time under ``attn``,
     # and ``readers_sparse.scope_share`` reads it with the part added
-    assert set(readers_scopes.PARTS) | {"indexer"} == DEVICE_SCOPE_PARTS
+    # ``window`` / ``full`` (PR 44) likewise: the kind of a layer's
+    # attention, inside ``attn``
+    assert set(readers_scopes.PARTS) | {
+        "indexer", "window", "full"
+    } == DEVICE_SCOPE_PARTS
     assert not DEVICE_SCOPE_ROLES & DEVICE_SCOPE_PARTS
 
 

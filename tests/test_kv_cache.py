@@ -419,3 +419,151 @@ class TestPagedDecodePath:
             np.asarray(all_logits[:, -1]),
             rtol=2e-4, atol=2e-4,
         )
+
+
+# ---------------------------------------------------------------------------
+# layers of two kinds in one manager (ISSUE 44): the layers WITH a window
+# keep their blocks under a second table a lane, a ring
+# ---------------------------------------------------------------------------
+
+
+class _WindowedModel:
+    """A model config as ``paged_cache_config`` reads it: five layers,
+    the fourth without a window."""
+
+    n_layers, n_kv_heads, head_dim, dtype = 5, 2, 8, jnp.float32
+
+    def __init__(self, windows=(32, 32, 32, None, 32)):
+        self._windows = windows
+
+    def layer_windows(self):
+        return self._windows
+
+
+def _windowed_cfg(**kw):
+    from dlrover_tpu.rl.kv_cache import paged_cache_config
+
+    args = dict(num_blocks=41, block_size=4, max_slots=3, prefill_chunk=10)
+    args.update(kw)
+    return paged_cache_config(_WindowedModel(), **args)
+
+
+class TestWindowLayers:
+    def test_the_count_is_sized_from_the_declaration(self):
+        from dlrover_tpu.rl.kv_cache import window_table_blocks
+
+        # the issue's geometry: 4096 / 2048 / 16 -> 385 a lane
+        assert window_table_blocks(4096, 2048, 16) == 385
+        cfg = _windowed_cfg()
+        # window - 1 + chunk = 41 positions: 11 blocks of 4, and one
+        # more because the span need not start at a block's first token
+        assert cfg.window_table_blocks == 12
+        assert cfg.window_blocks == 3 * 12 + 1
+        assert (cfg.n_full_layers, cfg.n_window_layers) == (1, 4)
+        pool = init_block_pool(cfg)
+        assert pool["k"].shape == pool["v"].shape == (1, 41, 4, 2, 8)
+        assert pool["wk"].shape == pool["wv"].shape == (4, 37, 4, 2, 8)
+
+    def test_a_model_without_windows_gets_the_pool_it_had(self):
+        from dlrover_tpu.rl.kv_cache import paged_cache_config
+
+        class Plain(_WindowedModel):
+            layer_windows = None
+
+        for model in (Plain(), _WindowedModel((None,) * 5)):
+            cfg = paged_cache_config(model, 41, 4, 3, 10)
+            assert cfg.layer_windows == () and cfg.window_blocks == 0
+            assert sorted(init_block_pool(cfg)) == ["k", "v"]
+            assert init_block_pool(cfg)["k"].shape[0] == 5
+            assert BlockPool(cfg).window is None
+            assert "window_blocks_live" not in BlockPool(cfg).stats()
+
+    @pytest.mark.parametrize("windows,chunk,why", [
+        ((32, 32, 32, None), 10, "names 4 layers of 5"),
+        ((32, 16, 32, None, 32), 10, "one window"),
+        ((32, 32, 32, None, 32), 0, "the prefill chunk"),
+        ((32,) * 5, 10, "at least one layer keeps every position"),
+    ])
+    def test_a_declaration_that_cannot_be_sized_is_refused(
+        self, windows, chunk, why
+    ):
+        from dlrover_tpu.rl.kv_cache import paged_cache_config
+
+        with pytest.raises(ValueError, match=why):
+            paged_cache_config(_WindowedModel(windows), 41, 4, 3, chunk)
+
+    def test_a_lane_never_exceeds_its_allotment(self):
+        """A sequence walked through chunks and then token by token to
+        200 positions holds at most its ring, whatever the boundaries,
+        and its ring names each live block at ``b % ring``."""
+        pool = BlockPool(_windowed_cfg())
+        blocks, window, chunk, bs = pool.window, 32, 10, 4
+        ring = blocks.table_blocks
+        pos = 0
+        while pos < 53:  # a prompt of 53 tokens in padded chunks of 10
+            blocks.advance(7, pos - window + 1, pos + chunk)
+            first, end = blocks.live_range(7)
+            assert end - first <= ring
+            assert first == max(pos - window + 1, 0) // bs
+            assert end == -(-(pos + chunk) // bs)
+            row = blocks.table_row(7)
+            live = {row[b % ring] for b in range(first, end)}
+            assert 0 not in live and len(live) == end - first
+            assert sum(x != 0 for x in row) == end - first
+            pos += chunk
+        for pos in range(53, 200):
+            blocks.advance(7, pos - window + 1, pos + 1)
+            first, end = blocks.live_range(7)
+            # the blocks a padded chunk took ahead stay until decode
+            # has written them; past those a lane holds its window
+            assert end - first <= (
+                ring if pos < 60 else (window - 1) // bs + 2
+            )
+            assert blocks.live_blocks == end - first
+        st = pool.stats()
+        assert st["window_blocks_released"] == first
+        assert st["window_blocks_allocated"] == end
+        assert st["window_blocks_peak"] <= ring
+        pool.free(7)
+        assert pool.stats()["window_blocks_live"] == 0
+
+    def test_a_released_block_goes_to_the_next_asker_and_is_never_named(
+        self
+    ):
+        """What a lane gives back leaves its ring at once (a stale entry
+        would read ANOTHER sequence's keys) and is re-issued first."""
+        blocks = BlockPool(_windowed_cfg()).window
+        blocks.advance(1, 0, 40)
+        held = set(blocks.table_row(1)) - {0}
+        blocks.advance(1, 17, 40)  # blocks 0-3 fall behind position 17
+        kept = set(blocks.table_row(1)) - {0}
+        gone = held - kept
+        assert len(gone) == 4
+        blocks.advance(2, 0, 16)
+        assert set(blocks.table_row(2)) - {0} == gone
+        assert not (set(blocks.table_row(1)) & gone)
+
+    def test_the_pool_of_every_lanes_ring_cannot_run_dry(self):
+        cfg = _windowed_cfg()
+        blocks = BlockPool(cfg).window
+        for seq in range(cfg.max_slots):
+            blocks.advance(seq, 100 - 31, 100 + 10)
+        assert blocks.live_blocks <= cfg.window_blocks - 1
+        with pytest.raises(ValueError, match="allotment"):
+            blocks.advance(0, 0, 200)
+
+    def test_full_layers_accounting_is_unchanged(self):
+        """The sequence's own table, watermark arithmetic and counters
+        read as they do for a model without windows."""
+        plain = BlockPool(CACHE_CFG)
+        windowed = BlockPool(_windowed_cfg(num_blocks=CACHE_CFG.num_blocks))
+        for pool in (plain, windowed):
+            pool.allocate(1, 9, extra_blocks=1)
+            pool.extend(1, 1)
+            pool.note_filled(1, 9)
+            pool.allocate(2, 4)
+            pool.free(2)
+        a, b = plain.stats(), windowed.stats()
+        assert {k: b[k] for k in a} == a
+        assert b["full_blocks_live"] == a["used_blocks"]
+        assert plain.blocks_of(1) == windowed.blocks_of(1)

@@ -584,3 +584,128 @@ class TestBenchHarness:
         assert payload["complete"] is False
         assert payload["skipped_points"] == 2
         assert snapshots  # the partial artifact still flushed
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 44: decode over a table that starts at a window's edge, and the
+# streamed attention of a long chunk
+# ---------------------------------------------------------------------------
+
+
+def _dense_chunk(q, k, v, start, key0, window):
+    """[C, H, D] against keys [KV, T, D], float64 on the host."""
+    c, nh, d = q.shape
+    nkv, t, _ = k.shape
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    out = np.zeros((c, nh, d))
+    for i in range(c):
+        p = start + i
+        pos = key0 + np.arange(t)
+        seen = pos <= p
+        if window is not None:
+            seen &= pos > p - window
+        for h in range(nh):
+            kh = h // (nh // nkv)
+            s = (k[kh] @ q[i, h]) * d ** -0.5
+            s = np.where(seen, s, -np.inf)
+            w = np.exp(s - s.max())
+            out[i, h] = (w / w.sum()) @ v[kh]
+    return out
+
+
+class TestWindowAndLongChunkKernels:
+    def test_window_table_view_orders_a_ring_by_position(self):
+        ring = jnp.asarray([[7, 8, 9, 0, 5, 6], [1, 2, 3, 4, 0, 0]], jnp.int32)
+        view = pa.window_table_view(ring, jnp.asarray([4, 0]))
+        assert view.tolist() == [[5, 6, 7, 8, 9, 0], [1, 2, 3, 4, 0, 0]]
+        padded = pa.window_table_view(ring, jnp.asarray([4, 0]), 8)
+        assert padded[:, 6:].tolist() == [[0, 0], [0, 0]]
+        assert padded[:, :6].tolist() == view.tolist()
+
+    @pytest.mark.parametrize("backend", ["jnp", "pallas"])
+    def test_decode_masks_what_lies_before_the_first_position(
+        self, backend
+    ):
+        """Positions of a lane's table before ``first`` carry POISON:
+        the result is the attention over ``[first, seq_len)`` alone,
+        under either backend, an empty lane exact zeros."""
+        rng = np.random.default_rng(3)
+        b, nkv, group, d, bs, mb = 4, 2, 3, 8, 4, 5
+        n_blocks = 1 + b * mb
+        k = rng.normal(size=(n_blocks, bs, nkv, d)).astype(np.float32)
+        v = rng.normal(size=(n_blocks, bs, nkv, d)).astype(np.float32)
+        q = rng.normal(size=(b, nkv * group, d)).astype(np.float32)
+        tables = 1 + np.arange(b * mb, dtype=np.int32).reshape(b, mb)
+        lens = np.asarray([17, 20, 3, 0], np.int32)
+        first = np.asarray([3, 0, 2, 0], np.int32)
+        want = np.zeros((b, nkv * group, d))
+        for i in range(b):
+            if lens[i] == 0:
+                continue
+            rows = slice(first[i], lens[i])
+            ki = k[tables[i]].reshape(-1, nkv, d)[rows].transpose(1, 0, 2)
+            vi = v[tables[i]].reshape(-1, nkv, d)[rows].transpose(1, 0, 2)
+            want[i] = _dense_chunk(
+                q[i][None], ki, vi, lens[i] - first[i] - 1, 0, None
+            )[0]
+            k[tables[i]].reshape(-1, nkv, d)  # (views; poison below)
+        for i in range(b):  # poison every masked cell
+            flat_k = k[tables[i]].reshape(-1, nkv, d)
+            flat_v = v[tables[i]].reshape(-1, nkv, d)
+            flat_k[: first[i]] = POISON
+            flat_v[: first[i]] = POISON
+            flat_k[lens[i]:] = POISON
+            flat_v[lens[i]:] = POISON
+            k[tables[i]] = flat_k.reshape(mb, bs, nkv, d)
+            v[tables[i]] = flat_v.reshape(mb, bs, nkv, d)
+        got = pa.paged_decode_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(tables), jnp.asarray(lens), backend,
+            first=jnp.asarray(first), name="paged_window_decode",
+        )
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+        assert not np.asarray(got[3]).any()
+
+    @pytest.mark.parametrize("window", [None, 10, 64])
+    @pytest.mark.parametrize("backend", ["jnp", "pallas"])
+    def test_chunk_attention_is_causal_and_windowed(self, backend, window):
+        """A chunk of 32 queries at positions 40.. against 96 keys whose
+        row 0 is position 8: the keys ``s <= t`` (and ``s > t - window``)
+        and no others, whatever lies above and behind."""
+        from dlrover_tpu.ops.paged_kernels import chunk_prefill_kernel
+
+        rng = np.random.default_rng(5)
+        c, nkv, group, d, t = 32, 2, 3, 8, 96
+        q = rng.normal(size=(c, nkv * group, d)).astype(np.float32)
+        k = rng.normal(size=(nkv, t, d)).astype(np.float32)
+        v = rng.normal(size=(nkv, t, d)).astype(np.float32)
+        start, key0 = 40, 8
+        want = _dense_chunk(q, k, v, start, key0, window)
+        if backend == "pallas":  # blocks smaller than the shapes
+            got = chunk_prefill_kernel(
+                jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                jnp.int32(start), jnp.int32(key0), window=window,
+                block_q=8, block_k=16,
+            )
+        else:
+            got = pa.paged_chunk_attention(
+                jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                jnp.int32(start), jnp.int32(key0), window, backend,
+            )
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+    def test_a_wide_table_streams_more_pages_a_step(self, monkeypatch):
+        """The untuned span on a compiled TPU grows with the table
+        (thousands of 16-token pages a lane at 32 k tokens); the tables
+        the cells had before keep theirs."""
+        from dlrover_tpu.ops import pallas_utils
+
+        monkeypatch.setattr(pallas_utils, "use_interpret", lambda: False)
+        shape = dict(group=6, head_dim=128, block_size=16, dtype=jnp.bfloat16)
+        spans = {
+            mb: autotune._heuristic("decode", max_blocks=mb, **shape)[
+                "kv_span"
+            ]
+            for mb in (64, 128, 385, 2048)
+        }
+        assert spans == {64: 4, 128: 4, 385: 16, 2048: 16}

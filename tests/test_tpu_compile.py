@@ -204,7 +204,75 @@ def _expert_ffn_case(rows):
     )
 
 
+def _window_decode_case(window):
+    """Decode attention at Trinity-Large's widths (48 / 8 heads of 128,
+    16 lanes): a window layer's position-ordered table of 385 blocks
+    with a first position that counts, or a full layer's 2048 blocks."""
+    from dlrover_tpu.ops.paged_attention import paged_decode_attention
+
+    lanes, blocks = 16, 385 if window else 2048
+    pool = ((lanes * blocks + 1, BLOCK, 8, D), BF16)
+    ints = ((lanes,), jnp.int32)
+
+    def fn(q, k, v, tables, lens, first):
+        return paged_decode_attention(
+            q, k, v, tables, lens, "pallas",
+            first=first if window else None,
+            name="paged_window_decode" if window else "paged_full_decode",
+        )
+
+    return fn, (
+        ((lanes, 48, D), BF16), pool, pool, ((lanes, blocks), jnp.int32),
+        ints, ints,
+    )
+
+
+def _chunk_prefill_case(window):
+    """A 2048-row chunk's streamed attention at Trinity-Large's widths
+    against the keys of its kind, gathered by position: a window
+    layer's 6656 (385 blocks rounded up to the key block), a full
+    layer's 32768."""
+    from dlrover_tpu.ops.paged_kernels import chunk_prefill_kernel
+
+    keys = ((8, 6656 if window else 32768, D), BF16)
+
+    def fn(q, k, v, start, key0):
+        return chunk_prefill_kernel(
+            q, k, v, start, key0, window=4096 if window else None,
+            name="paged_prefill_window" if window else "paged_prefill_full",
+        )
+
+    return fn, (
+        ((2048, 48, D), BF16), keys, keys, ((), jnp.int32), ((), jnp.int32),
+    )
+
+
+def _expert_share_case(rows):
+    """The routed experts over row tiles at Trinity-Large's widths and
+    its cut: 32 of 256 experts of 3072 x 3072 held, ``rows`` x 4
+    assignments over all 256."""
+    from dlrover_tpu.ops.grouped_gemm import expert_ffn
+
+    def fn(x, ids, gates, w_gate, w_up, w_down):
+        return expert_ffn(
+            x, ids, gates, w_gate, w_up, w_down, 0, 256, "pallas",
+            first_expert=0, held=32,
+        )
+
+    w = ((32, 3072, 3072), BF16)
+    return fn, (
+        ((rows, 3072), BF16), ((rows, 4), jnp.int32),
+        ((rows, 4), jnp.float32), w, w, w,
+    )
+
+
 CASES = {
+    "paged_window_decode": lambda: _window_decode_case(True),
+    "paged_full_decode_2048": lambda: _window_decode_case(False),
+    "paged_prefill_window": lambda: _chunk_prefill_case(True),
+    "paged_prefill_full": lambda: _chunk_prefill_case(False),
+    "moe_expert_share_decode": lambda: _expert_share_case(16),
+    "moe_expert_share_chunk": lambda: _expert_share_case(2048),
     "sparse_prefill": _sparse_prefill_case,
     "index_scores": _index_scores_case,
     "moe_expert_ffn_decode": lambda: _expert_ffn_case(16),
@@ -236,6 +304,10 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     ("ssm_decode_update", "ssm_decode_update"),
     ("sparse_prefill", "sparse_prefill"),
     ("index_scores", "index_scores"),
+    ("paged_window_decode", "paged_window_decode"),
+    ("paged_full_decode_2048", "paged_full_decode"),
+    ("paged_prefill_window", "paged_prefill_window"),
+    ("paged_prefill_full", "paged_prefill_full"),
 ])
 def test_serving_kernels_keep_their_names(case, name, one_chip):
     """A device trace names an operation by its HLO instruction: the
@@ -739,6 +811,98 @@ def test_sparse_block_reads_its_experts_and_index_keys_in_place(
     assert kernel("sparse_paged_decode") == (program == "decode")
     assert kernel("sparse_prefill") == (program != "decode")
     assert kernel("index_scores") == (program != "decode")
+    assert "ragged-dot" not in text
+
+
+@pytest.mark.parametrize(
+    "program", ["decode", "prefill_nohead", "prefill_last"]
+)
+def test_two_kinded_block_carries_both_pools_in_place(program, one_chip):
+    """Trinity-Large's step programs at ``trinity-large-rollout-c16-
+    ctx32k``'s geometry (the published widths at 1 dense + 4 expert
+    layers, 32 of 256 experts held, an eighth of the vocabulary; 16
+    lanes, tables of 2048 + 385 entries, chunk 2048): the full layer's
+    pool ``[1, 36416, ...]`` AND the four window layers' ``[4, 6161,
+    ...]`` — sized by the program, 4.0 GB together where one table for
+    five layers would be 11.9 — are aliased to the outputs and never
+    moved, no layer's ``[32, 3072, 3072]`` expert matrices and no fused
+    projection are copied (the layers are unrolled over their own
+    leaves), the temporaries stay small, and each kernel carries the
+    name that tells window from full in a trace."""
+    from dlrover_tpu.models import trinity
+    from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV
+    from dlrover_tpu.rl.kv_cache import init_block_pool, paged_cache_config
+
+    cfg = trinity.TrinityConfig(
+        num_hidden_layers=5, num_dense_layers=1, held_experts=32,
+        vocab_size=25024, max_seq_len=32768,
+    )
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def seeded():  # as the benchmark seeds it: matrices in bfloat16
+        tree = trinity.init_params(jax.random.PRNGKey(0), cfg)
+        return trinity.serving_params(jax.tree_util.tree_map(
+            lambda a: a.astype(BF16) if a.ndim >= 2 and a.shape[-1] != 256
+            else a, tree,
+        ), cfg)
+
+    params = jax.tree_util.tree_map(spec, jax.eval_shape(seeded))
+    cache = paged_cache_config(cfg, 36416, 16, 16, 2048)
+    assert cache.window_table_blocks == 385
+    pool = jax.tree_util.tree_map(
+        spec, jax.eval_shape(lambda: init_block_pool(cache))
+    )
+    assert pool["wk"].shape == (4, 16 * 385 + 1, 16, 8, 128)
+    pool_bytes = sum(math.prod(a.shape) * 2 for a in pool.values())
+    assert pool_bytes < 4.1e9
+    width = 2048 + 385
+    if program == "decode":
+        fn, rest = _scheduler_decode(
+            partial(trinity.paged_decode_step, cfg=cfg), 16, width, True
+        )
+    else:
+        fn, rest = _scheduler_prefill(
+            partial(trinity.paged_prefill_chunk, cfg=cfg), 16, False,
+            program == "prefill_last", 2048, width, True,
+        )
+    tokens, *after = [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in rest
+    ]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(PAGED_KERNEL_ENV, "pallas")
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+            params, tokens, pool, *after
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # a chunk holds its keys by position (a full layer's 32768: 134 MB
+    # for K and V) and its rows' projections; a decode step next to none
+    assert mem.temp_size_in_bytes < (
+        64 if program == "decode" else 512
+    ) * 2**20
+    pools = {math.prod(a.shape) for a in pool.values()}
+    layer = {math.prod(a.shape[1:]) for a in pool.values()}
+    stack = 32 * 3072 * 3072
+    fused = 3072 * (48 + 8 + 8 + 48) * 128
+    moved = [
+        line[:160] for elements, op, line in _materialised(text)
+        if elements in pools | layer | {stack, fused}
+        and re.match(r"(ROOT )?%(copy|dynamic-slice|slice|transpose)", line)
+        # (a chunk's program prefetches a layer's fused projection into
+        # fast memory, ``copy-start`` / ``copy-done``: no HBM buffer)
+        and not re.match(r"(ROOT )?%copy-(start|done)", line)
+    ]
+    assert not moved, moved
+
+    def kernel(name):  # an instruction of that name, not a path
+        return re.search(rf"%{name}(\.\d+)* = ", text) is not None
+
+    assert kernel("moe_expert_ffn")
+    for kind in ("window", "full"):
+        assert kernel(f"paged_{kind}_decode") == (program == "decode")
+        assert kernel(f"paged_prefill_{kind}") == (program != "decode")
     assert "ragged-dot" not in text
 
 
