@@ -2,8 +2,10 @@
 
 The kernels in ``ops/paged_kernels.py`` have two tunables per shape:
 ``q_rows`` (padded query rows per KV head — the q-block) and
-``kv_span`` (pool pages streamed per grid step — the kv-block; the
-grid's KV extent is ``ceil(max_blocks / kv_span)``).  Which pair wins
+``kv_span`` (pool pages a group — the kv-block: the pages the decode
+kernel fetches into one buffer and scores with one matmul, ``ceil(held
+blocks / kv_span)`` groups a lane; for verify the pages of a grid step,
+whose KV extent is ``ceil(max_blocks / kv_span)``).  Which pair wins
 depends on the device generation and the shape, so the choice is data,
 not code:
 
@@ -128,17 +130,27 @@ def _heuristic(
     """Untuned fallback.  No per-head row padding either way: the
     kernels run every head in one matmul and pad the TOTAL row count
     to a sublane tile themselves.  Interpret mode streams one page per
-    step; compiled TPU streams the widest legal span up to 4 pages (16
+    step.  Compiled, ``decode`` fetches its own pages a GROUP of
+    ``kv_span`` at a time for the blocks a lane holds, and a group has
+    a price of its own (~0.6 us on a v5e beside ~0.07 us a page:
+    PERF.md, PR 45): the widest legal power of two up to 32 that still
+    leaves a full table four groups, so that a short table's lanes are
+    not one group of mostly stale columns — the kernel's wrapper then
+    bounds the group by the fast memory a page's bytes leave it
+    (``paged_decode_kernel``).  ``verify`` streams its pages as
+    pipeline operands a grid step: the widest legal span up to 4 (16
     where a table holds 256 blocks or more), amortizing grid overhead."""
     from dlrover_tpu.ops.pallas_utils import use_interpret
 
     q_rows = group * (window if kernel == "verify" else 1)
     if use_interpret():
         return {"q_rows": q_rows, "kv_span": 1}
+    if kernel == "decode":
+        spans = tuple(c for c in (2, 4, 8, 16, 32) if 4 * c <= max_blocks)
+    else:
+        spans = (2, 4) + ((8, 16) if max_blocks >= 256 else ())
     span = 1
-    # a table of thousands of pages (a 32 k-token context) is a grid of
-    # thousands of steps a lane at 4 pages a step: wider there
-    for cand in (2, 4) + ((8, 16) if max_blocks >= 256 else ()):
+    for cand in spans:
         if cand <= max_blocks and _span_is_legal(
             cand, block_size, max_blocks, dtype
         ):
@@ -175,9 +187,10 @@ def candidates(
     rows = group * (window if kernel == "verify" else 1)
     tile = sublane_tile(dtype)
     row_opts = sorted({rows, ((rows + tile - 1) // tile) * tile})
+    # decode's groups reach 32 pages (its heuristic's widest)
     span_opts = [
         s
-        for s in (1, 2, 4, 8)
+        for s in (1, 2, 4, 8) + ((16, 32) if kernel == "decode" else ())
         if s <= max_blocks and _span_is_legal(s, block_size, max_blocks, dtype)
     ] or [1]
     return [

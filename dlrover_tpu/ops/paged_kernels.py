@@ -4,39 +4,49 @@ The jnp reference path in ``ops/paged_attention.py`` services one
 decode token by *gathering* the sequence's entire paged prefix into a
 dense ``[B, max_blocks*block_size, KV, D]`` tensor — O(context) HBM
 traffic for O(1) new work.  The kernels here stream the K/V pool
-block-by-block through the Pallas grid instead:
+page by page instead, and the gather never materializes:
 
 - the block table and sequence lengths ride in as **scalar-prefetch**
-  operands (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index
-  maps dereference ``tables[b, j]`` *before* each grid step and the
-  pipeline fetches exactly one pool page per step — the gather never
-  materializes;
+  operands (``pltpu.PrefetchScalarGridSpec``).  The **decode** kernel
+  reads ``tables[b, j]`` itself: a grid step is a LANE, the pools go
+  in whole and in place, and a loop over the blocks the lane holds
+  copies ``kv_span`` pages a GROUP into one contiguous buffer
+  (``pltpu.make_async_copy``, two slots a pool, the next group — or the
+  next lane's first — in flight while this one is computed).  Its time
+  follows what the lanes hold, not the table's width: a pipeline
+  operand a page cost 0.14-0.23 us for every ENTRY of the table, live
+  or dead (PERF.md, PR 45).  The **verify** kernel and the decode over
+  rows already gathered (``sparse_decode_kernel``) still stream pages
+  as ``BlockSpec`` operands whose index maps dereference the table
+  before each grid step (``_paged_call``);
 - softmax runs **online** per lane (running ``(m, l, acc)`` in VMEM
   scratch, the flash-attention recipe from ``ops/flash_attention.py``)
-  with fp32 logits and accumulation the decode kernel updates it once
-  for a group of the pages a grid step streams (their logits side by
-  side are one wider page's: a page at a time, each waited for the one
-  before it);
+  with fp32 logits and accumulation; the decode kernel updates it once
+  a group (the group's pages side by side are one wider page: one
+  logits matmul, one ``p x V`` matmul);
 - lanes past ``seq_lens`` and null-block-0 reads contribute exactly
   zero weight: out-of-window columns are masked to ``NEG_INF`` *and*
   their probability rows are zeroed explicitly, so a fully-masked lane
   (``seq_lens == 0``) returns exact zeros rather than uniform weights
   over garbage;
-- the per-lane **early exit** is in the index map: page indices are
-  clamped to the lane's last valid block, so consecutive grid steps
-  past a short sequence re-request the same page and the pipeline
-  elides the copy — short lanes in a mixed batch don't pay the longest
-  lane's traffic — while ``pl.when`` skips their FLOPs.
+- the per-lane **early exit**: the decode kernel's loop runs
+  ``ceil(held blocks / kv_span)`` times and fetches no page a lane does
+  not hold (an empty lane runs no iteration).  Under ``_paged_call``
+  it is in the index map: page indices are clamped to the lane's last
+  valid block, so consecutive grid steps past a short sequence
+  re-request the same page and the pipeline elides the copy, while
+  ``pl.when`` skips their FLOPs — every such step still costs its
+  operands' bookkeeping.
 
 Layout contract (established in PR 13, unchanged): pools are
 ``[num_blocks, block_size, KV, head_dim]`` with ``head_dim`` minormost;
 block 0 is the null block and is garbage by design.  The kernels view
 a pool as ``[num_blocks, block_size * KV, head_dim]`` — merging the two
 middle axes keeps the chip's tiled memory order, so the reshape is a
-bitcast, not a copy (pinned in ``tests/test_tpu_compile.py``).  One
-grid step fetches one whole page as a ``[block_size * KV, head_dim]``
-tile (row ``t * KV + h`` = token ``t`` of KV head ``h``) and ONE matmul
-scores every query row against every row of the page; a head-match
+bitcast, not a copy (pinned in ``tests/test_tpu_compile.py``).  A page
+is a ``[block_size * KV, head_dim]`` tile (row ``t * KV + h`` = token
+``t`` of KV head ``h``) and ONE matmul scores every query row against
+every row of the page (of the group's pages); a head-match
 mask keeps each query row on its own KV head's columns.  That spends
 KV times the useful MXU work on a memory-bound op in exchange for a
 body Mosaic accepts: no per-head strided sublane read and no
@@ -46,9 +56,10 @@ is used in.
 
 Tunables per kernel (see ``ops/autotune.py``): ``q_rows`` (query rows
 per KV head; the TOTAL row count is padded to a sublane tile here) and
-``kv_span`` (pool pages streamed per grid step; the pool is passed
-``kv_span`` times with staggered index maps, which is how a Pallas
-kernel widens its KV block without regathering).
+``kv_span`` (pool pages a group for decode; for verify the pages
+streamed per grid step: the pool is passed ``kv_span`` times with
+staggered index maps, which is how a pipelined kernel widens its KV
+block without regathering).
 
 CPU CI runs these kernels in interpret mode
 (``ops/pallas_utils.use_interpret``); on TPU the same bodies lower to
@@ -170,6 +181,9 @@ def _page_geometry(n_rows: int, per_head: int, block_size: int, n_kv: int):
 # ---------------------------------------------------------------------------
 # decode: one query token per lane
 # ---------------------------------------------------------------------------
+# ``_decode_kernel`` under ``_paged_call`` (a grid step a span of table
+# entries, a pipeline operand a page) serves ``sparse_decode_kernel``;
+# ``paged_decode_kernel`` runs ``_stream_decode_kernel``.
 
 
 def _decode_kernel(
@@ -339,6 +353,148 @@ def _paged_call(
     return out[:, :n_rows]
 
 
+def _stream_decode_kernel(
+    tables_ref,  # scalar prefetch [B, MB]
+    lens_ref,  # scalar prefetch [B], or [2 B]: lengths, then ``first``
+    q_ref,  # [1, R, D]
+    k_hbm,  # [N, bs*KV, D]: the whole pool, where it lies
+    v_hbm,
+    o_ref,  # [1, R, D]
+    k_buf,  # [2, span, bs*KV, D]: two slots, a group of pages each
+    v_buf,
+    sems,  # DMA [2, 2]: (pool, slot)
+    done,  # SMEM [1]: groups computed by the lanes before this one
+    m_scr,
+    l_scr,
+    acc_scr,
+    *,
+    span: int,
+    block_size: int,
+    n_kv: int,
+    gp: int,
+    scale: float,
+    lower: bool,
+):
+    """One lane a grid step; the kernel fetches its own pages.
+
+    A lane's blocks are read in GROUPS of ``span`` table entries: each
+    page is copied from where it lies in the pool into its place in one
+    contiguous ``[span * bs*KV, D]`` buffer, so a group is one wider
+    page — one logits matmul, one float32 softmax update, one ``p x V``
+    matmul.  The loop runs ``ceil(blocks / span)`` times for the blocks
+    the lane HOLDS, whatever the table's width; a group's copies are
+    started one group ahead (two slots a pool), and the first group of
+    the next lane before this lane's last is computed, so the slot
+    parity (``done``) carries over the grid step."""
+    b = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    max_blocks = tables_ref.shape[1]
+    cols = block_size * n_kv  # rows of the buffer a page fills
+
+    def held(lane):  # blocks a lane's table really holds
+        blocks = lax.div(lens_ref[lane] + block_size - 1, block_size)
+        return jnp.minimum(blocks, max_blocks)
+
+    def groups(lane):
+        return lax.div(held(lane) + span - 1, span)
+
+    def copies(lane, i, slot, arrive):
+        """Start, or wait for, the copy of every page lane ``lane``
+        holds of its group ``i``, in a loop of as many turns (unrolled
+        in Python, ``2 x span`` copies at each of four places cost a
+        replica seconds of tracing a layer).  Entries past the lane's
+        last block are neither read from the table (the last group may
+        reach past its end) nor fetched: their rows of the buffer are
+        stale, and masked.  A whole group is waited for at once: a
+        slot's semaphore counts what has arrived, and ``span`` pages
+        are the buffer's size."""
+        n_pages = jnp.minimum(held(lane) - i * span, span)
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+        def page(s, carry):
+            block = tables_ref[lane, i * span + s]
+            for pool, (hbm, buf) in enumerate(pools):
+                copy = pltpu.make_async_copy(
+                    hbm.at[block], buf.at[slot, s], sems.at[pool, slot]
+                )
+                copy.wait() if arrive else copy.start()
+            return carry
+
+        if not arrive:
+            lax.fori_loop(0, n_pages, page, 0)
+            return
+
+        @pl.when(n_pages == span)
+        def _whole():
+            for pool, (_, buf) in enumerate(pools):
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sems.at[pool, slot]
+                ).wait()
+
+        @pl.when(n_pages < span)
+        def _tail():
+            lax.fori_loop(0, n_pages, page, 0)
+
+    start = functools.partial(copies, arrive=False)
+    wait = functools.partial(copies, arrive=True)
+
+    @pl.when(b == 0)
+    def _first_lane():
+        done[0] = 0
+
+    seq_len = lens_ref[b]
+    first = lens_ref[lanes + b] if lower else None
+    n_groups = groups(b)
+    base = done[0]
+    before = jnp.maximum(b - 1, 0)
+    after = jnp.minimum(b + 1, lanes - 1)
+    after_reads = (b + 1 < lanes) & (groups(after) > 0)
+
+    # the lane before starts this lane's first group, if it ran at all
+    @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
+    def _own_first_group():
+        start(b, 0, lax.rem(base, 2))
+
+    _init_state(m_scr, l_scr, acc_scr)
+    # the group as ONE page of ``span * block_size`` tokens
+    _, same_head, col_tok, v_tok = _page_geometry(
+        q_ref.shape[1], gp, block_size * span, n_kv
+    )
+
+    def group(i, carry):
+        slot = lax.rem(base + i, 2)
+
+        @pl.when(i + 1 < n_groups)
+        def _next_group():
+            start(b, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == n_groups) & after_reads)
+        def _next_lane():
+            start(after, 0, 1 - slot)
+
+        wait(b, i, slot)
+        at = i * span * block_size  # the group's first position
+        keep = same_head & (at + col_tok < seq_len)  # [R, span * C]
+        v_keep = at + v_tok < seq_len
+        if lower:
+            keep = keep & (at + col_tok >= first)
+            v_keep = v_keep & (at + v_tok >= first)
+        # Zero garbage V rows: 0 * NaN would poison the accumulator.
+        v = v_buf[slot].reshape(span * cols, -1)
+        v = jnp.where(v_keep, v, jnp.zeros_like(v))
+        s_log = lax.dot_general(
+            q_ref[0], k_buf[slot].reshape(span * cols, -1),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        _online_update(m_scr, l_scr, acc_scr, s_log, v, keep)
+        return carry
+
+    lax.fori_loop(0, n_groups, group, 0)
+    done[0] = base + n_groups
+    _finalize(o_ref, m_scr, l_scr, acc_scr)
+
+
 def paged_decode_kernel(
     q: jnp.ndarray,  # [B, H, D]
     k_pool: jnp.ndarray,  # [N, bs, KV, D]
@@ -352,6 +508,11 @@ def paged_decode_kernel(
 ) -> jnp.ndarray:
     """Streamed paged GQA decode attention. Drop-in for the jnp path.
 
+    A grid step is a lane; the pools go in whole and in place, and the
+    kernel copies the pages a lane holds itself, ``kv_span`` of them a
+    group (:func:`_stream_decode_kernel`): its time follows what the
+    lanes hold, not the table's width.
+
     ``first``: positions of a lane's table before ``first[b]`` are
     masked too (the table of a layer with a window starts at the block
     that holds the window's edge, which need not be the block's first
@@ -359,7 +520,7 @@ def paged_decode_kernel(
     from dlrover_tpu.ops import autotune
 
     batch, n_heads, head_dim = q.shape
-    _, block_size, n_kv, _ = k_pool.shape
+    n_blocks, block_size, n_kv, _ = k_pool.shape
     group = n_heads // n_kv
     max_blocks = block_tables.shape[1]
     if config is None:
@@ -377,31 +538,78 @@ def paged_decode_kernel(
     qg = q.reshape(batch, n_kv, group, head_dim)
     if gp > group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
-    qg = qg.reshape(batch, n_kv * gp, head_dim)
-
-    out = _paged_call(
-        functools.partial(
-            _decode_kernel,
-            span=span,
-            block_size=block_size,
-            n_kv=n_kv,
-            gp=gp,
-            scale=head_dim**-0.5,
-            lower=first is not None,
-        ),
-        qg,
-        k_pool,
-        v_pool,
-        block_tables,
-        seq_lens if first is None else jnp.concatenate([seq_lens, first]),
-        span=span,
-        last_block=lambda lens, b: lax.div(
-            lens[b] + block_size - 1, block_size
-        )
-        - 1,
-        name=name,
+    n_rows = n_kv * gp
+    rows_p = _round_up(n_rows, sublane_tile(q.dtype))
+    qg = qg.reshape(batch, n_rows, head_dim)
+    if rows_p > n_rows:
+        qg = jnp.pad(qg, ((0, 0), (0, rows_p - n_rows), (0, 0)))
+    n_cols = block_size * n_kv
+    # of the fast memory a group's float32 logits stay under 2 MiB, and
+    # each of the four buffers (two slots a pool) under 1 MiB
+    page_bytes = n_cols * head_dim * k_pool.dtype.itemsize
+    span = max(
+        1,
+        min(span, (2 << 20) // (rows_p * n_cols * 4), (1 << 20) // page_bytes),
     )
-    out = out.reshape(batch, n_kv, gp, head_dim)[:, :, :group]
+
+    def q_index(b, tables, scal):
+        del tables, scal
+        return (b, 0, 0)
+
+    buf = pltpu.VMEM((2, span, n_cols, head_dim), k_pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[
+            pl.BlockSpec((1, rows_p, head_dim), q_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, rows_p, head_dim), q_index),
+        scratch_shapes=[
+            buf,
+            buf,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.VMEM((rows_p, head_dim), jnp.float32),
+        ],
+    )
+    out = named_kernel(
+        name,
+        pl.pallas_call(
+            functools.partial(
+                _stream_decode_kernel,
+                span=span,
+                block_size=block_size,
+                n_kv=n_kv,
+                gp=gp,
+                scale=head_dim**-0.5,
+                lower=first is not None,
+            ),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (batch, rows_p, head_dim), q.dtype
+            ),
+            interpret=use_interpret(),
+            name=name,
+            # the slot parity and the next lane's first group carry
+            # over a grid step: the lanes run in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
+        ),
+    )(
+        block_tables.astype(jnp.int32),
+        (
+            seq_lens if first is None else jnp.concatenate([seq_lens, first])
+        ).astype(jnp.int32),
+        qg,
+        k_pool.reshape(n_blocks, n_cols, head_dim),
+        v_pool.reshape(n_blocks, n_cols, head_dim),
+    )
+    out = out[:, :n_rows].reshape(batch, n_kv, gp, head_dim)[:, :, :group]
     return out.reshape(batch, n_heads, head_dim)
 
 

@@ -19,6 +19,14 @@ kill never loses completed measurements.  Honors
 timing, so the pallas numbers reflect the tuned config and the tuning
 events land on the timeline (``kernel_autotune`` spans).
 
+``--tables`` times the bare decode kernel instead, at the tables the
+benchmark's serving cells run (``TABLES``: Trinity-Large's full and
+window layers, deepseek-llm-7b's, Falcon-H1-34B's) with a quarter, a
+half and all of every lane's table live, and prints microseconds a
+call, microseconds a LIVE page and the GB/s of the K and V rows the
+lanes hold — the record ``PERF.md`` quotes.  A time is a device time
+only on a chip; in interpret mode the rows say nothing about speed.
+
 Wired into ``bench.py`` as the ``extras.paged_kernels`` leg.
 """
 
@@ -43,6 +51,17 @@ DEFAULT_SWEEP = (
     (8, 256, 16),
 )
 VERIFY_WINDOW = 4
+
+#: name -> (lanes, heads, kv_heads, table entries, a window layer's
+#: ``first``): the decode tables of the benchmark's serving cells, 16
+#: tokens a block and heads of 128 in bfloat16
+TABLES = {
+    "trinity_full": (16, 48, 8, 2048, False),
+    "trinity_window": (16, 48, 8, 385, True),
+    "deepseek7b": (16, 32, 32, 64, False),
+    "falcon_h1": (32, 20, 4, 64, False),
+}
+LIVE_SHARES = (0.25, 0.5, 1.0)
 
 
 def _time_call(call, reps: int) -> float:
@@ -216,6 +235,119 @@ def run_sweep(sweep=DEFAULT_SWEEP, reps: int = 5, autotune: bool = False,
     return payload
 
 
+def _table_case(lanes, heads, kv_heads, entries, window, live, *,
+                block_size=16, head_dim=128, dtype=None, seed=0):
+    """A pool whose pages lie scattered (a shuffled free list), every
+    lane holding ``live`` of its table and the null block behind."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    dtype = dtype or jnp.bfloat16
+    rng = np.random.default_rng(seed)
+    held = max(1, int(round(entries * live)))
+    num_blocks = lanes * entries + 1
+    tables = np.zeros((lanes, entries), np.int32)
+    tables[:, :held] = 1 + rng.permutation(lanes * entries)[
+        : lanes * held
+    ].reshape(lanes, held)
+    shape = (num_blocks, block_size, kv_heads, head_dim)
+    pool = jax.random.normal(jax.random.PRNGKey(seed), shape, dtype)
+    return dict(
+        q=jnp.asarray(
+            rng.standard_normal((lanes, heads, head_dim), np.float32), dtype
+        ),
+        k_pool=pool, v_pool=pool[::-1],
+        tables=jnp.asarray(tables),
+        seq_lens=jnp.full((lanes,), held * block_size - 3, jnp.int32),
+        # a window's edge: inside the first block, before the length
+        first=jnp.full((lanes,), block_size - 4, jnp.int32)
+        if window else None,
+        held=held,
+    )
+
+
+def bench_tables(names=None, shares=LIVE_SHARES, spans=(None,), reps=20,
+                 dims=None):
+    """Rows of the bare decode kernel over ``TABLES``: ``reps`` calls
+    chained inside ONE program (each call's queries depend on the last
+    call's output), so a row is the kernel's time on the device and not
+    a dispatch's.  ``spans``: pages a group to force (``None``: what
+    ``ops/autotune.py`` resolves).  ``dims``: ``block_size`` /
+    ``head_dim`` / ``dtype`` of a rehearsal."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from dlrover_tpu.ops import autotune
+    from dlrover_tpu.ops import paged_attention as pa
+    from dlrover_tpu.ops.paged_kernels import paged_decode_kernel
+
+    rows = []
+    for name in names or list(TABLES):
+        lanes, heads, kv_heads, entries, window = TABLES[name]
+        for live in shares:
+            a = _table_case(
+                lanes, heads, kv_heads, entries, window, live, **(dims or {})
+            )
+            block_size, _, head_dim = a["k_pool"].shape[1:]
+            for span in spans:
+                config = autotune.get_config(
+                    "decode", group=heads // kv_heads, head_dim=head_dim,
+                    block_size=block_size, max_blocks=entries,
+                    dtype=a["q"].dtype,
+                )
+                if span is not None:
+                    config = dict(config, kv_span=span)
+
+                def chained(q, k, v, tables, lens, first, config=config,
+                            kernel_name="paged_window_decode" if window
+                            else "paged_decode"):
+                    def body(_, q):
+                        out = paged_decode_kernel(
+                            q, k, v, tables, lens, config=config,
+                            first=first, name=kernel_name,
+                        )
+                        return q + (out * 1e-3).astype(q.dtype)
+
+                    return lax.fori_loop(0, reps, body, q)
+
+                fn = jax.jit(chained)
+                args = (a["q"], a["k_pool"], a["v_pool"], a["tables"],
+                        a["seq_lens"], a["first"])
+                fn(*args).block_until_ready()  # compile outside the clock
+                us = _time_call(
+                    lambda: fn(*args).block_until_ready(), 3
+                ) / reps
+                # the same call against the dense reference, on the
+                # device the row was timed on
+                single = (a["q"], a["k_pool"], a["v_pool"], a["tables"],
+                          a["seq_lens"])
+                diff = jnp.max(jnp.abs(
+                    paged_decode_kernel(
+                        *single, config=config, first=a["first"]
+                    ).astype(jnp.float32)
+                    - pa.paged_decode_attention(
+                        *single, backend="jnp", first=a["first"]
+                    ).astype(jnp.float32)
+                ))
+                tokens = lanes * int(a["seq_lens"][0])
+                row_bytes = 2 * kv_heads * head_dim * jnp.dtype(
+                    a["q"].dtype
+                ).itemsize
+                rows.append({
+                    "table": name, "entries": entries, "lanes": lanes,
+                    "live_share": live, "live_pages": lanes * a["held"],
+                    "kv_span": config["kv_span"],
+                    "us_a_call": round(us, 2),
+                    "us_a_live_page": round(us / (lanes * a["held"]), 4),
+                    "gb_per_s": round(tokens * row_bytes / us / 1e3, 2),
+                    "max_abs_diff_vs_jnp": float(diff),
+                })
+                print(json.dumps(rows[-1]), flush=True)
+    return rows
+
+
 def _interpret() -> bool:
     from dlrover_tpu.ops.pallas_utils import use_interpret
 
@@ -238,7 +370,36 @@ def main(argv=None) -> int:
         action="store_true",
         help="run the shape-keyed tuner per sweep point before timing",
     )
+    ap.add_argument(
+        "--tables", nargs="*", default=None, metavar="NAME",
+        help="time the bare decode kernel at the serving cells' tables "
+        f"({', '.join(TABLES)}; none named: all) instead of the sweep",
+    )
+    ap.add_argument(
+        "--live", default=",".join(str(x) for x in LIVE_SHARES),
+        help="shares of a lane's table that are live, comma-separated",
+    )
+    ap.add_argument(
+        "--spans", default="",
+        help="pages a group to force, comma-separated (default: autotune's)",
+    )
     args = ap.parse_args(argv)
+
+    if args.tables is not None:
+        import jax
+
+        rows = bench_tables(
+            args.tables or None,
+            shares=[float(x) for x in args.live.split(",")],
+            spans=[int(x) for x in args.spans.split(",") if x] or (None,),
+        )
+        _flush(args.out, {
+            "bench": "paged_decode_tables", "rows": rows,
+            "backend": jax.default_backend(), "interpret": _interpret(),
+            "device_kind": jax.devices()[0].device_kind,
+        })
+        print(f"wrote {args.out} ({len(rows)} rows)")
+        return 0
 
     payload = run_sweep(
         reps=args.reps,

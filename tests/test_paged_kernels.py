@@ -36,7 +36,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from dlrover_tpu.ops import autotune  # noqa: E402
+from dlrover_tpu.ops import autotune, paged_kernels  # noqa: E402
 from dlrover_tpu.ops import paged_attention as pa  # noqa: E402
 from dlrover_tpu.ops.paged_kernels import (  # noqa: E402
     paged_decode_kernel,
@@ -85,8 +85,8 @@ def _case(group, block_size, dtype, seed=0, batch=4, kv=2, head_dim=8,
     ).astype(np.float32)
     positions = np.maximum(seq_lens - window, 0).astype(np.int32)
     # every table entry past a lane's last resident block points at the
-    # poison guard block: only masking (jnp) / index-clamping (pallas)
-    # keeps it out of the output.  Verify's window K/V is resident by
+    # poison guard block: only masking (jnp) / fetching the blocks a
+    # lane holds and no other (pallas) keeps it out of the output.  Verify's window K/V is resident by
     # contract, so "resident" covers max(seq_len, pos + window) tokens.
     for b in range(batch):
         covered = max(int(seq_lens[b]), int(positions[b]) + window)
@@ -143,7 +143,7 @@ class TestDecodeParity:
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=_tol(dtype), rtol=0,
         )
-        # poison never leaked through masking or index clamping
+        # poison never leaked through masking or a fetch too many
         assert float(jnp.max(jnp.abs(out))) < POISON / 10
 
     @pytest.mark.parametrize(
@@ -184,6 +184,102 @@ class TestDecodeParity:
             assert bool(jnp.all(out[1] == 0.0)), backend
             # non-empty lanes are NOT zero (the fix is surgical)
             assert float(jnp.max(jnp.abs(out[0]))) > 0.0, backend
+
+
+def _held_lengths(span, block_size, max_blocks):
+    """Lengths a lane may hold around a group's edges: nothing, one
+    token, one short of / exactly at / one past the first group's edge
+    and the second's, and the whole table."""
+    edge = span * block_size
+    full = max_blocks * block_size
+    lens = [0, 1, edge - 1, edge, edge + 1, 2 * edge, 2 * edge + 1, full]
+    return [min(n, full) for n in lens]
+
+
+class TestStreamedDecode:
+    """ISSUE 45: the decode kernel fetches its own pages, ``kv_span``
+    of them a group, for the blocks a lane holds."""
+
+    @pytest.mark.parametrize("with_first", [False, True],
+                             ids=["whole", "first"])
+    @pytest.mark.parametrize("group", [6, 5, 1])
+    @pytest.mark.parametrize("span", [1, 4, 16])
+    def test_groups_of_held_pages_match_the_reference(
+        self, span, group, with_first
+    ):
+        """Every length around a group's edge, an empty lane between
+        two that hold something, a table whose width is no multiple of
+        the group — with NaN in the null block and in every page a lane
+        does not hold (the reference is given the same pool with zeros
+        there: ``0 * NaN`` is NaN in its dense product)."""
+        rng = np.random.default_rng(span * 10 + group)
+        kv, d, bs = 2, 8, 4
+        mb = 2 * span + 1  # the last group reaches past the table's end
+        lens = _held_lengths(span, bs, mb)
+        lens = lens[1:4] + [0] + lens[4:] + [0]  # empty lanes inside
+        b = len(lens)
+        n_blocks = 1 + b * mb
+        k = rng.standard_normal((n_blocks, bs, kv, d)).astype(np.float32)
+        v = rng.standard_normal((n_blocks, bs, kv, d)).astype(np.float32)
+        tables = 1 + rng.permutation(b * mb).reshape(b, mb).astype(np.int32)
+        dead = [0]
+        for lane, n in enumerate(lens):
+            held = -(-n // bs)
+            dead.extend(tables[lane, held:])
+            tables[lane, held:] = 0  # behind a lane: the null block
+        k_nan, v_nan = k.copy(), v.copy()
+        k_nan[dead] = np.nan
+        v_nan[dead] = np.nan
+        k[dead] = 0.0
+        v[dead] = 0.0
+        q = rng.standard_normal((b, kv * group, d)).astype(np.float32)
+        first = (
+            jnp.asarray([min(3, max(n - 1, 0)) for n in lens], jnp.int32)
+            if with_first else None
+        )
+        lens = jnp.asarray(lens, jnp.int32)
+        ref = pa.paged_decode_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(tables), lens, backend="jnp", first=first,
+        )
+        out = paged_decode_kernel(
+            jnp.asarray(q), jnp.asarray(k_nan), jnp.asarray(v_nan),
+            jnp.asarray(tables), lens, first=first,
+            config={"q_rows": group, "kv_span": span},
+        )
+        assert bool(jnp.all(jnp.isfinite(out)))
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=5e-6, rtol=0
+        )
+        for lane in np.flatnonzero(np.asarray(lens) == 0):
+            assert not np.asarray(out[lane]).any()
+
+    def test_the_kernels_time_cannot_follow_the_table(self):
+        """A grid step is a lane, whatever the table's width: the
+        kernel's program has no axis over the table's entries, and its
+        operands are the two pools whole, not a page operand an entry
+        of a step."""
+        c = _case(group=2, block_size=8, dtype=jnp.float32, max_blocks=12)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: paged_decode_kernel(
+                *a, config={"q_rows": 2, "kv_span": 4}
+            )
+        )(c["q"], c["k_pool"], c["v_pool"], c["tables"], c["seq_lens"])
+        (call,) = [
+            e for e in _all_eqns(jaxpr.jaxpr)
+            if e.primitive.name == "pallas_call"
+        ]
+        assert call.params["grid_mapping"].grid == (c["q"].shape[0],)
+        # tables, lengths, queries, K pool, V pool
+        assert len(call.invars) == 5
+        assert call.invars[3].aval.shape[0] == c["k_pool"].shape[0]
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
 
 
 class TestVerifyParity:
@@ -570,6 +666,27 @@ class TestBenchHarness:
                 assert field in point, point
         assert payload["decode_speedup_best"] > 0
 
+    def test_table_rows_name_the_price_of_a_live_page(self, monkeypatch):
+        """``--tables``: a row a (table, live share, group size) with
+        the time a call, a LIVE page and the GB/s of the rows the lanes
+        hold, checked against the dense reference where it was timed."""
+        bpa = self._module()
+        monkeypatch.setitem(bpa.TABLES, "tiny", (2, 4, 2, 9, True))
+        rows = bpa.bench_tables(
+            ["tiny"], shares=(0.25, 1.0), spans=(None, 4), reps=2,
+            dims=dict(block_size=4, head_dim=8, dtype=jnp.float32),
+        )
+        assert [(r["live_share"], r["kv_span"]) for r in rows] == [
+            (0.25, 1), (0.25, 4), (1.0, 1), (1.0, 4),
+        ]
+        for row in rows:
+            assert row["live_pages"] == 2 * max(1, round(9 * row["live_share"]))
+            assert row["us_a_call"] > 0 and row["gb_per_s"] >= 0
+            assert row["us_a_live_page"] == pytest.approx(
+                row["us_a_call"] / row["live_pages"], rel=1e-2
+            )
+            assert row["max_abs_diff_vs_jnp"] < 5e-6
+
     def test_budget_stops_between_points(self):
         bpa = self._module()
         snapshots = []
@@ -695,17 +812,47 @@ class TestWindowAndLongChunkKernels:
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
     def test_a_wide_table_streams_more_pages_a_step(self, monkeypatch):
-        """The untuned span on a compiled TPU grows with the table
-        (thousands of 16-token pages a lane at 32 k tokens); the tables
-        the cells had before keep theirs."""
+        """The untuned group on a compiled TPU grows with the table
+        (thousands of 16-token pages a lane at 32 k tokens) up to 32
+        pages and leaves a short table four groups; verify, still a
+        pipeline operand a page, keeps the spans it had."""
         from dlrover_tpu.ops import pallas_utils
 
         monkeypatch.setattr(pallas_utils, "use_interpret", lambda: False)
         shape = dict(group=6, head_dim=128, block_size=16, dtype=jnp.bfloat16)
-        spans = {
-            mb: autotune._heuristic("decode", max_blocks=mb, **shape)[
-                "kv_span"
-            ]
-            for mb in (64, 128, 385, 2048)
-        }
-        assert spans == {64: 4, 128: 4, 385: 16, 2048: 16}
+
+        def spans(kernel):
+            return {
+                mb: autotune._heuristic(kernel, max_blocks=mb, **shape)[
+                    "kv_span"
+                ]
+                for mb in (8, 64, 128, 385, 2048)
+            }
+
+        assert spans("decode") == {8: 2, 64: 16, 128: 32, 385: 32, 2048: 32}
+        assert spans("verify") == {8: 4, 64: 4, 128: 4, 385: 16, 2048: 16}
+
+    def test_a_group_is_bounded_by_the_fast_memory(self, monkeypatch):
+        """Whatever span is asked for, a group's four buffers stay
+        under 1 MiB each: pages of 128 KB (32 KV heads of 128, bfloat16)
+        come 8 a group, pages of 16 KB as many as were asked for."""
+        seen = []
+        real = paged_kernels.pl.pallas_call
+
+        def spy(kernel, **kw):
+            seen.append(kw["grid_spec"].scratch_shapes[0].shape)
+            return real(kernel, **kw)
+
+        monkeypatch.setattr(paged_kernels.pl, "pallas_call", spy)
+        for kv, want in ((32, 8), (4, 32)):
+            n_blocks, lanes, bs, d = 3, 2, 16, 128
+            pool = jnp.zeros((n_blocks, bs, kv, d), jnp.bfloat16)
+            jax.eval_shape(
+                lambda q, k, v, t, n: paged_decode_kernel(
+                    q, k, v, t, n, config={"q_rows": 1, "kv_span": 32}
+                ),
+                jnp.zeros((lanes, kv, d), jnp.bfloat16), pool, pool,
+                jnp.zeros((lanes, 64), jnp.int32),
+                jnp.zeros((lanes,), jnp.int32),
+            )
+            assert seen[-1] == (2, want, bs * kv, d)

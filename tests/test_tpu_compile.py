@@ -366,6 +366,31 @@ def test_paged_pool_view_is_a_bitcast(one_chip):
     assert " copy(" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("case,pool", [
+    ("paged_decode_kv8", (NUM_BLOCKS, BLOCK * 8, D)),
+    ("paged_decode_kv32", (NUM_BLOCKS, BLOCK * 32, D)),
+    ("paged_window_decode", (16 * 385 + 1, BLOCK * 8, D)),
+    ("paged_full_decode_2048", (16 * 2048 + 1, BLOCK * 8, D)),
+])
+def test_decode_kernel_takes_the_pools_whole(case, pool, one_chip):
+    """The decode kernel fetches its own pages: its operands are the
+    table, the lengths, the queries and the two pools WHOLE (where they
+    lie: no copy beside it), not a list of page operands that grows with
+    the pages a step streams."""
+    fn, shapes = CASES[case]()
+    text = _compiled_text(fn, *shapes, sharding=one_chip)
+    (call,) = [
+        line for line in text.splitlines()
+        if "custom-call(" in line and "tpu_custom_call" in line
+    ]
+    call = call.split("backend_config")[0]  # the kernel's body is long
+    operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+    assert len(operands.split(",")) == 5, call
+    layouts = call.split("operand_layout_constraints=")[1]
+    assert layouts.count("bf16[%d,%d,%d]" % pool) == 2, call
+    assert " copy(" not in text
+
+
 def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
     """The ``--four-chips`` program: loss + grad of the llama block at
     7B widths on an fsdp=2 x tensor=2 mesh, flash attention and the
