@@ -529,11 +529,13 @@ def _split_tables(block_tables, pools: _Pools):
 def _key_view_blocks(ring_blocks: int, block_size: int) -> int:
     """Entries of the position-ordered view of a ring that a prefill
     chunk's kernel reads: the ring, rounded up to the kernel's key block
-    of 512 positions where it is longer than one."""
+    where it is longer than one."""
+    from dlrover_tpu.ops.paged_kernels import CHUNK_KEY_BLOCK as bk
+
     rows = ring_blocks * block_size
-    if rows <= 512 or 512 % block_size:
+    if rows <= bk or bk % block_size:
         return ring_blocks
-    return -(-rows // 512) * 512 // block_size
+    return -(-rows // bk) * bk // block_size
 
 
 @jax.named_scope("prefill")

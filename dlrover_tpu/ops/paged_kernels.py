@@ -106,7 +106,7 @@ def _iota_cols(n: int) -> jnp.ndarray:
     return lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
 
-def _online_update(m_scr, l_scr, acc_scr, s_log, v, keep):
+def _online_update(m_scr, l_scr, acc_scr, s_log, v, keep=None):
     """One online-softmax step over every query row at once.
 
     ``s_log`` is fp32 ``[R, C]`` raw logits, ``keep`` a bool mask of
@@ -114,12 +114,17 @@ def _online_update(m_scr, l_scr, acc_scr, s_log, v, keep):
     Probabilities are re-zeroed after the exp so a row with no visible
     keys accumulates ``l == 0`` (→ exact-zero output at finalize)
     instead of the uniform-over-garbage a plain softmax produces.
+    ``keep=None`` says every row reads every column: no select before
+    the max and none after the exp.
     """
-    s_log = jnp.where(keep, s_log, NEG_INF)
+    if keep is not None:
+        s_log = jnp.where(keep, s_log, NEG_INF)
     m_prev = m_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s_log, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(keep, jnp.exp(s_log - m_new), 0.0)
+    p = jnp.exp(s_log - m_new)
+    if keep is not None:
+        p = jnp.where(keep, p, 0.0)
     l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
         p.astype(v.dtype),
@@ -867,6 +872,58 @@ def selected_prefill_kernel(
     return jnp.swapaxes(out, 0, 1)
 
 
+# keys a grid step of ``chunk_prefill_kernel`` reads; whoever lays out
+# its keys (``models/trinity._key_view_blocks``) rounds to this
+CHUNK_KEY_BLOCK = 1024
+
+
+def _chunk_reach(p_min, key0, block_q, block_k, window, xp=jnp):
+    """Of the key blocks of ``block_k`` positions from ``key0``, what a
+    query block whose first row is at ``p_min`` reads: ``(first, last,
+    whole_first, whole_last)``.  SOME row reads blocks ``first..last``
+    (up to the last row's own position and, with a window, from the
+    first row's edge); EVERY row reads blocks ``whole_first..whole_last``
+    whole (they end at or before the first row's position and start
+    inside the last row's window), so the mask cuts nothing there.  The
+    kernel's predicates, its index maps and :func:`chunk_key_blocks`
+    all come from here."""
+    last = (p_min + block_q - 1 - key0) // block_k
+    whole_last = (p_min + 1 - key0) // block_k - 1
+    if window is None:
+        return 0, last, 0, whole_last
+    first = xp.maximum(p_min - window + 1 - key0, 0) // block_k
+    edge = xp.maximum(p_min + block_q - window - key0, 0)
+    return first, last, (edge + block_k - 1) // block_k, whole_last
+
+
+def chunk_key_blocks(
+    start_pos: int,
+    key0: int,
+    c: int,
+    t: int,
+    window: Optional[int] = None,
+    block_q: int = 512,
+    block_k: int = CHUNK_KEY_BLOCK,
+):
+    """``(computed, unmasked, skipped)``: of the (query block, key
+    block) steps ``chunk_prefill_kernel`` runs a KV head for a chunk of
+    ``c`` rows at ``start_pos`` over ``t`` keys from ``key0``, how many
+    it computes, how many of those without a mask, and how many it
+    skips.  Positions alone decide it, so this is a count, not a
+    measurement."""
+    bq, bk = min(block_q, c), min(block_k, t)
+    nk = t // bk
+    p_min = start_pos + bq * np.arange(c // bq)
+    first, last, whole_first, whole_last = (
+        np.broadcast_to(x, p_min.shape)
+        for x in _chunk_reach(p_min, key0, bq, bk, window, xp=np)
+    )
+    last, whole_last = np.minimum(last, nk - 1), np.minimum(whole_last, nk - 1)
+    computed = int(np.maximum(last - first + 1, 0).sum())
+    unmasked = int(np.maximum(whole_last - whole_first + 1, 0).sum())
+    return computed, unmasked, len(p_min) * nk - computed
+
+
 def _chunk_prefill_kernel(
     bounds_ref,  # scalar prefetch [2]: the chunk's first position, key 0's
     q_ref,  # [1, G * BQ, D]: a KV head's query heads, BQ rows each
@@ -889,17 +946,20 @@ def _chunk_prefill_kernel(
     def _init():
         _init_state(m_scr, l_scr, acc_scr)
 
-    # the key blocks some row of this query block reads: up to its last
-    # row's own position and, with a window, from its first row's edge
     p_min = start + i * block_q
-    last = (p_min + block_q - 1 - key0) // block_k
-    first = (
-        0 if window is None
-        else jnp.maximum(p_min - window + 1 - key0, 0) // block_k
+    first, last, whole_first, whole_last = _chunk_reach(
+        p_min, key0, block_q, block_k, window
     )
+    whole = (j >= whole_first) & (j <= whole_last)
 
-    @pl.when((j >= first) & (j <= last))
-    def _compute():
+    @pl.when(whole)
+    def _unmasked():
+        _online_update(
+            m_scr, l_scr, acc_scr, _logits(q_ref, k_ref, scale), v_ref[0]
+        )
+
+    @pl.when((j >= first) & (j <= last) & jnp.logical_not(whole))
+    def _masked():
         shape = (q_ref.shape[1], block_k)
         q_pos = p_min + lax.rem(
             lax.broadcasted_iota(jnp.int32, shape, 0), block_q
@@ -928,7 +988,7 @@ def chunk_prefill_kernel(
     window: Optional[int] = None,
     name: str = "paged_prefill",
     block_q: int = 512,
-    block_k: int = 512,
+    block_k: int = CHUNK_KEY_BLOCK,
 ) -> jnp.ndarray:
     """Chunked-prefill GQA attention, causal and, with ``window``, over
     the keys ``t - window < s <= t`` only: a flash forward over one
@@ -939,10 +999,15 @@ def chunk_prefill_kernel(
     heads, ``block_q`` rows each, against ``block_k`` keys — a key block
     is fetched once for the six heads that share it — and the key blocks
     wholly above a query block's causal reach or wholly behind its
-    window are neither fetched nor computed.  Returns ``[C, H, D]``.
-    Blocks of 512 x 512 read 13 % faster on a v5e than 256 x 512 at 48
-    / 8 heads of 128; leaving the mask off the blocks every row reads
-    whole and the scale on the queries moved nothing (PERF.md, PR 44)."""
+    window are neither fetched nor computed.  A computed block runs one
+    of two bodies: the mask's iotas, compares and selects only where the
+    diagonal or the window's edge cuts it, none where every row reads
+    it whole (:func:`chunk_key_blocks` counts both).  Returns ``[C, H,
+    D]``.  What a step pays a row group — two cross-lane reductions, a
+    broadcast, the rescale — it pays once a KEY BLOCK: at 48 / 8 heads
+    of 128 on a v5e a call over 1024-key blocks takes 23-38 % less than
+    over 512, 2048 is slower again, and a step without the mask ~11 us
+    where a masked one takes ~14 (PERF.md, PR 46)."""
     c, n_heads, d = q.shape
     n_kv, t, _ = k.shape
     group = n_heads // n_kv
@@ -951,22 +1016,15 @@ def chunk_prefill_kernel(
         raise ValueError(f"a chunk of {c} x {t} keys in blocks {bq} x {bk}")
     nq, rows = c // bq, group * bq
 
-    def reach(i, bounds):
-        p_min = bounds[0] + i * bq
-        last = (p_min + bq - 1 - bounds[1]) // bk
-        first = (
-            0 if window is None
-            else jnp.maximum(p_min - window + 1 - bounds[1], 0) // bk
-        )
-        return first, jnp.minimum(last, t // bk - 1)
-
     def q_index(h, i, j, bounds):
         del j, bounds
         return (h * nq + i, 0, 0)
 
     def kv_index(h, i, j, bounds):
-        first, last = reach(i, bounds)
-        return (h, jnp.clip(j, first, last), 0)
+        first, last, _, _ = _chunk_reach(
+            bounds[0] + i * bq, bounds[1], bq, bk, window
+        )
+        return (h, jnp.clip(j, first, jnp.minimum(last, t // bk - 1)), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

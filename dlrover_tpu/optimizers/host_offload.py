@@ -592,6 +592,7 @@ class HostOffloadAdamW:
         minus ``duration_s`` so it sits on the same clock as B/E
         records."""
         try:
+            from dlrover_tpu.common.parallel_io import throughput_gbps
             from dlrover_tpu.observability.events import (
                 anchored_now,
                 get_event_logger,
@@ -600,14 +601,13 @@ class HostOffloadAdamW:
                 record_offload_io,
             )
 
-            gbps = nbytes / 1e9 / max(duration_s, 1e-9)
             events = get_event_logger()
             events.complete(
                 "offload_copy",
                 anchored_now() - max(duration_s, 0.0),
                 duration_s,
                 bytes=int(nbytes),
-                throughput_gbps=round(gbps, 3),
+                throughput_gbps=throughput_gbps(nbytes, duration_s),
                 buffered=bool(buffered),
             )
             record_offload_io(nbytes, duration_s, buffered)

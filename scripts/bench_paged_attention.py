@@ -27,6 +27,12 @@ call, microseconds a LIVE page and the GB/s of the K and V rows the
 lanes hold — the record ``PERF.md`` quotes.  A time is a device time
 only on a chip; in interpret mode the rows say nothing about speed.
 
+``--chunk`` times the bare prefill-chunk kernel at Trinity-Large's two
+geometries (``CHUNKS``: a full layer's 32 k keys at three positions, a
+window layer's view with the window full) and prints milliseconds a
+call, the share of the bf16 peak on the keys the mask admits, and
+``chunk_key_blocks``' count of computed, unmasked and skipped steps.
+
 Wired into ``bench.py`` as the ``extras.paged_kernels`` leg.
 """
 
@@ -62,6 +68,14 @@ TABLES = {
     "falcon_h1": (32, 20, 4, 64, False),
 }
 LIVE_SHARES = (0.25, 0.5, 1.0)
+
+#: kernel name -> (window, ring blocks or None for the full table's 32 k
+#: keys, the chunk's first positions): a 2048-row chunk of Trinity-Large
+#: (48 / 8 heads of 128, bfloat16, 16 tokens a block)
+CHUNKS = {
+    "paged_prefill_full": (None, None, (0, 8192, 24576)),
+    "paged_prefill_window": (4096, 385, (8192,)),
+}
 
 
 def _time_call(call, reps: int) -> float:
@@ -348,6 +362,102 @@ def bench_tables(names=None, shares=LIVE_SHARES, spans=(None,), reps=20,
     return rows
 
 
+def bench_chunk(names=None, reps=20, blocks=(None,), dims=None):
+    """Rows of the bare chunk kernel over ``CHUNKS``, ``reps`` calls
+    chained inside ONE program as :func:`bench_tables` does.
+    ``blocks``: ``(block_q, block_k)`` pairs to force (``None``: the
+    kernel's own).  ``dims``: ``rows`` / ``heads`` / ``kv_heads`` /
+    ``head_dim`` / ``keys`` / ``dtype`` of a rehearsal."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from dlrover_tpu.models.trinity import _key_view_blocks
+    from dlrover_tpu.observability.profiler import peak_flops_for_kind
+    from dlrover_tpu.ops import paged_attention as pa
+    from dlrover_tpu.ops.paged_kernels import (
+        chunk_key_blocks, chunk_prefill_kernel,
+    )
+
+    dims = {
+        **dict(rows=2048, heads=48, kv_heads=8, head_dim=128, keys=32768,
+               block_size=16, dtype=jnp.bfloat16),
+        **(dims or {}),
+    }
+    c, heads, n_kv, d = (
+        dims[n] for n in ("rows", "heads", "kv_heads", "head_dim")
+    )
+    bs, dtype = dims["block_size"], dims["dtype"]
+    try:  # a share of a peak is a chip's number: none off one
+        peak = peak_flops_for_kind(jax.devices()[0].device_kind)
+    except LookupError:
+        peak = None
+    out_rows = []
+    for name in names or list(CHUNKS):
+        window, ring, starts = CHUNKS[name]
+        t = dims["keys"] if ring is None else _key_view_blocks(ring, bs) * bs
+        k = jax.random.normal(jax.random.PRNGKey(1), (n_kv, t, d), dtype)
+        v = k[:, ::-1]
+        q = jax.random.normal(jax.random.PRNGKey(2), (c, heads, d), dtype)
+        for start in starts:
+            # a ring's view begins at the block of the window's edge
+            key0 = 0 if ring is None else max(start - window + 1, 0) // bs * bs
+            pos = start + np.arange(c)
+            admitted = int(
+                np.minimum(pos + 1, window or pos + 1).sum()
+            )
+            for pair in blocks:
+                kw = {} if pair is None else dict(
+                    block_q=pair[0], block_k=pair[1]
+                )
+                kernel = functools.partial(
+                    chunk_prefill_kernel, window=window, name=name, **kw
+                )
+
+                def chained(q, k, v, start, key0, kernel=kernel):
+                    def body(_, q):
+                        out = kernel(q, k, v, start, key0)
+                        return q + (out * 1e-3).astype(q.dtype)
+
+                    return lax.fori_loop(0, reps, body, q)
+
+                fn = jax.jit(chained)
+                args = (q, k, v, jnp.int32(start), jnp.int32(key0))
+                fn(*args).block_until_ready()  # compile outside the clock
+                ms = _time_call(
+                    lambda: fn(*args).block_until_ready(), 3
+                ) / reps / 1e3
+                # the chunk's first and last rows against the dense
+                # form, on the device the row was timed on
+                got = kernel(*args).astype(jnp.float32)
+                n = min(128, c)
+                diff = max(
+                    float(jnp.max(jnp.abs(
+                        got[lo:lo + n] - pa.paged_chunk_attention(
+                            q[lo:lo + n], k, v, jnp.int32(start + lo),
+                            jnp.int32(key0), window, "jnp",
+                        ).astype(jnp.float32)
+                    )))
+                    for lo in (0, c - n)
+                )
+                computed, unmasked, skipped = chunk_key_blocks(
+                    start, key0, c, t, window, **kw
+                )
+                out_rows.append({
+                    "kernel": name, "start": start, "key0": key0, "keys": t,
+                    "blocks": pair, "ms_a_call": round(ms, 4),
+                    "peak_pct_admitted": peak and round(
+                        100 * 4 * heads * d * admitted / (ms / 1e3) / peak, 2
+                    ),
+                    "steps_computed": computed, "steps_unmasked": unmasked,
+                    "steps_skipped": skipped,
+                    "max_abs_diff_vs_jnp": diff,
+                })
+                print(json.dumps(out_rows[-1]), flush=True)
+    return out_rows
+
+
 def _interpret() -> bool:
     from dlrover_tpu.ops.pallas_utils import use_interpret
 
@@ -383,7 +493,35 @@ def main(argv=None) -> int:
         "--spans", default="",
         help="pages a group to force, comma-separated (default: autotune's)",
     )
+    ap.add_argument(
+        "--chunk", nargs="*", default=None, metavar="NAME",
+        help="time the bare prefill-chunk kernel at Trinity-Large's "
+        f"geometries ({', '.join(CHUNKS)}; none named: both)",
+    )
+    ap.add_argument(
+        "--blocks", default="",
+        help="with --chunk: block shapes to force, e.g. 512x1024,256x1024 "
+        "(default: the kernel's own)",
+    )
     args = ap.parse_args(argv)
+
+    if args.chunk is not None:
+        import jax
+
+        rows = bench_chunk(
+            args.chunk or None, reps=max(args.reps, 20),
+            blocks=[
+                tuple(int(n) for n in b.split("x"))
+                for b in args.blocks.split(",") if b
+            ] or (None,),
+        )
+        _flush(args.out, {
+            "bench": "paged_prefill_chunk", "rows": rows,
+            "backend": jax.default_backend(), "interpret": _interpret(),
+            "device_kind": jax.devices()[0].device_kind,
+        })
+        print(f"wrote {args.out} ({len(rows)} rows)")
+        return 0
 
     if args.tables is not None:
         import jax

@@ -687,6 +687,34 @@ class TestBenchHarness:
             )
             assert row["max_abs_diff_vs_jnp"] < 5e-6
 
+    def test_chunk_rows_say_how_often_the_mask_is_left_off(self, monkeypatch):
+        """``--chunk``: a row a (kernel, position, block shape) with the
+        time a call, the share of the peak on the keys the mask admits
+        and the three counts of steps, checked against the dense form."""
+        bpa = self._module()
+        monkeypatch.setattr(bpa, "CHUNKS", {
+            "tiny_full": (None, None, (0, 64)),
+            "tiny_window": (24, 12, (64,)),
+        })
+        rows = bpa.bench_chunk(
+            reps=2, blocks=(None, (8, 16)),
+            dims=dict(rows=16, heads=4, kv_heads=2, head_dim=8, keys=96,
+                      block_size=4, dtype=jnp.float32),
+        )
+        assert [(r["kernel"], r["start"], r["key0"], r["keys"]) for r in rows[::2]] == [
+            ("tiny_full", 0, 0, 96), ("tiny_full", 64, 0, 96),
+            ("tiny_window", 64, 40, 48),
+        ]
+        for row in rows:
+            # no chip, no share of a chip's peak
+            assert row["ms_a_call"] > 0 and row["peak_pct_admitted"] is None
+            assert row["max_abs_diff_vs_jnp"] < 5e-6
+            assert row["steps_unmasked"] <= row["steps_computed"]
+        # one block of the kernel's own spans the tiny table: nothing to
+        # leave the mask off; 8 x 16 at position 64 has whole blocks
+        assert rows[2]["steps_unmasked"] == 0
+        assert rows[3]["blocks"] == (8, 16) and rows[3]["steps_unmasked"] == 8
+
     def test_budget_stops_between_points(self):
         bpa = self._module()
         snapshots = []
@@ -810,6 +838,113 @@ class TestWindowAndLongChunkKernels:
                 jnp.int32(start), jnp.int32(key0), window, backend,
             )
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+    @pytest.mark.parametrize("case,c,t,start,key0,window,both", [
+        # blocks 0-2 whole, the chunk's own two cut by the diagonal
+        ("diagonal", 32, 128, 72, 8, None, True),
+        # ... and behind them the window's edge cuts one more
+        ("window_edge", 32, 128, 72, 8, 40, True),
+        # no block of 16 keys fits a window of 10: every one is cut
+        ("narrow_window", 32, 128, 72, 8, 10, False),
+        # a prompt's first chunk: nothing lies wholly below any row
+        ("position_0", 16, 64, 0, 0, None, False),
+        # a ring's view: key 0 is the block of the window's edge
+        ("ring_view", 32, 112, 200, 160, 40, True),
+    ])
+    def test_chunk_kernel_masks_only_the_blocks_the_mask_cuts(
+        self, case, c, t, start, key0, window, both
+    ):
+        """Blocks of 8 rows x 16 keys, several a query block: where
+        every row reads a block whole the body without a mask runs,
+        where the diagonal or the window's edge cuts it the masked one,
+        and the result is the dense attention's either way."""
+        from dlrover_tpu.ops.paged_kernels import (
+            chunk_key_blocks, chunk_prefill_kernel,
+        )
+
+        rng = np.random.default_rng(11)
+        nkv, group, d = 2, 3, 8
+        q = rng.normal(size=(c, nkv * group, d)).astype(np.float32)
+        k = rng.normal(size=(nkv, t, d)).astype(np.float32)
+        v = rng.normal(size=(nkv, t, d)).astype(np.float32)
+        computed, unmasked, skipped = chunk_key_blocks(
+            start, key0, c, t, window, block_q=8, block_k=16
+        )
+        assert computed + skipped == (c // 8) * (t // 16)
+        assert computed - unmasked > 0
+        assert (unmasked > 0) == both, (computed, unmasked, skipped)
+        got = chunk_prefill_kernel(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.int32(start), jnp.int32(key0), window=window,
+            block_q=8, block_k=16,
+        )
+        want = _dense_chunk(q, k, v, start, key0, window)
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+    def test_chunk_key_blocks_count_what_the_dense_mask_shows(self):
+        """Random chunks, views and windows: a block is computed where
+        the dense mask has a one in it, unmasked where it is all ones,
+        skipped where it is all zeros."""
+        from dlrover_tpu.ops.paged_kernels import chunk_key_blocks
+
+        rng = np.random.default_rng(17)
+        seen_unmasked = 0
+        for _ in range(200):
+            bq, bk = (int(x) for x in rng.choice([4, 8, 16, 32], size=2))
+            c, t = bq * int(rng.integers(1, 5)), bk * int(rng.integers(1, 9))
+            key0 = int(rng.integers(0, 64))
+            start = key0 + int(rng.integers(0, max(t - c, 0) + 1))
+            window = (
+                None if rng.random() < 0.4 else int(rng.integers(1, 2 * t))
+            )
+            q_pos = (start + np.arange(c))[:, None]
+            k_pos = (key0 + np.arange(t))[None]
+            mask = k_pos <= q_pos
+            if window is not None:
+                mask &= k_pos > q_pos - window
+            tiles = mask.reshape(c // bq, bq, t // bk, bk)
+            want = (
+                int(tiles.any(axis=(1, 3)).sum()),
+                int(tiles.all(axis=(1, 3)).sum()),
+                int((~tiles.any(axis=(1, 3))).sum()),
+            )
+            got = chunk_key_blocks(start, key0, c, t, window, bq, bk)
+            assert got == want, (start, key0, c, t, window, bq, bk)
+            seen_unmasked += want[1]
+        assert seen_unmasked > 100
+
+    def test_chunk_kernel_takes_key_blocks_of_1024(self, monkeypatch):
+        """Untold, a grid step reads ``CHUNK_KEY_BLOCK`` keys (a shorter
+        table its own length), and at Trinity-Large's positions most
+        computed steps run without a mask."""
+        from dlrover_tpu.ops.paged_kernels import (
+            CHUNK_KEY_BLOCK, chunk_key_blocks, chunk_prefill_kernel,
+        )
+
+        seen = []
+        real = paged_kernels.pl.pallas_call
+
+        def spy(kernel, **kw):
+            spec = kw["grid_spec"]
+            seen.append((spec.grid, spec.in_specs[1].block_shape))
+            return real(kernel, **kw)
+
+        monkeypatch.setattr(paged_kernels.pl, "pallas_call", spy)
+        assert CHUNK_KEY_BLOCK == 1024
+        for t, want in ((4096, 1024), (96, 96)):
+            jax.eval_shape(
+                lambda q, k, v: chunk_prefill_kernel(
+                    q, k, v, jnp.int32(0), jnp.int32(0)
+                ),
+                jnp.zeros((32, 6, 8)), jnp.zeros((2, t, 8)),
+                jnp.zeros((2, t, 8)),
+            )
+            assert seen[-1] == ((2, 1, t // want), (1, want, 8))
+        # a full layer's chunk at position 8192 of 32 k keys: 9-10 blocks
+        # a query block, one of them cut; a window layer's (window 4096,
+        # its view from the block of the window's edge): 5, two cut
+        assert chunk_key_blocks(8192, 0, 2048, 32768) == (38, 34, 90)
+        assert chunk_key_blocks(8192, 4096, 2048, 7168, 4096) == (20, 12, 8)
 
     def test_a_wide_table_streams_more_pages_a_step(self, monkeypatch):
         """The untuned group on a compiled TPU grows with the table

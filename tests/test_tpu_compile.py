@@ -230,11 +230,13 @@ def _window_decode_case(window):
 def _chunk_prefill_case(window):
     """A 2048-row chunk's streamed attention at Trinity-Large's widths
     against the keys of its kind, gathered by position: a window
-    layer's 6656 (385 blocks rounded up to the key block), a full
-    layer's 32768."""
+    layer's 7168 (385 blocks rounded up to the key block of 1024), a
+    full layer's 32768."""
+    from dlrover_tpu.models.trinity import _key_view_blocks
     from dlrover_tpu.ops.paged_kernels import chunk_prefill_kernel
 
-    keys = ((8, 6656 if window else 32768, D), BF16)
+    assert _key_view_blocks(385, BLOCK) * BLOCK == 7168
+    keys = ((8, 7168 if window else 32768, D), BF16)
 
     def fn(q, k, v, start, key0):
         return chunk_prefill_kernel(

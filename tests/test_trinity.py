@@ -195,6 +195,23 @@ def test_prefill_in_chunks_then_decode_is_the_reference(
     assert stats["prefix_hits"] == 0 and stats["prefix_hits_skipped"] == 6
 
 
+def test_the_ring_view_is_rounded_to_the_kernels_key_block():
+    """The window layers' position-ordered view is whole key blocks of
+    the chunk kernel, whatever that block is: the cell's ring of 385
+    blocks of 16 becomes 7 x 1024 rows, a ring shorter than a block
+    stays its own length (the kernel then takes it as one block)."""
+    from dlrover_tpu.models.trinity import _key_view_blocks
+    from dlrover_tpu.ops.paged_kernels import CHUNK_KEY_BLOCK as bk
+
+    for ring, bs in ((385, 16), (200, 16), (65, 16), (97, 32)):
+        view = _key_view_blocks(ring, bs)
+        assert view >= ring and view * bs % bk == 0
+        assert (view - ring) * bs < bk
+    assert _key_view_blocks(385, 16) == 7 * bk // 16 == 448
+    assert _key_view_blocks(bk // 16, 16) == bk // 16
+    assert _key_view_blocks(12, 4) == 12
+
+
 def test_the_replica_reports_a_pool_of_two_kinds(monkeypatch):
     """``device_report``'s ``pool`` / ``pool_bytes``: the full layer's
     blocks as the traffic asked, the window layers' as the program sized
