@@ -243,8 +243,8 @@ def _run_goodput_bench(budget: "BenchBudget" = None) -> dict:
 def _run_restart_bench(budget: "BenchBudget" = None) -> dict:
     """Run scripts/bench_restart.py in a subprocess (it builds its own
     model + engine; isolation keeps its compile/restore work off this
-    process's backend) and return its payload: restart_serial_s vs
-    restart_overlap_s on the same host."""
+    process's backend) and return its payload: restart_overlap_s
+    beside the single-leg baselines on the same host."""
     if os.getenv("DLROVER_BENCH_SKIP_RESTART"):
         return {"skipped": True}
     script = os.path.join(
@@ -397,7 +397,7 @@ def _input_micro(batch_mb: int, batches: int) -> dict:
 
 
 def _control_micro(n_agents: int, wait_s: float) -> dict:
-    """Control-plane long-poll vs polling over the real gRPC master,
+    """Control-plane long-poll waits over the real gRPC master,
     same host (``scripts/bench_control_plane.py`` owns the
     measurement — ONE definition)."""
     sys.path.insert(
@@ -410,9 +410,8 @@ def _control_micro(n_agents: int, wait_s: float) -> dict:
 
     result = run_all(n_agents, wait_s)
     out = {"control_bench": result}
-    for key in ("control_rps", "control_rpc_reduction"):
-        if key in result:
-            out[key] = result[key]
+    if "control_rps" in result:
+        out["control_rps"] = result["control_rps"]
     return out
 
 
@@ -565,8 +564,8 @@ def measure_profiling_overhead(
 def _brain_loop_bench(budget: "BenchBudget" = None) -> dict:
     """The closed autonomy loop's acceptance artifact: Brain-on vs
     Brain-off goodput under the slow-node sleep fault, plus — when
-    the budget allows — the preempt-storm comparison (full autonomy
-    stack vs the static seed job).  ``scripts/chaos.py`` owns both
+    the budget allows — the preempt-storm comparison (the Brain vs
+    the static seed auto-scaler).  ``scripts/chaos.py`` owns both
     scenarios — ONE definition."""
     sys.path.insert(
         0,
@@ -603,12 +602,10 @@ def _brain_loop_bench(budget: "BenchBudget" = None) -> dict:
         # main() applies the same floor) or the job races to the
         # target between the SIGTERM and the first missed collective
         p_on = run_preempt_storm(
-            steps=30, step_sleep=0.25, reshard=True, brain=True,
-            timeout=240.0,
+            steps=30, step_sleep=0.25, brain=True, timeout=240.0,
         )
         p_off = run_preempt_storm(
-            steps=30, step_sleep=0.25, reshard=False, brain=False,
-            timeout=240.0,
+            steps=30, step_sleep=0.25, brain=False, timeout=240.0,
         )
         brain_loop["preempt_storm"] = {
             "brain": p_on,
@@ -795,16 +792,15 @@ def main(argv=None) -> int:
         goodput_bench = _run_goodput_bench(budget)
     extras["goodput"] = goodput_bench
     flush_partial(args.out, payload)
-    # restart critical path: serial vs overlapped MTTR on this host
+    # restart critical path: overlapped MTTR on this host
     # (trainer/restart_path.py; scripts/bench_restart.py)
     if budget.tight(150):
         restart_bench = {"skipped": "budget"}
     else:
         restart_bench = _run_restart_bench(budget)
     extras["restart"] = restart_bench
-    for key in ("restart_serial_s", "restart_overlap_s"):
-        if isinstance(restart_bench.get(key), (int, float)):
-            extras[key] = restart_bench[key]
+    if isinstance(restart_bench.get("restart_overlap_s"), (int, float)):
+        extras["restart_overlap_s"] = restart_bench["restart_overlap_s"]
     flush_partial(args.out, payload)
     # probe sizes shrink under pressure: in the throttled container
     # even the 768 MB of probe buffers costs double-digit seconds

@@ -15,11 +15,6 @@ from typing import Dict, List, Optional, Tuple
 from dlrover_tpu.common import messages as msg
 from dlrover_tpu.common.comm import MasterChannel, wait_channel_ready
 from dlrover_tpu.common.constants import NodeEnv, NodeType, RendezvousName
-from dlrover_tpu.common.env import (
-    control_batch_enabled,
-    control_longpoll_enabled,
-    master_failover_enabled,
-)
 from dlrover_tpu.common.fault_injection import maybe_crash
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.observability.events import get_event_logger
@@ -223,10 +218,7 @@ class MasterClient:
         mid-wait.  Block until the (restarted) master's channel is
         READY again — then refresh the fencing pair so the re-issued
         wait parks on the NEW incarnation.  False when the outage
-        outlives ``deadline`` or failover is kill-switched (the caller
-        re-raises)."""
-        if not master_failover_enabled():
-            return False
+        outlives ``deadline`` (the caller re-raises)."""
         remaining = deadline - time.time()
         if remaining <= 0:
             return False
@@ -369,15 +361,11 @@ class MasterClient:
         rdzv_name: str,
         node_rank: int,
         timeout: float,
-        poll_interval: float = 0.3,
     ) -> Tuple[int, int, Dict[int, int]]:
         """Long-poll ``get_comm_world``: block until the master
         declares the world complete (or ``timeout`` elapses — an empty
-        world is then returned).  Falls back to the get-every-
-        ``poll_interval`` loop under
-        ``DLROVER_TPU_CONTROL_LONGPOLL=0``."""
+        world is then returned)."""
         deadline = time.time() + max(timeout, 0.0)
-        longpoll = control_longpoll_enabled()
         # a master death can be absorbed BELOW this loop (the channel
         # retries inside its reconnect deadline and re-issues the
         # parked wait transparently) — watch the incarnation between
@@ -396,59 +384,41 @@ class MasterClient:
                 remaining = deadline - time.time()
                 if remaining <= 0:
                     return -1, 0, {}
-                if longpoll:
-                    chunk = min(remaining, LONGPOLL_CHUNK_S)
-                    t0 = time.monotonic()
-                    try:
-                        world = self._channel.get(
-                            msg.CommWorldRequest(
-                                node_id=node_rank,
-                                rdzv_name=rdzv_name,
-                                wait_timeout=chunk,
-                            ),
-                            timeout=chunk + _LONGPOLL_RPC_MARGIN_S,
-                        )
-                    except ConnectionError:
-                        # mid-wait master death: re-park on the new
-                        # incarnation.  Replay usually restored this
-                        # node's join; when the join ack died in the
-                        # write-behind linger window, re-assert it.
-                        if self._survive_outage(
-                            deadline, "comm-world wait"
-                        ):
-                            self._ensure_rdzv_membership(
-                                rdzv_name, node_rank
-                            )
-                            continue
-                        raise
-                    if world is not None and not isinstance(
-                        world, msg.NotModified
-                    ):
-                        result = (
-                            world.round, world.group, world.world or {}
-                        )
-                        if result[2]:
-                            if node_rank in result[2]:
-                                # joined world delivered: the pending
-                                # join is consumed, later monitor
-                                # waits must never re-join
-                                self._pending_join.pop(
-                                    rdzv_name, None
-                                )
-                            self._comm_world_cache[rdzv_name] = (
-                                getattr(world, "version", 0), result
-                            )
-                            return result
-                    _pace_longpoll(chunk, time.monotonic() - t0)
-                else:
-                    rnd, group, world_map = self.get_comm_world(
-                        rdzv_name, node_rank
+                chunk = min(remaining, LONGPOLL_CHUNK_S)
+                t0 = time.monotonic()
+                try:
+                    world = self._channel.get(
+                        msg.CommWorldRequest(
+                            node_id=node_rank,
+                            rdzv_name=rdzv_name,
+                            wait_timeout=chunk,
+                        ),
+                        timeout=chunk + _LONGPOLL_RPC_MARGIN_S,
                     )
-                    if world_map:
-                        if node_rank in world_map:
+                except ConnectionError:
+                    # mid-wait master death: re-park on the new
+                    # incarnation.  Replay usually restored this
+                    # node's join; when the join ack died in the
+                    # write-behind linger window, re-assert it.
+                    if self._survive_outage(deadline, "comm-world wait"):
+                        self._ensure_rdzv_membership(rdzv_name, node_rank)
+                        continue
+                    raise
+                if world is not None and not isinstance(
+                    world, msg.NotModified
+                ):
+                    result = (world.round, world.group, world.world or {})
+                    if result[2]:
+                        if node_rank in result[2]:
+                            # joined world delivered: the pending
+                            # join is consumed, later monitor
+                            # waits must never re-join
                             self._pending_join.pop(rdzv_name, None)
-                        return rnd, group, world_map
-                    time.sleep(poll_interval)
+                        self._comm_world_cache[rdzv_name] = (
+                            getattr(world, "version", 0), result
+                        )
+                        return result
+                _pace_longpoll(chunk, time.monotonic() - t0)
 
     def num_nodes_waiting(
         self,
@@ -543,54 +513,33 @@ class MasterClient:
         self,
         key: str,
         timeout: float = 300.0,
-        interval: float = 0.2,
-        longpoll: Optional[bool] = None,
     ) -> bytes:
         """Block until ``key`` appears in the master KV store.
 
-        Long-poll (default): each RPC parks on the master's KV
-        condition up to ``LONGPOLL_CHUNK_S`` — an idle 5 min wait costs
-        ~10 RPCs.  ``DLROVER_TPU_CONTROL_LONGPOLL=0`` (or
-        ``longpoll=False``) restores the get-every-``interval`` polling
-        loop as the bench reference.
+        Each RPC parks on the master's KV condition up to
+        ``LONGPOLL_CHUNK_S`` — an idle 5 min wait costs ~10 RPCs.
         """
-        if longpoll is None:
-            longpoll = control_longpoll_enabled()
         deadline = time.time() + timeout
         with get_event_logger().span("control_wait", kind="kv", key=key):
             while time.time() < deadline:
-                if longpoll:
-                    chunk = min(
-                        deadline - time.time(), LONGPOLL_CHUNK_S
+                chunk = min(deadline - time.time(), LONGPOLL_CHUNK_S)
+                t0 = time.monotonic()
+                try:
+                    res = self._channel.get(
+                        msg.KVWaitRequest(key=key, wait_timeout=chunk),
+                        timeout=chunk + _LONGPOLL_RPC_MARGIN_S,
                     )
-                    t0 = time.monotonic()
-                    try:
-                        res = self._channel.get(
-                            msg.KVWaitRequest(
-                                key=key, wait_timeout=chunk
-                            ),
-                            timeout=chunk + _LONGPOLL_RPC_MARGIN_S,
-                        )
-                    except ConnectionError:
-                        # mid-wait master death: re-park on the new
-                        # incarnation (journal replay restored the KV
-                        # contents, so a pre-crash set still answers)
-                        if self._survive_outage(deadline, "kv wait"):
-                            continue
-                        raise
-                    value = (
-                        res.value
-                        if res and res.value is not None
-                        else b""
-                    )
-                    if value:
-                        return value
-                    _pace_longpoll(chunk, time.monotonic() - t0)
-                else:
-                    value = self.kv_store_get(key)
-                    if value:
-                        return value
-                    time.sleep(interval)
+                except ConnectionError:
+                    # mid-wait master death: re-park on the new
+                    # incarnation (journal replay restored the KV
+                    # contents, so a pre-crash set still answers)
+                    if self._survive_outage(deadline, "kv wait"):
+                        continue
+                    raise
+                value = res.value if res and res.value is not None else b""
+                if value:
+                    return value
+                _pace_longpoll(chunk, time.monotonic() - t0)
         raise TimeoutError(f"key {key!r} not set within {timeout}s")
 
     # ---------------------------------------------------------- data shards
@@ -628,9 +577,9 @@ class MasterClient:
         """Next shard task; ``wait_timeout`` > 0 long-polls through
         WAIT answers (the master parks until a task is dispatchable).
 
-        A mid-wait master death re-parks on the new incarnation
-        (failover mode): an empty answer here would read as "dataset
-        exhausted" to ``fetch_shard`` and silently end the epoch."""
+        A mid-wait master death re-parks on the new incarnation: an
+        empty answer here would read as "dataset exhausted" to
+        ``fetch_shard`` and silently end the epoch."""
         wait_timeout, timeout = _longpoll_params(wait_timeout)
         deadline = time.time() + max(wait_timeout, 5.0)
         while True:
@@ -774,8 +723,7 @@ class MasterClient:
     ) -> Optional[Dict]:
         """Fetch the master observatory's derived snapshot (per-node
         health, goodput ledger, newest diagnosis conclusions); None
-        when the observatory is off (``DLROVER_TPU_OBSERVATORY=0``)
-        or the master predates it."""
+        when the master predates it."""
         res = self._channel.get(
             msg.JobStatusRequest(job=job, conclusions=conclusions)
         )
@@ -864,9 +812,6 @@ class ReportBuffer:
     items are dropped (counted on
     ``dlrover_tpu_control_dropped_reports`` + a warning) — a long
     outage must degrade observability, never OOM the agent.
-
-    ``DLROVER_TPU_CONTROL_BATCH=0`` degenerates ``add`` to the old
-    one-RPC-per-report path.
     """
 
     def __init__(
@@ -918,14 +863,10 @@ class ReportBuffer:
         )
 
     def add(self, message: msg.Message) -> bool:
-        """Queue one report (or send it straight through when batching
-        is disabled).  Returns the delivery ack for the direct path;
-        for a buffered enqueue it returns True unconditionally — the
+        """Queue one report.  Returns True unconditionally — the
         buffer owns delivery from here (a transport-failed inline
         flush re-queues the batch, so the report is still owed, not
         lost or rejected)."""
-        if not control_batch_enabled():
-            return self._client._channel.report(message)
         with self._lock:
             self._items.append(message)
             self._trim_locked()

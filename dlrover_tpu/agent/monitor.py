@@ -309,9 +309,8 @@ class TimelineReporter(PeriodicReporter):
             batch = delta[i:i + self._max_batch]
             events = [rec for rec, _ in batch]
             if self._buffer is not None:
-                # add() is the direct-send ack under
-                # DLROVER_TPU_CONTROL_BATCH=0 and True for a buffered
-                # enqueue — either way it IS the delivery verdict
+                # a buffered enqueue is True: the buffer owns
+                # delivery from here
                 ok = self._buffer.add(
                     msg.TimelineEventsReport(events=events)
                 )

@@ -38,11 +38,9 @@ from dlrover_tpu.common.constants import (
     TrainingExceptionLevel,
 )
 from dlrover_tpu.common.env import (
-    control_longpoll_enabled,
     env_float,
     get_free_port,
     preempt_drain_grace_s,
-    reshard_enabled,
 )
 from dlrover_tpu.common.jax_env import export_compile_cache
 from dlrover_tpu.common.log import default_logger as logger
@@ -94,11 +92,6 @@ class ElasticLaunchConfig:
     # ("" = the checkout's fixed default; $JAX_COMPILATION_CACHE_DIR,
     # when set, always wins — common/jax_env.export_compile_cache)
     compile_cache_dir: str = ""
-    # overlapped restart critical path in the workers (restore byte
-    # prefetch + background AOT compile, trainer/restart_path.py);
-    # False exports DLROVER_TPU_RESTART_OVERLAP=0 so every worker runs
-    # the serial restore->compile order
-    restart_overlap: bool = True
     # watch the GCE metadata maintenance-event endpoint: on TPU-VMs
     # preemption fires there ~60s before any SIGTERM (agent/preemption.py)
     watch_preemption: bool = True
@@ -142,14 +135,12 @@ class MasterRendezvousHandler:
         local_world_size: int,
         rdzv_name: str = RendezvousName.ELASTIC_TRAINING,
         timeout: float = RendezvousConstant.MAX_WAIT_SECS,
-        poll_interval: float = 0.3,
     ):
         self._client = client
         self._node_rank = node_rank
         self._local_world_size = local_world_size
         self._rdzv_name = rdzv_name
         self._timeout = timeout
-        self._poll = poll_interval
 
     def next_rendezvous(self):
         # topology hint (e.g. "superpod0/pod1/slice2") enables
@@ -173,14 +164,11 @@ class MasterRendezvousHandler:
         )
         # long-poll: the RPC parks on the master's rendezvous condition
         # and returns the moment the round completes — one RPC per
-        # ~30 s chunk instead of one every 0.3 s.  wait_comm_world
-        # falls back to the exact old get/sleep loop under
-        # DLROVER_TPU_CONTROL_LONGPOLL=0.
+        # ~30 s chunk
         rnd, group, world = self._client.wait_comm_world(
             self._rdzv_name,
             self._node_rank,
             timeout=self._timeout,
-            poll_interval=self._poll,
         )
         if world:
             if self._node_rank not in world:
@@ -339,8 +327,6 @@ class ElasticTrainingAgent:
             }
         )
         export_compile_cache(env, self._config.compile_cache_dir)
-        if not self._config.restart_overlap:
-            env["DLROVER_TPU_RESTART_OVERLAP"] = "0"
         # deep-capture rendezvous point: agent and workers must agree
         # where stack dumps and profile artifacts land — the NODE-
         # scoped dir (base from DLROVER_TPU_CAPTURE_DIR / the events
@@ -459,36 +445,24 @@ class ElasticTrainingAgent:
         return result
 
     def _pace_monitor(self):
-        """One monitor-interval pause.  Under long-poll the pause IS
-        the waiting-count RPC parked on the master — the same one RPC
-        per tick as the old sleep+poll pair, but a membership change
-        wakes the loop INSTANTLY instead of at the next tick.  The
-        legacy plain sleep survives the kill-switch."""
+        """One monitor-interval pause.  The pause IS the waiting-count
+        RPC parked on the master — one RPC per tick, and a membership
+        change wakes the loop INSTANTLY instead of at the next tick."""
         interval = self._config.monitor_interval
-        if not control_longpoll_enabled():
-            time.sleep(interval)
-            return
         try:
             self._last_waiting = self._client.num_nodes_waiting(
                 wait_timeout=interval, last_num=self._last_waiting
             )
         except ConnectionError:
             # unreachable master must read as "no membership change"
-            # (the old polling path returned False here) — a stale
-            # nonzero count would fire a restart storm every tick for
-            # the whole outage
+            # — a stale nonzero count would fire a restart storm every
+            # tick for the whole outage
             self._last_waiting = 0
             time.sleep(interval)
 
     def _membership_changed(self) -> bool:
-        if control_longpoll_enabled():
-            # _pace_monitor just fetched it — no second RPC
-            waiting = self._last_waiting
-        else:
-            try:
-                waiting = self._client.num_nodes_waiting()
-            except ConnectionError:
-                return False
+        # _pace_monitor just fetched it — no second RPC
+        waiting = self._last_waiting
         node_unit = max(self._config.node_unit, 1)
         return waiting > 0 and waiting % node_unit == 0
 
@@ -526,10 +500,7 @@ class ElasticTrainingAgent:
         persists the step the world just completed instead of the
         last periodic snapshot.  Workers wedged in a collective
         simply cannot advance; the grace expires and the flush uses
-        the newest complete snapshot, exactly today's behavior.
-        No-op under ``DLROVER_TPU_RESHARD=0``."""
-        if not reshard_enabled():
-            return
+        the newest complete snapshot."""
         live = [p for p in self._procs if p.poll() is None]
         if not live:
             return
@@ -701,19 +672,17 @@ class ElasticTrainingAgent:
             timeline_reporter.start()
         if self._start_ckpt_saver:
             factory_queue = AsyncCheckpointSaver.start_async_saving_ckpt()
-        if reshard_enabled():
-            # graceful-drain SIGTERM: supersede the bare ckpt_saver
-            # flush hook with drain → flush → fence → exit, so a pod
-            # kill leaves survivors a FRESH reshardable checkpoint
-            # and an already-fenced master.  DLROVER_TPU_RESHARD=0
-            # keeps today's flush-only hook exactly.
-            try:
-                signal.signal(signal.SIGTERM, self._on_sigterm)
-            except ValueError:
-                logger.warning(
-                    "not on main thread: graceful SIGTERM drain not "
-                    "installed"
-                )
+        # graceful-drain SIGTERM: supersede the bare ckpt_saver flush
+        # hook with drain → flush → fence → exit, so a pod kill leaves
+        # survivors a FRESH reshardable checkpoint and an
+        # already-fenced master
+        try:
+            signal.signal(signal.SIGTERM, self._on_sigterm)
+        except ValueError:
+            logger.warning(
+                "not on main thread: graceful SIGTERM drain not "
+                "installed"
+            )
         if self._config.watch_preemption:
             from dlrover_tpu.agent.preemption import PreemptionWatcher
 
@@ -767,9 +736,7 @@ class ElasticTrainingAgent:
             self._save_ckpt_to_storage(f"preemption:{event}")
             self._try_report_failure(
                 f"maintenance event {event}",
-                TrainingExceptionLevel.NODE_PREEMPTED
-                if reshard_enabled()
-                else TrainingExceptionLevel.NODE_ERROR,
+                TrainingExceptionLevel.NODE_PREEMPTED,
             )
 
     def _on_sigterm(self, signum, frame):  # pragma: no cover - signal
@@ -794,10 +761,10 @@ class ElasticTrainingAgent:
     def _take_brain_directive(self):
         """A master directive delivered on the monitor-pacing poll.
         ``capture`` executes here (background — the monitor loop keeps
-        supervising); ``drain`` is returned to the loop.  Ignored (and
-        logged) when the respective machinery is kill-switched — the
-        master's execution deadline then falls back to fencing this
-        node without our cooperation."""
+        supervising); ``drain`` is returned to the loop.  An unknown
+        action is ignored (and logged) — the master's execution
+        deadline then falls back to fencing this node without our
+        cooperation."""
         directive = self._client.take_node_action()
         if directive is None:
             return None
@@ -809,11 +776,6 @@ class ElasticTrainingAgent:
             logger.warning(
                 "ignoring unknown brain directive %r (decision %s)",
                 action, decision_id,
-            )
-            return None
-        if not reshard_enabled():
-            logger.warning(
-                "brain drain directive ignored: DLROVER_TPU_RESHARD=0"
             )
             return None
         return directive

@@ -7,7 +7,7 @@ we register the same two methods through grpc's generic handler API with
 identity serializers; the payload is the pickled ``Envelope`` from
 ``dlrover_tpu.common.messages``.
 
-Failover semantics (``DLROVER_TPU_MASTER_FAILOVER``, default on):
+Failover semantics:
 
 - retries use JITTERED exponential backoff under a bounded total
   deadline (``DLROVER_TPU_MASTER_RECONNECT_DEADLINE_S``) instead of
@@ -16,14 +16,10 @@ Failover semantics (``DLROVER_TPU_MASTER_FAILOVER``, default on):
   re-dialed cleanly;
 - every envelope carries the ``(job_epoch, master_incarnation)`` pair
   this client last learned; a ``StaleEpoch`` answer triggers an epoch
-  refresh + one transparent re-issue instead of surfacing a crash;
-- with the kill-switch off, behavior is today's fail-fast shape:
-  ``max_retry`` attempts then ``ConnectionError``, no epochs on the
-  wire, ``StaleEpoch`` answers raise.
+  refresh + one transparent re-issue instead of surfacing a crash.
 """
 
 import random
-import socket
 import threading
 import time
 from concurrent import futures
@@ -33,10 +29,7 @@ from typing import Callable, Optional
 import grpc
 
 from dlrover_tpu.common.constants import GRPC
-from dlrover_tpu.common.env import (
-    master_failover_enabled,
-    master_reconnect_deadline_s,
-)
+from dlrover_tpu.common.env import master_reconnect_deadline_s
 from dlrover_tpu.common.fault_injection import (
     FaultInjectedError,
     get_fault_injector,
@@ -64,25 +57,12 @@ class StaleEpochError(ConnectionError):
     its cached job identity is unrecoverably stale."""
 
 
-def addr_connectable(addr: str, timeout: float = 1.0) -> bool:
-    """True if a TCP connect to "host:port" succeeds."""
-    if not addr or ":" not in addr:
-        return False
-    host, _, port = addr.rpartition(":")
-    try:
-        with socket.create_connection((host, int(port)), timeout=timeout):
-            return True
-    except (OSError, ValueError):
-        return False
-
-
 def wait_channel_ready(addr: str, timeout: float = 60.0) -> bool:
     """Block until a gRPC channel to ``addr`` is READY (or timeout).
 
-    Replaces the connect-probe polling loop (``addr_connectable`` every
-    0.5 s): grpc's own reconnect backoff drives the retries and the
-    caller just parks on the ready future — the long-poll shape for
-    "wait for the master to come up".
+    grpc's own reconnect backoff drives the retries and the caller
+    just parks on the ready future — the long-poll shape for "wait for
+    the master to come up".
     """
     if not addr or ":" not in addr:
         return False
@@ -161,13 +141,11 @@ class MasterChannel:
         node_id: int = 0,
         node_type: str = "worker",
         timeout: float = 10.0,
-        max_retry: int = 3,
     ):
         self._addr = addr
         self._node_id = node_id
         self._node_type = node_type
         self._timeout = timeout
-        self._max_retry = max_retry
         #: RPCs actually issued on the wire (attempts, not logical
         #: calls) — what the idle-waiter RPC-bound test and the
         #: control-plane bench count
@@ -231,16 +209,8 @@ class MasterChannel:
                 node_id=self._node_id,
                 node_type=self._node_type,
                 data=serialize_message(message),
-                job_epoch=(
-                    self.job_epoch
-                    if master_failover_enabled()
-                    else -1
-                ),
-                master_incarnation=(
-                    self.master_incarnation
-                    if master_failover_enabled()
-                    else -1
-                ),
+                job_epoch=self.job_epoch,
+                master_incarnation=self.master_incarnation,
             )
         )
 
@@ -273,8 +243,8 @@ class MasterChannel:
         deadline_s: Optional[float] = None,
     ):
         """One logical RPC: jittered-exponential retries under a total
-        deadline; under failover the channel is also re-dialed after
-        repeated failures so a replacement master is picked up.  Each
+        deadline; the channel is re-dialed after repeated failures so
+        a replacement master is picked up.  Each
         retry pause is visible on the timeline as a ``control_wait``
         span with ``kind="retry"`` + a ``retries`` label.
 
@@ -290,13 +260,10 @@ class MasterChannel:
         captured callable would keep dialing the closed channel for
         the rest of the deadline ("Cannot invoke RPC on closed
         channel!" forever)."""
-        failover = master_failover_enabled()
         if deadline_s is None:
             deadline_s = getattr(self._deadline_override, "s", None)
         if deadline_s is None:
-            deadline_s = (
-                master_reconnect_deadline_s() if failover else 60.0
-            )
+            deadline_s = master_reconnect_deadline_s()
         deadline = time.monotonic() + deadline_s
         injector = get_fault_injector()
         err: Optional[Exception] = None
@@ -322,11 +289,7 @@ class MasterChannel:
                     self.rpc_count += 1
                     rpc(payload, timeout=timeout)
                 raw = rpc(payload, timeout=timeout)
-                if (
-                    attempt > 1
-                    and failover
-                    and msg_name != "ControlEpochRequest"
-                ):
+                if attempt > 1 and msg_name != "ControlEpochRequest":
                     # the call came back after failures: the master
                     # may be a NEW incarnation (or job epoch) — learn
                     # the fencing pair so delta caches invalidate and
@@ -346,21 +309,6 @@ class MasterChannel:
                     "master rpc to %s failed (attempt %d): %s",
                     self._addr, attempt, e,
                 )
-                if not failover:
-                    # kill-switched: today's fixed sleep schedule
-                    # EXACTLY (1 s, 2 s, 4 s … cap 5 s, after every
-                    # failure including the last) — the legacy path
-                    # tolerated a multi-second master stall between
-                    # attempts, and shrinking that window would turn
-                    # survivable flakes into job crashes
-                    delay = min(2.0 ** (attempt - 1), 5.0)
-                    t0_mono = time.monotonic()
-                    time.sleep(delay)
-                    if attempt >= self._max_retry:
-                        break
-                    self.retry_count += 1
-                    self._emit_retry_span(t0_mono, delay, attempt)
-                    continue
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     break
@@ -369,15 +317,12 @@ class MasterChannel:
                 t0_mono = time.monotonic()
                 time.sleep(delay)
                 self._emit_retry_span(t0_mono, delay, attempt)
-                if (
-                    failover
-                    and attempt % self.RECONNECT_AFTER_FAILURES == 0
-                ):
+                if attempt % self.RECONNECT_AFTER_FAILURES == 0:
                     # rebuild swaps self._report/self._get for stubs
                     # on the NEW channel; every attempt re-resolves
                     # from ``kind`` so all threads pick them up
                     self._reconnect()
-                if failover and msg_name != "ControlEpochRequest":
+                if msg_name != "ControlEpochRequest":
                     # probe the epoch BEFORE re-issuing: a parked
                     # long-poll re-sent to a restarted master would
                     # otherwise park its whole chunk before the
@@ -452,7 +397,7 @@ class MasterChannel:
 
     def _roundtrip(self, kind: str, message: Message, timeout: float):
         """Serialize, send with retry, deserialize — with transparent
-        StaleEpoch refresh+re-issue under failover."""
+        StaleEpoch refresh+re-issue."""
         name = type(message).__name__
         for _ in range(self.MAX_EPOCH_REFRESHES):
             raw = self._call_with_retry(
@@ -461,11 +406,6 @@ class MasterChannel:
             response = deserialize_message(raw)
             if not isinstance(response, StaleEpoch):
                 return response
-            if not master_failover_enabled():
-                raise StaleEpochError(
-                    f"master fenced {name}: job_epoch="
-                    f"{response.job_epoch}"
-                )
             self._adopt(response)
         raise StaleEpochError(
             f"master kept fencing {name} after "

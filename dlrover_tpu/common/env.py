@@ -60,74 +60,9 @@ def get_restart_count() -> int:
     return _get_int(NodeEnv.RESTART_COUNT, 0)
 
 
-INPUT_PIPELINE_ENV = "DLROVER_TPU_INPUT_PIPELINE"
-
-
-def input_pipeline_enabled() -> bool:
-    """Kill-switch for the pipelined input plane (background host
-    fetch in ``ElasticDataLoader``/``device_prefetch`` and the
-    shard-task RPC prefetch).  ``DLROVER_TPU_INPUT_PIPELINE=0``
-    reproduces the serial path — same batch order, byte-identical
-    batches (pinned by tests).  Default: enabled."""
-    return os.getenv(INPUT_PIPELINE_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
-
-
-CONTROL_LONGPOLL_ENV = "DLROVER_TPU_CONTROL_LONGPOLL"
-CONTROL_BATCH_ENV = "DLROVER_TPU_CONTROL_BATCH"
-DATASTORE_SYNC_ENV = "DLROVER_TPU_DATASTORE_SYNC"
-
-
-def control_longpoll_enabled() -> bool:
-    """Kill-switch for the control-plane fast path: server-side
-    long-poll waits (KV store, comm world, shard tasks, training
-    status, master-ready).  ``DLROVER_TPU_CONTROL_LONGPOLL=0``
-    reproduces the client-side polling loops exactly (the bench
-    reference and the rollback path).  Default: enabled."""
-    return os.getenv(CONTROL_LONGPOLL_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
-
-
-def control_batch_enabled() -> bool:
-    """Kill-switch for coalesced delta reporting: with
-    ``DLROVER_TPU_CONTROL_BATCH=0`` every ``ReportBuffer.add``
-    degenerates to the old one-RPC-per-report path.  Default:
-    enabled."""
-    return os.getenv(CONTROL_BATCH_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
-
-
-def datastore_sync_enabled() -> bool:
-    """``DLROVER_TPU_DATASTORE_SYNC=1`` keeps every Brain datastore
-    write a synchronous INSERT+commit (today's behavior, byte-for-byte
-    — pinned by tests); default is the write-behind flusher."""
-    return os.getenv(DATASTORE_SYNC_ENV, "").lower() in (
-        "1", "true", "on",
-    )
-
-
-OBSERVATORY_ENV = "DLROVER_TPU_OBSERVATORY"
 EVENTS_MAX_MB_ENV = "DLROVER_TPU_EVENTS_MAX_MB"
 TIMELINE_MAX_AGE_ENV = "DLROVER_TPU_TIMELINE_MAX_AGE_S"
 TIMELINE_MAX_ROWS_ENV = "DLROVER_TPU_TIMELINE_MAX_ROWS"
-
-
-def observatory_enabled() -> bool:
-    """Kill-switch for the master-side observatory: the streaming
-    health-derivation engine (``observability/health.py``), the
-    derived-signal diagnosis operators (straggler / data-stall / hang
-    watchdog), the ``JobStatusRequest`` RPC, the ``--status_port``
-    HTTP endpoints, and the timeline growth bounds (agent JSONL
-    rotation + Brain retention sweep).  ``DLROVER_TPU_OBSERVATORY=0``
-    reproduces today's paths exactly: the private
-    ``DiagnosisDataStore`` chain alone, SpeedMonitor-only hang
-    detection, unbounded timeline growth.  Default: enabled."""
-    return os.getenv(OBSERVATORY_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def env_float(name: str, default: float) -> float:
@@ -158,24 +93,8 @@ def timeline_max_rows() -> int:
     return int(env_float(TIMELINE_MAX_ROWS_ENV, 500_000))
 
 
-RESHARD_ENV = "DLROVER_TPU_RESHARD"
 CKPT_CLOSE_TIMEOUT_ENV = "DLROVER_TPU_CKPT_CLOSE_TIMEOUT_S"
 PREEMPT_DRAIN_GRACE_ENV = "DLROVER_TPU_PREEMPT_DRAIN_GRACE_S"
-
-
-def reshard_enabled() -> bool:
-    """Kill-switch for the elastic-reshard subsystem: device-count-
-    agnostic layout headers on checkpoint shards, the overlap-range
-    resharded restore leg in ``CheckpointEngine``, the agent's
-    graceful worker drain (SIGUSR1 snapshot-every-step + SIGTERM
-    drain-then-flush) and the ``node_preempted`` master fencing.
-    ``DLROVER_TPU_RESHARD=0`` reproduces today's behavior exactly: a
-    world-size change restores per-rank shard files or fails, the
-    SIGTERM path is the bare ckpt_saver flush, and preemption reports
-    stay ``node_error``.  Default: enabled."""
-    return os.getenv(RESHARD_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def ckpt_close_timeout_s() -> float:
@@ -427,26 +346,7 @@ def brain_sustain_cycles() -> int:
     return max(int(env_float(BRAIN_SUSTAIN_ENV, 2.0)), 1)
 
 
-SELF_OBS_ENV = "DLROVER_TPU_SELF_OBS"
 MASTER_WORKERS_ENV = "DLROVER_TPU_MASTER_WORKERS"
-
-
-def self_obs_enabled() -> bool:
-    """Kill-switch for the master's control-plane SELF-telemetry: the
-    per-RPC-kind latency / request-size / response-size histograms,
-    the in-flight / parked-long-poll / thread-pool-occupancy gauges,
-    the per-job state row counts, the datastore write-behind health
-    gauges (queue depth, flush-latency histogram, journal lag), the
-    snapshot age/duration gauges, the ``master`` section of
-    ``/status`` + ``JobStatusResponse``, and the ``MasterHealth``
-    overload deriver.  ``DLROVER_TPU_SELF_OBS=0`` reproduces the
-    pre-self-obs metric surface exactly — no ``dlrover_tpu_master_*``
-    / ``dlrover_tpu_datastore_*`` / ``dlrover_tpu_journal_*`` /
-    ``dlrover_tpu_snapshot_*`` series exist (pinned by tests).
-    Default: enabled."""
-    return os.getenv(SELF_OBS_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def master_workers() -> int:
@@ -461,27 +361,13 @@ def master_workers() -> int:
     return max(int(env_float(MASTER_WORKERS_ENV, 64.0)), 1)
 
 
-MASTER_FAILOVER_ENV = "DLROVER_TPU_MASTER_FAILOVER"
 RECONNECT_DEADLINE_ENV = "DLROVER_TPU_MASTER_RECONNECT_DEADLINE_S"
 SNAPSHOT_INTERVAL_ENV = "DLROVER_TPU_CONTROL_SNAPSHOT_INTERVAL_S"
 
 
-def master_failover_enabled() -> bool:
-    """Kill-switch for the master-failover subsystem: durable
-    control-plane journaling/replay, transparent ``MasterChannel``
-    reconnection, and ``(job_epoch, master_incarnation)`` fencing.
-    ``DLROVER_TPU_MASTER_FAILOVER=0`` reproduces the fail-fast
-    behavior exactly: a dead master raises ``ConnectionError`` after
-    ``max_retry`` attempts, no epochs ride the envelope, and the
-    master journals nothing.  Default: enabled."""
-    return os.getenv(MASTER_FAILOVER_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
-
-
 def master_reconnect_deadline_s() -> float:
     """Total time a client keeps retrying/reconnecting across a
-    master outage before giving up (failover mode only)."""
+    master outage before giving up."""
     try:
         return float(os.getenv(RECONNECT_DEADLINE_ENV, "120"))
     except ValueError:
@@ -500,7 +386,6 @@ def control_snapshot_interval_s() -> float:
 FLYWHEEL_STALENESS_ENV = "DLROVER_TPU_FLYWHEEL_STALENESS"
 FLYWHEEL_MAX_LAG_ENV = "DLROVER_TPU_FLYWHEEL_MAX_LAG"
 FLYWHEEL_PUBLISH_EVERY_ENV = "DLROVER_TPU_FLYWHEEL_PUBLISH_EVERY"
-FLYWHEEL_DRAFT_ENV = "DLROVER_TPU_FLYWHEEL_DRAFT"
 FLYWHEEL_LEND_QUEUE_ENV = "DLROVER_TPU_FLYWHEEL_LEND_QUEUE"
 FLYWHEEL_RECLAIM_QUEUE_ENV = "DLROVER_TPU_FLYWHEEL_RECLAIM_QUEUE"
 FLYWHEEL_MIN_TRAIN_ENV = "DLROVER_TPU_FLYWHEEL_MIN_TRAIN_WORLD"
@@ -528,16 +413,6 @@ def flywheel_publish_every() -> int:
     """K: the trainer publishes policy (and draft) weights into the
     shm snapshot segment every K optimizer steps (>= 1)."""
     return max(1, int(env_float(FLYWHEEL_PUBLISH_EVERY_ENV, 4)))
-
-
-def flywheel_draft_enabled() -> bool:
-    """Whether the flywheel trains + publishes a separate small DRAFT
-    model for K-step speculative decode (the PR-14 residual; today
-    the model drafts with itself).  Inert unless the serving factory
-    supplies draft-model parts.  Default: enabled."""
-    return os.getenv(FLYWHEEL_DRAFT_ENV, "1").lower() not in (
-        "0", "false", "off",
-    )
 
 
 def flywheel_lend_queue_depth() -> float:

@@ -82,9 +82,9 @@ class Envelope(Message):
     pair: the epoch identifies the JOB generation (stable across
     master restarts of the same job; bumped when the job itself is
     reborn), the incarnation identifies the serving MASTER process
-    (bumped on every master start).  ``-1`` = "not speaking the
-    fencing protocol" (old clients, or failover kill-switched) and is
-    never fenced."""
+    (bumped on every master start).  ``-1`` = "has not learned the
+    pair yet" (a client before its first refresh) and is never
+    fenced."""
 
     node_id: int = 0
     node_type: str = ""
@@ -573,7 +573,7 @@ class JobStatusResponse(Message):
     #: {"health": HealthEngine.snapshot(), "ledger": ...,
     #:  "conclusions": [...], "speed": {...}, "epoch": {...}}
     status: Dict = field(default_factory=dict)
-    available: bool = False  # False = observatory off / absent
+    available: bool = False  # False = no health engine behind it
 
 
 @dataclass

@@ -9,9 +9,8 @@ parameters take effect without restarting training.
 The loader is **pipelined**: a bounded producer pool (size =
 ``num_workers``, also tuned live through the config file) runs
 ``read_batch`` in the background so batch k+1 is being fetched while
-batch k is consumed.  Batches are yielded strictly in the serial
-order; ``DLROVER_TPU_INPUT_PIPELINE=0`` (or ``pipeline=False``) is
-the byte-identical serial fallback.  ``state_dict`` always reports
+batch k is consumed.  Batches are yielded strictly in the sampler's
+order.  ``state_dict`` always reports
 the sampler position of the last batch actually *yielded* — the
 loader's own producer read-ahead can never over-advance a mid-epoch
 checkpoint.  Batches the CONSUMER buffers after the yield (e.g.
@@ -30,7 +29,6 @@ from typing import Callable, Iterator, Optional
 
 import numpy as np
 
-from dlrover_tpu.common.env import input_pipeline_enabled
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.data.prefetch import _ThroughputMeter, batch_nbytes
 from dlrover_tpu.trainer.elastic.sampler import (
@@ -102,12 +100,10 @@ class ElasticDataLoader:
     changes take effect on the next epoch (matching the reference's
     ``load_config``-on-init + set_batch_size semantics).
 
-    With the pipeline enabled (default; kill-switch
-    ``DLROVER_TPU_INPUT_PIPELINE=0``) a producer pool of
-    ``num_workers`` threads runs ``read_batch`` up to
-    ``prefetch_depth`` batches ahead.  Batches are yielded in exactly
-    the serial order, so the pipelined and serial paths are
-    byte-identical for a deterministic ``read_batch``.  With
+    A producer pool of ``num_workers`` threads runs ``read_batch`` up
+    to ``prefetch_depth`` batches ahead.  Batches are yielded in
+    exactly the order the sampler drew them, whatever the pool's
+    width, for a deterministic ``read_batch``.  With
     ``num_workers > 1``, ``read_batch`` must be thread-safe (calls for
     different index batches run concurrently).
     """
@@ -125,13 +121,11 @@ class ElasticDataLoader:
         drop_last: bool = True,
         num_workers: int = 1,
         prefetch_depth: int = 2,
-        pipeline: Optional[bool] = None,
     ):
         self._read_batch = read_batch
         self.batch_size = batch_size
         self.num_workers = max(1, int(num_workers))
         self._prefetch_depth = max(1, int(prefetch_depth))
-        self._pipeline = pipeline
         self._config_file = config_file or os.getenv(
             "DLROVER_TPU_PARAL_CONFIG_FILE", DEFAULT_CONFIG_FILE
         )
@@ -147,11 +141,6 @@ class ElasticDataLoader:
         # advanced further by producer read-ahead)
         self._consumed_state: Optional[dict] = None
         self.load_config()
-
-    def _pipeline_on(self) -> bool:
-        if self._pipeline is not None:
-            return bool(self._pipeline)
-        return input_pipeline_enabled()
 
     def load_config(self):
         if not os.path.exists(self._config_file):
@@ -183,8 +172,8 @@ class ElasticDataLoader:
 
     # ------------------------------------------------------- iteration
     def _index_batches(self):
-        """Yield ``(indices, sampler_state_after_draw)`` in the serial
-        batch order — the single source of ordering for both paths."""
+        """Yield ``(indices, sampler_state_after_draw)`` in the
+        sampler's batch order — the single source of ordering."""
         batch = []
         for idx in self.sampler:
             batch.append(idx)
@@ -193,12 +182,6 @@ class ElasticDataLoader:
                 batch = []
         if batch and not self._drop_last:
             yield np.asarray(batch), self.sampler.state_dict()
-
-    def _iter_serial(self) -> Iterator:
-        for indices, watermark in self._index_batches():
-            out = self._read_batch(indices)
-            self._consumed_state = watermark
-            yield out
 
     def _iter_pipelined(self) -> Iterator:
         from concurrent.futures import ThreadPoolExecutor
@@ -241,9 +224,7 @@ class ElasticDataLoader:
 
     def __iter__(self) -> Iterator:
         self.load_config()
-        if self._pipeline_on():
-            return self._iter_pipelined()
-        return self._iter_serial()
+        return self._iter_pipelined()
 
     def __len__(self) -> int:
         n = len(self.sampler)

@@ -65,7 +65,10 @@ class _DecisionLoop:
         self._stopped = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.cycle_errors = 0
-        self._last_error_log = 0.0
+        # -inf, not 0: the monotonic clock counts from boot, and a
+        # master on a machine younger than the cooldown must still
+        # write its FIRST failure's traceback
+        self._last_error_log = float("-inf")
 
     def _cycle(self):
         raise NotImplementedError

@@ -19,7 +19,7 @@ Three recorders:
   — the diagnosis/audit trail
 
 High-rate writes (speed samples, node events, timeline batches) are
-WRITE-BEHIND by default: recorders enqueue rows on a bounded
+WRITE-BEHIND: recorders enqueue rows on a bounded
 in-memory queue and a single background flusher drains them with
 per-table ``executemany`` + ONE commit per batch — a timeline burst
 costs one fsync instead of one per event, and the report RPC path
@@ -28,9 +28,7 @@ read-your-writes semantics are preserved exactly.  Strategy
 measurements stay synchronous: they are one row per calibration step
 and a concurrently-live neighbour master may read the shared file the
 moment the recorder returns.  ``close()`` drains
-everything and checkpoints the WAL (fsync'd durability), and
-``DLROVER_TPU_DATASTORE_SYNC=1`` restores the old synchronous
-INSERT+commit-per-write behavior byte-for-byte (pinned by tests).
+everything and checkpoints the WAL (fsync'd durability).
 One lock serializes the shared connection (sqlite's own locking is
 per-process anyway).
 """
@@ -42,7 +40,6 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-from dlrover_tpu.common.env import datastore_sync_enabled
 from dlrover_tpu.common.log import default_logger as logger
 
 _SCHEMA = """
@@ -157,11 +154,8 @@ class BrainDatastore:
     #: rows instead of growing memory without bound
     MAX_PENDING = 10_000
 
-    def __init__(self, db_path: str, sync: Optional[bool] = None):
+    def __init__(self, db_path: str):
         self.path = db_path
-        self._sync = (
-            datastore_sync_enabled() if sync is None else bool(sync)
-        )
         # write-behind state (all guarded by _wb_cond; _enqueued /
         # _flushed count ROWS so a drain barrier is a counter compare)
         self._wb_cond = threading.Condition()
@@ -175,7 +169,13 @@ class BrainDatastore:
         #: rows its predecessor landed
         self._journal_seq: Dict[str, int] = {}
         self._journal_seq_lock = threading.Lock()
-        self._flusher: Optional[threading.Thread] = None
+        # built here, started last: the startup prune below passes
+        # through `_drain`, which names it
+        self._flusher = threading.Thread(
+            target=self._flusher_loop,
+            name="brain-write-behind",
+            daemon=True,
+        )
         parent = os.path.dirname(os.path.abspath(db_path))
         os.makedirs(parent, exist_ok=True)
         self._lock = threading.Lock()
@@ -240,33 +240,14 @@ class BrainDatastore:
                         "would delete other jobs' history)",
                         env_age,
                     )
-        if not self._sync:
-            self._flusher = threading.Thread(
-                target=self._flusher_loop,
-                name="brain-write-behind",
-                daemon=True,
-            )
-            self._flusher.start()
+        self._flusher.start()
 
     # ----------------------------------------------- write-behind core
     def _submit(self, sql: str, rows: List[tuple]):
-        """Record rows: synchronous INSERT+commit under
-        ``DLROVER_TPU_DATASTORE_SYNC=1`` (the pre-write-behind
-        behavior), else enqueue for the background flusher."""
+        """Record rows: enqueue for the background flusher."""
         if not rows:
             return
-        if self._sync:
-            with self._lock:
-                self._conn.executemany(sql, rows)
-                self._conn.commit()
-            return
         with self._wb_cond:
-
-            def _flusher_alive():
-                return (
-                    self._flusher is not None
-                    and self._flusher.is_alive()
-                )
 
             # bounded queue: backpressure instead of unbounded memory;
             # the flusher drains fast enough that this only trips on a
@@ -274,10 +255,10 @@ class BrainDatastore:
             while (
                 len(self._pending) >= self.MAX_PENDING
                 and not self._closed
-                and _flusher_alive()
+                and self._flusher.is_alive()
             ):
                 self._wb_cond.wait(0.05)
-            if not self._closed and _flusher_alive():
+            if not self._closed and self._flusher.is_alive():
                 self._pending.extend((sql, row) for row in rows)
                 self._enqueued += len(rows)
                 self._wb_cond.notify_all()
@@ -360,24 +341,18 @@ class BrainDatastore:
         Cheap (one lock hold, no sqlite); safe to call per scrape."""
         with self._wb_cond:
             return {
-                "sync": self._sync,
                 "queue_depth": len(self._pending),
                 "queue_cap": self.MAX_PENDING,
                 "enqueued_rows": self._enqueued,
                 "flushed_rows": self._flushed,
                 "lag_rows": max(self._enqueued - self._flushed, 0),
-                "flusher_alive": bool(
-                    self._flusher is not None
-                    and self._flusher.is_alive()
-                ),
+                "flusher_alive": self._flusher.is_alive(),
             }
 
     def _drain(self):
         """Barrier: block until every row enqueued so far is
         committed — readers call this first, preserving exact
         read-your-writes semantics over the async queue."""
-        if self._sync:
-            return
         if threading.current_thread() is self._flusher:
             return  # the flusher itself must never self-deadlock
         with self._wb_cond:
@@ -386,10 +361,7 @@ class BrainDatastore:
             self._wb_cond.notify_all()  # cut the flusher's linger short
             try:
                 while self._flushed < target:
-                    if (
-                        self._flusher is None
-                        or not self._flusher.is_alive()
-                    ):
+                    if not self._flusher.is_alive():
                         break  # dead flusher must not hang readers
                     self._wb_cond.wait(0.05)
             finally:
@@ -408,7 +380,7 @@ class BrainDatastore:
         learn from any other job's calibration through a shared db
         file — the cluster-wide role of the reference's Brain.
 
-        Deliberately SYNCHRONOUS even in write-behind mode: a
+        Deliberately SYNCHRONOUS beside the write-behind queue: a
         concurrently-live neighbour master reads this file directly,
         so a measurement must be committed (not parked in this
         process's queue) the moment the recorder returns — and the
@@ -929,12 +901,10 @@ class BrainDatastore:
         """Drain the write-behind queue (zero rows lost — pinned by
         tests), checkpoint the WAL so the bytes are fsync'd into the
         main db file, then close."""
-        if not self._sync:
-            with self._wb_cond:
-                self._closed = True
-                self._wb_cond.notify_all()
-            if self._flusher is not None:
-                self._flusher.join(timeout=10.0)
+        with self._wb_cond:
+            self._closed = True
+            self._wb_cond.notify_all()
+        self._flusher.join(timeout=10.0)
         with self._lock:
             try:
                 self._conn.commit()

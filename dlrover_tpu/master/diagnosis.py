@@ -435,15 +435,15 @@ class DiagnosisManager:
         capture=None,
         master_health=None,
     ):
-        """With a ``health_engine`` (the observatory is on) the chain
-        sits ON TOP of the streaming derivations: straggler /
+        """With a ``health_engine`` (the master always passes one) the
+        chain sits ON TOP of the streaming derivations: straggler /
         data-stall / per-node hang operators join the log-pattern
         operators, and the SpeedMonitor hang rule is subsumed by the
         span-heartbeat watchdog.  Conclusions are then recorded as
         ``diagnosis`` instants on the timeline and persisted to the
         Brain ``node_events`` table (``datastore``) so they survive
-        master failover.  Without an engine the manager is exactly
-        the pre-observatory one."""
+        master failover.  Without an engine the manager runs the
+        log-pattern operators and the SpeedMonitor hang rule alone."""
         self.store = DiagnosisDataStore()
         self._cooldown = conclusion_cooldown
         self._emitted: Dict = {}
@@ -507,7 +507,7 @@ class DiagnosisManager:
         trail survives master failover.  Best-effort: recording must
         never block or break the diagnose loop."""
         if self._health is None:
-            return  # observatory off: today's (unrecorded) behavior
+            return  # no engine: conclusions stay unrecorded
         from dlrover_tpu.observability.events import get_event_logger
 
         try:

@@ -25,8 +25,7 @@ for rendezvous/tasks/nodes, result-valued sets for KV), so the
 snapshot seq only needs to be a low-water mark: replaying an entry
 the snapshot already contains is a no-op.
 
-The whole subsystem is kill-switched by ``DLROVER_TPU_MASTER_FAILOVER=0``
-and inert when no Brain db is configured.
+The whole subsystem is inert when no Brain db is configured.
 """
 
 import threading

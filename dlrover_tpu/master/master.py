@@ -46,27 +46,28 @@ class JobMaster:
         from dlrover_tpu.common.env import (
             brain_enabled,
             master_workers,
-            observatory_enabled,
-            self_obs_enabled,
+            profile_enabled,
         )
         from dlrover_tpu.master.datastore import get_default_datastore
         from dlrover_tpu.observability.events import TimelineAggregator
+        from dlrover_tpu.observability.health import (
+            HealthEngine,
+            MasterHealth,
+        )
         from dlrover_tpu.observability.metrics import get_registry
+        from dlrover_tpu.observability.self_telemetry import (
+            MasterSelfTelemetry,
+        )
 
         self._job_name = os.getenv("DLROVER_TPU_JOB_NAME", "default")
         self.speed_monitor = SpeedMonitor()
         # the observatory: streaming per-node health derivations over
-        # the incoming timeline batches + agent reports.  None under
-        # the DLROVER_TPU_OBSERVATORY=0 kill-switch — every consumer
-        # (diagnosis operators, JobStatusRequest, status server,
-        # gauges) degrades to the pre-observatory behavior exactly.
-        self.health_engine = None
-        if observatory_enabled():
-            from dlrover_tpu.observability.health import HealthEngine
-
-            self.health_engine = HealthEngine(
-                job=self._job_name, registry=get_registry()
-            )
+        # the incoming timeline batches + agent reports, read by the
+        # diagnosis operators, JobStatusRequest, the status server and
+        # the gauges
+        self.health_engine = HealthEngine(
+            job=self._job_name, registry=get_registry()
+        )
         # unified job-event timeline: per-node streams merge here, the
         # goodput ledger is served live (get-RPC + exporter gauges) and
         # durably (sqlite datastore when configured); the health
@@ -77,23 +78,18 @@ class JobMaster:
             datastore=get_default_datastore(),
             health=self.health_engine,
         )
-        # the deep-capture arm (None = DLROVER_TPU_PROFILE=0 or
-        # observatory off): diagnosis-triggered captures ride the
-        # directive piggyback, results land in the Brain `profiles`
-        # table and the JobStatus snapshot
+        # the deep-capture arm (None = DLROVER_TPU_PROFILE=0):
+        # diagnosis-triggered captures ride the directive piggyback,
+        # results land in the Brain `profiles` table and the JobStatus
+        # snapshot
         self.capture_coordinator = None
-        if self.health_engine is not None:
-            from dlrover_tpu.common.env import profile_enabled
+        if profile_enabled():
+            from dlrover_tpu.master.capture import CaptureCoordinator
 
-            if profile_enabled():
-                from dlrover_tpu.master.capture import (
-                    CaptureCoordinator,
-                )
-
-                self.capture_coordinator = CaptureCoordinator(
-                    job=self._job_name,
-                    datastore=get_default_datastore(),
-                )
+            self.capture_coordinator = CaptureCoordinator(
+                job=self._job_name,
+                datastore=get_default_datastore(),
+            )
         self.task_manager = TaskManager(speed_monitor=self.speed_monitor)
         self.rdzv_managers = {
             RendezvousName.ELASTIC_TRAINING:
@@ -104,36 +100,25 @@ class JobMaster:
         self.job_manager = job_manager
         # control-plane SELF-telemetry: the master watching itself
         # (per-RPC-kind latency histograms, pool occupancy, state
-        # growth, journal lag) + the MasterHealth overload deriver.
-        # None under DLROVER_TPU_SELF_OBS=0 — the pre-self-obs metric
-        # surface exactly (pinned by tests).
-        self.master_telemetry = None
-        self.master_health = None
-        if self_obs_enabled():
-            from dlrover_tpu.observability.health import MasterHealth
-            from dlrover_tpu.observability.self_telemetry import (
-                MasterSelfTelemetry,
-            )
-
-            self.master_telemetry = MasterSelfTelemetry(
-                registry=get_registry(),
-                pool_size=master_workers(),
-            )
-            self.master_telemetry.attach(
-                kv_store=self.kv_store,
-                rdzv_managers=self.rdzv_managers,
-                task_manager=self.task_manager,
-                timeline_aggregator=self.timeline_aggregator,
-                datastore=get_default_datastore(),
-            )
-            self.master_health = MasterHealth(self.master_telemetry)
+        # growth, journal lag) + the MasterHealth overload deriver
+        self.master_telemetry = MasterSelfTelemetry(
+            registry=get_registry(),
+            pool_size=master_workers(),
+        )
+        self.master_telemetry.attach(
+            kv_store=self.kv_store,
+            rdzv_managers=self.rdzv_managers,
+            task_manager=self.task_manager,
+            timeline_aggregator=self.timeline_aggregator,
+            datastore=get_default_datastore(),
+        )
+        self.master_health = MasterHealth(self.master_telemetry)
         if diagnosis_manager is None:
             from dlrover_tpu.master.diagnosis import DiagnosisManager
 
-            # with the observatory on, the chain sits on top of the
-            # streaming derivations (straggler / data-stall / hang
-            # watchdog operators) and records conclusions to the
-            # timeline + Brain; off, it is exactly the old manager
+            # the chain sits on top of the streaming derivations
+            # (straggler / data-stall / hang watchdog operators) and
+            # records conclusions to the timeline + Brain
             diagnosis_manager = DiagnosisManager(
                 speed_monitor=self.speed_monitor,
                 health_engine=self.health_engine,
@@ -146,12 +131,11 @@ class JobMaster:
         # the autonomy loop (ROADMAP item 1): observatory signals ->
         # hysteresis-guarded BrainDecision -> ONE planned action
         # (cooperative drain directive + fence + reshard re-mesh, or
-        # a scaler plan).  None under DLROVER_TPU_BRAIN=0 or with the
-        # observatory off — the seed AllreduceAutoScaler (distributed
-        # masters with a scaler) is then the only scaling loop,
-        # exactly as before.
+        # a scaler plan).  None under DLROVER_TPU_BRAIN=0 — the seed
+        # AllreduceAutoScaler (distributed masters with a scaler) is
+        # then the only scaling loop.
         self.brain = None
-        if brain_enabled() and self.health_engine is not None:
+        if brain_enabled():
             from dlrover_tpu.master.auto_scaler import BrainAutoScaler
             from dlrover_tpu.master.brain import (
                 BrainExecutor,
@@ -186,8 +170,8 @@ class JobMaster:
         #: epoch 0 / incarnation 0 = no durability, fencing inert)
         self.job_epoch = 0
         self.incarnation = 0
-        #: durable control-plane journal (None = failover disabled or
-        #: no Brain db — today's memory-only behavior exactly)
+        #: durable control-plane journal (None = no Brain db: the
+        #: control plane's state lives in memory alone)
         self.control_journal = None
 
         self.job_manager.add_node_event_callback(
@@ -210,11 +194,8 @@ class JobMaster:
         replays snapshot+journal into the components, then attaches
         the journal hooks — all BEFORE the gRPC server opens, so the
         first reconnecting agent sees the resumed state."""
-        from dlrover_tpu.common.env import master_failover_enabled
         from dlrover_tpu.master.datastore import get_default_datastore
 
-        if not master_failover_enabled():
-            return
         store = get_default_datastore()
         if store is None:
             return
@@ -251,10 +232,7 @@ class JobMaster:
 
     def prepare(self):
         self._setup_failover()
-        if (
-            self.master_telemetry is not None
-            and self.control_journal is not None
-        ):
+        if self.control_journal is not None:
             # the journal only exists once failover setup ran; its
             # snapshot age/duration joins the self-telemetry sweep
             self.master_telemetry.attach(
@@ -290,8 +268,7 @@ class JobMaster:
     def _start_status_server(self, servicer):
         """Plain-HTTP ``/metrics`` (Prometheus text) + ``/status``
         (the JobStatusRequest snapshot as JSON).  Off by default:
-        needs ``--status_port`` (``DLROVER_TPU_STATUS_PORT``) AND the
-        observatory on."""
+        needs ``--status_port`` (``DLROVER_TPU_STATUS_PORT``)."""
         import os
 
         raw = os.getenv("DLROVER_TPU_STATUS_PORT", "")
@@ -305,12 +282,6 @@ class JobMaster:
             )
             return
         if port < 0:
-            return
-        if self.health_engine is None:
-            logger.info(
-                "status port requested but observatory is off "
-                "(DLROVER_TPU_OBSERVATORY=0); not serving"
-            )
             return
         from dlrover_tpu.common import messages as msg
         from dlrover_tpu.observability.metrics import get_registry

@@ -17,10 +17,7 @@ from dlrover_tpu.common.constants import (
     TrainingExceptionLevel,
     TrainingLoopStatus,
 )
-from dlrover_tpu.common.env import (
-    master_failover_enabled,
-    master_workers,
-)
+from dlrover_tpu.common.env import master_workers
 from dlrover_tpu.common.fault_injection import maybe_crash
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.observability.metrics import record_control_rpc
@@ -68,16 +65,16 @@ class MasterServicer:
         self._diagnosis_manager = diagnosis_manager
         self._sync_service = sync_service
         self._timeline_aggregator = timeline_aggregator
-        #: the observatory's streaming derivation engine (None =
-        #: DLROVER_TPU_OBSERVATORY=0 or a pre-observatory master);
+        #: the observatory's streaming derivation engine (None = a
+        #: servicer built without one: no status surface);
         #: heartbeats / steps / failures / resource reports tap it
         self._health_engine = health_engine
         #: the Brain auto-scaler (None = DLROVER_TPU_BRAIN=0):
         #: node directives ride the WaitingNodeNum response and its
         #: decision state joins the JobStatus snapshot
         self._brain = brain
-        #: the deep-capture coordinator (None = DLROVER_TPU_PROFILE=0
-        #: or observatory off): capture directives ride the SAME
+        #: the deep-capture coordinator (None =
+        #: DLROVER_TPU_PROFILE=0): capture directives ride the SAME
         #: WaitingNodeNum piggyback (a Brain drain outranks them) and
         #: the latest capture per node joins the JobStatus snapshot
         self._capture = capture_coordinator
@@ -85,8 +82,8 @@ class MasterServicer:
         #: lifetime RPC tally (gets + reports, batched items counted
         #: once per envelope) — the bench's server-side ground truth
         self.rpc_count = 0
-        #: self-telemetry collector (None = DLROVER_TPU_SELF_OBS=0 or
-        #: a pre-self-obs caller): per-RPC-kind latency/size
+        #: self-telemetry collector (None = a servicer built without
+        #: one): per-RPC-kind latency/size
         #: histograms, in-flight/parked gauges, the ``master`` status
         #: section
         self._telemetry = telemetry
@@ -129,10 +126,8 @@ class MasterServicer:
 
     def _fenced(self, envelope: msg.Envelope) -> Optional[msg.StaleEpoch]:
         """Typed fencing answer when the request's job_epoch doesn't
-        match this master's.  ``-1`` (old clients / kill-switched
-        failover) is never fenced."""
-        if not master_failover_enabled():
-            return None
+        match this master's.  ``-1`` (a client that has not learned
+        the pair yet) is never fenced."""
         epoch = getattr(envelope, "job_epoch", -1)
         if epoch is None or epoch < 0 or epoch == self.job_epoch:
             return None
@@ -143,7 +138,7 @@ class MasterServicer:
     @staticmethod
     def _response_bytes(response) -> Optional[int]:
         """Wire size of one response (None when there is none).  The
-        extra serialize only runs with self-obs ON and control
+        extra serialize only runs with a telemetry collector and control
         responses are small pickles — the histogram is worth the
         double-encode; a failure must not break the RPC."""
         if response is None:
@@ -277,8 +272,8 @@ class MasterServicer:
     ) -> msg.JobStatusResponse:
         """The observatory snapshot: streaming health derivations +
         the live goodput ledger + the newest diagnosis conclusions.
-        ``available=False`` when the observatory is off (kill-switch)
-        — the pre-observatory master had no such surface."""
+        ``available=False`` from a servicer built without a health
+        engine."""
         if self._health_engine is None:
             return msg.JobStatusResponse(available=False)
         status = {"health": self._health_engine.snapshot()}
@@ -317,7 +312,6 @@ class MasterServicer:
         if self._telemetry is not None:
             # the control plane's own vitals: RPC latency per kind,
             # pool occupancy, state growth, journal/datastore health
-            # (absent under DLROVER_TPU_SELF_OBS=0 — pinned)
             try:
                 status["master"] = self._telemetry.snapshot()
             except Exception as e:  # noqa: BLE001 - partial status
@@ -547,7 +541,7 @@ class MasterServicer:
             )
 
     def _report_dispatch(self, envelope: msg.Envelope):
-        """Fence FIRST, deserialize second (the pre-self-obs order):
+        """Fence FIRST, deserialize second:
         a stale client must get its typed ``StaleEpoch`` even when
         its payload no longer unpickles across a rolling upgrade, and
         a fenced request must not pay deserialization.  Returns

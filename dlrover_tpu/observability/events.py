@@ -160,7 +160,7 @@ PHASE_SERVE_REQUEST = "serve_request"
 # attributable from the timeline alone).
 PHASE_KV_SHIP = "kv_ship"
 # client-side control-plane wait (a long-poll RPC parked on the
-# master, or the legacy polling loop it replaces).  LOWEST priority:
+# master).  LOWEST priority:
 # these waits are almost always nested inside rendezvous/restart
 # spans, which keep the attribution; a standalone control_wait still
 # surfaces as its own loss bucket instead of vanishing into
@@ -663,13 +663,8 @@ class EventLogger:
         safe: a writer whose fd no longer matches the path (someone
         else already rotated) just follows to the new file instead of
         rotating the fresh file away."""
-        from dlrover_tpu.common.env import (
-            events_max_bytes,
-            observatory_enabled,
-        )
+        from dlrover_tpu.common.env import events_max_bytes
 
-        if not observatory_enabled():
-            return  # kill-switch: unbounded growth, exactly as before
         max_bytes = events_max_bytes()
         if max_bytes <= 0:
             return
@@ -1256,12 +1251,7 @@ class TimelineAggregator:
     def _maybe_sweep_retention(self):
         """Throttled Brain ``timeline_events`` retention sweep — the
         durable timeline must not grow without bound on a week-long
-        job (behind the observatory kill-switch like the rest of the
-        growth bounds)."""
-        from dlrover_tpu.common.env import observatory_enabled
-
-        if not observatory_enabled():
-            return
+        job."""
         now = time.monotonic()
         if now - self._last_retention_sweep < self.RETENTION_SWEEP_S:
             return

@@ -40,9 +40,7 @@ in ``master/diagnosis.py``; the full derived snapshot is served by the
 ``JobStatusRequest`` RPC, the ``--status_port`` HTTP endpoints
 (``observability/status_server.py``) and ``scripts/top.py``.  Gauges
 ``dlrover_tpu_node_health{node}`` / ``dlrover_tpu_straggler_score{node}``
-mirror the snapshot for Prometheus.  Everything here is behind the
-``DLROVER_TPU_OBSERVATORY=0`` kill-switch (the master simply never
-constructs an engine).
+mirror the snapshot for Prometheus.
 """
 
 import json

@@ -560,14 +560,8 @@ def record_datastore_flush(rows: int, seconds: float):
     the ``dlrover_tpu_datastore_flush_seconds`` histogram and the
     batch size the ``dlrover_tpu_datastore_flush_rows`` histogram —
     the tail of this distribution is the journal's durability lag
-    under load.  Gated by ``DLROVER_TPU_SELF_OBS=0`` (the pre-self-obs
-    metric surface must stay exact).  Never raises — telemetry must
-    not break a flush."""
-    from dlrover_tpu.common.env import self_obs_enabled
-
+    under load.  Never raises — telemetry must not break a flush."""
     try:
-        if not self_obs_enabled():
-            return
         reg = get_registry()
         reg.observe_histogram(
             "dlrover_tpu_datastore_flush_seconds", seconds

@@ -37,11 +37,6 @@ The derived verdict lives in ``observability/health.py``
 :class:`~dlrover_tpu.observability.health.MasterHealth` — sustained
 p99 / queue-near-bound / journal-lag / pool-saturation streaks become
 a ``master_overload`` diagnosis conclusion + instant.
-
-Everything is behind ``DLROVER_TPU_SELF_OBS=0`` (the master simply
-never constructs a collector; the flush-latency record function gates
-itself), which reproduces the pre-self-obs metric surface exactly —
-pinned by ``tests/test_self_obs.py``.
 """
 
 import threading

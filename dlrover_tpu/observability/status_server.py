@@ -18,7 +18,7 @@ standard library, threaded, daemonized):
 - anything else — 404.
 
 Off by default: the master only starts it when ``--status_port`` is
-given AND the observatory kill-switch is on.
+given.
 """
 
 import json

@@ -44,10 +44,7 @@ from dlrover_tpu.agent.ckpt_shm import (
     shard_lock,
     stream_shard_leaves,
 )
-from dlrover_tpu.common.env import (
-    ckpt_close_timeout_s,
-    reshard_enabled,
-)
+from dlrover_tpu.common.env import ckpt_close_timeout_s
 from dlrover_tpu.trainer.checkpoint import reshard as _reshard
 
 
@@ -458,8 +455,6 @@ class CheckpointEngine:
         """
         if not self.snapshot_slot_free(step):
             return False
-        if not reshard_enabled():
-            layouts = None  # kill-switch: today's format, byte for byte
         if blocking:
             return self._drain_snapshot(step, state, None, layouts)
         return self._launch_async_snapshot(step, state, None, layouts)
@@ -593,8 +588,6 @@ class CheckpointEngine:
         # drain thread enqueues it
         if not self.snapshot_slot_free(step):
             return False
-        if not reshard_enabled():
-            layouts = None  # kill-switch: today's format, byte for byte
         return self._launch_async_snapshot(
             step, state, target_dir, layouts
         )
@@ -616,8 +609,7 @@ class CheckpointEngine:
         ``layouts`` describes the per-leaf global slices THIS rank
         wants on the (possibly new) world; when the stored shards'
         placement differs, the restore reassembles each leaf from
-        whichever shards cover its new slices (reshard leg, gated by
-        ``DLROVER_TPU_RESHARD``).
+        whichever shards cover its new slices (reshard leg).
 
         Returns (step, state) where state is ``target``-shaped if a
         target pytree was given, else {keypath: ndarray}; (-1, None)
@@ -995,9 +987,6 @@ class CheckpointEngine:
         return self._read_storage_step_dir(path, layouts)
 
     # -- reshard ------------------------------------------------------------
-    def _reshard_active(self, layouts) -> bool:
-        return bool(layouts) and reshard_enabled()
-
     def _usable_shm_steps(self, layouts=None):
         """Steps restorable from THIS rank's shm segment under the
         requested layouts.  After a world change the segment may hold
@@ -1006,10 +995,9 @@ class CheckpointEngine:
         mis-sharded state.  A slot is usable when its layout header
         matches the request, or (headerless legacy slot) when every
         spec's local shape matches the requested local shape.  Without
-        requested layouts (or with the reshard kill-switch off) this
-        is exactly ``steps_available()`` — today's behavior."""
+        requested layouts this is exactly ``steps_available()``."""
         steps = self._shm_handler.steps_available()
-        if not self._reshard_active(layouts):
+        if not layouts:
             return steps
         usable = []
         for step in steps:
@@ -1045,7 +1033,7 @@ class CheckpointEngine:
         the resharded overlap-range read otherwise."""
         if ckpt_path is None:
             return -1, {}
-        if not self._reshard_active(layouts):
+        if not layouts:
             return self._read_storage_shard(ckpt_path)
         step, arrays = -1, {}
         try:
@@ -1100,8 +1088,8 @@ class CheckpointEngine:
         direct = os.path.join(
             ckpt_dir, f"shard_{self._rank}.drckpt"
         )
-        if not self._reshard_active(layouts) or (
-            self._direct_shard_compatible(ckpt_dir, layouts)
+        if not layouts or self._direct_shard_compatible(
+            ckpt_dir, layouts
         ):
             yield from stream_shard_leaves(direct, self._storage)
             return

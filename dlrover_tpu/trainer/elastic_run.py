@@ -28,9 +28,9 @@ from dlrover_tpu.agent.training import (
     ElasticLaunchConfig,
     launch_agent,
 )
-from dlrover_tpu.common.comm import addr_connectable, wait_channel_ready
+from dlrover_tpu.common.comm import wait_channel_ready
 from dlrover_tpu.common.constants import NodeEnv
-from dlrover_tpu.common.env import control_longpoll_enabled, get_free_port
+from dlrover_tpu.common.env import get_free_port
 from dlrover_tpu.common.jax_env import compile_cache_dir, platform_from_env
 from dlrover_tpu.common.log import default_logger as logger
 
@@ -85,14 +85,6 @@ def parse_args(argv=None):
         action="store_true",
         help="fork restarted workers from a pre-imported zygote "
         "(removes the Python/jax import chain from restart latency)",
-    )
-    parser.add_argument(
-        "--no_restart_overlap",
-        action="store_true",
-        help="disable the overlapped restart critical path (restore "
-        "prefetch + background AOT compile; trainer/restart_path.py) "
-        "— workers then run the serial restore->compile order "
-        "(exports DLROVER_TPU_RESTART_OVERLAP=0)",
     )
     parser.add_argument(
         "--network-check",
@@ -198,21 +190,6 @@ def _launch_local_master(node_num: int) -> Tuple[subprocess.Popen, str]:
     return proc, addr
 
 
-def _wait_master(addr: str, timeout: float = 60.0) -> bool:
-    """Wait for the master's gRPC port to come up.  Default: park on
-    grpc's channel-ready future (its own reconnect backoff drives the
-    probing); ``DLROVER_TPU_CONTROL_LONGPOLL=0`` restores the 0.5 s
-    TCP-connect polling loop."""
-    if control_longpoll_enabled():
-        return wait_channel_ready(addr, timeout=timeout)
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if addr_connectable(addr):
-            return True
-        time.sleep(0.5)
-    return False
-
-
 def _build_entrypoint(args) -> List[str]:
     script_args = list(args.training_script_args)
     if args.module:
@@ -290,7 +267,9 @@ def run(args) -> int:
             )
         master_proc, master_addr = _launch_local_master(max_nodes)
         logger.info("launched local master at %s", master_addr)
-    if not _wait_master(master_addr):
+    # park on grpc's channel-ready future: its own reconnect backoff
+    # drives the probing
+    if not wait_channel_ready(master_addr, timeout=60.0):
         raise SystemExit(f"master at {master_addr} is unreachable")
 
     os.environ[NodeEnv.MASTER_ADDR] = master_addr
@@ -311,7 +290,6 @@ def run(args) -> int:
         prefork=args.prefork,
         node_rank=node_rank,
         compile_cache_dir=args.compile_cache_dir,
-        restart_overlap=not args.no_restart_overlap,
     )
     from dlrover_tpu.observability.events import get_event_logger
 

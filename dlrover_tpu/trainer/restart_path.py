@@ -26,16 +26,15 @@ This module sequences them so the restart costs
 3. :meth:`resolve_train_step` hands the first step the compiled
    artifact instead of a cold trace.
 
-Degradation contract: ``DLROVER_TPU_RESTART_OVERLAP=0`` — or ANY leg
-thread failing — reproduces today's serial order with byte-identical
-restored state.  The legs emit ``restart_path`` child spans
+Degradation contract: ANY leg failing (at launch or on its thread)
+yields the serial order with byte-identical restored state.  The legs
+emit ``restart_path`` child spans
 (``restore_prefetch`` / ``aot_compile`` / ``rendezvous_wait`` /
 ``finish_restore``) on the PR-1 timeline, so the goodput ledger shows
-the measured overlap; ``scripts/bench_restart.py`` reports serial vs
+the measured overlap; ``scripts/bench_restart.py`` reports the
 overlapped MTTR from the same machinery.
 """
 
-import os
 import threading
 import time
 from contextlib import contextmanager
@@ -46,15 +45,6 @@ from dlrover_tpu.observability.events import (
     anchored_now,
     get_event_logger,
 )
-
-#: kill-switch: "0"/"false"/"off" forces today's serial restart order
-OVERLAP_ENV = "DLROVER_TPU_RESTART_OVERLAP"
-
-
-def overlap_enabled() -> bool:
-    return os.getenv(OVERLAP_ENV, "1").strip().lower() not in (
-        "0", "false", "off",
-    )
 
 
 def _gate_for(barrier: Optional[threading.Barrier]):
@@ -136,11 +126,11 @@ class RestartCoordinator:
     then overlaps the rendezvous itself.
     """
 
-    def __init__(self, engine=None, events=None,
-                 overlap: Optional[bool] = None):
+    def __init__(self, engine=None, events=None):
         self._engine = engine
         self._events = events or get_event_logger()
-        self.overlap = overlap_enabled() if overlap is None else overlap
+        #: cleared when a leg fails to launch: the serial order follows
+        self.overlap = True
         self._prefetch = None
         self._compile_leg: Optional[_CompileLeg] = None
         self._path_sid = -1
@@ -211,7 +201,7 @@ class RestartCoordinator:
                        checkpoint_dir: Optional[str] = None,
                        layouts=None):
         """Consensus + staged-bytes application; serial ``load`` when
-        overlap is off, was never started, or any leg failed.  Returns
+        the prefetch was never started or any leg failed.  Returns
         ``(step, state)`` like ``CheckpointEngine.load``.  ``layouts``
         supersedes what ``start`` passed — a caller that only learns
         its target slices after the prefetch launched (the Trainer

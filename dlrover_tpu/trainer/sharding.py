@@ -18,10 +18,6 @@ from dlrover_tpu.agent.master_client import (
     MasterClient,
     _pace_longpoll,
 )
-from dlrover_tpu.common.env import (
-    control_longpoll_enabled,
-    input_pipeline_enabled,
-)
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.messages import DataShard, Task, TaskType
 
@@ -29,13 +25,12 @@ from dlrover_tpu.common.messages import DataShard, Task, TaskType
 class ShardingClient:
     """Fetches data-shard tasks from the master and acknowledges them.
 
-    With the input pipeline enabled (``DLROVER_TPU_INPUT_PIPELINE``,
-    default on; also ``prefetch_tasks=``), the *next* shard task is
-    requested from the master in the background the moment the current
-    one is handed out — consuming a shard completely hides the
-    ``get_task`` RPC round trip.  A prefetched-but-never-consumed task
-    is recovered master-side by the ordinary timeout/dead-worker
-    requeue, same as a shard in flight at a worker crash.
+    The *next* shard task is requested from the master in the
+    background the moment the current one is handed out — consuming a
+    shard completely hides the ``get_task`` RPC round trip.  A
+    prefetched-but-never-consumed task is recovered master-side by the
+    ordinary timeout/dead-worker requeue, same as a shard in flight at
+    a worker crash.
     """
 
     def __init__(
@@ -48,18 +43,12 @@ class ShardingClient:
         num_minibatches_per_shard: int = 2,
         client: Optional[MasterClient] = None,
         storage_type: str = "table",
-        prefetch_tasks: Optional[bool] = None,
     ):
         self._client = client or MasterClient.singleton_instance()
         self._dataset_name = dataset_name
         self._batch_size = batch_size
         self._pending: deque = deque()
         self._lock = threading.Lock()
-        self._prefetch_enabled = (
-            input_pipeline_enabled()
-            if prefetch_tasks is None
-            else bool(prefetch_tasks)
-        )
         self._prefetched: Optional[Future] = None
         self._rpc_pool: Optional[ThreadPoolExecutor] = None
         if dataset_size > 0:
@@ -87,7 +76,7 @@ class ShardingClient:
     def _kick_prefetch(self):
         """Request the NEXT task in the background so the RPC overlaps
         the consumption of the shard just handed out."""
-        if not self._prefetch_enabled or self._prefetched is not None:
+        if self._prefetched is not None:
             return
         if self._rpc_pool is None:
             self._rpc_pool = ThreadPoolExecutor(
@@ -97,29 +86,23 @@ class ShardingClient:
             self._client.get_task, self._dataset_name
         )
 
-    def fetch_shard(self, wait_interval: float = 2.0) -> Optional[DataShard]:
+    def fetch_shard(self) -> Optional[DataShard]:
         """Next shard, or None when the dataset is exhausted.  Blocks
-        through WAIT tasks (dataset not fully dispatched yet) — under
-        long-poll the master parks the RPC until a task is
-        dispatchable, so waiting out a starved dispatch queue costs
-        ~1 RPC instead of one every ``wait_interval``."""
-        longpoll = control_longpoll_enabled()
+        through WAIT tasks (dataset not fully dispatched yet): the
+        master parks the RPC until a task is dispatchable, so waiting
+        out a starved dispatch queue costs ~1 RPC."""
         while True:
             task: Task = self._next_task()
             if task.task_type == TaskType.WAIT:
-                if longpoll:
-                    t0 = time.monotonic()
-                    task = self._client.get_task(
-                        self._dataset_name, wait_timeout=30.0
-                    )
-                    if task.task_type == TaskType.WAIT:
-                        # a saturated master answers WAIT immediately
-                        # instead of parking; _pace_longpoll's shared
-                        # policy keeps the retry at the 10 Hz fallback
-                        _pace_longpoll(30.0, time.monotonic() - t0)
-                        continue
-                else:
-                    time.sleep(wait_interval)
+                t0 = time.monotonic()
+                task = self._client.get_task(
+                    self._dataset_name, wait_timeout=30.0
+                )
+                if task.task_type == TaskType.WAIT:
+                    # a saturated master answers WAIT immediately
+                    # instead of parking; _pace_longpoll's shared
+                    # policy keeps the retry at the 10 Hz fallback
+                    _pace_longpoll(30.0, time.monotonic() - t0)
                     continue
             if task.is_empty:
                 return None

@@ -239,8 +239,8 @@ class Trainer:
             )
             # restart critical path: kick the restore byte prefetch
             # NOW, so it streams while init_state traces+compiles in
-            # _init_or_restore_state; DLROVER_TPU_RESTART_OVERLAP=0
-            # (or any prefetch failure) reproduces the serial load.
+            # _init_or_restore_state; any prefetch failure falls
+            # back to the serial load.
             # After a WORLD CHANGE the target layouts are unknowable
             # until init_state shards the new state — the blind
             # prefetch would stage the OLD world's shard, so the
@@ -862,7 +862,6 @@ class Trainer:
 
     # ------------------------------------------------------------- train
     def train(self):
-        from dlrover_tpu.common.env import input_pipeline_enabled
         from dlrover_tpu.data.prefetch import device_prefetch
 
         start_step = self._init_or_restore_state()
@@ -871,11 +870,6 @@ class Trainer:
         self._hang.start()
         self._callbacks.on_train_begin(start_step)
         batch_sharding = self._fns.batch_sharding
-        # pipelined input plane: host fetch of batch k+1 runs on a
-        # background thread while batch k stages h2d and batch k-1
-        # computes; DLROVER_TPU_INPUT_PIPELINE=0 reproduces the serial
-        # fetch + inline device_put path exactly
-        pipeline_on = input_pipeline_enabled()
         step = start_step
         step_times = []
         eval_every = (
@@ -908,18 +902,16 @@ class Trainer:
             trace_t0_mono = 0.0
             trace_t0_wall = 0.0
             while step < self._args.max_steps:
-                if pipeline_on:
-                    # batches arrive device-resident, with `size`
-                    # transfers in flight and the NEXT host fetch
-                    # already running in the background
-                    epoch_iter = device_prefetch(
-                        self._data_iter_fn(),
-                        size=2,
-                        sharding=batch_sharding,
-                        pipelined=True,
-                    )
-                else:
-                    epoch_iter = self._data_iter_fn()
+                # pipelined input plane: host fetch of batch k+1 runs
+                # on a background thread while batch k stages h2d and
+                # batch k-1 computes; batches arrive device-resident,
+                # with `size` transfers in flight
+                epoch_iter = device_prefetch(
+                    self._data_iter_fn(),
+                    size=2,
+                    sharding=batch_sharding,
+                    pipelined=True,
+                )
                 for batch in epoch_iter:
                     if step >= self._args.max_steps:
                         break
@@ -980,24 +972,18 @@ class Trainer:
                         trace_t0_mono = time.monotonic()
                         trace_t0_wall = anchored_now(trace_t0_mono)
                     if self._replay is not None:
-                        # on the pipelined path `batch` is already
-                        # device-resident; the recorder's np.asarray
-                        # pulls it back — replay is an opt-in debug
-                        # mode, correctness over overlap
+                        # `batch` is already device-resident; the
+                        # recorder's np.asarray pulls it back — replay
+                        # is an opt-in debug mode, correctness over
+                        # overlap
                         self._replay.record(step + 1, batch)
-                    if pipeline_on:
-                        device_batch = batch
-                    else:
-                        device_batch = jax.device_put(
-                            batch, batch_sharding
-                        )
                     # step boundaries on an open profiler trace (the
                     # dispatch only: the loss is read a step later)
                     with jax.profiler.StepTraceAnnotation(
                         "train", step_num=step + 1
                     ):
                         self.state, metrics = self._fns.train_step(
-                            self.state, device_batch
+                            self.state, batch
                         )
                     step += 1
                     if (

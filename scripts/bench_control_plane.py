@@ -2,25 +2,18 @@
 
 Spins the REAL gRPC master servicer on localhost (KV store,
 rendezvous managers, task manager — the same components
-``LocalJobMaster`` wires) and drives it with N simulated agents, in
-two modes:
+``LocalJobMaster`` wires) and drives it with N simulated agents whose
+waits are long-polls: one RPC parks on the master's condition and
+returns the moment the state changes.
 
-- ``poll`` — the pre-fast-path reference: every "wait for X" is a
-  client loop of ``get`` RPCs at a fixed interval
-  (``DLROVER_TPU_CONTROL_LONGPOLL=0`` behavior).
-- ``longpoll`` — one RPC parks on the master's condition and returns
-  the moment the state changes.
-
-Reported per mode:
+Reported:
 
 - ``idle`` — N agents wait 5 s (budget-scaled) on a key that is never
-  set: total RPC count (client AND server side) and RPC/s.  The
-  acceptance bar is >= 10x fewer RPCs under long-poll.
+  set: total RPC count (client AND server side) and RPC/s.
 - ``wakeup`` — the key is set mid-wait: per-agent latency from ``kv
   set`` to waiter return, p50/p99.
 - ``throughput`` — N agents hammer ``kv get`` for ~1 s:
-  ``control_rps``, the sustained master RPC rate (mode-independent;
-  measured once).
+  ``control_rps``, the sustained master RPC rate.
 
 The FLEET SIMULATOR leg (``--fleet N``) is the ROADMAP item-2 proof:
 a sweep of 64..N simulated agents (threads with real ``MasterClient``
@@ -77,8 +70,6 @@ from dlrover_tpu.master.servicer import (  # noqa: E402
 )
 from dlrover_tpu.master.shard.task_manager import TaskManager  # noqa: E402
 
-POLL_INTERVAL_S = 0.2  # the reference client loop cadence
-
 
 def start_master():
     """The real servicer over real gRPC on a free localhost port;
@@ -100,7 +91,7 @@ def start_master():
     return f"127.0.0.1:{port}", servicer, server, kv
 
 
-def _run_waiters(addr, n_agents, key, wait_s, longpoll):
+def _run_waiters(addr, n_agents, key, wait_s):
     """N agents waiting on ``key``; returns (clients, results) where
     results[i] is the waiter's return wall time or None on timeout."""
     clients = [
@@ -111,12 +102,7 @@ def _run_waiters(addr, n_agents, key, wait_s, longpoll):
 
     def _wait(i):
         try:
-            clients[i].kv_store_wait(
-                key,
-                timeout=wait_s,
-                interval=POLL_INTERVAL_S,
-                longpoll=longpoll,
-            )
+            clients[i].kv_store_wait(key, timeout=wait_s)
             results[i] = time.perf_counter()
         except TimeoutError:
             results[i] = None
@@ -130,13 +116,13 @@ def _run_waiters(addr, n_agents, key, wait_s, longpoll):
     return clients, results, threads
 
 
-def bench_idle_wait(addr, servicer, n_agents, wait_s, longpoll) -> dict:
+def bench_idle_wait(addr, servicer, n_agents, wait_s) -> dict:
     """The acceptance workload: an idle ``wait_s`` KV wait on a key
     nobody sets.  Counts every RPC the waiters issue."""
     server_before = servicer.rpc_count
-    key = f"bench/idle/{'lp' if longpoll else 'poll'}/{os.getpid()}"
+    key = f"bench/idle/{os.getpid()}"
     clients, _results, threads = _run_waiters(
-        addr, n_agents, key, wait_s, longpoll
+        addr, n_agents, key, wait_s
     )
     t0 = time.perf_counter()
     for t in threads:
@@ -155,12 +141,12 @@ def bench_idle_wait(addr, servicer, n_agents, wait_s, longpoll) -> dict:
     }
 
 
-def bench_wakeup(addr, kv, n_agents, wait_s, longpoll) -> dict:
+def bench_wakeup(addr, kv, n_agents, wait_s) -> dict:
     """Latency from ``kv set`` to waiter return, p50/p99 over the
     agent fleet."""
-    key = f"bench/wake/{'lp' if longpoll else 'poll'}/{os.getpid()}"
+    key = f"bench/wake/{os.getpid()}"
     clients, results, threads = _run_waiters(
-        addr, n_agents, key, wait_s + 10.0, longpoll
+        addr, n_agents, key, wait_s + 10.0
     )
     time.sleep(min(0.5, wait_s / 4))  # everyone parked
     t_set = time.perf_counter()
@@ -669,8 +655,8 @@ def run_overload(
 
 def run_all(n_agents: int = 8, wait_s: float = 5.0,
             out_path: str = "", payload: dict = None) -> dict:
-    """All phases, poll vs long-poll; shared with ``bench.py`` extras
-    and the tier-1 smoke test."""
+    """All phases; shared with ``bench.py`` extras and the tier-1
+    smoke test."""
     addr, servicer, server, kv = start_master()
     result = {
         "agents": n_agents,
@@ -684,24 +670,13 @@ def run_all(n_agents: int = 8, wait_s: float = 5.0,
             _flush(out_path, payload)
 
     try:
-        for mode, longpoll in (("poll", False), ("longpoll", True)):
-            result[mode] = {
-                "idle": bench_idle_wait(
-                    addr, servicer, n_agents, wait_s, longpoll
-                ),
-            }
-            _checkpoint()
-            result[mode]["wakeup"] = bench_wakeup(
-                addr, kv, n_agents, wait_s, longpoll
-            )
-            _checkpoint()
+        result["idle"] = bench_idle_wait(
+            addr, servicer, n_agents, wait_s
+        )
+        _checkpoint()
+        result["wakeup"] = bench_wakeup(addr, kv, n_agents, wait_s)
+        _checkpoint()
         result["throughput"] = bench_throughput(addr, kv, n_agents)
-        poll_rpcs = result["poll"]["idle"]["client_rpcs"]
-        lp_rpcs = result["longpoll"]["idle"]["client_rpcs"]
-        if lp_rpcs:
-            result["control_rpc_reduction"] = round(
-                poll_rpcs / lp_rpcs, 2
-            )
         result["control_rps"] = result["throughput"]["control_rps"]
         _checkpoint()
     finally:
@@ -741,20 +716,20 @@ def main(argv=None) -> int:
     n_agents, wait_s = args.agents, args.wait_s
     if budget.tight(60):
         # shed the wait window first (it dominates wall time), then
-        # the fleet size; the poll/longpoll RPC ratio survives both
+        # the fleet size
         wait_s = min(wait_s, 2.0)
     if budget.tight(20):
         n_agents, wait_s = min(n_agents, 2), min(wait_s, 1.0)
 
     payload = {
-        "metric": "control_rpc_reduction",
+        "metric": "control_rps",
         "value": None,
-        "unit": "x",
+        "unit": "rpc/s",
         "vs_baseline": None,
         "extras": {"bench_budget_s": budget.total},
     }
     result = run_all(n_agents, wait_s, args.out, payload)
-    payload["value"] = result.get("control_rpc_reduction")
+    payload["value"] = result.get("control_rps")
     payload["extras"]["control_plane"] = result
     if args.out:
         _flush(args.out, payload)

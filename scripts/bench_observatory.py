@@ -94,7 +94,6 @@ def run_scenario(
     # every half interval so a verdict never waits a full minute
     overrides = {
         "DLROVER_TPU_JOB_NAME": job,
-        "DLROVER_TPU_OBSERVATORY": "1",
         "DLROVER_TPU_HANG_WATCHDOG_S": str(2.0 * interval),
         "DLROVER_TPU_DIAGNOSIS_INTERVAL_S": str(interval / 2.0),
         "DLROVER_TPU_STRAGGLER_RATIO": "1.5",

@@ -1,32 +1,23 @@
-"""Micro-benchmark: serial vs overlapped restart critical path (MTTR).
+"""Micro-benchmark: the restart critical path (MTTR).
 
 Measures "restart decided" → "first step completed on the restored
-state" twice on the SAME host and checkpoint:
+state" through the real ``RestartCoordinator``: ``start`` runs the
+restore byte prefetch and the AOT compile concurrently, the
+rendezvous wait rides under them, ``finish_restore`` pipelines
+per-leaf ``device_put`` against the staged bytes, and the first step
+waits on the compiled artifact.
 
-- **serial**: today's order — rendezvous wait, then
-  ``CheckpointEngine.load`` (committed storage shard), then the train
-  step's first-call trace+compile, then the step
-  (``DLROVER_TPU_RESTART_OVERLAP=0`` through the real
-  ``RestartCoordinator``, so the measured code path is the product's
-  fallback, not a reimplementation);
-- **overlapped**: ``RestartCoordinator.start`` runs the restore byte
-  prefetch and the AOT compile concurrently, the SAME rendezvous wait
-  rides under them, ``finish_restore`` pipelines per-leaf
-  ``device_put`` against the staged bytes, and the first step waits
-  on the compiled artifact.
-
-Both modes pay an identical ``--rendezvous_s`` coordination wait
-(default 0.5 s — the goodput harness's measured worker-side
+Every round pays a ``--rendezvous_s`` coordination wait (default
+0.5 s — the goodput harness's measured worker-side
 rendezvous+backend-init leg): it is the third leg of the real
-critical path, dead time for the serial order and a free overlap
-window for the other two legs.  ``--rendezvous_s 0`` measures the
-pure two-leg overlap.
+critical path, a free overlap window for the other two legs.
+``--rendezvous_s 0`` measures the pure two-leg overlap.
 
-Each mode gets a FRESH jit function (a new executable cache entry —
-no cross-mode compile reuse) and a fresh engine namespace (no shm
-reuse); both restore the same committed shard.  Single-leg baselines
+Each round gets a FRESH jit function (a new executable cache entry —
+no cross-round compile reuse) and a fresh engine namespace (no shm
+reuse); all restore the same committed shard.  Single-leg baselines
 (``restore_only_s``, ``compile_only_s``) bound the ideal:
-``max(legs) <= overlap <= serial ~= sum(legs)``.
+``max(legs) <= overlap <= sum(legs)``.
 
 Honors ``DLROVER_TPU_BENCH_BUDGET_S`` (scales the state down and
 drops to one round), flushes the payload-so-far to ``--out`` after
@@ -231,7 +222,7 @@ def measure_reshard(root_dir: str, state_mb: int = 64,
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
-        description="serial vs overlapped restart MTTR"
+        description="restart critical-path MTTR"
     )
     parser.add_argument("--state_mb", type=int, default=192)
     parser.add_argument("--depth", type=int, default=4)
@@ -245,8 +236,8 @@ def main(argv=None) -> int:
     if budget.tight(300):
         # keep a REAL byte leg even when scaled down: below ~100 MB
         # the restore is milliseconds and the measurement degenerates
-        # into pure fixed-overhead comparison (one full round pair is
-        # well under a minute at this size)
+        # into pure fixed overhead (one full round is well under a
+        # minute at this size)
         state_mb = min(state_mb, 96)
         rounds = min(rounds, 2)
 
@@ -260,10 +251,7 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     from dlrover_tpu.trainer.checkpoint.engine import CheckpointEngine
-    from dlrover_tpu.trainer.restart_path import (
-        OVERLAP_ENV,
-        RestartCoordinator,
-    )
+    from dlrover_tpu.trainer.restart_path import RestartCoordinator
 
     init_state, make_step, batch_shape, hidden = build_workload(
         state_mb, args.depth
@@ -302,51 +290,42 @@ def main(argv=None) -> int:
 
     batch = jnp.ones(batch_shape, jnp.float32)
 
-    def measure(overlap: bool, tag: str) -> float:
-        prev = os.environ.get(OVERLAP_ENV)
-        os.environ[OVERLAP_ENV] = "1" if overlap else "0"
-        try:
-            engine = CheckpointEngine(
-                checkpoint_dir=ckpt_dir, process_rank=0,
-                process_count=1, local_shard_num=1, name=tag,
+    def measure(tag: str) -> float:
+        engine = CheckpointEngine(
+            checkpoint_dir=ckpt_dir, process_rank=0,
+            process_count=1, local_shard_num=1, name=tag,
+        )
+        step_fn = make_step()
+
+        def aot():
+            specs = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                state,
             )
-            step_fn = make_step()
+            return step_fn.lower(
+                specs,
+                jax.ShapeDtypeStruct(batch_shape, jnp.float32),
+            ).compile()
 
-            def aot():
-                specs = jax.tree_util.tree_map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                    state,
-                )
-                return step_fn.lower(
-                    specs,
-                    jax.ShapeDtypeStruct(batch_shape, jnp.float32),
-                ).compile()
-
-            t0 = time.perf_counter()
-            coord = RestartCoordinator(engine)
-            coord.start(compile_fn=aot)
-            if args.rendezvous_s > 0:
-                # the coordination wait both orders pay: the worker
-                # blocks on the device world assembling — pure dead
-                # time serially, a free window for the launched legs
-                with coord.rendezvous_wait():
-                    time.sleep(args.rendezvous_s)
-            got, restored = coord.finish_restore(target=state)
-            assert got == 7, got
-            fn = coord.resolve_train_step(fallback=step_fn)
-            out_state, _loss = fn(restored, batch)
-            jax.block_until_ready(out_state)
-            elapsed = time.perf_counter() - t0
-            engine.close()
-            return elapsed
-        finally:
-            if prev is None:
-                os.environ.pop(OVERLAP_ENV, None)
-            else:
-                os.environ[OVERLAP_ENV] = prev
+        t0 = time.perf_counter()
+        coord = RestartCoordinator(engine)
+        coord.start(compile_fn=aot)
+        if args.rendezvous_s > 0:
+            # the coordination wait: the worker blocks on the device
+            # world assembling — a free window for the launched legs
+            with coord.rendezvous_wait():
+                time.sleep(args.rendezvous_s)
+        got, restored = coord.finish_restore(target=state)
+        assert got == 7, got
+        fn = coord.resolve_train_step(fallback=step_fn)
+        out_state, _loss = fn(restored, batch)
+        jax.block_until_ready(out_state)
+        elapsed = time.perf_counter() - t0
+        engine.close()
+        return elapsed
 
     # single-leg baselines bound the ideal: max(legs) is the floor
-    # the overlapped path aims at, their sum is ~the serial path
+    # the overlapped path aims at, their sum what a failed leg costs
     t0 = time.perf_counter()
     probe_engine = CheckpointEngine(
         checkpoint_dir=ckpt_dir, process_rank=0, process_count=1,
@@ -366,24 +345,13 @@ def main(argv=None) -> int:
     payload["compile_only_s"] = round(time.perf_counter() - t0, 4)
     _flush(args.out, payload)
 
-    serial, overlapped = [], []
+    overlapped = []
     for r in range(rounds):
         if budget.tight(30):
             payload["rounds_completed"] = r
             break
-        # alternate the order each round: container-level throttling
-        # drifts over the run, and a fixed order would systematically
-        # charge the drift to whichever mode always runs second
-        order = (False, True) if r % 2 == 0 else (True, False)
-        for overlap in order:
-            runs = overlapped if overlap else serial
-            tag = f"br_{'o' if overlap else 's'}{r}"
-            runs.append(measure(overlap, tag))
-            _flush(
-                args.out,
-                dict(payload, serial_runs=serial,
-                     overlap_runs=overlapped),
-            )
+        overlapped.append(measure(f"br_o{r}"))
+        _flush(args.out, dict(payload, overlap_runs=overlapped))
 
     # ---- reshard leg: elastic world change vs restart-from-scratch
     if not budget.tight(45):
@@ -402,17 +370,10 @@ def main(argv=None) -> int:
             payload["reshard"] = {"error": str(e)}
         _flush(args.out, payload)
 
-    if serial and overlapped:
-        payload["restart_serial_s"] = round(min(serial), 4)
+    if overlapped:
         payload["restart_overlap_s"] = round(min(overlapped), 4)
         payload["value"] = payload["restart_overlap_s"]
-        payload["serial_runs"] = [round(s, 4) for s in serial]
         payload["overlap_runs"] = [round(s, 4) for s in overlapped]
-        payload["speedup"] = round(
-            payload["restart_serial_s"]
-            / max(payload["restart_overlap_s"], 1e-9),
-            3,
-        )
         ideal = max(
             payload["restore_only_s"], payload["compile_only_s"]
         )

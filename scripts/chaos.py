@@ -43,8 +43,8 @@ Reported per run (JSON ``--out`` artifact, wired into ``bench.py``
 - ``stall_max_s`` — the longest gap between consecutive completed
   steps; under master failover a master kill should barely dent this
   (steps don't go through the master at steady state).
-- ``job_survived`` — with ``--no-failover`` the same storm is
-  fail-fast by design: the first master death crashes the job.
+- ``job_survived`` — the job MUST survive the storm: a dead job is a
+  harness-level failure.
 
 Honors ``DLROVER_TPU_BENCH_BUDGET_S`` (scales the step budget down).
 
@@ -87,9 +87,8 @@ PLANS = (
     # fresh snapshot, flushes, fences itself at the master, and the
     # SURVIVOR re-meshes onto the shrunken world without a restart-
     # from-scratch; the pod is re-created after a delay and the world
-    # grows back.  Run twice by main() — the full autonomy stack
-    # (DLROVER_TPU_BRAIN=1 + DLROVER_TPU_RESHARD=1) vs the static
-    # seed job (both off) — to produce the Brain-vs-static
+    # grows back.  Run twice by main() — DLROVER_TPU_BRAIN=1 vs the
+    # static seed auto-scaler (=0) — to produce the Brain-vs-static
     # goodput/MTTR artifact.
     "preempt-storm",
     # sleep-fault one pod of three MID-RUN (a chip degrades under the
@@ -212,7 +211,7 @@ class MasterSupervisor:
         also 'the resumed job state is installed')."""
         if not wait_channel_ready(self.addr, timeout=timeout):
             return False
-        chan = MasterChannel(self.addr, max_retry=3)
+        chan = MasterChannel(self.addr)
         try:
             chan.refresh_epoch(timeout=5.0, deadline_s=5.0)
             return True
@@ -359,26 +358,19 @@ def run_preempt_storm(
     term_grace: float = 10.0,
     relaunch_delay: float = 12.0,
     timeout: float = 300.0,
-    reshard: bool = True,
-    brain: bool = None,
+    brain: bool = True,
 ) -> dict:
     """SIGTERM-with-grace preemption waves against pod 1 of a 2-pod
-    job.  With the reshard loop ON the dying pod drains + fences and
-    the survivor re-meshes within a monitor interval — training
-    continues on the shrunken world THROUGH the ``relaunch_delay``
-    outage (the realistic gap before the scheduler re-creates the
-    pod).  OFF reproduces today's behavior: bare flush, no fencing,
-    the survivor stalls wedged in its collective until the re-created
-    pod rejoins, then replays back to the last periodic snapshot.
-    Per-wave MTTR = SIGTERM → first step BEYOND the pre-death
-    watermark, logged AFTER the pod actually died.
+    job.  The dying pod drains + fences and the survivor re-meshes
+    within a monitor interval — training continues on the shrunken
+    world THROUGH the ``relaunch_delay`` outage (the realistic gap
+    before the scheduler re-creates the pod).  Per-wave MTTR =
+    SIGTERM → first step BEYOND the pre-death watermark, logged AFTER
+    the pod actually died.
 
-    ``brain`` follows ``reshard`` unless overridden: the autonomy
-    comparison is the full stack (Brain + execution arm) vs the
-    static seed job (neither) — ``DLROVER_TPU_BRAIN`` rides both the
-    master and the job."""
-    if brain is None:
-        brain = reshard
+    ``brain``: the autonomy comparison is the Brain vs the static
+    seed auto-scaler — ``DLROVER_TPU_BRAIN`` rides both the master
+    and the job."""
     workdir = tempfile.mkdtemp(prefix="dlrover_preempt_")
     progress = os.path.join(workdir, "progress.jsonl")
     supervisor = MasterSupervisor(
@@ -400,7 +392,6 @@ def run_preempt_storm(
         DLROVER_TPU_EVENTS_FILE=os.path.join(
             workdir, "events.jsonl"
         ),
-        DLROVER_TPU_RESHARD="1" if reshard else "0",
         DLROVER_TPU_BRAIN="1" if brain else "0",
         DLROVER_TPU_PREEMPT_DRAIN_GRACE_S="2.0",
         DLROVER_TPU_EMERGENCY_COMMIT_TIMEOUT_S="3.0",
@@ -535,7 +526,6 @@ def run_preempt_storm(
     )
     return {
         "plan": "preempt-storm",
-        "reshard": reshard,
         "brain": brain,
         "steps": final_step,
         "target_steps": steps,
@@ -613,7 +603,6 @@ def run_slow_node(
         GOODPUT_PROGRESS_FILE=progress,
         GOODPUT_CKPT_DIR=os.path.join(workdir, "ckpt"),
         DLROVER_TPU_BRAIN=brain_flag,
-        DLROVER_TPU_RESHARD="1",
         DLROVER_TPU_TIMELINE_REPORT_S="1.0",
         DLROVER_TPU_PREEMPT_DRAIN_GRACE_S="2.0",
         DLROVER_TPU_EMERGENCY_COMMIT_TIMEOUT_S="3.0",
@@ -739,12 +728,11 @@ def run_plan(
     seed: int = 7,
     step_sleep: float = 0.08,
     timeout: float = 300.0,
-    failover: bool = True,
     nproc: int = 2,
 ) -> dict:
     """One chaos run; returns the metrics dict.  Raises RuntimeError
-    only on harness failure — a job death under ``failover=False`` is
-    a RESULT (``job_survived=False``), not an error."""
+    on harness failure, a job that did not survive its plan
+    included."""
     if plan not in PLANS:
         raise ValueError(f"unknown plan {plan!r} (have: {PLANS})")
     workdir = tempfile.mkdtemp(prefix="dlrover_chaos_")
@@ -769,7 +757,6 @@ def run_plan(
         DLROVER_TPU_EVENTS_FILE=os.path.join(
             workdir, "events.jsonl"
         ),
-        DLROVER_TPU_MASTER_FAILOVER="1" if failover else "0",
         JAX_PLATFORMS="cpu",
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
         PYTHONPATH=REPO,
@@ -842,16 +829,11 @@ def run_plan(
                     master_kills == 0
                 ):
                     master_kills += 1
-                if failover:
-                    if not supervisor.restart():
-                        raise RuntimeError(
-                            "restarted master never became ready: "
-                            + supervisor.log_tail()
-                        )
-                # fail-fast mode: no restart — the next
-                # master-dependent operation crashes the job (steady
-                # -state steps may still finish: they never touch the
-                # master, and reports were always advisory)
+                if not supervisor.restart():
+                    raise RuntimeError(
+                        "restarted master never became ready: "
+                        + supervisor.log_tail()
+                    )
             time.sleep(0.05)
     finally:
         supervisor.stop()
@@ -864,9 +846,9 @@ def run_plan(
     final_step = max((e["step"] for e in lines), default=0)
     if launcher.returncode != 0 or final_step < steps:
         job_survived = False
-    if job_survived is False and failover and plan != "none":
-        # under failover the job MUST survive the storm — this is the
-        # acceptance bar, so a dead job is a harness-level failure
+    if job_survived is False and plan != "none":
+        # the job MUST survive the storm — this is the acceptance
+        # bar, so a dead job is a harness-level failure
         raise RuntimeError(
             f"job did not survive plan {plan!r} "
             f"(rc={launcher.returncode}, step {final_step}/{steps}); "
@@ -906,7 +888,6 @@ def run_plan(
     return {
         "plan": plan,
         "seed": seed,
-        "failover": failover,
         "steps": final_step,
         "target_steps": steps,
         "wall_s": round(wall_s, 2),
@@ -937,25 +918,18 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=7)
     parser.add_argument("--step_sleep", type=float, default=0.08)
     parser.add_argument("--timeout", type=float, default=300.0)
-    parser.add_argument("--no-failover", action="store_true",
-                        help="DLROVER_TPU_MASTER_FAILOVER=0 on the "
-                        "job: pin today's fail-fast behavior")
     parser.add_argument("--waves", type=int, default=2,
                         help="preempt-storm: SIGTERM waves")
     parser.add_argument("--save_every", type=int, default=5,
                         help="preempt-storm: shm snapshot cadence "
                         "(steps) — the periodic-RPO the graceful "
                         "drain beats")
-    parser.add_argument("--no-reshard", action="store_true",
-                        help="preempt-storm: run ONLY the "
-                        "DLROVER_TPU_RESHARD=0 leg (default runs "
-                        "both and reports the comparison)")
-    parser.add_argument("--reshard-only", action="store_true",
-                        help="preempt-storm: run only the reshard leg")
     parser.add_argument("--brain-only", action="store_true",
-                        help="slow-node: run only the Brain-on leg")
+                        help="slow-node / preempt-storm: run only "
+                        "the Brain-on leg")
     parser.add_argument("--static-only", action="store_true",
-                        help="slow-node: run only the Brain-off leg")
+                        help="slow-node / preempt-storm: run only "
+                        "the Brain-off leg")
     parser.add_argument("--slow_factor", type=float, default=5.0,
                         help="slow-node: sleep-fault multiplier")
     parser.add_argument("--pods", type=int, default=3,
@@ -1033,8 +1007,8 @@ def main(argv=None) -> int:
     if args.plan == "preempt-storm":
         payload["metric"] = "preempt_recovery_mean_s"
         legs = (
-            [False] if args.no_reshard
-            else [True] if args.reshard_only
+            [True] if args.brain_only
+            else [False] if args.static_only
             else [True, False]
         )
         timeout = budget.cap_timeout(args.timeout)
@@ -1043,17 +1017,16 @@ def main(argv=None) -> int:
         # missed collective and the wave measures nothing
         storm_sleep = max(args.step_sleep, 0.25)
         try:
-            for reshard in legs:
+            for brain in legs:
                 leg = run_preempt_storm(
                     steps=steps,
                     waves=args.waves,
                     step_sleep=storm_sleep,
                     save_every=args.save_every,
                     timeout=timeout,
-                    reshard=reshard,
+                    brain=brain,
                 )
-                key = "reshard" if reshard else "restart"
-                payload["extras"][key] = leg
+                payload["extras"]["brain" if brain else "static"] = leg
                 if args.out:
                     _flush(args.out, payload)
         except RuntimeError as e:
@@ -1062,8 +1035,8 @@ def main(argv=None) -> int:
                 _flush(args.out, payload)
             print(json.dumps(payload, indent=2))
             return 1
-        re_leg = payload["extras"].get("reshard")
-        rs_leg = payload["extras"].get("restart")
+        re_leg = payload["extras"].get("brain")
+        rs_leg = payload["extras"].get("static")
         if re_leg:
             payload["value"] = re_leg["recovery_mean_s"]
         if re_leg and rs_leg:
@@ -1080,7 +1053,7 @@ def main(argv=None) -> int:
         print(json.dumps(payload, indent=2))
         survived = all(
             payload["extras"].get(k, {}).get("job_survived", False)
-            for k in ("reshard", "restart")
+            for k in ("brain", "static")
             if k in payload["extras"]
         )
         return 0 if survived else 1
@@ -1093,7 +1066,6 @@ def main(argv=None) -> int:
             seed=args.seed,
             step_sleep=args.step_sleep,
             timeout=budget.cap_timeout(args.timeout),
-            failover=not args.no_failover,
         )
     except RuntimeError as e:
         payload["extras"]["error"] = str(e)
@@ -1106,7 +1078,7 @@ def main(argv=None) -> int:
     if args.out:
         _flush(args.out, payload)
     print(json.dumps(payload, indent=2))
-    return 0 if result["job_survived"] or args.no_failover else 1
+    return 0 if result["job_survived"] else 1
 
 
 if __name__ == "__main__":

@@ -114,7 +114,7 @@ DECLARED_METRICS = {
     "dlrover_tpu_autoscale_errors",
     "dlrover_tpu_autoscale_world",
     # the master's control-plane SELF-telemetry
-    # (observability/self_telemetry.py, behind DLROVER_TPU_SELF_OBS):
+    # (observability/self_telemetry.py):
     # per-RPC-kind latency + request/response-size histograms
     "dlrover_tpu_master_rpc_latency_seconds",
     "dlrover_tpu_master_rpc_request_bytes",

@@ -29,7 +29,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import optax  # noqa: E402
 
-from dlrover_tpu.common.env import input_pipeline_enabled  # noqa: E402
 from dlrover_tpu.data.prefetch import device_prefetch  # noqa: E402
 from dlrover_tpu.observability.events import (  # noqa: E402
     anchored_now,
@@ -122,8 +121,8 @@ def main() -> int:
 
     # overlapped restart critical path: restore byte prefetch and the
     # train-step AOT compile (or its persistent-cache hit) run
-    # concurrently; the serial order survives any leg failure or
-    # DLROVER_TPU_RESTART_OVERLAP=0 (trainer/restart_path.py)
+    # concurrently; the serial order follows any leg failure
+    # (trainer/restart_path.py)
     host_state = jax.device_get(state)
     x_spec = jax.ShapeDtypeStruct((16, 32), jnp.float32)
     state_spec = jax.tree_util.tree_map(
@@ -188,8 +187,7 @@ def main() -> int:
     def batch_stream(start: int):
         """Deterministic per-step host batches: a restart resuming at
         step k regenerates exactly the batches the dead incarnation
-        would have consumed — the pipelined and serial paths stay
-        byte-identical across restarts."""
+        would have consumed."""
         i = start
         while True:
             rng = np.random.default_rng((ctx.rank << 20) + i)
@@ -197,15 +195,10 @@ def main() -> int:
             i += 1
 
     # pipelined input plane: the host fetch of batch k+1 overlaps the
-    # device staging of batch k and the compute of step k-1;
-    # DLROVER_TPU_INPUT_PIPELINE=0 falls back to inline fetch (same
-    # batch order)
-    if input_pipeline_enabled():
-        batches = iter(
-            device_prefetch(batch_stream(step), size=2, pipelined=True)
-        )
-    else:
-        batches = batch_stream(step)
+    # device staging of batch k and the compute of step k-1
+    batches = iter(
+        device_prefetch(batch_stream(step), size=2, pipelined=True)
+    )
 
     first_step = True
     while step < TARGET:

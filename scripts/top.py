@@ -138,7 +138,7 @@ def render(status: dict) -> str:
     master = status.get("master") or {}
     if master:
         # the control plane's own vitals (absent when the master
-        # runs with DLROVER_TPU_SELF_OBS=0 or predates self-obs)
+        # predates self-telemetry)
         pool = master.get("pool") or {}
         ds = master.get("datastore") or {}
         jrn = master.get("journal") or {}
@@ -371,8 +371,8 @@ def main(argv=None) -> int:
             else:
                 if status is None:
                     frame = (
-                        "(observatory unavailable — master runs with "
-                        "DLROVER_TPU_OBSERVATORY=0 or predates it)"
+                        "(observatory unavailable — the master "
+                        "predates it)"
                     )
                 else:
                     frame = render(status)

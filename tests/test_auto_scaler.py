@@ -595,27 +595,80 @@ class FlakyOptimizer:
 
 
 class TestSeedLoopSatellites:
-    def test_cycle_errors_counted_and_traceback_throttled(self):
+    def test_cycle_errors_counted_and_traceback_throttled(
+        self, monkeypatch
+    ):
+        """Every failing cycle ticks the counter; the traceback is
+        written for the first one and then once a cooldown.  The
+        throttle runs on an injected clock that starts 10 s after
+        "boot": the monotonic clock counts from there, and a master
+        on a machine younger than the cooldown wrote no traceback at
+        all while the throttle's first stamp was 0.0 (the red run on
+        record: a tier-1 run inside the machine's first 300 s)."""
+        import logging
+
+        from dlrover_tpu.master import auto_scaler as mod
         from dlrover_tpu.master.scaler import InMemoryScaler
         from dlrover_tpu.observability.metrics import get_registry
 
         registry = get_registry()
         key = "dlrover_tpu_autoscale_errors"
         before = registry._metrics.get(key, 0.0)
+        # the real loop: three failing cycles reach the accounting
+        # (the deadline only keeps a wedged loop from hanging the run)
         auto = AllreduceAutoScaler(
             FlakyOptimizer(), InMemoryScaler(), interval=0.01
         )
         auto.start()
-        deadline = time.time() + 5.0
+        deadline = time.time() + 60.0
         while auto.cycle_errors < 3 and time.time() < deadline:
             time.sleep(0.01)
         auto.stop()
         assert auto.cycle_errors >= 3
-        # the traceback throttle state advanced exactly once (all
-        # failures landed inside one cooldown window)
-        assert auto._last_error_log > 0.0
+        looped = auto.cycle_errors
+        assert registry._metrics.get(key, 0.0) >= before + looped
+
+        # the throttle, on the injected clock
+        records = []
+
+        class _Keep(logging.Handler):
+            def emit(self, record):
+                records.append(record)
+
+        keep = _Keep()
+        mod.logger.addHandler(keep)
+        clock = [10.0]
+
+        class _Clock:
+            """The module's ``time``, but for ``monotonic``."""
+
+            def __getattr__(self, name):
+                return getattr(time, name)
+
+            def monotonic(self):
+                return clock[0]
+
+        monkeypatch.setattr(mod, "time", _Clock())
+        fresh = AllreduceAutoScaler(
+            FlakyOptimizer(), InMemoryScaler(), interval=0.01
+        )
+        try:
+            for _ in range(3):
+                fresh._on_cycle_error(RuntimeError("boom"))
+                clock[0] += 1.0
+            assert fresh._last_error_log == 10.0  # advanced once
+            assert [bool(r.exc_info) for r in records] == [
+                True, False, False,
+            ]
+            clock[0] = 10.0 + fresh.ERROR_LOG_COOLDOWN_S
+            fresh._on_cycle_error(RuntimeError("boom"))
+            assert fresh._last_error_log == clock[0]
+            assert bool(records[-1].exc_info)
+        finally:
+            mod.logger.removeHandler(keep)
+        assert fresh.cycle_errors == 4
         after = registry._metrics.get(key, 0.0)
-        assert after >= before + 3
+        assert after >= before + looped + 4
 
     def test_stop_joins_the_loop_thread(self):
         from dlrover_tpu.master.scaler import InMemoryScaler

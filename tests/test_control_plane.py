@@ -230,28 +230,75 @@ class TestLongPollKV:
         client.close()
         setter.close()
 
-    def test_polling_fallback_kill_switch(self, master, monkeypatch):
-        """DLROVER_TPU_CONTROL_LONGPOLL=0 reproduces the polling
-        loop: many get RPCs at the poll interval."""
-        monkeypatch.setenv("DLROVER_TPU_CONTROL_LONGPOLL", "0")
-        client = MasterClient(master.addr, node_id=0)
-        before = client.rpc_count
-        with pytest.raises(TimeoutError):
-            client.kv_store_wait("never-set", timeout=1.2, interval=0.2)
-        polls = client.rpc_count - before
-        assert polls >= 4  # ~6 at 0.2 s over 1.2 s
-        client.close()
+    def test_wait_for_master_parks_on_channel_ready(self, monkeypatch):
+        """The launcher's wait for a master that is not up yet parks
+        on grpc's channel-ready future: no TCP connect probe from
+        Python, and it returns within a reconnect backoff of the
+        server opening — False once the timeout passes unanswered."""
+        from dlrover_tpu.common.comm import wait_channel_ready
 
-    def test_explicit_longpoll_param_overrides_env(
-        self, master, monkeypatch
+        probes = []
+        real_connect = socket.create_connection
+
+        def _counting_connect(*a, **k):
+            probes.append(a)
+            return real_connect(*a, **k)
+
+        monkeypatch.setattr(
+            socket, "create_connection", _counting_connect
+        )
+        port = get_free_port()
+        addr = f"127.0.0.1:{port}"
+        assert wait_channel_ready(addr, timeout=0.3) is False
+        m = LocalJobMaster(port, node_num=1)
+        threading.Timer(0.4, m.prepare).start()
+        try:
+            t0 = time.monotonic()
+            assert wait_channel_ready(addr, timeout=20.0) is True
+            assert time.monotonic() - t0 >= 0.3  # it did wait
+        finally:
+            m.stop()
+        assert probes == []
+
+    def test_saturated_master_is_repolled_at_the_paced_rate(
+        self, master
     ):
-        monkeypatch.setenv("DLROVER_TPU_CONTROL_LONGPOLL", "0")
+        """A master past its parked-wait cap answers a long-poll at
+        once; the client then re-issues at the 10 Hz pace
+        (``_pace_longpoll``), not in a hot RPC spin."""
+        slots = master._servicer._wait_slots
+        taken = 0
+        while slots.acquire(blocking=False):
+            taken += 1
+        assert taken == master._servicer.max_parked_waits
         client = MasterClient(master.addr, node_id=0)
+        setter = MasterClient(master.addr, node_id=1)
         before = client.rpc_count
-        with pytest.raises(TimeoutError):
-            client.kv_store_wait("never-set", timeout=1.0, longpoll=True)
-        assert client.rpc_count - before <= 2
+        got = []
+        waiter = threading.Thread(
+            target=lambda: got.append(
+                client.kv_store_wait("late-key", timeout=30.0)
+            ),
+            daemon=True,
+        )
+        try:
+            t0 = time.monotonic()
+            waiter.start()
+            time.sleep(1.0)
+            rpcs = client.rpc_count - before
+            elapsed = time.monotonic() - t0
+            setter.kv_store_set("late-key", b"v")
+            waiter.join(timeout=10.0)
+        finally:
+            for _ in range(taken):
+                slots.release()
+        assert got == [b"v"]  # the immediate answers still deliver
+        # the pace guarantees the ceiling (one RPC a 0.1 s backoff, as
+        # long as the sleep really took); how FEW a loaded host's
+        # scheduler lets through says nothing about a hot spin
+        assert rpcs <= elapsed / 0.1 + 2, (rpcs, elapsed)
         client.close()
+        setter.close()
 
 
 class TestLongPollRendezvous:
@@ -558,15 +605,30 @@ class TestReportBuffer:
         assert len(client._channel.sent) == 1
         assert client._channel.sent[0].items[0].step == 42
 
-    def test_batch_kill_switch(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_CONTROL_BATCH", "0")
+    def test_add_owns_delivery_and_the_age_flusher_ships_it(self):
+        """``add`` never sends on its own below the size threshold and
+        acks True even with the master down (the buffer owes the
+        report from there); the age flusher then ships it as ONE
+        enveloped batch once the master is back."""
         client = _FakeClient()
-        buf = ReportBuffer(client, auto_flush=False)
-        buf.add(msg.HeartBeat(timestamp=1.0))
-        # degenerated to the old one-RPC-per-report path: raw message,
-        # no envelope, no buffering
+        client._channel.down = True
+        buf = ReportBuffer(client, max_age_s=0.05)
+        try:
+            assert buf.add(msg.HeartBeat(timestamp=1.0)) is True
+            time.sleep(0.2)  # age flushes fail and re-queue
+            assert buf.pending == 1 and client._channel.sent == []
+            client._channel.down = False
+            deadline = time.monotonic() + 5.0
+            while not client._channel.sent:
+                assert time.monotonic() < deadline
+                time.sleep(0.02)
+        finally:
+            buf.close()
+        assert len(client._channel.sent) == 1
+        batch = client._channel.sent[0]
+        assert isinstance(batch, msg.BatchedReport)
+        assert isinstance(batch.items[0], msg.HeartBeat)
         assert buf.pending == 0
-        assert isinstance(client._channel.sent[0], msg.HeartBeat)
 
     def test_batched_report_against_real_master(self, master):
         """End to end: one BatchedReport applies every item in order
@@ -593,7 +655,7 @@ class TestReportBuffer:
 class TestWriteBehindDatastore:
     def test_close_drains_zero_rows_lost(self, tmp_path):
         db = str(tmp_path / "brain.db")
-        store = BrainDatastore(db, sync=False)
+        store = BrainDatastore(db)
         n = 500
         for i in range(n):
             store.record_speed("job", i % 7 + 1, float(i))
@@ -606,7 +668,7 @@ class TestWriteBehindDatastore:
         assert count == n
 
     def test_read_your_writes_before_any_flush_interval(self, tmp_path):
-        store = BrainDatastore(str(tmp_path / "b.db"), sync=False)
+        store = BrainDatastore(str(tmp_path / "b.db"))
         store.record_speed("job", 4, 100.0)
         store.record_node_event("job", "n0", "oom", "detail")
         # immediate read: the drain barrier makes the queue invisible
@@ -616,7 +678,7 @@ class TestWriteBehindDatastore:
         store.close()
 
     def test_timeline_batch_lands_as_one_executemany(self, tmp_path):
-        store = BrainDatastore(str(tmp_path / "b.db"), sync=False)
+        store = BrainDatastore(str(tmp_path / "b.db"))
         events = [
             {"name": "step", "ph": "X", "wall": float(i), "dur": 0.1}
             for i in range(100)
@@ -625,18 +687,25 @@ class TestWriteBehindDatastore:
         assert len(store.timeline_events("job")) == 100
         store.close()
 
-    def test_sync_env_restores_commit_per_write(
-        self, tmp_path, monkeypatch
-    ):
-        """DLROVER_TPU_DATASTORE_SYNC=1: every write is committed the
-        moment the recorder returns — visible to a SECOND connection
-        with no drain (today's behavior, byte-for-byte)."""
-        monkeypatch.setenv("DLROVER_TPU_DATASTORE_SYNC", "1")
-        db = str(tmp_path / "sync.db")
+    def test_measurement_commits_beside_the_queue(self, tmp_path):
+        """The one synchronous recorder: a strategy measurement is
+        visible to a SECOND connection the moment the call returns,
+        no drain — a neighbour master reads this file directly —
+        while the high-rate row recorded before it rides the
+        flusher's queue and is there after the drain."""
+        db = str(tmp_path / "beside.db")
         store = BrainDatastore(db)
-        assert store._sync and store._flusher is None
+        assert store._flusher.is_alive()
         store.record_speed("job", 2, 50.0)
+        assert store.health()["enqueued_rows"] == 1
+        store.record_measurement("wl", {"fsdp": 2}, 0.25, job="job")
         conn = sqlite3.connect(db)  # independent reader, no drain
+        count = conn.execute(
+            "SELECT COUNT(*) FROM strategy_measurements"
+        ).fetchone()[0]
+        assert count == 1
+        store._drain()
+        assert store.health()["lag_rows"] == 0
         count = conn.execute(
             "SELECT COUNT(*) FROM speed_samples"
         ).fetchone()[0]
@@ -645,11 +714,11 @@ class TestWriteBehindDatastore:
         store.close()
 
     def test_async_buffers_between_commits(self, tmp_path):
-        """The inverse of the sync test: async mode genuinely
-        batches — an independent reader does NOT see an enqueued row
+        """The write-behind queue genuinely batches — an independent
+        reader does NOT see an enqueued row
         before the linger, while the owning store (drain) does."""
         db = str(tmp_path / "async.db")
-        store = BrainDatastore(db, sync=False)
+        store = BrainDatastore(db)
         # stall the flusher wake-up by writing exactly once
         store.record_speed("job", 2, 50.0)
         conn = sqlite3.connect(db)
@@ -680,14 +749,10 @@ class TestBenchControlPlaneSmoke:
         from bench_control_plane import run_all
 
         result = run_all(n_agents=2, wait_s=1.0)
-        for mode in ("poll", "longpoll"):
-            assert result[mode]["idle"]["client_rpcs"] > 0
-            assert "wakeup_p50_ms" in result[mode]["wakeup"]
+        idle = result["idle"]
+        assert idle["client_rpcs"] > 0
+        assert "wakeup_p50_ms" in result["wakeup"]
         assert result["control_rps"] > 0
-        # the acceptance direction, at smoke scale: long-poll strictly
-        # cheaper than the polling reference
-        assert (
-            result["longpoll"]["idle"]["client_rpcs"]
-            < result["poll"]["idle"]["client_rpcs"]
-        )
-        assert result["control_rpc_reduction"] > 1.0
+        # the acceptance bound, at smoke scale: an idle wait costs a
+        # parked RPC or two a waiter, not one per poll interval
+        assert idle["rpcs_per_waiter"] <= 2

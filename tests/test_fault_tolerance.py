@@ -326,11 +326,17 @@ class TestMasterKillMidJob:
         assert faulted[0] == reference[0]
         assert faulted[1] == reference[1]
 
-    def test_kill_switch_fail_fast_mid_kv_wait(self, monkeypatch):
-        """DLROVER_TPU_MASTER_FAILOVER=0 restores today's behavior
-        exactly: a master death mid-wait raises ConnectionError after
-        max_retry attempts instead of reconnecting."""
-        monkeypatch.setenv("DLROVER_TPU_MASTER_FAILOVER", "0")
+    def test_master_gone_for_good_mid_kv_wait_raises_at_deadline(
+        self, monkeypatch
+    ):
+        """A master that dies mid-wait and never comes back: the wait
+        outlives the channel's own reconnect deadline (it keeps
+        waiting for a replacement) and gives up with ConnectionError
+        only when the CALLER's timeout runs out — bounded, not at a
+        fixed attempt count and not forever."""
+        monkeypatch.setenv(
+            "DLROVER_TPU_MASTER_RECONNECT_DEADLINE_S", "1.0"
+        )
         port = get_free_port()
         master = LocalJobMaster(port, node_num=1)
         master.prepare()
@@ -339,17 +345,22 @@ class TestMasterKillMidJob:
 
         def _wait():
             try:
-                client.kv_store_wait("never/set", timeout=60.0)
+                client.kv_store_wait("never/set", timeout=4.0)
             except (ConnectionError, TimeoutError) as e:
                 errs.append(e)
 
         t = threading.Thread(target=_wait, daemon=True)
+        t0 = time.monotonic()
         t.start()
         time.sleep(0.4)  # parked on the live master
         try:
             master._server.stop(grace=0)
             t.join(timeout=30.0)
+            elapsed = time.monotonic() - t0
             assert errs and isinstance(errs[0], ConnectionError)
+            # past the 1 s reconnect deadline, out by the 4 s timeout
+            assert 3.0 <= elapsed < 15.0, elapsed
+            assert client._channel.retry_count >= 1
         finally:
             client.close()
             master.stop()

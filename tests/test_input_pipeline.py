@@ -173,11 +173,13 @@ class _SourcePool:
             (dataset_size, width)
         ).astype(np.float32)
         self.calls = []
+        self.threads = []
         self._lock = threading.Lock()
 
     def __call__(self, indices: np.ndarray):
         with self._lock:
             self.calls.append(np.array(indices))
+            self.threads.append(threading.current_thread().name)
         return {"x": self.data[indices], "idx": np.array(indices)}
 
 
@@ -190,16 +192,18 @@ class TestElasticDataLoaderPipeline:
         return ElasticDataLoader(read_batch=pool, **kwargs)
 
     def test_pipelined_byte_identical_to_serial(self):
-        """Same sampler seed: the pipelined producer pool yields the
-        exact serial batch sequence, byte for byte — including with a
-        multi-worker pool."""
+        """Same sampler seed: the producer pool yields the exact
+        batch sequence a serial read of the sampler's draws gives,
+        byte for byte — including with a multi-worker pool."""
         pool = _SourcePool(64)
-        serial = list(self._loader(pool, pipeline=False))
+        serial = [
+            pool(indices)
+            for indices, _ in self._loader(pool)._index_batches()
+        ]
         for workers in (1, 3):
             out = list(
                 self._loader(
-                    pool, pipeline=True, num_workers=workers,
-                    prefetch_depth=3,
+                    pool, num_workers=workers, prefetch_depth=3,
                 )
             )
             assert len(out) == len(serial)
@@ -207,17 +211,27 @@ class TestElasticDataLoaderPipeline:
                 assert a["x"].tobytes() == b["x"].tobytes()
                 assert a["idx"].tobytes() == b["idx"].tobytes()
 
-    def test_kill_switch_env_disables_pipeline(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_INPUT_PIPELINE", "0")
+    def test_read_batch_runs_on_the_producer_pool(self):
+        """Every ``read_batch`` call runs on an ``input-fetch`` pool
+        thread, never on the consumer's, and the pool has read ahead
+        by the time the first batch is handed out; the yield order is
+        the sampler's draw order all the same."""
         pool = _SourcePool(32)
-        loader = self._loader(pool)
-        assert not loader._pipeline_on()
-        batches = list(loader)
-        # serial path: read_batch call order IS the yield order
-        for call, batch in zip(pool.calls, batches):
-            np.testing.assert_array_equal(call, batch["idx"])
-        monkeypatch.setenv("DLROVER_TPU_INPUT_PIPELINE", "1")
-        assert loader._pipeline_on()
+        loader = self._loader(pool, num_workers=2, prefetch_depth=3)
+        it = iter(loader)
+        first = next(it)
+        time.sleep(0.1)
+        assert len(pool.calls) > 1  # read-ahead past the yielded one
+        batches = [first] + list(it)
+        assert len(batches) == 8
+        assert pool.threads and all(
+            t.startswith("input-fetch") for t in pool.threads
+        )
+        draws = [
+            idx for idx, _ in self._loader(pool)._index_batches()
+        ]
+        for want, batch in zip(draws, batches):
+            np.testing.assert_array_equal(want, batch["idx"])
 
     def test_num_workers_tuned_from_config(self, tmp_path):
         config = tmp_path / "paral.json"
@@ -237,7 +251,7 @@ class TestElasticDataLoaderPipeline:
         prefetched-but-unconsumed batches."""
         pool = _SourcePool(64)
         loader = self._loader(
-            pool, pipeline=True, num_workers=2, prefetch_depth=4
+            pool, num_workers=2, prefetch_depth=4
         )
         it = iter(loader)
         consumed = [next(it), next(it)]
@@ -247,7 +261,7 @@ class TestElasticDataLoaderPipeline:
         it.close()
 
         pool2 = _SourcePool(64)
-        resumed = self._loader(pool2, pipeline=True, num_workers=2)
+        resumed = self._loader(pool2, num_workers=2)
         resumed.load_state_dict(state)
         rest = list(resumed)
 
@@ -343,9 +357,7 @@ class TestShardTaskPrefetch:
         from dlrover_tpu.trainer.sharding import ShardingClient
 
         stub = _StubMasterClient(5)
-        client = ShardingClient(
-            "d", batch_size=4, client=stub, prefetch_tasks=True
-        )
+        client = ShardingClient("d", batch_size=4, client=stub)
         shards = list(client.iter_shards())
         assert [s.start for s in shards] == [0, 4, 8, 12, 16]
         # the prefetcher issued RPCs off the consumer thread
@@ -354,34 +366,36 @@ class TestShardTaskPrefetch:
         )
 
     def test_prefetch_overlaps_consumption(self):
-        """With prefetch on, the 2nd shard's RPC runs while the 1st is
+        """The 2nd shard's RPC runs while the 1st is
         being 'consumed' — the consumer never waits the full RPC
         latency again after the first fetch."""
         from dlrover_tpu.trainer.sharding import ShardingClient
 
         delay = 0.15
         stub = _StubMasterClient(3, delay_s=delay)
-        client = ShardingClient(
-            "d", batch_size=4, client=stub, prefetch_tasks=True
-        )
+        client = ShardingClient("d", batch_size=4, client=stub)
         assert client.fetch_shard() is not None  # pays the first RPC
         time.sleep(delay * 1.5)  # "consume" the shard
         t0 = time.monotonic()
         assert client.fetch_shard() is not None
         assert time.monotonic() - t0 < delay / 2
 
-    def test_prefetch_disabled_is_synchronous(self):
+    def test_only_the_first_rpc_is_on_the_consumer_thread(self):
+        """Nothing is prefetched before the first ``fetch_shard``: that
+        RPC runs on the caller's thread; every later one — the one
+        that finds the dataset exhausted included — comes from the
+        prefetcher, one RPC a task and none twice."""
         from dlrover_tpu.trainer.sharding import ShardingClient
 
         stub = _StubMasterClient(2)
-        client = ShardingClient(
-            "d", batch_size=4, client=stub, prefetch_tasks=False
-        )
+        client = ShardingClient("d", batch_size=4, client=stub)
         shards = list(client.iter_shards())
         assert [s.start for s in shards] == [0, 4]
+        me = threading.current_thread().name
+        assert stub.get_task_threads[0] == me
+        assert len(stub.get_task_threads) == 3
         assert all(
-            "shard-prefetch" not in t
-            for t in stub.get_task_threads
+            "shard-prefetch" in t for t in stub.get_task_threads[1:]
         )
 
 

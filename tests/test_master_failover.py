@@ -453,8 +453,8 @@ class TestServicerFencing:
         assert not isinstance(out, msg.StaleEpoch)
 
     def test_legacy_client_never_fenced(self):
-        """-1 = not speaking the protocol (old client or kill-switched
-        failover): dispatched, never fenced."""
+        """-1 = has not learned the pair yet (an old client, or one
+        before its first refresh): dispatched, never fenced."""
         servicer = _servicer(job_epoch=3)
         out = servicer.get(_envelope(msg.KeyValuePair(key="k")))
         assert not isinstance(out, msg.StaleEpoch)
@@ -467,13 +467,18 @@ class TestServicerFencing:
         assert isinstance(out, msg.ControlEpoch)
         assert (out.job_epoch, out.incarnation) == (3, 7)
 
-    def test_kill_switch_disables_fencing(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_MASTER_FAILOVER", "0")
+    def test_fenced_report_is_not_applied(self):
+        """Fencing comes before dispatch: a stale client's KV write
+        gets the typed answer and leaves no trace; the same write at
+        the master's own epoch lands."""
         servicer = _servicer(job_epoch=3)
-        out = servicer.get(
-            _envelope(msg.KeyValuePair(key="k"), job_epoch=1)
-        )
+        write = msg.KeyValuePair(key="coord", value=b"old-world")
+        out = servicer.report(_envelope(write, job_epoch=1))
+        assert isinstance(out, msg.StaleEpoch)
+        assert not servicer._kv_store.get("coord")
+        out = servicer.report(_envelope(write, job_epoch=3))
         assert not isinstance(out, msg.StaleEpoch)
+        assert servicer._kv_store.get("coord") == b"old-world"
 
 
 class TestChannelEpochHandling:
@@ -481,7 +486,7 @@ class TestChannelEpochHandling:
         # nothing listens on the address: these tests never touch the
         # wire (they drive _roundtrip with a fake rpc callable)
         return MasterChannel(
-            f"127.0.0.1:{get_free_port()}", max_retry=1, timeout=1.0
+            f"127.0.0.1:{get_free_port()}", timeout=1.0
         )
 
     def test_stale_answer_adopts_and_reissues(self):
@@ -515,9 +520,14 @@ class TestChannelEpochHandling:
                 "get", msg.KeyValuePair(key="k"), timeout=1.0
             )
 
-    def test_kill_switch_stale_raises_immediately(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_MASTER_FAILOVER", "0")
+    def test_fencing_reissues_a_bounded_number_of_times(self):
+        """A fenced call adopts the answer's pair and re-issues
+        transparently — ``MAX_EPOCH_REFRESHES`` wire calls in all
+        against a master that keeps fencing, the change callback once
+        (the pair changed once), then the typed error."""
         chan = self._channel()
+        changes = []
+        chan.on_epoch_change = lambda e, i: changes.append((e, i))
         calls = []
 
         def fake_rpc(payload, timeout):
@@ -531,55 +541,56 @@ class TestChannelEpochHandling:
             chan._roundtrip(
                 "get", msg.KeyValuePair(key="k"), timeout=1.0
             )
-        assert len(calls) == 1  # no transparent refresh
+        assert len(calls) == MasterChannel.MAX_EPOCH_REFRESHES
+        assert changes == [(4, 9)]
+        assert (chan.job_epoch, chan.master_incarnation) == (4, 9)
 
-    def test_kill_switch_envelope_carries_no_epochs(self, monkeypatch):
-        chan = self._channel()
-        chan.job_epoch, chan.master_incarnation = 5, 3
+    def test_envelope_always_carries_the_fencing_pair(self):
+        """Every envelope carries ``(job_epoch, master_incarnation)``
+        as last learned: -1/-1 before the first refresh (never
+        fenced), then whatever a refresh or a fenced answer taught."""
         import pickle
 
+        chan = self._channel()
         env = pickle.loads(chan._wrap(msg.HeartBeat(timestamp=1.0)))
-        assert env.job_epoch == 5
-        monkeypatch.setenv("DLROVER_TPU_MASTER_FAILOVER", "0")
+        assert (env.job_epoch, env.master_incarnation) == (-1, -1)
+        chan.job_epoch, chan.master_incarnation = 5, 3
         env = pickle.loads(chan._wrap(msg.HeartBeat(timestamp=1.0)))
-        assert env.job_epoch == -1
-        assert env.master_incarnation == -1
+        assert (env.job_epoch, env.master_incarnation) == (5, 3)
+        chan._adopt(msg.StaleEpoch(job_epoch=6, incarnation=1))
+        env = pickle.loads(chan._wrap(msg.HeartBeat(timestamp=1.0)))
+        assert (env.job_epoch, env.master_incarnation) == (6, 1)
 
 
 class TestChannelRetryShape:
-    def test_kill_switch_fail_fast_attempt_count(self, monkeypatch):
-        """DLROVER_TPU_MASTER_FAILOVER=0 reproduces today's behavior
-        exactly: max_retry wire attempts on the legacy FIXED sleep
-        schedule (1 s, 2 s, 4 s … cap 5 s — the multi-second stall
-        tolerance the old loop gave a flaky master), then
-        ConnectionError."""
-        monkeypatch.setenv("DLROVER_TPU_MASTER_FAILOVER", "0")
-        chan = MasterChannel(
-            f"127.0.0.1:{get_free_port()}", max_retry=2, timeout=0.2
-        )
-        t0 = time.monotonic()
-        with pytest.raises(ConnectionError):
-            chan.get(msg.KeyValuePair(key="k"), timeout=0.2)
-        assert chan.rpc_count == 2
-        assert chan.reconnect_count == 0  # no channel rebuilds either
-        # legacy sleeps: 1 s after attempt 1, 2 s after attempt 2 —
-        # jittered-exponential (~0.45 s total) would be a behavior
-        # change behind the kill-switch
-        assert time.monotonic() - t0 >= 2.5
+    def test_backoff_is_jittered_exponential_and_clamped(self):
+        """The pause before attempt n+1 is ``0.1 * 2^(n-1)`` s jittered
+        to [0.5, 1.5)x, capped at 5 s before the jitter and never past
+        what is left of the deadline — no fixed schedule a fleet could
+        fall into lockstep on."""
+        chan = MasterChannel(f"127.0.0.1:{get_free_port()}")
+        for attempt in range(1, 10):
+            base = min(0.1 * 2 ** (attempt - 1), 5.0)
+            draws = [chan._backoff(attempt, 1e9) for _ in range(50)]
+            assert all(0.5 * base <= d < 1.5 * base for d in draws)
+            assert len(set(draws)) > 1  # jittered, not a constant
+        assert chan._backoff(9, 0.25) == 0.25
+        assert chan._backoff(1, 0.0) == 0.0
+        chan.close()
 
     def test_failover_deadline_bounds_retries(self, monkeypatch):
         monkeypatch.setenv(
             "DLROVER_TPU_MASTER_RECONNECT_DEADLINE_S", "1.5"
         )
         chan = MasterChannel(
-            f"127.0.0.1:{get_free_port()}", max_retry=2, timeout=0.2
+            f"127.0.0.1:{get_free_port()}", timeout=0.2
         )
         t0 = time.monotonic()
         with pytest.raises(ConnectionError):
             chan.get(msg.KeyValuePair(key="k"), timeout=0.2)
         elapsed = time.monotonic() - t0
         assert elapsed < 10.0  # bounded by the deadline, not 120 s
-        assert chan.rpc_count > 2  # kept trying past max_retry
+        assert chan.rpc_count > 2  # the deadline bounds it, no count
         assert chan.retry_count >= 2
 
     def test_epoch_probe_deadline_bounded(self):

@@ -1,7 +1,7 @@
 """The job observatory: streaming health derivation, derived-signal
 diagnosis, the JobStatusRequest/HTTP surfaces, the closed-loop
-straggler+hang scenario, and the DLROVER_TPU_OBSERVATORY=0
-kill-switch."""
+straggler+hang scenario, and what a master wires with no setting
+at all."""
 
 import json
 import os
@@ -359,7 +359,6 @@ class TestDerivedOperators:
 
 @pytest.fixture
 def observatory_master(monkeypatch):
-    monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "1")
     monkeypatch.setenv("DLROVER_TPU_STATUS_PORT", "0")
     from dlrover_tpu.master.master import LocalJobMaster
 
@@ -420,35 +419,45 @@ class TestStatusSurfaces:
             client.close()
 
 
-class TestKillSwitch:
-    def test_observatory_off_reproduces_today(self, monkeypatch):
-        """DLROVER_TPU_OBSERVATORY=0: no engine, no status surface,
-        legacy diagnosis operator set, no diagnosis instants."""
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "0")
-        monkeypatch.setenv("DLROVER_TPU_STATUS_PORT", "0")
+class TestMasterWiring:
+    def test_master_always_builds_the_observatory(self, monkeypatch):
+        """No setting asked for: the master has a health engine that
+        the timeline aggregator taps, the derived-signal operators
+        beside the whole-job stagnation rule, and the status snapshot
+        on the RPC — the HTTP port alone waits for
+        ``DLROVER_TPU_STATUS_PORT``."""
+        monkeypatch.delenv("DLROVER_TPU_STATUS_PORT", raising=False)
         from dlrover_tpu.master.diagnosis import (
+            DataStallOperator,
             HangOperator,
             HangWatchdogOperator,
+            MasterOverloadOperator,
+            StragglerOperator,
         )
         from dlrover_tpu.master.master import LocalJobMaster
 
         m = LocalJobMaster(get_free_port(), node_num=1)
         try:
-            assert m.health_engine is None
-            assert m.timeline_aggregator._health is None
-            ops = m.diagnosis_manager.chain._operators
-            assert any(isinstance(o, HangOperator) for o in ops)
-            assert not any(
-                isinstance(o, HangWatchdogOperator) for o in ops
-            )
+            assert m.health_engine is not None
+            assert m.timeline_aggregator._health is m.health_engine
+            assert m.master_health is not None
+            kinds = {
+                type(o) for o in m.diagnosis_manager.chain._operators
+            }
+            assert {
+                StragglerOperator, DataStallOperator,
+                HangWatchdogOperator, MasterOverloadOperator,
+                HangOperator,
+            } <= kinds
             m.prepare()
-            # status port requested but the kill-switch wins
-            assert m.status_server is None
+            assert m.status_server is None  # no port was asked for
             chan = MasterChannel(m.addr, node_id=0)
             try:
                 res = chan.get(msg.JobStatusRequest())
-                assert res.available is False
-                assert res.status == {}
+                assert res.available is True
+                assert {"health", "epoch", "master"} <= set(
+                    res.status
+                )
             finally:
                 chan.close()
         finally:

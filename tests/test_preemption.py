@@ -83,17 +83,6 @@ def test_agent_preemption_drains_flushes_and_fences():
     assert agent._preempted
 
 
-def test_agent_preemption_kill_switch_reports_node_error(monkeypatch):
-    """DLROVER_TPU_RESHARD=0 reproduces today's behavior: the report
-    stays a generic node_error (no fencing)."""
-    monkeypatch.setenv("DLROVER_TPU_RESHARD", "0")
-    calls = {"flush": [], "report": []}
-    agent = _bare_agent(calls)
-    agent._on_preemption("TRUE")
-    assert calls["flush"] == ["preemption:TRUE"]
-    assert calls["report"][0][1] == "node_error"
-
-
 class _StubSaver:
     """Stands in for the agent-side AsyncCheckpointSaver: records the
     emergency flush and answers the drain's common-step poll."""
@@ -108,6 +97,52 @@ class _StubSaver:
     def save_shm_to_storage(self, reason=""):
         self.flushes.append(reason)
         return True
+
+
+class _FakeProc:
+    def __init__(self, rc=None):
+        self._rc = rc
+        self.signals = []
+
+    def poll(self):
+        return self._rc
+
+    def send_signal(self, sig):
+        self.signals.append(sig)
+
+
+def test_agent_drain_signals_live_workers_and_returns_on_fresh_step(
+    monkeypatch,
+):
+    """The drain asks every LIVE worker (and no exited one) to
+    snapshot at its next step boundary, then returns the moment a
+    common step newer than the one it started from lands in shm —
+    not at the end of the grace."""
+    from dlrover_tpu.agent.ckpt_saver import AsyncCheckpointSaver
+    from dlrover_tpu.trainer.drain import DRAIN_SIGNAL
+
+    monkeypatch.setenv("DLROVER_TPU_PREEMPT_DRAIN_GRACE_S", "20")
+    calls = {"flush": [], "report": []}
+    agent = _bare_agent(calls)
+    live = [_FakeProc(), _FakeProc()]
+    dead = _FakeProc(rc=0)
+    agent._procs = [live[0], dead, live[1]]
+    stub = _StubSaver()
+    polls = []
+
+    def _common_step():
+        # the fresh snapshot lands once both workers were signalled
+        polls.append(len(live[0].signals) + len(live[1].signals))
+        return 12 if len(polls) > 2 else 11
+
+    stub.max_common_step = _common_step
+    monkeypatch.setattr(AsyncCheckpointSaver, "_instance", stub)
+    t0 = time.monotonic()
+    agent._drain_worker_snapshots("preemption:TRUE")
+    assert time.monotonic() - t0 < 5.0  # far inside the 20 s grace
+    assert [p.signals for p in live] == [[DRAIN_SIGNAL]] * 2
+    assert dead.signals == []
+    assert polls[0] == 0  # the baseline was read before any signal
 
 
 def test_preemption_drain_end_to_end(monkeypatch):

@@ -714,7 +714,6 @@ class TestDeepCaptureE2E:
         from dlrover_tpu.common.env import get_free_port
         from dlrover_tpu.master.master import LocalJobMaster
 
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "1")
         monkeypatch.setenv("DLROVER_TPU_PROFILE", "1")
         monkeypatch.setenv("DLROVER_TPU_HANG_WATCHDOG_S", "0.2")
         monkeypatch.setenv("DLROVER_TPU_JOB_NAME", "capture-e2e")
@@ -821,7 +820,6 @@ class TestProfileKillSwitch:
         from dlrover_tpu.common.env import get_free_port
         from dlrover_tpu.master.master import LocalJobMaster
 
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "1")
         monkeypatch.setenv("DLROVER_TPU_PROFILE", "0")
         monkeypatch.setattr(ds_mod, "_default_store", None)
         master = LocalJobMaster(get_free_port(), node_num=1)

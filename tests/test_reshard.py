@@ -1,13 +1,13 @@
 """Elastic mesh resharding: device-count-agnostic shard format,
-overlap-range resharded restore, shm layout gating, kill-switch.
+overlap-range resharded restore, shm layout gating.
 
 The headline pin is the 8→4→8 round-trip: a simulated 8-host job
 checkpoints an axis-0-sharded optimizer state, "loses" half its
 hosts, reshard-restores onto 4, trains one (simulated) step, saves,
 grows back to 8, and ends with optimizer state BITWISE-identical to
 an uninterrupted run.  Old-format (headerless) shards must still
-restore on an unchanged world, and ``DLROVER_TPU_RESHARD=0`` must
-reproduce the historical restart-from-scratch failure exactly.
+restore on an unchanged world, and a grown world's new rank reads its
+rows out of the old ranks' files.
 """
 
 import json
@@ -342,12 +342,14 @@ class TestReshardRoundTrip:
         finally:
             _close_all(engines)
 
-    def test_kill_switch_reproduces_full_restart_failure(
-        self, tmp_ckpt_dir, monkeypatch
+    def test_grown_world_restores_through_overlap_range_leg(
+        self, tmp_ckpt_dir
     ):
-        """DLROVER_TPU_RESHARD=0: a grown world cannot read the old
-        checkpoint — rank 2 of 4 has no shard_2 file, exactly
-        today's restart-from-scratch behavior."""
+        """A world grown 2 -> 4: rank 2 has no ``shard_2`` file.  A
+        restore that names its layouts reads its rows out of old
+        rank 1's file (rows 4..6 of 8 lie inside rows 4..8); one that
+        names none has only the per-rank file to go by and refuses
+        rather than hand back another rank's rows."""
         g = _opt_state(rows=8, cols=4)
         engines = _engines(tmp_ckpt_dir, 2, "ks_w2")
         try:
@@ -355,7 +357,6 @@ class TestReshardRoundTrip:
         finally:
             _close_all(engines)
 
-        monkeypatch.setenv("DLROVER_TPU_RESHARD", "0")
         # one process per node: rank 2 hosts its own saver endpoints
         eng = CheckpointEngine(
             checkpoint_dir=tmp_ckpt_dir, process_rank=2,
@@ -364,22 +365,8 @@ class TestReshardRoundTrip:
             step_sync_fn=lambda avail: max(avail),
         )
         try:
-            target = _rank_tree(g, 0, 2)
             with pytest.raises(RuntimeError, match="unavailable"):
-                eng.load(layouts=_rank_layouts(target, 2, 4))
-        finally:
-            eng.close()
-        # reshard ON succeeds from the same shards (2-way covers 4-way
-        # only for divisible splits: rank 2 of 4 = rows 2..4 of 8,
-        # inside old rank 1's rows 4..8?  rows 4..6 — yes, covered)
-        monkeypatch.setenv("DLROVER_TPU_RESHARD", "1")
-        eng = CheckpointEngine(
-            checkpoint_dir=tmp_ckpt_dir, process_rank=2,
-            process_count=4, local_shard_num=1, node_rank=2,
-            name="ks_w4_2b",
-            step_sync_fn=lambda avail: max(avail),
-        )
-        try:
+                eng.load()
             per = 2
             target = {
                 "p": np.zeros((per, 4), np.float32),
@@ -391,9 +378,10 @@ class TestReshardRoundTrip:
                 layouts=_rank_layouts(target, 2, 4)
             )
             assert got == 4
-            np.testing.assert_array_equal(
-                arrays["['p']"], g["p"][4:6]
-            )
+            for k in ("p", "m", "v"):
+                np.testing.assert_array_equal(
+                    arrays[f"['{k}']"], g[k][4:6]
+                )
         finally:
             eng.close()
 

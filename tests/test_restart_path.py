@@ -5,8 +5,8 @@ The contracts under test:
 
 - the overlapped restore is BYTE-IDENTICAL to the serial ``load`` —
   from shm (zero-copy staging) and from a leaf-streamed storage shard;
-- ``DLROVER_TPU_RESTART_OVERLAP=0`` and ANY prefetch/compile failure
-  reproduce the serial order (clean fallback, never a corrupt state);
+- ANY prefetch/compile failure, at launch or on the leg's thread,
+  yields the serial order (clean fallback, never a corrupt state);
 - the two legs genuinely run concurrently: their timeline spans'
   mono-anchored intervals intersect;
 - the AOT-compiled train step computes exactly what the lazy jit does.
@@ -37,11 +37,7 @@ from dlrover_tpu.observability.events import (
     set_default_event_logger,
 )
 from dlrover_tpu.trainer.checkpoint.engine import CheckpointEngine
-from dlrover_tpu.trainer.restart_path import (
-    OVERLAP_ENV,
-    RestartCoordinator,
-    overlap_enabled,
-)
+from dlrover_tpu.trainer.restart_path import RestartCoordinator
 
 
 def make_state(scale=1.0):
@@ -303,21 +299,30 @@ class TestRestartCoordinator:
         finally:
             eng.close()
 
-    def test_kill_switch_reproduces_serial(
+    def test_launch_failure_yields_serial_order(
         self, tmp_ckpt_dir, tmp_path, monkeypatch
     ):
+        """A leg that cannot even be LAUNCHED (not one that dies on
+        its thread): the coordinator gives up the overlap for this
+        restart — no compile leg behind the failed prefetch, the lazy
+        step, a serial ``load`` with byte-identical state, and a
+        timeline without leg spans whose parent span is closed."""
         p, log = self._events(tmp_path)
-        monkeypatch.setenv(OVERLAP_ENV, "0")
-        assert not overlap_enabled()
         eng = _engine(tmp_ckpt_dir, "co2")
         try:
             state = make_state()
             assert eng.save_to_memory(3, jax.device_get(state))
+
+            def no_thread(**_kw):
+                raise RuntimeError("can't start new thread")
+
+            monkeypatch.setattr(eng, "start_prefetch", no_thread)
             called = []
             coord = RestartCoordinator(eng, events=log)
             coord.start(
                 compile_fn=lambda: called.append(1) or "artifact"
             )
+            assert coord.overlap is False
             assert (
                 coord.resolve_train_step(fallback="lazy") == "lazy"
             )
@@ -326,11 +331,12 @@ class TestRestartCoordinator:
             assert step == 3
             step_s, serial = eng.load(target=state)
             assert_bytes_equal(restored, serial)
-            # serial order: no overlap spans on the timeline
-            phases = {iv["phase"] for iv in pair_spans(read_events(p))}
+            ivs = pair_spans(read_events(p))
+            phases = {iv["phase"] for iv in ivs}
             assert "restore_prefetch" not in phases
             assert "aot_compile" not in phases
-            assert "restart_path" not in phases
+            assert "finish_restore" not in phases
+            assert "restart_path" in phases  # opened, and closed
         finally:
             eng.close()
 

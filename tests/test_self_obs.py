@@ -1,7 +1,7 @@
 """Control-plane self-telemetry (ISSUE 13): histogram metric type,
 servicer self-instrumentation, journal/datastore health, the
-MasterHealth overload deriver, the SELF_OBS=0 surface pin, and the
-fleet-bench smoke."""
+MasterHealth overload deriver, the metric surface of a master with
+and of a servicer without a collector, and the fleet-bench smoke."""
 
 import json
 import os
@@ -337,7 +337,7 @@ class TestDatastoreHealth:
             MasterSelfTelemetry,
         )
 
-        store = BrainDatastore(str(tmp_path / "b.db"), sync=False)
+        store = BrainDatastore(str(tmp_path / "b.db"))
         release = threading.Event()
         real_write = store._write_batch
         store._write_batch = (
@@ -371,27 +371,35 @@ class TestDatastoreHealth:
         # drained on close: lag returns to zero
         assert store.health()["lag_rows"] == 0
 
-    def test_flush_latency_histogram_gated_by_self_obs(
+    def test_every_flush_lands_in_the_histograms(
         self, tmp_path, monkeypatch
     ):
+        """One observation a flushed batch in
+        ``dlrover_tpu_datastore_flush_seconds`` (the commit latency)
+        and in ``..._flush_rows`` (its size): two drained bursts are
+        two batches, their rows summed."""
         from dlrover_tpu.observability import metrics as m
         from dlrover_tpu.master.datastore import BrainDatastore
 
         registry = MetricsRegistry(path=str(tmp_path / "m.prom"))
         monkeypatch.setattr(m, "_default_registry", registry)
-        monkeypatch.setenv("DLROVER_TPU_SELF_OBS", "0")
-        store = BrainDatastore(str(tmp_path / "b.db"), sync=False)
-        store.record_speed("j", 2, 1.0)
+        store = BrainDatastore(str(tmp_path / "b.db"))
+        for i in range(3):
+            store.record_speed("j", 2, float(i))
+        store._drain()
+        for i in range(4):
+            store.record_speed("j", 2, float(i))
         store.close()
-        assert "datastore_flush" not in registry.render_text()
-        monkeypatch.setenv("DLROVER_TPU_SELF_OBS", "1")
-        store2 = BrainDatastore(str(tmp_path / "b2.db"), sync=False)
-        store2.record_speed("j", 2, 1.0)
-        store2.close()
-        assert (
-            "dlrover_tpu_datastore_flush_seconds_count"
-            in registry.render_text()
-        )
+        lines = registry.render_text().splitlines()
+
+        def value(name):
+            (line,) = [ln for ln in lines if ln.startswith(name + " ")]
+            return float(line.split()[-1])
+
+        batches = value("dlrover_tpu_datastore_flush_seconds_count")
+        assert batches >= 2
+        assert value("dlrover_tpu_datastore_flush_rows_count") == batches
+        assert value("dlrover_tpu_datastore_flush_rows_sum") == 7.0
 
     def test_snapshot_health_from_journal(self, tmp_path):
         from dlrover_tpu.master.datastore import BrainDatastore
@@ -548,7 +556,7 @@ class TestMasterHealthDeriver:
 
 
 # --------------------------------------------------------------------------
-# SELF_OBS=0: the pre-self-obs metric surface, exactly
+# the metric surface with and without a collector
 # --------------------------------------------------------------------------
 
 SELF_OBS_PREFIXES = (
@@ -559,32 +567,33 @@ SELF_OBS_PREFIXES = (
 )
 
 
-class TestSelfObsKillSwitch:
-    def test_surface_pinned_off(self, monkeypatch, tmp_path):
-        """DLROVER_TPU_SELF_OBS=0: no telemetry object, no master
-        status section, and not ONE self-obs-prefixed series in the
-        registry after real traffic."""
+class TestSelfObsSurface:
+    def test_servicer_without_collector_records_nothing(
+        self, monkeypatch, tmp_path
+    ):
+        """A servicer built without a collector (a bench's bare
+        master, a unit test) serves the same RPCs and leaves not ONE
+        self-telemetry series behind; the master always builds one
+        (next test)."""
         from dlrover_tpu.observability import metrics as m
-        from dlrover_tpu.master.master import LocalJobMaster
 
-        monkeypatch.setenv("DLROVER_TPU_SELF_OBS", "0")
         registry = MetricsRegistry(path=str(tmp_path / "m.prom"))
         monkeypatch.setattr(m, "_default_registry", registry)
-        master = LocalJobMaster(get_free_port(), node_num=1)
-        assert master.master_telemetry is None
-        assert master.master_health is None
-        master.prepare()
-        chan = MasterChannel(master.addr, node_id=0)
-        try:
-            chan.report(msg.HeartBeat(timestamp=time.time()))
-            chan.report(msg.KeyValuePair(key="a", value=b"1"))
-            chan.get(msg.KeyValuePair(key="a"))
-            res = chan.get(msg.JobStatusRequest())
-            assert res.available
-            assert "master" not in res.status
-        finally:
-            chan.close()
-            master.stop()
+        servicer, kv = _make_servicer()
+        assert servicer.report(
+            _envelope(msg.KeyValuePair(key="a", value=b"1"))
+        ).success
+        assert servicer.get(
+            _envelope(msg.KeyValuePair(key="a"))
+        ).value == b"1"
+        # a parked wait too: the cap is kept without the gauges
+        out = servicer.get(
+            _envelope(msg.KVWaitRequest(key="nope", wait_timeout=0.05))
+        )
+        assert out.value == b""
+        res = servicer.get(_envelope(msg.JobStatusRequest()))
+        assert res.available is False  # no health engine either
+        assert servicer.rpc_count == 4
         text = registry.render_text()
         offenders = [
             line
@@ -597,7 +606,6 @@ class TestSelfObsKillSwitch:
         from dlrover_tpu.observability import metrics as m
         from dlrover_tpu.master.master import LocalJobMaster
 
-        monkeypatch.setenv("DLROVER_TPU_SELF_OBS", "1")
         registry = MetricsRegistry(path=str(tmp_path / "m.prom"))
         monkeypatch.setattr(m, "_default_registry", registry)
         master = LocalJobMaster(get_free_port(), node_num=1)

@@ -1,7 +1,7 @@
 """Timeline growth bounds: size-based rotation of the agent-side
 JSONL events file and the age/row-cap retention sweep for the Brain
-``timeline_events`` table.  Both are generous by default, configurable,
-and behind the observatory kill-switch."""
+``timeline_events`` table.  Both are generous by default and
+configurable."""
 
 import os
 import time
@@ -17,7 +17,6 @@ def _fill(events: EventLogger, n: int):
 
 class TestEventsFileRotation:
     def test_rotates_past_the_size_cap(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "1")
         # ~8 KB cap; each record is ~200 bytes
         monkeypatch.setenv("DLROVER_TPU_EVENTS_MAX_MB", "0.008")
         path = str(tmp_path / "events.jsonl")
@@ -40,22 +39,42 @@ class TestEventsFileRotation:
         # multi-rotation run are dropped by design)
         assert total <= 3 * EventLogger.ROTATE_CHECK_EVERY + 1
 
-    def test_kill_switch_restores_unbounded_growth(self, tmp_path,
-                                                   monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "0")
-        monkeypatch.setenv("DLROVER_TPU_EVENTS_MAX_MB", "0.008")
+    def test_second_writer_follows_a_rotation(self, tmp_path,
+                                              monkeypatch):
+        """Two processes' loggers share one events file.  When one of
+        them rotates it, the other — whose descriptor now points at
+        the backup — follows to the new file at its next size check
+        instead of rotating the fresh file over the history."""
+        # cap below one check window of bytes: one window, one
+        # rotation
+        monkeypatch.setenv("DLROVER_TPU_EVENTS_MAX_MB", "0.02")
         path = str(tmp_path / "events.jsonl")
-        events = EventLogger(path=path, job="j", node=0, rank=0,
+        first = EventLogger(path=path, job="j", node=0, rank=0,
+                            incarnation=0)
+        second = EventLogger(path=path, job="j", node=0, rank=1,
                              incarnation=0)
-        _fill(events, 3 * EventLogger.ROTATE_CHECK_EVERY)
-        events.close()
-        assert not os.path.exists(path + ".1")
-        assert len(read_events(path)) == (
-            3 * EventLogger.ROTATE_CHECK_EVERY
-        )
+        second.instant("job_start", who="second")  # opens its fd
+        _fill(first, EventLogger.ROTATE_CHECK_EVERY)  # rotates
+        first.instant("job_end", who="first")  # recreates the file
+        assert os.path.exists(path + ".1")
+        backup_size = os.path.getsize(path + ".1")
+        # the second writer's next window still lands in the backup
+        # (its fd), then its size check sees the path moved on
+        _fill(second, EventLogger.ROTATE_CHECK_EVERY - 1)
+        second.instant("job_end", who="second")
+        first.close()
+        second.close()
+        assert os.path.getsize(path + ".1") >= backup_size
+        live = read_events(path)
+        assert [e["labels"]["who"] for e in live] == [
+            "first", "second",
+        ]
+        # nothing was rotated away: every line of both writers is in
+        # one of the two files
+        total = len(live) + len(read_events(path + ".1"))
+        assert total == 2 * EventLogger.ROTATE_CHECK_EVERY + 2
 
     def test_zero_cap_disables_rotation(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "1")
         monkeypatch.setenv("DLROVER_TPU_EVENTS_MAX_MB", "0")
         path = str(tmp_path / "events.jsonl")
         events = EventLogger(path=path, job="j", node=0, rank=0,
@@ -69,7 +88,6 @@ class TestEventsFileRotation:
         a truncation and keeps shipping post-rotation events."""
         from dlrover_tpu.agent.monitor import TimelineReporter
 
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "1")
         # cap > one check window of bytes: at most ONE rotation per
         # size check, so the backup always holds the unshipped tail
         # (a double rotation between ticks is documented-lossy)
@@ -182,7 +200,6 @@ class TestBrainTimelineRetention:
             TimelineAggregator,
         )
 
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "1")
         monkeypatch.setenv("DLROVER_TPU_TIMELINE_MAX_ROWS", "10")
         store = BrainDatastore(str(tmp_path / "b.db"))
         try:
@@ -197,22 +214,28 @@ class TestBrainTimelineRetention:
         finally:
             store.close()
 
-    def test_kill_switch_disables_the_sweep_trigger(self, tmp_path,
-                                                    monkeypatch):
+    def test_sweep_stays_off_the_hot_path_between_intervals(
+        self, tmp_path, monkeypatch
+    ):
+        """One sweep per ``RETENTION_SWEEP_S``: a fresh aggregator's
+        first batches are not swept, and right after a sweep a burst
+        past the cap stays until the interval has passed again."""
         from dlrover_tpu.observability.events import (
             TimelineAggregator,
         )
 
-        monkeypatch.setenv("DLROVER_TPU_OBSERVATORY", "0")
         monkeypatch.setenv("DLROVER_TPU_TIMELINE_MAX_ROWS", "10")
         store = BrainDatastore(str(tmp_path / "b.db"))
         try:
             agg = TimelineAggregator(job="j", datastore=store)
             agg.add_events(0, self._mk_events(30))
+            assert len(store.timeline_events("j")) == 30
             agg._last_retention_sweep = (
                 time.monotonic() - 2 * agg.RETENTION_SWEEP_S
             )
             agg.add_events(0, self._mk_events(5))
-            assert len(store.timeline_events("j")) == 35
+            assert len(store.timeline_events("j")) == 10
+            agg.add_events(0, self._mk_events(5))
+            assert len(store.timeline_events("j")) == 15
         finally:
             store.close()
