@@ -297,6 +297,14 @@ DEVICE_SCOPE_PARTS = frozenset(
         # that reads every cached position
         "window",
         "full",
+        # a gated delta-rule layer (models/olmo_hybrid.py), entered
+        # INSIDE ``attn`` as ``window`` and ``full`` are (projections,
+        # conv, norms, the decode update or the chunk scan, the gated
+        # norm, ``wo``); ``gdn_scan`` INSIDE ``linear`` around the
+        # prefill's chunk scan alone, which tells the scan from the
+        # projections around it
+        "linear",
+        "gdn_scan",
         # final norm + logits of a serving step program
         "head",
         # final norm + logits + cross-entropy of the train step

@@ -272,6 +272,39 @@ def falcon_h1_factory(**cfg_kwargs):
     }
 
 
+def olmo_hybrid_factory(**cfg_kwargs):
+    """Built-in factory of the decoder with gated delta-rule and full
+    attention layers (``models/olmo_hybrid.py``): the same worker
+    contract, with the model's own step programs — its prefill is told
+    the lane and the count of real tokens, because ``cfg.lane_state()``
+    declares a conv tail and a recurrent state a lane, and
+    ``cfg.layer_keeps()`` tells the cache which layers keep them and
+    which keep pages — and its own ``serving_params_fn``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import olmo_hybrid
+
+    if isinstance(cfg_kwargs.get("dtype"), str):
+        # the spec rides through JSON: dtype arrives as a name
+        cfg_kwargs = dict(cfg_kwargs, dtype=jnp.dtype(cfg_kwargs["dtype"]))
+    cfg = olmo_hybrid.OlmoHybridConfig(**cfg_kwargs)
+    return {
+        "forward_fn": partial(olmo_hybrid.forward, cfg=cfg),
+        "params_template_fn": lambda: olmo_hybrid.init_params(
+            jax.random.PRNGKey(0), cfg
+        ),
+        "cfg": cfg,
+        "paged_decode_fn": partial(olmo_hybrid.paged_decode_step, cfg=cfg),
+        "paged_prefill_fn": partial(
+            olmo_hybrid.paged_prefill_chunk, cfg=cfg
+        ),
+        "serving_params_fn": partial(olmo_hybrid.serving_params, cfg=cfg),
+    }
+
+
 def keye_vl2_factory(**cfg_kwargs):
     """Built-in factory of the decoder with sparse experts and a
     learned top-k indexer (``models/keye_vl2.py``): the same worker

@@ -40,6 +40,14 @@ window.  At 16 lanes of 32 k positions, one full and four window layers
 of 4096 cost 2.39 + 1.62 GB where one table for all five would cost
 11.9.
 
+**Layers that keep no keys**: a model whose lanes keep state beside
+their pages MAY say, a layer, which of the two that layer keeps
+(``layer_keeps()``: ``"pages"``, ``"state"`` or ``"both"``).  ``k`` /
+``v`` then hold the layers that page and each state slab the layers
+that hold state, each indexed by the layer's rank among its kind: nine
+recurrent layers and three attention layers page 3 layers' blocks and
+hold 9 layers' state, not 12 of each.
+
 Accounting (the observatory's ``kv_blocks_used`` /
 ``kv_utilization`` gauges read these):
 
@@ -104,16 +112,40 @@ class PagedCacheConfig:
     # sized by :func:`window_table_blocks`
     layer_windows: Tuple[Optional[int], ...] = ()
     window_table_blocks: int = 0
+    # what each layer keeps, as the model declares it: ``"pages"``,
+    # ``"state"`` (the ``lane_state`` leaves and no keys) or ``"both"``.
+    # Empty for a model whose layers all keep whatever it declares.
+    layer_keeps: Tuple[str, ...] = ()
+    # a block's K (or V) lies ``[block_size * KV, head_dim]``, its rows
+    # side by side, and not ``[block_size, KV, head_dim]``: as the model
+    # declares it, whose step programs address the pool
+    flat_pages: bool = False
 
     @property
     def n_window_layers(self) -> int:
         return sum(w is not None for w in self.layer_windows)
 
     @property
+    def n_state_layers(self) -> int:
+        """Layers of the ``lane_state`` slabs (none for a model of
+        pages only, all of them for one that declares no
+        ``layer_keeps``)."""
+        if not self.lane_state:
+            return 0
+        return self.n_layers - self.layer_keeps.count("pages")
+
+    @property
+    def n_paged_layers(self) -> int:
+        """Layers that keep pages of either kind (``k`` / ``v`` or
+        ``wk`` / ``wv``)."""
+        return self.n_layers - self.layer_keeps.count("state")
+
+    @property
     def n_full_layers(self) -> int:
-        """Layers of the ``k`` / ``v`` pool: those that keep every
-        position (all of them for a model that declares no window)."""
-        return self.n_layers - self.n_window_layers
+        """Layers of the ``k`` / ``v`` pool: those that page and keep
+        every position (all of them for a model that declares neither a
+        window nor ``layer_keeps``)."""
+        return self.n_paged_layers - self.n_window_layers
 
     @property
     def window(self) -> Optional[int]:
@@ -191,7 +223,31 @@ def paged_cache_config(
     positions ``[b * block_size, (b + 1) * block_size)`` sits at entry
     ``b % W`` — which :class:`WindowBlocks` (owned by the
     :class:`BlockPool`) fills as the lane advances and empties behind
-    the window.  Such blocks are never shared by prefix or shipped."""
+    the window.  Such blocks are never shared by prefix or shipped.
+
+    Last, a model that declares ``lane_state()`` MAY provide
+    ``layer_keeps() -> ("pages" | "state" | "both", ...)``, one entry a
+    layer: a ``"state"`` layer keeps the lane-state leaves and NO keys
+    (a recurrent layer between attention layers), a ``"pages"`` layer
+    keys and no state, ``"both"`` — every layer's default — both.  ``k``
+    / ``v`` (and the ``paged_leaves``) are then ``[layers that page,
+    ...]`` and each lane-state slab ``[layers that hold state,
+    max_slots, ...]``; a step program addresses either by the layer's
+    RANK among its kind, as it does ``wk`` / ``wv``.  This is the one
+    per-layer declaration beside ``layer_windows()``, and the two
+    cannot disagree: a layer with a window keeps ``"pages"`` (a model
+    with windows declares no lane state at all).  A declaration that
+    says what the default says leaves the config as it was.
+
+    One thing about the LAYOUT of a page is the model's to say as well,
+    because its step programs address the pool: ``flat_pages = True`` —
+    a block's K (or V) lies ``[block_size * KV, head_dim]``, the same
+    bytes in the same order as ``[block_size, KV, head_dim]`` (row ``t *
+    KV + h`` is token ``t`` of KV head ``h``: the view the paged kernels
+    take of any pool).  For a KV head count that is no multiple of the
+    chip's sublane tile (30): there ``[block_size, 30, head_dim]`` is
+    padded to 32 in memory, and turning it into the kernels' view moves
+    the whole pool."""
 
     def declared(method):
         method = getattr(model_cfg, method, None)
@@ -238,6 +294,32 @@ def paged_cache_config(
         table_blocks = window_table_blocks(
             min(sizes), prefill_chunk, block_size
         )
+    keeps = getattr(model_cfg, "layer_keeps", None)
+    keeps = tuple(keeps()) if keeps else ()
+    if keeps:
+        kinds = ("pages", "state", "both")
+        for ok, what in (
+            (len(keeps) == model_cfg.n_layers,
+             f"names {len(keeps)} layers of {model_cfg.n_layers}"),
+            (all(k in kinds for k in keeps),
+             f"takes one of {kinds} a layer (got {sorted(set(keeps))})"),
+            (bool(leaves) or all(k == "pages" for k in keeps),
+             "names layers that keep state, and the model declares no "
+             "lane_state()"),
+            (not leaves or any(k != "pages" for k in keeps),
+             "leaves no layer to keep the lane_state() the model declares"),
+            (any(k != "state" for k in keeps),
+             "leaves no layer that keeps pages (the sequence's pool and "
+             "tables are theirs)"),
+            (all(k == "pages" for k, w in zip(keeps, windows)
+                 if w is not None),
+             "disagrees with layer_windows(): a layer with a window "
+             "keeps pages"),
+        ):
+            if not ok:
+                raise ValueError(f"layer_keeps() {what}")
+        if all(k == ("both" if leaves else "pages") for k in keeps):
+            keeps = ()  # what every layer does undeclared
     return PagedCacheConfig(
         n_layers=model_cfg.n_layers,
         n_kv_heads=model_cfg.n_kv_heads,
@@ -250,6 +332,8 @@ def paged_cache_config(
         paged_leaves=paged,
         layer_windows=windows,
         window_table_blocks=table_blocks,
+        layer_keeps=keeps,
+        flat_pages=bool(getattr(model_cfg, "flat_pages", False)),
     )
 
 
@@ -262,7 +346,11 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
     1) * width)`` of its row).  Where the model declares windows,
     ``k``, ``v`` hold the layers WITHOUT one (in layer order) and
     ``wk``, ``wv`` ``[window layers, window_blocks, block_size, KV,
-    head_dim]`` the others'."""
+    head_dim]`` the others'; where it declares ``layer_keeps``, ``k``,
+    ``v`` hold the layers that page and each slab the layers that hold
+    state, both in layer order; where it declares ``flat_pages``, a
+    block's rows lie side by side: ``[L, num_blocks, block_size * KV,
+    head_dim]``."""
     shape = (
         cfg.n_full_layers,
         cfg.num_blocks,
@@ -270,6 +358,8 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
         cfg.n_kv_heads,
         cfg.head_dim,
     )
+    if cfg.flat_pages:
+        shape = shape[:2] + (cfg.block_size * cfg.n_kv_heads, cfg.head_dim)
     pool = {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
@@ -280,7 +370,7 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
         pool["wv"] = jnp.zeros(wshape, dtype=cfg.dtype)
     for name, leaf_shape, dtype in cfg.lane_state:
         pool[name] = jnp.zeros(
-            (cfg.n_layers, cfg.max_slots) + leaf_shape, dtype=dtype
+            (cfg.n_state_layers, cfg.max_slots) + leaf_shape, dtype=dtype
         )
     for name, leaf_shape, dtype in cfg.paged_leaves:
         # a block's rows side by side in ONE minor axis: a minor axis
@@ -627,6 +717,11 @@ class BlockPool:
             more = dict(self.window.stats(), full_blocks_live=self.used_blocks)
         return {
             **more,
+            # of the model's layers, how many keep pages and how many
+            # per-lane state (a block id names a block in every one of
+            # the former; what a block holds is not known here)
+            "paged_layers": self.cfg.n_paged_layers,
+            "state_layers": self.cfg.n_state_layers,
             "used_blocks": self.used_blocks,
             "free_blocks": self.free_blocks,
             "cached_shared_blocks": self.cached_shared_blocks,

@@ -94,6 +94,7 @@ from dlrover_tpu.rl.kv_cache import (
     paged_cache_config,
     pool_can_ever_hold,
     prefix_block_keys,
+    region_nbytes_per_block,
 )
 
 SLO_INTERACTIVE = "interactive"
@@ -411,8 +412,8 @@ class ContinuousBatchingScheduler:
     """The token-level serving loop over a paged KV cache.
 
     What a model must provide (``models/llama.py``,
-    ``models/falcon_h1.py``, ``models/keye_vl2.py`` and
-    ``models/trinity.py`` do):
+    ``models/falcon_h1.py``, ``models/keye_vl2.py``,
+    ``models/trinity.py`` and ``models/olmo_hybrid.py`` do):
 
     - ``model_cfg``: the paged K/V geometry as attributes
       (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and,
@@ -420,7 +421,13 @@ class ContinuousBatchingScheduler:
       lane keeps per layer beside its pages (a recurrent state, a
       convolution's tail).  ``rl/kv_cache.paged_cache_config`` reads
       both; the scheduler owns the resulting pool, the state slabs
-      indexed by lane, the K/V by :class:`BlockPool`'s tables.  Also
+      indexed by lane, the K/V by :class:`BlockPool`'s tables.  Such a
+      model MAY also say which layers keep which (``layer_keeps() ->
+      ("pages" | "state" | "both", ...)``, ``models/olmo_hybrid.py``):
+      the pool then holds ``k``, ``v`` for the layers that page and
+      each slab for the layers that hold state, and nothing here
+      changes — the refusals below and the ``(lane, real)`` contract
+      are those of any model with lane state.  Also
       optionally ``paged_leaves() -> {leaf: (shape, dtype)}`` — what a
       TOKEN keeps per layer beside its K and V (an index key): a
       further leaf in the same blocks under the same tables, shared by
@@ -748,6 +755,9 @@ class ContinuousBatchingScheduler:
         self.block_pool = BlockPool(cache_cfg)
         self._pool = init_block_pool(cache_cfg)
         self.state_bytes = lane_state_nbytes(self._pool, cache_cfg)
+        # bytes one block id names over the layers that page (K and V;
+        # a model with lane state pages nothing else)
+        self._block_bytes = 2 * region_nbytes_per_block(self._pool)
         # the draft pool mirrors the policy pool's GEOMETRY (same
         # block ids, tables, block size) with the DRAFT model's shapes
         # — one host-side allocator drives both
@@ -2470,11 +2480,27 @@ class ContinuousBatchingScheduler:
                 slots=self.sched.max_slots,
                 state_bytes=self.state_bytes,
                 state_resets=self._step_state_resets,
+                **self._cache_labels(),
                 **self._selection_labels(),
             )
         self.sel_rows += self._step_sel_rows
         self.index_bytes += self._step_index_bytes
         return finished
+
+    def _cache_labels(self) -> Dict:
+        """The ``serve_step`` labels of a model that keeps per-lane
+        state: how its layers divide between the two kinds of cache and
+        what the cache held this step — the state slabs and the blocks
+        live over the layers that page; none for a model of pages only
+        (its record is as it was)."""
+        if not self.lane_state:
+            return {}
+        return dict(
+            state_layers=self.pool_cfg.n_state_layers,
+            paged_layers=self.pool_cfg.n_paged_layers,
+            cache_bytes=self.state_bytes
+            + self.block_pool.used_blocks * self._block_bytes,
+        )
 
     def _selection_labels(self) -> Dict:
         """The ``serve_step`` labels of a model with an indexer or a

@@ -73,6 +73,15 @@ TINY = {
         moe_intermediate_size=32, num_experts=2, num_experts_per_tok=2,
         sliding_window=16,
     ),
+    # one whole period of the published pattern; head sizes that are
+    # not powers of two (a packed state, flat pages)
+    "family_olmo_hybrid": lambda cfg: dict(
+        hidden_size=72, intermediate_size=96, num_hidden_layers=4,
+        layer_types=cfg["layer_types"][:4], vocab_size=384,
+        num_attention_heads=3, num_key_value_heads=3,
+        linear_num_key_heads=4, linear_num_value_heads=4,
+        linear_key_head_dim=12, linear_value_head_dim=24,
+    ),
 }
 
 

@@ -118,9 +118,11 @@ def test_the_reader_repeats_the_programs_closed_set():
     # which a program PR may not edit, counts its time under ``attn``,
     # and ``readers_sparse.scope_share`` reads it with the part added
     # ``window`` / ``full`` (PR 44) likewise: the kind of a layer's
-    # attention, inside ``attn``
+    # attention, inside ``attn``; ``linear`` (PR 49) too, and
+    # ``gdn_scan`` inside ``linear``: a gated delta-rule layer and its
+    # prefill's chunk scan
     assert set(readers_scopes.PARTS) | {
-        "indexer", "window", "full"
+        "indexer", "window", "full", "linear", "gdn_scan"
     } == DEVICE_SCOPE_PARTS
     assert not DEVICE_SCOPE_ROLES & DEVICE_SCOPE_PARTS
 

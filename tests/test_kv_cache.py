@@ -564,6 +564,212 @@ class TestWindowLayers:
             pool.allocate(2, 4)
             pool.free(2)
         a, b = plain.stats(), windowed.stats()
+        # (how many layers page is the model's: two here, five there)
+        assert a.pop("paged_layers") == CACHE_CFG.n_layers
+        assert b["paged_layers"] == 5 and b["state_layers"] == 0
         assert {k: b[k] for k in a} == a
         assert b["full_blocks_live"] == a["used_blocks"]
         assert plain.blocks_of(1) == windowed.blocks_of(1)
+
+
+# ---------------------------------------------------------------------------
+# one declaration of what a layer keeps (ISSUE 49): layers that keep
+# lane state and no keys beside layers that keep pages and no state
+# ---------------------------------------------------------------------------
+
+
+class _HybridModel:
+    """A model config as ``paged_cache_config`` reads it: four layers,
+    the last an attention layer between recurrent ones."""
+
+    n_layers, n_kv_heads, head_dim, dtype = 4, 3, 8, jnp.float32
+
+    def __init__(self, keeps=("state", "state", "state", "pages"),
+                 state=True, flat=False, windows=None):
+        self._keeps, self._state, self.flat_pages = keeps, state, flat
+        if windows is not None:
+            self.layer_windows = lambda: windows
+
+    def lane_state(self):
+        if not self._state:
+            return {}
+        return {"conv": ((6,), jnp.float32), "s": ((2, 4, 8), jnp.float32)}
+
+    def layer_keeps(self):
+        return self._keeps
+
+
+def _hybrid_cfg(model=None, **kw):
+    from dlrover_tpu.rl.kv_cache import paged_cache_config
+
+    args = dict(num_blocks=21, block_size=4, max_slots=5, prefill_chunk=8)
+    args.update(kw)
+    return paged_cache_config(model or _HybridModel(), **args)
+
+
+class TestLayerKeeps:
+    def test_each_pool_holds_the_layers_of_its_kind(self):
+        from dlrover_tpu.rl.kv_cache import lane_state_nbytes
+
+        cfg = _hybrid_cfg()
+        assert (cfg.n_full_layers, cfg.n_paged_layers,
+                cfg.n_state_layers) == (1, 1, 3)
+        pool = init_block_pool(cfg)
+        assert {k: v.shape for k, v in pool.items()} == {
+            "k": (1, 21, 4, 3, 8), "v": (1, 21, 4, 3, 8),
+            "conv": (3, 5, 6), "s": (3, 5, 2, 4, 8),
+        }
+        # bytes: one table for four layers would page 4x and hold a
+        # state for the layer that has none
+        assert pool["k"].nbytes == 21 * 4 * 3 * 8 * 4
+        assert lane_state_nbytes(pool, cfg) == 3 * 5 * (6 + 64) * 4
+        st = BlockPool(cfg).stats()
+        assert (st["paged_layers"], st["state_layers"]) == (1, 3)
+
+    def test_both_is_the_default_and_leaves_the_config_as_it_was(self):
+        """Falcon-H1's case: every layer keeps state AND pages, declared
+        or not."""
+        said = _hybrid_cfg(_HybridModel(("both",) * 4))
+
+        class Undeclared(_HybridModel):
+            layer_keeps = None
+
+        unsaid = _hybrid_cfg(Undeclared())
+        assert said == unsaid and said.layer_keeps == ()
+        assert (said.n_full_layers, said.n_state_layers) == (4, 4)
+        pool = init_block_pool(said)
+        assert pool["k"].shape[0] == 4 and pool["s"].shape[0] == 4
+        # and a model of pages only says "pages" of every layer
+        dense = _hybrid_cfg(_HybridModel(("pages",) * 4, state=False))
+        assert dense.layer_keeps == () and dense.n_state_layers == 0
+        assert sorted(init_block_pool(dense)) == ["k", "v"]
+        st = BlockPool(dense).stats()
+        assert (st["paged_layers"], st["state_layers"]) == (4, 0)
+
+    def test_a_layer_may_keep_both_beside_layers_that_keep_one(self):
+        cfg = _hybrid_cfg(_HybridModel(("state", "both", "pages", "state")))
+        assert (cfg.n_full_layers, cfg.n_state_layers) == (2, 3)
+        pool = init_block_pool(cfg)
+        assert pool["k"].shape[0] == 2 and pool["conv"].shape[0] == 3
+
+    def test_flat_pages_hold_the_same_bytes_side_by_side(self):
+        """A KV head count the chip's tiling would pad (3; the model's
+        30) lies ``[block_size * KV, D]``."""
+        flat = init_block_pool(_hybrid_cfg(_HybridModel(flat=True)))
+        tiled = init_block_pool(_hybrid_cfg())
+        assert flat["k"].shape == flat["v"].shape == (1, 21, 4 * 3, 8)
+        assert flat["k"].nbytes == tiled["k"].nbytes
+        assert flat["s"].shape == tiled["s"].shape
+
+    @pytest.mark.parametrize("keeps,state,windows,why", [
+        (("state", "pages"), True, None, "names 2 layers of 4"),
+        (("state", "keys", "pages", "pages"), True, None,
+         "takes one of"),
+        (("state", "state", "state", "pages"), False, None,
+         "names layers that keep state"),
+        (("pages",) * 4, True, None, "leaves no layer to keep the "
+                                     "lane_state"),
+        (("state",) * 4, True, None, "leaves no layer that keeps pages"),
+        (("pages", "both", "pages", "pages"), False, None,
+         "names layers that keep state"),
+    ])
+    def test_misuse_is_refused_by_name(self, keeps, state, windows, why):
+        with pytest.raises(ValueError, match="layer_keeps\\(\\) " + why):
+            _hybrid_cfg(_HybridModel(keeps, state, windows=windows))
+
+    def test_it_cannot_disagree_with_the_windows(self):
+        """A layer with a window keeps pages: a declaration that says so
+        composes, one that says otherwise cannot be made (lane state
+        beside windows is refused where the windows are read)."""
+        agrees = _hybrid_cfg(_HybridModel(
+            ("pages",) * 4, state=False, windows=(16, 16, 16, None),
+        ))
+        assert agrees.n_window_layers == 3 and agrees.n_full_layers == 1
+        assert agrees.layer_keeps == ()
+        with pytest.raises(ValueError, match="declares no lane_state"):
+            _hybrid_cfg(_HybridModel(
+                ("state", "pages", "pages", "pages"), state=True,
+                windows=(16, 16, 16, None),
+            ))
+
+    def test_block_pool_hands_out_ids_and_knows_no_layer(self):
+        """The allocator is the one it was: ids, tables, no notion of
+        what a block holds."""
+        pool = BlockPool(_hybrid_cfg())
+        blocks = pool.allocate(0, 9)
+        assert len(blocks) == 3 and 0 not in blocks
+        assert pool.table_row(0, 6) == blocks + [0, 0, 0]
+        pool.free(0)
+        assert pool.used_blocks == 0
+
+
+#: ``init_block_pool``'s shape tree at each serving cell's geometry, as
+#: the parent of ISSUE 49 made it: the new declaration moves none
+CELL_POOLS = {
+    "deepseek7b-rollout-c16": {
+        "k": ((5, 1152, 16, 32, 128), "bfloat16"),
+        "v": ((5, 1152, 16, 32, 128), "bfloat16"),
+    },
+    "falconh1-34b-rollout-c32": {
+        "conv": ((6, 32, 3, 5120), "float32"),
+        "k": ((6, 2304, 16, 4, 128), "bfloat16"),
+        "ssm": ((6, 32, 32, 128, 256), "float32"),
+        "v": ((6, 2304, 16, 4, 128), "bfloat16"),
+    },
+    "keye-vl2-rollout-c16-ctx16k": {
+        "ik": ((5, 18240, 1024), "bfloat16"),
+        "k": ((5, 18240, 16, 4, 128), "bfloat16"),
+        "v": ((5, 18240, 16, 4, 128), "bfloat16"),
+    },
+    "trinity-large-rollout-c16-ctx32k": {
+        "k": ((1, 36416, 16, 8, 128), "bfloat16"),
+        "v": ((1, 36416, 16, 8, 128), "bfloat16"),
+        "wk": ((4, 6161, 16, 8, 128), "bfloat16"),
+        "wv": ((4, 6161, 16, 8, 128), "bfloat16"),
+    },
+    # three layers page, nine hold state; 12.94 GB with the weights
+    "olmo-hybrid-rollout-c64": {
+        "conv": ((9, 64, 34560), "float32"),
+        "gdn": ((9, 64, 15, 96, 384), "float32"),
+        "k": ((3, 6848, 480, 128), "bfloat16"),
+        "v": ((3, 6848, 480, 128), "bfloat16"),
+    },
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_POOLS))
+def test_the_pool_of_every_serving_cell_is_what_it_was(cell):
+    """To the byte: the shape tree (nothing is allocated)."""
+    from dlrover_tpu.rl.kv_cache import paged_cache_config
+
+    bench = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks",
+    )
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import harness
+
+    loaded = harness.load_cell(cell)
+    t = loaded["traffic"]
+    fam = harness.family(loaded["config"])
+    parts = fam.serving_parts(
+        **fam.model_kwargs(loaded["config"], t["max_seq_len"]),
+        dtype="bfloat16",
+    )
+    cfg = paged_cache_config(
+        parts["cfg"], t["num_blocks"], t["block_size"], t["max_slots"],
+        t["prefill_chunk"],
+    )
+    pool = jax.eval_shape(lambda: init_block_pool(cfg))
+    assert {
+        k: (v.shape, str(v.dtype)) for k, v in pool.items()
+    } == CELL_POOLS[cell]
+    if cell == "olmo-hybrid-rollout-c64":
+        nbytes = {
+            k: int(np.prod(v.shape)) * v.dtype.itemsize
+            for k, v in pool.items()
+        }
+        assert round(2 * nbytes["k"] / 1e9, 2) == 5.05
+        assert round((nbytes["conv"] + nbytes["gdn"]) / 1e9, 2) == 1.35
+        assert (cfg.n_paged_layers, cfg.n_state_layers) == (3, 9)

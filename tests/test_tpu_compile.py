@@ -227,6 +227,66 @@ def _window_decode_case(window):
     )
 
 
+def _gdn_case():
+    """The gated delta rule's decode update at Olmo-Hybrid-7B's widths
+    and the benchmark cell's geometry: 9 linear layers x 64 lanes of 30
+    heads x 96 x 192 float32 states, held as 15 pairs of heads ``[96,
+    384]`` (``ops/gdn.state_shape``: 3 lane tiles, no padding)."""
+    from dlrover_tpu.ops import gdn
+
+    f32 = jnp.float32
+    layers, lanes, heads, dk, dv = 9, 64, 30, 96, 192
+    assert gdn.state_shape(heads, dk, dv) == (15, 96, 384)
+
+    def fn(state, layer, q, k, v, alpha, beta, real):
+        return gdn.gdn_decode_update(
+            state, layer, q, k, v, alpha, beta, real, backend="pallas"
+        )
+
+    return fn, (
+        ((layers, lanes, 15, 96, 384), f32), ((), jnp.int32),
+        ((lanes, heads, dk), f32), ((lanes, heads, dk), f32),
+        ((lanes, heads, dv), f32), ((lanes, heads), f32),
+        ((lanes, heads), f32), ((lanes,), jnp.bool_),
+    )
+
+
+def _kv30_case(kernel):
+    """The paged kernels over 30 KV heads (Olmo-Hybrid-7B's full
+    layers: MHA, one query row a KV head), the pool as its step
+    programs hold it — a block's rows side by side, ``[3 x 6848, 16 x
+    30, 128]`` (``flat_pages``) — at the cell's geometry: 64 lanes,
+    tables of 96 blocks; a 256-row chunk against 1536 keys rounded up
+    to two key blocks of 1024."""
+    from dlrover_tpu.ops import paged_attention as pa
+    from dlrover_tpu.ops.paged_kernels import chunk_prefill_kernel
+
+    if kernel == "decode":
+        pool = ((3 * 6848, 16 * 30, D), BF16)
+
+        def fn(q, k, v, tables, lens):
+            shape = (-1, 16, 30, D)
+            return pa.paged_decode_attention(
+                q, k.reshape(shape), v.reshape(shape), tables, lens,
+                backend="pallas", name="paged_full_decode",
+            )
+
+        return fn, (
+            ((64, 30, D), BF16), pool, pool, ((64, 96), jnp.int32),
+            ((64,), jnp.int32),
+        )
+    keys = ((30, 2048, D), BF16)
+
+    def fn(q, k, v, start, key0):
+        return chunk_prefill_kernel(
+            q, k, v, start, key0, name="paged_prefill_full"
+        )
+
+    return fn, (
+        ((256, 30, D), BF16), keys, keys, ((), jnp.int32), ((), jnp.int32),
+    )
+
+
 def _chunk_prefill_case(window):
     """A 2048-row chunk's streamed attention at Trinity-Large's widths
     against the keys of its kind, gathered by position: a window
@@ -280,6 +340,9 @@ CASES = {
     "moe_expert_ffn_decode": lambda: _expert_ffn_case(16),
     "moe_expert_ffn_chunk": lambda: _expert_ffn_case(2048),
     "ssm_decode_update": _ssm_case,
+    "gdn_decode_update": _gdn_case,
+    "paged_full_decode_kv30": lambda: _kv30_case("decode"),
+    "paged_prefill_full_kv30": lambda: _kv30_case("prefill"),
     "flash_fwd": lambda: _flash_case(H, backward=False),
     "flash_fwd_bwd_mha": lambda: _flash_case(H, backward=True),
     "flash_fwd_bwd_gqa8": lambda: _flash_case(8, backward=True),
@@ -304,6 +367,9 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     ("paged_verify_w4_kv32", "paged_verify"),
     ("rms_norm_fwd_bwd", "rmsnorm_fwd"),
     ("ssm_decode_update", "ssm_decode_update"),
+    ("gdn_decode_update", "gdn_decode_update"),
+    ("paged_full_decode_kv30", "paged_full_decode"),
+    ("paged_prefill_full_kv30", "paged_prefill_full"),
     ("sparse_prefill", "sparse_prefill"),
     ("index_scores", "index_scores"),
     ("paged_window_decode", "paged_window_decode"),
@@ -1017,6 +1083,160 @@ def test_ssm_state_is_updated_in_place(one_chip):
         line for line in compiled.as_text().splitlines()
         if " copy(" in line and "f32[6,32,32,128,256]" in line
     ]
+
+
+def test_a_flat_pool_of_30_kv_heads_reaches_the_kernel_whole(one_chip):
+    """30 KV heads are no multiple of the chip's sublane tile: as ``[N,
+    16, 30, 128]`` a pool is padded to 32 heads in memory and copied
+    WHOLE into the ``[N, 16 x 30, 128]`` view the decode kernel takes
+    (5.05 GB of pool, two copies of 2.35 GB a step: it does not even
+    fit).  Held flat (``flat_pages``) the two pools are the kernel's
+    operands as they lie; only the 30 query rows a lane are padded."""
+    fn, shapes = _kv30_case("decode")
+    text = _compiled_text(fn, *shapes, sharding=one_chip)
+    (call,) = [
+        line for line in text.splitlines()
+        if "custom-call(" in line and "tpu_custom_call" in line
+    ]
+    layouts = call.split("backend_config")[0].split(
+        "operand_layout_constraints="
+    )[1]
+    assert layouts.count("bf16[%d,%d,%d]" % (3 * 6848, 16 * 30, D)) == 2
+    assert not [
+        line[:120] for line in text.splitlines()
+        if " copy(" in line and "bf16[20544," in line
+    ]
+
+
+def test_gdn_state_is_updated_in_place_and_holds_no_padding(one_chip):
+    """The gated delta rule's decode kernel addresses one slab of the
+    stacked ``[linear layers, lanes, 15, 96, 384]`` state through its
+    index maps and aliases the buffer to its output: donated, nothing of
+    the 1.27 GB is copied, and the slab's ON-DEVICE bytes are its
+    logical bytes — as ``[.., 30, 96, 192]`` the chip's ``(8, 128)``
+    tiling would hold a third more (192 padded to 256)."""
+    fn, shapes = _gdn_case()
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        *[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes
+        ]
+    ).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = 9 * 64 * 30 * 96 * 192 * 4
+    small = 64 * (2 * 30 * 96 + 30 * 192 + 2 * 30 + 1) * 4 + 4
+    # the arguments: the state at its logical size and the token's rows
+    assert state_bytes <= mem.argument_size_in_bytes < (
+        state_bytes + 2 * small + 2**20
+    )
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < 16 * 2**20
+    assert not [
+        line for line in compiled.as_text().splitlines()
+        if " copy(" in line and "f32[9,64,15,96,384]" in line
+    ]
+
+
+@pytest.mark.parametrize(
+    "program", ["decode", "prefill_nohead", "prefill_last"]
+)
+def test_hybrid_linear_attention_block_keeps_every_pool_in_place(
+        program, one_chip):
+    """Olmo-Hybrid-7B's step programs at ``olmo-hybrid-rollout-c64``'s
+    geometry (the published widths at three whole periods, 9 linear + 3
+    full layers, the whole vocabulary; 64 lanes, 6848 blocks of 16,
+    tables of 96, chunk 256): the three full layers' pages ``[3, 6848,
+    480, 128]`` and the nine linear layers' slabs — the conv tails
+    ``[9, 64, 34560]`` and the states ``[9, 64, 15, 96, 384]`` — are
+    aliased to the outputs at their LOGICAL bytes (6.40 GB: no padding
+    of 30 KV heads, of 192 columns or of 3 conv rows) and never moved,
+    no fused projection is copied, nothing is written page by page in a
+    loop, the temporaries stay small, and each kernel carries its name.
+    Arguments + temporaries (``assumed.depth_choice`` of the
+    configuration file): decode 12.05 + 0.03 GiB, a chunk 11.07 + 0.16,
+    the last chunk 12.05 + 0.16, of 15.75."""
+    from dlrover_tpu.models import olmo_hybrid as model
+    from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV
+    from dlrover_tpu.rl.kv_cache import init_block_pool, paged_cache_config
+
+    kinds = (model.LINEAR,) * 3 + (model.FULL,)
+    cfg = model.OlmoHybridConfig(
+        num_hidden_layers=12, layer_types=kinds * 3, max_seq_len=1536
+    )
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def seeded():  # as the benchmark seeds it: matrices in bfloat16
+        tree = model.init_params(jax.random.PRNGKey(0), cfg)
+        return model.serving_params(jax.tree_util.tree_map(
+            lambda a: a.astype(BF16) if a.ndim >= 2 and a.shape[0] != 4
+            else a, tree,
+        ), cfg)
+
+    params = jax.tree_util.tree_map(spec, jax.eval_shape(seeded))
+    cache = paged_cache_config(cfg, 6848, 16, 64, 256)
+    assert (cache.n_full_layers, cache.n_state_layers) == (3, 9)
+    pool = jax.tree_util.tree_map(
+        spec, jax.eval_shape(lambda: init_block_pool(cache))
+    )
+    assert pool["k"].shape == (3, 6848, 16 * 30, 128)
+    assert pool["gdn"].shape == (9, 64, 15, 96, 384)
+    pool_bytes = sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in pool.values()
+    )
+    assert 6.40e9 < pool_bytes < 6.41e9
+    if program == "decode":
+        fn, rest = _scheduler_decode(
+            partial(model.paged_decode_step, cfg=cfg), 64, 96
+        )
+    else:
+        fn, rest = _scheduler_prefill(
+            partial(model.paged_prefill_chunk, cfg=cfg), 64, True,
+            program == "prefill_last", 256, 96,
+        )
+    tokens, *after = [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in rest
+    ]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(PAGED_KERNEL_ENV, "pallas")
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+            params, tokens, pool, *after
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    # every pool aliased, at its logical bytes: a padded layout would
+    # alias (and hold) more
+    assert mem.alias_size_in_bytes == pool_bytes
+    gib = 2**30
+    assert mem.argument_size_in_bytes < (
+        11.2 if program == "prefill_nohead" else 12.2
+    ) * gib
+    assert mem.temp_size_in_bytes < (
+        64 if program == "decode" else 256
+    ) * 2**20
+    pools = {math.prod(a.shape) for a in pool.values()}
+    layer = {math.prod(a.shape[1:]) for a in pool.values()}
+    fused = {3840 * 17340, 3840 * 3 * 3840}
+    moved = [
+        line[:160] for elements, op, line in _materialised(text)
+        if elements in pools | layer | fused
+        and re.match(r"(ROOT )?%(copy|dynamic-slice|slice|transpose)", line)
+        and not re.match(r"(ROOT )?%copy-(start|done)", line)
+    ]
+    assert not moved, moved
+    # a page write is a scatter of whole blocks: no loop over the lanes
+    # or the chunk's rows (the chunk scan's own loop over its four
+    # sub-chunks is the only kind there is)
+    loops = re.findall(r'while\(.*?op_name="([^"]*)"', text)
+    assert all("gdn_scan" in name for name in loops), loops
+    assert (program == "decode") == (not loops)
+
+    def kernel(name):  # an instruction of that name, not a path
+        return re.search(rf"%{name}(\.\d+)* = ", text) is not None
+
+    assert kernel("gdn_decode_update") == (program == "decode")
+    assert kernel("paged_full_decode") == (program == "decode")
+    assert kernel("paged_prefill_full") == (program != "decode")
 
 
 def _train_state_shapes():
