@@ -55,9 +55,17 @@ the minor axis, a multiple of 128 lanes), and a block's K (or V) as
 ``[block_size * KV, D]`` (``flat_pages``): 30 KV heads are no multiple
 of the chip's sublane tile, ``[block_size, 30, D]`` would be padded to
 32 in memory and copied whole into the view the paged kernels take of
-it.  The layers differ, so they are UNROLLED, each with its own leaves (``params["layers"]`` is a tuple
-of dicts: no stack is sliced), the page pool rides through them flat
-and every slab is written in place at a static rank.
+it.  The layers differ, so they are UNROLLED, each with its own leaves
+(``params["layers"]`` is a tuple of dicts: no stack is sliced), the
+page pool rides through them flat and every slab is written in place at
+a static rank.  A prefill chunk is ONE lane's: it reads that lane's
+conv tail and state where they lie, one ``dynamic_slice`` of the
+stacked pool at ``(rank, lane, ...)`` — the mirror of the
+``dynamic_update_slice`` that writes them back; a ``pool[rank]`` first
+would copy all 64 lanes' slabs of the layer, 141.6 MB for 2.2 — and
+its WY systems are inverted by products (``ops/gdn``), so the chunk
+program calls no routine of the compiler's library.  The decode step
+takes the layer as its kernel's scalar-prefetch index.
 
 There is no training path.
 """
@@ -711,9 +719,14 @@ def paged_prefill_chunk(
         with jax.named_scope("attn"), _kind_scope(kind):
             if kind == LINEAR:
                 qkv, g, a, b = _linear_inputs(x, lp, cfg)
+                # the lane's slabs are read where they lie, as they are
+                # written back: one slice of the stacked pool (a
+                # ``conv_all[j]`` would copy every lane's)
                 tail = jnp.where(
                     fresh, 0.0,
-                    lax.dynamic_index_in_dim(conv_all[j], lane, 0, False),
+                    lax.dynamic_slice(
+                        conv_all, (j, lane, 0), (1, 1) + conv_all.shape[2:]
+                    ),
                 ).reshape(taps - 1, cfg.conv_dim)
                 window = jnp.concatenate([tail, qkv[0]], axis=0)
                 q, k, v = _qkv_heads(_causal_conv(window, lp["conv_w"]), cfg)
@@ -730,7 +743,10 @@ def paged_prefill_chunk(
                 state = jnp.where(
                     fresh, 0.0,
                     unpack_state(
-                        lax.dynamic_index_in_dim(gdn_all[j], lane, 0, False),
+                        lax.dynamic_slice(
+                            gdn_all, (j, lane, 0, 0, 0),
+                            (1, 1) + gdn_all.shape[2:],
+                        )[0, 0],
                         heads,
                     ),
                 )

@@ -29,11 +29,15 @@ v_t^T``.  Three ops:
   ``(I + A) U = beta (V - diag(g) K S_0)``; then ``O = diag(g) Q S_0 +
   tril(Q K^T * decay) U`` and ``S_C = g_C S_0 + (K * g_C / g)^T U``.
   The part of ``U`` that does not depend on ``S_0`` and the part that
-  multiplies it are solved for every sub-chunk at once; a ``lax.scan``
-  carries ``S`` from one sub-chunk to the next.  Plain XLA, float32 at
-  the highest matmul precision; a token with ``alpha == 1`` and ``beta
-  == 0`` advances nothing, which is how a padded tail stays out of the
-  state.
+  multiplies it are solved for every sub-chunk at once: ``(I + A)^-1``
+  is built by products on the MXU (:func:`_unit_lower_inverse`:
+  forward substitution in blocks that double, two ``[C, C]`` products a
+  doubling — a triangular-solve routine of the compiler's library
+  costs a layer more than the rest of the scan) and multiplies
+  ``[beta V | beta g K]`` once; a ``lax.scan`` carries ``S`` from one
+  sub-chunk to the next.  Plain XLA, float32 at the highest matmul
+  precision; a token with ``alpha == 1`` and ``beta == 0`` advances
+  nothing, which is how a padded tail stays out of the state.
 - :func:`gdn_scan_reference` — the recurrence token by token: what the
   other two must reproduce, and the decode update's jnp form.
 
@@ -272,6 +276,46 @@ def gdn_decode_update(
 # ------------------------------------------------------- the chunked form
 
 
+def _unit_lower_inverse(system: jnp.ndarray) -> jnp.ndarray:
+    """The inverse of unit lower-triangular ``system [..., c, c]``
+    (zeros above the diagonal, ``c`` a power of 2), float32, by
+    products: forward substitution in blocks that double.  A block of
+    one row is its own inverse, and two neighbours ``P``, ``Q`` joined
+    by the entries ``R`` below ``P`` and left of ``Q`` invert to
+    ``[[P, 0], [R, Q]]^-1 = [[P^-1, 0], [-Q^-1 R P^-1, Q^-1]]``.  With
+    ``X`` the block-diagonal matrix of the inverses so far and ``R``
+    every such join of one size at once, that is ``X - X R X`` on the
+    whole ``[c, c]`` matrix: two products a doubling, ``log2(c) - 1``
+    doublings (the first needs none: ``X`` is the identity there).
+    Every entry comes out of the sums a triangular solve would form,
+    so keys that repeat (``A`` far from small: a prompt that repeats a
+    token) are inverted as exactly as by one; the shorter product ``(I
+    - A)(I + A^2)(I + A^4)...`` is not (``A`` is nilpotent, but its
+    powers outgrow float32's digits before they cancel)."""
+    c = system.shape[-1]
+    if c & (c - 1):
+        raise ValueError(f"a sub-chunk of {c} tokens is not a power of 2")
+    at = jnp.arange(c)
+
+    def joins(b):  # the entries that join two blocks of ``b`` to one
+        pair, block = at // (2 * b), at // b
+        return jnp.where(
+            (pair[:, None] == pair[None, :])
+            & (block[:, None] != block[None, :]),
+            system, 0.0,
+        )
+
+    inv = jnp.eye(c, dtype=system.dtype) - joins(1)
+    b = 2
+    while b < c:
+        inv = inv - jnp.matmul(
+            jnp.matmul(inv, joins(b), precision=_HIGHEST), inv,
+            precision=_HIGHEST,
+        )
+        b *= 2
+    return inv
+
+
 def gdn_chunk_scan(
     q: jnp.ndarray,  # [B, T, H, dk]
     k: jnp.ndarray,  # [B, T, H, dk]
@@ -287,8 +331,6 @@ def gdn_chunk_scan(
     is padded with ``alpha == 1``, ``beta == 0`` tokens, which advance
     nothing.  Returns ``(o [B, T, H, dv], state after the last
     token)``."""
-    from jax.scipy.linalg import solve_triangular
-
     f32 = jnp.float32
     bsz, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -326,12 +368,13 @@ def gdn_chunk_scan(
     g = jnp.exp(cum)  # [B, nc, H, c]
     # (I + A)^-1 [beta V | beta g K]: what U is without the carried
     # state, and what multiplies the carried state
-    solved = solve_triangular(
-        system,
+    solved = jnp.einsum(
+        "bnhij,bnhjv->bnhiv",
+        _unit_lower_inverse(system),
         jnp.concatenate(
             [beta[..., None] * v, (beta * g)[..., None] * k], axis=-1
         ),
-        lower=True, unit_diagonal=True,
+        precision=_HIGHEST,
     )
     u_free, u_state = solved[..., :dv], solved[..., dv:]
     qk = jnp.einsum("bnhid,bnhjd->bnhij", q, k, precision=_HIGHEST) * decay

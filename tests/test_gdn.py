@@ -3,7 +3,7 @@ decode update (both backends; the Pallas kernel in interpret mode)
 against the recurrence token by token.
 
 Tolerances: float32 everywhere.  The chunked form reorders sums over a
-sub-chunk of 64 rows and solves a triangular system: 2e-5 against
+sub-chunk of 64 rows and inverts a triangular system: 2e-5 against
 outputs of order 0.5 and states of order 1; a bfloat16 state (3
 significant digits) or a dropped ``alpha`` / ``beta`` would miss it by
 1e-2 and more.  The decode update is the recurrence itself: 1e-6.
@@ -210,3 +210,72 @@ def test_decode_steps_continue_a_chunk_scan():
     assert float(
         jnp.abs(gdn.unpack_state(slabs[0], heads) - s_ref).max()
     ) < CHUNK_TOL
+
+
+def _hard_case(name):
+    """Inputs on which the inverse of a sub-chunk's system ``I + A`` is
+    far from ``I - A``: what a prompt that repeats a token, a padded or
+    gated-off row, a short or ragged run and a forgotten past make of
+    it."""
+    t = {"run_1": 1, "run_63": 63, "run_65": 65, "run_256": 256}.get(
+        name, 64
+    )
+    q, k, v, alpha, beta, state = _inputs(23, 1, t, 4, 24, 48)
+    if name == "identical_keys":
+        # every row reflects the state about ONE key: A[t, j] == 2 for
+        # every j < t, the inverse's entries alternate +-2 and powers of
+        # A grow like 2 ** n * binomials before they cancel
+        k = jnp.broadcast_to(k[:, :1], k.shape)
+        alpha, beta = jnp.ones_like(alpha), jnp.full_like(beta, 2.0)
+        # nothing decays and the state's component along the key walks
+        # by 2 v a token: a sixteenth of v (2 * sqrt(64)) keeps outputs
+        # and states of the order the tolerance is stated for
+        v, state = v / 16, state / 16
+    elif name == "beta_zero_rows":
+        beta = beta.at[:, 5:9].set(0.0).at[:, 30, 2].set(0.0)
+        beta = beta.at[:, 63].set(0.0)
+    elif name == "alpha_underflow_mid":
+        alpha = alpha.at[:, 31].set(0.0).at[:, 40, 1].set(1e-42)
+    return q, k, v, alpha, beta, state
+
+
+@pytest.mark.parametrize("name", [
+    "identical_keys", "beta_zero_rows", "alpha_underflow_mid",
+    "run_1", "run_63", "run_64", "run_65", "run_256",
+])
+def test_chunk_scan_inverts_its_hard_systems(name):
+    """The WY system is inverted by products (forward substitution in
+    blocks): the recurrence, at the tolerance of a triangular solve, on
+    the inputs where a shorter product of powers of ``A`` would not
+    be."""
+    args = _hard_case(name)
+    o_ref, s_ref = gdn.gdn_scan_reference(*args)
+    o, s = jax.jit(gdn.gdn_chunk_scan)(*args)
+    assert o.shape == o_ref.shape and s.shape == s_ref.shape
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+    assert float(jnp.abs(o - o_ref).max()) < CHUNK_TOL
+    assert float(jnp.abs(s - s_ref).max()) < CHUNK_TOL
+    assert float(jnp.abs(o_ref).max()) > 0.1
+
+
+def test_the_hard_systems_see_a_product_of_powers(monkeypatch):
+    """What the repeated keys are there for: ``(I - A)(I + A^2)(I +
+    A^4)...(I + A^32)`` IS the inverse (``A`` is nilpotent) and passes
+    on random keys, but its powers of ``A`` outgrow float32's digits
+    before they cancel once keys repeat."""
+    def product_of_powers(system):
+        eye = jnp.eye(system.shape[-1], dtype=system.dtype)
+        mm = jax.lax.Precision.HIGHEST
+        inv, power = eye - (system - eye), system - eye
+        for _ in range(5):
+            power = jnp.matmul(power, power, precision=mm)
+            inv = jnp.matmul(inv, eye + power, precision=mm)
+        return inv
+
+    monkeypatch.setattr(gdn, "_unit_lower_inverse", product_of_powers)
+    for name, passes in (("run_64", True), ("identical_keys", False)):
+        args = _hard_case(name)
+        o_ref, _ = gdn.gdn_scan_reference(*args)
+        # a function of its own: ``jit`` must trace the patched inverse
+        o, _ = jax.jit(lambda *a: gdn.gdn_chunk_scan(*a))(*args)
+        assert (float(jnp.abs(o - o_ref).max()) < CHUNK_TOL) == passes
