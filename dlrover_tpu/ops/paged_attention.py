@@ -569,20 +569,27 @@ def selected_prefill_attention(
     and 32 heads against 16 k keys would be 4 GB at once, and a chunk
     early in its prompt has few keys.  Returns ``[C, H, D]``.
 
-    Under the Pallas backend, where the shapes tile, the flash form of
-    the same sum (``ops/paged_kernels.selected_prefill_kernel``, which
-    also skips the key blocks above the chunk's causal reach); else
-    this one, in plain XLA."""
+    Under the Pallas backend, where the chunk's rows and keys tile by
+    the kernel's blocks (256 rows of a KV head's every query head
+    against 1024 keys a grid step, so a key block and the selection's
+    tile are fetched once for the heads that share them; an extent
+    under a block is one block), the flash form of the same sum
+    (``ops/paged_kernels.selected_prefill_kernel``, which also skips
+    the key blocks above the chunk's causal reach); else this one, in
+    plain XLA."""
     c, nh, d = q.shape
     t, nkv = k.shape[:2]
     group = nh // nkv
-    if (
-        (backend or paged_kernel_backend()) == "pallas"
-        and c % min(512, c) == 0 and t % min(512, t) == 0
-    ):
-        from dlrover_tpu.ops.paged_kernels import selected_prefill_kernel
+    if (backend or paged_kernel_backend()) == "pallas":
+        from dlrover_tpu.ops import paged_kernels as pk
 
-        return selected_prefill_kernel(q, k, v, taken, start_pos, kv_len)
+        if (
+            c % min(pk.SELECTED_BLOCK_Q, c) == 0
+            and t % min(pk.SELECTED_BLOCK_K, t) == 0
+        ):
+            return pk.selected_prefill_kernel(
+                q, k, v, taken, start_pos, kv_len
+            )
     kb = min(key_block, t)
     if t % kb:
         raise ValueError(f"{t} cached positions in key blocks of {kb}")

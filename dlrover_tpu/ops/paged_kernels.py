@@ -752,13 +752,46 @@ def index_scores_kernel(
     )
 
 
+def _rows_by_kv_head(q, block_q: int, n_kv: int):
+    """``[C, H, D]`` queries as ``[KV * C / BQ, G * BQ, D]``: a grid
+    step's rows are a KV head's query heads one after the other, the
+    same ``block_q`` positions of each."""
+    c, n_heads, d = q.shape
+    nq, group = c // block_q, n_heads // n_kv
+    qg = q.reshape(nq, block_q, n_kv, group, d).transpose(2, 0, 3, 1, 4)
+    return qg.reshape(n_kv * nq, group * block_q, d)
+
+
+def _rows_by_position(out, c: int, n_heads: int, block_q: int):
+    """:func:`_rows_by_kv_head` undone: ``[C, H, D]``."""
+    nq, d = c // block_q, out.shape[-1]
+    n_kv = out.shape[0] // nq
+    out = out.reshape(n_kv, nq, n_heads // n_kv, block_q, d)
+    return out.transpose(1, 3, 0, 2, 4).reshape(c, n_heads, d)
+
+
+def _chunk_step_params(rows: int, block_k: int):
+    """Fast memory for a prefill step's ``[rows, block_k]`` float32
+    logits and what the compiler keeps beside them (75 MB at 2048 x
+    1024, 109 at 3072)."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(max(32 << 20, 8 * rows * block_k * 4 + (8 << 20)))
+    )
+
+
+# a query head's rows and the keys a grid step of
+# ``selected_prefill_kernel`` takes; a chunk they do not tile keeps the
+# XLA form (``ops/paged_attention.selected_prefill_attention``)
+SELECTED_BLOCK_Q, SELECTED_BLOCK_K = 256, 1024
+
+
 def _selected_prefill_kernel(
     bounds_ref,  # scalar prefetch [2]: the chunk's first position, kv_len
-    q_ref,  # [1, BQ, D]
+    q_ref,  # [1, G * BQ, D]: a KV head's query heads, BQ rows each
     k_ref,  # [1, BK, D]
     v_ref,
-    keep_ref,  # [BQ, BK] int8: the keys each query reads
-    o_ref,  # [1, BQ, D]
+    keep_ref,  # [BQ, BK] int8: the keys each query reads, of every head
+    o_ref,  # [1, G * BQ, D]
     m_scr,
     l_scr,
     acc_scr,
@@ -781,9 +814,13 @@ def _selected_prefill_kernel(
 
     @pl.when(j * block_k <= last)
     def _compute():
+        # the selection is a token's, not a head's: the tile is fetched
+        # and widened once, and laid under each head's rows
+        keep = keep_ref[...].astype(jnp.int32)
+        keep = jnp.concatenate([keep] * (q_ref.shape[1] // block_q), axis=0)
         _online_update(
             m_scr, l_scr, acc_scr, _logits(q_ref, k_ref, scale), v_ref[0],
-            keep_ref[...].astype(jnp.int32) != 0,
+            keep != 0,
         )
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -799,25 +836,43 @@ def selected_prefill_kernel(
     start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
     kv_len: jnp.ndarray,  # scalar int32: keys past it are never read
     *,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = SELECTED_BLOCK_Q,
+    block_k: int = SELECTED_BLOCK_K,
 ) -> jnp.ndarray:
     """Chunked-prefill GQA attention where query ``i`` reads exactly
     the keys ``taken[i]`` marks (``ops/paged_attention.
     selected_prefill_attention``'s Pallas form, ``sparse_prefill`` in a
     device trace): a flash forward whose mask is data.  A grid step is
-    one head's ``block_q`` queries against ``block_k`` keys; key blocks
-    past the query block's causal reach or past ``kv_len`` are neither
-    fetched nor computed, so a chunk early in its prompt costs its own
-    keys.  The logits never leave the chip's fast memory (the XLA form
-    writes and re-reads ``[C, H, key_block]`` float32 three times a key
-    block: 0.8 GB a block at 2048 x 32)."""
+    one KV head's ``group`` query heads, ``block_q`` rows each, against
+    ``block_k`` keys, the rows laid out as :func:`chunk_prefill_kernel`
+    lays them: a key block, and the ``[block_q, block_k]`` int8 tile of
+    the selection (a token's, the same for every head), are fetched
+    once for the eight heads that share them, and what a step pays a
+    row group — its fixed price, two cross-lane reductions, the rescale
+    — it pays once 1024 keys.  Key blocks past the query block's causal
+    reach or past ``kv_len`` are neither fetched nor computed, so a
+    chunk early in its prompt costs its own keys.  The logits never
+    leave the chip's fast memory (the XLA form writes and re-reads
+    ``[C, H, key_block]`` float32 three times a key block: 0.8 GB a
+    block at 2048 x 32).  Returns ``[C, H, D]``.
+
+    At 32 / 4 heads of 128 the compiler's schedule of a step
+    (``scripts/kernel_schedule.py sparse_prefill``) is 11 088 bundles
+    for eight ``256 x 1024`` tiles of logits, 1 386 a 262 144 logits,
+    where one head's ``512 x 512`` step was 2 178; on a v5e the bare
+    kernel reads 1.07-1.23 us a 262 144 logits where that read
+    2.22-2.35, and a 2048-row chunk that ends at 4096 / 8192 / 12288 /
+    16384 keys 1.10 / 2.12 / 3.19 / 4.24 ms where it took 1.95 / 4.20 /
+    6.44 / 8.66.  ``128 x 1024`` is within 2 %, ``256 x 512`` half as
+    far from the old form, and the heads in a loop inside the step (the
+    tile widened once) 12-15 % slower (PERF.md, PR 52)."""
     c, n_heads, d = q.shape
     t, n_kv, _ = k.shape
     group = n_heads // n_kv
     bq, bk = min(block_q, c), min(block_k, t)
     if c % bq or t % bk:
         raise ValueError(f"a chunk of {c} x {t} keys in blocks {bq} x {bk}")
+    nq, rows = c // bq, group * bq
 
     def last_block(i, bounds):
         last = jnp.minimum(bounds[1] - 1, bounds[0] + (i + 1) * bq - 1)
@@ -825,10 +880,10 @@ def selected_prefill_kernel(
 
     def q_index(h, i, j, bounds):
         del j, bounds
-        return (h, i, 0)
+        return (h * nq + i, 0, 0)
 
     def kv_index(h, i, j, bounds):
-        return (h // group, jnp.minimum(j, last_block(i, bounds)), 0)
+        return (h, jnp.minimum(j, last_block(i, bounds)), 0)
 
     def keep_index(h, i, j, bounds):
         del h
@@ -836,18 +891,18 @@ def selected_prefill_kernel(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_heads, c // bq, t // bk),
+        grid=(n_kv, nq, t // bk),
         in_specs=[
-            pl.BlockSpec((1, bq, d), q_index),
+            pl.BlockSpec((1, rows, d), q_index),
             pl.BlockSpec((1, bk, d), kv_index),
             pl.BlockSpec((1, bk, d), kv_index),
             pl.BlockSpec((bq, bk), keep_index),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), q_index),
+        out_specs=pl.BlockSpec((1, rows, d), q_index),
         scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
         ],
     )
     out = named_kernel(
@@ -858,18 +913,19 @@ def selected_prefill_kernel(
                 scale=d**-0.5,
             ),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n_heads, c, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((n_kv * nq, rows, d), q.dtype),
             interpret=use_interpret(),
             name="sparse_prefill",
+            compiler_params=_chunk_step_params(rows, bk),
         ),
     )(
         jnp.stack([start_pos, kv_len]).astype(jnp.int32),
-        jnp.swapaxes(q, 0, 1),
+        _rows_by_kv_head(q, bq, n_kv),
         jnp.swapaxes(k, 0, 1),
         jnp.swapaxes(v, 0, 1),
         taken.astype(jnp.int8),
     )
-    return jnp.swapaxes(out, 0, 1)
+    return _rows_by_position(out, c, n_heads, bq)
 
 
 # keys a grid step of ``chunk_prefill_kernel`` reads; whoever lays out
@@ -1041,9 +1097,6 @@ def chunk_prefill_kernel(
             pltpu.VMEM((rows, d), jnp.float32),
         ],
     )
-    # [C, KV, G, D] -> [KV, C / BQ, G, BQ, D]: a grid step's rows are a
-    # KV head's query heads one after the other
-    qg = q.reshape(nq, bq, n_kv, group, d).transpose(2, 0, 3, 1, 4)
     out = named_kernel(
         name,
         pl.pallas_call(
@@ -1055,20 +1108,15 @@ def chunk_prefill_kernel(
             out_shape=jax.ShapeDtypeStruct((n_kv * nq, rows, d), q.dtype),
             interpret=use_interpret(),
             name=name,
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=int(
-                    max(32 << 20, 8 * rows * bk * 4 + (8 << 20))
-                )
-            ),
+            compiler_params=_chunk_step_params(rows, bk),
         ),
     )(
         jnp.stack([start_pos, key0]).astype(jnp.int32),
-        qg.reshape(n_kv * nq, rows, d),
+        _rows_by_kv_head(q, bq, n_kv),
         k,
         v,
     )
-    out = out.reshape(n_kv, nq, group, bq, d).transpose(1, 3, 0, 2, 4)
-    return out.reshape(c, n_heads, d)
+    return _rows_by_position(out, c, n_heads, bq)
 
 
 # ---------------------------------------------------------------------------

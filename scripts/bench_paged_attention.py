@@ -33,6 +33,13 @@ window layer's view with the window full) and prints milliseconds a
 call, the share of the bf16 peak on the keys the mask admits, and
 ``chunk_key_blocks``' count of computed, unmasked and skipped steps.
 
+``--selected`` times the bare selected-keys prefill kernel
+(``sparse_prefill``) at Keye-VL-2.0's widths (``SELECTED_WIDTHS``: a
+2048-row chunk that ends at 4096 / 8192 / 12288 / 16384 cached
+positions, each row reading its top 2048 of random scores) and prints
+milliseconds a call and microseconds a 262 144 logits of the key
+blocks it computes.
+
 Wired into ``bench.py`` as the ``extras.paged_kernels`` leg.
 """
 
@@ -77,6 +84,11 @@ CHUNKS = {
     "paged_prefill_window": (4096, 385, (8192,)),
 }
 
+#: cached positions a 2048-row chunk of Keye-VL-2.0 (32 / 4 heads of
+#: 128, bfloat16, top 2048) ends at: the four static widths its prefill
+#: program picks from at 16 384 positions a lane
+SELECTED_WIDTHS = (4096, 8192, 12288, 16384)
+
 
 def _time_call(call, reps: int) -> float:
     """Best-of-reps wall microseconds for an already-warm callable."""
@@ -86,6 +98,24 @@ def _time_call(call, reps: int) -> float:
         call()
         best = min(best, (time.perf_counter() - t0) * 1e6)
     return best
+
+
+def _chained_us(kernel, q, rest, reps: int) -> float:
+    """Microseconds a call of ``kernel(q, *rest)``: ``reps`` calls
+    chained inside ONE program (each call's queries depend on the one
+    before), compiled outside the clock."""
+    import jax
+    from jax import lax
+
+    def chained(q, *rest):
+        def body(_, q):
+            return q + (kernel(q, *rest) * 1e-3).astype(q.dtype)
+
+        return lax.fori_loop(0, reps, body, q)
+
+    fn = jax.jit(chained)
+    fn(q, *rest).block_until_ready()
+    return _time_call(lambda: fn(q, *rest).block_until_ready(), 3) / reps
 
 
 def _make_point(batch, context, block_size, *, heads=4, kv_heads=2, head_dim=8,
@@ -291,7 +321,6 @@ def bench_tables(names=None, shares=LIVE_SHARES, spans=(None,), reps=20,
     ``head_dim`` / ``dtype`` of a rehearsal."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     from dlrover_tpu.ops import autotune
     from dlrover_tpu.ops import paged_attention as pa
@@ -314,25 +343,17 @@ def bench_tables(names=None, shares=LIVE_SHARES, spans=(None,), reps=20,
                 if span is not None:
                     config = dict(config, kv_span=span)
 
-                def chained(q, k, v, tables, lens, first, config=config,
-                            kernel_name="paged_window_decode" if window
-                            else "paged_decode"):
-                    def body(_, q):
-                        out = paged_decode_kernel(
-                            q, k, v, tables, lens, config=config,
-                            first=first, name=kernel_name,
-                        )
-                        return q + (out * 1e-3).astype(q.dtype)
+                def kernel(q, k, v, tables, lens, first, config=config,
+                           kernel_name="paged_window_decode" if window
+                           else "paged_decode"):
+                    return paged_decode_kernel(
+                        q, k, v, tables, lens, config=config,
+                        first=first, name=kernel_name,
+                    )
 
-                    return lax.fori_loop(0, reps, body, q)
-
-                fn = jax.jit(chained)
                 args = (a["q"], a["k_pool"], a["v_pool"], a["tables"],
                         a["seq_lens"], a["first"])
-                fn(*args).block_until_ready()  # compile outside the clock
-                us = _time_call(
-                    lambda: fn(*args).block_until_ready(), 3
-                ) / reps
+                us = _chained_us(kernel, args[0], args[1:], reps)
                 # the same call against the dense reference, on the
                 # device the row was timed on
                 single = (a["q"], a["k_pool"], a["v_pool"], a["tables"],
@@ -371,7 +392,6 @@ def bench_chunk(names=None, reps=20, blocks=(None,), dims=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax import lax
 
     from dlrover_tpu.models.trinity import _key_view_blocks
     from dlrover_tpu.observability.profiler import peak_flops_for_kind
@@ -415,19 +435,8 @@ def bench_chunk(names=None, reps=20, blocks=(None,), dims=None):
                     chunk_prefill_kernel, window=window, name=name, **kw
                 )
 
-                def chained(q, k, v, start, key0, kernel=kernel):
-                    def body(_, q):
-                        out = kernel(q, k, v, start, key0)
-                        return q + (out * 1e-3).astype(q.dtype)
-
-                    return lax.fori_loop(0, reps, body, q)
-
-                fn = jax.jit(chained)
                 args = (q, k, v, jnp.int32(start), jnp.int32(key0))
-                fn(*args).block_until_ready()  # compile outside the clock
-                ms = _time_call(
-                    lambda: fn(*args).block_until_ready(), 3
-                ) / reps / 1e3
+                ms = _chained_us(kernel, q, args[1:], reps) / 1e3
                 # the chunk's first and last rows against the dense
                 # form, on the device the row was timed on
                 got = kernel(*args).astype(jnp.float32)
@@ -455,6 +464,76 @@ def bench_chunk(names=None, reps=20, blocks=(None,), dims=None):
                     "max_abs_diff_vs_jnp": diff,
                 })
                 print(json.dumps(out_rows[-1]), flush=True)
+    return out_rows
+
+
+def bench_selected(widths=SELECTED_WIDTHS, reps=20, blocks=(None,), dims=None):
+    """Rows of the bare selected-keys prefill kernel, ``reps`` calls
+    chained inside ONE program as :func:`bench_chunk` does, the chunk
+    the last ``rows`` positions of each width.  ``blocks`` and ``dims``
+    (``rows`` / ``heads`` / ``kv_heads`` / ``head_dim`` / ``topk`` /
+    ``dtype``) as there."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.ops import paged_attention as pa
+    from dlrover_tpu.ops.paged_kernels import (
+        SELECTED_BLOCK_K, SELECTED_BLOCK_Q, selected_prefill_kernel,
+    )
+
+    dims = {
+        **dict(rows=2048, heads=32, kv_heads=4, head_dim=128, topk=2048,
+               dtype=jnp.bfloat16),
+        **(dims or {}),
+    }
+    c, heads, n_kv, d = (
+        dims[n] for n in ("rows", "heads", "kv_heads", "head_dim")
+    )
+    out_rows = []
+    for t in widths:
+        start = t - c
+        k = jax.random.normal(jax.random.PRNGKey(1), (t, n_kv, d), dims["dtype"])
+        v = k[::-1]
+        q = jax.random.normal(jax.random.PRNGKey(2), (c, heads, d), dims["dtype"])
+        scores = jax.random.normal(jax.random.PRNGKey(3), (c, t))
+        causal = jnp.arange(t)[None] <= start + jnp.arange(c)[:, None]
+        taken = pa.exact_topk_mask(
+            jnp.where(causal, scores, -jnp.inf), min(dims["topk"], t)
+        )
+        for pair in blocks:
+            block_q, block_k = pair or (SELECTED_BLOCK_Q, SELECTED_BLOCK_K)
+            kernel = functools.partial(
+                selected_prefill_kernel, block_q=block_q, block_k=block_k
+            )
+            bq, bk = min(block_q, c), min(block_k, t)
+            # the key blocks up to each query block's last row: the rest
+            # the kernel neither fetches nor computes
+            logits = heads * bq * bk * sum(
+                (start + (i + 1) * bq - 1) // bk + 1 for i in range(c // bq)
+            )
+
+            args = (q, k, v, taken, jnp.int32(start), jnp.int32(t))
+            ms = _chained_us(kernel, q, args[1:], reps) / 1e3
+            # the chunk's first and last rows against the XLA form, on
+            # the device the row was timed on
+            got = kernel(*args).astype(jnp.float32)
+            n = min(128, c)
+            diff = max(
+                float(jnp.max(jnp.abs(
+                    got[lo:lo + n] - pa.selected_prefill_attention(
+                        q[lo:lo + n], k, v, taken[lo:lo + n],
+                        jnp.int32(start + lo), jnp.int32(t), backend="jnp",
+                    ).astype(jnp.float32)
+                )))
+                for lo in (0, c - n)
+            )
+            out_rows.append({
+                "kernel": "sparse_prefill", "keys": t, "start": start,
+                "blocks": (bq, bk), "ms_a_call": round(ms, 4),
+                "us_a_262144_logits": round(ms * 1e3 * 262144 / logits, 3),
+                "max_abs_diff_vs_jnp": diff,
+            })
+            print(json.dumps(out_rows[-1]), flush=True)
     return out_rows
 
 
@@ -500,20 +579,37 @@ def main(argv=None) -> int:
     )
     ap.add_argument(
         "--blocks", default="",
-        help="with --chunk: block shapes to force, e.g. 512x1024,256x1024 "
-        "(default: the kernel's own)",
+        help="with --chunk or --selected: block shapes to force, e.g. "
+        "512x1024,256x1024 (default: the kernel's own)",
+    )
+    ap.add_argument(
+        "--selected", action="store_true",
+        help="time the bare selected-keys prefill kernel at Keye-VL-2.0's "
+        f"widths ({', '.join(str(w) for w in SELECTED_WIDTHS)} keys)",
     )
     args = ap.parse_args(argv)
+    blocks = [
+        tuple(int(n) for n in b.split("x"))
+        for b in args.blocks.split(",") if b
+    ] or (None,)
+
+    if args.selected:
+        import jax
+
+        rows = bench_selected(reps=max(args.reps, 20), blocks=blocks)
+        _flush(args.out, {
+            "bench": "sparse_prefill", "rows": rows,
+            "backend": jax.default_backend(), "interpret": _interpret(),
+            "device_kind": jax.devices()[0].device_kind,
+        })
+        print(f"wrote {args.out} ({len(rows)} rows)")
+        return 0
 
     if args.chunk is not None:
         import jax
 
         rows = bench_chunk(
-            args.chunk or None, reps=max(args.reps, 20),
-            blocks=[
-                tuple(int(n) for n in b.split("x"))
-                for b in args.blocks.split(",") if b
-            ] or (None,),
+            args.chunk or None, reps=max(args.reps, 20), blocks=blocks,
         )
         _flush(args.out, {
             "bench": "paged_prefill_chunk", "rows": rows,

@@ -453,35 +453,95 @@ def test_the_index_score_kernel_is_the_plain_scan(start, monkeypatch):
     np.testing.assert_allclose(got[visible], want[visible], atol=1e-5)
 
 
-@pytest.mark.parametrize("start,kv_len", [(0, 24), (24, 48), (40, 61)])
+_SMALL = dict(block_q=8, block_k=16)  # 3 query blocks x 4 key blocks
+
+
+@pytest.mark.parametrize("start,kv_len,heads,blocks,dtype", [
+    (0, 24, (4, 2), _SMALL, jnp.float32),
+    (24, 48, (4, 2), _SMALL, jnp.float32),
+    (40, 61, (4, 2), _SMALL, jnp.float32),
+    # a KV head's eight query heads a grid step, as in the cell: key
+    # blocks past ``kv_len`` and past each query block's reach, ``kv_len``
+    # inside a key block, a selection inside the causal mask
+    (0, 24, (16, 2), _SMALL, jnp.float32),
+    (24, 48, (16, 2), _SMALL, jnp.float32),
+    (40, 61, (16, 2), _SMALL, jnp.float32),
+    (24, 48, (2, 2), _SMALL, jnp.float32),  # a group of one
+    (24, 48, (8, 1), dict(block_q=24, block_k=16), jnp.float32),
+    (24, 48, (16, 2), dict(block_q=8, block_k=64), jnp.float32),
+    # the kernel's own blocks on extents smaller than they are
+    (0, 24, (16, 2), {}, jnp.float32),
+    (40, 64, (16, 2), {}, jnp.float32),
+    (24, 48, (16, 2), _SMALL, jnp.bfloat16),
+    (40, 64, (8, 1), {}, jnp.bfloat16),
+])
 def test_the_prefill_kernel_is_the_plain_selected_attention(
-    start, kv_len, monkeypatch
+    start, kv_len, heads, blocks, dtype, monkeypatch
 ):
     """The flash form (interpreted) of a chunk's attention over the
     keys ``taken`` marks equals the plain XLA form, key blocks past the
     chunk's reach skipped; a row that reads nothing comes out zero."""
     monkeypatch.setenv("DLROVER_TPU_PALLAS_INTERPRET", "1")
     rng = np.random.default_rng(start)
-    c, t, nh, nkv, d = 24, 64, 4, 2, 16
+    (nh, nkv), c, t, d = heads, 24, 64, 16
     q = rng.normal(size=(c, nh, d)).astype(np.float32)
     k = rng.normal(size=(t, nkv, d)).astype(np.float32)
     v = rng.normal(size=(t, nkv, d)).astype(np.float32)
     visible = np.arange(t)[None] <= (start + np.arange(c))[:, None]
     taken = visible & (rng.random((c, t)) < 0.4)
     taken[3] = False  # a row with no key at all
-    args = tuple(jnp.asarray(a) for a in (q, k, v, taken))
+    args = tuple(jnp.asarray(a, dtype) for a in (q, k, v)) + (
+        jnp.asarray(taken),
+    )
     want = pa.selected_prefill_attention(
         *args, jnp.int32(start), jnp.int32(kv_len), backend="jnp"
     )
     from dlrover_tpu.ops.paged_kernels import selected_prefill_kernel
 
     got = selected_prefill_kernel(
-        *args, jnp.int32(start), jnp.int32(kv_len), block_q=8, block_k=16
+        *args, jnp.int32(start), jnp.int32(kv_len), **blocks
     )
+    assert got.dtype == want.dtype and got.shape == (c, nh, d)
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=2e-5
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        # bfloat16: the probabilities are rounded once a key block, and
+        # the two forms' key blocks differ
+        atol=2e-5 if dtype == jnp.float32 else 2e-2,
     )
-    assert (np.asarray(got)[3] == 0).all()
+    assert (np.asarray(got, np.float32)[3] == 0).all()
+
+
+def test_a_chunk_the_kernels_blocks_do_not_tile_keeps_the_xla_form(
+    monkeypatch
+):
+    """``selected_prefill_attention`` under the Pallas backend sends a
+    chunk to the kernel where its rows and keys tile by the kernel's
+    blocks (an extent under a block is one block), and keeps the XLA
+    form for the rest."""
+    from dlrover_tpu.ops import paged_kernels as pk
+
+    sent = []
+    monkeypatch.setattr(
+        pk, "selected_prefill_kernel",
+        lambda q, *a, **kw: sent.append(q.shape[0]) or q,
+    )
+    monkeypatch.setattr(pk, "SELECTED_BLOCK_Q", 8)
+    monkeypatch.setattr(pk, "SELECTED_BLOCK_K", 16)
+
+    def run(c, t):
+        q = jnp.zeros((c, 4, 8))
+        kv = jnp.zeros((t, 2, 8))
+        return pa.selected_prefill_attention(
+            q, kv, kv, jnp.ones((c, t), bool), jnp.int32(0), jnp.int32(t),
+            key_block=t, backend="pallas",
+        )
+
+    for c, t in [(16, 32), (4, 12), (8, 48)]:
+        run(c, t)
+    assert sent == [16, 4, 8]
+    for c, t in [(12, 32), (16, 40)]:
+        run(c, t)
+    assert sent == [16, 4, 8]
 
 
 # ------------------------------------ (e) the third paged leaf in a block

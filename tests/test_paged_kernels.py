@@ -715,6 +715,29 @@ class TestBenchHarness:
         assert rows[2]["steps_unmasked"] == 0
         assert rows[3]["blocks"] == (8, 16) and rows[3]["steps_unmasked"] == 8
 
+    def test_selected_rows_say_what_a_tile_of_logits_costs(self):
+        """``--selected``: a row a (width, block shape) with the time a
+        call and a 262 144 logits, the chunk the width's last rows and
+        every row reading its top ``topk``, checked against the XLA
+        form."""
+        bpa = self._module()
+        rows = bpa.bench_selected(
+            widths=(32, 64), reps=2, blocks=(None, (8, 16)),
+            dims=dict(rows=16, heads=4, kv_heads=2, head_dim=8, topk=12,
+                      dtype=jnp.float32),
+        )
+        assert [(r["keys"], r["start"], r["blocks"]) for r in rows] == [
+            (32, 16, (16, 32)), (32, 16, (8, 16)),
+            (64, 48, (16, 64)), (64, 48, (8, 16)),
+        ]
+        # 8 x 16 at 32 keys: rows 16-23 read two key blocks, 24-31 two
+        for row, logits in zip(rows, (16 * 32, 8 * 16 * 4, 16 * 64, 8 * 16 * 8)):
+            assert row["kernel"] == "sparse_prefill"
+            assert row["us_a_262144_logits"] == pytest.approx(
+                row["ms_a_call"] * 1e3 * 262144 / (4 * logits), rel=1e-2,
+            )
+            assert row["max_abs_diff_vs_jnp"] < 5e-6
+
     def test_budget_stops_between_points(self):
         bpa = self._module()
         snapshots = []

@@ -160,15 +160,15 @@ def _ssm_case():
     )
 
 
-def _sparse_prefill_case():
+def _sparse_prefill_case(keys=8192):
     """A 2048-row chunk's attention over the keys a selection marks, at
-    Keye-VL-2.0's widths (32 / 4 heads of 128) against 8192 cached
-    positions."""
+    Keye-VL-2.0's widths (32 / 4 heads of 128) against ``keys`` cached
+    positions (the cell's chunks read 4096 / 8192 / 12288 / 16384)."""
     from dlrover_tpu.ops.paged_kernels import selected_prefill_kernel
 
-    kv = ((8192, 4, D), BF16)
+    kv = ((keys, 4, D), BF16)
     return selected_prefill_kernel, (
-        ((2048, 32, D), BF16), kv, kv, ((2048, 8192), jnp.bool_),
+        ((2048, 32, D), BF16), kv, kv, ((2048, keys), jnp.bool_),
         ((), jnp.int32), ((), jnp.int32),
     )
 
@@ -392,6 +392,41 @@ def test_serving_kernels_keep_their_names(case, name, one_chip):
     for line in calls:
         assert re.match(rf"(ROOT )?%{name}(\.\d+)* = ", line), line
     assert "closed_call" not in text
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("keys", [4096, 8192, 12288, 16384])
+def test_sparse_prefill_steps_a_kv_heads_group_over_1024_keys(keys):
+    """What ``sparse_prefill`` is at the cell's four widths: a grid of
+    (KV heads, query blocks of 256 rows, key blocks of 1024) whose step
+    takes a KV head's eight query heads at once — 2048 rows of queries
+    against one key block and ONE ``[256, 1024]`` int8 tile of the
+    selection — where a step was one head's 512 rows against 512 keys
+    (32 x 4 x keys / 512 steps, each fetching and widening the tile
+    its seven siblings also fetched)."""
+    fn, shapes = _sparse_prefill_case(keys)
+    jaxpr = jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in shapes)
+    )
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (4, 2048 // 256, keys // 1024)
+    blocks = [
+        tuple(b.block_size for b in m.block_shape)
+        for m in mapping.block_mappings
+    ]
+    q_rows = (1, 8 * 256, D)
+    assert blocks == [
+        q_rows, (1, 1024, D), (1, 1024, D), (256, 1024), q_rows
+    ]
 
 
 @pytest.mark.parametrize("kernel", ["decode", "verify"])
