@@ -259,17 +259,27 @@ def _indexer_inputs(h, lp, cfg: KeyeVL2Config):
     dt = cfg.dtype
     hi, di = cfg.indexer_num_heads, cfg.indexer_head_dim
     qi = _proj(h, lp["wi_q"], dt).reshape(h.shape[:-1] + (hi, di))
+    ik, w = _index_key_and_weights(h, lp, hi, di, cfg.rms_norm_eps, dt)
+    return qi, ik, w
+
+
+def _index_key_and_weights(h, lp, hi: int, di: int, eps: float, dt):
+    """``h [..., D]`` -> the index key ``LayerNorm(W_ik h) [..., Di]``
+    (weight and bias; before the rope, compute dtype) and the float32
+    head weights ``W_iw h * Hi ** -0.5 * Di ** -0.5 [..., Hi]``: the
+    part of an indexer that reads the hidden state whatever feeds its
+    queries."""
     raw = jnp.matmul(
         h, lp["wi_k"].astype(dt), preferred_element_type=jnp.float32
     )
     mean = jnp.mean(raw, -1, keepdims=True)
     var = jnp.mean((raw - mean) ** 2, -1, keepdims=True)
-    ik = (raw - mean) * lax.rsqrt(var + cfg.rms_norm_eps)
+    ik = (raw - mean) * lax.rsqrt(var + eps)
     ik = (ik * lp["ik_norm"] + lp["ik_norm_bias"]).astype(dt)
     w = jnp.matmul(
         h, lp["wi_w"].astype(dt), preferred_element_type=jnp.float32
     ) * (hi ** -0.5 * di ** -0.5)
-    return qi, ik, w
+    return ik, w
 
 
 def _route(x, lp, cfg: KeyeVL2Config):

@@ -305,6 +305,13 @@ DEVICE_SCOPE_PARTS = frozenset(
         # projections around it
         "linear",
         "gdn_scan",
+        # latent attention (models/deepseek_v32.py), entered INSIDE
+        # ``attn`` as the kinds above are: the two low-rank projections
+        # and their norms, the rotation, the write of the cached row,
+        # the absorbed decode over the picked rows or a chunk's
+        # decompression and its multi-head attention, ``wo`` (the
+        # indexer beside it runs under ``indexer``)
+        "latent",
         # final norm + logits of a serving step program
         "head",
         # final norm + logits + cross-entropy of the train step

@@ -130,8 +130,13 @@ def tile_aligned_layout(expert_ids: jnp.ndarray, num_experts: int, tile: int):
 
 
 def _expert_ffn_kernel(tile_expert_ref, n_tiles_ref, x_ref, wg_ref, wu_ref,
-                       wd_ref, o_ref):
+                       wd_ref, o_ref, *acc):
+    """A grid step is a tile of rows and a block of its expert's width:
+    ``[D, F / n]`` of gate and up, ``[F / n, D]`` of down.  ``acc``: the
+    float32 sum of the blocks' products, where there is more than one
+    block (``n == 1``: none, the product is the output)."""
     del tile_expert_ref  # the index maps read it
+    j, last = pl.program_id(1), pl.num_programs(1) - 1
     used = pl.program_id(0) < n_tiles_ref[0]
 
     @pl.when(used)
@@ -140,13 +145,47 @@ def _expert_ffn_kernel(tile_expert_ref, n_tiles_ref, x_ref, wg_ref, wu_ref,
         gate = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
         up = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
         act = (jax.nn.silu(gate) * up).astype(x.dtype)
-        o_ref[...] = jnp.dot(
-            act, wd_ref[0], preferred_element_type=jnp.float32
-        ).astype(o_ref.dtype)
+        part = jnp.dot(act, wd_ref[0], preferred_element_type=jnp.float32)
+        if not acc:
+            o_ref[...] = part.astype(o_ref.dtype)
+            return
+        acc_ref, = acc
 
-    @pl.when(jnp.logical_not(used))
+        @pl.when(j == 0)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(j > 0)
+        def _more():
+            acc_ref[...] += part
+
+        @pl.when(j == last)
+        def _done():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(used) & (j == last))
     def _skip():
         o_ref[...] = jnp.zeros_like(o_ref)
+
+
+# fast memory an expert's three matrices, double-buffered, may take
+# whole (Trinity-Large's 3 x 3072 x 3072: 113 MB of a v5e's 128); a
+# wider expert is taken in blocks of its width
+_EXPERT_WHOLE_BYTES = 120 << 20
+
+
+def expert_width_blocks(d: int, f: int, itemsize: int) -> int:
+    """Blocks an expert's width ``f`` is taken in: the fewest, a power
+    of two, whose three ``d x f / n`` matrices fit fast memory twice (1
+    at every width the benchmark had before DeepSeek-V3.2's 7168 x 2048:
+    2)."""
+    n = 1
+    while (
+        2 * 3 * d * (f // n) * itemsize > _EXPERT_WHOLE_BYTES
+        and f % (2 * n) == 0 and (f // (2 * n)) % 128 == 0
+    ):
+        n *= 2
+    return n
 
 
 def expert_ffn_tiles(
@@ -161,36 +200,55 @@ def expert_ffn_tiles(
     """``W_down^g (silu(W_gate^g r) * W_up^g r)`` for every row ``r`` of
     every used tile, ``g`` the tile's group: one Pallas kernel, named
     ``moe_expert_ffn`` in a device trace, a grid step a tile.  Float32
-    accumulation, the activation rounded once to the rows' dtype."""
+    accumulation, the activation rounded once to the rows' dtype.  The
+    grid's second axis is the blocks an expert's width is taken in
+    (:func:`expert_width_blocks`): one, unless the expert is too wide
+    for fast memory."""
     from jax.experimental.pallas import tpu as pltpu
 
     from dlrover_tpu.ops.pallas_utils import named_kernel, use_interpret
 
     n_rows, d = rows.shape
     f = w_gate.shape[-1]
+    blocks = expert_width_blocks(d, f, w_gate.dtype.itemsize)
+    fb = f // blocks
 
-    def row_index(i, groups, used):
-        del groups
+    def row_index(i, j, groups, used):
+        del j, groups
         return (jnp.minimum(i, jnp.maximum(used[0] - 1, 0)), 0)
 
-    def group_index(i, groups, used):
-        del used
-        return (groups[i], 0, 0)
+    def block_of(i, j, used):
+        # a tile past the last used one names the block fetched last
+        return jnp.where(i < used[0], j, blocks - 1)
+
+    def in_index(i, j, groups, used):
+        return (groups[i], 0, block_of(i, j, used))
+
+    def down_index(i, j, groups, used):
+        return (groups[i], block_of(i, j, used), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_rows // tile,),
+        grid=(n_rows // tile, blocks),
         in_specs=[
             pl.BlockSpec((tile, d), row_index),
-            pl.BlockSpec((1, d, f), group_index),
-            pl.BlockSpec((1, d, f), group_index),
-            pl.BlockSpec((1, f, d), group_index),
+            pl.BlockSpec((1, d, fb), in_index),
+            pl.BlockSpec((1, d, fb), in_index),
+            pl.BlockSpec((1, fb, d), down_index),
         ],
-        out_specs=pl.BlockSpec((tile, d), lambda i, groups, used: (i, 0)),
+        out_specs=pl.BlockSpec(
+            (tile, d), lambda i, j, groups, used: (i, 0)
+        ),
+        # the blocks' float32 sum, where there is more than one
+        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] * (blocks > 1),
     )
-    # three matrices of an expert, double-buffered, and the tiles
-    weights = 3 * d * f * w_gate.dtype.itemsize
-    room = 2 * weights + 8 * tile * max(d, f) * 4 + (4 << 20)
+    # three matrices of an expert's block, double-buffered, the tiles
+    # and the sum
+    weights = 3 * d * fb * w_gate.dtype.itemsize
+    room = (
+        2 * weights + (8 + 2 * (blocks > 1)) * tile * max(d, fb) * 4
+        + (4 << 20)
+    )
     return named_kernel(
         "moe_expert_ffn",
         pl.pallas_call(

@@ -57,6 +57,7 @@ and re-stacks it, i.e. moves the whole pool three times a step
 (``tests/test_tpu_compile.py`` pins the compiled programs).
 """
 
+import math
 import os
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -427,7 +428,8 @@ def exact_topk_rows(
     scores: jnp.ndarray,  # [B, T] float32
     k: int,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32, T / max_blocks a block
-) -> jnp.ndarray:
+    with_mask: bool = False,
+):
     """The pool ROWS (``block * block_size + offset``, through each
     lane's table) of the ``k`` positions of largest score, exactly
     (``lax.approx_max_k`` below recall 1 would be an approximate answer
@@ -438,15 +440,25 @@ def exact_topk_rows(
     each on the chip: 0.33 ms a layer at 16 lanes x 2048, as long as
     fetching the rows themselves).  Where fewer than ``k`` scores are
     finite the tail names ``-inf`` positions: the caller knows the
-    count."""
+    count.  ``with_mask``: and the same choice as a bool ``[B, T]`` over
+    the positions (finite scores only), for a program that reports what
+    it picked."""
     b, t = scores.shape
     bs = t // block_tables.shape[1]
     rows = (
         block_tables[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)
     ).reshape(b, t)
-    _, rows = lax.sort((-scores, rows), dimension=1, is_stable=True,
-                       num_keys=1)
-    return rows[:, :k]
+    neg, rows = lax.sort((-scores, rows), dimension=1, is_stable=True,
+                         num_keys=1)
+    if not with_mask:
+        return rows[:, :k]
+    # the same choice by POSITION: above the k-th value, and of the
+    # scores equal to it the lowest positions, as the stable sort took
+    kth = -neg[:, k - 1:k]
+    above, equal = scores > kth, scores == kth
+    room = k - jnp.sum(above, -1, keepdims=True)
+    taken = above | (equal & (jnp.cumsum(equal, -1) <= room))
+    return rows[:, :k], taken & jnp.isfinite(scores)
 
 
 def _order_keys(scores: jnp.ndarray) -> jnp.ndarray:
@@ -628,6 +640,108 @@ def selected_prefill_attention(
     return out.astype(v.dtype).reshape(c, nh, d)
 
 
+# ---------------------------------------------------------------------------
+# latent attention (MLA): a token keeps ONE compressed row — no per-head
+# keys or values — which decode reads in absorbed form (the row is every
+# head's key and, in its leading part, every head's value) and a prefill
+# chunk in decompressed, multi-head form
+# ---------------------------------------------------------------------------
+
+
+def latent_rows_decode_attention(
+    q_c: jnp.ndarray,  # [B, H, Dc] absorbed queries: q_nope W_uk
+    q_pe: jnp.ndarray,  # [B, H, Dr] rotated queries
+    c_pool: jnp.ndarray,  # [rows, Dc] the latent pool as token rows
+    pe_pool: jnp.ndarray,  # [rows * Dr / M, M]: M / Dr tokens' keys a row
+    rows: jnp.ndarray,  # [B, K] int32 pool rows (exact_topk_rows)
+    counts: jnp.ndarray,  # [B] int32: how many of a lane's K are real
+    scale: float,
+    backend: Optional[str] = None,
+) -> jnp.ndarray:
+    """Single-token attention of every head over the SELECTED rows of
+    each lane (:func:`exact_topk_rows`) in absorbed form: ``softmax(
+    scale * (q_c . c + q_pe . k_pe)) c``, the first ``counts[b]`` of a
+    lane's rows counted, the rest masked (and routed to row 0, a null
+    block's).  Returns the summed latents ``[B, H, Dc]``; the caller
+    applies ``W_uv``.
+
+    The latents are fetched by one gather into ``[B, K, Dc]``.  The
+    rotated shared keys lie ``M / Dr`` tokens a row of ``M`` lanes (a
+    64-wide minor axis is one the device pads): the token's row is
+    fetched whole, the other tokens' lanes zeroed, and the query laid
+    under every token's lanes, so that ``q_pe . k_pe`` is one product of
+    ``M``.  The attention over both is ``ops/paged_kernels.
+    mla_sparse_decode_kernel`` (``mla_sparse_decode`` in a device
+    trace) or the jnp reference."""
+    n_sel, dr, lanes = rows.shape[1], q_pe.shape[-1], pe_pool.shape[-1]
+    per_row = lanes // dr
+    real = jnp.arange(n_sel)[None] < counts[:, None]
+    rows = jnp.where(real, rows, 0)
+    c = c_pool[rows]  # [B, K, Dc]
+    mine = (jnp.arange(lanes) // dr)[None, None] == (rows % per_row)[..., None]
+    pe = jnp.where(mine, pe_pool[rows // per_row], 0)  # [B, K, M]
+    q_pe = jnp.tile(q_pe, (1, 1, per_row))
+    if (backend or paged_kernel_backend()) == "pallas":
+        from dlrover_tpu.ops.paged_kernels import mla_sparse_decode_kernel
+
+        return mla_sparse_decode_kernel(q_c, q_pe, c, pe, counts, scale=scale)
+    logits = (
+        jnp.einsum("bhd,btd->bht", q_c, c, preferred_element_type=jnp.float32)
+        + jnp.einsum(
+            "bhd,btd->bht", q_pe, pe, preferred_element_type=jnp.float32
+        )
+    ) * scale
+    probs = jax.nn.softmax(jnp.where(real[:, None], logits, NEG_INF), -1)
+    probs = jnp.where(counts[:, None, None] > 0, probs, 0.0)
+    return jnp.einsum(
+        "bht,btd->bhd", probs.astype(c.dtype), c,
+        preferred_element_type=jnp.float32,
+    ).astype(c.dtype)
+
+
+def latent_prefill_attention(
+    q: jnp.ndarray,  # [C, H, Dk] a chunk's queries, one sequence
+    k: jnp.ndarray,  # [H, T, Dk] decompressed keys by position
+    v: jnp.ndarray,  # [H, T, Dv] decompressed values
+    taken: jnp.ndarray,  # [C, T] bool: the keys each query reads
+    start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
+    kv_len: jnp.ndarray,  # scalar int32: keys past it are never read
+    scale: float,
+    backend: Optional[str] = None,
+) -> jnp.ndarray:
+    """A prefill chunk's attention in multi-head form, every head its
+    own decompressed keys (``Dk``) and values (``Dv``, another width),
+    query ``i`` reading exactly the keys ``taken[i]`` marks.  Under the
+    Pallas backend, where rows and keys tile by its blocks,
+    ``ops/paged_kernels.mla_prefill_kernel``; else one head at a time
+    in plain XLA (``[C, T]`` float32 a head).  Returns ``[C, H, Dv]``."""
+    c, t = taken.shape
+    if (backend or paged_kernel_backend()) == "pallas":
+        from dlrover_tpu.ops import paged_kernels as pk
+
+        if (
+            c % min(pk.MLA_BLOCK_Q, c) == 0
+            and t % min(pk.SELECTED_BLOCK_K, t) == 0
+        ):
+            return pk.mla_prefill_kernel(
+                q, k, v, taken, start_pos, kv_len, scale=scale
+            )
+
+    def one_head(head):
+        q_h, k_h, v_h = head
+        s = jnp.einsum(
+            "cd,td->ct", q_h, k_h, preferred_element_type=jnp.float32
+        ) * scale
+        p = jax.nn.softmax(jnp.where(taken, s, NEG_INF), -1)
+        p = jnp.where(jnp.any(taken, -1, keepdims=True), p, 0.0)
+        return jnp.einsum(
+            "ct,td->cd", p.astype(v_h.dtype), v_h,
+            preferred_element_type=jnp.float32,
+        ).astype(v_h.dtype)
+
+    return jnp.swapaxes(lax.map(one_head, (jnp.swapaxes(q, 0, 1), k, v)), 0, 1)
+
+
 class LayerPool(NamedTuple):
     """What one layer of a step program sees of the K/V cache: the
     WHOLE pool, every layer's blocks in one ``[L * num_blocks,
@@ -714,6 +828,45 @@ class LayerPool(NamedTuple):
             v=self.v.reshape(flat).at[rows].set(v_new).reshape(self.v.shape),
         )
 
+    def write_leaf_rows(
+        self,
+        name: str,
+        rows: jnp.ndarray,  # [N, width] one token's row per write
+        block_ids: jnp.ndarray,  # [N] int32, ids of a TABLE (0 = null)
+        offsets: jnp.ndarray,  # [N] int32
+    ) -> "LayerPool":
+        """:meth:`write_leaf` for a leaf whose blocks lie in ROWS,
+        ``[L * num_blocks, block_size * width / minor, minor]``
+        (``paged_leaf_rows()``, ``rl/kv_cache.init_block_pool``): where
+        a token IS a row, one scatter of whole rows over the pool seen
+        as rows, as :meth:`write_rows` writes K and V; where a row holds
+        several tokens, one scatter of ``width``-wide windows into
+        them."""
+        leaf = self.paged[name]
+        rows = rows.astype(leaf.dtype)
+        per_block, minor = leaf.shape[1:]
+        width = rows.shape[1]
+        if width == minor:
+            at = (block_ids + self.base) * per_block + offsets
+            flat = leaf.reshape(-1, minor)
+            leaf = flat.at[at].set(rows).reshape(leaf.shape)
+        else:
+            lane = offsets * width
+            leaf = lax.scatter(
+                leaf,
+                jnp.stack(
+                    [block_ids + self.base, lane // minor, lane % minor], -1
+                ),
+                rows,
+                lax.ScatterDimensionNumbers(
+                    update_window_dims=(1,),
+                    inserted_window_dims=(0, 1),
+                    scatter_dims_to_operand_dims=(0, 1, 2),
+                ),
+                mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+            )
+        return self._replace(paged={**self.paged, name: leaf})
+
     def write_leaf_run(
         self,
         name: str,
@@ -730,7 +883,10 @@ class LayerPool(NamedTuple):
         leaf = self.paged[name]
         rows = rows.reshape(rows.shape[0], -1).astype(leaf.dtype)
         c, width = rows.shape
-        bs, mb = leaf.shape[1] // width, block_table.shape[0]
+        # (a block's layout is the leaf's own: flat, or rows of some
+        # minor width — the blocks read are viewed by token here)
+        bs = math.prod(leaf.shape[1:]) // width
+        mb = block_table.shape[0]
         at = start // bs + jnp.arange(-(-c // bs) + 1)  # table entries
         blocks = jnp.where(
             at < mb, block_table[jnp.minimum(at, mb - 1)], 0
@@ -741,7 +897,7 @@ class LayerPool(NamedTuple):
             mine, rows[jnp.clip(rel, 0, c - 1)],
             leaf[blocks].reshape(-1, bs, width),
         )
-        leaf = leaf.at[blocks].set(new.reshape(-1, bs * width))
+        leaf = leaf.at[blocks].set(new.reshape((-1,) + leaf.shape[1:]))
         return self._replace(paged={**self.paged, name: leaf})
 
 

@@ -696,6 +696,19 @@ def _index_scores_kernel(
         o_ref[...] = jnp.full((block_q, block_k), -jnp.inf, jnp.float32)
 
 
+def _index_scores_params(heads: int, bq: int, bk: int, d: int, dtype):
+    """Fast memory for a step's index queries and head weights (a
+    weight a lane-padded row: ``heads * block_q * 128`` float32), twice
+    each, where that passes what a kernel has by default (64 heads of
+    128: 24 MB); None where it does not (16 heads of 64: 5 MB)."""
+    need = 2 * heads * bq * (d * np.dtype(dtype).itemsize + 128 * 4)
+    if need <= (8 << 20):
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(need + 6 * bq * bk * 4 + (8 << 20))
+    )
+
+
 def index_scores_kernel(
     qi: jnp.ndarray,  # [C, Hi, Di] a chunk's index queries
     w: jnp.ndarray,  # [C, Hi] float32 head weights
@@ -743,6 +756,7 @@ def index_scores_kernel(
             out_shape=jax.ShapeDtypeStruct((c, t), jnp.float32),
             interpret=use_interpret(),
             name="index_scores",
+            compiler_params=_index_scores_params(heads, bq, bk, d, qi.dtype),
         ),
     )(
         jnp.reshape(start_pos, (1,)).astype(jnp.int32),
@@ -868,11 +882,38 @@ def selected_prefill_kernel(
     tile widened once) 12-15 % slower (PERF.md, PR 52)."""
     c, n_heads, d = q.shape
     t, n_kv, _ = k.shape
-    group = n_heads // n_kv
     bq, bk = min(block_q, c), min(block_k, t)
     if c % bq or t % bk:
         raise ValueError(f"a chunk of {c} x {t} keys in blocks {bq} x {bk}")
-    nq, rows = c // bq, group * bq
+    out = _selected_prefill_call(
+        _rows_by_kv_head(q, bq, n_kv), jnp.swapaxes(k, 0, 1),
+        jnp.swapaxes(v, 0, 1), taken, start_pos, kv_len,
+        block_q=bq, block_k=bk, scale=d**-0.5, name="sparse_prefill",
+    )
+    return _rows_by_position(out, c, n_heads, bq)
+
+
+def _selected_prefill_call(
+    qg: jnp.ndarray,  # [KV * C / BQ, G * BQ, Dk] (``_rows_by_kv_head``)
+    k: jnp.ndarray,  # [KV, T, Dk] keys by position, a KV head leading
+    v: jnp.ndarray,  # [KV, T, Dv]
+    taken: jnp.ndarray,  # [C, T] bool
+    start_pos: jnp.ndarray,
+    kv_len: jnp.ndarray,
+    *,
+    block_q: int,
+    block_k: int,
+    scale: float,
+    name: str,
+) -> jnp.ndarray:
+    """The ``pallas_call`` of :func:`selected_prefill_kernel` and
+    :func:`mla_prefill_kernel` under ``name``: keys of ``Dk``, values
+    of ``Dv`` (the two may differ).  Returns ``[KV * C / BQ, G * BQ,
+    Dv]``."""
+    n_kv, t, d = k.shape
+    dv = v.shape[-1]
+    bq, bk = block_q, block_k
+    nq, rows = taken.shape[0] // bq, qg.shape[1]
 
     def last_block(i, bounds):
         last = jnp.minimum(bounds[1] - 1, bounds[0] + (i + 1) * bq - 1)
@@ -895,37 +936,183 @@ def selected_prefill_kernel(
         in_specs=[
             pl.BlockSpec((1, rows, d), q_index),
             pl.BlockSpec((1, bk, d), kv_index),
-            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((1, bk, dv), kv_index),
             pl.BlockSpec((bq, bk), keep_index),
         ],
-        out_specs=pl.BlockSpec((1, rows, d), q_index),
+        out_specs=pl.BlockSpec((1, rows, dv), q_index),
         scratch_shapes=[
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, dv), jnp.float32),
         ],
     )
-    out = named_kernel(
-        "sparse_prefill",
+    return named_kernel(
+        name,
         pl.pallas_call(
             functools.partial(
                 _selected_prefill_kernel, block_q=bq, block_k=bk,
-                scale=d**-0.5,
+                scale=scale,
             ),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n_kv * nq, rows, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((n_kv * nq, rows, dv), qg.dtype),
             interpret=use_interpret(),
-            name="sparse_prefill",
+            name=name,
             compiler_params=_chunk_step_params(rows, bk),
         ),
     )(
         jnp.stack([start_pos, kv_len]).astype(jnp.int32),
-        _rows_by_kv_head(q, bq, n_kv),
-        jnp.swapaxes(k, 0, 1),
-        jnp.swapaxes(v, 0, 1),
-        taken.astype(jnp.int8),
+        qg, k, v, taken.astype(jnp.int8),
+    )
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA): ONE cached row a token serves every query head
+# ---------------------------------------------------------------------------
+
+
+# a head's rows a grid step of ``mla_prefill_kernel`` takes: the whole
+# of a 512-row chunk, so that a head's key block is fetched once
+MLA_BLOCK_Q = 512
+
+
+def mla_prefill_kernel(
+    q: jnp.ndarray,  # [C, H, Dk] a chunk's queries (nope | rope)
+    k: jnp.ndarray,  # [H, T, Dk] decompressed keys by position, a head leading
+    v: jnp.ndarray,  # [H, T, Dv] decompressed values
+    taken: jnp.ndarray,  # [C, T] bool: the keys each query reads
+    start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
+    kv_len: jnp.ndarray,  # scalar int32: keys past it are never read
+    *,
+    scale: float,
+    block_q: int = MLA_BLOCK_Q,
+    block_k: int = SELECTED_BLOCK_K,
+) -> jnp.ndarray:
+    """A prefill chunk's attention in MULTI-HEAD (decompressed) form
+    under a per-query selection: :func:`selected_prefill_kernel`'s step
+    (a flash forward whose mask is data, key blocks past the causal
+    reach or ``kv_len`` neither fetched nor computed) with every head
+    its own keys and values, of two widths (``Dk`` 192 = 128 + the 64
+    rotated, ``Dv`` 128), ``mla_prefill`` in a device trace.  A grid
+    step is one head's ``block_q`` rows — the whole of a 512-row chunk,
+    so that a key block is fetched once a head — against ``block_k``
+    keys.  ``k`` and ``v`` arrive a head leading, as the decompression
+    writes them.  Returns ``[C, H, Dv]``."""
+    c, n_heads, _ = q.shape
+    t = k.shape[1]
+    bq, bk = min(block_q, c), min(block_k, t)
+    if c % bq or t % bk:
+        raise ValueError(f"a chunk of {c} x {t} keys in blocks {bq} x {bk}")
+    out = _selected_prefill_call(
+        _rows_by_kv_head(q, bq, n_heads), k, v, taken, start_pos, kv_len,
+        block_q=bq, block_k=bk, scale=scale, name="mla_prefill",
     )
     return _rows_by_position(out, c, n_heads, bq)
+
+
+def _mla_decode_kernel(
+    counts_ref,  # scalar prefetch [B]: rows of a lane that are real
+    qc_ref,  # [1, H, Dc]: every head's absorbed query
+    qpe_ref,  # [1, H, M]: every head's rotated query
+    c_ref,  # [1, P, Dc]: a page of the lane's selected latents
+    pe_ref,  # [1, P, M]: their rotated shared keys
+    o_ref,  # [1, H, Dc]
+    m_scr,
+    l_scr,
+    acc_scr,
+    *,
+    page: int,
+    scale: float,
+):
+    b, j = pl.program_id(0), pl.program_id(1)
+    count = counts_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        _init_state(m_scr, l_scr, acc_scr)
+
+    # a lane's pages past its rows were index-clamped: no copy, no work
+    @pl.when(j * page < count)
+    def _compute():
+        c = c_ref[0]
+        s_log = (
+            lax.dot_general(
+                qc_ref[0], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            + lax.dot_general(
+                qpe_ref[0], pe_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        ) * scale
+        # the latent is key and value at once
+        _online_update(
+            m_scr, l_scr, acc_scr, s_log, c,
+            j * page + _iota_cols(page) < count,
+        )
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        _finalize(o_ref, m_scr, l_scr, acc_scr)
+
+
+def mla_sparse_decode_kernel(
+    q_c: jnp.ndarray,  # [B, H, Dc] absorbed queries: q_nope W_uk
+    q_pe: jnp.ndarray,  # [B, H, M] rotated queries
+    c: jnp.ndarray,  # [B, K, Dc] the selected latents, gathered
+    pe: jnp.ndarray,  # [B, K, M] their rotated shared keys
+    counts: jnp.ndarray,  # [B] int32: rows of a lane that are real
+    *,
+    scale: float,
+    page: int = 512,
+) -> jnp.ndarray:
+    """Decode attention in ABSORBED form over a lane's selected rows
+    (``ops/paged_attention.latent_rows_decode_attention``),
+    ``mla_sparse_decode`` in a device trace: a token's latent is the
+    key of every head (with its one rotated shared key) and the value
+    of every head, so a page of rows is fetched ONCE for 128 heads — ``2
+    * H * (576 + 512)`` operations a row against ``2 * 576`` bytes, 242
+    a byte at 128 heads: on the v5e's ridge.  Rows past ``counts`` are
+    masked, and a lane's pages past them are neither fetched nor
+    computed.  Returns ``[B, H, Dc]`` (the summed latents: the caller
+    applies ``W_uv``)."""
+    batch, n_heads, dc = q_c.shape
+    n_sel, lanes = c.shape[1], pe.shape[-1]
+    page = int(np.gcd(n_sel, page))
+
+    def page_index(b, j, counts):
+        last = jnp.maximum(lax.div(counts[b] + page - 1, page) - 1, 0)
+        return (b, jnp.minimum(j, last), 0)
+
+    def lane_index(b, j, counts):
+        del j, counts
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(batch, n_sel // page),
+        in_specs=[
+            pl.BlockSpec((1, n_heads, dc), lane_index),
+            pl.BlockSpec((1, n_heads, lanes), lane_index),
+            pl.BlockSpec((1, page, dc), page_index),
+            pl.BlockSpec((1, page, lanes), page_index),
+        ],
+        out_specs=pl.BlockSpec((1, n_heads, dc), lane_index),
+        scratch_shapes=[
+            pltpu.VMEM((n_heads, 128), jnp.float32),
+            pltpu.VMEM((n_heads, 128), jnp.float32),
+            pltpu.VMEM((n_heads, dc), jnp.float32),
+        ],
+    )
+    return named_kernel(
+        "mla_sparse_decode",
+        pl.pallas_call(
+            functools.partial(_mla_decode_kernel, page=page, scale=scale),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((batch, n_heads, dc), q_c.dtype),
+            interpret=use_interpret(),
+            name="mla_sparse_decode",
+        ),
+    )(counts.astype(jnp.int32), q_c, q_pe, c, pe)
 
 
 # keys a grid step of ``chunk_prefill_kernel`` reads; whoever lays out

@@ -366,6 +366,40 @@ def trinity_factory(**cfg_kwargs):
     }
 
 
+def deepseek_v32_factory(**cfg_kwargs):
+    """Built-in factory of the decoder with latent attention, a learned
+    top-k indexer and a share of its group-routed experts
+    (``models/deepseek_v32.py``): the same worker contract, with the
+    model's own step programs — its config declares that it pages no K
+    and no V (``cfg.pages_kv``), only one latent row and one index key
+    a token (``cfg.paged_leaves()``), and they return the experts every
+    position was sent to (``cfg.per_token_outputs()``) — and its own
+    ``serving_params_fn``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import deepseek_v32
+
+    if isinstance(cfg_kwargs.get("dtype"), str):
+        # the spec rides through JSON: dtype arrives as a name
+        cfg_kwargs = dict(cfg_kwargs, dtype=jnp.dtype(cfg_kwargs["dtype"]))
+    cfg = deepseek_v32.DeepSeekV32Config(**cfg_kwargs)
+    return {
+        "forward_fn": partial(deepseek_v32.forward, cfg=cfg),
+        "params_template_fn": lambda: deepseek_v32.init_params(
+            jax.random.PRNGKey(0), cfg
+        ),
+        "cfg": cfg,
+        "paged_decode_fn": partial(deepseek_v32.paged_decode_step, cfg=cfg),
+        "paged_prefill_fn": partial(
+            deepseek_v32.paged_prefill_chunk, cfg=cfg
+        ),
+        "serving_params_fn": partial(deepseek_v32.serving_params, cfg=cfg),
+    }
+
+
 def worker_main() -> int:
     """Generation-process entry (``python -m
     dlrover_tpu.rl.generation_service``); spec arrives via env."""
@@ -655,14 +689,18 @@ def _serving_worker_loop(spec) -> int:
     # slots; both sides derive the SAME slot geometry from the sched
     # spec + this pool's per-block region size, so a staged [L,
     # n_blocks, block_size, KV, head_dim] pair round-trips bitwise
-    block_bytes = region_nbytes_per_block(scheduler._pool)
     if scheduler.pool_cfg.paged_leaves and spec.get("ship_arena"):
         raise ValueError(
             "a disaggregated fleet's ship arena carries K and V only: "
             "the model also pages "
-            + ", ".join(scheduler.pool_cfg.paged_names[2:])
+            + ", ".join(scheduler.pool_cfg.leaf_names)
             + ", which a shipped prefill would lose"
         )
+    # (a model that pages no K / V has no arena: refused above)
+    block_bytes = (
+        region_nbytes_per_block(scheduler._pool)
+        if scheduler.pool_cfg.pages_kv else 0
+    )
     import math as _math
 
     ship_slot_bytes = 2 * block_bytes * _math.ceil(

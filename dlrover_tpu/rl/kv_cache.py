@@ -40,6 +40,13 @@ window.  At 16 lanes of 32 k positions, one full and four window layers
 of 4096 cost 2.39 + 1.62 GB where one table for all five would cost
 11.9.
 
+**Tokens that keep no keys and values**: a model whose attention reads
+ONE compressed row a token for every head (a latent) MAY say that it
+pages no ``k`` / ``v`` at all (``pages_kv = False``).  The pool then
+holds its ``paged_leaves()`` alone — no zero-width stand-in, no unread
+``v`` — and everything a block id names (bytes, shipping regions,
+labels) follows from those leaves.
+
 **Layers that keep no keys**: a model whose lanes keep state beside
 their pages MAY say, a layer, which of the two that layer keeps
 (``layer_keeps()``: ``"pages"``, ``"state"`` or ``"both"``).  ``k`` /
@@ -120,6 +127,14 @@ class PagedCacheConfig:
     # side by side, and not ``[block_size, KV, head_dim]``: as the model
     # declares it, whose step programs address the pool
     flat_pages: bool = False
+    # the model pages ``k`` and ``v``; False for one whose tokens keep
+    # the ``paged_leaves`` alone (a latent row a token, no per-head
+    # keys or values): ``n_kv_heads`` / ``head_dim`` are then 0 and
+    # unread
+    pages_kv: bool = True
+    # ``((leaf, minor), ...)``: the paged leaves whose blocks lie in
+    # rows of ``minor`` elements, as the model declares them
+    leaf_rows: Tuple[Tuple[str, int], ...] = ()
 
     @property
     def n_window_layers(self) -> int:
@@ -162,9 +177,15 @@ class PagedCacheConfig:
 
     @property
     def paged_names(self) -> Tuple[str, ...]:
-        """Every leaf of the pool that is paged: ``k``, ``v`` and the
-        model's further ones — what a block ship carries."""
-        return ("k", "v") + tuple(name for name, _, _ in self.paged_leaves)
+        """Every leaf of the pool that is paged: ``k``, ``v`` (of a
+        model that pages them) and the model's further ones — what a
+        block ship carries."""
+        return (("k", "v") if self.pages_kv else ()) + self.leaf_names
+
+    @property
+    def leaf_names(self) -> Tuple[str, ...]:
+        """The ``paged_leaves`` by name."""
+        return tuple(name for name, _, _ in self.paged_leaves)
 
     @property
     def usable_blocks(self) -> int:
@@ -247,7 +268,26 @@ def paged_cache_config(
     take of any pool).  For a KV head count that is no multiple of the
     chip's sublane tile (30): there ``[block_size, 30, head_dim]`` is
     padded to 32 in memory, and turning it into the kernels' view moves
-    the whole pool."""
+    the whole pool.
+
+    A model whose attention reads one compressed row a token for every
+    head declares ``pages_kv = False``: its tokens keep NO per-head keys
+    and values, so the pool holds its ``paged_leaves()`` alone
+    (``n_kv_heads`` / ``head_dim`` are not read) — a ``v`` of equal size
+    that is never read would double the cache.  Such a model declares at
+    least one paged leaf, and neither windows nor lane state nor
+    ``layer_keeps``.
+
+    Any model MAY say, for some of its paged leaves, in rows of how many
+    elements a block lies (``paged_leaf_rows() -> {leaf: minor}``): the
+    leaf is then ``[layers, num_blocks, block_size * width / minor,
+    minor]`` — the same bytes in the same order as the flat layout, with
+    ``minor`` (a multiple of the device's 128 lanes; a block shorter
+    than a row is one row) the minor axis, so that the pool viewed as
+    rows (merging the leading axes: free) is read row by row through a
+    gather.  A minor axis of a token's own width that is no multiple of
+    128 (576, 64) is one the device pads or lays out blocks-minor, and
+    every program then copies the leaf."""
 
     def declared(method):
         method = getattr(model_cfg, method, None)
@@ -266,8 +306,41 @@ def paged_cache_config(
             f"lane_state / paged_leaves leaf name(s) {clash} are taken "
             "(``k`` and ``v`` are the paged K/V pool's)"
         )
+    pages_kv = bool(getattr(model_cfg, "pages_kv", True))
     windows = getattr(model_cfg, "layer_windows", None)
     windows = tuple(windows()) if windows else ()
+    if not pages_kv:
+        for ok, what in (
+            (bool(paged), "and declares no paged_leaves(): it caches nothing"),
+            (not leaves, "beside lane_state(): the state slabs are laid "
+             "out by the layers of the k / v pool"),
+            (not any(w is not None for w in windows),
+             "beside layer_windows(): the window layers' blocks are wk / wv"),
+            (not getattr(model_cfg, "layer_keeps", None),
+             "beside layer_keeps(): every layer keeps the paged leaves"),
+        ):
+            if not ok:
+                raise ValueError(
+                    f"a model that pages no k / v (pages_kv false) {what}"
+                )
+    leaf_rows = getattr(model_cfg, "paged_leaf_rows", None)
+    leaf_rows = dict(leaf_rows() if leaf_rows else {})
+    widths = {name: math.prod(shape) for name, shape, _ in paged}
+    for name, minor in leaf_rows.items():
+        if name not in widths:
+            raise ValueError(
+                f"paged_leaf_rows(): {name!r} is no paged leaf "
+                f"({sorted(widths)})"
+            )
+        # a block shorter than a row (a test's) is one row
+        minor = math.gcd(block_size * widths[name], minor)
+        if minor % widths[name] and widths[name] % minor:
+            raise ValueError(
+                f"paged_leaf_rows(): rows of {minor} hold no whole token "
+                f"of {name!r} ({widths[name]}) and no token whole rows"
+            )
+        leaf_rows[name] = minor
+    leaf_rows = tuple(leaf_rows.items())
     table_blocks = 0
     if not any(w is not None for w in windows):
         windows = ()
@@ -322,8 +395,8 @@ def paged_cache_config(
             keeps = ()  # what every layer does undeclared
     return PagedCacheConfig(
         n_layers=model_cfg.n_layers,
-        n_kv_heads=model_cfg.n_kv_heads,
-        head_dim=model_cfg.head_dim,
+        n_kv_heads=model_cfg.n_kv_heads if pages_kv else 0,
+        head_dim=model_cfg.head_dim if pages_kv else 0,
         num_blocks=num_blocks,
         block_size=block_size,
         dtype=model_cfg.dtype,
@@ -334,6 +407,8 @@ def paged_cache_config(
         window_table_blocks=table_blocks,
         layer_keeps=keeps,
         flat_pages=bool(getattr(model_cfg, "flat_pages", False)),
+        pages_kv=pages_kv,
+        leaf_rows=leaf_rows,
     )
 
 
@@ -350,7 +425,9 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
     ``v`` hold the layers that page and each slab the layers that hold
     state, both in layer order; where it declares ``flat_pages``, a
     block's rows lie side by side: ``[L, num_blocks, block_size * KV,
-    head_dim]``."""
+    head_dim]``.  A model that pages no ``k`` / ``v`` gets its paged
+    leaves alone; a leaf declared in rows of ``minor`` lies ``[L,
+    num_blocks, block_size * width / minor, minor]``."""
     shape = (
         cfg.n_full_layers,
         cfg.num_blocks,
@@ -360,10 +437,10 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
     )
     if cfg.flat_pages:
         shape = shape[:2] + (cfg.block_size * cfg.n_kv_heads, cfg.head_dim)
-    pool = {
-        "k": jnp.zeros(shape, dtype=cfg.dtype),
-        "v": jnp.zeros(shape, dtype=cfg.dtype),
-    }
+    pool = {}
+    if cfg.pages_kv:
+        pool["k"] = jnp.zeros(shape, dtype=cfg.dtype)
+        pool["v"] = jnp.zeros(shape, dtype=cfg.dtype)
     if cfg.n_window_layers:
         wshape = (cfg.n_window_layers, cfg.window_blocks) + shape[2:]
         pool["wk"] = jnp.zeros(wshape, dtype=cfg.dtype)
@@ -376,8 +453,14 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
         # a block's rows side by side in ONE minor axis: a minor axis
         # of a token's own width (64) is one the device pads or lays
         # out blocks-minor, and every program then copies the leaf
+        # — unless the model reads it row by row and says in rows of
+        # how many lanes a block lies
+        block = cfg.block_size * math.prod(leaf_shape)
+        minor = dict(cfg.leaf_rows).get(name)
         pool[name] = jnp.zeros(
-            shape[:2] + (cfg.block_size * math.prod(leaf_shape),),
+            shape[:2] + (
+                (block,) if minor is None else (block // minor, minor)
+            ),
             dtype=dtype,
         )
     return pool
@@ -404,6 +487,14 @@ def prefix_block_keys(tokens, block_size: int) -> List[str]:
         h.update(toks[start:start + block_size].tobytes())
         keys.append(h.hexdigest())
     return keys
+
+
+def block_nbytes(pool: Dict[str, jnp.ndarray],
+                 leaves: Sequence[str]) -> int:
+    """Bytes ONE block id names over ``leaves``
+    (``PagedCacheConfig.paged_names``) and all their layers: what a
+    live block costs, and what a ship of it carries."""
+    return sum(region_nbytes_per_block(pool, name) for name in leaves)
 
 
 def region_nbytes_per_block(pool: Dict[str, jnp.ndarray],

@@ -94,7 +94,7 @@ from dlrover_tpu.rl.kv_cache import (
     paged_cache_config,
     pool_can_ever_hold,
     prefix_block_keys,
-    region_nbytes_per_block,
+    block_nbytes,
 )
 
 SLO_INTERACTIVE = "interactive"
@@ -413,7 +413,8 @@ class ContinuousBatchingScheduler:
 
     What a model must provide (``models/llama.py``,
     ``models/falcon_h1.py``, ``models/keye_vl2.py``,
-    ``models/trinity.py`` and ``models/olmo_hybrid.py`` do):
+    ``models/trinity.py``, ``models/olmo_hybrid.py`` and
+    ``models/deepseek_v32.py`` do):
 
     - ``model_cfg``: the paged K/V geometry as attributes
       (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and,
@@ -438,7 +439,15 @@ class ContinuousBatchingScheduler:
       *shape]}``; while logprobs are captured it rides into
       ``GenResult.per_token``, and such a model takes no prefix hit
       (a shared block has no rows).  Either refuses a K-step window
-      and a draft model at construction.  And optionally
+      and a draft model at construction.  A model whose tokens keep NO
+      per-head keys and values (``models/deepseek_v32.py``: one latent
+      row a token serves every head) says ``pages_kv = False``: the
+      pool then holds its ``paged_leaves()`` alone, ``n_kv_heads`` /
+      ``head_dim`` are not read, the ``serve_step`` record carries
+      ``cache_bytes`` (beside an indexer's ``sel_rows`` and
+      ``cached_rows``), and the ``prefill`` role is refused by name
+      beside the two above (a ship's regions are a K and a V).  And
+      optionally
       ``layer_windows() -> (window | None, ...)``, one entry a layer
       (``models/trinity.py``): a layer WITH a window reads the keys ``t
       - window < s <= t`` only, so its blocks are its own kind — the
@@ -700,7 +709,7 @@ class ContinuousBatchingScheduler:
             per_token() if per_token and self.capture_logprobs else {}
         )
         if cache_cfg.paged_leaves or per_token:
-            leaves = ", ".join(cache_cfg.paged_names[2:]) or "none"
+            leaves = ", ".join(cache_cfg.leaf_names) or "none"
             for refused, why in (
                 (self.decode_k > 1,
                  "multi-token decode (DLROVER_TPU_DECODE_STEPS > 1): "
@@ -708,6 +717,11 @@ class ContinuousBatchingScheduler:
                 (draft_cfg is not None,
                  "a draft model: its verify-and-write step reads and "
                  "writes K and V only"),
+                # a model whose tokens keep no K / V at all: the ship
+                # arena's slots are laid out as a K and a V region
+                (role == "prefill" and not cache_cfg.pages_kv,
+                 "the prefill role: a shipped prefill carries K and V "
+                 "regions, and this model pages neither"),
             ):
                 if refused:
                     raise ValueError(
@@ -755,9 +769,10 @@ class ContinuousBatchingScheduler:
         self.block_pool = BlockPool(cache_cfg)
         self._pool = init_block_pool(cache_cfg)
         self.state_bytes = lane_state_nbytes(self._pool, cache_cfg)
-        # bytes one block id names over the layers that page (K and V;
-        # a model with lane state pages nothing else)
-        self._block_bytes = 2 * region_nbytes_per_block(self._pool)
+        # bytes one block id names over the layers that page: K and V
+        # (a model with lane state pages nothing else), or the paged
+        # leaves of a model that keeps neither
+        self._block_bytes = block_nbytes(self._pool, cache_cfg.paged_names)
         # the draft pool mirrors the policy pool's GEOMETRY (same
         # block ids, tables, block size) with the DRAFT model's shapes
         # — one host-side allocator drives both
@@ -838,19 +853,24 @@ class ContinuousBatchingScheduler:
         self.overrun_tokens = 0
         # a model with an indexer / a router: what a decode step's
         # lanes selected and how its rows fell on the experts (the
-        # ``serve_step`` labels ``sel_rows``, ``index_bytes``,
+        # ``serve_step`` labels ``sel_rows``, ``cached_rows``,
+        # ``index_bytes``,
         # ``experts_hit``, ``expert_rows_max``, ``expert_rows_mean``),
         # and their sums over the run
         self._index_row_bytes = sum(
             int(np.prod(shape)) * np.dtype(dtype).itemsize
             for _, shape, dtype in cache_cfg.paged_leaves
         ) * cache_cfg.n_layers
+        self._has_indexer = bool(
+            getattr(model_cfg, "topk", None) and cache_cfg.paged_leaves
+        )
         self._step_sel_rows = self._step_index_bytes = 0
+        self._step_cached_rows = 0
         self._step_experts: Dict = {}
         # a model with windows: the rows a decode step's kernels had to
-        # read, by kind of layer, and a chunk's shape (``serve_step``
-        # labels ``kv_rows_window`` / ``kv_rows_full``; ``prefill``
-        # span labels ``rows`` / ``kv_len``)
+        # read, by kind of layer (``serve_step`` labels
+        # ``kv_rows_window`` / ``kv_rows_full``); every model: a chunk's
+        # shape (``prefill`` span labels ``rows`` / ``kv_len``)
         self._step_kv_rows = [0, 0]
         self._step_chunk: Dict = {}
         self._window_counts = (0, 0)
@@ -1339,7 +1359,7 @@ class ContinuousBatchingScheduler:
             sync_steps=dict(self.sync_steps),
             overrun_tokens=self.overrun_tokens,
         )
-        if self.pool_cfg.paged_leaves:
+        if self._has_indexer:
             st.update(sel_rows=self.sel_rows, index_bytes=self.index_bytes)
         if self.expert_totals["steps"]:
             st.update(self.expert_totals)
@@ -1741,10 +1761,10 @@ class ContinuousBatchingScheduler:
     def _note_selection(self, cached: int):
         """One decode lane's share of the step's ``sel_rows`` /
         ``index_bytes`` labels (a model with an indexer only)."""
-        topk = getattr(self.cfg, "topk", None)
-        if topk is None or not self.pool_cfg.paged_leaves:
+        if not self._has_indexer:
             return
-        self._step_sel_rows += min(cached, int(topk))
+        self._step_sel_rows += min(cached, int(self.cfg.topk))
+        self._step_cached_rows += cached
         self._step_index_bytes += cached * self._index_row_bytes
 
     def _note_experts(self, rows: Dict[str, np.ndarray], slots: List[int]):
@@ -2390,6 +2410,7 @@ class ContinuousBatchingScheduler:
         self._step_commits = 0
         self._step_state_resets = self._step_prefill_heads = 0
         self._step_sel_rows = self._step_index_bytes = 0
+        self._step_cached_rows = 0
         self._step_experts = {}
         self._step_kv_rows = [0, 0]
         self._step_chunk = {}
@@ -2440,7 +2461,7 @@ class ContinuousBatchingScheduler:
                     tokens=pre,
                     prefix_hit_blocks=hit_blocks,
                     req_id=self._last_prefill_req,
-                    **(self._step_chunk if self.window is not None else {}),
+                    **self._step_chunk,
                 )
             if dec or self._lanes_decode:
                 self._events.complete(
@@ -2491,8 +2512,13 @@ class ContinuousBatchingScheduler:
         """The ``serve_step`` labels of a model that keeps per-lane
         state: how its layers divide between the two kinds of cache and
         what the cache held this step — the state slabs and the blocks
-        live over the layers that page; none for a model of pages only
-        (its record is as it was)."""
+        live over the layers that page; of a model that pages no K / V,
+        the bytes its live blocks hold; none for a model of K / V pages
+        only (its record is as it was)."""
+        if not self.pool_cfg.pages_kv:
+            return dict(
+                cache_bytes=self.block_pool.used_blocks * self._block_bytes
+            )
         if not self.lane_state:
             return {}
         return dict(
@@ -2506,11 +2532,17 @@ class ContinuousBatchingScheduler:
         """The ``serve_step`` labels of a model with an indexer or a
         router; none for a model without (its record is as it was)."""
         out = dict(self._step_experts)
-        if self.pool_cfg.paged_leaves:
+        if self._has_indexer:
+            # a lane-step's attention reads the selected rows of the
+            # cached ones, a layer: both summed over the decoding lanes
             out.update(
                 sel_rows=self._step_sel_rows,
-                index_bytes=self._step_index_bytes,
+                cached_rows=self._step_cached_rows,
             )
+            if self.pool_cfg.pages_kv:
+                # beside K and V the paged leaves are the index keys
+                # alone: what the indexer had to scan
+                out.update(index_bytes=self._step_index_bytes)
         if self.window is not None:
             # rows the decode kernels had to read, summed over the lanes
             # that decoded and the layers of each kind; the window

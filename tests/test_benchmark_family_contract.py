@@ -82,6 +82,18 @@ TINY = {
         linear_num_key_heads=4, linear_num_value_heads=4,
         linear_key_head_dim=12, linear_value_head_dim=24,
     ),
+    # ``index_topk`` below SEQ: forward and reference both select; 2
+    # groups of which 1 is taken; 2 routed experts held of the router's
+    # 32 x 2 (``deployment`` stays the configuration's: 32 chips a layer)
+    "family_deepseek_v32": lambda cfg: dict(
+        hidden_size=64, num_hidden_layers=3, first_k_dense_replace=1,
+        num_dense_layers=1, num_expert_layers=2, vocab_size=384,
+        num_attention_heads=4, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        intermediate_size=128, moe_intermediate_size=32,
+        n_routed_experts=2, num_experts_per_tok=2, n_group=2,
+        topk_group=1, index_n_heads=2, index_head_dim=16, index_topk=16,
+    ),
 }
 
 

@@ -1,0 +1,794 @@
+"""DeepSeek-V3.2's decoder (``models/deepseek_v32.py``: latent
+attention over a cache of one compressed row a token, a learned top-k
+indexer, a share of group-routed experts) on the serving plane, at tiny
+sizes on the CPU.
+
+The chain of evidence: the benchmark's plain reference
+(``benchmarks/reference_deepseek_v32.py``, which imports nothing of the
+program; multi-head form, no cache) = the program's whole-sequence
+forward = what the scheduler serves through chunked prefill (the rows
+decompressed) and paged decode (the rows read in absorbed form) over a
+pool that holds no ``k`` and no ``v``.  Logits are compared, never
+tokens.  The tiny configuration's ``index_topk`` (32) is below its
+sequences, so every test that serves also selects, and its router takes
+1 of 2 groups.
+"""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import family_deepseek_v32 as F  # noqa: E402
+import reference_deepseek_v32 as R  # noqa: E402
+
+from dlrover_tpu.models import deepseek_v32 as M, llama  # noqa: E402
+from dlrover_tpu.observability.events import EventLogger  # noqa: E402
+import dlrover_tpu.ops.grouped_gemm  # noqa: E402,F401  (the MODULE:
+# ``from dlrover_tpu.ops import grouped_gemm`` is the function)
+from dlrover_tpu.ops import paged_attention as pa  # noqa: E402
+from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV  # noqa: E402
+from dlrover_tpu.rl.generation_service import (  # noqa: E402
+    deepseek_v32_factory,
+)
+from dlrover_tpu.rl.kv_cache import (  # noqa: E402
+    block_nbytes,
+    extract_block_regions,
+    init_block_pool,
+    insert_block_regions,
+    paged_cache_config,
+)
+from dlrover_tpu.rl.scheduler import (  # noqa: E402
+    ContinuousBatchingScheduler,
+    SchedulerConfig,
+)
+
+with open(os.path.join(
+    BENCH, "tests", "tiny", "data", "configs", "tiny-deepseek-v32.json"
+)) as _f:
+    HF = json.load(_f)
+with open(os.path.join(BENCH, "configs", "deepseek-v3.2.json")) as _f:
+    PUBLISHED = json.load(_f)
+grouped_gemm = sys.modules["dlrover_tpu.ops.grouped_gemm"]
+KW = dict(F.model_kwargs(HF, 64), dtype="float32")
+PARTS = deepseek_v32_factory(**KW)
+CFG = PARTS["cfg"]
+TOPK = HF["index_topk"]
+SCHED = dict(
+    max_slots=3, block_size=4, num_blocks=48, max_seq_len=64,
+    prefill_chunk=12, temperature=1.0,
+)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return R.seeded_params(HF, 2**31 + 42)
+
+
+@pytest.fixture(autouse=True)
+def _exact_float32():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def make_scheduler(params, events=None, capture_logprobs=True,
+                   role="unified", **overrides):
+    sch = ContinuousBatchingScheduler(
+        CFG, SchedulerConfig(**dict(SCHED, **overrides)),
+        paged_decode_fn=PARTS["paged_decode_fn"],
+        paged_prefill_fn=PARTS["paged_prefill_fn"],
+        serving_params_fn=PARTS["serving_params_fn"],
+        capture_logprobs=capture_logprobs, events=events, role=role,
+    )
+    sch.sync_weights(params)
+    return sch
+
+
+def prompts_of(lengths, seed=1):
+    rng = np.random.default_rng(seed)
+    return [
+        rng.integers(0, HF["vocab_size"], size=n).astype(np.int32)
+        for n in lengths
+    ]
+
+
+def serve(sch, prompts, max_new=9):
+    for i, p in enumerate(prompts):
+        sch.submit(p, max_new=max_new + i, seed=i)
+    return {r.req_id: r for r in sch.run()}
+
+
+# ------------------------------------- (a) the forward is the reference
+
+
+def test_init_params_has_the_reference_tree():
+    ours = jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), CFG)
+    )
+    assert jax.tree_util.tree_map(
+        lambda a: a.shape, ours
+    ) == jax.tree_util.tree_map(
+        tuple, R.model_shapes(HF), is_leaf=lambda x: isinstance(x, tuple)
+    )
+
+
+def test_forward_matches_the_reference_per_token(params):
+    tokens = np.stack(prompts_of((48, 48), seed=3))  # 3 x index_topk
+    logits, experts = M.forward(
+        params, jnp.asarray(tokens), CFG, return_experts=True
+    )
+    logp = jax.nn.log_softmax(logits, -1)
+    got = np.take_along_axis(
+        np.asarray(logp)[:, :-1], tokens[:, 1:, None], -1
+    )[..., 0]
+    want = np.asarray(R.token_logprobs(params, tokens, HF))
+    np.testing.assert_allclose(got, want, atol=5e-5)
+    # the router's choices are the reference's own: forced onto them it
+    # reads the same logprobs and no slack anywhere
+    forced, slack = R.token_logprobs_forced(
+        params, tokens, HF, {"experts": np.asarray(experts)}
+    )
+    np.testing.assert_allclose(np.asarray(forced), want, atol=5e-5)
+    assert float(np.asarray(slack).max()) == 0.0
+    # the serving copy (W_kvb as its two views) computes the same
+    served = M.forward(
+        M.serving_params(params, CFG), jnp.asarray(tokens), CFG
+    )
+    np.testing.assert_allclose(
+        np.asarray(served), np.asarray(logits), atol=5e-5
+    )
+
+
+def test_the_reference_reports_a_wrong_router_and_a_wrong_group(params):
+    tokens = np.stack(prompts_of((24,), seed=4))
+    _, experts = M.forward(
+        params, jnp.asarray(tokens), CFG, return_experts=True
+    )
+    experts = np.array(experts)
+    e = F.router_width(HF)
+    per_group = e // HF["n_group"]
+
+    forced = jax.jit(lambda served: R.token_logprobs_forced(
+        params, tokens, HF, {"experts": served}
+    )[1])
+
+    def slack_of(served):
+        return np.asarray(forced(served))
+
+    # the other experts of the SAME group: a wrong choice inside it
+    group = experts[0, 5, 1, 0] // per_group
+    inside = [
+        x for x in range(group * per_group, (group + 1) * per_group)
+        if x not in experts[0, 5, 1]
+    ]
+    swapped = experts.copy()
+    swapped[0, 5, 1] = inside[:2]
+    got = slack_of(swapped)
+    assert got[0, 5] > 0 and np.isfinite(got).all()
+    assert (np.delete(got[0], 5)[:5] == 0).all()
+    # the two best experts of the OTHER group: the group limit is seen
+    other = 1 - group
+    moved = experts.copy()
+    moved[0, 6, 0] = [other * per_group, other * per_group + 1]
+    assert slack_of(moved)[0, 6] > 0
+    # one expert from each group: more groups than topk_group (1)
+    split = experts.copy()
+    split[0, 7, 0] = [0, per_group]
+    assert np.isinf(slack_of(split)[0, 7])
+    for bad in (-1, e, experts[0, 8, 0, 1]):  # malformed: inf
+        broken = experts.copy()
+        broken[0, 8, 0, 0] = bad
+        assert np.isinf(slack_of(broken)[0, 8])
+
+
+# ------------------------ (b) chunked prefill + paged decode = the same
+
+
+def reference_logprobs(params, result, prompt_len):
+    ref = np.asarray(R.token_logprobs(params, result.tokens[None], HF))[0]
+    return ref[prompt_len - 1:]
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_served_logprobs_match_the_reference(params, backend, monkeypatch):
+    # six prompts on three lanes, chunks of 12: the prompts of 30 and
+    # 41 end past index_topk = 32 (41 with a chunk boundary past it), the
+    # longer requests decode past it (contexts on both sides of the
+    # selection), slots and blocks are reused.  ``pallas``: the kernels
+    # interpreted (mla_sparse_decode, mla_prefill, index_scores,
+    # moe_expert_ffn)
+    monkeypatch.setenv(PAGED_KERNEL_ENV, backend)
+    prompts = prompts_of(
+        (30, 7, 25, 12, 41, 18) if backend == "jnp" else (30, 7, 41)
+    )
+    sch = make_scheduler(params)
+    res = serve(sch, prompts)
+    assert sorted(res) == list(range(len(prompts)))
+    for i, p in enumerate(prompts):
+        r = res[i]
+        assert r.new_tokens == 9 + i and r.logprobs.size == r.new_tokens
+        np.testing.assert_allclose(
+            r.logprobs, reference_logprobs(params, r, p.size), atol=5e-5
+        )
+        # every computed position has its experts, ids among all 8
+        rows = r.per_token["experts"]
+        assert rows.shape == (r.tokens.size, 2, 2)
+        assert (rows[:-1] >= 0).all() and (rows[:-1] < 8).all()
+        assert (rows[-1] == -1).all()
+        # and the keys its indexer picked in every layer, a bit a
+        # position: min(t + 1, index_topk) of the keys s <= t
+        picked = r.per_token["selection"]
+        assert picked.shape == (r.tokens.size, 3, CFG.selection_words)
+        assert picked.dtype == np.int32 and (picked[-1] == -1).all()
+        bits = unpacked(picked[:-1])  # [positions, layers, 64]
+        at = np.arange(r.tokens.size - 1)
+        assert (
+            bits.sum(-1) == np.minimum(at + 1, TOPK)[:, None]
+        ).all()
+        assert not (bits & (np.arange(64) > at[:, None, None])).any()
+        # forced onto both choices the reference reads the same
+        # logprobs, and in float32 neither choice has any slack
+        forced, routed, chosen = R.forced_readings(
+            params, r.tokens[None], HF,
+            {n: a[None] for n, a in r.per_token.items()},
+        )
+        np.testing.assert_allclose(
+            np.asarray(forced)[0, p.size - 1:], r.logprobs, atol=5e-5
+        )
+        assert float(np.asarray(routed)[0].max()) == 0.0
+        assert float(np.asarray(chosen)[0].max()) < 1e-5
+    assert sch.compile_counts() == {"decode": 1, "prefill": 1, "sample": 1}
+    st = sch.stats()
+    assert st["prefix_hits"] == 0
+    assert st["prefix_hits_skipped"] == len(prompts)
+    assert st["sel_rows"] > 0
+
+
+def unpacked(words):
+    """int32 ``[..., W]`` -> bool ``[..., 32 W]``, bit ``b`` of word
+    ``j`` position ``32 j + b``."""
+    bits = (words[..., None] >> np.arange(32)) & 1
+    return bits.reshape(words.shape[:-1] + (-1,)).astype(bool)
+
+
+def packed(bits):
+    words = bits.reshape(bits.shape[:-1] + (-1, 32)).astype(np.uint32)
+    return (words << np.arange(32, dtype=np.uint32)).sum(
+        -1, dtype=np.uint32
+    ).view(np.int32)
+
+
+def test_a_selection_is_packed_a_bit_a_position():
+    rng = np.random.default_rng(3)
+    taken = rng.random((5, 50)) < 0.4
+    words = np.asarray(M.pack_selection(jnp.asarray(taken), 2))
+    assert words.shape == (5, 2) and words.dtype == np.int32
+    assert (unpacked(words)[:, :50] == taken).all()
+    assert not unpacked(words)[:, 50:].any()
+    # the reference reads it the same, and counts every bit
+    theirs, count = R._unpack(jnp.asarray(words), 40)
+    assert (np.asarray(theirs) == taken[:, :40]).all()
+    assert (np.asarray(count) == taken.sum(-1)).all()
+    # narrower than the positions it is asked for: the rest unpicked
+    assert np.asarray(M.pack_selection(jnp.asarray(taken), 1)).shape == (5, 1)
+    theirs, _ = R._unpack(jnp.asarray(words[:, :1]), 40)
+    assert (np.asarray(theirs)[:, :32] == taken[:, :32]).all()
+    assert not np.asarray(theirs)[:, 32:].any()
+
+
+@pytest.fixture(scope="module")
+def one_served(params):
+    """One request served in float32 (prompt 30, 10 new), with what it
+    chose, and the reference's readings forced onto it."""
+    with jax.default_matmul_precision("highest"):
+        sch = make_scheduler(params)
+        r = serve(sch, prompts_of((30,), seed=8), max_new=10)[0]
+    read = jax.jit(lambda served: R.forced_readings(
+        params, r.tokens[None], HF, served
+    ))
+
+    def readings(selection):
+        with jax.default_matmul_precision("highest"):
+            return [np.asarray(a)[0] for a in read({
+                "experts": r.per_token["experts"][None],
+                "selection": selection[None],
+            })]
+
+    return r, readings
+
+
+@pytest.mark.parametrize("fault", [
+    "the newest keys", "a key dropped", "a key it cannot see",
+    "never computed",
+])
+def test_the_reference_follows_and_judges_a_served_selection(
+    one_served, fault
+):
+    """Forced onto the served side's picks the reference attends over
+    THEM, and says how far under its own scores a pick lies below a key
+    left out; a row that is no selection of the query's reads inf and
+    the reference's own is taken in its place."""
+    r, readings = one_served
+    sound_logp, _, sound_slack = readings(r.per_token["selection"])
+    assert sound_slack[:-1].max() < 1e-5
+    t, layer = 37, 1  # a decoded position past index_topk
+    bits = unpacked(r.per_token["selection"].copy())
+    assert bits[t, layer, :t + 1].sum() == TOPK < t
+    taken = np.flatnonzero(bits[t, layer])
+    if fault == "the newest keys":  # as a bypassed indexer picks
+        assert not bits[t, layer, t + 1 - TOPK:t + 1].all()
+        bits[t, layer] = False
+        bits[t, layer, t + 1 - TOPK:t + 1] = True
+    elif fault == "a key dropped":
+        bits[t, layer, taken[0]] = False
+    elif fault == "a key it cannot see":
+        bits[t, layer, taken[0]], bits[t, layer, t + 1] = False, True
+    else:
+        bits[t] = True  # -1, as a position never computed holds
+    logp, _, slack = readings(packed(bits))
+    others = np.delete(np.arange(slack.size - 1), t)
+    assert (slack[others] < 1e-5).all()
+    if fault == "the newest keys":
+        # a whole selection, not the indexer's: seen in the slack,
+        # finite, and the attention of this and every later position
+        # has moved
+        assert 1e-3 < slack[t] < np.inf
+        assert np.abs(logp - sound_logp)[t:].max() > 1e-4
+        np.testing.assert_allclose(logp[:t], sound_logp[:t], atol=1e-6)
+    else:
+        assert np.isinf(slack[t])
+        np.testing.assert_allclose(logp, sound_logp, atol=5e-5)
+
+
+def test_one_slack_a_position_holds_both_choices(one_served, params):
+    """``reference_check.py`` takes ONE slack a position: the larger of
+    the router's and ``assumed.selection_slack_weight`` times the
+    selection's."""
+    r, readings = one_served
+    bits = unpacked(r.per_token["selection"].copy())
+    bits[37, 1] = False
+    bits[37, 1, 38 - TOPK:38] = True
+    _, routed, chosen = readings(packed(bits))
+    for weight in (1.0, 0.25):
+        cfg = dict(HF, assumed=dict(
+            HF["assumed"], selection_slack_weight=weight
+        ))
+        with jax.default_matmul_precision("highest"):
+            _, slack = R.token_logprobs_forced(params, r.tokens[None], cfg, {
+                "experts": r.per_token["experts"][None],
+                "selection": packed(bits)[None],
+            })
+        np.testing.assert_allclose(
+            np.asarray(slack)[0], np.maximum(routed, weight * chosen),
+            rtol=1e-6,
+        )
+        assert np.asarray(slack)[0, 37] > 0
+
+
+def test_blocks_freed_and_reused_serve_the_same(params):
+    # one lane and a pool that holds one request at a time: the second
+    # and third requests' blocks are the first's, freed, in another
+    # order of use (a selected row must be read through the table)
+    prompts = prompts_of((33, 21, 38), seed=6)
+    sch = make_scheduler(params, max_slots=1, num_blocks=16)
+    res = serve(sch, prompts, max_new=12)
+    for i, p in enumerate(prompts):
+        np.testing.assert_allclose(
+            res[i].logprobs, reference_logprobs(params, res[i], p.size),
+            atol=5e-5,
+        )
+
+
+# --------------------------------------------- (c) the pieces, one by one
+
+
+def test_the_absorbed_form_is_the_decompressed_form():
+    """Decode reads a picked row as key and value of every head
+    (``q_nope W_uk`` against the latent, ``sum p c`` then ``W_uv``); the
+    reference decompresses ``k_nope`` and ``v`` a head.  Float32, the
+    rotated keys two tokens a row as the pool holds them."""
+    rng = np.random.default_rng(0)
+    b, h, k, rank, dn, dr, dv = 3, 4, 10, 32, 16, 8, 16
+    f32 = np.float32
+    c_pool = rng.standard_normal((40, rank)).astype(f32)
+    pe_tok = rng.standard_normal((40, dr)).astype(f32)
+    w_uk = rng.standard_normal((h, dn, rank)).astype(f32) * rank ** -0.5
+    w_uv = rng.standard_normal((h, rank, dv)).astype(f32) * rank ** -0.5
+    q_nope = rng.standard_normal((b, h, dn)).astype(f32)
+    q_pe = rng.standard_normal((b, h, dr)).astype(f32)
+    rows = np.stack([rng.permutation(40)[:k] for _ in range(b)])
+    counts = np.array([k, 4, 1])
+    scale = 0.3
+    latent = pa.latent_rows_decode_attention(
+        jnp.einsum("bhd,hdc->bhc", q_nope, w_uk), jnp.asarray(q_pe),
+        jnp.asarray(c_pool), jnp.asarray(pe_tok.reshape(20, 2 * dr)),
+        jnp.asarray(rows, jnp.int32), jnp.asarray(counts, jnp.int32),
+        scale, "jnp",
+    )
+    got = np.asarray(jnp.einsum("bhc,hcd->bhd", latent, w_uv))
+    for i in range(b):
+        sel = rows[i, :counts[i]]
+        k_nope = np.einsum("sc,hdc->shd", c_pool[sel], w_uk)
+        v = np.einsum("sc,hcd->shd", c_pool[sel], w_uv)
+        logits = (
+            np.einsum("hd,shd->hs", q_nope[i], k_nope)
+            + np.einsum("hd,sd->hs", q_pe[i], pe_tok[sel])
+        ) * scale
+        p = np.exp(logits - logits.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        np.testing.assert_allclose(
+            got[i], np.einsum("hs,shd->hd", p, v), atol=2e-5
+        )
+
+
+@pytest.mark.parametrize("counts", [(10, 4, 1), (16, 16, 9)])
+def test_the_decode_kernel_is_the_plain_absorbed_attention(counts):
+    rng = np.random.default_rng(1)
+    b, h, k, rank, lanes = 3, 4, 16, 32, 16
+    q_c = jnp.asarray(rng.standard_normal((b, h, rank)), jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((b, h, 8)), jnp.float32)
+    c_pool = jnp.asarray(rng.standard_normal((64, rank)), jnp.float32)
+    pe_pool = jnp.asarray(rng.standard_normal((32, lanes)), jnp.float32)
+    rows = jnp.asarray(
+        np.stack([rng.permutation(64)[:k] for _ in range(b)]), jnp.int32
+    )
+    args = (q_c, q_pe, c_pool, pe_pool, rows, jnp.asarray(counts), 0.25)
+    np.testing.assert_allclose(
+        np.asarray(pa.latent_rows_decode_attention(*args, "pallas")),
+        np.asarray(pa.latent_rows_decode_attention(*args, "jnp")),
+        atol=2e-5,
+    )
+
+
+@pytest.mark.parametrize("start,kv_len", [(0, 16), (16, 32), (40, 56)])
+def test_the_prefill_kernel_is_the_plain_multi_head_attention(start, kv_len):
+    """Keys of one width, values of another, a selection that is data."""
+    rng = np.random.default_rng(2)
+    c, h, t, dk, dv = 16, 4, 64, 24, 16
+    q = jnp.asarray(rng.standard_normal((c, h, dk)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((h, t, dk)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((h, t, dv)), jnp.float32)
+    causal = np.arange(t)[None] <= (start + np.arange(c))[:, None]
+    taken = jnp.asarray(causal & (rng.random((c, t)) < 0.6) | (
+        np.arange(t)[None] == (start + np.arange(c))[:, None]
+    ))
+    args = (q, k, v, taken, jnp.int32(start), jnp.int32(kv_len), 0.2)
+    got = pa.latent_prefill_attention(*args, "pallas")
+    assert got.shape == (c, h, dv)
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(pa.latent_prefill_attention(*args, "jnp")), atol=2e-5,
+    )
+
+
+def _loop_router(score, k, n_group, topk_group):
+    """The group limit as a loop over groups: stable sorts, so equal
+    scores go to the lowest id, for groups as for experts."""
+    out = []
+    for row in np.asarray(score, np.float64):
+        groups = row.reshape(n_group, -1)
+        gscore = [np.sort(g)[::-1][:2].sum() for g in groups]
+        best = np.argsort(-np.asarray(gscore), kind="stable")[:topk_group]
+        size = groups.shape[1]
+        masked = np.full_like(row, -np.inf)
+        for g in best:
+            masked[g * size:(g + 1) * size] = row[g * size:(g + 1) * size]
+        out.append(np.argsort(-masked, kind="stable")[:k])
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("n_group,topk_group,k", [
+    (8, 4, 8), (2, 1, 2), (4, 4, 3), (1, 1, 4),
+])
+def test_the_group_limited_router_is_the_loop_over_groups(
+    n_group, topk_group, k
+):
+    rng = np.random.default_rng(n_group)
+    # a coarse grid of scores: ties between experts AND between groups
+    score = rng.integers(0, 6, size=(64, 32)).astype(np.float32) / 4
+    got = np.asarray(M.group_limited_topk(
+        jnp.asarray(score), k, n_group, topk_group
+    ))
+    want = _loop_router(score, k, n_group, topk_group)
+    np.testing.assert_array_equal(np.sort(got, -1), np.sort(want, -1))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_the_yarn_tables_are_the_closed_form():
+    """At the published widths: 32 frequencies, the first dims as
+    ``theta ** (-2 j / 64)``, the last divided by 40, a linear ramp
+    between the correction dims of ``beta_fast`` 32 and ``beta_slow`` 1
+    over 4096 positions (dims 10 and 23); the program's table is the
+    reference's."""
+    cfg = M.DeepSeekV32Config()
+    got = M.yarn_inv_freq(cfg)
+    j = np.arange(32)
+    plain = 1e4 ** (-2.0 * j / 64)
+    assert got.shape == (32,)
+    np.testing.assert_allclose(got[:11], plain[:11], rtol=1e-12)
+    np.testing.assert_allclose(got[23:], plain[23:] / 40, rtol=1e-12)
+    ramp = (j - 10) / 13.0
+    mid = slice(11, 23)
+    np.testing.assert_allclose(
+        got[mid], plain[mid] / 40 * ramp[mid] + plain[mid] * (1 - ramp[mid]),
+        rtol=1e-12,
+    )
+    np.testing.assert_allclose(
+        got, np.asarray(R.yarn_inv_freq(PUBLISHED)), rtol=1e-6
+    )
+    assert abs(cfg.softmax_scale - 0.13523) < 1e-5
+    assert abs(R.softmax_scale(PUBLISHED) - cfg.softmax_scale) < 1e-9
+    cos, sin = M._rope_tables(cfg, jnp.asarray([0, 5]))
+    np.testing.assert_allclose(np.asarray(cos)[1], np.cos(5 * got), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(sin)[0], 0.0, atol=1e-7)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(params):
+    """The four shares' routed terms plus the shared expert ONCE are
+    the layer with every expert here: what a share leaves out is what
+    the other shares add."""
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((24, 64)), jnp.float32)
+    lp = dict(params["layers"][1])
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    full = {  # the router's 8 experts
+        n: jax.random.normal(k, (8,) + lp[n].shape[1:], jnp.float32) * 0.2
+        for n, k in zip(("w_gate", "w_up", "w_down"), keys)
+    }
+    whole = M.DeepSeekV32Config(**dict(KW, held_experts=8, first_expert=0))
+    want, ids = M._mlp(x, {**lp, **full}, whole)
+    routed = jnp.zeros_like(x)
+    shared = None
+    for share in range(4):
+        cut = M.DeepSeekV32Config(**dict(KW, first_expert=2 * share))
+        held = {n: w[2 * share:2 * share + 2] for n, w in full.items()}
+        y, ids_s = M._mlp(x, {**lp, **held}, cut)
+        np.testing.assert_array_equal(np.asarray(ids_s), np.asarray(ids))
+        nothing = {n: jnp.zeros_like(w) for n, w in held.items()}
+        shared, _ = M._mlp(x, {**lp, **nothing}, cut)  # x + Shared(h')
+        routed = routed + (y - shared)
+    np.testing.assert_allclose(
+        np.asarray(shared + routed), np.asarray(want), atol=2e-5
+    )
+    # and the reference leaves out the same terms: share 0's layer
+    h = R._rms_norm(x, lp["mlp_norm"], HF["rms_norm_eps"])
+    ref, _ = R._experts(h, lp, HF, None)
+    mine, _ = M._mlp(x, lp, CFG)
+    np.testing.assert_allclose(
+        np.asarray(mine - x), np.asarray(ref), atol=2e-5
+    )
+
+
+def test_a_wide_expert_is_taken_in_blocks_of_its_width(monkeypatch):
+    """DeepSeek-V3.2's 7168 x 2048 expert does not fit fast memory
+    twice: the kernel takes it in two blocks of its width; every width
+    the benchmark had before is taken whole."""
+    assert grouped_gemm.expert_width_blocks(7168, 2048, 2) == 2
+    assert grouped_gemm.expert_width_blocks(3072, 3072, 2) == 1
+    assert grouped_gemm.expert_width_blocks(2048, 768, 2) == 1
+    rng = np.random.default_rng(7)
+    rows = jnp.asarray(rng.standard_normal((32, 64)), jnp.float32)
+    w = [
+        jnp.asarray(rng.standard_normal(s), jnp.float32) * 0.1
+        for s in ((3, 64, 256), (3, 64, 256), (3, 256, 64))
+    ]
+    groups, used = jnp.asarray([0, 2, 2, 1]), jnp.asarray([3])
+    whole = grouped_gemm.expert_ffn_tiles(rows, *w, groups, used, 8)
+    # the same kernel with room for half of this expert
+    monkeypatch.setattr(
+        grouped_gemm, "_EXPERT_WHOLE_BYTES", 2 * 3 * 64 * 128 * 4
+    )
+    assert grouped_gemm.expert_width_blocks(64, 256, 4) == 2
+    split = grouped_gemm.expert_ffn_tiles(rows, *w, groups, used, 8)
+    np.testing.assert_allclose(
+        np.asarray(split), np.asarray(whole), atol=2e-5
+    )
+    assert (np.asarray(split)[24:] == 0).all()  # the tile past the used
+
+
+# --------------------------- (d) a cache without keys and without values
+
+
+def test_the_pool_holds_the_paged_leaves_alone():
+    cache = paged_cache_config(CFG, 10, 4, 3)
+    assert not cache.pages_kv
+    assert cache.paged_names == ("c", "kpe", "ik")
+    assert (cache.n_kv_heads, cache.head_dim) == (0, 0)
+    pool = init_block_pool(cache)
+    assert sorted(pool) == ["c", "ik", "kpe"]  # no k, no v, no stand-in
+    assert pool["c"].shape == (3, 10, 4, 32)  # a latent a row
+    assert pool["kpe"].shape == (3, 10, 1, 32)  # a block's keys one row
+    assert pool["ik"].shape == (3, 10, 4 * 16)  # flat, as Keye-VL's
+    # bytes a block over the three leaves and three layers, float32
+    assert block_nbytes(pool, cache.paged_names) == 3 * 4 * (32 + 8 + 16) * 4
+
+
+def test_a_token_of_a_layer_keeps_1408_bytes_at_the_published_widths():
+    kw = F.model_kwargs(PUBLISHED, 8192)
+    cfg = deepseek_v32_factory(**kw, dtype="bfloat16")["cfg"]
+    cache = paged_cache_config(cfg, 65, 16, 32, 512)
+    pool = jax.eval_shape(lambda: init_block_pool(cache))
+    assert sorted(pool) == ["c", "ik", "kpe"]
+    assert pool["c"].shape == (7, 65, 16, 512)
+    assert pool["kpe"].shape == (7, 65, 8, 128)  # two tokens a row
+    assert pool["ik"].shape == (7, 65, 16 * 128)
+    per_token_layer = sum(
+        a.size * a.dtype.itemsize for a in pool.values()
+    ) // (7 * 65 * 16)
+    assert per_token_layer == 1408
+    assert per_token_layer == F.cache_bytes_per_token_layer(PUBLISHED)
+    assert per_token_layer == (
+        PUBLISHED["deployment"]["cache_bytes_per_token_layer"]
+    )
+    # against 128 heads of 192 + 128 in bfloat16
+    assert 128 * (192 + 128) * 2 == 81920
+
+
+def test_a_block_ship_carries_every_leaf_bit_for_bit():
+    cache = paged_cache_config(CFG, 10, 4, 3)
+    rng = np.random.default_rng(0)
+    pool = {
+        n: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+        for n, a in init_block_pool(cache).items()
+    }
+    regions = extract_block_regions(pool, [3, 7], cache.paged_names)
+    assert [r.shape for r in regions] == [
+        (3, 2, 4, 32), (3, 2, 1, 32), (3, 2, 4 * 16)
+    ]
+    other = insert_block_regions(
+        init_block_pool(cache), [5, 1], *regions, leaves=cache.paged_names
+    )
+    for n in pool:
+        np.testing.assert_array_equal(
+            np.asarray(other[n][:, [5, 1]]), np.asarray(pool[n][:, [3, 7]])
+        )
+
+
+@pytest.mark.parametrize("start, c", [(0, 8), (8, 8), (6, 5), (20, 8)])
+def test_a_run_is_written_the_same_by_rows_and_by_blocks(start, c):
+    """A prefill chunk's rows (``write_leaf_run``: whole blocks) and a
+    decode step's (``write_leaf_rows``: a row, or a token's lanes of a
+    row) land in the same cells of a leaf kept in rows."""
+    cache = paged_cache_config(CFG, 12, 4, 3)
+    pool = init_block_pool(cache)
+    table = jnp.asarray([3, 9, 1, 7, 5, 2, 8, 4], jnp.int32)
+    rng = np.random.default_rng(start)
+    for name, width in (("c", 32), ("kpe", 8)):
+        flat = pool[name].reshape((-1,) + pool[name].shape[2:])
+        kv = pa.LayerPool(None, None, jnp.int32(12), jnp.int32(1),
+                          {name: flat})
+        rows = jnp.asarray(rng.standard_normal((c, width)), jnp.float32)
+        positions = start + np.arange(c)
+        by_rows = kv.write_leaf_rows(
+            name, rows, table[positions // 4], jnp.asarray(positions % 4)
+        ).paged[name]
+        by_blocks = kv.write_leaf_run(
+            name, rows, table, jnp.int32(start)
+        ).paged[name]
+        np.testing.assert_array_equal(
+            np.asarray(by_rows), np.asarray(by_blocks)
+        )
+        assert float(jnp.abs(by_rows).sum()) > 0
+        # layer 1's blocks alone were written
+        assert float(jnp.abs(by_rows[:12]).sum()) == 0
+
+
+def _build(monkeypatch, env=None, **kw):
+    for k, v in (env or {}).items():
+        monkeypatch.setenv(k, v)
+    return ContinuousBatchingScheduler(
+        CFG, SchedulerConfig(**SCHED),
+        paged_decode_fn=PARTS["paged_decode_fn"],
+        paged_prefill_fn=PARTS["paged_prefill_fn"], **kw,
+    )
+
+
+@pytest.mark.parametrize("case,env,kw,why", [
+    ("decode_k", {"DLROVER_TPU_DECODE_STEPS": "3"}, {},
+     "verify program reads K and V only"),
+    ("draft", {}, {"draft_cfg": llama.LlamaConfig.tiny()}, "draft model"),
+    ("prefill_role", {}, {"role": "prefill"},
+     "the prefill role: a shipped prefill carries K and V regions"),
+])
+def test_what_it_cannot_do_yet_is_refused_by_name(
+    monkeypatch, case, env, kw, why
+):
+    with pytest.raises(ValueError, match=why) as err:
+        _build(monkeypatch, env, **kw)
+    assert "pages more than K and V (c, kpe, ik)" in str(err.value)
+
+
+@pytest.mark.parametrize("declares,why", [
+    (dict(), "declares no paged_leaves"),
+    (dict(lane_state=lambda: {"s": ((2,), jnp.float32)},
+          paged_leaves=lambda: {"c": ((8,), jnp.float32)}),
+     "beside lane_state"),
+    (dict(layer_windows=lambda: (4, None),
+          paged_leaves=lambda: {"c": ((8,), jnp.float32)}),
+     "beside layer_windows"),
+    (dict(paged_leaves=lambda: {"c": ((8,), jnp.float32)},
+          paged_leaf_rows=lambda: {"x": 128}), "is no paged leaf"),
+    (dict(paged_leaves=lambda: {"c": ((6,), jnp.float32)},
+          paged_leaf_rows=lambda: {"c": 8}), "hold no whole token"),
+])
+def test_a_declaration_that_cannot_be_laid_out_is_refused(declares, why):
+    model = type("Model", (), dict(
+        n_layers=2, dtype=jnp.float32, pages_kv=False,
+        **{k: staticmethod(v) for k, v in declares.items()},
+    ))()
+    with pytest.raises(ValueError, match=why):
+        paged_cache_config(model, 4, 4, 1, 8)
+
+
+def test_the_plain_construction_is_accepted(monkeypatch):
+    sch = _build(monkeypatch, capture_logprobs=True)
+    assert sorted(sch._pool) == ["c", "ik", "kpe"]
+    assert sch.per_token and not sch.prefix_cache and not sch.lane_state
+
+
+# ------------------------------------------------------ (e) the records
+
+
+def test_serve_step_carries_the_rows_the_experts_and_the_cache(
+    params, tmp_path
+):
+    path = str(tmp_path / "events.jsonl")
+    sch = make_scheduler(params, events=EventLogger(path=path))
+    serve(sch, prompts_of((22, 18, 30)), max_new=6)
+    from dlrover_tpu.observability.events import read_events
+
+    events = read_events(path)
+    steps = [e["labels"] for e in events if e["name"] == "serve_step"]
+    decoded = [s for s in steps if s.get("lanes_decode", 0) > 0]
+    assert decoded
+    block_bytes = 3 * 4 * (32 + 8 + 16) * 4
+    for s in steps:
+        assert s["cache_bytes"] % block_bytes == 0
+        # the paged leaves are not the index keys alone
+        assert "index_bytes" not in s
+    for s in decoded:
+        assert 0 < s["sel_rows"] <= TOPK * s["lanes_decode"]
+        assert s["sel_rows"] <= s["cached_rows"]
+    assert any(s["sel_rows"] < s["cached_rows"] for s in decoded)
+    assert max(s["cache_bytes"] for s in steps) > 0
+    routed = [s for s in steps if "experts_hit" in s]
+    assert routed
+    for s in routed:
+        assert s["experts"] == 2 and 0 <= s["experts_hit"] <= 2
+        assert 0 <= s["expert_rows_local"] <= s["expert_rows"]
+    chunks = [e["labels"] for e in events if e["name"] == "prefill"]
+    assert chunks and all(
+        0 < c["rows"] <= 12 and c["kv_len"] >= c["rows"] for c in chunks
+    )
+
+
+def test_the_kernels_counts_are_the_issues():
+    """One lane and layer at the published widths: 2048 rows of 576
+    (2.36 MB) and the lane's queries and outputs; 128 heads x 2048 rows
+    x (576 + 512) x 2 = 570 MFLOP — 242 operations a byte of rows, on
+    the v5e's ridge (197e12 / 819e9 = 240.5)."""
+    one = dict(PUBLISHED, num_hidden_layers=1)
+    flops = F.mla_decode_flops(one, 2048, 1)
+    moved = F.mla_decode_bytes(one, 2048, 1)
+    assert flops == 128 * 2048 * (576 + 512) * 2 == 570425344
+    assert moved == 2048 * 576 * 2 + 128 * (576 + 512) * 2
+    assert 241 < flops / (2048 * 576 * 2) < 243
+    # a 512-row chunk behind 2048 cached rows: every query reads 2048
+    assert F.prefill_attention_flops(one, 512, 2560) == (
+        128 * 2 * (192 + 128) * 512 * 2048
+    )
+    # the first chunk is causal: sum of t + 1
+    assert F.prefill_attention_flops(one, 512, 512) == (
+        128 * 2 * (192 + 128) * (512 * 513 // 2)
+    )
+    assert F.layers_of_kind(PUBLISHED) == {"dense": 1, "expert": 6}
+    assert F.expert_bytes(PUBLISHED) == 3 * 7168 * 2048 * 2

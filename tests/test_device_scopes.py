@@ -120,9 +120,10 @@ def test_the_reader_repeats_the_programs_closed_set():
     # ``window`` / ``full`` (PR 44) likewise: the kind of a layer's
     # attention, inside ``attn``; ``linear`` (PR 49) too, and
     # ``gdn_scan`` inside ``linear``: a gated delta-rule layer and its
-    # prefill's chunk scan
+    # prefill's chunk scan; ``latent`` (PR 53) likewise: latent
+    # attention's own work beside its indexer
     assert set(readers_scopes.PARTS) | {
-        "indexer", "window", "full", "linear", "gdn_scan"
+        "indexer", "window", "full", "linear", "gdn_scan", "latent"
     } == DEVICE_SCOPE_PARTS
     assert not DEVICE_SCOPE_ROLES & DEVICE_SCOPE_PARTS
 

@@ -48,8 +48,8 @@ def data_root(tmp_path_factory):
             m["workloads"].append(CELL)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         real = json.load(f)
-    for m in real["per_layer"]:  # the two that read labels, not a trace
-        if m["name"].startswith("moe."):
+    for m in real["per_layer"]:  # those that read labels, not a trace
+        if m["name"].startswith(("moe.", "kv.selected")):
             bench["per_layer"].append(dict(m, workloads=[CELL]))
     root = tmp_path_factory.mktemp("bm")
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
@@ -97,4 +97,7 @@ def test_a_traced_run_reads_the_expert_load_from_the_records(data_root):
     # 8 experts, 2 a token, at most 4 lanes: some experts idle a step
     assert 0 < got["moe.experts_hit_pct"] <= 100
     assert got["moe.rows_max_over_mean"] >= 1
+    # an indexer's ``sel_rows`` over its ``cached_rows``: the longer
+    # lanes read a part of what they have cached
+    assert 10 < got["kv.selected_share_pct"] <= 100
     assert "rollout_tokens_per_s" not in got

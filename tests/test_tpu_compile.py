@@ -173,14 +173,16 @@ def _sparse_prefill_case(keys=8192):
     )
 
 
-def _index_scores_case():
+def _index_scores_case(rows=2048, heads=16, dim=64):
     """A 2048-row chunk's index scores at Keye-VL-2.0's indexer (16
-    heads of 64) against 8192 cached index keys."""
+    heads of 64) against 8192 cached index keys; or a 512-row chunk's
+    at DeepSeek-V3.2's (64 heads of 128: the heads' queries and weights
+    pass a kernel's default fast memory)."""
     from dlrover_tpu.ops.paged_kernels import index_scores_kernel
 
     return index_scores_kernel, (
-        ((2048, 16, 64), BF16), ((2048, 16), jnp.float32),
-        ((8192, 64), BF16), ((), jnp.int32),
+        ((rows, heads, dim), BF16), ((rows, heads), jnp.float32),
+        ((8192, dim), BF16), ((), jnp.int32),
     )
 
 
@@ -328,7 +330,36 @@ def _expert_share_case(rows):
     )
 
 
+def _mla_decode_case():
+    """The absorbed decode over the picked rows at DeepSeek-V3.2's
+    widths and its cell's lanes: 32 lanes, 128 heads, 2048 rows of a
+    512-wide latent (key and value) and a rotated shared key in a
+    128-lane row."""
+    from dlrover_tpu.ops.paged_kernels import mla_sparse_decode_kernel
+
+    return partial(mla_sparse_decode_kernel, scale=0.13523), (
+        ((32, 128, 512), BF16), ((32, 128, 128), BF16),
+        ((32, 2048, 512), BF16), ((32, 2048, 128), BF16),
+        ((32,), jnp.int32),
+    )
+
+
+def _mla_prefill_case(keys=4096):
+    """A 512-row chunk's attention in multi-head form at DeepSeek-V3.2's
+    widths: 128 heads, keys of 192 and values of 128 decompressed a
+    head, under a selection over ``keys`` cached positions."""
+    from dlrover_tpu.ops.paged_kernels import mla_prefill_kernel
+
+    return partial(mla_prefill_kernel, scale=0.13523), (
+        ((512, 128, 192), BF16), ((128, keys, 192), BF16),
+        ((128, keys, 128), BF16), ((512, keys), jnp.bool_),
+        ((), jnp.int32), ((), jnp.int32),
+    )
+
+
 CASES = {
+    "mla_sparse_decode": _mla_decode_case,
+    "mla_prefill": _mla_prefill_case,
     "paged_window_decode": lambda: _window_decode_case(True),
     "paged_full_decode_2048": lambda: _window_decode_case(False),
     "paged_prefill_window": lambda: _chunk_prefill_case(True),
@@ -337,6 +368,7 @@ CASES = {
     "moe_expert_share_chunk": lambda: _expert_share_case(2048),
     "sparse_prefill": _sparse_prefill_case,
     "index_scores": _index_scores_case,
+    "index_scores_64x128": lambda: _index_scores_case(512, 64, 128),
     "moe_expert_ffn_decode": lambda: _expert_ffn_case(16),
     "moe_expert_ffn_chunk": lambda: _expert_ffn_case(2048),
     "ssm_decode_update": _ssm_case,
@@ -371,6 +403,8 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     ("paged_full_decode_kv30", "paged_full_decode"),
     ("paged_prefill_full_kv30", "paged_prefill_full"),
     ("sparse_prefill", "sparse_prefill"),
+    ("mla_sparse_decode", "mla_sparse_decode"),
+    ("mla_prefill", "mla_prefill"),
     ("index_scores", "index_scores"),
     ("paged_window_decode", "paged_window_decode"),
     ("paged_full_decode_2048", "paged_full_decode"),
@@ -1031,6 +1065,97 @@ def test_two_kinded_block_carries_both_pools_in_place(program, one_chip):
     for kind in ("window", "full"):
         assert kernel(f"paged_{kind}_decode") == (program == "decode")
         assert kernel(f"paged_prefill_{kind}") == (program != "decode")
+    assert "ragged-dot" not in text
+
+
+@pytest.mark.parametrize(
+    "program", ["decode", "prefill_nohead", "prefill_last"]
+)
+def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
+    """DeepSeek-V3.2's step programs at ``deepseek-v32-rollout-c32-
+    reason8k``'s geometry (the published widths at 1 dense + 6 expert
+    layers, 8 of 256 experts held, an eighth of the vocabulary; 32
+    lanes, tables of 512 entries, chunk 512): the pool is the latents,
+    the rotated shared keys and the index keys ALONE — no ``k``, no
+    ``v`` — 1408 bytes a token and layer, every leaf aliased to the
+    outputs and never moved (the rows' views ``[L * blocks * 16, 512]``
+    and ``[L * blocks * 8, 128]`` are merges of leading axes), no
+    layer's ``[8, 7168, 2048]`` expert matrices copied, and each kernel
+    under the name a trace tells it by."""
+    from dlrover_tpu.models import deepseek_v32
+    from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV
+    from dlrover_tpu.rl.kv_cache import init_block_pool, paged_cache_config
+
+    cfg = deepseek_v32.DeepSeekV32Config(
+        num_hidden_layers=7, first_k_dense_replace=1, held_experts=8,
+        vocab_size=16160, max_seq_len=8192,
+    )
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def seeded():  # as the benchmark seeds it: matrices in bfloat16
+        tree = deepseek_v32.init_params(jax.random.PRNGKey(0), cfg)
+        return deepseek_v32.serving_params(jax.tree_util.tree_map(
+            lambda a: a.astype(BF16) if a.ndim >= 2 and a.shape[-1] != 256
+            else a, tree,
+        ), cfg)
+
+    params = jax.tree_util.tree_map(spec, jax.eval_shape(seeded))
+    cache = paged_cache_config(cfg, 18240, 16, 32, 512)
+    assert not cache.pages_kv and cache.paged_names == ("c", "kpe", "ik")
+    pool = jax.tree_util.tree_map(
+        spec, jax.eval_shape(lambda: init_block_pool(cache))
+    )
+    assert set(pool) == {"c", "kpe", "ik"}
+    assert pool["c"].shape == (7, 18240, 16, 512)
+    assert pool["kpe"].shape == (7, 18240, 8, 128)
+    assert pool["ik"].shape == (7, 18240, 16 * 128)
+    pool_bytes = sum(math.prod(a.shape) * 2 for a in pool.values())
+    assert pool_bytes == 7 * 18240 * 16 * 1408
+    if program == "decode":
+        fn, rest = _scheduler_decode(
+            partial(deepseek_v32.paged_decode_step, cfg=cfg), 32, 512, True
+        )
+    else:
+        fn, rest = _scheduler_prefill(
+            partial(deepseek_v32.paged_prefill_chunk, cfg=cfg), 32, False,
+            program == "prefill_last", 512, 512, True,
+        )
+    tokens, *after = [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in rest
+    ]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(PAGED_KERNEL_ENV, "pallas")
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+            params, tokens, pool, *after
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # a chunk holds the keys and values it decompressed (8192 positions
+    # x 128 heads x (192 + 128): 671 MB) and its rows' projections; a
+    # decode step every lane's index keys and picked rows a layer
+    assert mem.temp_size_in_bytes < (
+        768 if program == "decode" else 2048
+    ) * 2**20
+    pools = {math.prod(a.shape) for a in pool.values()}
+    layer = {math.prod(a.shape[1:]) for a in pool.values()}
+    stack = 8 * 7168 * 2048
+    moved = [
+        line[:160] for elements, op, line in _materialised(text)
+        if elements in pools | layer | {stack}
+        and re.match(r"(ROOT )?%(copy|dynamic-slice|slice|transpose)", line)
+        and not re.match(r"(ROOT )?%copy-(start|done)", line)
+    ]
+    assert not moved, moved
+
+    def kernel(name):  # an instruction of that name, not a path
+        return re.search(rf"%{name}(\.\d+)* = ", text) is not None
+
+    assert kernel("moe_expert_ffn")
+    assert kernel("mla_sparse_decode") == (program == "decode")
+    assert kernel("mla_prefill") == (program != "decode")
+    assert kernel("index_scores") == (program != "decode")
     assert "ragged-dot" not in text
 
 
