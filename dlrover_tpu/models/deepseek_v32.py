@@ -53,8 +53,10 @@ and values, so the pool (``rl/kv_cache.paged_cache_config``) holds the
 ``paged_leaves()`` alone, the cached row in its two parts — ``c [L,
 blocks, block_size, kv_lora_rank]`` and ``kpe [L, blocks, block_size *
 qk_rope_head_dim / 128, 128]``, both in ROWS (``paged_leaf_rows()``)
-because decode gathers them row by row, two tokens' rotated keys a row:
-a minor axis of 576 or of 64 is one the device pads or lays out
+because decode reads them row by row — a block of each is one copy of
+the kernel that streams a lane's blocks, a row one element of the
+gather under a very wide table —, two tokens' rotated keys a row: a
+minor axis of 576 or of 64 is one the device pads or lays out
 blocks-minor, and every program then copies the leaf — and ``ik [L,
 blocks, block_size * index_head_dim]``: 1408 bytes a token and layer in
 bfloat16 at the published widths, against 81 920 for 128 heads of 192 +
@@ -63,8 +65,13 @@ bfloat16 at the published widths, against 81 920 for 128 heads of 192 +
 **Two forms of one attention.**  Decode is ABSORBED: ``q_nope W_uk``
 is a ``kv_lora_rank``-wide query a head, scored with ``q_pe`` against
 the cached row itself; the output is ``sum p c_kv``, then ``W_uv``,
-then ``W_o`` — the picked rows are read once for all heads
-(``ops/paged_kernels.mla_sparse_decode_kernel``).  A prefill chunk
+then ``W_o`` — a row is read once for all heads, by a kernel that
+copies the blocks a lane holds itself and masks the rows not picked
+(``ops/paged_kernels.mla_stream_decode_kernel``; under a table more
+than ``LATENT_STREAM_WIDTH`` times ``index_topk`` wide the picked rows
+are gathered for ``mla_sparse_decode_kernel``:
+``ops/paged_attention.latent_decode_selection`` picks by the shapes).
+A prefill chunk
 DECOMPRESSES the rows it may see (``W_uk`` / ``W_uv``, views of
 ``W_kvb`` made once in :func:`serving_params`) and attends in
 multi-head form under each query's selection
@@ -247,6 +254,21 @@ class DeepSeekV32Config:
                 (self.num_hidden_layers, self.selection_words), "int32"
             ),
         }
+
+    def decode_read_rows(
+        self, cached: int, table_positions: int, block_size: int
+    ) -> int:
+        """The rows decode attention fetches a layer for a lane of
+        ``cached`` positions, by the attention's own test of shapes
+        (``ops/paged_attention.latent_decode_read_rows``): arithmetic
+        on the host for the scheduler's ``read_rows`` label, which
+        learns no model's name."""
+        from dlrover_tpu.ops.paged_attention import latent_decode_read_rows
+
+        return latent_decode_read_rows(
+            cached, table_positions, min(self.index_topk, table_positions),
+            block_size,
+        )
 
     @property
     def selection_words(self) -> int:
@@ -828,20 +850,23 @@ def paged_decode_step(
     its latent row and index key, scores its index query against every
     index key it has cached, takes the exact top ``index_topk``
     positions (all of them below it) and attends over those rows alone
-    in ABSORBED form — the row is key and value of every head.  An
-    inactive lane writes to the null block and reads one masked row.
+    in ABSORBED form — the row is key and value of every head —,
+    reading the blocks it holds itself under the selection's mask
+    (``ops/paged_attention.latent_decode_selection``: the picked rows
+    are gathered only under a table many times ``index_topk`` wide).  An
+    inactive lane writes to the null block and reads nothing.
     Shapes depend on (lanes, pool geometry) only: compiled once.
     Returns (logits [B, vocab], pool, {"experts": [B, expert layers,
     k], "selection": [B, layers, words]})."""
     from dlrover_tpu.ops.paged_attention import (
         decode_index_scores,
-        exact_topk_rows,
         gather_index_keys,
-        latent_rows_decode_attention,
+        latent_decode_attention,
+        latent_decode_selection,
         paged_kernel_backend,
     )
 
-    dt, dr, rank = cfg.dtype, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    dt, dr = cfg.dtype, cfg.qk_rope_head_dim
     n = tokens.shape[0]
     leaves = _Leaves(pool)
     bs, mb = leaves.block_size, block_tables.shape[1]
@@ -861,8 +886,8 @@ def paged_decode_step(
         )
         off = jnp.where(active, positions % bs, 0)
         seq_lens = jnp.where(active, positions + 1, 1)
+        held = jnp.where(active, positions + 1, 0)  # what attention reads
         n_sel = min(cfg.index_topk, mb * bs)
-        counts = jnp.minimum(seq_lens, n_sel)
     chosen, picked = [], []
     for i, lp in enumerate(params["layers"]):
         kv = leaves.layer(i)
@@ -883,17 +908,17 @@ def paged_decode_step(
             keys = gather_index_keys(
                 kv.paged["ik"], tables, cfg.index_head_dim
             )
-            rows, taken = exact_topk_rows(
-                decode_index_scores(qi, w, keys, seq_lens), n_sel, tables,
-                with_mask=True,
+            # a mask over the positions where attention reads a lane's
+            # blocks itself, the rows to gather beside it under a table
+            # much wider than what is picked
+            sel = latent_decode_selection(
+                decode_index_scores(qi, w, keys, seq_lens), n_sel, tables
             )
-            picked.append(pack_selection(taken, cfg.selection_words))
+            picked.append(pack_selection(sel.taken, cfg.selection_words))
         with jax.named_scope("attn"), jax.named_scope("latent"):
-            c_pool, pe_pool = kv.paged["c"], kv.paged["kpe"]
-            latent = latent_rows_decode_attention(
-                q_c, q_pe, c_pool.reshape(-1, rank),
-                pe_pool.reshape(-1, pe_pool.shape[-1]), rows, counts,
-                cfg.softmax_scale, backend,
+            latent = latent_decode_attention(
+                q_c, q_pe, kv.paged["c"], kv.paged["kpe"], tables, held,
+                sel, cfg.softmax_scale, backend,
             )
             attn = _per_head(latent, w_uv, dt)
             x = x + _proj(attn.reshape(n, -1), lp["wo"], dt)

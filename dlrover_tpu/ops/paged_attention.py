@@ -685,18 +685,144 @@ def latent_rows_decode_attention(
         from dlrover_tpu.ops.paged_kernels import mla_sparse_decode_kernel
 
         return mla_sparse_decode_kernel(q_c, q_pe, c, pe, counts, scale=scale)
+    return _absorbed_attention(q_c, q_pe, c, pe, real, scale)
+
+
+def _absorbed_attention(q_c, q_pe, c, pe, keep, scale):
+    """The jnp form of absorbed attention over rows laid out a lane:
+    ``c`` ``[B, T, Dc]`` key and value, ``pe`` ``[B, T, M]`` against
+    ``q_pe`` ``[B, H, M]``, ``keep`` ``[B, T]`` the rows that count (a
+    lane with none returns zeros)."""
     logits = (
         jnp.einsum("bhd,btd->bht", q_c, c, preferred_element_type=jnp.float32)
         + jnp.einsum(
             "bhd,btd->bht", q_pe, pe, preferred_element_type=jnp.float32
         )
     ) * scale
-    probs = jax.nn.softmax(jnp.where(real[:, None], logits, NEG_INF), -1)
-    probs = jnp.where(counts[:, None, None] > 0, probs, 0.0)
+    probs = jax.nn.softmax(jnp.where(keep[:, None], logits, NEG_INF), -1)
+    probs = jnp.where(keep[:, None], probs, 0.0)
     return jnp.einsum(
         "bht,btd->bhd", probs.astype(c.dtype), c,
         preferred_element_type=jnp.float32,
     ).astype(c.dtype)
+
+
+#: Decode attention over a latent cache streams a lane's own blocks
+#: while its table holds at most this many times ``n_sel`` positions,
+#: and gathers the picked rows of a wider one
+#: (:func:`latent_decode_selection`).  Bare on a v5e (PR 54, 32 lanes x
+#: 128 heads, top 2048 of a 512 + 64 wide row, ``scripts/
+#: bench_paged_attention.py --latent-sweep``): the gathered fetch is
+#: 1.59 ms a layer whatever a lane holds (1.07 at 2048 held), the
+#: streamed one 0.39 / 0.74 / 1.09 / 1.43 / 2.13 / 2.83 / 5.59 ms at
+#: 2 / 4 / 6 / 8 / 12 / 16 / 32 k held positions (0.17 us a position):
+#: they cross at ~9.1 k held, 4.4 x 2048, and a table is an upper bound
+#: of what its lanes hold.  The sweep's table was as wide as what a
+#: lane holds, and the test is of the TABLE: a 32 k table whose lanes
+#: hold 4 k is gathered at 1.59 ms where streaming would read 0.75.  No
+#: benchmark cell has such a table (V's is 8192 = 4 x 2048, streamed):
+#: a wide-table cell comes before anyone tunes this number
+#: (``ROADMAP.md`` Queue 1).
+LATENT_STREAM_WIDTH = 4
+
+
+def latent_decode_streams(table_positions: int, n_sel: int) -> bool:
+    """Does decode attention over ``n_sel`` picked rows of a table of
+    ``table_positions`` read the lane's blocks itself, rather than
+    gather the rows?  The ONE test, of shapes alone, behind
+    :func:`latent_decode_selection` and
+    :func:`latent_decode_read_rows`."""
+    return table_positions <= LATENT_STREAM_WIDTH * n_sel
+
+
+class LatentSelection(NamedTuple):
+    """A decode step's picked positions, in the form
+    :func:`latent_decode_attention` fetches them by."""
+
+    taken: jnp.ndarray  # [B, T] bool: the positions a lane picked
+    #: ``[B, n_sel]`` int32 leaf rows of the same choice where the rows
+    #: are gathered; None where the lane's blocks are streamed
+    rows: Optional[jnp.ndarray]
+
+
+def latent_decode_selection(
+    scores: jnp.ndarray,  # [B, T] float32 index scores, -inf past a lane
+    n_sel: int,
+    tables: jnp.ndarray,  # [B, MB] int32 block ids IN the leaves
+) -> LatentSelection:
+    """The exact top ``n_sel`` positions of every lane (equal scores
+    lowest position first), prepared for the fetch the table's width
+    calls for — where the choice between the two is made, once: a mask
+    alone by the counting search where the blocks are streamed
+    (``[32, 8192]``, top 2048, bare on a v5e: 67.5 us against the
+    sort's 258.8; PR 54), the sort that carries each position's row
+    where the rows are gathered."""
+    if latent_decode_streams(scores.shape[1], n_sel):
+        return LatentSelection(exact_topk_mask(scores, n_sel), None)
+    rows, taken = exact_topk_rows(scores, n_sel, tables, with_mask=True)
+    return LatentSelection(taken, rows)
+
+
+def latent_decode_read_rows(
+    cached: int, table_positions: int, n_sel: int, block_size: int
+) -> int:
+    """The rows :func:`latent_decode_attention` fetches a layer for a
+    lane of ``cached`` positions under such a table: every row of the
+    blocks it holds where they are streamed, else the rows picked.
+    Host arithmetic for a scheduler's ``read_rows`` label — what the
+    program WOULD read by its own test of shapes, not a count taken on
+    the device."""
+    if latent_decode_streams(table_positions, n_sel):
+        return -(-cached // block_size) * block_size
+    return min(cached, n_sel)
+
+
+def latent_decode_attention(
+    q_c: jnp.ndarray,  # [B, H, Dc] absorbed queries: q_nope W_uk
+    q_pe: jnp.ndarray,  # [B, H, Dr] rotated queries
+    c_leaf: jnp.ndarray,  # [N, bs, Dc] the latents' leaf
+    pe_leaf: jnp.ndarray,  # [N, bs * Dr / M, M]: M / Dr tokens' keys a row
+    tables: jnp.ndarray,  # [B, MB] int32 block ids IN the leaves
+    seq_lens: jnp.ndarray,  # [B] int32: positions of a lane that count
+    picked: LatentSelection,  # of :func:`latent_decode_selection`
+    scale: float,
+    backend: Optional[str] = None,
+) -> jnp.ndarray:
+    """Single-token attention of every head over the positions each
+    lane PICKED (below ``seq_lens``) in absorbed form: ``softmax(scale *
+    (q_c . c + q_pe . k_pe)) c``.  Returns the summed latents ``[B, H,
+    Dc]``; the caller applies ``W_uv``.  A lane of length 0 reads
+    nothing and returns zeros.
+
+    One sum, two ways to fetch, as the selection was prepared
+    (:func:`latent_decode_selection`, by the table's width): a mask
+    alone is STREAMED — the kernel copies the blocks a lane holds
+    itself, scores every held row and masks the ones not picked
+    (``ops/paged_kernels.mla_stream_decode_kernel``; a copy a block of
+    16 rows costs what ~4 gathered rows do) — and where it names the
+    rows they are gathered (:func:`latent_rows_decode_attention`).
+    Both are ``mla_sparse_decode`` in a device trace."""
+    bs, mb = c_leaf.shape[1], tables.shape[1]
+    taken, rows = picked
+    if rows is not None:
+        return latent_rows_decode_attention(
+            q_c, q_pe, c_leaf.reshape(-1, c_leaf.shape[-1]),
+            pe_leaf.reshape(-1, pe_leaf.shape[-1]), rows,
+            jnp.minimum(seq_lens, rows.shape[1]), scale, backend,
+        )
+    if (backend or paged_kernel_backend()) == "pallas":
+        from dlrover_tpu.ops.paged_kernels import mla_stream_decode_kernel
+
+        return mla_stream_decode_kernel(
+            q_c, q_pe, c_leaf, pe_leaf, tables, seq_lens, taken, scale=scale
+        )
+    b = q_c.shape[0]
+    c = c_leaf[tables].reshape(b, mb * bs, -1)  # by position
+    pe = pe_leaf[tables].reshape(b, mb * bs, -1)
+    keep = taken & (jnp.arange(mb * bs)[None] < seq_lens[:, None])
+    c = jnp.where(keep[..., None], c, 0)  # past the length lies garbage
+    pe = jnp.where(keep[..., None], pe, 0)
+    return _absorbed_attention(q_c, q_pe, c, pe, keep, scale)
 
 
 def latent_prefill_attention(

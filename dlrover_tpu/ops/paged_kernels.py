@@ -15,9 +15,18 @@ page by page instead, and the gather never materializes:
   next lane's first — in flight while this one is computed).  Its time
   follows what the lanes hold, not the table's width: a pipeline
   operand a page cost 0.14-0.23 us for every ENTRY of the table, live
-  or dead (PERF.md, PR 45).  The **verify** kernel and the decode over
-  rows already gathered (``sparse_decode_kernel``) still stream pages
-  as ``BlockSpec`` operands whose index maps dereference the table
+  or dead (PERF.md, PR 45).  The **latent decode** kernel
+  (``mla_stream_decode_kernel``, PR 54) is the same form over the two
+  leaves of a cache that keeps ONE row a token: it copies the blocks a
+  lane holds, scores every held row in absorbed form and takes the
+  indexer's selection as a mask over positions — no picked row is
+  gathered while a table holds a few times what is picked
+  (``ops/paged_attention.latent_decode_selection`` chooses by the
+  shapes; under a wider table the picked rows are gathered for
+  ``mla_sparse_decode_kernel``).  The **verify** kernel and the decode
+  over rows already gathered (``sparse_decode_kernel``,
+  ``mla_sparse_decode_kernel``) still stream pages as ``BlockSpec``
+  operands whose index maps dereference the table, or the rows' count,
   before each grid step (``_paged_call``);
 - softmax runs **online** per lane (running ``(m, l, acc)`` in VMEM
   scratch, the flash-attention recipe from ``ops/flash_attention.py``)
@@ -1113,6 +1122,282 @@ def mla_sparse_decode_kernel(
             name="mla_sparse_decode",
         ),
     )(counts.astype(jnp.int32), q_c, q_pe, c, pe)
+
+
+def _mla_stream_kernel(
+    tables_ref,  # scalar prefetch [B, MB]: block ids in the leaves
+    lens_ref,  # scalar prefetch [B]: positions of a lane that count
+    qc_ref,  # [1, H, Dc]: every head's absorbed query
+    qpe_ref,  # [1, H, M]: every head's rotated query, under each token's lanes
+    picked_ref,  # [1, G, span * bs] int32: the selection, a group a row (one
+    # row of 32-bit words is read at a dynamic index; of int8 it is not)
+    c_hbm,  # [N, bs, Dc]: the latents' leaf, where it lies
+    pe_hbm,  # [N, bs * Dr / M, M]: the rotated keys' leaf, M / Dr tokens a row
+    o_ref,  # [1, H, Dc]
+    c_buf,  # [2, span, bs, Dc]: two slots, a group of blocks each
+    pe_buf,  # [2, span, bs * Dr / M, M]
+    sems,  # DMA [2, 2]: (leaf, slot)
+    done,  # SMEM [1]: groups computed by the lanes before this one
+    m_scr,
+    l_scr,
+    acc_scr,
+    *,
+    span: int,
+    block_size: int,
+    slab: int,
+    scale: float,
+):
+    """One lane a grid step; the kernel fetches the blocks the lane
+    HOLDS from the two leaves itself (:func:`_stream_decode_kernel`'s
+    form: groups of ``span`` table entries, two slots a leaf, the next
+    group — or the next lane's first — in flight) and attends in
+    absorbed form over the positions its selection marks.  A position
+    that is not picked has exactly zero weight; one past the length is
+    zeroed before it is multiplied (the tail of the last held block,
+    and the stale rows of a group the lane holds in part, are garbage).
+
+    The rotated keys lie ``M / Dr`` tokens a row.  A 0/1 product a
+    ``slab`` of positions lays each token's row under the token
+    (``[slab, slab / per_row] x [slab / per_row, M]``: exact, a
+    position's row is one row times 1), the other tokens' lanes are
+    zeroed, and the query — laid under every token's lanes by the
+    caller — meets it in one product of ``M``."""
+    b = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    max_blocks = tables_ref.shape[1]
+    pe_rows, width = pe_hbm.shape[1:]  # rows a block, lanes a row
+    per_row = block_size // pe_rows  # tokens a row
+    dr = width // per_row
+
+    def held(lane):  # blocks a lane's table really holds
+        blocks = lax.div(lens_ref[lane] + block_size - 1, block_size)
+        return jnp.minimum(blocks, max_blocks)
+
+    def groups(lane):
+        return lax.div(held(lane) + span - 1, span)
+
+    def copies(lane, i, slot, arrive):
+        """Start, or wait for, the two copies of every block lane
+        ``lane`` holds of its group ``i`` — a loop of as many turns, not
+        unrolled; a whole group is waited for at once (a slot's
+        semaphore counts what arrived, and ``span`` blocks are the
+        buffer's size).  Entries past the lane's last block are neither
+        read from the table nor fetched."""
+        n_blocks = jnp.minimum(held(lane) - i * span, span)
+        leaves = ((c_hbm, c_buf), (pe_hbm, pe_buf))
+
+        def block(s, carry):
+            at = tables_ref[lane, i * span + s]
+            for leaf, (hbm, buf) in enumerate(leaves):
+                copy = pltpu.make_async_copy(
+                    hbm.at[at], buf.at[slot, s], sems.at[leaf, slot]
+                )
+                copy.wait() if arrive else copy.start()
+            return carry
+
+        if not arrive:
+            lax.fori_loop(0, n_blocks, block, 0)
+            return
+
+        @pl.when(n_blocks == span)
+        def _whole():
+            for leaf, (_, buf) in enumerate(leaves):
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sems.at[leaf, slot]
+                ).wait()
+
+        @pl.when(n_blocks < span)
+        def _tail():
+            lax.fori_loop(0, n_blocks, block, 0)
+
+    start = functools.partial(copies, arrive=False)
+    wait = functools.partial(copies, arrive=True)
+
+    @pl.when(b == 0)
+    def _first_lane():
+        done[0] = 0
+
+    seq_len = lens_ref[b]
+    n_groups = groups(b)
+    base = done[0]
+    before = jnp.maximum(b - 1, 0)
+    after = jnp.minimum(b + 1, lanes - 1)
+    after_reads = (b + 1 < lanes) & (groups(after) > 0)
+
+    # the lane before starts this lane's first group, if it ran at all
+    @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
+    def _own_first_group():
+        start(b, 0, lax.rem(base, 2))
+
+    _init_state(m_scr, l_scr, acc_scr)
+    n_pos = span * block_size  # positions a group
+    pos_col = _iota_cols(n_pos)  # [1, P]
+    pos_row = _iota_rows(n_pos)  # [P, 1]
+    # a row of rotated keys: the position of each of its lanes' token
+    pe_pos = per_row * _iota_rows(span * pe_rows) + lax.div(
+        _iota_cols(width), dr
+    )  # [P / per_row, M]
+    # the 0/1 product of a slab, and the lanes that are a position's own
+    lay = jnp.where(
+        lax.div(_iota_rows(slab), per_row) == _iota_cols(slab // per_row),
+        1.0, 0.0,
+    )
+    own = lax.rem(pos_row, per_row) == lax.div(_iota_cols(width), dr)
+
+    def group(i, carry):
+        slot = lax.rem(base + i, 2)
+
+        @pl.when(i + 1 < n_groups)
+        def _next_group():
+            start(b, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == n_groups) & after_reads)
+        def _next_lane():
+            start(after, 0, 1 - slot)
+
+        wait(b, i, slot)
+        at = i * n_pos  # the group's first position
+        # Zero what lies past the length: 0 * NaN would poison the
+        # accumulator, and the 0/1 product a whole slab.
+        c = c_buf[slot].reshape(n_pos, -1)
+        c = jnp.where(at + pos_row < seq_len, c, jnp.zeros_like(c))
+        pe = pe_buf[slot].reshape(span * pe_rows, width)
+        pe = jnp.where(at + pe_pos < seq_len, pe, jnp.zeros_like(pe))
+        rows = slab // per_row
+        pe = jnp.concatenate([
+            lax.dot_general(
+                lay.astype(pe.dtype), pe[n * rows:(n + 1) * rows],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for n in range(n_pos // slab)
+        ], axis=0)  # [P, M]: a position's row of keys
+        pe = jnp.where(own, pe, 0.0).astype(c.dtype)
+        s_log = (
+            lax.dot_general(
+                qc_ref[0], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            + lax.dot_general(
+                qpe_ref[0], pe, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        ) * scale
+        keep = (picked_ref[0, pl.ds(i, 1), :] != 0) & (at + pos_col < seq_len)
+        # the latent is key and value at once
+        _online_update(m_scr, l_scr, acc_scr, s_log, c, keep)
+        return carry
+
+    lax.fori_loop(0, n_groups, group, 0)
+    done[0] = base + n_groups
+    _finalize(o_ref, m_scr, l_scr, acc_scr)
+
+
+def mla_stream_decode_kernel(
+    q_c: jnp.ndarray,  # [B, H, Dc] absorbed queries: q_nope W_uk
+    q_pe: jnp.ndarray,  # [B, H, Dr] rotated queries
+    c_leaf: jnp.ndarray,  # [N, bs, Dc] the latents' leaf, whole
+    pe_leaf: jnp.ndarray,  # [N, bs * Dr / M, M] the rotated keys' leaf, whole
+    block_tables: jnp.ndarray,  # [B, MB] int32 block ids IN the leaves
+    seq_lens: jnp.ndarray,  # [B] int32: positions of a lane that count
+    taken: jnp.ndarray,  # [B, MB * bs] bool: the positions a lane picked
+    *,
+    scale: float,
+) -> jnp.ndarray:
+    """Decode attention in ABSORBED form over the positions each lane
+    PICKED of the blocks it holds, ``mla_sparse_decode`` in a device
+    trace as the kernel over gathered rows is
+    (:func:`mla_sparse_decode_kernel`): the two leaves go in whole and
+    in place, a grid step is a lane, and the kernel copies the lane's
+    blocks itself (:func:`_mla_stream_kernel`) — every held row is read
+    and scored, the selection is a mask, and no row is gathered.  A lane
+    of length 0 reads nothing and returns exact zeros.  Returns ``[B,
+    H, Dc]`` (the summed latents: the caller applies ``W_uv``).
+
+    The blocks a group follow from the shapes: a group's float32 logits
+    stay under 2 MiB of the fast memory and the two slots of a leaf
+    under 1 MiB, in whole slabs of 128 positions (32 blocks at
+    DeepSeek-V3.2's widths; bare on a v5e 16 read 26 % slower at 4 k
+    held positions, 56 within 2 % at 4-8 k and 6 % slower at 2 k:
+    ``PERF.md`` section 6, PR 54)."""
+    batch, n_heads, dc = q_c.shape
+    _, block_size, _ = c_leaf.shape
+    _, pe_rows, width = pe_leaf.shape
+    max_blocks = block_tables.shape[1]
+    per_row = block_size // pe_rows
+    rows_p = _round_up(n_heads, sublane_tile(q_c.dtype))
+    span = max(1, min(
+        max_blocks,
+        (2 << 20) // (rows_p * block_size * 4),
+        (1 << 20) // (2 * block_size * dc * c_leaf.dtype.itemsize),
+    ))
+    per_slab = max(1, 128 // block_size)
+    if span > per_slab:
+        span -= span % per_slab
+    n_pos = span * block_size
+    slab = int(np.gcd(n_pos, 128))
+    if slab % per_row:
+        raise ValueError(
+            f"{per_row} tokens a row of keys in slabs of {slab} positions"
+        )
+    n_groups = -(-max_blocks // span)
+    picked = taken.astype(jnp.int32)
+    if n_groups * n_pos > picked.shape[1]:
+        picked = jnp.pad(
+            picked, ((0, 0), (0, n_groups * n_pos - picked.shape[1]))
+        )
+    picked = picked.reshape(batch, n_groups, n_pos)
+    if rows_p > n_heads:
+        pad = ((0, 0), (0, rows_p - n_heads), (0, 0))
+        q_c, q_pe = jnp.pad(q_c, pad), jnp.pad(q_pe, pad)
+
+    def lane_index(b, tables, lens):
+        del tables, lens
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[
+            pl.BlockSpec((1, rows_p, dc), lane_index),
+            pl.BlockSpec((1, rows_p, width), lane_index),
+            pl.BlockSpec((1, n_groups, n_pos), lane_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, rows_p, dc), lane_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, block_size, dc), c_leaf.dtype),
+            pltpu.VMEM((2, span, pe_rows, width), pe_leaf.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.VMEM((rows_p, dc), jnp.float32),
+        ],
+    )
+    out = named_kernel(
+        "mla_sparse_decode",
+        pl.pallas_call(
+            functools.partial(
+                _mla_stream_kernel, span=span, block_size=block_size,
+                slab=slab, scale=scale,
+            ),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((batch, rows_p, dc), q_c.dtype),
+            interpret=use_interpret(),
+            name="mla_sparse_decode",
+            # the slot parity and the next lane's first group carry
+            # over a grid step: the lanes run in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
+        ),
+    )(
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        q_c, jnp.tile(q_pe, (1, 1, per_row)), picked, c_leaf, pe_leaf,
+    )
+    return out[:, :n_heads]
 
 
 # keys a grid step of ``chunk_prefill_kernel`` reads; whoever lays out

@@ -444,8 +444,9 @@ class ContinuousBatchingScheduler:
       row a token serves every head) says ``pages_kv = False``: the
       pool then holds its ``paged_leaves()`` alone, ``n_kv_heads`` /
       ``head_dim`` are not read, the ``serve_step`` record carries
-      ``cache_bytes`` (beside an indexer's ``sel_rows`` and
-      ``cached_rows``), and the ``prefill`` role is refused by name
+      ``cache_bytes`` (beside an indexer's ``sel_rows``,
+      ``cached_rows`` and ``read_rows``), and the ``prefill`` role is
+      refused by name
       beside the two above (a ship's regions are a K and a V).  And
       optionally
       ``layer_windows() -> (window | None, ...)``, one entry a layer
@@ -854,7 +855,7 @@ class ContinuousBatchingScheduler:
         # a model with an indexer / a router: what a decode step's
         # lanes selected and how its rows fell on the experts (the
         # ``serve_step`` labels ``sel_rows``, ``cached_rows``,
-        # ``index_bytes``,
+        # ``read_rows``, ``index_bytes``,
         # ``experts_hit``, ``expert_rows_max``, ``expert_rows_mean``),
         # and their sums over the run
         self._index_row_bytes = sum(
@@ -865,7 +866,7 @@ class ContinuousBatchingScheduler:
             getattr(model_cfg, "topk", None) and cache_cfg.paged_leaves
         )
         self._step_sel_rows = self._step_index_bytes = 0
-        self._step_cached_rows = 0
+        self._step_cached_rows = self._step_read_rows = 0
         self._step_experts: Dict = {}
         # a model with windows: the rows a decode step's kernels had to
         # read, by kind of layer (``serve_step`` labels
@@ -1760,11 +1761,21 @@ class ContinuousBatchingScheduler:
 
     def _note_selection(self, cached: int):
         """One decode lane's share of the step's ``sel_rows`` /
-        ``index_bytes`` labels (a model with an indexer only)."""
+        ``read_rows`` / ``index_bytes`` labels (a model with an indexer
+        only)."""
         if not self._has_indexer:
             return
-        self._step_sel_rows += min(cached, int(self.cfg.topk))
+        sel = min(cached, int(self.cfg.topk))
+        self._step_sel_rows += sel
         self._step_cached_rows += cached
+        # what attention fetched to use them: the model says where it
+        # reads more than it picks (a lane's whole blocks, streamed)
+        reads = getattr(self.cfg, "decode_read_rows", None)
+        self._step_read_rows += reads(
+            cached,
+            self.sched.max_blocks_per_seq * self.sched.block_size,
+            self.sched.block_size,
+        ) if reads else sel
         self._step_index_bytes += cached * self._index_row_bytes
 
     def _note_experts(self, rows: Dict[str, np.ndarray], slots: List[int]):
@@ -2410,7 +2421,7 @@ class ContinuousBatchingScheduler:
         self._step_commits = 0
         self._step_state_resets = self._step_prefill_heads = 0
         self._step_sel_rows = self._step_index_bytes = 0
-        self._step_cached_rows = 0
+        self._step_cached_rows = self._step_read_rows = 0
         self._step_experts = {}
         self._step_kv_rows = [0, 0]
         self._step_chunk = {}
@@ -2538,6 +2549,7 @@ class ContinuousBatchingScheduler:
             out.update(
                 sel_rows=self._step_sel_rows,
                 cached_rows=self._step_cached_rows,
+                read_rows=self._step_read_rows,
             )
             if self.pool_cfg.pages_kv:
                 # beside K and V the paged leaves are the index keys

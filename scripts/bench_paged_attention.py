@@ -89,6 +89,14 @@ CHUNKS = {
 #: program picks from at 16 384 positions a lane
 SELECTED_WIDTHS = (4096, 8192, 12288, 16384)
 
+#: held positions of DeepSeek-V3.2's decode row (32 lanes, 128 heads,
+#: blocks of 16, a table of 512 entries, top 2048 of a 512-wide latent
+#: and a 64-wide rotated key, bfloat16), and of the sweep that set
+#: ``ops/paged_attention.LATENT_STREAM_WIDTH`` (the table as wide as
+#: what a lane holds)
+LATENT_HELD = (2048, 4096, 8192)
+LATENT_SWEEP = (2048, 4096, 6144, 8192, 12288, 16384, 32768)
+
 
 def _time_call(call, reps: int) -> float:
     """Best-of-reps wall microseconds for an already-warm callable."""
@@ -537,6 +545,160 @@ def bench_selected(widths=SELECTED_WIDTHS, reps=20, blocks=(None,), dims=None):
     return out_rows
 
 
+def _latent_case(lanes, heads, entries, held, dims, seed=0):
+    """The two leaves with their blocks scattered, every lane holding
+    ``held`` positions less 3 of a table of ``entries`` and picking its
+    top ``topk`` of random scores."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.ops import paged_attention as pa
+
+    bs, dc, dr, width, topk, dtype = (
+        dims[n] for n in ("block_size", "rank", "rope", "minor", "topk",
+                          "dtype")
+    )
+    rng = np.random.default_rng(seed)
+    blocks = held // bs
+    tables = np.zeros((lanes, entries), np.int32)
+    tables[:, :blocks] = 1 + rng.permutation(lanes * blocks).reshape(
+        lanes, blocks
+    )
+    n = lanes * blocks + 1
+    key = jax.random.PRNGKey(seed)
+    lens = jnp.full((lanes,), held - 3, jnp.int32)
+    scores = jnp.where(
+        jnp.arange(entries * bs)[None] < lens[:, None],
+        jax.random.normal(key, (lanes, entries * bs)), -jnp.inf,
+    )
+    n_sel = min(topk, entries * bs)
+    tables = jnp.asarray(tables)
+    rows, taken = pa.exact_topk_rows(scores, n_sel, tables, with_mask=True)
+    return dict(
+        q_c=jax.random.normal(key, (lanes, heads, dc), dtype),
+        q_pe=jax.random.normal(key, (lanes, heads, dr), dtype),
+        c=jax.random.normal(key, (n, bs, dc), dtype),
+        pe=jax.random.normal(key, (n, bs * dr // width, width), dtype),
+        tables=tables, lens=lens, scores=scores, rows=rows, taken=taken,
+        n_sel=n_sel,
+    )
+
+
+def bench_latent(held=LATENT_HELD, entries=512, reps=20, dims=None):
+    """Rows of DeepSeek-V3.2's bare decode attention, ``reps`` calls
+    chained inside ONE program as :func:`bench_tables` does: the
+    GATHERED fetch (two gathers of the picked rows, the ``where``, the
+    kernel over the buffer: ``latent_rows_decode_attention``) against
+    the STREAMED kernel (``mla_stream_decode_kernel``) on the same
+    leaves, tables and selection, each with its largest difference from
+    the jnp form.  ``entries``: the table's width (``None``: as wide as
+    what a lane holds — the sweep that sets ``LATENT_STREAM_WIDTH``).
+    ``dims``: a rehearsal's sizes."""
+    import jax.numpy as jnp
+
+    from dlrover_tpu.ops import paged_attention as pa
+    from dlrover_tpu.ops.paged_kernels import mla_stream_decode_kernel
+
+    dims = {
+        **dict(lanes=32, heads=128, block_size=16, rank=512, rope=64,
+               minor=128, topk=2048, dtype=jnp.bfloat16, scale=0.1352),
+        **(dims or {}),
+    }
+    out_rows = []
+    for positions in held:
+        width = entries or positions // dims["block_size"]
+        a = _latent_case(dims["lanes"], dims["heads"], width, positions, dims)
+        scale, n_sel = dims["scale"], a["n_sel"]
+
+        def gathered(q_c, q_pe, c, pe, rows, lens, backend="pallas"):
+            # the rows hang on the queries (by a zero no compiler can
+            # see): a chained call gathers anew, as a decode step does,
+            # where a gather of the loop's constants would be made once
+            rows = rows + (q_c[0, 0, 0] > 1e30).astype(rows.dtype)
+            return pa.latent_rows_decode_attention(
+                q_c, q_pe, c.reshape(-1, c.shape[-1]),
+                pe.reshape(-1, pe.shape[-1]), rows,
+                jnp.minimum(lens, n_sel), scale, backend,
+            )
+
+        rest = (a["q_pe"], a["c"], a["pe"], a["rows"], a["lens"])
+        row = {
+            "kernel": "mla_sparse_decode", "held": positions,
+            "table": width * dims["block_size"], "topk": n_sel,
+            "gathered_us": round(
+                _chained_us(gathered, a["q_c"], rest, reps), 1
+            ),
+        }
+        ref = gathered(a["q_c"], *rest, backend="jnp").astype(jnp.float32)
+        row["gathered_max_abs_diff_vs_jnp"] = float(jnp.max(jnp.abs(
+            gathered(a["q_c"], *rest).astype(jnp.float32) - ref
+        )))
+        kernel = functools.partial(mla_stream_decode_kernel, scale=scale)
+        rest = (a["q_pe"], a["c"], a["pe"], a["tables"], a["lens"],
+                a["taken"])
+        us = _chained_us(kernel, a["q_c"], rest, reps)
+        got = kernel(a["q_c"], *rest).astype(jnp.float32)
+        out_rows.append({
+            **row, "streamed_us": round(us, 1),
+            # the same sum in another order of rows
+            "streamed_max_abs_diff_vs_gathered_jnp": float(
+                jnp.max(jnp.abs(got - ref))
+            ),
+            # the streamed jnp form gathers a lane's whole table,
+            # ``[lanes, T, rank]``: up to the cell's 8192 positions
+            "streamed_max_abs_diff_vs_jnp": float(jnp.max(jnp.abs(
+                got - pa.latent_decode_attention(
+                    a["q_c"], a["q_pe"], a["c"], a["pe"], a["tables"],
+                    a["lens"], pa.LatentSelection(a["taken"], None), scale,
+                    backend="jnp",
+                ).astype(jnp.float32)
+            ))) if width * dims["block_size"] <= 8192 else None,
+        })
+        print(json.dumps(out_rows[-1]), flush=True)
+    return out_rows
+
+
+def bench_selection(lanes=32, positions=8192, topk=2048, reps=20):
+    """Microseconds a call of the three exact selections at a decode
+    step's ``[lanes, positions]`` scores: the sort that carries each
+    position's pool row (``exact_topk_rows`` with its mask), the sort
+    that carries nothing and the counting search (``exact_topk_mask``)
+    — and that the three pick the same."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from dlrover_tpu.ops import paged_attention as pa
+
+    scores = jax.random.normal(jax.random.PRNGKey(0), (lanes, positions))
+    tables = jnp.arange(lanes * positions // 16, dtype=jnp.int32).reshape(
+        lanes, -1
+    )
+    def sort_alone(s):  # the k-th value of a sort that carries nothing
+        kth = -lax.sort(-s, dimension=1)[:, topk - 1:topk]
+        room = topk - jnp.sum(s > kth, -1, keepdims=True)
+        return (s > kth) | ((s == kth) & (jnp.cumsum(s == kth, -1) <= room))
+
+    forms = {
+        "sort_with_rows": lambda s: pa.exact_topk_rows(
+            s, topk, tables, with_mask=True
+        )[1],
+        "sort_alone": sort_alone,
+        "counting_search": lambda s: pa.exact_topk_mask(s, topk),
+    }
+    want = forms["sort_with_rows"](scores)
+    row = {"selection": [lanes, positions], "topk": topk}
+    for name, form in forms.items():
+        row[f"{name}_us"] = round(_chained_us(
+            lambda s, form=form: jnp.where(form(s), 1.0, 0.0), scores, (),
+            reps,
+        ), 1)
+        row[f"{name}_same"] = bool(jnp.all(form(scores) == want))
+    print(json.dumps(row), flush=True)
+    return [row]
+
+
 def _interpret() -> bool:
     from dlrover_tpu.ops.pallas_utils import use_interpret
 
@@ -587,11 +749,43 @@ def main(argv=None) -> int:
         help="time the bare selected-keys prefill kernel at Keye-VL-2.0's "
         f"widths ({', '.join(str(w) for w in SELECTED_WIDTHS)} keys)",
     )
+    ap.add_argument(
+        "--latent", action="store_true",
+        help="time DeepSeek-V3.2's bare decode attention, the gathered "
+        "fetch against the streamed kernel, at lanes holding "
+        f"{', '.join(str(h) for h in LATENT_HELD)} positions of a "
+        "512-entry table, and the three exact selections at [32, 8192]",
+    )
+    ap.add_argument(
+        "--latent-sweep", action="store_true",
+        help="the same two fetches with the table as wide as what a lane "
+        f"holds ({', '.join(str(h) for h in LATENT_SWEEP)} positions): "
+        "the crossover that set LATENT_STREAM_WIDTH",
+    )
     args = ap.parse_args(argv)
     blocks = [
         tuple(int(n) for n in b.split("x"))
         for b in args.blocks.split(",") if b
     ] or (None,)
+
+    if args.latent or args.latent_sweep:
+        import jax
+
+        rows = []
+        if args.latent:
+            rows += bench_latent(reps=max(args.reps, 20))
+            rows += bench_selection(reps=max(args.reps, 20))
+        if args.latent_sweep:
+            rows += bench_latent(
+                LATENT_SWEEP, entries=None, reps=max(args.reps, 20),
+            )
+        _flush(args.out, {
+            "bench": "mla_sparse_decode", "rows": rows,
+            "backend": jax.default_backend(), "interpret": _interpret(),
+            "device_kind": jax.devices()[0].device_kind,
+        })
+        print(f"wrote {args.out} ({len(rows)} rows)")
+        return 0
 
     if args.selected:
         import jax

@@ -470,6 +470,193 @@ def test_the_prefill_kernel_is_the_plain_multi_head_attention(start, kv_len):
     )
 
 
+def _stream_case(lens, k, scores="random", *, h=4, bs=16, mb=8, rank=32,
+                 dr=8, minor=16, seed=3):
+    """Two leaves whose blocks lie scattered, every lane holding the
+    blocks of its ``lens`` positions and the null block behind them;
+    NaN in the null block, in every block no lane holds and in a last
+    held block's rows past the length.  ``scores``: ``random``, or
+    ``tied`` (a few values, so that the ``k``-th is shared)."""
+    rng = np.random.default_rng(seed)
+    lens = np.asarray(lens, np.int32)
+    b, t, n = len(lens), mb * bs, len(lens) * mb + 1
+    c_leaf = rng.standard_normal((n, bs, rank)).astype(np.float32)
+    pe_tok = rng.standard_normal((n, bs, dr)).astype(np.float32)
+    tables = np.zeros((b, mb), np.int32)
+    free = 1 + rng.permutation(n - 1)
+    unheld = np.ones(n, bool)
+    for i, length in enumerate(lens):
+        held = -(-int(length) // bs)
+        tables[i, :held] = free[i * mb:i * mb + held]
+        unheld[tables[i, :held]] = False
+        if length % bs:
+            c_leaf[tables[i, held - 1], length % bs:] = np.nan
+            pe_tok[tables[i, held - 1], length % bs:] = np.nan
+    c_leaf[unheld] = pe_tok[unheld] = np.nan
+    score = rng.standard_normal((b, t)).astype(np.float32)
+    if scores == "tied":
+        score = np.round(score)
+    score[np.arange(t)[None] >= lens[:, None]] = -np.inf
+    score = jnp.asarray(score)
+    tables = jnp.asarray(tables)
+    n_sel = min(k, t)
+    rows, taken = pa.exact_topk_rows(score, n_sel, tables, with_mask=True)
+    np.testing.assert_array_equal(
+        np.asarray(taken), np.asarray(pa.exact_topk_mask(score, n_sel))
+    )
+    return dict(
+        # logits of one size whatever the rank
+        q_c=jnp.asarray(
+            rng.standard_normal((b, h, rank)) * (32 / rank) ** 0.5,
+            jnp.float32,
+        ),
+        q_pe=jnp.asarray(rng.standard_normal((b, h, dr)), jnp.float32),
+        c=jnp.asarray(c_leaf),
+        pe=jnp.asarray(pe_tok.reshape(n, bs * dr // minor, minor)),
+        tables=tables, lens=jnp.asarray(lens), taken=taken, rows=rows,
+        score=score, n_sel=n_sel, c_np=c_leaf, pe_np=pe_tok,
+    )
+
+
+def _absorbed_by_hand(a, scale):
+    """numpy, a lane at a time over the picked positions alone."""
+    tables, taken = np.asarray(a["tables"]), np.asarray(a["taken"])
+    out = np.zeros(a["q_c"].shape, np.float32)
+    bs = a["c_np"].shape[1]
+    for i, length in enumerate(np.asarray(a["lens"])):
+        at = np.flatnonzero(taken[i, :length])
+        if not at.size:
+            continue
+        c = a["c_np"][tables[i, at // bs], at % bs]
+        pe = a["pe_np"][tables[i, at // bs], at % bs]
+        logits = (
+            np.asarray(a["q_c"][i]) @ c.T + np.asarray(a["q_pe"][i]) @ pe.T
+        ) * scale
+        p = np.exp(logits - logits.max(-1, keepdims=True))
+        out[i] = (p / p.sum(-1, keepdims=True)) @ c
+    return out
+
+
+#: the blocks a group follow from the shapes (two slots of a leaf under
+#: 1 MiB): a table of 8 blocks is one group; float32 latents of 512 are
+#: read 16 blocks (two slabs of 128 positions) a group, of 1024 8 blocks
+_ONE, _G16, _G8 = dict(), dict(rank=512, mb=40), dict(rank=1024, mb=20)
+
+
+@pytest.mark.parametrize("lens,k,scores,dims", [
+    ((0, 1, 20), 32, "random", _ONE),  # empty, one row, under the top k
+    ((255, 256, 257), 12, "random", _G16),  # around the first group's edge
+    ((511, 512, 513), 12, "random", _G16),  # around the second's
+    ((640, 0, 40), 12, "random", _G16),  # a full table, an idle lane between
+    ((640, 640, 300), 5, "random", _G16),  # a sparse choice
+    ((128, 77, 16), 128, "random", _ONE),  # a dense one: every row
+    ((640, 290, 33), 12, "tied", _G16),  # a choice that ends on a tie
+    ((320, 129, 33), 12, "tied", _G8),  # ... a slab a group
+], ids=["short", "edge1", "edge2", "idle", "sparse", "dense", "tie", "tie1"])
+def test_the_streamed_decode_kernel_reads_its_own_blocks_under_the_mask(
+    lens, k, scores, dims
+):
+    """``mla_stream_decode_kernel`` (interpret mode) and the jnp form
+    of the streamed fetch against numpy over the picked rows: a position
+    that is not picked, or lies past the length, weighs nothing whatever
+    its block holds, and a lane of length 0 returns exact zeros."""
+    from dlrover_tpu.ops import paged_kernels as pk
+
+    a = _stream_case(lens, k, scores, **dims)
+    want = _absorbed_by_hand(a, 0.25)
+    args = (a["q_c"], a["q_pe"], a["c"], a["pe"], a["tables"], a["lens"])
+    got = np.asarray(pk.mla_stream_decode_kernel(
+        *args, a["taken"], scale=0.25
+    ))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert not got[np.asarray(a["lens"]) == 0].any()
+    # the op's jnp form of the same fetch, whatever the table's width
+    np.testing.assert_allclose(
+        np.asarray(pa.latent_decode_attention(
+            *args, pa.LatentSelection(a["taken"], None), 0.25, backend="jnp"
+        )), want, atol=2e-5,
+    )
+
+
+@pytest.mark.parametrize("k,streams", [(32, True), (31, False)])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_decode_attention_picks_its_fetch_by_the_tables_width(
+    k, streams, backend, monkeypatch
+):
+    """A table of 128 positions: the selection is a mask alone, and the
+    lane's blocks are streamed, while the table holds at most
+    ``LATENT_STREAM_WIDTH`` times what is picked; else it names the rows
+    too and they are gathered — one choice, made where the selection is
+    prepared, and one answer either way."""
+    from dlrover_tpu.ops import paged_kernels as pk
+
+    assert pa.latent_decode_streams(128, k) == streams
+    assert pa.latent_decode_streams(8192, 2048)
+    assert not pa.latent_decode_streams(32768, 2048)
+    ran = []
+    for name in ("mla_stream_decode_kernel", "mla_sparse_decode_kernel"):
+        kernel = getattr(pk, name)
+        monkeypatch.setattr(
+            pk, name,
+            lambda *args, _n=name, _k=kernel, **kw: (
+                ran.append(_n), _k(*args, **kw)
+            )[1],
+        )
+    a = _stream_case((128, 50, 0, 97), k)
+    if not streams:  # the gathered fetch sends what it masks to row 0
+        a["c"], a["pe"] = jnp.nan_to_num(a["c"]), jnp.nan_to_num(a["pe"])
+    picked = pa.latent_decode_selection(a["score"], a["n_sel"], a["tables"])
+    np.testing.assert_array_equal(
+        np.asarray(picked.taken), np.asarray(a["taken"])
+    )
+    assert (picked.rows is None) == streams
+    if not streams:
+        np.testing.assert_array_equal(
+            np.asarray(picked.rows), np.asarray(a["rows"])
+        )
+    args = (a["q_c"], a["q_pe"], a["c"], a["pe"], a["tables"], a["lens"])
+    got = pa.latent_decode_attention(*args, picked, 0.25, backend)
+    want = _absorbed_by_hand(a, 0.25)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    if backend == "pallas":
+        assert ran == [
+            "mla_stream_decode_kernel" if streams
+            else "mla_sparse_decode_kernel"
+        ]
+    if not streams:  # the other fetch of the same choice: the same sum
+        other = pa.latent_decode_attention(
+            *args, pa.LatentSelection(a["taken"], None), 0.25, backend
+        )
+        np.testing.assert_allclose(np.asarray(other), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("k", [1, 7, 40, 64])
+def test_the_selection_as_a_mask_is_the_sorts_choice(k):
+    """A decode step that streams takes ``exact_topk_mask`` for its
+    selection: the positions ``exact_topk_rows`` names and marks, equal
+    scores lowest position first, ``-inf`` never."""
+    rng = np.random.default_rng(k)
+    scores = np.round(rng.standard_normal((5, 64)) * 2).astype(np.float32)
+    scores[1, 30:] = -np.inf
+    scores[2, :] = 1.0
+    scores[3, 3:] = -np.inf
+    scores[4, ::2] *= 0.0  # both zeros are one value
+    tables = jnp.arange(5 * 4, dtype=jnp.int32).reshape(5, 4)
+    rows, taken = pa.exact_topk_rows(
+        jnp.asarray(scores), k, tables, with_mask=True
+    )
+    got = np.asarray(pa.exact_topk_mask(jnp.asarray(scores), k))
+    np.testing.assert_array_equal(got, np.asarray(taken))
+    for i in range(5):
+        order = np.argsort(-scores[i], kind="stable")[:k]
+        order = order[np.isfinite(scores[i, order])]
+        assert set(np.flatnonzero(got[i])) == set(order)
+        # 16 positions a block: row = table[p // 16] * 16 + p % 16
+        assert set(np.asarray(rows[i])[:order.size]) == {
+            int(tables[i, p // 16]) * 16 + p % 16 for p in order
+        }
+
+
 def _loop_router(score, k, n_group, topk_group):
     """The group limit as a loop over groups: stable sorts, so equal
     scores go to the lowest id, for groups as for experts."""
@@ -742,8 +929,29 @@ def test_serve_step_carries_the_rows_the_experts_and_the_cache(
     params, tmp_path
 ):
     path = str(tmp_path / "events.jsonl")
-    sch = make_scheduler(params, events=EventLogger(path=path))
+    traced = []
+
+    def decode_fn(params, tokens, pool, block_tables, *rest):
+        traced.append((block_tables.shape[1], pool["c"].shape[2]))
+        return PARTS["paged_decode_fn"](
+            params, tokens, pool, block_tables, *rest
+        )
+
+    sch = ContinuousBatchingScheduler(
+        CFG, SchedulerConfig(**SCHED), paged_decode_fn=decode_fn,
+        paged_prefill_fn=PARTS["paged_prefill_fn"],
+        serving_params_fn=PARTS["serving_params_fn"],
+        capture_logprobs=True, events=EventLogger(path=path),
+    )
+    sch.sync_weights(params)
     serve(sch, prompts_of((22, 18, 30)), max_new=6)
+    # ``read_rows`` is the host's arithmetic, not a count from the
+    # device: it holds while the decode program is traced with the table
+    # width and the block size the scheduler reckons with
+    assert traced == [(sch.sched.max_blocks_per_seq, sch.sched.block_size)]
+    assert pa.latent_decode_streams(
+        sch.sched.max_blocks_per_seq * sch.sched.block_size, TOPK
+    )
     from dlrover_tpu.observability.events import read_events
 
     events = read_events(path)
@@ -758,6 +966,12 @@ def test_serve_step_carries_the_rows_the_experts_and_the_cache(
     for s in decoded:
         assert 0 < s["sel_rows"] <= TOPK * s["lanes_decode"]
         assert s["sel_rows"] <= s["cached_rows"]
+        # a table of 64 positions, top 32: decode attention streams the
+        # blocks of 4 a lane holds — every cached row, rounded up
+        assert s["cached_rows"] <= s["read_rows"] < (
+            s["cached_rows"] + 4 * s["lanes_decode"]
+        )
+        assert s["read_rows"] % 4 == 0
     assert any(s["sel_rows"] < s["cached_rows"] for s in decoded)
     assert max(s["cache_bytes"] for s in steps) > 0
     routed = [s for s in steps if "experts_hit" in s]
@@ -769,6 +983,22 @@ def test_serve_step_carries_the_rows_the_experts_and_the_cache(
     assert chunks and all(
         0 < c["rows"] <= 12 and c["kv_len"] >= c["rows"] for c in chunks
     )
+
+
+def test_the_rows_a_lane_reads_follow_the_attentions_own_choice():
+    """``decode_read_rows``: the held blocks where decode attention
+    streams (the table at most ``LATENT_STREAM_WIDTH`` times
+    ``index_topk``), the picked rows where it gathers — the one test of
+    shapes that prepares the selection
+    (``latent_decode_selection``)."""
+    cfg = M.DeepSeekV32Config()
+    assert pa.latent_decode_streams(8192, 2048)
+    assert cfg.decode_read_rows(3900, 8192, 16) == 3904
+    assert cfg.decode_read_rows(1, 8192, 16) == 16
+    assert cfg.decode_read_rows(100, 1024, 16) == 112  # top = the table
+    assert not pa.latent_decode_streams(32768, 2048)
+    assert cfg.decode_read_rows(3900, 32768, 16) == 2048
+    assert cfg.decode_read_rows(100, 131072, 16) == 100
 
 
 def test_the_kernels_counts_are_the_issues():

@@ -704,6 +704,8 @@ def test_serve_step_carries_the_selection_and_expert_labels(params, tmp_path):
     ik_bytes = 2 * 8 * 4  # layers x index dim x float32, a cached token
     for s in decoded:
         assert 0 < s["sel_rows"] <= TOPK * s["lanes_decode"]
+        # the picked rows are gathered: what is read is what is used
+        assert s["read_rows"] == s["sel_rows"]
         assert s["index_bytes"] % ik_bytes == 0
         assert s["index_bytes"] // ik_bytes >= s["sel_rows"]
     routed = [s for s in steps if "experts_hit" in s]

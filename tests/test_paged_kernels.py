@@ -738,6 +738,36 @@ class TestBenchHarness:
             )
             assert row["max_abs_diff_vs_jnp"] < 5e-6
 
+    @pytest.mark.parametrize("entries", [8, None])
+    def test_latent_rows_set_the_two_fetches_side_by_side(self, entries):
+        """``--latent`` / ``--latent-sweep``: a row a count of held
+        positions with the time of the gathered fetch and of the
+        streamed kernel on one selection, each against the jnp form; the
+        sweep's table is as wide as what a lane holds."""
+        bpa = self._module()
+        rows = bpa.bench_latent(
+            held=(64, 128), entries=entries, reps=2,
+            dims=dict(lanes=3, heads=4, block_size=16, rank=32, rope=8,
+                      minor=16, topk=40, dtype=jnp.float32, scale=0.25),
+        )
+        assert [(r["held"], r["table"]) for r in rows] == [
+            (64, 128 if entries else 64), (128, 128),
+        ]
+        for row in rows:
+            assert row["kernel"] == "mla_sparse_decode" and row["topk"] == 40
+            assert row["gathered_us"] > 0 and row["streamed_us"] > 0
+            for name in ("gathered_max_abs_diff_vs_jnp",
+                         "streamed_max_abs_diff_vs_gathered_jnp",
+                         "streamed_max_abs_diff_vs_jnp"):
+                assert row[name] < 5e-6
+
+    def test_selection_row_times_three_forms_of_one_choice(self):
+        bpa = self._module()
+        (row,) = bpa.bench_selection(lanes=3, positions=128, topk=40, reps=2)
+        assert row["selection"] == [3, 128] and row["topk"] == 40
+        for form in ("sort_with_rows", "sort_alone", "counting_search"):
+            assert row[f"{form}_us"] > 0 and row[f"{form}_same"]
+
     def test_budget_stops_between_points(self):
         bpa = self._module()
         snapshots = []

@@ -330,17 +330,45 @@ def _expert_share_case(rows):
     )
 
 
-def _mla_decode_case():
-    """The absorbed decode over the picked rows at DeepSeek-V3.2's
-    widths and its cell's lanes: 32 lanes, 128 heads, 2048 rows of a
-    512-wide latent (key and value) and a rotated shared key in a
-    128-lane row."""
-    from dlrover_tpu.ops.paged_kernels import mla_sparse_decode_kernel
+def _mla_decode_case(entries=512, form="streamed"):
+    """The absorbed decode at DeepSeek-V3.2's widths and its cell's
+    lanes — 32 lanes, 128 heads, the two leaves of seven layers' 18240
+    blocks of 16 (a 512-wide latent, key and value; the rotated shared
+    keys two tokens a 128-lane row), top 2048 — in one of its two
+    forms: ``streamed``, the kernel that copies the blocks a lane holds
+    under the selection's mask (``entries`` 512: the cell's 8192
+    positions), or ``gathered``, the kernel over the picked rows (2048:
+    a table of 32 k positions).  ``chosen``: from the index scores,
+    through the selection that picks between the two by the table's
+    width."""
+    from dlrover_tpu.ops.paged_attention import (
+        LatentSelection,
+        latent_decode_attention,
+        latent_decode_selection,
+    )
 
-    return partial(mla_sparse_decode_kernel, scale=0.13523), (
-        ((32, 128, 512), BF16), ((32, 128, 128), BF16),
-        ((32, 2048, 512), BF16), ((32, 2048, 128), BF16),
-        ((32,), jnp.int32),
+    def fn(q_c, q_pe, c, pe, tables, lens, *selection):
+        picked = {
+            "streamed": lambda taken: LatentSelection(taken, None),
+            "gathered": LatentSelection,
+            "chosen": lambda scores: latent_decode_selection(
+                scores, 2048, tables
+            ),
+        }[form](*selection)
+        return latent_decode_attention(
+            q_c, q_pe, c, pe, tables, lens, picked, 0.13523, "pallas"
+        )
+
+    positions = (32, entries * 16)
+    return fn, (
+        ((32, 128, 512), BF16), ((32, 128, 64), BF16),
+        ((7 * 18240, 16, 512), BF16), ((7 * 18240, 8, 128), BF16),
+        ((32, entries), jnp.int32), ((32,), jnp.int32),
+        *{
+            "streamed": [(positions, jnp.bool_)],
+            "gathered": [(positions, jnp.bool_), ((32, 2048), jnp.int32)],
+            "chosen": [(positions, jnp.float32)],
+        }[form],
     )
 
 
@@ -359,6 +387,7 @@ def _mla_prefill_case(keys=4096):
 
 CASES = {
     "mla_sparse_decode": _mla_decode_case,
+    "mla_sparse_decode_rows_32k": lambda: _mla_decode_case(2048, "gathered"),
     "mla_prefill": _mla_prefill_case,
     "paged_window_decode": lambda: _window_decode_case(True),
     "paged_full_decode_2048": lambda: _window_decode_case(False),
@@ -404,6 +433,7 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     ("paged_prefill_full_kv30", "paged_prefill_full"),
     ("sparse_prefill", "sparse_prefill"),
     ("mla_sparse_decode", "mla_sparse_decode"),
+    ("mla_sparse_decode_rows_32k", "mla_sparse_decode"),
     ("mla_prefill", "mla_prefill"),
     ("index_scores", "index_scores"),
     ("paged_window_decode", "paged_window_decode"),
@@ -426,6 +456,45 @@ def test_serving_kernels_keep_their_names(case, name, one_chip):
     for line in calls:
         assert re.match(rf"(ROOT )?%{name}(\.\d+)* = ", line), line
     assert "closed_call" not in text
+
+
+def _kernel_operands(text, name):
+    """Element counts of what the instruction ``%name`` is handed, from
+    the lines that define its operands."""
+    call = re.search(rf"%{name}(\.\d+)* = [^\n]*custom-call\(([^)]*)\)", text)
+    assert call, name
+    sizes = []
+    for operand in re.findall(r"%([\w.\-]+)", call.group(2)):
+        shape = re.search(
+            rf"%{re.escape(operand)} = \w+\[([\d,]*)\]", text
+        )
+        sizes.append(
+            math.prod(map(int, shape.group(1).split(",")))
+            if shape and shape.group(1) else 1
+        )
+    return sizes
+
+
+@pytest.mark.parametrize("entries,streams", [(512, True), (2048, False)])
+def test_latent_decode_streams_the_leaves_or_gathers_the_rows(
+    entries, streams, one_chip
+):
+    """From the index scores on: at the cell's table (8192 positions,
+    top 2048) the kernel is handed both leaves whole and nothing of
+    ``[32, 2048, 512]`` is gathered; under a table of 32 k positions it
+    is handed the gathered rows and neither leaf."""
+    fn, shapes = _mla_decode_case(entries, "chosen")
+    text = _compiled_text(fn, *shapes, sharding=one_chip)
+    handed = _kernel_operands(text, "mla_sparse_decode")
+    leaves = {7 * 18240 * 16 * 512, 7 * 18240 * 8 * 128}
+    picked = 32 * 2048 * 512
+    assert leaves <= set(handed) if streams else not leaves & set(handed)
+    assert (picked in handed) != streams
+    assert bool(re.search(r"bf16\[32,2048,512\]", text)) != streams
+    assert not [
+        line for elements, op, line in _materialised(text)
+        if op == "copy" and elements in leaves
+    ]
 
 
 def _pallas_calls(jaxpr):
@@ -1154,6 +1223,12 @@ def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
 
     assert kernel("moe_expert_ffn")
     assert kernel("mla_sparse_decode") == (program == "decode")
+    if program == "decode":
+        # the kernel reads the lanes' own blocks: both leaves whole (and,
+        # by the pin above, where they lie), no picked row gathered
+        handed = _kernel_operands(text, "mla_sparse_decode")
+        assert {math.prod(pool[n].shape) for n in ("c", "kpe")} <= set(handed)
+        assert not re.search(r"bf16\[32,2048,(512|128)\]", text)
     assert kernel("mla_prefill") == (program != "decode")
     assert kernel("index_scores") == (program != "decode")
     assert "ragged-dot" not in text
