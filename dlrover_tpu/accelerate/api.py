@@ -17,6 +17,7 @@ import jax
 from dlrover_tpu.accelerate.analyser import analyse_model
 from dlrover_tpu.accelerate.strategy import Strategy, generate_candidates
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.observability.events import get_event_logger
 from dlrover_tpu.parallel.mesh import create_parallel_mesh
 from dlrover_tpu.parallel.sharding import default_rules
 from dlrover_tpu.parallel.train_step import TrainStepFns, build_train_step
@@ -106,6 +107,11 @@ def auto_accelerate(
     (``bayes_search.tune_strategy``) spends ``tune_budget`` extra
     timed builds searching the tunables inside it.
     """
+    # a training worker's ``startup`` stage between the backend's
+    # initialisation and the train state (observability/events.py
+    # ``STARTUP_STAGES``)
+    events = get_event_logger()
+    stage = events.begin("startup", stage="accelerate")
     if devices is None:
         devices = jax.devices()
     profile = analyse_model(init_params_fn, optimizer)
@@ -215,6 +221,7 @@ def auto_accelerate(
     fns, mesh_ctx, rules = _build_for_strategy(
         strategy, loss_fn, optimizer, init_params_fn, param_axes, devices
     )
+    events.end("startup", stage, params=int(profile.num_params))
     return AccelerateResult(
         fns=fns,
         strategy=strategy,

@@ -14,16 +14,23 @@ to from the environment alone:
   (:func:`backend_initialized` — the guard the zygote and the tests
   use).
 
-One helper is for the process that OWNS the chip:
+Two are for the process that OWNS the chip:
 :func:`pinned_host_works`, whether a compiled program can place arrays
 in ``pinned_host`` memory (the host-offloaded optimizer's state, the
-trainer's staged snapshot).
+trainer's staged snapshot), and :func:`install_compile_meter`, the
+process's one :class:`CompileMeter`: what it traced, lowered and
+compiled or was handed by the persistent cache, counted and, with an
+events file, written to the timeline a program and stage.
 """
 
 import contextlib
 import os
+import re
 import sys
+import threading
 from typing import Dict, MutableMapping, Optional
+
+from dlrover_tpu.observability.events import anchored_now
 
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
@@ -157,29 +164,85 @@ class CompileMeter:
     """Counts this process's persistent-compile-cache hits and misses
     and sums the seconds spent in backend compiles, off JAX's own
     monitoring events.  Create it before the first compile; touches no
-    device."""
+    device.
+
+    Given an enabled event logger it also writes one ``compile`` record
+    a program and stage onto the timeline (``ph: "X"``, on the logger's
+    anchored clock: the end is the callback's instant, the start that
+    less JAX's duration): ``trace`` (to a jaxpr) and ``lower`` (to a
+    module) — of the many small functions JAX traces inside a
+    program's own trace or lowering only the outer record is written,
+    which covers them — and ``backend_compile``, with
+    ``cache``: ``hit`` where the persistent cache handed the executable
+    over (the record is then as long as the retrieval), ``miss`` where
+    it was compiled and written, ``none`` where it was compiled and the
+    cache not asked or the entry not kept — JAX keeps nothing that
+    compiled in under a second, so such a program compiles at EVERY
+    start.  The cache's own events carry no program's name: what a
+    thread heard since its last backend compile is that compile's.
+    Nothing fires unless something compiles."""
 
     _HIT = "/jax/compilation_cache/cache_hits"
     _MISS = "/jax/compilation_cache/cache_misses"
+    _TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    _LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
     _COMPILE = "/jax/core/compile/backend_compile_duration"
+    _STAGES = {_TRACE: "trace", _LOWER: "lower", _COMPILE: "backend_compile"}
 
-    def __init__(self):
+    def __init__(self, events=None):
         import jax.monitoring
 
         self._counts = {self._HIT: 0, self._MISS: 0}
         self._compile_s = 0.0
+        self._events = (
+            events if events is not None and events.enabled else None
+        )
+        # per thread: traces and lowerings open (JAX announces the
+        # start of either as a scalar), and the cache's verdict on the
+        # compile under way
+        self._thread = threading.local()
         jax.monitoring.register_event_listener(self._on_event)
         jax.monitoring.register_event_duration_secs_listener(
             self._on_duration
         )
+        if self._events is not None:
+            jax.monitoring.register_scalar_listener(self._on_scalar)
 
     def _on_event(self, event: str, **_kwargs):
         if event in self._counts:
             self._counts[event] += 1
+            self._thread.cache = "hit" if event == self._HIT else "miss"
 
-    def _on_duration(self, event: str, duration_secs: float, **_kwargs):
+    def _on_scalar(self, event: str, _value, **_kwargs):
+        if event in (self._TRACE, self._LOWER):
+            self._thread.open = getattr(self._thread, "open", 0) + 1
+
+    def _on_duration(self, event: str, duration_secs: float, **kwargs):
         if event == self._COMPILE:
             self._compile_s += duration_secs
+        stage = self._STAGES.get(event)
+        if stage is None or self._events is None:
+            return
+        labels = {}
+        if stage != "backend_compile":
+            self._thread.open = max(getattr(self._thread, "open", 1) - 1, 0)
+            if self._thread.open:
+                return  # inside another program's trace or lowering
+        else:
+            labels["cache"] = getattr(self._thread, "cache", "none")
+            self._thread.cache = "none"
+        # ``jit(step)`` and ``step`` are one program: the trace names
+        # the function, the later stages the module made from it
+        program = str(kwargs.get("fun_name", ""))
+        wrapped = re.fullmatch(r"\w+\((.*)\)", program)
+        self._events.complete(
+            "compile",
+            anchored_now() - duration_secs,
+            duration_secs,
+            program=wrapped.group(1) if wrapped else program,
+            stage=stage,
+            **labels,
+        )
 
     def snapshot(self) -> Dict[str, float]:
         return {
@@ -187,6 +250,20 @@ class CompileMeter:
             "cache_misses": self._counts[self._MISS],
             "compile_s": round(self._compile_s, 3),
         }
+
+
+_COMPILE_METER: Optional[CompileMeter] = None
+
+
+def install_compile_meter(events=None) -> CompileMeter:
+    """This process's one meter, made by the first call — which a
+    chip-owning process makes before its first compile (the top of a
+    serving replica's loop, ``init_distributed``), with the logger its
+    ``compile`` records go to.  Later calls return the same meter."""
+    global _COMPILE_METER
+    if _COMPILE_METER is None:
+        _COMPILE_METER = CompileMeter(events=events)
+    return _COMPILE_METER
 
 
 def backend_initialized() -> bool:

@@ -62,6 +62,26 @@ def anchored_now(mono: Optional[float] = None) -> float:
         mono = time.monotonic()
     return _WALL_EPOCH + (mono - _MONO_EPOCH)
 
+
+def process_start_wall() -> Optional[float]:
+    """When THIS process started, on the anchored clock: the kernel's
+    own record of it (``/proc/self/stat`` field 22, clock ticks after
+    boot, against ``CLOCK_BOOTTIME``), so the interpreter's start and
+    the imports above the first line of the program's code are on the
+    timeline without a stamp in the environment.  A forked child's
+    start is its fork.  None where the kernel does not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            # after the parenthesised command name: field 3 onward
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf(
+            "SC_CLK_TCK"
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return anchored_now() - age if age >= 0 else None
+
+
 #: Span phases, HIGHEST attribution priority first.  When spans
 #: overlap, each instant of wall clock is charged to the
 #: highest-priority covering phase.  ``step`` is the only USEFUL
@@ -100,6 +120,12 @@ PHASE_RESTORE_PREFETCH = "restore_prefetch"
 # replaces when the world changed).
 PHASE_RESHARD = "reshard"
 PHASE_FINISH_RESTORE = "finish_restore"
+# one stage of one program's way to an executable, written by
+# ``common/jax_env.CompileMeter`` from JAX's own duration events: the
+# ``trace`` to a jaxpr, the ``lower`` to a module (a Pallas kernel is
+# traced in the first and lowered in the second: both are paid whatever
+# the persistent cache holds) and the ``backend_compile``, which is the
+# compiler's run or the cache's hand-over (``cache``: hit | miss | none)
 PHASE_COMPILE = "compile"
 PHASE_AOT_COMPILE = "aot_compile"
 PHASE_RENDEZVOUS = "rendezvous"
@@ -159,6 +185,20 @@ PHASE_SERVE_REQUEST = "serve_request"
 # regression here shows up as decode-side TTFT, so it must be
 # attributable from the timeline alone).
 PHASE_KV_SHIP = "kv_ship"
+# one finished request's way out of a serving replica
+# (rl/generation_service.py ``_serving_worker_loop``): its per-position
+# rows and its result put on the response rings — what the
+# ``sched.reply`` leaf spends a finished request, as a record over the
+# whole run and not only inside a profiler's window
+PHASE_REPLY = "reply"
+# a chip-owning process from its start to its first step, one span a
+# STAGE (``STARTUP_STAGES``; a stage ends where the next begins and none
+# overlaps another in one process).  Ranks below ``step`` /
+# ``serve_step`` and below ``compile``, ``rendezvous`` and
+# ``checkpoint_restore``, which lie inside a stage and keep their
+# attribution; a restarted worker's stages lie inside the agent's
+# ``restart`` span, which keeps its own.
+PHASE_STARTUP = "startup"
 # client-side control-plane wait (a long-poll RPC parked on the
 # master).  LOWEST priority:
 # these waits are almost always nested inside rendezvous/restart
@@ -220,12 +260,40 @@ PHASES: Tuple[str, ...] = (
     PHASE_RESUME,
     PHASE_SERVE_REQUEST,
     PHASE_KV_SHIP,
+    PHASE_REPLY,
+    PHASE_STARTUP,
     PHASE_CONTROL_WAIT,
     PHASE_KERNEL_AUTOTUNE,
     PHASE_WEIGHT_PUBLISH,
     PHASE_WEIGHT_CAST,
     PHASE_ROLLOUT_ROUND,
     PHASE_TRAJECTORY,
+)
+
+#: The ``stage`` of a ``startup`` span, in the order a process passes
+#: them.  A serving replica: ``process`` (the kernel's start of the
+#: process to the entry of the program's code: interpreter and top-level
+#: imports), ``imports`` (JAX and the library's modules),
+#: ``backend_init`` (the first device query: the runtime's
+#: initialisation), ``factory`` (the model's parts), ``pool`` (the
+#: scheduler and its cache), ``weights`` (the template tree, waited
+#: for).  A training worker: ``process``, ``imports``, ``backend_init``,
+#: ``accelerate`` (strategy and parameter initialisation), ``state``
+#: (the train state, outside its ``checkpoint_restore`` child),
+#: ``first_step`` (the first step's dispatch to its completion: the
+#: span that holds the step program's ``compile`` records).
+STARTUP_STAGES = frozenset(
+    {
+        "process",
+        "imports",
+        "backend_init",
+        "factory",
+        "pool",
+        "weights",
+        "accelerate",
+        "state",
+        "first_step",
+    }
 )
 
 #: Phases that count as useful training time in the ledger.
@@ -526,6 +594,13 @@ REQUIRED_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
     # checkpoint/offload data-plane spans: staged blocks, moved
     # bytes, achieved shm throughput
     PHASE_KV_SHIP: ("blocks", "bytes", "throughput_gbps"),
+    # whose reply, and how much rode the per-position ring beside it (0
+    # for a model without per-position rows): the stall a finished
+    # request costs the loop grows with the second
+    PHASE_REPLY: ("req_id", "per_token_bytes"),
+    # a start-up span without its stage is the blip the phase exists to
+    # replace (``STARTUP_STAGES``, linted as a literal)
+    PHASE_STARTUP: ("stage",),
     PHASE_QUEUE_WAIT: ("req_id",),
     PHASE_ADMIT: ("req_id",),
     # a resume without the restored tail size can't distinguish a
@@ -598,6 +673,16 @@ OPTIONAL_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
     # ``wk``, ``wv`` -> ``wqkv``: 3 where it was made, 0 where the tree
     # came fused or is served as given)
     PHASE_WEIGHT_CAST: ("generation", "leaves_fused"),
+    # what a stage brought up: the device the runtime reported
+    # (``backend_init``), the cache's bytes (``pool``), the template
+    # tree's (``weights``), the model's parameter count (``accelerate``)
+    PHASE_STARTUP: ("device_kind", "pool_bytes", "bytes", "params"),
+    # ``CompileMeter``'s records carry all of ``program`` (JAX's
+    # ``fun_name``, without its ``jit(...)`` wrapper) and ``stage``
+    # (trace | lower | backend_compile), and ``cache`` on a
+    # ``backend_compile``; a hand-written span around a whole compile
+    # carries none
+    PHASE_COMPILE: ("program", "stage", "cache"),
 }
 
 _NO_ANNOTATION = nullcontext()
@@ -836,6 +921,17 @@ class EventLogger:
         if not self._path:
             return
         self.emit(self._record(name, "i", **labels))
+
+    def process_stage(self):
+        """The ``startup`` stage ``process`` of THIS process, written
+        where the program's own code begins: from the kernel's start of
+        the process (:func:`process_start_wall`) to now — interpreter
+        and top-level imports."""
+        born = process_start_wall()
+        if born is not None:
+            self.complete(
+                PHASE_STARTUP, born, anchored_now() - born, stage="process"
+            )
 
     @contextmanager
     def span(self, phase: str, **labels):

@@ -567,22 +567,41 @@ def _serving_worker_loop(spec) -> int:
     SIGUSR1/SIGTERM = drain (stop admitting, hand unfinished
     sequences back to the dispatcher by exiting cleanly — the
     dispatcher requeues everything it never saw complete)."""
-    import jax
+    from dlrover_tpu.observability.events import (
+        anchored_now,
+        get_event_logger,
+    )
 
-    from dlrover_tpu.agent.ckpt_shm import (
-        SharedMemoryHandler,
-        restore_to_target,
-    )
-    from dlrover_tpu.observability.events import get_event_logger
-    from dlrover_tpu.observability.metrics import (
-        Histogram,
-        record_serving,
-    )
-    from dlrover_tpu.rl.kv_cache import region_nbytes_per_block
-    from dlrover_tpu.rl.scheduler import (
-        ContinuousBatchingScheduler,
-        SchedulerConfig,
-    )
+    # the ``startup`` stages, one after the other up to READY
+    # (observability/events.py ``STARTUP_STAGES``); first what lies
+    # behind: the interpreter's start and this module's own imports
+    events = get_event_logger()
+    events.process_stage()
+    with events.span("startup", stage="imports"):
+        from dlrover_tpu.common.jax_env import (
+            device_report,
+            install_compile_meter,
+        )
+
+        # before this process's first compile: every program's trace,
+        # lowering and backend compile or cache load is a ``compile``
+        # record from here on (the meter's import of jax.monitoring is
+        # what brings JAX in)
+        install_compile_meter(events)
+        import jax
+
+        from dlrover_tpu.agent.ckpt_shm import (
+            SharedMemoryHandler,
+            restore_to_target,
+        )
+        from dlrover_tpu.observability.metrics import Histogram
+        from dlrover_tpu.ops.paged_attention import paged_kernel_backend
+        from dlrover_tpu.ops.pallas_utils import use_interpret
+        from dlrover_tpu.rl.kv_cache import region_nbytes_per_block
+        from dlrover_tpu.rl.scheduler import (
+            ContinuousBatchingScheduler,
+            SchedulerConfig,
+        )
 
     name = spec["name"]
     replica = int(spec["replica"])
@@ -607,8 +626,14 @@ def _serving_worker_loop(spec) -> int:
     for sig in (signal.SIGUSR1, signal.SIGTERM):
         signal.signal(sig, _on_signal)
 
-    factory = _import_factory(spec["factory"])
-    parts = factory(**spec.get("factory_kwargs", {}))
+    # the first device query is the runtime's initialisation: it has a
+    # stage of its own, before a factory that may make arrays
+    sid = events.begin("startup", stage="backend_init")
+    device = device_report()
+    events.end("startup", sid, device_kind=device["device_kind"])
+    with events.span("startup", stage="factory"):
+        factory = _import_factory(spec["factory"])
+        parts = factory(**spec.get("factory_kwargs", {}))
     cfg = parts.get("cfg")
     if cfg is None:
         raise RuntimeError(
@@ -621,6 +646,7 @@ def _serving_worker_loop(spec) -> int:
     # for through ``capture_logprobs=`` / a ``draft`` sub-dict
     fly = spec.get("flywheel") or {}
     draft_cfg = parts.get("draft_cfg")
+    sid = events.begin("startup", stage="pool")
     scheduler = ContinuousBatchingScheduler(
         cfg,
         SchedulerConfig(
@@ -637,13 +663,18 @@ def _serving_worker_loop(spec) -> int:
         paged_prefill_fn=parts.get("paged_prefill_fn"),
         paged_verify_fn=parts.get("paged_verify_fn"),
         serving_params_fn=parts.get("serving_params_fn"),
-        events=get_event_logger(),
+        events=events,
         replica=tag,
         role=("prefill" if role == "prefill" else "unified"),
         capture_logprobs=bool(fly.get("capture")),
         draft_cfg=draft_cfg,
     )
-    events = get_event_logger()
+    jax.block_until_ready(scheduler._pool)
+    # the cache as the program sized it: every leaf of the pool
+    # (``k``, ``v``; ``wk``, ``wv`` of the layers with a window; lane
+    # state; further paged leaves) and their bytes together
+    pool = scheduler.pool_report()
+    events.end("startup", sid, pool_bytes=pool["pool_bytes"])
     ttft_hist = Histogram()
     # chaos seam of the health tests (spec["faults"], keyed by
     # replica index): "sleep_s" stalls every scheduler iteration (an
@@ -660,6 +691,8 @@ def _serving_worker_loop(spec) -> int:
     # owns a resident copy in the model's compute dtype, made by
     # ``sync_weights`` once per adoption (the same arrays where the
     # dtypes already agree).
+    sid = events.begin("startup", stage="weights")
+    template = parts["params_template_fn"]()
     if draft_cfg is not None:
         # draft mode: the publish segment carries ONE combined
         # {"policy", "draft"} tree, restored onto a combined template.
@@ -667,13 +700,19 @@ def _serving_worker_loop(spec) -> int:
         # (sync_weights without draft params) — the random-init draft
         # template is never decoded with.
         template = {
-            "policy": parts["params_template_fn"](),
+            "policy": template,
             "draft": parts["draft_template_fn"](),
         }
-        scheduler.sync_weights(template["policy"])
-    else:
-        template = parts["params_template_fn"]()
-        scheduler.sync_weights(template)
+    # waited for inside its stage: ``sync_weights`` consumes the tree
+    # next, so no overlap is lost
+    jax.block_until_ready(template)
+    events.end(
+        "startup", sid,
+        bytes=sum(a.nbytes for a in jax.tree_util.tree_leaves(template)),
+    )
+    scheduler.sync_weights(
+        template["policy"] if draft_cfg is not None else template
+    )
 
     shm = SharedMemoryHandler(rank=0, name=name)
     req_ring = _Ring(f"{tag}-req")
@@ -882,11 +921,7 @@ def _serving_worker_loop(spec) -> int:
             ),
         )
 
-    from dlrover_tpu.common.jax_env import device_report
-    from dlrover_tpu.ops.paged_attention import paged_kernel_backend
-    from dlrover_tpu.ops.pallas_utils import use_interpret
-
-    device = device_report()
+    # the mark of READY: "engine up", from inside
     events.instant(
         "device_report",
         platform=device["platform"],
@@ -895,10 +930,7 @@ def _serving_worker_loop(spec) -> int:
         replica=tag,
         kernel_backend=paged_kernel_backend(),
         interpret=use_interpret(),
-        # the cache as the program sized it: every leaf of the pool
-        # (``k``, ``v``; ``wk``, ``wv`` of the layers with a window;
-        # lane state; further paged leaves) and their bytes together
-        **scheduler.pool_report(),
+        **pool,
     )
     # READY carries the per-block region size so the dispatcher can
     # size the ship arena without instantiating the model itself
@@ -1009,7 +1041,16 @@ def _serving_worker_loop(spec) -> int:
             for res in finished:
                 served += 1
                 window_tokens += res.new_tokens
-                _flush_result(res)
+                # one record a finished request: what the leaf around
+                # it spends on the request's way out, over the whole run
+                with events.span(
+                    "reply",
+                    req_id=res.req_id,
+                    per_token_bytes=sum(
+                        a.nbytes for a in res.per_token.values()
+                    ),
+                ):
+                    _flush_result(res)
             if scheduler.shipped:
                 # prefill worker: stage each completed prefill's KV
                 # blocks in its reserved arena slot and hand the manifest
@@ -1018,6 +1059,7 @@ def _serving_worker_loop(spec) -> int:
                     slot = pending_ship.pop(rec["req_id"], -1)
                     if slot < 0:
                         continue  # locally-submitted on a prefill role
+                    ship_wall = anchored_now()
                     t0 = time.perf_counter()
                     k_b = rec["k"].tobytes()
                     v_b = rec["v"].tobytes()
@@ -1030,20 +1072,11 @@ def _serving_worker_loop(spec) -> int:
                     nbytes = len(k_b) + len(v_b)
                     events.complete(
                         "kv_ship",
-                        time.time() - ship_s,
+                        ship_wall,
                         ship_s,
                         blocks=int(rec["n_blocks"]),
                         bytes=nbytes,
                         throughput_gbps=round(nbytes / ship_s / 1e9, 3),
-                    )
-                    from dlrover_tpu.observability.metrics import (
-                        get_registry,
-                    )
-
-                    get_registry().inc_counter(
-                        "dlrover_tpu_serving_kv_shipped_blocks_total",
-                        int(rec["n_blocks"]),
-                        labels={"replica": tag},
                     )
                     window_tokens += rec["prompt_len"]
                     _respond(
@@ -1060,18 +1093,9 @@ def _serving_worker_loop(spec) -> int:
             if now - window_t0 >= 1.0:
                 tps = window_tokens / (now - window_t0)
                 st = scheduler.stats()
-                record_serving(
-                    replica=tag,
-                    tokens_per_s=tps,
-                    queue_depth=scheduler.queue_depth,
-                    kv_blocks_used=scheduler.block_pool.used_blocks,
-                    kv_utilization=st["kv_utilization"],
-                    preemptions=st["preemptions"],
-                    prefix_hit_rate=st["prefix_hit_rate"],
-                    accepted_tokens_per_step=st["accepted_per_step"],
-                )
-                # the dispatcher-side serving pane reads the same numbers
-                # off the response ring (best-effort); the replica's
+                # the dispatcher records the serving gauges from these
+                # numbers (``_dispatch_once``: it is the process with a
+                # registry somebody reads); the replica's
                 # shared-block key index and its cumulative prefix
                 # counters ride along — the affinity router's whole
                 # view, no extra RPC

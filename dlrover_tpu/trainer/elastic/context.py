@@ -49,29 +49,36 @@ class ElasticContext:
 
 
 _context: Optional[ElasticContext] = None
+_started = False  # this process's ``process`` stage is on the timeline
 
 
-def init_distributed(initialize_jax: bool = True) -> ElasticContext:
-    """Initialize multi-process JAX from the agent-provided env.
+def _bring_up_jax(rank: int, world: int, coord: str):
+    """JAX, the device world and the backend, each under its ``startup``
+    stage (observability/events.py ``STARTUP_STAGES``): ``process``
+    (once a process) is what lies behind — the interpreter's start and the
+    worker script's imports up to here — ``imports`` brings JAX in,
+    ``backend_init`` is the first device query, which is the runtime's
+    initialisation and would else hide in whatever touches a device
+    first.  The process's compile meter is installed before anything can
+    compile: every program's trace, lowering and backend compile or
+    cache load is a ``compile`` record from here on."""
+    global _started
+    from dlrover_tpu.observability.events import get_event_logger
 
-    Safe to call when launched standalone (single process, no
-    coordinator): it becomes a no-op world of size 1.
-    """
-    global _context
-    if _context is not None:
-        return _context
-    rank = process_rank()
-    world = process_count()
-    coord = os.getenv(NodeEnv.COORDINATOR_ADDR, "")
-    if initialize_jax and world > 1 and coord:
+    events = get_event_logger()
+    if not _started:
+        events.process_stage()
+    _started = True
+    with events.span("startup", stage="imports"):
+        from dlrover_tpu.common.jax_env import install_compile_meter
+
+        install_compile_meter(events)
         import jax
-
-        from dlrover_tpu.observability.events import get_event_logger
-
+    if world > 1 and coord:
         # trainer-side rendezvous: connecting to the coordinator and
         # assembling the device world is restart overhead the goodput
         # ledger must see
-        with get_event_logger().span("rendezvous"):
+        with events.span("rendezvous"):
             jax.distributed.initialize(
                 coordinator_address=coord,
                 num_processes=world,
@@ -83,6 +90,26 @@ def init_distributed(initialize_jax: bool = True) -> ElasticContext:
             world,
             coord,
         )
+    sid = events.begin("startup", stage="backend_init")
+    kind = jax.devices()[0].device_kind
+    events.end("startup", sid, device_kind=kind)
+
+
+def init_distributed(initialize_jax: bool = True) -> ElasticContext:
+    """Initialize multi-process JAX from the agent-provided env.
+
+    Safe to call when launched standalone (single process, no
+    coordinator): it becomes a world of size 1, whose backend is up
+    when this returns (``initialize_jax=False`` leaves JAX alone).
+    """
+    global _context
+    if _context is not None:
+        return _context
+    rank = process_rank()
+    world = process_count()
+    coord = os.getenv(NodeEnv.COORDINATOR_ADDR, "")
+    if initialize_jax:
+        _bring_up_jax(rank, world, coord)
     _context = ElasticContext(
         rank=rank,
         world_size=world,

@@ -309,6 +309,10 @@ class Trainer:
         # synchronous leg; both are no-ops without an events file
         self._events = get_event_logger()
         self._step_span_from = None  # perf_counter of the last step done
+        # the ``startup`` stage ``first_step``, open from the first
+        # step's dispatch (its program's resolution and compile before
+        # it) to its completion in _consume_metrics
+        self._first_step_sid = None
         # the remat policy the step runs under, once the first batch's
         # shape has resolved it: a label of every ``step`` span
         self._remat = None
@@ -627,6 +631,9 @@ class Trainer:
 
     def _consume_metrics(self, step: int, metrics, batch) -> float:
         loss = float(metrics["loss"])  # syncs on step completion
+        if self._first_step_sid is not None:
+            self._events.end("startup", self._first_step_sid)
+            self._first_step_sid = None
         now = time.perf_counter()
         prev_done = self._last_done
         dt = now - prev_done
@@ -864,7 +871,8 @@ class Trainer:
     def train(self):
         from dlrover_tpu.data.prefetch import device_prefetch
 
-        start_step = self._init_or_restore_state()
+        with self._events.span("startup", stage="state"):
+            start_step = self._init_or_restore_state()
         if self._exporter is not None:
             self._exporter.start()
         self._hang.start()
@@ -916,6 +924,12 @@ class Trainer:
                     if step >= self._args.max_steps:
                         break
                     if step == start_step:
+                        # the stage that holds the step program's
+                        # ``compile`` records: a ``step`` span exists
+                        # only from the second step on
+                        self._first_step_sid = self._events.begin(
+                            "startup", stage="first_step"
+                        )
                         self._resolve_remat(batch)
                     open_mode = None
                     if tracing_left == 0:

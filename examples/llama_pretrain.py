@@ -156,11 +156,13 @@ def device_report_callback(meter, fns, batch_shape, mesh_devices):
 def main():
     args = parse_args()
 
-    from dlrover_tpu.common.jax_env import CompileMeter
+    from dlrover_tpu.common.jax_env import install_compile_meter
     from dlrover_tpu.trainer.elastic import init_distributed
 
-    meter = CompileMeter()  # before the first compile of this process
     ctx = init_distributed()
+    # the process's one meter, which ``init_distributed`` installed
+    # before the first compile of this process
+    meter = install_compile_meter()
 
     import jax
 

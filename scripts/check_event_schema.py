@@ -18,6 +18,9 @@ every call to an event-logger method (``span`` / ``begin`` / ``end`` /
   its sites may pass the required and the optional labels, no others
   (``serve_step``'s host-time partition: a typo'd ``admit_ms`` would
   silently drop out of the metric that reads it);
+- a ``startup`` span's ``stage`` is a string literal of
+  ``STARTUP_STAGES``: the set-up readers sum the stages by name, and a
+  typo'd stage would fall into no part of the split;
 - ``leaf()`` — the profiler-only annotation of a per-iteration phase —
   names a declared leaf (``LEAF_ANNOTATIONS``) or a declared phase;
 - ``named_scope()`` — the name ``jax.named_scope`` puts on the device
@@ -46,6 +49,7 @@ from dlrover_tpu.observability.events import (  # noqa: E402
     PHASES,
     REQUIRED_INSTANT_LABELS,
     REQUIRED_SPAN_LABELS,
+    STARTUP_STAGES,
 )
 
 EMIT_METHODS = {"span", "begin", "end", "complete", "instant", "leaf"}
@@ -173,11 +177,6 @@ DECLARED_METRICS = {
     # per-replica health verdict gauge (ServingHealthEngine):
     # 1 ok .. 0.1 dead_air, mirroring dlrover_tpu_node_health
     "dlrover_tpu_serving_health",
-    # disaggregated prefill/decode (ISSUE 17,
-    # DLROVER_TPU_FLEET_PREFILL_WORKERS): KV blocks a prefill worker
-    # filled and shipped through the shm block arena for a decode
-    # replica to adopt — each increment pairs with a kv_ship span
-    "dlrover_tpu_serving_kv_shipped_blocks_total",
     # paged-attention kernel autotuner (ops/autotune.py): the winning
     # candidate's best-of-reps wall time for one (kernel, shape) key,
     # labeled {kernel, backend} — each sample pairs with a
@@ -368,6 +367,20 @@ def check_file(path: str):
                         f"{where}: {method}({phase!r}) passes "
                         f"undeclared label(s) {unknown} (the phase's "
                         "label set is closed: OPTIONAL_SPAN_LABELS)"
+                    )
+            if phase == "startup" and not has_splat:
+                stage = next(
+                    (kw.value for kw in node.keywords if kw.arg == "stage"),
+                    None,
+                )
+                if stage is not None and not (
+                    isinstance(stage, ast.Constant)
+                    and stage.value in STARTUP_STAGES
+                ):
+                    violations.append(
+                        f"{where}: {method}('startup') stage must be a "
+                        "string literal of STARTUP_STAGES "
+                        f"({sorted(STARTUP_STAGES)})"
                     )
             # retry-storm visibility: a control_wait span opened as a
             # retry pause must carry the attempt ordinal, or storms

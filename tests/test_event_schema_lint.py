@@ -543,26 +543,29 @@ def test_lint_enforces_fleet_routing_labels(tmp_path):
     )
 
 
-def test_lint_declares_kv_ship_counter():
-    """The shipped-blocks counter is declared vocabulary; an
-    in-package near-miss typo is not."""
+def test_lint_no_longer_declares_the_kv_ship_counter():
+    """The shipped-blocks counter went with its only emit site (PR 55:
+    the replica's registry is exported by nobody; a ship's blocks are
+    the ``kv_ship`` span's ``blocks`` label): an in-package site that
+    brings the name back is refused until it is declared again, and the
+    fleet's declared gauges still pass."""
     probe = os.path.join(
         REPO, "dlrover_tpu", "_lint_probe_ship_delete_me.py"
     )
     with open(probe, "w") as f:
         f.write(
             "def f(reg):\n"
+            "    reg.set_gauge("
+            "'dlrover_tpu_serving_prefix_hit_rate', 0.5)\n"
             "    reg.inc_counter("
             "'dlrover_tpu_serving_kv_shipped_blocks_total', 3)\n"
-            "    reg.inc_counter("
-            "'dlrover_tpu_serving_kv_shiped_blocks_total', 3)\n"
         )
     try:
         proc = _run(probe)
         assert proc.returncode == 1, proc.stdout + proc.stderr
         assert "event_schema_violations=1" in proc.stdout, proc.stdout
         assert (
-            "dlrover_tpu_serving_kv_shiped_blocks_total"
+            "dlrover_tpu_serving_kv_shipped_blocks_total"
             in proc.stdout
         )
     finally:
@@ -737,3 +740,54 @@ def test_lint_declares_flywheel_metrics():
         assert "dlrover_tpu_flywheel_publish_stalls" in proc.stdout
     finally:
         os.unlink(probe)
+
+
+def test_lint_enforces_startup_stage_and_labels(tmp_path):
+    """A ``startup`` span names its stage with a literal of
+    ``STARTUP_STAGES`` (the set-up readers sum the stages by name) and
+    carries no label outside the phase's closed set."""
+    bad = tmp_path / "bad_startup.py"
+    bad.write_text(
+        "events = None\n"
+        "def f(events, stage):\n"
+        "    events.span('startup')\n"                   # no stage
+        "    events.span('startup', stage='warmup')\n"    # undeclared
+        "    events.begin('startup', stage=stage)\n"      # not a literal
+        "    events.begin('startup', stage='pool', blocks=3)\n"
+        "    events.complete('startup', 0.0, 1.0, stage='process')\n"
+        "    events.begin('startup', stage='backend_init')\n"
+        "    events.end('startup', 1, device_kind='cpu')\n"
+        "    events.span('startup', stage='weights', bytes=1)\n"
+    )
+    proc = _run(str(bad))
+    assert proc.returncode == 1, proc.stdout + proc.stderr
+    assert "event_schema_violations=4" in proc.stdout, proc.stdout
+    assert "missing required label(s) ['stage']" in proc.stdout
+    assert proc.stdout.count("string literal of STARTUP_STAGES") == 2
+    assert "undeclared label(s) ['blocks']" in proc.stdout
+
+
+def test_lint_enforces_reply_and_compile_labels(tmp_path):
+    """A ``reply`` span says whose reply it is and what rode beside it;
+    a ``compile`` record's label set is closed (``program``, ``stage``,
+    ``cache``), and a hand-made span around a whole compile may carry
+    none of them."""
+    bad = tmp_path / "bad_reply_compile.py"
+    bad.write_text(
+        "events = None\n"
+        "def f(events):\n"
+        "    events.span('reply', req_id=1)\n"
+        "    events.span('reply', req_id=1, per_token_bytes=0)\n"
+        "    events.complete('compile', 0.0, 1.0, program='f',\n"
+        "                    stage='trace', cached=True)\n"
+        "    events.complete('compile', 0.0, 1.0, program='f',\n"
+        "                    stage='backend_compile', cache='hit')\n"
+        "    events.span('compile')\n"
+    )
+    proc = _run(str(bad))
+    assert proc.returncode == 1, proc.stdout + proc.stderr
+    assert "event_schema_violations=2" in proc.stdout, proc.stdout
+    assert (
+        "missing required label(s) ['per_token_bytes']" in proc.stdout
+    )
+    assert "undeclared label(s) ['cached']" in proc.stdout
