@@ -80,6 +80,26 @@ operations a key against the absorbed form's ``2 * H * (576 + 512)``.
 
 The layers differ (a dense MLP, then experts), so they are unrolled,
 each with its own leaves (``params["layers"]`` is a tuple of dicts).
+What the two step programs' loops call for a layer, though, are
+``jax.jit``-wrapped pieces: ONE set for the attention of all layers —
+the latent row's write, the indexer, the attention over what it picked,
+each piece a sub-scope of ``attn``; a layer's attention leaves, the
+flat paged leaves and the layer's offsets are its arguments — and one
+``mlp`` (``_Leaves.walk``), which JAX traces once for the dense layers
+and once for the expert layers because their leaves differ.  JAX finds
+the later layers' calls in its trace cache and lowers one private
+function a piece and kind, which the module calls a layer; XLA inlines
+the calls.  A replica so traces and lowers one layer's attention and
+two MLPs a program instead of every layer at EVERY start, whatever the
+compile cache holds (``PERF.md`` section 6, PR 56: the unrolled loops
+cost ~21 s of every start; what XLA compiles from the calls is the
+unrolled program but for its scheduler's choices).  The pieces are made
+INSIDE the step program's call and die with its trace: JAX keys a trace
+by function and argument types, not by what the function looks up, so a
+piece kept at module level would hand the NEXT program the indexer it
+traced before ``ops.paged_attention.decode_index_scores`` was replaced
+— which the benchmark's planted faults and the tests do between two
+traces (``tests/test_deepseek_v32_blocks.py``).
 There is no training path.
 """
 
@@ -595,10 +615,24 @@ def _route(x, lp, cfg: DeepSeekV32Config):
     return hf.astype(cfg.dtype), ids, w * cfg.routed_scaling_factor
 
 
+_MLP_LEAVES = (
+    "mlp_norm", "mlp_gate", "mlp_up", "mlp_down", "router", "router_bias",
+    "shared_gate", "shared_up", "shared_down", "w_gate", "w_up", "w_down",
+)
+
+
+def _mlp_leaves(lp):
+    """The leaves of a layer that :func:`_mlp` reads — it takes them
+    through here, so one left off the list fails there by its name; the
+    others, under the same names and shapes in every layer, are its
+    attention's (``_Leaves.walk`` splits a layer so)."""
+    return {n: lp[n] for n in _MLP_LEAVES if n in lp}
+
+
 def _mlp(x, lp, cfg: DeepSeekV32Config, backend: str = "jnp"):
     """``x [N, D]`` -> (``x + MLP(RMSNorm(x))``, the experts chosen
     ``[N, k]`` or None for a dense layer)."""
-    dt = cfg.dtype
+    dt, lp = cfg.dtype, _mlp_leaves(lp)
     if "router" not in lp:
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         return x + _swiglu(
@@ -612,6 +646,8 @@ def _mlp(x, lp, cfg: DeepSeekV32Config, backend: str = "jnp"):
         first_expert=cfg.first_expert, held=cfg.held_experts,
     ).astype(dt)
     return x + y, ids
+
+
 
 
 # ------------------------------------------------------- whole sequences
@@ -709,8 +745,36 @@ class _Leaves:
             self.flat,
         )
 
-    def keep(self, kv):
-        self.flat = kv.paged
+    def walk(self, layers, x, attention, mlp, cfg):
+        """The layers in turn through a step program's jitted pieces
+        (the module docstring says why jitted, and why anew for every
+        program): ``attention(x, a layer's attention leaves, its
+        LayerPool) -> (x, paged leaves, selection)``, which calls the
+        pieces of attention, and the jitted ``mlp(x, a layer's MLP
+        leaves) -> (x, experts chosen)``, which JAX traces once for the
+        dense layers and once for the expert layers, whose leaves
+        differ.  -> (x, the program's per-position rows).
+
+        A piece's scopes are entered on BOTH sides of its call, because
+        the inliner hands on a path in two ways.  What XLA itself makes
+        inside an inlined piece (fusions, copies) carries the CALL's
+        path and nothing else: the caller enters part and sub-part
+        around the call.  An operation inside a loop or a branch of a
+        piece keeps the path it has INSIDE the piece and loses the
+        caller's: the piece enters role, part and sub-part itself."""
+        chosen, picked = [], []
+        for i, lp in enumerate(layers):
+            of_mlp = _mlp_leaves(lp)
+            of_attn = {n: w for n, w in lp.items() if n not in of_mlp}
+            x, self.flat, taken = attention(x, of_attn, self.layer(i))
+            with jax.named_scope("mlp"):
+                x, ids = mlp(x, of_mlp)
+            chosen.append(ids)
+            picked.append(taken)
+        return x, {
+            "experts": _stack_experts(chosen, cfg),
+            "selection": jnp.stack(picked, axis=1),
+        }
 
     def stacked(self) -> Dict:
         return {
@@ -786,53 +850,86 @@ def paged_prefill_chunk(
                 cfg.softmax_scale, backend,
             ), pack_selection(taken, cfg.selection_words)
 
-    chosen, picked = [], []
-    for i, lp in enumerate(params["layers"]):
-        kv = leaves.layer(i)
+    # a layer's attention, one jitted piece a sub-scope: each is traced
+    # for the first layer and found for the others (module docstring);
+    # what differs by layer is an argument, what does not is closed over
+    @jax.jit
+    @jax.named_scope("prefill")
+    @jax.named_scope("attn")
+    @jax.named_scope("latent")
+    def write_rows(x, lp, kv):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_pe, c_q = _queries(h, lp, cfg)
+        q = jnp.concatenate(
+            [q_nope, _rotate(q_pe, cos[:, None], sin[:, None])], axis=-1
+        )
+        c_kv, k_pe = _latent_row(h, lp, cfg, cos, sin)
+        kv = kv.write_leaf_run(
+            "c", c_kv, block_table, start_pos
+        ).write_leaf_run("kpe", k_pe, block_table, start_pos)
+        return h, c_q, q, kv.paged
+
+    @jax.jit
+    @jax.named_scope("prefill")
+    @jax.named_scope("attn")
+    @jax.named_scope("indexer")
+    def index(h, c_q, lp, kv):
+        qi, ik, w = _indexer_inputs(h, c_q, lp, cfg)
+        qi = _rotate_lead(qi, cos[:, None], sin[:, None], dr)
+        kv = kv.write_leaf_run(
+            "ik", _rotate_lead(ik, cos, sin, dr), block_table, start_pos
+        )
+        keys = gather_index_keys(
+            kv.paged["ik"], kv.tables(block_table), cfg.index_head_dim
+        )
+        return qi, w, keys, kv.paged
+
+    @jax.jit
+    @jax.named_scope("prefill")
+    @jax.named_scope("attn")
+    def attend_width(q, qi, w, keys, lp, kv):
+        # the sequence's rows by position, ONCE: a branch that took
+        # the pool itself had it copied into it
         table = kv.tables(block_table)
+        w_uk, w_uv = _kv_up(lp, cfg)
+        return lax.switch(
+            bucket,
+            [partial(attend, width) for width in widths],
+            q, qi, w, keys,
+            kv.paged["c"][table].reshape(mb * bs, rank),
+            kv.paged["kpe"][table].reshape(mb * bs, dr),
+            w_uk.astype(dt), w_uv.astype(dt),
+        )
+
+    @jax.jit
+    @jax.named_scope("prefill")
+    @jax.named_scope("attn")
+    @jax.named_scope("latent")
+    def project(x, attn, lp):
+        return x + _proj(attn.reshape(c, -1), lp["wo"], dt)
+
+    def attention(x, lp, kv):
         with jax.named_scope("attn"), jax.named_scope("latent"):
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_pe, c_q = _queries(h, lp, cfg)
-            q = jnp.concatenate(
-                [q_nope, _rotate(q_pe, cos[:, None], sin[:, None])], axis=-1
-            )
-            c_kv, k_pe = _latent_row(h, lp, cfg, cos, sin)
-            kv = kv.write_leaf_run(
-                "c", c_kv, block_table, start_pos
-            ).write_leaf_run("kpe", k_pe, block_table, start_pos)
+            h, c_q, q, paged = write_rows(x, lp, kv)
         with jax.named_scope("attn"), jax.named_scope("indexer"):
-            qi, ik, w = _indexer_inputs(h, c_q, lp, cfg)
-            qi = _rotate_lead(qi, cos[:, None], sin[:, None], dr)
-            kv = kv.write_leaf_run(
-                "ik", _rotate_lead(ik, cos, sin, dr), block_table, start_pos
-            )
-            keys = gather_index_keys(
-                kv.paged["ik"], table, cfg.index_head_dim
-            )
+            qi, w, keys, paged = index(h, c_q, lp, kv._replace(paged=paged))
         with jax.named_scope("attn"):
-            # the sequence's rows by position, ONCE: a branch that took
-            # the pool itself had it copied into it
-            w_uk, w_uv = _kv_up(lp, cfg)
-            attn, taken = lax.switch(
-                bucket,
-                [partial(attend, width) for width in widths],
-                q, qi, w, keys,
-                kv.paged["c"][table].reshape(mb * bs, rank),
-                kv.paged["kpe"][table].reshape(mb * bs, dr),
-                w_uk.astype(dt), w_uv.astype(dt),
+            attn, taken = attend_width(
+                q, qi, w, keys, lp, kv._replace(paged=paged)
             )
             with jax.named_scope("latent"):
-                x = x + _proj(attn.reshape(c, -1), lp["wo"], dt)
-        leaves.keep(kv)
-        with jax.named_scope("mlp"):
-            x, ids = _mlp(x, lp, cfg, backend)
-        chosen.append(ids)
-        picked.append(taken)
+                x = project(x, attn, lp)
+        return x, paged, taken
+
+    @jax.jit
+    @jax.named_scope("prefill")
+    @jax.named_scope("mlp")
+    def mlp(x, lp):
+        return _mlp(x, lp, cfg, backend)
+
+    x, rows = leaves.walk(params["layers"], x, attention, mlp, cfg)
     return (
-        _logits(x[None], params, cfg),
-        {**pool, **leaves.stacked()},
-        {"experts": _stack_experts(chosen, cfg),
-         "selection": jnp.stack(picked, axis=1)},
+        _logits(x[None], params, cfg), {**pool, **leaves.stacked()}, rows
     )
 
 
@@ -888,47 +985,70 @@ def paged_decode_step(
         seq_lens = jnp.where(active, positions + 1, 1)
         held = jnp.where(active, positions + 1, 0)  # what attention reads
         n_sel = min(cfg.index_topk, mb * bs)
-    chosen, picked = [], []
-    for i, lp in enumerate(params["layers"]):
-        kv = leaves.layer(i)
+
+    # a layer's attention in jitted pieces, as the chunk's
+    @jax.jit
+    @jax.named_scope("decode")
+    @jax.named_scope("attn")
+    @jax.named_scope("latent")
+    def write_rows(x, lp, kv):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_pe, c_q = _queries(h, lp, cfg)
+        q_c = _per_head(q_nope, _kv_up(lp, cfg)[0], dt)
+        q_pe = _rotate(q_pe, cos[:, None], sin[:, None])
+        c_kv, k_pe = _latent_row(h, lp, cfg, cos, sin)
+        kv = kv.write_leaf_rows("c", c_kv, blk, off)
+        kv = kv.write_leaf_rows("kpe", k_pe, blk, off)
+        return h, c_q, q_c, q_pe, kv.paged
+
+    @jax.jit
+    @jax.named_scope("decode")
+    @jax.named_scope("attn")
+    @jax.named_scope("indexer")
+    def index(h, c_q, lp, kv):
         tables = kv.tables(block_tables)
+        qi, ik, w = _indexer_inputs(h, c_q, lp, cfg)
+        qi = _rotate_lead(qi, cos[:, None], sin[:, None], dr)
+        kv = kv.write_leaf("ik", _rotate_lead(ik, cos, sin, dr), blk, off)
+        keys = gather_index_keys(kv.paged["ik"], tables, cfg.index_head_dim)
+        # a mask over the positions where attention reads a lane's
+        # blocks itself, the rows to gather beside it under a table
+        # much wider than what is picked
+        sel = latent_decode_selection(
+            decode_index_scores(qi, w, keys, seq_lens), n_sel, tables
+        )
+        return sel, pack_selection(sel.taken, cfg.selection_words), kv.paged
+
+    @jax.jit
+    @jax.named_scope("decode")
+    @jax.named_scope("attn")
+    @jax.named_scope("latent")
+    def attend(x, q_c, q_pe, sel, lp, kv):
+        latent = latent_decode_attention(
+            q_c, q_pe, kv.paged["c"], kv.paged["kpe"],
+            kv.tables(block_tables), held, sel, cfg.softmax_scale, backend,
+        )
+        attn = _per_head(latent, _kv_up(lp, cfg)[1], dt)
+        return x + _proj(attn.reshape(n, -1), lp["wo"], dt)
+
+    def attention(x, lp, kv):
         with jax.named_scope("attn"), jax.named_scope("latent"):
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_pe, c_q = _queries(h, lp, cfg)
-            w_uk, w_uv = _kv_up(lp, cfg)
-            q_c = _per_head(q_nope, w_uk, dt)
-            q_pe = _rotate(q_pe, cos[:, None], sin[:, None])
-            c_kv, k_pe = _latent_row(h, lp, cfg, cos, sin)
-            kv = kv.write_leaf_rows("c", c_kv, blk, off)
-            kv = kv.write_leaf_rows("kpe", k_pe, blk, off)
+            h, c_q, q_c, q_pe, paged = write_rows(x, lp, kv)
         with jax.named_scope("attn"), jax.named_scope("indexer"):
-            qi, ik, w = _indexer_inputs(h, c_q, lp, cfg)
-            qi = _rotate_lead(qi, cos[:, None], sin[:, None], dr)
-            kv = kv.write_leaf("ik", _rotate_lead(ik, cos, sin, dr), blk, off)
-            keys = gather_index_keys(
-                kv.paged["ik"], tables, cfg.index_head_dim
-            )
-            # a mask over the positions where attention reads a lane's
-            # blocks itself, the rows to gather beside it under a table
-            # much wider than what is picked
-            sel = latent_decode_selection(
-                decode_index_scores(qi, w, keys, seq_lens), n_sel, tables
-            )
-            picked.append(pack_selection(sel.taken, cfg.selection_words))
+            sel, taken, paged = index(h, c_q, lp, kv._replace(paged=paged))
         with jax.named_scope("attn"), jax.named_scope("latent"):
-            latent = latent_decode_attention(
-                q_c, q_pe, kv.paged["c"], kv.paged["kpe"], tables, held,
-                sel, cfg.softmax_scale, backend,
-            )
-            attn = _per_head(latent, w_uv, dt)
-            x = x + _proj(attn.reshape(n, -1), lp["wo"], dt)
-        leaves.keep(kv)
-        with jax.named_scope("mlp"):
-            x, ids = _mlp(x, lp, cfg, backend)
-        chosen.append(ids)
+            x = attend(x, q_c, q_pe, sel, lp, kv._replace(paged=paged))
+        return x, paged, taken
+
+    @jax.jit
+    @jax.named_scope("decode")
+    @jax.named_scope("mlp")
+    def mlp(x, lp):
+        return _mlp(x, lp, cfg, backend)
+
+    x, rows = leaves.walk(params["layers"], x, attention, mlp, cfg)
     return (
         _logits(x[:, None], params, cfg)[:, 0],
         {**pool, **leaves.stacked()},
-        {"experts": _stack_experts(chosen, cfg),
-         "selection": jnp.stack(picked, axis=1)},
+        rows,
     )

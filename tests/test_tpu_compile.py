@@ -1137,20 +1137,15 @@ def test_two_kinded_block_carries_both_pools_in_place(program, one_chip):
     assert "ragged-dot" not in text
 
 
-@pytest.mark.parametrize(
-    "program", ["decode", "prefill_nohead", "prefill_last"]
-)
-def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
-    """DeepSeek-V3.2's step programs at ``deepseek-v32-rollout-c32-
-    reason8k``'s geometry (the published widths at 1 dense + 6 expert
-    layers, 8 of 256 experts held, an eighth of the vocabulary; 32
-    lanes, tables of 512 entries, chunk 512): the pool is the latents,
-    the rotated shared keys and the index keys ALONE — no ``k``, no
-    ``v`` — 1408 bytes a token and layer, every leaf aliased to the
-    outputs and never moved (the rows' views ``[L * blocks * 16, 512]``
-    and ``[L * blocks * 8, 128]`` are merges of leading axes), no
-    layer's ``[8, 7168, 2048]`` expert matrices copied, and each kernel
-    under the name a trace tells it by."""
+_LATENT_COMPILED = {}
+
+
+def _latent_step_compiled(program, one_chip):
+    """``(compiled program, pool specs)`` of one of DeepSeek-V3.2's three
+    step programs at ``deepseek-v32-rollout-c32-reason8k``'s geometry,
+    compiled once a module run: two tests read it."""
+    if program in _LATENT_COMPILED:
+        return _LATENT_COMPILED[program]
     from dlrover_tpu.models import deepseek_v32
     from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV
     from dlrover_tpu.rl.kv_cache import init_block_pool, paged_cache_config
@@ -1176,12 +1171,6 @@ def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
     pool = jax.tree_util.tree_map(
         spec, jax.eval_shape(lambda: init_block_pool(cache))
     )
-    assert set(pool) == {"c", "kpe", "ik"}
-    assert pool["c"].shape == (7, 18240, 16, 512)
-    assert pool["kpe"].shape == (7, 18240, 8, 128)
-    assert pool["ik"].shape == (7, 18240, 16 * 128)
-    pool_bytes = sum(math.prod(a.shape) * 2 for a in pool.values())
-    assert pool_bytes == 7 * 18240 * 16 * 1408
     if program == "decode":
         fn, rest = _scheduler_decode(
             partial(deepseek_v32.paged_decode_step, cfg=cfg), 32, 512, True
@@ -1199,6 +1188,31 @@ def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
         compiled = jax.jit(fn, donate_argnums=(2,)).lower(
             params, tokens, pool, *after
         ).compile()
+    _LATENT_COMPILED[program] = compiled, pool
+    return compiled, pool
+
+
+@pytest.mark.parametrize(
+    "program", ["decode", "prefill_nohead", "prefill_last"]
+)
+def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
+    """DeepSeek-V3.2's step programs at ``deepseek-v32-rollout-c32-
+    reason8k``'s geometry (the published widths at 1 dense + 6 expert
+    layers, 8 of 256 experts held, an eighth of the vocabulary; 32
+    lanes, tables of 512 entries, chunk 512): the pool is the latents,
+    the rotated shared keys and the index keys ALONE — no ``k``, no
+    ``v`` — 1408 bytes a token and layer, every leaf aliased to the
+    outputs and never moved (the rows' views ``[L * blocks * 16, 512]``
+    and ``[L * blocks * 8, 128]`` are merges of leading axes), no
+    layer's ``[8, 7168, 2048]`` expert matrices copied, and each kernel
+    under the name a trace tells it by."""
+    compiled, pool = _latent_step_compiled(program, one_chip)
+    assert set(pool) == {"c", "kpe", "ik"}
+    assert pool["c"].shape == (7, 18240, 16, 512)
+    assert pool["kpe"].shape == (7, 18240, 8, 128)
+    assert pool["ik"].shape == (7, 18240, 16 * 128)
+    pool_bytes = sum(math.prod(a.shape) * 2 for a in pool.values())
+    assert pool_bytes == 7 * 18240 * 16 * 1408
     mem, text = compiled.memory_analysis(), compiled.as_text()
     assert mem.alias_size_in_bytes >= pool_bytes
     # a chunk holds the keys and values it decompressed (8192 positions
@@ -1232,6 +1246,92 @@ def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
     assert kernel("mla_prefill") == (program != "decode")
     assert kernel("index_scores") == (program != "decode")
     assert "ragged-dot" not in text
+
+
+# what the UNROLLED loop's programs read (the parent of PR 56, the same
+# case compiled from its tree): kernels by name, bytes of arguments, of
+# outputs aliased to them, and of temporaries — and the temporaries of
+# the programs whose loop calls jitted pieces, which are this tree's
+_UNROLLED = {
+    "decode": (
+        {"rmsnorm_fwd": 23, "mla_sparse_decode": 7, "moe_expert_ffn": 6},
+        11748319744, 2876375040, 102251008, 103799296,
+    ),
+    "prefill_nohead": (
+        {"index_scores": 28, "mla_prefill": 28, "rmsnorm_fwd": 22,
+         "moe_expert_ffn": 5},
+        10723819008, 2876375040, 1214667264, 1214183424,
+    ),
+    "prefill_last": (
+        {"index_scores": 28, "mla_prefill": 28, "rmsnorm_fwd": 23,
+         "moe_expert_ffn": 6},
+        11748243456, 2876375040, 1217268224, 1216752128,
+    ),
+}
+# a chunk's paths under ``attn`` and neither ``latent`` nor ``indexer``,
+# by what follows ``attn``: the unrolled loop's three (the width
+# switch, its index, the gather of the sequence's rows) and what XLA
+# itself makes inside the switch's piece — copies of a width's mask,
+# the packing's window sum — which had NO path in the unrolled program
+# (unscoped there, ``attn`` here)
+_BARE_ATTN = {
+    "clamp", "cond", "gather",
+    "", "reshape", "reduce_window_sum", "broadcast_in_dim",
+}
+
+
+@pytest.mark.parametrize(
+    "program", ["decode", "prefill_nohead", "prefill_last"]
+)
+def test_latent_pieces_are_inlined_into_the_unrolled_program(
+    program, one_chip
+):
+    """The same three programs, whose layer loop calls jitted pieces —
+    attention's, one a sub-scope, and an MLP a kind
+    (``models/deepseek_v32.py``): the compiler inlines every call, and
+    what it compiles is the unrolled loop's program by every count that
+    does not hang on its scheduler — each kernel as often under its
+    name, the same bytes of arguments, the pool's three leaves aliased
+    to the outputs.  The temporaries are NOT the unrolled program's to
+    the byte (the inliner's clones come in another order and the
+    scheduler then decides otherwise): +1.5 MB in the decode step,
+    -0.5 MB in a chunk; pinned as read, so that a drift shows.  And a
+    device trace still tells every operation's role and part: no
+    scoped path without its role, and under ``attn`` without a
+    sub-part what the unrolled loop had there — nothing in the decode
+    step, the width switch in a chunk."""
+    compiled, _ = _latent_step_compiled(program, one_chip)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    kernels, argument, alias, unrolled_temp, temp = _UNROLLED[program]
+    assert not re.search(r" = [^\n=]*? call\(", text)
+    found = {}
+    for name in re.findall(
+        r"%([\w\-]+?)(?:\.\d+)* = [^\n]*custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"', text
+    ):
+        found[name] = found.get(name, 0) + 1
+    assert found == kernels
+    # (``models/deepseek_v32.py`` ``_Leaves.walk`` says which side of a
+    # call hands on which path)
+    role = "decode" if program == "decode" else "prefill"
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    lost = {
+        path for path in paths
+        if re.search(r"\b(attn|mlp)\b", path)
+        and not re.search(rf"\b{role}\b", path)
+    }
+    assert not lost, sorted(lost)[:5]
+    bare = {
+        re.sub(r"\bp?jit\([^()]*\)", "", path).rsplit("attn", 1)[1]
+        .strip("/")
+        for path in paths
+        if re.search(r"\battn\b", path)
+        and not re.search(r"\b(latent|indexer)\b", path)
+    }
+    assert bare == (set() if program == "decode" else _BARE_ATTN)
+    assert mem.argument_size_in_bytes == argument
+    assert mem.alias_size_in_bytes == alias
+    assert mem.temp_size_in_bytes == temp, unrolled_temp
 
 
 def _copy_case(cell):
