@@ -373,7 +373,12 @@ DEVICE_SCOPE_PARTS = frozenset(
         # projections around it
         "linear",
         "gdn_scan",
-        # latent attention (models/deepseek_v32.py), entered INSIDE
+        # a Kimi Delta Attention layer (models/kimi_linear.py) enters
+        # ``linear`` as well, and ``kda_scan`` INSIDE it around its
+        # prefill's chunk scan alone (ops/kda.kda_chunk_scan)
+        "kda_scan",
+        # latent attention (models/deepseek_v32.py; the NoPE layers of
+        # models/kimi_linear.py, which have no indexer), entered INSIDE
         # ``attn`` as the kinds above are: the two low-rank projections
         # and their norms, the rotation, the write of the cached row,
         # the absorbed decode over the picked rows or a chunk's

@@ -400,6 +400,43 @@ def deepseek_v32_factory(**cfg_kwargs):
     }
 
 
+def kimi_linear_factory(**cfg_kwargs):
+    """Built-in factory of the decoder with Kimi Delta Attention and
+    NoPE latent-attention layers over a share of its routed experts
+    (``models/kimi_linear.py``): the same worker contract, with the
+    model's own step programs — its config declares that it pages no K
+    and no V (``cfg.pages_kv``), one latent row a token in the layers
+    that page (``cfg.paged_leaves()``) AND a conv tail and a recurrent
+    state a lane in the others (``cfg.lane_state()``,
+    ``cfg.layer_keeps()``), so its prefill is told the lane and the
+    count of real tokens, and they return the experts every position
+    was sent to (``cfg.per_token_outputs()``) — and its own
+    ``serving_params_fn``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import kimi_linear
+
+    if isinstance(cfg_kwargs.get("dtype"), str):
+        # the spec rides through JSON: dtype arrives as a name
+        cfg_kwargs = dict(cfg_kwargs, dtype=jnp.dtype(cfg_kwargs["dtype"]))
+    cfg = kimi_linear.KimiLinearConfig(**cfg_kwargs)
+    return {
+        "forward_fn": partial(kimi_linear.forward, cfg=cfg),
+        "params_template_fn": lambda: kimi_linear.init_params(
+            jax.random.PRNGKey(0), cfg
+        ),
+        "cfg": cfg,
+        "paged_decode_fn": partial(kimi_linear.paged_decode_step, cfg=cfg),
+        "paged_prefill_fn": partial(
+            kimi_linear.paged_prefill_chunk, cfg=cfg
+        ),
+        "serving_params_fn": partial(kimi_linear.serving_params, cfg=cfg),
+    }
+
+
 def worker_main() -> int:
     """Generation-process entry (``python -m
     dlrover_tpu.rl.generation_service``); spec arrives via env."""

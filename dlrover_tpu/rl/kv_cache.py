@@ -275,8 +275,17 @@ def paged_cache_config(
     and values, so the pool holds its ``paged_leaves()`` alone
     (``n_kv_heads`` / ``head_dim`` are not read) — a ``v`` of equal size
     that is never read would double the cache.  Such a model declares at
-    least one paged leaf, and neither windows nor lane state nor
-    ``layer_keeps``.
+    least one paged leaf and no windows.  It MAY declare ``lane_state()``
+    — with ``layer_keeps()``, every layer ``"pages"`` or ``"state"``
+    (``models/kimi_linear.py``: recurrent layers that keep a state slab
+    and no row, between latent-attention layers that keep a row a token
+    and no state): the paged leaves are then ``[layers that page, ...]``
+    and each slab ``[layers that hold state, max_slots, ...]``, each
+    addressed by the layer's rank among its kind as ``k`` / ``v`` and
+    the slabs of a model that pages them are.  ``"both"`` stays refused
+    for such a model (no step program addresses a layer's row AND its
+    slab), and so does ``layer_keeps()`` without lane state (every
+    layer keeps the paged leaves).
 
     Any model MAY say, for some of its paged leaves, in rows of how many
     elements a block lies (``paged_leaf_rows() -> {leaf: minor}``): the
@@ -309,15 +318,22 @@ def paged_cache_config(
     pages_kv = bool(getattr(model_cfg, "pages_kv", True))
     windows = getattr(model_cfg, "layer_windows", None)
     windows = tuple(windows()) if windows else ()
+    keeps = getattr(model_cfg, "layer_keeps", None)
+    keeps = tuple(keeps()) if keeps else ()
     if not pages_kv:
         for ok, what in (
             (bool(paged), "and declares no paged_leaves(): it caches nothing"),
-            (not leaves, "beside lane_state(): the state slabs are laid "
-             "out by the layers of the k / v pool"),
             (not any(w is not None for w in windows),
              "beside layer_windows(): the window layers' blocks are wk / wv"),
-            (not getattr(model_cfg, "layer_keeps", None),
-             "beside layer_keeps(): every layer keeps the paged leaves"),
+            (bool(leaves) or not keeps,
+             "beside layer_keeps() without lane_state(): every layer "
+             "keeps the paged leaves"),
+            (not leaves or bool(keeps),
+             "beside lane_state() without layer_keeps(): say which layers "
+             "keep the state slabs and which the paged leaves"),
+            (not leaves or "both" not in keeps,
+             "beside lane_state() with a layer that keeps \"both\": a "
+             "layer keeps the paged leaves or the state slabs"),
         ):
             if not ok:
                 raise ValueError(
@@ -367,8 +383,6 @@ def paged_cache_config(
         table_blocks = window_table_blocks(
             min(sizes), prefill_chunk, block_size
         )
-    keeps = getattr(model_cfg, "layer_keeps", None)
-    keeps = tuple(keeps()) if keeps else ()
     if keeps:
         kinds = ("pages", "state", "both")
         for ok, what in (
@@ -422,8 +436,9 @@ def init_block_pool(cfg: PagedCacheConfig) -> Dict[str, jnp.ndarray]:
     ``k``, ``v`` hold the layers WITHOUT one (in layer order) and
     ``wk``, ``wv`` ``[window layers, window_blocks, block_size, KV,
     head_dim]`` the others'; where it declares ``layer_keeps``, ``k``,
-    ``v`` hold the layers that page and each slab the layers that hold
-    state, both in layer order; where it declares ``flat_pages``, a
+    ``v`` (or, of a model that pages neither, its paged leaves) hold
+    the layers that page and each slab the layers that hold state, both
+    in layer order; where it declares ``flat_pages``, a
     block's rows lie side by side: ``[L, num_blocks, block_size * KV,
     head_dim]``.  A model that pages no ``k`` / ``v`` gets its paged
     leaves alone; a leaf declared in rows of ``minor`` lies ``[L,

@@ -413,8 +413,8 @@ class ContinuousBatchingScheduler:
 
     What a model must provide (``models/llama.py``,
     ``models/falcon_h1.py``, ``models/keye_vl2.py``,
-    ``models/trinity.py``, ``models/olmo_hybrid.py`` and
-    ``models/deepseek_v32.py`` do):
+    ``models/trinity.py``, ``models/olmo_hybrid.py``,
+    ``models/deepseek_v32.py`` and ``models/kimi_linear.py`` do):
 
     - ``model_cfg``: the paged K/V geometry as attributes
       (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and,
@@ -447,7 +447,12 @@ class ContinuousBatchingScheduler:
       ``cache_bytes`` (beside an indexer's ``sel_rows``,
       ``cached_rows`` and ``read_rows``), and the ``prefill`` role is
       refused by name
-      beside the two above (a ship's regions are a K and a V).  And
+      beside the two above (a ship's regions are a K and a V).  Such a
+      model MAY keep lane state too, with ``layer_keeps()`` saying
+      which layers keep the slabs and which the latent rows
+      (``models/kimi_linear.py``): the refusals of both kinds hold, the
+      record carries ``state_layers`` / ``paged_layers`` and a
+      ``cache_bytes`` of slabs plus live blocks.  And
       optionally
       ``layer_windows() -> (window | None, ...)``, one entry a layer
       (``models/trinity.py``): a layer WITH a window reads the keys ``t
@@ -2524,20 +2529,21 @@ class ContinuousBatchingScheduler:
         state: how its layers divide between the two kinds of cache and
         what the cache held this step — the state slabs and the blocks
         live over the layers that page; of a model that pages no K / V,
-        the bytes its live blocks hold; none for a model of K / V pages
-        only (its record is as it was)."""
-        if not self.pool_cfg.pages_kv:
-            return dict(
-                cache_bytes=self.block_pool.used_blocks * self._block_bytes
-            )
-        if not self.lane_state:
+        the bytes its live blocks hold (beside its slabs, where it keeps
+        both: ``models/kimi_linear.py``); none for a model of K / V
+        pages only (its record is as it was)."""
+        if self.pool_cfg.pages_kv and not self.lane_state:
             return {}
-        return dict(
-            state_layers=self.pool_cfg.n_state_layers,
-            paged_layers=self.pool_cfg.n_paged_layers,
+        out = dict(
             cache_bytes=self.state_bytes
             + self.block_pool.used_blocks * self._block_bytes,
         )
+        if self.lane_state:
+            out.update(
+                state_layers=self.pool_cfg.n_state_layers,
+                paged_layers=self.pool_cfg.n_paged_layers,
+            )
+        return out
 
     def _selection_labels(self) -> Dict:
         """The ``serve_step`` labels of a model with an indexer or a

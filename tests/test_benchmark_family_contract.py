@@ -94,6 +94,20 @@ TINY = {
         n_routed_experts=2, num_experts_per_tok=2, n_group=2,
         topk_group=1, index_n_heads=2, index_head_dim=16, index_topk=16,
     ),
+    # one dense layer and one whole period (KDA KDA KDA MLA, numbered
+    # from 1 as published); 2 routed experts held of the router's 16 x 2
+    # (``deployment`` stays the configuration's: 16 chips a layer)
+    "family_kimi_linear": lambda cfg: dict(
+        hidden_size=64, num_hidden_layers=4, num_dense_layers=1,
+        num_expert_layers=3, vocab_size=384, num_attention_heads=4,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, intermediate_size=128, moe_intermediate_size=32,
+        num_experts=2, num_experts_per_token=2,
+        linear_attn_config=dict(
+            cfg["linear_attn_config"], kda_layers=[1, 2, 3],
+            full_attn_layers=[4], num_heads=4, head_dim=16,
+        ),
+    ),
 }
 
 

@@ -898,7 +898,14 @@ def test_what_it_cannot_do_yet_is_refused_by_name(
     (dict(), "declares no paged_leaves"),
     (dict(lane_state=lambda: {"s": ((2,), jnp.float32)},
           paged_leaves=lambda: {"c": ((8,), jnp.float32)}),
-     "beside lane_state"),
+     r"beside lane_state\(\) without layer_keeps"),
+    (dict(lane_state=lambda: {"s": ((2,), jnp.float32)},
+          layer_keeps=lambda: ("both", "pages"),
+          paged_leaves=lambda: {"c": ((8,), jnp.float32)}),
+     'a layer that keeps "both"'),
+    (dict(layer_keeps=lambda: ("pages", "pages"),
+          paged_leaves=lambda: {"c": ((8,), jnp.float32)}),
+     r"beside layer_keeps\(\) without lane_state"),
     (dict(layer_windows=lambda: (4, None),
           paged_leaves=lambda: {"c": ((8,), jnp.float32)}),
      "beside layer_windows"),

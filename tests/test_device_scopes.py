@@ -121,9 +121,12 @@ def test_the_reader_repeats_the_programs_closed_set():
     # attention, inside ``attn``; ``linear`` (PR 49) too, and
     # ``gdn_scan`` inside ``linear``: a gated delta-rule layer and its
     # prefill's chunk scan; ``latent`` (PR 53) likewise: latent
-    # attention's own work beside its indexer
+    # attention's own work beside its indexer; ``kda_scan`` (PR 57)
+    # inside ``linear`` as ``gdn_scan`` is: Kimi Delta Attention's
+    # chunk scan
     assert set(readers_scopes.PARTS) | {
-        "indexer", "window", "full", "linear", "gdn_scan", "latent"
+        "indexer", "window", "full", "linear", "gdn_scan", "kda_scan",
+        "latent",
     } == DEVICE_SCOPE_PARTS
     assert not DEVICE_SCOPE_ROLES & DEVICE_SCOPE_PARTS
 

@@ -74,6 +74,10 @@ def _serve_once(events_path, cache_dir, socks):
         mp.setenv("DLROVER_TPU_EVENTS_FILE", str(events_path))
         mp.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
         mp.setenv("DLROVER_TPU_SOCKET_DIR", str(socks))
+        # JAX keeps what took a second to compile: on a loaded machine a
+        # tiny program does, in one process and not in the other.  With
+        # the threshold out of reach only ``kept_in_compile_cache`` keeps
+        mp.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "600")
         eng = ServingEngine(
             factory="dlrover_tpu.rl.generation_service:tiny_llama_factory",
             factory_kwargs=SERVE_CFG_KW,
