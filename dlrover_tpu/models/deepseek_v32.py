@@ -52,15 +52,15 @@ left out.
 and values, so the pool (``rl/kv_cache.paged_cache_config``) holds the
 ``paged_leaves()`` alone, the cached row in its two parts — ``c [L,
 blocks, block_size, kv_lora_rank]`` and ``kpe [L, blocks, block_size *
-qk_rope_head_dim / 128, 128]``, both in ROWS (``paged_leaf_rows()``)
+qk_rope_head_dim / 128, 128]`` — and ``ik [L, blocks, block_size *
+index_head_dim / 128, 128]``, all in ROWS (``paged_leaf_rows()``)
 because decode reads them row by row — a block of each is one copy of
-the kernel that streams a lane's blocks, a row one element of the
-gather under a very wide table —, two tokens' rotated keys a row: a
-minor axis of 576 or of 64 is one the device pads or lays out
-blocks-minor, and every program then copies the leaf — and ``ik [L,
-blocks, block_size * index_head_dim]``: 1408 bytes a token and layer in
-bfloat16 at the published widths, against 81 920 for 128 heads of 192 +
-128.
+a kernel that streams a lane's blocks, a row one element of the gather
+under a very wide table —, two tokens' rotated keys a row and one
+index key: a minor axis of 576 or of 64 is one the device pads or lays
+out blocks-minor, and every program then copies the leaf.  1408 bytes
+a token and layer in bfloat16 at the published widths, against 81 920
+for 128 heads of 192 + 128.
 
 **Two forms of one attention.**  Decode is ABSORBED: ``q_nope W_uk``
 is a ``kv_lora_rank``-wide query a head, scored with ``q_pe`` against
@@ -252,11 +252,13 @@ class DeepSeekV32Config:
 
     def paged_leaf_rows(self) -> Dict[str, int]:
         """The leaves decode reads row by row, and the rows a block of
-        each lies in: a latent a row; the rotated keys in rows of (at
-        least) the device's 128 lanes, several tokens' a row."""
+        each lies in: a latent a row; the rotated keys and the index
+        keys in rows of (at least) the device's 128 lanes, whole
+        tokens' a row (an index key a row at 128)."""
         return {
             "c": self.kv_lora_rank,
             "kpe": max(128, self.qk_rope_head_dim),
+            "ik": max(128, self.index_head_dim),
         }
 
     def per_token_outputs(self) -> Dict[str, Tuple[Tuple[int, ...], str]]:
@@ -945,7 +947,11 @@ def paged_decode_step(
 ) -> Tuple[jnp.ndarray, Dict, Dict]:
     """One continuous-batching decode step: every active lane writes
     its latent row and index key, scores its index query against every
-    index key it has cached, takes the exact top ``index_topk``
+    index key it has cached — read from the blocks the lane holds, in
+    place (``ops/paged_attention.gather_index_keys`` hands
+    ``decode_index_scores`` a view of the leaf under the Pallas
+    backend, and gathers every entry of every table under ``jnp``) —,
+    takes the exact top ``index_topk``
     positions (all of them below it) and attends over those rows alone
     in ABSORBED form — the row is key and value of every head —,
     reading the blocks it holds itself under the selection's mask
@@ -1009,7 +1015,9 @@ def paged_decode_step(
         tables = kv.tables(block_tables)
         qi, ik, w = _indexer_inputs(h, c_q, lp, cfg)
         qi = _rotate_lead(qi, cos[:, None], sin[:, None], dr)
-        kv = kv.write_leaf("ik", _rotate_lead(ik, cos, sin, dr), blk, off)
+        kv = kv.write_leaf_rows(
+            "ik", _rotate_lead(ik, cos, sin, dr), blk, off
+        )
         keys = gather_index_keys(kv.paged["ik"], tables, cfg.index_head_dim)
         # a mask over the positions where attention reads a lane's
         # blocks itself, the rows to gather beside it under a table

@@ -33,8 +33,10 @@ What the serving plane needs of a model (``rl/scheduler.py`` says what
 it takes) is here in the shape ``models/llama.py`` gives it, with two
 declarations of its own: ``paged_leaves()`` — the index key lives in
 the SAME blocks as K and V, a third paged leaf ``ik [L, blocks,
-block_size * index_dim]`` (it has positions: shared by prefix, shipped
-and freed with its block) — and ``per_token_outputs()`` — each step
+block_size * index_dim / 128, 128]`` (it has positions: shared by
+prefix, shipped and freed with its block; in rows of 128 lanes,
+``paged_leaf_rows()``, which the decode step's index scores are read
+from in place) — and ``per_token_outputs()`` — each step
 program also returns the experts it sent every position to, ``[rows,
 layers, k]``, which a reply carries so that a float32 reference can be
 held to the served routing.  There is no training path, and the
@@ -106,6 +108,12 @@ class KeyeVL2Config:
         """Per layer and TOKEN, beside K and V: ``{leaf: (shape,
         dtype)}``.  One index key a token, in the compute dtype."""
         return {"ik": ((self.indexer_head_dim,), self.dtype)}
+
+    def paged_leaf_rows(self) -> Dict[str, int]:
+        """The index keys lie in rows of (at least) the device's 128
+        lanes, whole tokens' a row (two at 64): the rows the decode
+        step's scores are read from in place."""
+        return {"ik": max(128, self.indexer_head_dim)}
 
     def per_token_outputs(self) -> Dict[str, Tuple[Tuple[int, ...], str]]:
         """What a step program returns for every row it computes,
@@ -434,7 +442,7 @@ def forward(params: Dict, tokens: jnp.ndarray, cfg: KeyeVL2Config,
 def paged_prefill_chunk(
     params: Dict,
     tokens: jnp.ndarray,  # [1, C] one sequence's prompt chunk, padded
-    pool: Dict,  # k, v [L, blocks, bs, KV, D]; ik [L, blocks, bs * Di]
+    pool: Dict,  # k, v [L, N, bs, KV, D]; ik [L, N, bs * Di / 128, 128]
     block_table: jnp.ndarray,  # [max_blocks] int32
     start_pos: jnp.ndarray,  # scalar int32: the chunk's first position
     cfg: KeyeVL2Config,
@@ -536,7 +544,7 @@ def paged_prefill_chunk(
 def paged_decode_step(
     params: Dict,
     tokens: jnp.ndarray,  # [B] current token per lane
-    pool: Dict,  # k, v [L, blocks, bs, KV, D]; ik [L, blocks, bs * Di]
+    pool: Dict,  # k, v [L, N, bs, KV, D]; ik [L, N, bs * Di / 128, 128]
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     positions: jnp.ndarray,  # [B] int32 position being decoded per lane
     active: jnp.ndarray,  # [B] bool: the lane decodes this step
@@ -544,7 +552,9 @@ def paged_decode_step(
 ) -> Tuple[jnp.ndarray, Dict, Dict]:
     """One continuous-batching decode step: every active lane writes
     its K, V and index key, scores its index query against every index
-    key it has cached, takes the exact top ``topk`` positions (all of
+    key it has cached (read from the blocks the lane holds, in place,
+    under the Pallas backend: ``ops/paged_attention.gather_index_keys``),
+    takes the exact top ``topk`` positions (all of
     them below ``topk``) and attends over those token rows alone.  An
     inactive lane writes to the null block and reads one masked row.
     Shapes depend on (lanes, pool geometry) only: compiled once.
@@ -593,7 +603,7 @@ def paged_decode_step(
         with jax.named_scope("attn"), jax.named_scope("indexer"):
             qi, ik, w = _indexer_inputs(h[:, 0], lp, cfg)
             qi = _rotate(qi, icos[:, None], isin[:, None])
-            kv = kv.write_leaf("ik", _rotate(ik, icos, isin), blk, off)
+            kv = kv.write_leaf_rows("ik", _rotate(ik, icos, isin), blk, off)
             keys = gather_index_keys(
                 kv.paged["ik"], kv.tables(block_tables),
                 cfg.indexer_head_dim,

@@ -354,31 +354,77 @@ def write_block_kv(
 # ---------------------------------------------------------------------------
 # learned sparse attention: an indexer scores every cached token from a
 # paged INDEX-KEY cache beside the K/V pool, an EXACT top-k picks the
-# token rows, and attention reads those rows alone
+# token rows, and attention reads those rows alone.  A prefill chunk
+# gathers ONE sequence's index keys by its table; a decode step under
+# the Pallas backend gathers nothing: every lane's keys are scored from
+# the leaf in place (``ops/paged_kernels.index_decode_scores_kernel``)
 # ---------------------------------------------------------------------------
 
 
+class IndexKeyView(NamedTuple):
+    """Every lane's cached index keys WITHOUT a gather: the leaf where
+    it lies and the lanes' tables — what :func:`gather_index_keys` hands
+    :func:`decode_index_scores` under the Pallas backend, whose kernel
+    copies the blocks a lane holds itself.  ``shape`` reads as the
+    gathered array's would."""
+
+    leaf: jnp.ndarray  # [num_blocks, block_size * Di / M, M], rows of M lanes
+    tables: jnp.ndarray  # [B, max_blocks] int32 block ids IN the leaf
+    width: int  # Di
+
+    @property
+    def shape(self) -> Tuple[int, int, int]:
+        lanes, max_blocks = self.tables.shape
+        block = math.prod(self.leaf.shape[1:]) // self.width
+        return (lanes, max_blocks * block, self.width)
+
+
 def gather_index_keys(
-    ik_pool: jnp.ndarray,  # [num_blocks, block_size * Di] index keys
+    ik_pool: jnp.ndarray,  # [num_blocks, ...] a block's index keys, in order
     tables: jnp.ndarray,  # [..., max_blocks] int32
     width: int,  # Di
-) -> jnp.ndarray:
+    backend: Optional[str] = None,  # None -> DLROVER_TPU_PAGED_KERNEL
+):
     """The index keys of each table's sequence, ``[..., max_blocks *
-    block_size, Di]`` (position ``s`` at row ``s``)."""
+    block_size, Di]`` (position ``s`` at row ``s``), whichever way a
+    block of the leaf lies (flat, or in rows: ``paged_leaf_rows()``).
+
+    EVERY LANE's tables (``[B, max_blocks]``: a decode step) over a leaf
+    in rows that hold whole keys are not gathered under the Pallas
+    backend: an :class:`IndexKeyView` goes to :func:`decode_index_scores`
+    instead (``[32, 8192, 128]`` bfloat16 a layer, 67 MB, held or not,
+    at DeepSeek-V3.2's decode step).  The choice is of shapes and
+    backend alone."""
+    if (
+        tables.ndim == 2 and ik_pool.ndim == 3
+        and ik_pool.shape[-1] % width == 0
+        and (backend or paged_kernel_backend()) == "pallas"
+    ):
+        return IndexKeyView(ik_pool, tables, width)
     keys = ik_pool[tables]
-    return keys.reshape(keys.shape[:-2] + (-1, width))
+    return keys.reshape(tables.shape[:-1] + (-1, width))
 
 
 def decode_index_scores(
     qi: jnp.ndarray,  # [B, Hi, Di] one index query per lane and head
     w: jnp.ndarray,  # [B, Hi] float32 head weights
-    keys: jnp.ndarray,  # [B, T, Di] each lane's cached index keys
+    keys,  # [B, T, Di] each lane's cached index keys, or an IndexKeyView
     seq_lens: jnp.ndarray,  # [B] int32: valid positions per lane
 ) -> jnp.ndarray:
     """Index scores of each lane's query against its cached index keys
     (:func:`gather_index_keys`): ``I[b, s] = sum_h w[b, h] * relu(qi[b,
     h] . ik[s])``, float32 ``[B, T]``, ``-inf`` at ``s >= seq_lens[b]``
-    (the null block and unwritten cells never score)."""
+    (the null block and unwritten cells never score).  Handed a view of
+    the leaf, ONE streamed kernel (``index_decode_scores`` in a device
+    trace) reads the blocks each lane holds and writes the ``[B, T]``
+    scores alone; handed gathered keys, two XLA products through ``[B,
+    Hi, T]`` float32."""
+    if isinstance(keys, IndexKeyView):
+        from dlrover_tpu.ops.paged_kernels import index_decode_scores_kernel
+
+        return index_decode_scores_kernel(
+            qi, w, keys.leaf, keys.tables, seq_lens
+        )
     s = jnp.einsum(
         "bhd,btd->bht", qi, keys, preferred_element_type=jnp.float32
     )
@@ -881,7 +927,9 @@ class LayerPool(NamedTuple):
     layer: jnp.ndarray  # scalar int32
     # what a model pages beside K and V (``paged_leaves()`` of its
     # config: an index key a token), ``{leaf: [L * num_blocks,
-    # block_size * width]}``; empty for a block of keys and values only
+    # block_size * width]}``, or in rows where the model says so
+    # (``paged_leaf_rows()``: ``[L * num_blocks, block_size * width /
+    # minor, minor]``); empty for a block of keys and values only
     paged: Dict[str, jnp.ndarray] = {}
 
     def tables(self, block_tables: jnp.ndarray) -> jnp.ndarray:

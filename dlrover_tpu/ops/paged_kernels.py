@@ -23,7 +23,13 @@ page by page instead, and the gather never materializes:
   gathered while a table holds a few times what is picked
   (``ops/paged_attention.latent_decode_selection`` chooses by the
   shapes; under a wider table the picked rows are gathered for
-  ``mla_sparse_decode_kernel``).  The **verify** kernel and the decode
+  ``mla_sparse_decode_kernel``).  The **index scores of a decode
+  step** (``index_decode_scores_kernel``, PR 58) are the third of the
+  form, over the ONE leaf of index keys, and the first written on the
+  scaffold the three share (``_stream_lane_blocks``: the leaves to
+  copy, the body to run a group): every held key is scored against the
+  lane's index queries and the ``[B, T]`` float32 scores are all that
+  is written — no key is gathered.  The **verify** kernel and the decode
   over rows already gathered (``sparse_decode_kernel``,
   ``mla_sparse_decode_kernel``) still stream pages as ``BlockSpec``
   operands whose index maps dereference the table, or the rows' count,
@@ -1398,6 +1404,293 @@ def mla_stream_decode_kernel(
         q_c, jnp.tile(q_pe, (1, 1, per_row)), picked, c_leaf, pe_leaf,
     )
     return out[:, :n_heads]
+
+
+#: copies a turn of :func:`_stream_lane_blocks`' issue loop, and the
+#: compiler parameters of a kernel built on it: the lanes run in order
+#: (the slot parity and a lane's first group carry over a grid step),
+#: and every address is in range by construction (a table entry is
+#: clamped, a slot's index is below ``span``)
+STREAM_UNROLL = 16
+STREAM_PARAMS = dict(
+    dimension_semantics=("arbitrary",), disable_bounds_checks=True
+)
+
+
+def _stream_lane_blocks(tables_ref, held, leaves, sems, done, span, body):
+    """The copy-and-wait scaffold of a kernel that reads a lane's OWN
+    blocks from leaves that go in whole (``memory_space=pl.ANY``), one
+    lane a grid step (``dimension_semantics`` "arbitrary": the slot
+    parity and a lane's first group carry over a step).  ``leaves``:
+    ``(hbm, buf)`` a leaf to copy, ``hbm`` ``[N, ...]`` blocks and
+    ``buf`` ``[2, span, ...]`` two slots of ``span`` blocks; ``sems``
+    DMA ``[len(leaves), 2]``; ``done`` SMEM ``[1]``; ``held(lane)`` the
+    table entries a lane really holds.  Groups of ``span`` entries: the
+    next group — or the next lane's first — is in flight while
+    ``body(i, slot)`` runs on group ``i``, whose held blocks lie in
+    ``buf[slot, :n]`` of every leaf (the rest of the slot is stale).
+    Entries past the lane's last block are neither read from the table
+    nor fetched; an entry is clamped into its leaf, as a gather clamps,
+    so a kernel built on this may drop the compiler's own bounds checks
+    (:data:`STREAM_PARAMS`: they are 13 of the 21 bundles a copy costs
+    the scalar core, ``PERF.md`` section 6, PR 58).
+    (:func:`_stream_decode_kernel` and
+    :func:`_mla_stream_kernel` carry this scaffold by hand still:
+    ``ROADMAP.md`` Queue 3 item 17 moves them here.)"""
+    b = pl.program_id(0)
+    lanes = pl.num_programs(0)
+
+    def groups(lane):
+        return lax.div(held(lane) + span - 1, span)
+
+    def copies(lane, i, slot, arrive):
+        """Start, or wait for, a copy a leaf of every block ``lane``
+        holds of its group ``i`` — a loop of as many turns; a whole
+        group is waited for at once (a slot's semaphore counts what
+        arrived, and ``span`` blocks are the slot's size)."""
+        n_blocks = jnp.minimum(held(lane) - i * span, span)
+
+        def block(s, carry):
+            at = tables_ref[lane, i * span + s]
+            for leaf, (hbm, buf) in enumerate(leaves):
+                copy = pltpu.make_async_copy(
+                    hbm.at[jnp.clip(at, 0, hbm.shape[0] - 1)],
+                    buf.at[slot, s], sems.at[leaf, slot],
+                )
+                copy.wait() if arrive else copy.start()
+            return carry
+
+        if not arrive:
+            # some copies a turn: the scalar core issues them, and a
+            # turn's branch is a fifth of what a copy costs alone
+            unroll = min(STREAM_UNROLL, span)
+            whole = lax.div(n_blocks, unroll)
+
+            def turn(t, carry):
+                for u in range(unroll):
+                    block(t * unroll + u, carry)
+                return carry
+
+            lax.fori_loop(0, whole, turn, 0)
+            lax.fori_loop(whole * unroll, n_blocks, block, 0)
+            return
+
+        @pl.when(n_blocks == span)
+        def _whole():
+            for leaf, (_, buf) in enumerate(leaves):
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sems.at[leaf, slot]
+                ).wait()
+
+        @pl.when(n_blocks < span)
+        def _tail():
+            lax.fori_loop(0, n_blocks, block, 0)
+
+    @pl.when(b == 0)
+    def _first_lane():
+        done[0] = 0
+
+    n_groups = groups(b)
+    base = done[0]
+    before = jnp.maximum(b - 1, 0)
+    after = jnp.minimum(b + 1, lanes - 1)
+    after_reads = (b + 1 < lanes) & (groups(after) > 0)
+
+    # the lane before starts this lane's first group, if it ran at all
+    @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
+    def _own_first_group():
+        copies(b, 0, lax.rem(base, 2), False)
+
+    def group(i, carry):
+        slot = lax.rem(base + i, 2)
+
+        @pl.when(i + 1 < n_groups)
+        def _next_group():
+            copies(b, i + 1, 1 - slot, False)
+
+        @pl.when((i + 1 == n_groups) & after_reads)
+        def _next_lane():
+            copies(after, 0, 1 - slot, False)
+
+        copies(b, i, slot, True)
+        body(i, slot)
+        return carry
+
+    lax.fori_loop(0, n_groups, group, 0)
+    done[0] = base + n_groups
+
+
+def _index_decode_kernel(
+    tables_ref,  # scalar prefetch [B, MB]: block ids in the leaf
+    lens_ref,  # scalar prefetch [B]: positions of a lane that count
+    q_ref,  # [1, per_row * H, M]: the index queries, under each token's lanes
+    w_ref,  # [1, per_row * H, 1] float32: the heads' weights, as often
+    ik_hbm,  # [N, bs * Di / M, M]: the index keys' leaf, M / Di tokens a row
+    o_ref,  # [1, groups * per_row, R] float32: a group's rows' scores a row
+    ik_buf,  # [2, span, bs * Di / M, M]: two slots, a group of blocks each
+    sems,  # DMA [1, 2]
+    done,  # SMEM [1]: groups computed by the lanes before this one
+    *,
+    span: int,
+    block_size: int,
+    n_heads: int,
+):
+    """One lane a grid step: the index keys of the blocks the lane
+    HOLDS are copied from the leaf where it lies
+    (:func:`_stream_lane_blocks`) and meet the lane's index queries a
+    group at a time, ``sum_h w[h] * relu(qi[h] . ik[s])`` in float32.
+    The leaf lies in rows of ``M`` lanes, ``per_row = M / Di`` tokens a
+    row; the caller laid the queries under each token's lanes (rows
+    ``j * H ..`` of ``q_ref`` hold them under token ``j``'s, zeros
+    elsewhere), so ONE product scores the ``per_row`` tokens of every
+    row, and row ``i * per_row + j`` of the result holds token ``j`` of
+    group ``i``'s rows.  A position past the length scores ``-inf``;
+    where a row holds several tokens its keys are zeroed first (``0 *
+    NaN`` of an unwritten neighbour would reach a position that
+    counts); a group the lane does not hold is never visited and stays
+    at the ``-inf`` the result starts from."""
+    b = pl.program_id(0)
+    max_blocks = tables_ref.shape[1]
+    rows, width = ik_hbm.shape[1:]  # rows a block, lanes a row
+    per_row = block_size // rows  # tokens a row
+    n_rows = span * rows  # rows a group
+    seq_len = lens_ref[b]
+
+    def held(lane):  # blocks a lane's table really holds
+        blocks = lax.div(lens_ref[lane] + block_size - 1, block_size)
+        return jnp.minimum(blocks, max_blocks)
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+    q = q_ref[0]
+    w = w_ref[0]  # [per_row * H, 1]
+    row_col = _iota_cols(n_rows)  # [1, R]
+    if per_row > 1:  # a row of keys: the position of each lane's token
+        key_pos = per_row * _iota_rows(n_rows) + lax.div(
+            _iota_cols(width), width // per_row
+        )  # [R, M]
+
+    def scores(i, slot):
+        at = i * n_rows  # the group's first row
+        keys = ik_buf[slot].reshape(n_rows, width)
+        if per_row > 1:
+            keys = jnp.where(
+                at * per_row + key_pos < seq_len, keys, jnp.zeros_like(keys)
+            )
+        s = lax.dot_general(
+            q, keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [per_row * H, R]
+        s = jnp.maximum(s, 0.0) * w
+        for j in range(per_row):  # a row's tokens, not the heads
+            score = jnp.sum(
+                s[j * n_heads:(j + 1) * n_heads], axis=0, keepdims=True
+            )
+            pos = (at + row_col) * per_row + j
+            o_ref[0, pl.ds(i * per_row + j, 1), :] = jnp.where(
+                pos < seq_len, score, -jnp.inf
+            )
+
+    _stream_lane_blocks(
+        tables_ref, held, ((ik_hbm, ik_buf),), sems, done, span, scores
+    )
+
+
+def index_decode_span(qi, ik_leaf, max_blocks: int) -> int:
+    """Table entries a group of :func:`index_decode_scores_kernel` for
+    queries ``[B, Hi, Di]`` over a leaf ``[N, rows, M]``: the two slots
+    of a group's blocks under 1 MiB and its float32 scores (a row a
+    head and token of a leaf's row) under 1 MiB, a power of two."""
+    _, rows, width = ik_leaf.shape
+    score_rows = _round_up(qi.shape[1], 8) * (width // qi.shape[2])
+    span = min(
+        (1 << 20) // (2 * rows * width * ik_leaf.dtype.itemsize),
+        (1 << 20) // (score_rows * rows * 4),
+    )
+    return max(1, min(1 << (max(span, 1).bit_length() - 1), max_blocks))
+
+
+def index_decode_scores_kernel(
+    qi: jnp.ndarray,  # [B, Hi, Di] one index query per lane and head
+    w: jnp.ndarray,  # [B, Hi] float32 head weights
+    ik_leaf: jnp.ndarray,  # [N, bs * Di / M, M] the index keys' leaf, whole
+    block_tables: jnp.ndarray,  # [B, MB] int32 block ids IN the leaf
+    seq_lens: jnp.ndarray,  # [B] int32: positions of a lane that count
+    *,
+    span: Optional[int] = None,
+) -> jnp.ndarray:
+    """The decode step's index scores, ``I[b, s] = sum_h w[b, h] *
+    relu(qi[b, h] . ik[s])`` in float32 ``[B, MB * bs]``, ``-inf`` at
+    ``s >= seq_lens[b]``, ``index_decode_scores`` in a device trace:
+    what ``ops/paged_attention.decode_index_scores`` computes from
+    gathered keys, read from the leaf in place — the leaf goes in whole,
+    a grid step is a lane and the kernel copies the blocks the lane
+    holds itself (:func:`_index_decode_kernel`); no key is gathered, no
+    ``[B, Hi, T]`` scores are written.  The leaf lies in rows of ``M``
+    lanes (``paged_leaf_rows()``: 128; a token a row at DeepSeek-V3.2's
+    128-wide keys, two a row at Keye-VL-2.0's 64).  A lane of length 0
+    reads nothing and scores ``-inf`` everywhere."""
+    batch, n_heads, di = qi.shape
+    _, rows, width = ik_leaf.shape
+    max_blocks = block_tables.shape[1]
+    if width % di:
+        raise ValueError(f"index keys of {di} in rows of {width}")
+    per_row = width // di
+    block_size = rows * per_row
+    heads_p = _round_up(n_heads, 8)
+    span = span or index_decode_span(qi, ik_leaf, max_blocks)
+    n_groups = -(-max_blocks // span)
+    n_rows = span * rows
+    pad = ((0, 0), (0, heads_p - n_heads))
+    qi = jnp.pad(qi.astype(ik_leaf.dtype), pad + ((0, 0),))
+    w = jnp.pad(w.astype(jnp.float32), pad)
+    # rows j * H .. of the queries lie under token j's lanes of a row
+    q_lay = jnp.concatenate([
+        jnp.pad(qi, ((0, 0), (0, 0), (j * di, width - (j + 1) * di)))
+        for j in range(per_row)
+    ], axis=1)
+
+    def lane_index(b, tables, lens):
+        del tables, lens
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[
+            pl.BlockSpec((1, per_row * heads_p, width), lane_index),
+            pl.BlockSpec((1, per_row * heads_p, 1), lane_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, n_groups * per_row, n_rows), lane_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, rows, width), ik_leaf.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = named_kernel(
+        "index_decode_scores",
+        pl.pallas_call(
+            functools.partial(
+                _index_decode_kernel, span=span, block_size=block_size,
+                n_heads=heads_p,
+            ),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (batch, n_groups * per_row, n_rows), jnp.float32
+            ),
+            interpret=use_interpret(),
+            name="index_decode_scores",
+            compiler_params=pltpu.CompilerParams(**STREAM_PARAMS),
+        ),
+    )(
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        q_lay, jnp.tile(w, (1, per_row))[..., None], ik_leaf,
+    )
+    # [B, group, token of a row, row of the group] -> by position
+    out = out.reshape(batch, n_groups, per_row, n_rows)
+    out = jnp.swapaxes(out, 2, 3).reshape(batch, -1)
+    return out[:, :max_blocks * block_size]
 
 
 # keys a grid step of ``chunk_prefill_kernel`` reads; whoever lays out

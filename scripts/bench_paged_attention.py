@@ -98,6 +98,18 @@ LATENT_HELD = (2048, 4096, 8192)
 LATENT_SWEEP = (2048, 4096, 6144, 8192, 12288, 16384, 32768)
 
 
+#: the decode step's index scores (``--index-decode``): lanes, index
+#: heads, their width, table entries, top-k and the held positions
+#: swept, at DeepSeek-V3.2's cell (prompts of median 2048 and half an
+#: answer of median 3072: ~4 k held) and at Keye-VL-2.0's (3-15 k)
+INDEX_DECODE = {
+    "deepseek-v32": dict(lanes=32, heads=64, dim=128, entries=512,
+                         topk=2048, held=(2048, 4096, 8192)),
+    "keye-vl2": dict(lanes=16, heads=16, dim=64, entries=1024,
+                     topk=2048, held=(4096, 9216, 15360)),
+}
+
+
 def _time_call(call, reps: int) -> float:
     """Best-of-reps wall microseconds for an already-warm callable."""
     best = float("inf")
@@ -659,6 +671,132 @@ def bench_latent(held=LATENT_HELD, entries=512, reps=20, dims=None):
     return out_rows
 
 
+def bench_index_decode(names=None, spans=(None,), reps=20, block_size=16,
+                       dims=None):
+    """Rows of the decode step's bare index scores, ``reps`` calls
+    chained inside ONE program: the GATHERED form (every entry of every
+    lane's table gathered from the flat leaf, relaid to keys, ``[B, Hi,
+    T]`` float32 scores, the heads' weighted sum: what a decode step
+    ran before PR 58) against the STREAMED kernel
+    (``index_decode_scores_kernel``) on the same keys in rows of 128
+    lanes, every lane holding ``held`` positions less 3 of scattered
+    blocks: each form's largest difference from the same sum in
+    float32 at the highest precision over the largest score (``*_err``;
+    on the chip the gathered form's second product rounds its float32
+    operands to bfloat16), and in how many positions ``exact_topk_mask``
+    picks otherwise from the streamed scores than from the gathered
+    ones and from the exact ones.  ``spans``: table entries a group to force.  ``dims``: a
+    rehearsal's cases in :data:`INDEX_DECODE`'s place."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.ops import paged_attention as pa
+    from dlrover_tpu.ops.paged_kernels import (
+        index_decode_scores_kernel,
+        index_decode_span,
+    )
+
+    def chained_us(form, qi, rest):
+        # a call's queries hang on the scores of the one before
+        def kernel(qi, *rest):
+            out = form(qi, *rest)
+            return jnp.max(jnp.where(jnp.isfinite(out), out, 0.0)).astype(
+                qi.dtype
+            )[None, None, None]
+
+        return _chained_us(kernel, qi, rest, reps)
+
+    out_rows = []
+    for name, case in (dims or INDEX_DECODE).items():
+        if names and name not in names:
+            continue
+        lanes, heads, dim, entries, topk = (
+            case[n] for n in ("lanes", "heads", "dim", "entries", "topk")
+        )
+        minor = max(128, dim)
+        for held in case["held"]:
+            rng = np.random.default_rng(held)
+            blocks = held // block_size
+            tables = np.zeros((lanes, entries), np.int32)
+            tables[:, :blocks] = 1 + rng.permutation(lanes * blocks).reshape(
+                lanes, blocks
+            )
+            tables = jnp.asarray(tables)
+            key = jax.random.PRNGKey(held)
+            n = lanes * blocks + 1
+            flat = jax.random.normal(
+                key, (n, block_size * dim), jnp.bfloat16
+            )
+            rows = flat.reshape(n, -1, minor)
+            qi = jax.random.normal(key, (lanes, heads, dim), jnp.bfloat16)
+            w = jax.random.normal(key, (lanes, heads), jnp.float32)
+            lens = jnp.full((lanes,), held - 3, jnp.int32)
+
+            def gathered(qi, w, leaf, tables, lens):
+                # the tables hang on the queries (by a zero no compiler
+                # can see): a chained call gathers anew, as a decode
+                # step does
+                tables = tables + (qi[0, 0, 0] > 1e30).astype(tables.dtype)
+                return pa.decode_index_scores(
+                    qi, w, pa.gather_index_keys(leaf, tables, dim, "jnp"),
+                    lens,
+                )
+
+            want = gathered(qi, w, flat, tables, lens)
+            with jax.default_matmul_precision("highest"):
+                exact = gathered(
+                    qi.astype(jnp.float32), w, flat.astype(jnp.float32),
+                    tables, lens,
+                )
+            finite = jnp.isfinite(want)
+            size = jnp.max(jnp.where(finite, jnp.abs(exact), 0.0))
+
+            def err(got):
+                return float(jnp.max(
+                    jnp.where(finite, jnp.abs(got - exact), 0.0)
+                ) / size)
+
+            row = {
+                "kernel": "index_decode_scores", "case": name, "held": held,
+                "table": entries * block_size,
+                "gathered_us": round(
+                    chained_us(gathered, qi, (w, flat, tables, lens)), 1
+                ),
+                "gathered_rows_leaf_us": round(
+                    chained_us(gathered, qi, (w, rows, tables, lens)), 1
+                ),
+                "gathered_err": err(want),
+            }
+            n_sel = min(topk, entries * block_size)
+            for span in spans:
+                kernel = functools.partial(
+                    index_decode_scores_kernel, span=span
+                )
+                got = kernel(qi, w, rows, tables, lens)
+                out_rows.append({
+                    **row,
+                    "span": span or index_decode_span(qi, rows, entries),
+                    "streamed_us": round(
+                        chained_us(kernel, qi, (w, rows, tables, lens)), 1
+                    ),
+                    "same_finite": bool(
+                        jnp.all(jnp.isfinite(got) == finite)
+                    ),
+                    "streamed_err": err(got),
+                    "topk_differ": int(jnp.sum(
+                        pa.exact_topk_mask(got, n_sel)
+                        != pa.exact_topk_mask(want, n_sel)
+                    )),
+                    "topk_differ_from_exact": int(jnp.sum(
+                        pa.exact_topk_mask(got, n_sel)
+                        != pa.exact_topk_mask(exact, n_sel)
+                    )),
+                })
+                print(json.dumps(out_rows[-1]), flush=True)
+    return out_rows
+
+
 def bench_selection(lanes=32, positions=8192, topk=2048, reps=20):
     """Microseconds a call of the three exact selections at a decode
     step's ``[lanes, positions]`` scores: the sort that carries each
@@ -762,11 +900,34 @@ def main(argv=None) -> int:
         f"holds ({', '.join(str(h) for h in LATENT_SWEEP)} positions): "
         "the crossover that set LATENT_STREAM_WIDTH",
     )
+    ap.add_argument(
+        "--index-decode", nargs="*", default=None, metavar="NAME",
+        help="time the decode step's bare index scores, the gathered form "
+        "against the streamed kernel, at the serving cells' shapes "
+        f"({', '.join(INDEX_DECODE)}; none named: both); --spans forces "
+        "the table entries a group",
+    )
     args = ap.parse_args(argv)
     blocks = [
         tuple(int(n) for n in b.split("x"))
         for b in args.blocks.split(",") if b
     ] or (None,)
+
+    if args.index_decode is not None:
+        import jax
+
+        rows = bench_index_decode(
+            args.index_decode or None,
+            spans=[int(x) for x in args.spans.split(",") if x] or (None,),
+            reps=max(args.reps, 20),
+        )
+        _flush(args.out, {
+            "bench": "index_decode_scores", "rows": rows,
+            "backend": jax.default_backend(), "interpret": _interpret(),
+            "device_kind": jax.devices()[0].device_kind,
+        })
+        print(f"wrote {args.out} ({len(rows)} rows)")
+        return 0
 
     if args.latent or args.latent_sweep:
         import jax

@@ -578,6 +578,134 @@ def test_the_streamed_decode_kernel_reads_its_own_blocks_under_the_mask(
     )
 
 
+#: the tiny indexers: DeepSeek-V3.2's (2 heads of 16, blocks of 4 in one
+#: row of 64: four keys a row) and Keye-VL-2.0's (2 heads of 8, a row of
+#: 32); and the published rows at small depth (a key a row of 128, two
+#: keys a row) in blocks of 16
+_INDEXERS = {
+    "v": dict(h=2, di=16, bs=4, minor=64),
+    "k": dict(h=2, di=8, bs=4, minor=32),
+    "v128": dict(h=8, di=128, bs=16, minor=128),
+    "k64": dict(h=16, di=64, bs=16, minor=128),
+}
+
+
+def _index_case(shape, lens, *, mb=10, layers=1, layer=0, idle=(), seed=7):
+    """An index-key leaf of ``layers`` layers whose blocks lie
+    scattered, every lane holding the blocks of its ``lens`` positions
+    of layer ``layer`` and the null block behind them; NaN in every
+    block no lane holds (the other layers' whole), in a last held
+    block's keys past the length and in the null block past its first
+    key.  ``idle``: lanes that do not decode — an all-null table read as
+    one position, as the decode step hands them."""
+    h, di, bs, minor = (
+        _INDEXERS[shape][n] for n in ("h", "di", "bs", "minor")
+    )
+    rng = np.random.default_rng(seed)
+    lens = np.asarray(lens, np.int32)
+    b, nb = len(lens), len(lens) * mb + 1
+    leaf = rng.standard_normal((layers * nb, bs, di)).astype(np.float32)
+    tables = np.zeros((b, mb), np.int32)
+    free = 1 + rng.permutation(nb - 1)
+    unheld = np.ones(layers * nb, bool)
+    base = layer * nb
+    for i, length in enumerate(lens):
+        if i in idle:
+            continue
+        held = min(-(-int(length) // bs), mb)
+        tables[i, :held] = free[i * mb:i * mb + held]
+        unheld[base + tables[i, :held]] = False
+        if length % bs and length < mb * bs:
+            leaf[base + tables[i, held - 1], length % bs:] = np.nan
+    leaf[unheld] = np.nan
+    leaf[base, 0] = rng.standard_normal(di)  # the null block's first key
+    return dict(
+        qi=jnp.asarray(rng.standard_normal((b, h, di)), jnp.float32),
+        w=jnp.asarray(rng.standard_normal((b, h)), jnp.float32),
+        leaf=jnp.asarray(leaf.reshape(layers * nb, bs * di // minor, minor)),
+        tables=jnp.asarray(tables) + base, lens=jnp.asarray(lens),
+        di=di, bs=bs,
+    )
+
+
+@pytest.mark.parametrize("shape,lens,kw", [
+    # empty, one key, inside a block, a whole table, past the table
+    ("v", (0, 1, 6, 40, 43), {}),
+    ("k", (0, 1, 6, 40, 43), {}),
+    # a lane that does not decode between two that do
+    ("v", (33, 1, 18), dict(idle=(1,))),
+    ("k", (33, 1, 18), dict(idle=(1,))),
+    # two layers in one leaf: the tables address the second's blocks
+    ("v", (40, 7, 21), dict(layers=2, layer=1)),
+    ("k", (40, 7, 21), dict(layers=2, layer=1)),
+    # groups of 3 entries of a table of 10: a group held in part, a
+    # last group shorter than the others
+    ("v", (40, 11, 12, 13, 25), dict(span=3)),
+    ("k", (40, 11, 12, 13, 25), dict(span=3)),
+    # the published rows: a key a row of 128 lanes, and two keys a row
+    ("v128", (160, 0, 17, 100), dict(span=4)),
+    ("k64", (160, 0, 17, 100), dict(span=4)),
+], ids=["v-edges", "k-edges", "v-idle", "k-idle", "v-layer1", "k-layer1",
+        "v-groups", "k-groups", "v-rows128", "k-rows128"])
+def test_the_index_scores_are_read_from_the_leaf_in_place(shape, lens, kw):
+    """``index_decode_scores_kernel`` (interpret mode) against
+    ``decode_index_scores`` of the GATHERED keys: the same float32
+    scores to 1e-5, ``-inf`` at the same positions — past the length,
+    and over every table entry the lane does not hold, whose blocks are
+    full of NaN here and poison nothing — and the same exact choice, as
+    a mask and as rows, wherever the k-th score stands clear of the
+    next by more than that."""
+    from dlrover_tpu.ops import paged_kernels as pk
+
+    kw = dict(kw)
+    span = kw.pop("span", None)
+    a = _index_case(shape, lens, **kw)
+    want = np.asarray(pa.decode_index_scores(
+        a["qi"], a["w"],
+        pa.gather_index_keys(a["leaf"], a["tables"], a["di"], "jnp"),
+        a["lens"],
+    ))
+    got = np.asarray(pk.index_decode_scores_kernel(
+        a["qi"], a["w"], a["leaf"], a["tables"], a["lens"], span=span
+    ))
+    t = a["tables"].shape[1] * a["bs"]
+    assert got.shape == want.shape == (len(lens), t)
+    counts = np.minimum(np.asarray(a["lens"]), t)
+    finite = np.arange(t)[None] < counts[:, None]
+    np.testing.assert_array_equal(np.isfinite(want), finite)
+    np.testing.assert_array_equal(np.isneginf(got), ~finite)
+    # 1e-5 of the scores' size: the heads' sum runs in another order
+    size = max(1.0, float(np.abs(want[finite]).max()))
+    np.testing.assert_allclose(
+        got[finite], want[finite], rtol=1e-5, atol=1e-5 * size
+    )
+    # the seam hands the kernel the same leaf and tables
+    view = pa.gather_index_keys(a["leaf"], a["tables"], a["di"], "pallas")
+    assert isinstance(view, pa.IndexKeyView)
+    assert view.shape == (len(lens), t, a["di"])
+    np.testing.assert_array_equal(np.asarray(pa.decode_index_scores(
+        a["qi"], a["w"], view, a["lens"]
+    )), np.asarray(pk.index_decode_scores_kernel(
+        a["qi"], a["w"], a["leaf"], a["tables"], a["lens"]
+    )))
+    for k in (1, 5, 16):
+        ordered = -np.sort(-want, axis=1)
+        with np.errstate(invalid="ignore"):  # -inf less -inf
+            clear = (counts <= k) | (
+                ordered[:, k - 1] - ordered[:, min(k, t - 1)] > 1e-4 * size
+            )
+        assert clear.any()
+        for pick in (
+            lambda s: pa.exact_topk_mask(jnp.asarray(s), k),
+            lambda s: pa.exact_topk_rows(
+                jnp.asarray(s), k, a["tables"], with_mask=True
+            )[1],
+        ):
+            np.testing.assert_array_equal(
+                np.asarray(pick(got))[clear], np.asarray(pick(want))[clear]
+            )
+
+
 @pytest.mark.parametrize("k,streams", [(32, True), (31, False)])
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_decode_attention_picks_its_fetch_by_the_tables_width(
@@ -794,7 +922,7 @@ def test_the_pool_holds_the_paged_leaves_alone():
     assert sorted(pool) == ["c", "ik", "kpe"]  # no k, no v, no stand-in
     assert pool["c"].shape == (3, 10, 4, 32)  # a latent a row
     assert pool["kpe"].shape == (3, 10, 1, 32)  # a block's keys one row
-    assert pool["ik"].shape == (3, 10, 4 * 16)  # flat, as Keye-VL's
+    assert pool["ik"].shape == (3, 10, 1, 4 * 16)  # in rows, as Keye-VL's
     # bytes a block over the three leaves and three layers, float32
     assert block_nbytes(pool, cache.paged_names) == 3 * 4 * (32 + 8 + 16) * 4
 
@@ -807,7 +935,7 @@ def test_a_token_of_a_layer_keeps_1408_bytes_at_the_published_widths():
     assert sorted(pool) == ["c", "ik", "kpe"]
     assert pool["c"].shape == (7, 65, 16, 512)
     assert pool["kpe"].shape == (7, 65, 8, 128)  # two tokens a row
-    assert pool["ik"].shape == (7, 65, 16 * 128)
+    assert pool["ik"].shape == (7, 65, 16, 128)  # an index key a row
     per_token_layer = sum(
         a.size * a.dtype.itemsize for a in pool.values()
     ) // (7 * 65 * 16)
@@ -829,7 +957,7 @@ def test_a_block_ship_carries_every_leaf_bit_for_bit():
     }
     regions = extract_block_regions(pool, [3, 7], cache.paged_names)
     assert [r.shape for r in regions] == [
-        (3, 2, 4, 32), (3, 2, 1, 32), (3, 2, 4 * 16)
+        (3, 2, 4, 32), (3, 2, 1, 32), (3, 2, 1, 4 * 16)
     ]
     other = insert_block_regions(
         init_block_pool(cache), [5, 1], *regions, leaves=cache.paged_names

@@ -181,9 +181,10 @@ def sound():
     return serve, serve(), plen
 
 
-@pytest.mark.parametrize("patched", ["decode", "prefill"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("patched", ["decode", "prefill", "gather"])
 def test_a_patched_module_is_honoured_by_the_next_trace(
-    patched, sound, monkeypatch
+    patched, backend, sound, monkeypatch
 ):
     """In ONE process: a request served sound; then the planted fault of
     ``family_deepseek_v32_faulty`` assigned to
@@ -192,26 +193,68 @@ def test_a_patched_module_is_honoured_by_the_next_trace(
     scheduler, whose programs are traced anew.  The rows of the patched
     program pick the NEWEST ``index_topk`` positions, the other
     program's rows what they picked before: no piece traced before the
-    patch was handed to a program traced after it."""
+    patch was handed to a program traced after it.
+
+    ``gather``: ``gather_index_keys`` wrapped as the limits' probes wrap
+    it (``benchmarks/tolerance_probe_deepseek_v32.py``,
+    ``ik_previous_layer``: the tables of the layer before), here for
+    every lane's tables alone, so that the chunk program stays sound:
+    the prompt's rows are what they were, and a layer past the first
+    picks something else in the decode program's rows.  Under the
+    ``pallas`` backend (interpret mode) the decode program gathers
+    nothing and scores the leaf in place
+    (``index_decode_scores_kernel``): an assigned ``decode_index_scores``
+    replaces the kernel and a wrapped gather hands it the shifted
+    tables, so both faults bite there as they do on the chip; and with
+    the module restored the next scheduler picks the sound rows
+    again."""
+    monkeypatch.setenv(pa.PAGED_KERNEL_ENV, backend)
     serve, picked, plen = sound
     topk = M.DeepSeekV32Config.tiny().index_topk
     rows = picked.shape[0] - 1  # the last new token computed no row
+    if backend == "pallas":  # the sound choice does not hang on the backend
+        assert (serve()[:rows] == picked[:rows]).all()
     newest = np.zeros_like(picked[:rows])
     for t in range(rows):
         newest[t, :, max(0, t + 1 - topk):t + 1] = True
     assert not (picked[topk:rows] == newest[topk:]).all()
 
     # what ``_newest`` assigns, restored when the test ends
-    monkeypatch.setattr(pa, "decode_index_scores", pa.decode_index_scores)
-    monkeypatch.setattr(pa, "prefill_index_scores", pa.prefill_index_scores)
-    kept = "prefill" if patched == "decode" else "decode"
-    keep = getattr(pa, f"{kept}_index_scores")
-    faulty._newest(pa)
-    setattr(pa, f"{kept}_index_scores", keep)
-    served = serve()[:rows]
-    # row j is what the program decided while it computed position j:
-    # the prompt's rows are the chunk program's, the others decode's
-    mine = slice(plen, rows) if patched == "decode" else slice(0, plen)
-    assert (served[mine] == newest[mine]).all()
-    if patched == "decode":
+    names = ("decode_index_scores", "prefill_index_scores",
+             "gather_index_keys")
+    sound_fns = {name: getattr(pa, name) for name in names}
+    for name, fn in sound_fns.items():
+        monkeypatch.setattr(pa, name, fn)
+    if patched == "gather":
+        gather, nb = pa.gather_index_keys, 40  # ``_serve_one``'s blocks
+
+        def previous(ik_pool, tables, width):
+            if tables.ndim == 1:  # the chunk's: one sequence's table
+                return gather(ik_pool, tables, width)
+            return gather(
+                ik_pool, jnp.where(tables >= nb, tables - nb, tables), width
+            )
+
+        pa.gather_index_keys = previous
+        served = serve()[:rows]
         assert (served[:plen] == picked[:plen]).all()
+        # the first new token is the sound chunk's: the first layer, whose
+        # tables are its own, picks for it what it picked
+        assert (served[plen, 0] == picked[plen, 0]).all()
+        assert not (served[plen:, 1:] == picked[plen:rows, 1:]).all()
+    else:
+        kept = "prefill" if patched == "decode" else "decode"
+        keep = getattr(pa, f"{kept}_index_scores")
+        faulty._newest(pa)
+        setattr(pa, f"{kept}_index_scores", keep)
+        served = serve()[:rows]
+        # row j is what the program decided while it computed position
+        # j: the prompt's rows are the chunk program's, the others
+        # decode's
+        mine = slice(plen, rows) if patched == "decode" else slice(0, plen)
+        assert (served[mine] == newest[mine]).all()
+        if patched == "decode":
+            assert (served[:plen] == picked[:plen]).all()
+    for name, fn in sound_fns.items():
+        setattr(pa, name, fn)
+    assert (serve()[:rows] == picked[:rows]).all()

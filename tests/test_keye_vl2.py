@@ -551,7 +551,7 @@ def test_the_pool_holds_the_index_key_in_k_and_vs_blocks():
     cache = paged_cache_config(CFG, 10, 4, 3)
     assert cache.paged_names == ("k", "v", "ik") and cache.lane_state == ()
     pool = init_block_pool(cache)
-    assert pool["ik"].shape == (2, 10, 4 * 8)  # a block's keys in a row
+    assert pool["ik"].shape == (2, 10, 1, 4 * 8)  # a block's keys one row
     assert pool["k"].shape == pool["v"].shape == (2, 10, 4, 2, 16)
     assert region_nbytes_per_block(pool, "ik") == 2 * 4 * 8 * 4
     assert region_nbytes_per_block(pool) == 2 * 4 * 2 * 16 * 4
@@ -618,7 +618,7 @@ def test_a_shipped_prefill_is_adopted_with_its_index_keys(params):
             break
     payload = pre.shipped.pop()
     assert payload["req_id"] == rid and payload["n_blocks"] == 7
-    assert payload["ik"].shape == (2, 7, 4 * 8)
+    assert payload["ik"].shape == (2, 7, 1, 4 * 8)
     dec = make_scheduler(params, capture_logprobs=False, max_slots=2)
     adopted = dec.submit(
         prompt, max_new=10, seed=4,

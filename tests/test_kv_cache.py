@@ -704,7 +704,9 @@ class TestLayerKeeps:
 
 
 #: ``init_block_pool``'s shape tree at each serving cell's geometry, as
-#: the parent of ISSUE 49 made it: the new declaration moves none
+#: the parent of ISSUE 49 made it: the new declaration moves none (PR 58
+#: laid K's index keys in rows of 128 lanes: the same bytes in the same
+#: order, ``[5, 18240, 1024]`` before)
 CELL_POOLS = {
     "deepseek7b-rollout-c16": {
         "k": ((5, 1152, 16, 32, 128), "bfloat16"),
@@ -717,7 +719,7 @@ CELL_POOLS = {
         "v": ((6, 2304, 16, 4, 128), "bfloat16"),
     },
     "keye-vl2-rollout-c16-ctx16k": {
-        "ik": ((5, 18240, 1024), "bfloat16"),
+        "ik": ((5, 18240, 8, 128), "bfloat16"),
         "k": ((5, 18240, 16, 4, 128), "bfloat16"),
         "v": ((5, 18240, 16, 4, 128), "bfloat16"),
     },

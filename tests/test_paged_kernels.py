@@ -761,6 +761,27 @@ class TestBenchHarness:
                          "streamed_max_abs_diff_vs_jnp"):
                 assert row[name] < 5e-6
 
+    def test_index_decode_rows_set_gathered_beside_streamed(self):
+        """``--index-decode``: a row a case, a count of held positions
+        and a span, the gathered form's time beside the streamed
+        kernel's on the same keys, with what the two differ by."""
+        bpa = self._module()
+        rows = bpa.bench_index_decode(
+            spans=(None, 2), reps=2,
+            dims={"tiny": dict(lanes=3, heads=4, dim=16, entries=8,
+                               topk=40, held=(64, 128))},
+        )
+        assert [(r["held"], r["span"]) for r in rows] == [
+            (64, 8), (64, 2), (128, 8), (128, 2),
+        ]
+        for row in rows:
+            assert row["kernel"] == "index_decode_scores"
+            assert row["case"] == "tiny" and row["table"] == 128
+            assert row["gathered_us"] > 0 and row["streamed_us"] > 0
+            assert row["same_finite"] and not row["topk_differ"]
+            assert not row["topk_differ_from_exact"]
+            assert row["gathered_err"] < 1e-5 and row["streamed_err"] < 1e-5
+
     def test_selection_row_times_three_forms_of_one_choice(self):
         bpa = self._module()
         (row,) = bpa.bench_selection(lanes=3, positions=128, topk=40, reps=2)

@@ -395,6 +395,26 @@ def _mla_decode_case(entries=512, form="streamed"):
     )
 
 
+def _index_decode_case(model="v32", span=None):
+    """The decode step's index scores from the leaf in place, at the
+    published widths and the cells' geometry: DeepSeek-V3.2 (32 lanes,
+    64 index heads of 128, seven layers' 18240 blocks of 16, an index
+    key a 128-lane row, a table of 512 entries) or Keye-VL-2.0 (16
+    lanes, 16 heads of 64, five layers' blocks, two keys a row, a table
+    of 1024)."""
+    from dlrover_tpu.ops.paged_kernels import index_decode_scores_kernel
+
+    lanes, heads, dim, layers, rows, entries = {
+        "v32": (32, 64, 128, 7, 16, 512),
+        "keye": (16, 16, 64, 5, 8, 1024),
+    }[model]
+    return partial(index_decode_scores_kernel, span=span), (
+        ((lanes, heads, dim), BF16), ((lanes, heads), jnp.float32),
+        ((layers * 18240, rows, 128), BF16),
+        ((lanes, entries), jnp.int32), ((lanes,), jnp.int32),
+    )
+
+
 def _mla_prefill_case(keys=4096):
     """A 512-row chunk's attention in multi-head form at DeepSeek-V3.2's
     widths: 128 heads, keys of 192 and values of 128 decompressed a
@@ -421,6 +441,8 @@ CASES = {
     "sparse_prefill": _sparse_prefill_case,
     "index_scores": _index_scores_case,
     "index_scores_64x128": lambda: _index_scores_case(512, 64, 128),
+    "index_decode_scores": _index_decode_case,
+    "index_decode_scores_keye": lambda: _index_decode_case("keye"),
     "moe_expert_ffn_decode": lambda: _expert_ffn_case(16),
     "moe_expert_ffn_chunk": lambda: _expert_ffn_case(2048),
     "ssm_decode_update": _ssm_case,
@@ -461,6 +483,8 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     ("mla_sparse_decode_rows_32k", "mla_sparse_decode"),
     ("mla_prefill", "mla_prefill"),
     ("index_scores", "index_scores"),
+    ("index_decode_scores", "index_decode_scores"),
+    ("index_decode_scores_keye", "index_decode_scores"),
     ("paged_window_decode", "paged_window_decode"),
     ("paged_full_decode_2048", "paged_full_decode"),
     ("paged_prefill_window", "paged_prefill_window"),
@@ -816,7 +840,8 @@ def _keye_vl2_step_case(program):
     geometry: the published widths (128 experts of 768, top-8; a 16 x
     64 indexer, top 2048) at depth 5, bf16 weights, 16 lanes, 18240
     blocks of 16 (4 KV heads), tables of 1024 blocks, the index key a
-    third paged leaf ``[5, 18240, 16 * 64]``, prefill chunk 2048; the
+    third paged leaf ``[5, 18240, 8, 128]`` (a block's keys in rows of
+    128 lanes, two a row), prefill chunk 2048; the
     experts each position chose ride out with the logprobs."""
     from dlrover_tpu.models import keye_vl2
 
@@ -827,7 +852,7 @@ def _keye_vl2_step_case(program):
         )
     )
     pool_shape = (5, 18240, 16, 4, 128)
-    paged = {"ik": ((5, 18240, 16 * 64), BF16)}
+    paged = {"ik": ((5, 18240, 8, 128), BF16)}
     if program == "decode":
         fn, rest = _scheduler_decode(
             partial(keye_vl2.paged_decode_step, cfg=cfg), 16, 1024, True
@@ -1037,10 +1062,13 @@ def test_sparse_block_reads_its_experts_and_index_keys_in_place(
 ):
     """The block with routed experts and an indexer, at its cell's
     geometry: the index-key leaf rides in the layer scan's carry beside
-    K and V (aliased, never copied: stored a block's keys side by side,
-    ``[L, blocks, 16 * 64]`` — a 64-wide minor axis made every program
-    copy the leaf in and out, 0.37 GB a call and as much a layer in a
-    chunk); no layer's ``[128, 2048, 768]`` expert stack is cut out of
+    K and V (aliased, never copied: stored a block's keys side by side
+    in rows of 128 lanes, ``[L, blocks, 8, 128]`` — a 64-wide minor axis
+    made every program copy the leaf in and out, 0.37 GB a call and as
+    much a layer in a chunk); the decode step hands the leaf WHOLE to
+    ``index_decode_scores``, which reads the lanes' own blocks, and
+    gathers no ``[16, 16384, 64]`` of keys (33.5 MB a layer before PR
+    58); no layer's ``[128, 2048, 768]`` expert stack is cut out of
     ``[5, 128, ...]`` (1.2 GB a layer before the experts were read at
     ``layer * 128`` of the flattened stacks); and the two kernels carry
     their names."""
@@ -1067,7 +1095,22 @@ def test_sparse_block_reads_its_experts_and_index_keys_in_place(
     assert kernel("sparse_paged_decode") == (program == "decode")
     assert kernel("sparse_prefill") == (program != "decode")
     assert kernel("index_scores") == (program != "decode")
+    assert kernel("index_decode_scores") == (program == "decode")
+    if program == "decode":
+        _index_keys_are_read_in_place(
+            text, ik_elems, r"16,(1024,8,128|16384,64|1024,1024)"
+        )
     assert "ragged-dot" not in text
+
+
+def _index_keys_are_read_in_place(text, leaf, gathered):
+    """A compiled decode step hands ``index_decode_scores`` the
+    index-key leaf whole (``leaf`` elements: where it lies, by the pins
+    on what is moved) and holds no bfloat16 array of every lane's table
+    of keys, gathered or relaid (``gathered``: its shapes — by entry,
+    by position, by flat block)."""
+    assert leaf in _kernel_operands(text, "index_decode_scores")
+    assert not re.search(rf"bf16\[({gathered})\]", text)
 
 
 @pytest.mark.parametrize(
@@ -1228,14 +1271,17 @@ def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
     the rotated shared keys and the index keys ALONE — no ``k``, no
     ``v`` — 1408 bytes a token and layer, every leaf aliased to the
     outputs and never moved (the rows' views ``[L * blocks * 16, 512]``
-    and ``[L * blocks * 8, 128]`` are merges of leading axes), no
+    and ``[L * blocks * 8, 128]`` are merges of leading axes), the
+    index keys read in place by the decode step's
+    ``index_decode_scores`` (no ``[32, 8192, 128]`` of them gathered:
+    67 MB a layer before PR 58), no
     layer's ``[8, 7168, 2048]`` expert matrices copied, and each kernel
     under the name a trace tells it by."""
     compiled, pool = _latent_step_compiled(program, one_chip)
     assert set(pool) == {"c", "kpe", "ik"}
     assert pool["c"].shape == (7, 18240, 16, 512)
     assert pool["kpe"].shape == (7, 18240, 8, 128)
-    assert pool["ik"].shape == (7, 18240, 16 * 128)
+    assert pool["ik"].shape == (7, 18240, 16, 128)
     pool_bytes = sum(math.prod(a.shape) * 2 for a in pool.values())
     assert pool_bytes == 7 * 18240 * 16 * 1408
     mem, text = compiled.memory_analysis(), compiled.as_text()
@@ -1268,29 +1314,39 @@ def test_latent_block_carries_its_two_leaves_in_place(program, one_chip):
         handed = _kernel_operands(text, "mla_sparse_decode")
         assert {math.prod(pool[n].shape) for n in ("c", "kpe")} <= set(handed)
         assert not re.search(r"bf16\[32,2048,(512|128)\]", text)
+        _index_keys_are_read_in_place(
+            text, math.prod(pool["ik"].shape),
+            r"32,(512,16,128|8192,128|512,2048)",
+        )
     assert kernel("mla_prefill") == (program != "decode")
     assert kernel("index_scores") == (program != "decode")
+    assert kernel("index_decode_scores") == (program == "decode")
     assert "ragged-dot" not in text
 
 
 # what the UNROLLED loop's programs read (the parent of PR 56, the same
 # case compiled from its tree): kernels by name, bytes of arguments, of
 # outputs aliased to them, and of temporaries — and the temporaries of
-# the programs whose loop calls jitted pieces, which are this tree's
+# the programs whose loop calls jitted pieces, which are this tree's.
+# Since PR 58 the decode step scores its index keys in place (a kernel
+# a layer more, 64.6 MB of temporaries less: every lane's table of keys
+# is no longer gathered), and the index keys lie in rows (a chunk's
+# temporaries 1.3-1.4 MB less)
 _UNROLLED = {
     "decode": (
-        {"rmsnorm_fwd": 23, "mla_sparse_decode": 7, "moe_expert_ffn": 6},
-        11748319744, 2876375040, 102251008, 103799296,
+        {"rmsnorm_fwd": 23, "mla_sparse_decode": 7, "moe_expert_ffn": 6,
+         "index_decode_scores": 7},
+        11748319744, 2876375040, 102251008, 39235584,
     ),
     "prefill_nohead": (
         {"index_scores": 28, "mla_prefill": 28, "rmsnorm_fwd": 22,
          "moe_expert_ffn": 5},
-        10723819008, 2876375040, 1214667264, 1214183424,
+        10723819008, 2876375040, 1214667264, 1212796416,
     ),
     "prefill_last": (
         {"index_scores": 28, "mla_prefill": 28, "rmsnorm_fwd": 23,
          "moe_expert_ffn": 6},
-        11748243456, 2876375040, 1217268224, 1216752128,
+        11748243456, 2876375040, 1217268224, 1215494144,
     ),
 }
 # a chunk's paths under ``attn`` and neither ``latent`` nor ``indexer``,
@@ -1320,7 +1376,8 @@ def test_latent_pieces_are_inlined_into_the_unrolled_program(
     to the outputs.  The temporaries are NOT the unrolled program's to
     the byte (the inliner's clones come in another order and the
     scheduler then decides otherwise): +1.5 MB in the decode step,
-    -0.5 MB in a chunk; pinned as read, so that a drift shows.  And a
+    -0.5 MB in a chunk at PR 56; pinned as read, so that a drift
+    shows.  And a
     device trace still tells every operation's role and part: no
     scoped path without its role, and under ``attn`` without a
     sub-part what the unrolled loop had there — nothing in the decode
