@@ -402,9 +402,10 @@ def _linear_inputs(x, lp, cfg: OlmoHybridConfig):
     )
 
 
-def _causal_conv(window, conv_w):
+def _causal_conv(window, conv_w, act=jax.nn.silu):
     """``window [..., T + K - 1, C]`` (the tail before the run, then
-    the run) -> ``silu(conv) [..., T, C]``; no bias."""
+    the run) -> ``act(conv) [..., T, C]``; no bias (``act`` None: the
+    sum as it is, ``models/lfm2_moe.py``)."""
     k = conv_w.shape[0]
     t = window.shape[-2] - (k - 1)
     out = 0.0
@@ -412,10 +413,10 @@ def _causal_conv(window, conv_w):
         out = out + conv_w[j] * lax.slice_in_dim(
             window, j, j + t, axis=window.ndim - 2
         )
-    return jax.nn.silu(out)
+    return out if act is None else act(out)
 
 
-def _conv_step(window, conv_w):
+def _conv_step(window, conv_w, act=jax.nn.silu):
     """:func:`_causal_conv` for ONE token of every lane, the window's
     ``K`` rows side by side ``[B, K * C]`` (oldest first: the lane's
     tail, then the token) -> ``[B, C]``: every operand stays ``[B,
@@ -424,7 +425,7 @@ def _conv_step(window, conv_w):
     out = 0.0
     for j in range(k):
         out = out + conv_w[j] * window[:, j * c:(j + 1) * c]
-    return jax.nn.silu(out)
+    return out if act is None else act(out)
 
 
 def _l2_normed(x):
@@ -590,10 +591,10 @@ class _Pages:
     token ``t`` of KV head ``h``).  The ``j``-th full layer addresses
     block ``id`` at ``j * blocks + id``."""
 
-    def __init__(self, pool: Dict, cfg: OlmoHybridConfig):
+    def __init__(self, pool: Dict, n_kv: int):
         self._shape = pool["k"].shape
         self.n_blocks, rows, self.head_dim = self._shape[1:]
-        self.n_kv = cfg.num_key_value_heads
+        self.n_kv = n_kv  # the rows a token has in a block
         self.block_size = rows // self.n_kv
         self.k, self.v = (
             pool[n].reshape((-1,) + self._shape[2:]) for n in ("k", "v")
@@ -704,7 +705,7 @@ def paged_prefill_chunk(
     )
 
     _, c = tokens.shape
-    pages = _Pages(pool, cfg)
+    pages = _Pages(pool, cfg.num_key_value_heads)
     bs = pages.block_size
     heads, taps = cfg.linear_num_value_heads, cfg.linear_conv_kernel_dim
     backend = paged_kernel_backend()
@@ -800,7 +801,7 @@ def paged_decode_step(
     )
 
     n = tokens.shape[0]
-    pages = _Pages(pool, cfg)
+    pages = _Pages(pool, cfg.num_key_value_heads)
     bs, mb = pages.block_size, block_tables.shape[1]
     backend = paged_kernel_backend()
     x = _embed(params, tokens, cfg)[:, None]  # [B, 1, D]

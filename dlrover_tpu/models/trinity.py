@@ -347,10 +347,11 @@ def _swiglu(h, w_gate, w_up, w_down, dt):
     return _proj((jax.nn.silu(gate) * up).astype(dt), w_down, dt)
 
 
-def _route(x, lp, cfg: TrinityConfig):
+def _route(x, lp, cfg: TrinityConfig, renorm_eps: float = 1e-20):
     """The router on ``x [N, D]``: float32 norm, float32 logits over
     every expert at full precision, ``s = sigmoid``, the top-k of ``s +
-    b`` and the chosen experts' ``s`` normalised to ``route_scale``.
+    b`` and the chosen experts' ``s`` normalised (``renorm_eps`` beside
+    their sum: ``models/lfm2_moe.py``'s is 1e-6) to ``route_scale``.
     -> (h' [N, D] in the compute dtype, ids [N, k] int32 among ALL
     experts, weights [N, k] float32)."""
     xf = x.astype(jnp.float32)
@@ -363,7 +364,7 @@ def _route(x, lp, cfg: TrinityConfig):
     ))
     _, ids = lax.top_k(s + lp["router_bias"], cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(s, ids, -1)
-    w = chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
+    w = chosen / (jnp.sum(chosen, -1, keepdims=True) + renorm_eps)
     return hf.astype(cfg.dtype), ids.astype(jnp.int32), w * cfg.route_scale
 
 

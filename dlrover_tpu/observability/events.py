@@ -385,6 +385,11 @@ DEVICE_SCOPE_PARTS = frozenset(
         # decompression and its multi-head attention, ``wo`` (the
         # indexer beside it runs under ``indexer``)
         "latent",
+        # a gated short-convolution layer (models/lfm2_moe.py), entered
+        # INSIDE ``attn`` as the kinds above are: the input projection,
+        # the two gates, the 3-tap depthwise sum with the read and the
+        # write of the lane's tail, the output projection
+        "conv",
         # final norm + logits of a serving step program
         "head",
         # final norm + logits + cross-entropy of the train step

@@ -352,6 +352,46 @@ def write_block_kv(
 
 
 # ---------------------------------------------------------------------------
+# heads narrower than the device's 128 lanes: ``r`` KV heads lie side by
+# side in one row of the pool (``rl/kv_cache.paged_cache_config``,
+# ``kv_row_heads``), ``[.., KV / r, r * hd]`` — the same bytes in the
+# same order as ``[.., KV, hd]``, so a token's K (or V) is written as it
+# comes (a reshape) and the kernels above read a model of ``KV / r`` KV
+# heads of ``r * hd``.  What is left to the model: a query head lies in
+# ITS KV head's part of a row-wide query, zeros elsewhere
+# (:func:`row_queries`), so a score is its own head's; the kernels scale
+# by ``(r * hd) ** -0.5``, so the model multiplies its queries by ``r **
+# 0.5``; and of the row-wide sum over the values a head keeps its own
+# part (:func:`row_outputs`).
+# ---------------------------------------------------------------------------
+
+
+def row_queries(q: jnp.ndarray, n_kv: int, r: int) -> jnp.ndarray:
+    """``q [N, H, hd]`` -> ``[N, H, r * hd]``: head ``i`` (of KV head
+    ``i // (H / n_kv)``, which is part ``kv % r`` of its row) in that
+    part, exact zeros in the others."""
+    if r == 1:
+        return q
+    n, nh, hd = q.shape
+    parts = jnp.eye(r, dtype=q.dtype)[None, None, :, None, :, None]
+    return (
+        q.reshape(n, n_kv // r, r, nh // n_kv, 1, hd) * parts
+    ).reshape(n, nh, r * hd)
+
+
+def row_outputs(out: jnp.ndarray, n_kv: int, r: int) -> jnp.ndarray:
+    """``[N, H, r * hd]`` (a paged attention's result over rows of ``r``
+    heads) -> ``[N, H, hd]``: each head's own part."""
+    if r == 1:
+        return out
+    n, nh, row = out.shape
+    out = out.reshape(n, n_kv // r, r, nh // n_kv, r, row // r)
+    return jnp.stack(
+        [out[:, :, part, :, part] for part in range(r)], axis=2
+    ).reshape(n, nh, row // r)
+
+
+# ---------------------------------------------------------------------------
 # learned sparse attention: an indexer scores every cached token from a
 # paged INDEX-KEY cache beside the K/V pool, an EXACT top-k picks the
 # token rows, and attention reads those rows alone.  A prefill chunk

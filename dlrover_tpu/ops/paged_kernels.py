@@ -80,6 +80,23 @@ CPU CI runs these kernels in interpret mode
 (``ops/pallas_utils.use_interpret``); on TPU the same bodies lower to
 Mosaic (``tests/test_tpu_compile.py`` compiles them for a described
 v5e at head_dim 128, ``chip_smoke.py`` runs them on the chip).
+
+**Head widths.**  Every kernel here is compiled and run at a minor axis
+of 128 lanes and at no other: the dense, hybrid, window / full and
+selected-keys models have heads of 128 (the benchmark's cells C, F, K,
+T, O run them on the chip).  A model with NARROWER heads does not come
+here with a minor axis of its own: it lays ``r = 128 / head_dim`` KV
+heads side by side in one row of the pool (``rl/kv_cache.py``
+``kv_row_heads``; ``models/lfm2_moe.py``: two heads of 64) and these
+kernels read a model of ``KV / r`` heads of 128 — the queries zero
+outside their own head's part of the row, the part cut from the result
+(``ops/paged_attention.row_queries`` / ``row_outputs``).
+``tests/test_tpu_compile.py`` compiles ``paged_full_decode`` and
+``paged_prefill_full`` at that shape (``*_kv64``: 256 lanes, 4 rows of
+128 a token, 8 query rows a KV row) and the benchmark's cell M runs them
+on the chip.  The latent and index-key kernels further down have minor
+axes of their own (512, 128: ``paged_leaf_rows()``), compiled and run at
+V's, L's and K's shapes.
 """
 
 from __future__ import annotations

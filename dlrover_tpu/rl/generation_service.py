@@ -437,6 +437,40 @@ def kimi_linear_factory(**cfg_kwargs):
     }
 
 
+def lfm2_moe_factory(**cfg_kwargs):
+    """Built-in factory of the decoder with gated short-convolution
+    layers between grouped-query attention layers over routed experts
+    held whole (``models/lfm2_moe.py``): the same worker contract, with
+    the model's own step programs — its config declares a conv tail a
+    lane in the layers of the first kind (``cfg.lane_state()``,
+    ``cfg.layer_keeps()``), pages in rows of two 64-wide KV heads in the
+    others (``cfg.kv_row_heads``), so its prefill is told the lane and
+    the count of real tokens, and they return the experts every position
+    was sent to (``cfg.per_token_outputs()``) — and its own
+    ``serving_params_fn``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import lfm2_moe
+
+    if isinstance(cfg_kwargs.get("dtype"), str):
+        # the spec rides through JSON: dtype arrives as a name
+        cfg_kwargs = dict(cfg_kwargs, dtype=jnp.dtype(cfg_kwargs["dtype"]))
+    cfg = lfm2_moe.Lfm2MoeConfig(**cfg_kwargs)
+    return {
+        "forward_fn": partial(lfm2_moe.forward, cfg=cfg),
+        "params_template_fn": lambda: lfm2_moe.init_params(
+            jax.random.PRNGKey(0), cfg
+        ),
+        "cfg": cfg,
+        "paged_decode_fn": partial(lfm2_moe.paged_decode_step, cfg=cfg),
+        "paged_prefill_fn": partial(lfm2_moe.paged_prefill_chunk, cfg=cfg),
+        "serving_params_fn": partial(lfm2_moe.serving_params, cfg=cfg),
+    }
+
+
 def worker_main() -> int:
     """Generation-process entry (``python -m
     dlrover_tpu.rl.generation_service``); spec arrives via env."""

@@ -77,6 +77,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
+#: the lanes of the device's vector registers: the minor axis its memory
+#: is tiled in (a narrower minor axis is padded to it)
+ROW_LANES = 128
+
 
 def blocks_needed(n_tokens: int, block_size: int) -> int:
     """Blocks needed to hold ``n_tokens`` cache positions."""
@@ -270,6 +274,21 @@ def paged_cache_config(
     padded to 32 in memory, and turning it into the kernels' view moves
     the whole pool.
 
+    And ``kv_row_heads = r`` — a row of the pool holds ``r`` KV heads
+    side by side, ``k``, ``v`` ``[.., KV / r, r * head_dim]``: again the
+    same bytes in the same order, so a token's keys are written as they
+    come, and the paged kernels see ``KV / r`` heads of ``r * head_dim``
+    (``ops/paged_attention.row_queries`` says what is left to the model:
+    ``models/lfm2_moe.py``, two heads of 64 a row).  For heads narrower
+    than the device's 128 lanes: a minor axis of 64 is padded to 128 —
+    the pool then takes twice its bytes — or laid out otherwise, and the
+    kernels are built for rows of 128.  A model whose ``head_dim`` is no
+    multiple of ``ROW_LANES`` although whole rows of its heads exist
+    (``ROW_LANES / head_dim`` divides ``n_kv_heads``) and that does not
+    declare them is REFUSED by name; narrower heads that fill no row (a
+    test's sizes) pass, and a declared row is either ``ROW_LANES`` wide
+    or the token's every head.
+
     A model whose attention reads one compressed row a token for every
     head declares ``pages_kv = False``: its tokens keep NO per-head keys
     and values, so the pool holds its ``paged_leaves()`` alone
@@ -357,6 +376,29 @@ def paged_cache_config(
             )
         leaf_rows[name] = minor
     leaf_rows = tuple(leaf_rows.items())
+    n_kv, head_dim = 0, 0
+    if pages_kv:
+        n_kv, head_dim = model_cfg.n_kv_heads, model_cfg.head_dim
+        row_heads = int(getattr(model_cfg, "kv_row_heads", 1))
+        fit = ROW_LANES // head_dim if ROW_LANES % head_dim == 0 else 0
+        if row_heads == 1 and fit > 1 and n_kv % fit == 0:
+            raise ValueError(
+                f"head_dim {head_dim}: a pool whose minor axis is "
+                f"{head_dim} of the device's {ROW_LANES} lanes is padded "
+                f"to twice its bytes or more; declare kv_row_heads = {fit} "
+                f"(a row of {fit} KV heads side by side) and read it "
+                "through ops/paged_attention.row_queries / row_outputs"
+            )
+        if row_heads < 1 or n_kv % row_heads or (
+            row_heads > 1 and row_heads != n_kv
+            and row_heads * head_dim != ROW_LANES
+        ):
+            raise ValueError(
+                f"kv_row_heads {row_heads}: rows of {row_heads} of the "
+                f"{n_kv} KV heads of {head_dim} are neither {ROW_LANES} "
+                "lanes wide nor a token's every head"
+            )
+        n_kv, head_dim = n_kv // row_heads, head_dim * row_heads
     table_blocks = 0
     if not any(w is not None for w in windows):
         windows = ()
@@ -409,8 +451,8 @@ def paged_cache_config(
             keeps = ()  # what every layer does undeclared
     return PagedCacheConfig(
         n_layers=model_cfg.n_layers,
-        n_kv_heads=model_cfg.n_kv_heads if pages_kv else 0,
-        head_dim=model_cfg.head_dim if pages_kv else 0,
+        n_kv_heads=n_kv,
+        head_dim=head_dim,
         num_blocks=num_blocks,
         block_size=block_size,
         dtype=model_cfg.dtype,

@@ -414,7 +414,8 @@ class ContinuousBatchingScheduler:
     What a model must provide (``models/llama.py``,
     ``models/falcon_h1.py``, ``models/keye_vl2.py``,
     ``models/trinity.py``, ``models/olmo_hybrid.py``,
-    ``models/deepseek_v32.py`` and ``models/kimi_linear.py`` do):
+    ``models/deepseek_v32.py``, ``models/kimi_linear.py`` and
+    ``models/lfm2_moe.py`` do):
 
     - ``model_cfg``: the paged K/V geometry as attributes
       (``n_layers``, ``n_kv_heads``, ``head_dim``, ``dtype``) and,
@@ -878,6 +879,14 @@ class ContinuousBatchingScheduler:
         # ``kv_rows_window`` / ``kv_rows_full``); every model: a chunk's
         # shape (``prefill`` span labels ``rows`` / ``kv_len``)
         self._step_kv_rows = [0, 0]
+        # and a model whose layers divide between pages of K / V and
+        # lane state (``layer_keeps()``): ``kv_rows_full`` alone, every
+        # cached position of the lanes that decoded over the layers
+        # that page
+        self._counts_full_rows = bool(
+            self.window is None and cache_cfg.pages_kv
+            and cache_cfg.layer_keeps
+        )
         self._step_chunk: Dict = {}
         self._window_counts = (0, 0)
         self.sel_rows = self.index_bytes = 0
@@ -2194,6 +2203,9 @@ class ContinuousBatchingScheduler:
                     self._advance_window(slot, pos, pos + 1)
                     self._step_kv_rows[0] += min(pos + 1, self.window)
                     self._step_kv_rows[1] += pos + 1
+            elif self._counts_full_rows:
+                at = self._positions[[slot for slot, _ in lanes]]
+                self._step_kv_rows[1] += int(at.sum()) + len(lanes)
             packed = np.empty((S, MW + 2), np.int32)
             packed[:, :MB] = self._tables
             packed[:, MB:MW] = self._wtables
@@ -2575,6 +2587,11 @@ class ContinuousBatchingScheduler:
                 window_blocks_taken=blocks.allocated - taken,
                 window_blocks_released=blocks.released - released,
                 full_blocks_live=self.block_pool.used_blocks,
+            )
+        elif self._counts_full_rows:
+            out.update(
+                kv_rows_full=self._step_kv_rows[1]
+                * self.pool_cfg.n_full_layers,
             )
         return out
 

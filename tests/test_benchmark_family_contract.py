@@ -108,6 +108,16 @@ TINY = {
             full_attn_layers=[4], num_heads=4, head_dim=16,
         ),
     ),
+    # both dense layers and one whole period of the published pattern
+    # behind them (layers 2-5: full_attention conv conv conv); heads of
+    # 16, two KV heads a row of the pool; every one of 8 experts held
+    # (``deployment`` stays the configuration's: one chip a layer)
+    "family_lfm2_moe": lambda cfg: dict(
+        hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=6, layer_types=cfg["published"]["layer_types"][:6],
+        num_expert_layers=4, vocab_size=384, num_attention_heads=4,
+        num_key_value_heads=2, num_experts=8, num_experts_per_tok=2,
+    ),
 }
 
 

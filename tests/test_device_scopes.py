@@ -123,10 +123,11 @@ def test_the_reader_repeats_the_programs_closed_set():
     # prefill's chunk scan; ``latent`` (PR 53) likewise: latent
     # attention's own work beside its indexer; ``kda_scan`` (PR 57)
     # inside ``linear`` as ``gdn_scan`` is: Kimi Delta Attention's
-    # chunk scan
+    # chunk scan; ``conv`` (PR 59) inside ``attn`` as the kinds are: a
+    # gated short-convolution layer
     assert set(readers_scopes.PARTS) | {
         "indexer", "window", "full", "linear", "gdn_scan", "kda_scan",
-        "latent",
+        "latent", "conv",
     } == DEVICE_SCOPE_PARTS
     assert not DEVICE_SCOPE_ROLES & DEVICE_SCOPE_PARTS
 
@@ -379,6 +380,71 @@ def test_serving_program_heavy_instructions_carry_one_role_and_part(
     body = [path for _, path in ops if "/while/body/" in path]
     loose = {primitive(p) for p in body if len(scopes_on(p)) < 2}
     assert loose <= _PLUMBING, loose - _PLUMBING
+
+
+def _lfm2_program(name):
+    """LFM2-MoE's step programs as the scheduler jits them, over the
+    pool its config asks ``rl/kv_cache`` for (conv tails, pages in rows
+    of two heads)."""
+    from dlrover_tpu.models import lfm2_moe
+    from dlrover_tpu.rl.kv_cache import init_block_pool, paged_cache_config
+    from dlrover_tpu.rl.scheduler import decode_program, prefill_programs
+
+    cfg = lfm2_moe.Lfm2MoeConfig.tiny()
+    params = jax.eval_shape(
+        lambda: lfm2_moe.serving_params(
+            lfm2_moe.init_params(jax.random.PRNGKey(0), cfg), cfg
+        )
+    )
+    pool = jax.eval_shape(lambda: init_block_pool(
+        paged_cache_config(cfg, BLOCKS, BLOCK, LANES, CHUNK)
+    ))
+    if name == "decode":
+        step = partial(lfm2_moe.paged_decode_step, cfg=cfg)
+        return jax.jit(
+            decode_program(step, 1.0, True, MAX_BLOCKS, True)
+        ).lower(
+            params, pool, _spec((LANES,)), _spec((LANES, MAX_BLOCKS + 2)),
+            _spec((LANES, 2), jnp.uint32),
+        )
+    chunk = partial(lfm2_moe.paged_prefill_chunk, cfg=cfg)
+    _, last = prefill_programs(chunk, 1.0, True, True, True)
+    return jax.jit(last).lower(
+        params, pool, _spec((LANES,)), _spec((LANES, 2), jnp.uint32),
+        _spec((1, CHUNK)), _spec((MAX_BLOCKS,)), _spec(()), _spec(()),
+        _spec(()),
+    )
+
+
+@pytest.mark.parametrize("role", ["decode", "prefill"])
+def test_short_conv_layers_run_under_conv_inside_attn(role):
+    """``models/lfm2_moe.py``: every heavy instruction carries its one
+    role; under ``attn`` it carries the layer's kind as well — ``conv``
+    (the projections around the taps, the tail's read and write) or
+    ``full`` — and elsewhere one part; the tail is written under
+    ``conv`` and nowhere else."""
+    ops = operations(_lfm2_program(role))
+    heavy = [(op, path) for op, path in ops if op in HEAVY]
+    assert len(heavy) >= 8
+    kinds = set()
+    for op, path in heavy:
+        found = scopes_on(path)
+        assert [s for s in found if s in DEVICE_SCOPE_ROLES] == [role], path
+        parts = [s for s in found if s in DEVICE_SCOPE_PARTS]
+        if parts[:1] == ["attn"]:
+            assert len(parts) == 2 and parts[1] in ("conv", "full"), path
+            kinds.add(parts[1])
+        else:
+            assert len(parts) == 1, (op, path)
+    assert kinds == {"conv", "full"}
+    assert {"mlp", "head", "sample", "embed"} <= {
+        s for _, path in ops for s in scopes_on(path)
+    }
+    # the reader's closed set lacks ``conv``: it counts the time under
+    # ``attn``, and ``readers_sparse.scope_share`` reads it with the
+    # part added
+    assert {readers_scopes.classify(path)[1] for _, path in ops
+            if "conv" in scopes_on(path)} == {"attn"}
 
 
 def _prefill_programs(name):
