@@ -1171,86 +1171,40 @@ def _mla_stream_kernel(
     scale: float,
 ):
     """One lane a grid step; the kernel fetches the blocks the lane
-    HOLDS from the two leaves itself (:func:`_stream_decode_kernel`'s
-    form: groups of ``span`` table entries, two slots a leaf, the next
-    group — or the next lane's first — in flight) and attends in
-    absorbed form over the positions its selection marks.  A position
-    that is not picked has exactly zero weight; one past the length is
-    zeroed before it is multiplied (the tail of the last held block,
-    and the stale rows of a group the lane holds in part, are garbage).
+    HOLDS from the two leaves itself (:func:`_stream_lane_blocks`:
+    groups of ``span`` table entries, two slots a leaf, the next group
+    — or the next lane's first — in flight) and attends in absorbed
+    form over the positions its selection marks.  A position that is
+    not picked has exactly zero weight; one past the length is zeroed
+    before it is multiplied (the tail of the last held block, and the
+    stale rows of a group the lane holds in part, are garbage).
 
     The rotated keys lie ``M / Dr`` tokens a row.  A 0/1 product a
     ``slab`` of positions lays each token's row under the token
     (``[slab, slab / per_row] x [slab / per_row, M]``: exact, a
     position's row is one row times 1), the other tokens' lanes are
     zeroed, and the query — laid under every token's lanes by the
-    caller — meets it in one product of ``M``."""
+    caller — meets it in one product of ``M``.
+
+    Compiled under :data:`STREAM_PARAMS`, so NO dynamic address of the
+    kernel is checked, and each is in range by construction: the
+    scaffold clamps a table entry into its leaf and fills ``buf[slot,
+    s]`` at ``slot`` 0 or 1 and ``s < span``, the slots' own sizes;
+    ``c_buf[slot]`` / ``pe_buf[slot]`` are read at that ``slot``;
+    ``picked_ref[0, i]`` at a group ``i`` the lane holds, below the
+    ``ceil(MB / span)`` rows the caller pads the selection to (a lane
+    holds at most ``MB`` entries); ``tables_ref`` / ``lens_ref`` at a
+    lane of the grid and an entry below ``held(lane) <= MB``."""
     b = pl.program_id(0)
-    lanes = pl.num_programs(0)
     max_blocks = tables_ref.shape[1]
     pe_rows, width = pe_hbm.shape[1:]  # rows a block, lanes a row
     per_row = block_size // pe_rows  # tokens a row
     dr = width // per_row
+    seq_len = lens_ref[b]
 
     def held(lane):  # blocks a lane's table really holds
         blocks = lax.div(lens_ref[lane] + block_size - 1, block_size)
         return jnp.minimum(blocks, max_blocks)
-
-    def groups(lane):
-        return lax.div(held(lane) + span - 1, span)
-
-    def copies(lane, i, slot, arrive):
-        """Start, or wait for, the two copies of every block lane
-        ``lane`` holds of its group ``i`` — a loop of as many turns, not
-        unrolled; a whole group is waited for at once (a slot's
-        semaphore counts what arrived, and ``span`` blocks are the
-        buffer's size).  Entries past the lane's last block are neither
-        read from the table nor fetched."""
-        n_blocks = jnp.minimum(held(lane) - i * span, span)
-        leaves = ((c_hbm, c_buf), (pe_hbm, pe_buf))
-
-        def block(s, carry):
-            at = tables_ref[lane, i * span + s]
-            for leaf, (hbm, buf) in enumerate(leaves):
-                copy = pltpu.make_async_copy(
-                    hbm.at[at], buf.at[slot, s], sems.at[leaf, slot]
-                )
-                copy.wait() if arrive else copy.start()
-            return carry
-
-        if not arrive:
-            lax.fori_loop(0, n_blocks, block, 0)
-            return
-
-        @pl.when(n_blocks == span)
-        def _whole():
-            for leaf, (_, buf) in enumerate(leaves):
-                pltpu.make_async_copy(
-                    buf.at[slot], buf.at[slot], sems.at[leaf, slot]
-                ).wait()
-
-        @pl.when(n_blocks < span)
-        def _tail():
-            lax.fori_loop(0, n_blocks, block, 0)
-
-    start = functools.partial(copies, arrive=False)
-    wait = functools.partial(copies, arrive=True)
-
-    @pl.when(b == 0)
-    def _first_lane():
-        done[0] = 0
-
-    seq_len = lens_ref[b]
-    n_groups = groups(b)
-    base = done[0]
-    before = jnp.maximum(b - 1, 0)
-    after = jnp.minimum(b + 1, lanes - 1)
-    after_reads = (b + 1 < lanes) & (groups(after) > 0)
-
-    # the lane before starts this lane's first group, if it ran at all
-    @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
-    def _own_first_group():
-        start(b, 0, lax.rem(base, 2))
 
     _init_state(m_scr, l_scr, acc_scr)
     n_pos = span * block_size  # positions a group
@@ -1267,18 +1221,7 @@ def _mla_stream_kernel(
     )
     own = lax.rem(pos_row, per_row) == lax.div(_iota_cols(width), dr)
 
-    def group(i, carry):
-        slot = lax.rem(base + i, 2)
-
-        @pl.when(i + 1 < n_groups)
-        def _next_group():
-            start(b, i + 1, 1 - slot)
-
-        @pl.when((i + 1 == n_groups) & after_reads)
-        def _next_lane():
-            start(after, 0, 1 - slot)
-
-        wait(b, i, slot)
+    def attend(i, slot):
         at = i * n_pos  # the group's first position
         # Zero what lies past the length: 0 * NaN would poison the
         # accumulator, and the 0/1 product a whole slab.
@@ -1309,10 +1252,11 @@ def _mla_stream_kernel(
         keep = (picked_ref[0, pl.ds(i, 1), :] != 0) & (at + pos_col < seq_len)
         # the latent is key and value at once
         _online_update(m_scr, l_scr, acc_scr, s_log, c, keep)
-        return carry
 
-    lax.fori_loop(0, n_groups, group, 0)
-    done[0] = base + n_groups
+    _stream_lane_blocks(
+        tables_ref, held, ((c_hbm, c_buf), (pe_hbm, pe_buf)), sems, done,
+        span, attend,
+    )
     _finalize(o_ref, m_scr, l_scr, acc_scr)
 
 
@@ -1410,11 +1354,7 @@ def mla_stream_decode_kernel(
             out_shape=jax.ShapeDtypeStruct((batch, rows_p, dc), q_c.dtype),
             interpret=use_interpret(),
             name="mla_sparse_decode",
-            # the slot parity and the next lane's first group carry
-            # over a grid step: the lanes run in order
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)
-            ),
+            compiler_params=pltpu.CompilerParams(**STREAM_PARAMS),
         ),
     )(
         block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
@@ -1451,9 +1391,9 @@ def _stream_lane_blocks(tables_ref, held, leaves, sems, done, span, body):
     so a kernel built on this may drop the compiler's own bounds checks
     (:data:`STREAM_PARAMS`: they are 13 of the 21 bundles a copy costs
     the scalar core, ``PERF.md`` section 6, PR 58).
-    (:func:`_stream_decode_kernel` and
-    :func:`_mla_stream_kernel` carry this scaffold by hand still:
-    ``ROADMAP.md`` Queue 3 item 17 moves them here.)"""
+    (:func:`_index_decode_kernel` and :func:`_mla_stream_kernel` run on
+    it; :func:`_stream_decode_kernel` carries this scaffold by hand
+    still: ``ROADMAP.md`` Queue 3 item 17 moves it here.)"""
     b = pl.program_id(0)
     lanes = pl.num_programs(0)
 

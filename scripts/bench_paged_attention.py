@@ -637,6 +637,7 @@ def bench_latent(held=LATENT_HELD, entries=512, reps=20, dims=None):
         rest = (a["q_pe"], a["c"], a["pe"], a["rows"], a["lens"])
         row = {
             "kernel": "mla_sparse_decode", "held": positions,
+            "heads": dims["heads"],
             "table": width * dims["block_size"], "topk": n_sel,
             "gathered_us": round(
                 _chained_us(gathered, a["q_c"], rest, reps), 1
@@ -895,6 +896,11 @@ def main(argv=None) -> int:
         "512-entry table, and the three exact selections at [32, 8192]",
     )
     ap.add_argument(
+        "--heads", type=int, default=128,
+        help="with --latent or --latent-sweep: the heads a lane "
+        "(DeepSeek-V3.2's 128; Kimi Linear's latent layers have 32)",
+    )
+    ap.add_argument(
         "--latent-sweep", action="store_true",
         help="the same two fetches with the table as wide as what a lane "
         f"holds ({', '.join(str(h) for h in LATENT_SWEEP)} positions): "
@@ -932,13 +938,14 @@ def main(argv=None) -> int:
     if args.latent or args.latent_sweep:
         import jax
 
-        rows = []
+        rows, dims = [], dict(heads=args.heads)
         if args.latent:
-            rows += bench_latent(reps=max(args.reps, 20))
+            rows += bench_latent(reps=max(args.reps, 20), dims=dims)
             rows += bench_selection(reps=max(args.reps, 20))
         if args.latent_sweep:
             rows += bench_latent(
                 LATENT_SWEEP, entries=None, reps=max(args.reps, 20),
+                dims=dims,
             )
         _flush(args.out, {
             "bench": "mla_sparse_decode", "rows": rows,

@@ -471,12 +471,15 @@ def test_the_prefill_kernel_is_the_plain_multi_head_attention(start, kv_len):
 
 
 def _stream_case(lens, k, scores="random", *, h=4, bs=16, mb=8, rank=32,
-                 dr=8, minor=16, seed=3):
+                 dr=8, minor=16, seed=3, past=()):
     """Two leaves whose blocks lie scattered, every lane holding the
     blocks of its ``lens`` positions and the null block behind them;
     NaN in the null block, in every block no lane holds and in a last
     held block's rows past the length.  ``scores``: ``random``, or
-    ``tied`` (a few values, so that the ``k``-th is shared)."""
+    ``tied`` (a few values, so that the ``k``-th is shared).  ``past``:
+    ``(lane, entry)`` pairs of held table entries that name a block PAST
+    the leaves: a gather reads the leaves' last block there, which keeps
+    its numbers, and the block the entry named before is NaN."""
     rng = np.random.default_rng(seed)
     lens = np.asarray(lens, np.int32)
     b, t, n = len(lens), mb * bs, len(lens) * mb + 1
@@ -492,6 +495,10 @@ def _stream_case(lens, k, scores="random", *, h=4, bs=16, mb=8, rank=32,
         if length % bs:
             c_leaf[tables[i, held - 1], length % bs:] = np.nan
             pe_tok[tables[i, held - 1], length % bs:] = np.nan
+    for n_past, (i, entry) in enumerate(past):
+        assert entry < -(-int(lens[i]) // bs)
+        unheld[tables[i, entry]], unheld[n - 1] = True, False
+        tables[i, entry] = n + 3 * n_past
     c_leaf[unheld] = pe_tok[unheld] = np.nan
     score = rng.standard_normal((b, t)).astype(np.float32)
     if scores == "tied":
@@ -520,7 +527,9 @@ def _stream_case(lens, k, scores="random", *, h=4, bs=16, mb=8, rank=32,
 
 def _absorbed_by_hand(a, scale):
     """numpy, a lane at a time over the picked positions alone."""
-    tables, taken = np.asarray(a["tables"]), np.asarray(a["taken"])
+    taken = np.asarray(a["taken"])
+    # an entry past the leaves reads their last block, as a gather does
+    tables = np.minimum(np.asarray(a["tables"]), len(a["c_np"]) - 1)
     out = np.zeros(a["q_c"].shape, np.float32)
     bs = a["c_np"].shape[1]
     for i, length in enumerate(np.asarray(a["lens"])):
@@ -541,6 +550,12 @@ def _absorbed_by_hand(a, scale):
 #: 1 MiB): a table of 8 blocks is one group; float32 latents of 512 are
 #: read 16 blocks (two slabs of 128 positions) a group, of 1024 8 blocks
 _ONE, _G16, _G8 = dict(), dict(rank=512, mb=40), dict(rank=1024, mb=20)
+#: groups wider than a turn of the issue loop (``STREAM_UNROLL`` = 16
+#: blocks): 32 blocks of latents of 256 in a table of 80, and a table of
+#: 48 in one group; and entries of the second group that name a block
+#: past the leaves
+_G32, _G48 = dict(rank=256, mb=80), dict(mb=48)
+_PAST = dict(_G16, past=((0, 21), (1, 18)))
 
 
 @pytest.mark.parametrize("lens,k,scores,dims", [
@@ -552,14 +567,28 @@ _ONE, _G16, _G8 = dict(), dict(rank=512, mb=40), dict(rank=1024, mb=20)
     ((128, 77, 16), 128, "random", _ONE),  # a dense one: every row
     ((640, 290, 33), 12, "tied", _G16),  # a choice that ends on a tie
     ((320, 129, 33), 12, "tied", _G8),  # ... a slab a group
-], ids=["short", "edge1", "edge2", "idle", "sparse", "dense", "tie", "tie1"])
+    # 1, 15, 16 and 17 blocks of the second group (a remainder alone, a
+    # whole turn, a turn and a remainder; each waited for block by
+    # block), and a full table: two whole groups of two turns, then 16
+    ((520, 752, 760, 777, 1280), 12, "random", _G32),
+    # 1, 15, 16, 17, 33 and all 48 blocks of the one group
+    ((9, 240, 250, 272, 520, 768), 12, "random", _G48),
+    ((1280, 0, 1279), 1280, "random", _G32),  # every row, two lanes apart
+    # every row picked, so that what a clamped entry reads is weighed
+    ((640, 290, 33), 640, "random", _PAST),
+], ids=["short", "edge1", "edge2", "idle", "sparse", "dense", "tie", "tie1",
+        "turns32", "turns48", "dense32", "past"])
 def test_the_streamed_decode_kernel_reads_its_own_blocks_under_the_mask(
     lens, k, scores, dims
 ):
     """``mla_stream_decode_kernel`` (interpret mode) and the jnp form
     of the streamed fetch against numpy over the picked rows: a position
     that is not picked, or lies past the length, weighs nothing whatever
-    its block holds, and a lane of length 0 returns exact zeros."""
+    its block holds, and a lane of length 0 returns exact zeros.  The
+    copies are :func:`_stream_lane_blocks`': whole turns of 16 blocks, a
+    remainder, a group held in part waited for block by block, and a
+    table entry past the leaves clamped as the jnp form's gather clamps
+    it."""
     from dlrover_tpu.ops import paged_kernels as pk
 
     a = _stream_case(lens, k, scores, **dims)

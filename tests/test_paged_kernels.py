@@ -755,6 +755,7 @@ class TestBenchHarness:
         ]
         for row in rows:
             assert row["kernel"] == "mla_sparse_decode" and row["topk"] == 40
+            assert row["heads"] == 4
             assert row["gathered_us"] > 0 and row["streamed_us"] > 0
             for name in ("gathered_max_abs_diff_vs_jnp",
                          "streamed_max_abs_diff_vs_gathered_jnp",

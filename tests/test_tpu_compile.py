@@ -563,6 +563,29 @@ def test_serving_kernels_keep_their_names(case, name, one_chip):
     assert "closed_call" not in text
 
 
+@pytest.mark.parametrize("case,checked", [
+    ("mla_sparse_decode", False),  # the two leaves, streamed
+    ("index_decode_scores", False),
+    ("mla_sparse_decode_rows_32k", True),  # gathered rows: a BlockSpec a page
+])
+def test_streamed_kernels_carry_the_scaffolds_parameters(
+    case, checked, one_chip
+):
+    """A kernel on ``_stream_lane_blocks`` is compiled under
+    ``STREAM_PARAMS``: the Mosaic call's own config says the compiler's
+    bounds checks are off (they are most of what a copy costs the scalar
+    core, and the scaffold clamps what it addresses), and a kernel that
+    is not on it keeps them — so the hand-written loop cannot come back
+    unnoticed."""
+    fn, shapes = CASES[case]()
+    text = _compiled_text(fn, *shapes, sharding=one_chip)
+    (call,) = [
+        line for line in text.splitlines()
+        if "custom-call(" in line and "tpu_custom_call" in line
+    ]
+    assert ('"disable_bounds_checks":true' in call) != checked, case
+
+
 def _kernel_operands(text, name):
     """Element counts of what the instruction ``%name`` is handed, from
     the lines that define its operands."""
