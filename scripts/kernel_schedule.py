@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """What a kernel change should cost, before the chip is asked.
 
-Compiles a named kernel case of ``tests/test_tpu_compile.py`` for the
+Compiles a named kernel case of ``tests/tpu_compile_lib.py`` for the
 described ``v5e:2x2`` with the TPU compiler's own dump of its final
 VLIW schedule, and prints, for each ``pl.when`` body of the kernel (the
 span from one forward ``sbr.rel`` to the next in ``*-final_bundles.txt``),
@@ -41,7 +41,7 @@ def _compile(case: str) -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    import test_tpu_compile as cases
+    import tpu_compile_lib as cases
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -87,7 +87,7 @@ def bodies(bundles_path: str, utilization_path: str):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("case", help="a key of tests/test_tpu_compile.CASES")
+    ap.add_argument("case", help="a key of tests/tpu_compile_lib.CASES")
     ap.add_argument("--op", help="the kernel's name in the dump (default: the case)")
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)

@@ -28,6 +28,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     CONFIGS = {c["name"]: c for c in json.load(_f)["configs"]}
@@ -122,6 +123,9 @@ TINY = {
 
 
 def tiny_cfg(name):
+    """The configuration at the tiny sizes of its family: what
+    ``tiny_families`` is asked for (its parts and weights are made once
+    a configuration, for every case below)."""
     with open(os.path.join(REPO, CONFIGS[name]["file"])) as f:
         cfg = json.load(f)
     return dict(cfg, **TINY[cfg["family"]](cfg))
@@ -162,15 +166,13 @@ def test_train_parts_build_the_serving_model(cfg):
     assert {"model", "init_params_fn", "loss_fn", "param_axes",
             "forward"} <= set(parts)
     assert callable(fam.train_flops_per_token)
-    served = fam.serving_parts(**fam.model_kwargs(cfg, SEQ),
-                               dtype="bfloat16")
-    assert served["cfg"] == parts["model"]
+    assert T.parts(cfg, SEQ, "bfloat16")["cfg"] == parts["model"]
 
 
 def test_the_counts_are_the_tree(cfg):
     fam = harness.family(cfg)
-    params = fam.seeded_params(cfg, 2**31 + 5)
-    served = fam.serving_parts(**fam.model_kwargs(cfg, SEQ), dtype="float32")
+    params = T.params(cfg, 2**31 + 5)
+    served = T.parts(cfg, SEQ)
     # the reference's tree IS the program's: same leaves, same shapes
     template = jax.eval_shape(served["params_template_fn"])
     assert jax.tree_util.tree_map(
@@ -183,11 +185,11 @@ def test_the_counts_are_the_tree(cfg):
 
 def test_program_and_reference_agree_per_token(cfg):
     fam = harness.family(cfg)
-    params = fam.seeded_params(cfg, 2**31 + 5)
+    params = T.params(cfg, 2**31 + 5)
     tokens = np.random.default_rng(7).integers(
         0, cfg["vocab_size"], size=(2, SEQ + 1), dtype=np.int32
     )
-    served = fam.serving_parts(**fam.model_kwargs(cfg, SEQ), dtype="float32")
+    served = T.parts(cfg, SEQ)
     with jax.default_matmul_precision("highest"):
         got = jax.nn.log_softmax(
             served["forward_fn"](params, tokens[:, :-1]).astype(jnp.float32),
@@ -201,7 +203,7 @@ def test_program_and_reference_agree_per_token(cfg):
 def test_the_training_loss_is_the_reference_mean(cfg):
     fam = harness.family(cfg)
     parts = train_parts_or_skip(fam, cfg)
-    params = fam.seeded_params(cfg, 2**31 + 5)
+    params = T.params(cfg, 2**31 + 5)
     tokens = np.random.default_rng(7).integers(
         0, cfg["vocab_size"], size=(2, SEQ + 1), dtype=np.int32
     )

@@ -9,7 +9,6 @@ switch — over the tiny dense model and the tiny Falcon-H1, whose lanes
 keep a recurrent state beside their pages.
 """
 
-import json
 import os
 import sys
 from functools import partial
@@ -24,11 +23,10 @@ BENCH = os.path.join(REPO, "benchmarks")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-import reference_falcon_h1 as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
-from dlrover_tpu.models import falcon_h1, llama  # noqa: E402
+from dlrover_tpu.models import llama  # noqa: E402
 from dlrover_tpu.observability import events as ev  # noqa: E402
-from dlrover_tpu.rl.generation_service import falcon_h1_factory  # noqa: E402
 from dlrover_tpu.rl.scheduler import (  # noqa: E402
     FINISH_EOS,
     FINISH_LENGTH,
@@ -47,17 +45,7 @@ DENSE_CFG = llama.LlamaConfig.tiny(
     vocab_size=97, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
     mlp_dim=64, remat="none", dtype=jnp.float32,
 )
-with open(os.path.join(
-    BENCH, "tests", "tiny", "data", "configs", "tiny-falcon-h1.json"
-)) as _f:
-    HF = {
-        k: v for k, v in json.load(_f).items()
-        if k not in ("source", "family", "reduced", "assumed")
-    }
-FALCON = falcon_h1_factory(
-    **{k: v for k, v in HF.items() if k != "mamba_expand"},
-    max_seq_len=128, dtype="float32",
-)
+FALCON = T.parts("falcon_h1", 128)
 
 
 class _Model:
@@ -70,7 +58,7 @@ class _Model:
                 for s in (0, 1)
             ]
         else:
-            self.cfg, self.vocab = FALCON["cfg"], HF["vocab_size"]
+            self.cfg, self.vocab = FALCON["cfg"], FALCON["cfg"].vocab_size
             self.kw = {
                 k: FALCON[k] for k in (
                     "paged_decode_fn", "paged_prefill_fn",
@@ -78,7 +66,7 @@ class _Model:
                 )
             }
             self.params = [
-                R.seeded_params(HF, 2**31 + s) for s in (11, 12)
+                T.params("falcon_h1", 2**31 + s) for s in (11, 12)
             ]
 
     def scheduler(self, lockstep=False, events=None, **overrides):

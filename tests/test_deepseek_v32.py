@@ -14,7 +14,6 @@ sequences, so every test that serves also selects, and its router takes
 1 of 2 groups.
 """
 
-import json
 import os
 import sys
 
@@ -30,6 +29,7 @@ if BENCH not in sys.path:
 
 import family_deepseek_v32 as F  # noqa: E402
 import reference_deepseek_v32 as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 from dlrover_tpu.models import deepseek_v32 as M, llama  # noqa: E402
 from dlrover_tpu.observability.events import EventLogger  # noqa: E402
@@ -37,9 +37,6 @@ import dlrover_tpu.ops.grouped_gemm  # noqa: E402,F401  (the MODULE:
 # ``from dlrover_tpu.ops import grouped_gemm`` is the function)
 from dlrover_tpu.ops import paged_attention as pa  # noqa: E402
 from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV  # noqa: E402
-from dlrover_tpu.rl.generation_service import (  # noqa: E402
-    deepseek_v32_factory,
-)
 from dlrover_tpu.rl.kv_cache import (  # noqa: E402
     block_nbytes,
     extract_block_regions,
@@ -52,15 +49,11 @@ from dlrover_tpu.rl.scheduler import (  # noqa: E402
     SchedulerConfig,
 )
 
-with open(os.path.join(
-    BENCH, "tests", "tiny", "data", "configs", "tiny-deepseek-v32.json"
-)) as _f:
-    HF = json.load(_f)
-with open(os.path.join(BENCH, "configs", "deepseek-v3.2.json")) as _f:
-    PUBLISHED = json.load(_f)
+HF = T.config("deepseek_v32")
+PUBLISHED = T.published("deepseek-v3.2")
 grouped_gemm = sys.modules["dlrover_tpu.ops.grouped_gemm"]
-KW = dict(F.model_kwargs(HF, 64), dtype="float32")
-PARTS = deepseek_v32_factory(**KW)
+KW = T.kwargs("deepseek_v32", 64)
+PARTS = T.parts("deepseek_v32", 64)
 CFG = PARTS["cfg"]
 TOPK = HF["index_topk"]
 SCHED = dict(
@@ -71,7 +64,7 @@ SCHED = dict(
 
 @pytest.fixture(scope="module")
 def params():
-    return R.seeded_params(HF, 2**31 + 42)
+    return T.params("deepseek_v32", 2**31 + 42)
 
 
 @pytest.fixture(autouse=True)
@@ -82,15 +75,10 @@ def _exact_float32():
 
 def make_scheduler(params, events=None, capture_logprobs=True,
                    role="unified", **overrides):
-    sch = ContinuousBatchingScheduler(
-        CFG, SchedulerConfig(**dict(SCHED, **overrides)),
-        paged_decode_fn=PARTS["paged_decode_fn"],
-        paged_prefill_fn=PARTS["paged_prefill_fn"],
-        serving_params_fn=PARTS["serving_params_fn"],
-        capture_logprobs=capture_logprobs, events=events, role=role,
+    return T.scheduler(
+        PARTS, dict(SCHED, **overrides), params, events=events,
+        capture_logprobs=capture_logprobs, role=role,
     )
-    sch.sync_weights(params)
-    return sch
 
 
 def prompts_of(lengths, seed=1):
@@ -957,8 +945,7 @@ def test_the_pool_holds_the_paged_leaves_alone():
 
 
 def test_a_token_of_a_layer_keeps_1408_bytes_at_the_published_widths():
-    kw = F.model_kwargs(PUBLISHED, 8192)
-    cfg = deepseek_v32_factory(**kw, dtype="bfloat16")["cfg"]
+    cfg = T.parts(PUBLISHED, 8192, "bfloat16")["cfg"]
     cache = paged_cache_config(cfg, 65, 16, 32, 512)
     pool = jax.eval_shape(lambda: init_block_pool(cache))
     assert sorted(pool) == ["c", "ik", "kpe"]

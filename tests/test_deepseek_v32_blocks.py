@@ -11,7 +11,6 @@ Tiny widths on the CPU; what is read are counts of functions, calls and
 Python executions, never a time.
 """
 
-import json
 import os
 import re
 import sys
@@ -28,9 +27,8 @@ for _p in (BENCH, os.path.join(BENCH, "tests", "tiny", "data")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-import family_deepseek_v32 as F  # noqa: E402
 import family_deepseek_v32_faulty as faulty  # noqa: E402
-import reference_deepseek_v32 as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 from dlrover_tpu.models import deepseek_v32 as M  # noqa: E402
 from dlrover_tpu.ops import paged_attention as pa  # noqa: E402
@@ -39,8 +37,6 @@ from dlrover_tpu.rl.kv_cache import (  # noqa: E402
     paged_cache_config,
 )
 from dlrover_tpu.rl.scheduler import (  # noqa: E402
-    ContinuousBatchingScheduler,
-    SchedulerConfig,
     decode_program,
     prefill_programs,
 )
@@ -139,18 +135,11 @@ def _unpacked(words):
     return bits.reshape(words.shape[:-1] + (-1,)).astype(bool)
 
 
-def _serve_one(cfg, parts, params, prompt):
-    sch = ContinuousBatchingScheduler(
-        cfg, SchedulerConfig(
-            max_slots=2, block_size=4, num_blocks=40, max_seq_len=64,
-            prefill_chunk=12, temperature=1.0,
-        ),
-        paged_decode_fn=parts["paged_decode_fn"],
-        paged_prefill_fn=parts["paged_prefill_fn"],
-        serving_params_fn=parts["serving_params_fn"],
-        capture_logprobs=True,
-    )
-    sch.sync_weights(params)
+def _serve_one(parts, params, prompt):
+    sch = T.scheduler(parts, dict(
+        max_slots=2, block_size=4, num_blocks=40, max_seq_len=64,
+        prefill_chunk=12, temperature=1.0,
+    ), params)
     sch.submit(prompt, max_new=6, seed=0)
     (result,) = sch.run()
     return _unpacked(np.asarray(result.per_token["selection"]))
@@ -158,27 +147,38 @@ def _serve_one(cfg, parts, params, prompt):
 
 @pytest.fixture(scope="module")
 def sound():
-    """The tiny family's parts and one request served by sound
-    programs: ``(serve, rows picked, prompt length)``."""
-    from dlrover_tpu.rl.generation_service import deepseek_v32_factory
-
-    with open(os.path.join(
-        BENCH, "tests", "tiny", "data", "configs", "tiny-deepseek-v32.json"
-    )) as f:
-        hf = json.load(f)
-    parts = deepseek_v32_factory(
-        **dict(F.model_kwargs(hf, 64), dtype="float32")
-    )
-    params = R.seeded_params(hf, 2**31 + 56)
+    """The tiny family's parts and one request: ``(serve, picked,
+    prompt length)`` — ``serve()`` by a NEW scheduler under the backend
+    the environment names, ``picked(backend)`` the rows sound programs
+    pick under it, served once a module.  When the last case is over and
+    its patches are undone, the request is served once more under every
+    backend a case used: the restored module picks the sound rows again
+    (a piece traced for a patched module that outlived it shows here)."""
+    parts = T.parts("deepseek_v32", 64)
+    params = T.params("deepseek_v32", 2**31 + 56)
     plen = 42
     prompt = np.random.default_rng(5).integers(
-        0, hf["vocab_size"], size=plen
+        0, parts["cfg"].vocab_size, size=plen
     ).astype(np.int32)
 
     def serve():
-        return _serve_one(parts["cfg"], parts, params, prompt)
+        return _serve_one(parts, params, prompt)
 
-    return serve, serve(), plen
+    def under(backend):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv(pa.PAGED_KERNEL_ENV, backend)
+            return serve()
+
+    sound_rows = {}
+
+    def picked(backend):
+        if backend not in sound_rows:
+            sound_rows[backend] = under(backend)
+        return sound_rows[backend]
+
+    yield serve, picked, plen
+    for backend, rows in sound_rows.items():
+        assert (under(backend) == rows).all(), backend
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
@@ -206,14 +206,15 @@ def test_a_patched_module_is_honoured_by_the_next_trace(
     (``index_decode_scores_kernel``): an assigned ``decode_index_scores``
     replaces the kernel and a wrapped gather hands it the shifted
     tables, so both faults bite there as they do on the chip; and with
-    the module restored the next scheduler picks the sound rows
-    again."""
+    the module restored the next scheduler picks the sound rows again
+    (``sound``, once, after the last case)."""
     monkeypatch.setenv(pa.PAGED_KERNEL_ENV, backend)
-    serve, picked, plen = sound
+    serve, picked_under, plen = sound
     topk = M.DeepSeekV32Config.tiny().index_topk
+    picked = picked_under("jnp")
     rows = picked.shape[0] - 1  # the last new token computed no row
     if backend == "pallas":  # the sound choice does not hang on the backend
-        assert (serve()[:rows] == picked[:rows]).all()
+        assert (picked_under("pallas")[:rows] == picked[:rows]).all()
     newest = np.zeros_like(picked[:rows])
     for t in range(rows):
         newest[t, :, max(0, t + 1 - topk):t + 1] = True
@@ -255,6 +256,5 @@ def test_a_patched_module_is_honoured_by_the_next_trace(
         assert (served[mine] == newest[mine]).all()
         if patched == "decode":
             assert (served[:plen] == picked[:plen]).all()
-    for name, fn in sound_fns.items():
-        setattr(pa, name, fn)
-    assert (serve()[:rows] == picked[:rows]).all()
+    # the module is restored when the case ends, and the module-scoped
+    # ``sound`` serves the request again after the last of them

@@ -21,10 +21,9 @@ metric.
 import json
 import os
 import shutil
-import subprocess
-import sys
 
 import pytest
+import rehearsal
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")
@@ -94,23 +93,7 @@ def data_root(tmp_path_factory):
 
 
 def run(data_root, cell, trace):
-    """``harness.run_cell`` in a process of its own, as the command
-    line is one: the cell checks that the engine's parent never touched
-    the JAX backend, which a test process that ran other files has."""
-    code = (
-        "import json, sys\n"
-        f"sys.path.insert(0, {BENCH!r})\n"
-        "import harness\n"
-        f"line = harness.run_cell({cell!r}, {2**31 + 78}, 4.0, {trace}, "
-        f"expect_platform='cpu', data_root={data_root!r})\n"
-        "print(json.dumps(line))\n"
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=900,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return rehearsal.run_cell(cell, 2**31 + 78, 4.0, trace, "cpu", data_root)
 
 
 def test_the_cell_is_correct_and_reads_its_labels(data_root):

@@ -518,7 +518,7 @@ def test_the_last_chunks_head_hands_on_one_row(name, prefill_lowered):
     ``prefill`` / ``head``; the sum is the last that sees the chunk's
     width, so the compiler can fuse it into the product (that it does,
     and writes one row, is pinned on the compiled program:
-    ``tests/test_tpu_compile.py``)."""
+    ``tests/test_tpu_compile_*.py``)."""
     _, _, last = prefill_lowered(name)
     under_head = {
         primitive(path) for _, path in operations(last)

@@ -8,7 +8,6 @@ serves through chunked prefill and paged decode with the per-lane state
 in its pool.  Every multiplier of the tiny configuration is away from 1.
 """
 
-import json
 import os
 import sys
 from functools import partial
@@ -24,11 +23,11 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import reference_falcon_h1 as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 from dlrover_tpu.models import falcon_h1, llama  # noqa: E402
 from dlrover_tpu.ops import ssm  # noqa: E402
 from dlrover_tpu.rl.generation_service import (  # noqa: E402
-    falcon_h1_factory,
     tiny_llama_factory,
 )
 from dlrover_tpu.rl.scheduler import (  # noqa: E402
@@ -36,18 +35,12 @@ from dlrover_tpu.rl.scheduler import (  # noqa: E402
     SchedulerConfig,
 )
 
-with open(os.path.join(
-    BENCH, "tests", "tiny", "data", "configs", "tiny-falcon-h1.json"
-)) as _f:
-    HF = {
-        k: v for k, v in json.load(_f).items()
-        if k not in ("source", "family", "reduced", "assumed")
-    }
-KW = dict(
-    {k: v for k, v in HF.items() if k != "mamba_expand"},
-    max_seq_len=128, dtype="float32",
-)
-PARTS = falcon_h1_factory(**KW)
+#: the published keys alone: ``transformers`` is handed them as they are
+HF = {
+    k: v for k, v in T.config("falcon_h1").items()
+    if k not in ("source", "family", "reduced", "assumed")
+}
+PARTS = T.parts("falcon_h1", 128)
 CFG = PARTS["cfg"]
 SCHED = dict(
     max_slots=3, block_size=4, num_blocks=64, max_seq_len=64,
@@ -57,7 +50,7 @@ SCHED = dict(
 
 @pytest.fixture(scope="module")
 def params():
-    return R.seeded_params(HF, 2**31 + 11)
+    return T.params("falcon_h1", 2**31 + 11)
 
 
 @pytest.fixture(autouse=True)
@@ -67,15 +60,9 @@ def _exact_float32():
 
 
 def make_scheduler(params, events=None, **overrides):
-    sch = ContinuousBatchingScheduler(
-        CFG, SchedulerConfig(**dict(SCHED, **overrides)),
-        paged_decode_fn=PARTS["paged_decode_fn"],
-        paged_prefill_fn=PARTS["paged_prefill_fn"],
-        serving_params_fn=PARTS["serving_params_fn"],
-        capture_logprobs=True, events=events,
+    return T.scheduler(
+        PARTS, dict(SCHED, **overrides), params, events=events
     )
-    sch.sync_weights(params)
-    return sch
 
 
 def prompts_of(lengths, seed=1):

@@ -134,16 +134,21 @@ from dlrover_tpu.master.master import LocalJobMaster
 STEPS = 4
 
 
-def _toy_train(addr, rank, gates=None, done=None):
+def _toy_train(addr, rank, gates, done, params_reported):
     """Deterministic 2-rank 'training': per step each rank publishes a
     gradient to the master KV store and waits (long-poll) for the
     peer's, then both apply the identical mean update.  The ONLY
     nondeterminism possible is a lost/duplicated coordination message
-    — exactly what master failover must never cause."""
+    — exactly what master failover must never cause.  Rank 1 joins
+    only once rank 0 has told the master that the round takes two
+    nodes: a join that overtook that report completed a round of one
+    (the default) on a loaded machine, in the no-fault run too."""
     client = MasterClient(addr, node_id=rank)
     try:
         if rank == 0:
             client.report_rdzv_params(2, 2, 60, 1)
+            params_reported.set()
+        assert params_reported.wait(timeout=60)
         if gates and ("join", rank) in gates:
             gates[("join", rank)].wait(timeout=60)
         client.join_rendezvous(rank, 1)
@@ -202,10 +207,11 @@ class TestMasterKillMidJob:
         addr = f"127.0.0.1:{port}"
         gates = fault.gates if fault else {}
         done = {}
+        params_reported = threading.Event()
         threads = [
             threading.Thread(
                 target=_toy_train,
-                args=(addr, rank, gates, done),
+                args=(addr, rank, gates, done, params_reported),
                 daemon=True,
             )
             for rank in (0, 1)

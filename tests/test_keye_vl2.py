@@ -10,7 +10,6 @@ third paged leaf.  The tiny configuration's ``topk`` (16) is below its
 sequences, so every test that serves also selects.
 """
 
-import json
 import os
 import sys
 
@@ -24,15 +23,13 @@ BENCH = os.path.join(REPO, "benchmarks")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-import family_keye_vl2 as F  # noqa: E402
 import reference_keye_vl2 as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 from dlrover_tpu.models import keye_vl2, llama  # noqa: E402
 from dlrover_tpu.observability.events import EventLogger  # noqa: E402
 from dlrover_tpu.ops import paged_attention as pa  # noqa: E402
 from dlrover_tpu.rl.generation_service import (  # noqa: E402
-    falcon_h1_factory,
-    keye_vl2_factory,
     tiny_llama_factory,
 )
 from dlrover_tpu.rl.kv_cache import (  # noqa: E402
@@ -47,12 +44,8 @@ from dlrover_tpu.rl.scheduler import (  # noqa: E402
     SchedulerConfig,
 )
 
-with open(os.path.join(
-    BENCH, "tests", "tiny", "data", "configs", "tiny-keye-vl2.json"
-)) as _f:
-    HF = json.load(_f)
-KW = dict(F.model_kwargs(HF, 64), dtype="float32")
-PARTS = keye_vl2_factory(**KW)
+HF = T.config("keye_vl2")
+PARTS = T.parts("keye_vl2", 64)
 CFG = PARTS["cfg"]
 TOPK = HF["sa_config"]["topk"]
 SCHED = dict(
@@ -63,7 +56,7 @@ SCHED = dict(
 
 @pytest.fixture(scope="module")
 def params():
-    return R.seeded_params(HF, 2**31 + 42)
+    return T.params("keye_vl2", 2**31 + 42)
 
 
 @pytest.fixture(autouse=True)
@@ -74,15 +67,10 @@ def _exact_float32():
 
 def make_scheduler(params, events=None, capture_logprobs=True,
                    role="unified", **overrides):
-    sch = ContinuousBatchingScheduler(
-        CFG, SchedulerConfig(**dict(SCHED, **overrides)),
-        paged_decode_fn=PARTS["paged_decode_fn"],
-        paged_prefill_fn=PARTS["paged_prefill_fn"],
-        serving_params_fn=PARTS["serving_params_fn"],
-        capture_logprobs=capture_logprobs, events=events, role=role,
+    return T.scheduler(
+        PARTS, dict(SCHED, **overrides), params, events=events,
+        capture_logprobs=capture_logprobs, role=role,
     )
-    sch.sync_weights(params)
-    return sch
 
 
 def prompts_of(lengths, seed=1):
@@ -763,15 +751,7 @@ def _dense_parts():
 
 
 def _hybrid_parts():
-    with open(os.path.join(
-        BENCH, "tests", "tiny", "data", "configs", "tiny-falcon-h1.json"
-    )) as f:
-        hf = {
-            k: v for k, v in json.load(f).items()
-            if k not in ("source", "family", "reduced", "assumed",
-                         "mamba_expand")
-        }
-    return falcon_h1_factory(**dict(hf, max_seq_len=128, dtype="float32"))
+    return T.parts("falcon_h1", 128)
 
 
 @pytest.mark.parametrize("parts_of,leaves", [

@@ -15,7 +15,6 @@ layers, latent pages for one and no ``k`` and no ``v``.  Logits are
 compared, never tokens.
 """
 
-import json
 import os
 import sys
 
@@ -31,14 +30,12 @@ if BENCH not in sys.path:
 
 import family_kimi_linear as F  # noqa: E402
 import reference_kimi_linear as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 from dlrover_tpu.models import kimi_linear as M, llama  # noqa: E402
 from dlrover_tpu.observability.events import EventLogger  # noqa: E402
 from dlrover_tpu.ops import paged_attention as pa  # noqa: E402
 from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV  # noqa: E402
-from dlrover_tpu.rl.generation_service import (  # noqa: E402
-    kimi_linear_factory,
-)
 from dlrover_tpu.rl.kv_cache import (  # noqa: E402
     block_nbytes,
     init_block_pool,
@@ -51,14 +48,10 @@ from dlrover_tpu.rl.scheduler import (  # noqa: E402
     SchedulerConfig,
 )
 
-with open(os.path.join(
-    BENCH, "tests", "tiny", "data", "configs", "tiny-kimi-linear.json"
-)) as _f:
-    HF = json.load(_f)
-with open(os.path.join(BENCH, "configs", "kimi-linear-48b-a3b.json")) as _f:
-    PUBLISHED = json.load(_f)
-KW = dict(F.model_kwargs(HF, 96), dtype="float32")
-PARTS = kimi_linear_factory(**KW)
+HF = T.config("kimi_linear")
+PUBLISHED = T.published("kimi-linear-48b-a3b")
+KW = T.kwargs("kimi_linear", 96)
+PARTS = T.parts("kimi_linear", 96)
 CFG = PARTS["cfg"]
 SCHED = dict(
     max_slots=3, block_size=4, num_blocks=80, max_seq_len=96,
@@ -68,7 +61,7 @@ SCHED = dict(
 
 @pytest.fixture(scope="module")
 def params():
-    return R.seeded_params(HF, 2**31 + 42)
+    return T.params("kimi_linear", 2**31 + 42)
 
 
 @pytest.fixture(autouse=True)
@@ -78,15 +71,9 @@ def _exact_float32():
 
 
 def make_scheduler(params, events=None, **overrides):
-    sch = ContinuousBatchingScheduler(
-        CFG, SchedulerConfig(**dict(SCHED, **overrides)),
-        paged_decode_fn=PARTS["paged_decode_fn"],
-        paged_prefill_fn=PARTS["paged_prefill_fn"],
-        serving_params_fn=PARTS["serving_params_fn"],
-        capture_logprobs=True, events=events,
+    return T.scheduler(
+        PARTS, dict(SCHED, **overrides), params, events=events
     )
-    sch.sync_weights(params)
-    return sch
 
 
 def prompts_of(lengths, seed=1):
@@ -328,8 +315,7 @@ def test_the_pool_holds_slabs_for_state_layers_and_leaves_for_the_others():
 def test_the_published_cut_is_three_layers_of_leaves_and_nine_of_state():
     """To the byte: 1152 B a token and MLA layer in bfloat16, 2 244 608 B
     a lane and KDA layer in float32."""
-    kw = F.model_kwargs(PUBLISHED, 8192)
-    cfg = kimi_linear_factory(**kw, dtype="bfloat16")["cfg"]
+    cfg = T.parts(PUBLISHED, 8192, "bfloat16")["cfg"]
     cache = paged_cache_config(cfg, 65, 16, 128, 512)
     pool = jax.eval_shape(lambda: init_block_pool(cache))
     assert sorted(pool) == ["c", "conv", "kda", "kpe"]

@@ -14,9 +14,9 @@ shifted in place, the packed rows read by the paged kernels).  Logits are
 compared, never tokens.
 """
 
-import json
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -30,32 +30,22 @@ if BENCH not in sys.path:
 
 import family_lfm2_moe as F  # noqa: E402
 import reference_lfm2_moe as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 from dlrover_tpu.models import lfm2_moe as M  # noqa: E402
 from dlrover_tpu.observability.events import EventLogger  # noqa: E402
 from dlrover_tpu.ops import paged_attention as pa  # noqa: E402
 from dlrover_tpu.ops.paged_attention import PAGED_KERNEL_ENV  # noqa: E402
-from dlrover_tpu.rl.generation_service import (  # noqa: E402
-    lfm2_moe_factory,
-)
 from dlrover_tpu.rl.kv_cache import (  # noqa: E402
     init_block_pool,
     lane_state_nbytes,
     paged_cache_config,
 )
-from dlrover_tpu.rl.scheduler import (  # noqa: E402
-    ContinuousBatchingScheduler,
-    SchedulerConfig,
-)
 
-with open(os.path.join(
-    BENCH, "tests", "tiny", "data", "configs", "tiny-lfm2-moe.json"
-)) as _f:
-    HF = json.load(_f)
-with open(os.path.join(BENCH, "configs", "lfm2-24b-a2b.json")) as _f:
-    PUBLISHED = json.load(_f)
-KW = dict(F.model_kwargs(HF, 96), dtype="float32")
-PARTS = lfm2_moe_factory(**KW)
+HF = T.config("lfm2_moe")
+PUBLISHED = T.published("lfm2-24b-a2b")
+KW = T.kwargs("lfm2_moe", 96)
+PARTS = T.parts("lfm2_moe", 96)
 CFG = PARTS["cfg"]
 SCHED = dict(
     max_slots=3, block_size=4, num_blocks=80, max_seq_len=96,
@@ -65,7 +55,7 @@ SCHED = dict(
 
 @pytest.fixture(scope="module")
 def params():
-    return R.seeded_params(HF, 2**31 + 59)
+    return T.params("lfm2_moe", 2**31 + 59)
 
 
 @pytest.fixture(autouse=True)
@@ -75,15 +65,9 @@ def _exact_float32():
 
 
 def make_scheduler(params, events=None, **overrides):
-    sch = ContinuousBatchingScheduler(
-        CFG, SchedulerConfig(**dict(SCHED, **overrides)),
-        paged_decode_fn=PARTS["paged_decode_fn"],
-        paged_prefill_fn=PARTS["paged_prefill_fn"],
-        serving_params_fn=PARTS["serving_params_fn"],
-        capture_logprobs=True, events=events,
+    return T.scheduler(
+        PARTS, dict(SCHED, **overrides), params, events=events
     )
-    sch.sync_weights(params)
-    return sch
 
 
 def prompts_of(lengths, seed=1):
@@ -236,22 +220,25 @@ def test_two_lanes_of_different_lengths_decode_in_one_step(params):
     tables = np.zeros((3, 24), np.int32)
     tables[0, :2] = (1, 2)
     tables[2, :10] = np.arange(3, 13)
+    # one program a step, as the scheduler runs them (called eagerly the
+    # two steps compiled an operation at a time: 54 s)
+    prefill_chunk = jax.jit(partial(M.paged_prefill_chunk, cfg=CFG))
     for lane, p in ((0, prompts[0]), (2, prompts[1])):
         for start in range(0, p.size, 20):
             chunk = np.zeros((1, 20), np.int32)
             real = min(20, p.size - start)
             chunk[0, :real] = p[start:start + real]
-            _, pool, _ = M.paged_prefill_chunk(
+            _, pool, _ = prefill_chunk(
                 served, jnp.asarray(chunk), pool, jnp.asarray(tables[lane]),
-                jnp.int32(start), jnp.int32(lane), jnp.int32(real), CFG,
+                jnp.int32(start), jnp.int32(lane), jnp.int32(real),
             )
     marker = jnp.full_like(pool["conv"][:, 1], 7.25)
     pool = dict(pool, conv=pool["conv"].at[:, 1].set(marker))
     nxt = np.array([9, 0, 200], np.int32)
-    logits, after, rows = M.paged_decode_step(
+    logits, after, rows = jax.jit(partial(M.paged_decode_step, cfg=CFG))(
         served, jnp.asarray(nxt), pool, jnp.asarray(tables),
         jnp.asarray([5, 0, 37], jnp.int32),
-        jnp.asarray([True, False, True]), CFG,
+        jnp.asarray([True, False, True]),
     )
     np.testing.assert_array_equal(
         np.asarray(after["conv"][:, 1]), np.asarray(marker)

@@ -308,10 +308,13 @@ class TestDerivedOperators:
         assert out[0].problem == "data_stall"
         assert "host_fetch" in out[0].cause
 
-    def test_manager_records_conclusions(self, tmp_path):
+    def test_manager_records_conclusions(self, tmp_path, monkeypatch):
         """Fresh conclusions land on the timeline (``diagnosis``
         instant) and in the Brain node_events table, and stay
-        readable via recent_conclusions without being consumed."""
+        readable via recent_conclusions without being consumed.  The
+        manager reads an injected clock: the cooldown is over when the
+        test says so, not when a loaded machine gets round to it."""
+        from dlrover_tpu.master import diagnosis
         from dlrover_tpu.master.datastore import BrainDatastore
         from dlrover_tpu.observability.events import (
             EventLogger,
@@ -319,6 +322,16 @@ class TestDerivedOperators:
             set_default_event_logger,
         )
 
+        class Clock:
+            """``time`` as ``master/diagnosis.py`` sees it."""
+
+            now = time.time()
+
+            def time(self):
+                return self.now
+
+        clock = Clock()
+        monkeypatch.setattr(diagnosis, "time", clock)
         events_file = str(tmp_path / "events.jsonl")
         store = BrainDatastore(str(tmp_path / "brain.db"))
         set_default_event_logger(EventLogger(path=events_file))
@@ -348,8 +361,9 @@ class TestDerivedOperators:
             assert len(mgr.take_conclusions()) == 1
             assert len(mgr.recent_conclusions()) == 1
             # cooldown: the same verdict does not re-fire...
+            clock.now += 0.15
             assert mgr.diagnose() == []
-            time.sleep(0.25)
+            clock.now += 0.1
             # ...until the cooldown elapses
             assert len(mgr.diagnose()) == 1
         finally:

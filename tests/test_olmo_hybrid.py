@@ -17,7 +17,6 @@ paged cache against a dense causal product).  Logits are of order 3:
 more (``test_the_tolerance_sees_a_rounded_state``).
 """
 
-import json
 import os
 import sys
 
@@ -32,11 +31,9 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import reference_olmo_hybrid as R  # noqa: E402
+import tiny_families as T  # noqa: E402
 
 from dlrover_tpu.models import llama, olmo_hybrid  # noqa: E402
-from dlrover_tpu.rl.generation_service import (  # noqa: E402
-    olmo_hybrid_factory,
-)
 from dlrover_tpu.rl.kv_cache import (  # noqa: E402
     init_block_pool,
     paged_cache_config,
@@ -46,18 +43,8 @@ from dlrover_tpu.rl.scheduler import (  # noqa: E402
     SchedulerConfig,
 )
 
-with open(os.path.join(
-    BENCH, "tests", "tiny", "data", "configs", "tiny-olmo-hybrid.json"
-)) as _f:
-    HF = {
-        k: v for k, v in json.load(_f).items()
-        if k not in ("source", "family", "reduced", "assumed")
-    }
-KW = dict(
-    {k: v for k, v in HF.items() if k != "model_type"},
-    max_seq_len=128, dtype="float32",
-)
-PARTS = olmo_hybrid_factory(**KW)
+HF = T.config("olmo_hybrid")
+PARTS = T.parts("olmo_hybrid", 128)
 CFG = PARTS["cfg"]
 SCHED = dict(
     max_slots=3, block_size=4, num_blocks=64, max_seq_len=64,
@@ -68,7 +55,7 @@ TOL = 5e-5
 
 @pytest.fixture(scope="module")
 def params():
-    return R.seeded_params(HF, 2**31 + 17)
+    return T.params("olmo_hybrid", 2**31 + 17)
 
 
 @pytest.fixture(autouse=True)
@@ -78,15 +65,9 @@ def _exact_float32():
 
 
 def make_scheduler(params, events=None, **overrides):
-    sch = ContinuousBatchingScheduler(
-        CFG, SchedulerConfig(**dict(SCHED, **overrides)),
-        paged_decode_fn=PARTS["paged_decode_fn"],
-        paged_prefill_fn=PARTS["paged_prefill_fn"],
-        serving_params_fn=PARTS["serving_params_fn"],
-        capture_logprobs=True, events=events,
+    return T.scheduler(
+        PARTS, dict(SCHED, **overrides), params, events=events
     )
-    sch.sync_weights(params)
-    return sch
 
 
 def prompts_of(lengths, seed=1):

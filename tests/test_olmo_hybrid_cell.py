@@ -14,12 +14,10 @@ metric.
 """
 
 import importlib.util
-import json
 import os
-import subprocess
-import sys
 
 import pytest
+import rehearsal
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -33,32 +31,10 @@ _cases = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_cases)
 
 
-
-def _run_cell_in_a_process_of_its_own(workload, seed, seconds, trace,
-                                      expect_platform, data_root):
-    """``harness.run_cell`` as the command line is one process: the cell
-    checks that the engine's parent never touched the JAX backend, which
-    a test process that ran other files has."""
-    code = (
-        "import json, sys\n"
-        f"sys.path.insert(0, {os.path.join(REPO, 'benchmarks')!r})\n"
-        "import harness\n"
-        f"line = harness.run_cell({workload!r}, {seed}, {seconds}, {trace}, "
-        f"expect_platform={expect_platform!r}, data_root={data_root!r})\n"
-        "print(json.dumps(line))\n"
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=900,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
 @pytest.fixture(autouse=True)
 def _cells_run_in_their_own_process(monkeypatch):
     monkeypatch.setattr(
-        _cases.harness, "run_cell", _run_cell_in_a_process_of_its_own
+        _cases.harness, "run_cell", rehearsal.run_cell
     )
 
 

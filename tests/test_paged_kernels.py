@@ -110,7 +110,7 @@ def _tol(dtype):
 #: (dtype, block_size, group, head_dim, kv): the tiny sweep, plus the
 #: shape the chip runs (head_dim 128, block_size 16, bf16 — GQA with 8
 #: KV heads and MHA with 32, what ``chip_smoke.py`` and
-#: ``tests/test_tpu_compile.py`` compile to Mosaic), small batch
+#: ``tests/test_tpu_compile_*.py`` compile to Mosaic), small batch
 PARITY_CASES = [
     pytest.param(dtype, bs, group, 8, 2,
                  id=f"g{group}-bs{bs}-{jnp.dtype(dtype).name}")

@@ -982,5 +982,7 @@ def test_profiling_overhead_under_two_percent():
     CPU CI the trace capture itself dwarfs the 20 ms step."""
     from bench import measure_profiling_overhead
 
-    result = measure_profiling_overhead(steps=40, every=10)
+    # six rounds of an off and an on leg: the median ratio forgets the
+    # round a neighbouring worker's burst of load fell into
+    result = measure_profiling_overhead(steps=120, every=10)
     assert result["profiling_overhead"] < 0.02, result

@@ -53,18 +53,26 @@ SERVE_CFG_KW = dict(
 )
 
 
+@jax.jit
+def _last_logits(tokens):
+    """The whole forward of one sequence, one program a length (called
+    eagerly it compiled every operation anew for every length: most of
+    this file's seconds)."""
+    return llama.forward(
+        params=PARAMS,
+        tokens=tokens,
+        cfg=CFG,
+        attention_fn=llama.dot_product_attention,
+    )[0, -1]
+
+
 def unbatched_reference(prompt, max_new, seed, temp, eos=None):
     """The O(T^2) full-forward loop, one sequence at a time — the
     ground truth continuous batching must be invisible against."""
     toks = list(int(t) for t in prompt)
     key = jax.random.PRNGKey(seed)
     for _ in range(max_new):
-        logits = llama.forward(
-            params=PARAMS,
-            tokens=jnp.asarray([toks], jnp.int32),
-            cfg=CFG,
-            attention_fn=llama.dot_product_attention,
-        )[0, -1]
+        logits = _last_logits(jnp.asarray([toks], jnp.int32))
         pos = len(toks)
         if temp <= 0:
             tok = int(jnp.argmax(logits))
@@ -853,9 +861,21 @@ def _adoptions(eng, seed0, timeout=60.0):
     raise AssertionError("no STATS row reached the dispatcher")
 
 
+def _publish(eng, key):
+    """A policy of the case's own, published; returns its version.  A
+    case stands on this and not on what an earlier case left."""
+    eng.sync_weights(
+        llama.init_params(
+            jax.random.PRNGKey(key), llama.LlamaConfig(**SERVE_CFG_KW)
+        )
+    )
+    return eng.status()["version"]
+
+
 class TestServingEngineWholeBatchSurface:
     """``generate`` / ``sync_weights`` on one engine session, in the
-    order a trainer meets them; the kill comes last."""
+    order a trainer meets them; the kill comes last.  Every case
+    publishes for itself: one that fails leaves the others green."""
 
     def test_a_publish_reaches_generate(self, one_replica_engine):
         """Two different policies published through shm: greedy
@@ -890,16 +910,17 @@ class TestServingEngineWholeBatchSurface:
         self, one_replica_engine
     ):
         """No new publish: the replica's adoption count stays where
-        the two publishes left it, over a second of traffic and more,
-        and finding that out costs no meta RPC (the generation
+        the publishes left it (one a version), over a second of traffic
+        and more, and finding that out costs no meta RPC (the generation
         side-segment); the same request gives the same tokens."""
         eng, _ = one_replica_engine
+        version = _publish(eng, 7)
         row = _adoptions(eng, seed0=2000)
-        assert row["adoptions"] == 2, row
+        assert row["adoptions"] == version, row
         prompts = np.array([[1, 2]], np.int32)
         first = eng.generate(prompts, seed=0)
         again = _adoptions(eng, seed0=3000)
-        assert again["adoptions"] == 2, again
+        assert again["adoptions"] == version, again
         assert again["meta_rpcs"] == row["meta_rpcs"], (row, again)
         np.testing.assert_array_equal(
             first, eng.generate(prompts, seed=0)
@@ -910,6 +931,8 @@ class TestServingEngineWholeBatchSurface:
         whatever the fleet's size, and the replica's SLO series are
         in the registry."""
         eng, reg = one_replica_engine
+        _publish(eng, 8)
+        eng.generate(np.arange(12, dtype=np.int32).reshape(6, 2), seed=0)
         status = eng.status()
         assert set(status) == {
             "replicas", "queue_depth", "completed", "p50_latency_s",

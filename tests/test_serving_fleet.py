@@ -50,17 +50,24 @@ CFG = llama.LlamaConfig.tiny(
 PARAMS = llama.init_params(jax.random.PRNGKey(0), CFG)
 
 
+@jax.jit
+def _last_logits(tokens):
+    """The whole forward of one sequence, one program a length (called
+    eagerly it compiled every operation anew for every length)."""
+    return llama.forward(
+        params=PARAMS,
+        tokens=tokens,
+        cfg=CFG,
+        attention_fn=llama.dot_product_attention,
+    )[0, -1]
+
+
 def unbatched_reference(prompt, max_new):
     """Greedy lone-sequence full-forward loop — the ground truth any
     scheduling/shipping path must be invisible against."""
     toks = list(int(t) for t in prompt)
     for _ in range(max_new):
-        logits = llama.forward(
-            params=PARAMS,
-            tokens=jnp.asarray([toks], jnp.int32),
-            cfg=CFG,
-            attention_fn=llama.dot_product_attention,
-        )[0, -1]
+        logits = _last_logits(jnp.asarray([toks], jnp.int32))
         toks.append(int(jnp.argmax(logits)))
     return np.asarray(toks, np.int32)
 

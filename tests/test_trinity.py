@@ -30,6 +30,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import reference_trinity as ref  # noqa: E402
+import tiny_families as T  # noqa: E402
 from dlrover_tpu.models import trinity  # noqa: E402
 from dlrover_tpu.ops.grouped_gemm import (  # noqa: E402
     expert_ffn,
@@ -48,6 +49,7 @@ from dlrover_tpu.rl.scheduler import (  # noqa: E402
 #: the tiny configuration of the family as its file would hold it: a
 #: window of 32 below its sequences, 2 of 8 experts held (share 1 of 4)
 FILE = dict(
+    family="family_trinity",
     vocab_size=256, hidden_size=64, num_hidden_layers=5, num_dense_layers=1,
     num_attention_heads=4, num_key_value_heads=2, head_dim=16,
     intermediate_size=128, moe_intermediate_size=32, num_experts=2,
@@ -73,8 +75,7 @@ def program_cfg(file_cfg=FILE, **kw):
 def seeded():
     """The reference's seeded tree in float32 (its matrices hold
     bfloat16 values; both sides compute on the same numbers)."""
-    params = ref.seeded_params(FILE, 2**31 + 44)
-    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+    return T.params(FILE, 2**31 + 44, "float32")
 
 
 def reference_logprobs(params, tokens, chosen=None):
