@@ -48,6 +48,7 @@ from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import SharedDict, SharedMemory
 from dlrover_tpu.common.parallel_io import (
     input_copy_workers,
+    parallel_fill,
     parallel_memcpy,
 )
 
@@ -144,7 +145,7 @@ def _attach_ring(name: str, timeout: float = 60.0) -> "_ShmRing":
 
 class _ShmRing:
     def __init__(self, name: str, spec: BatchSpec, num_slots: int,
-                 create: bool):
+                 create: bool, touch: bool = False):
         self.spec = spec
         self.num_slots = num_slots
         # header: [closed, state_0 .. state_{n-1}] as aligned uint64
@@ -159,6 +160,17 @@ class _ShmRing:
         self._hdr = np.frombuffer(
             self.shm.buf, dtype=np.uint64, count=hdr_words
         )
+        if create and touch:
+            # a ring whose slots are written IN PLACE at a message's own
+            # length (``slot_views``) has every page of its payload
+            # touched here, once, before anyone can attach: a page first
+            # touched by the writer under load stalls its loop (tens of
+            # microseconds a page on a virtual machine), and a slot
+            # written whole by ``write_slot`` pays that in its first lap
+            parallel_fill(
+                np.frombuffer(self.shm.buf, np.uint8)[self.payload_off:],
+                0, workers=input_copy_workers(),
+            )
         self.meta = SharedDict(f"shm_ring_meta_{name}", create=create)
         if create:
             self._hdr[:] = 0

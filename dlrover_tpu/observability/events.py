@@ -604,10 +604,13 @@ REQUIRED_SPAN_LABELS: Dict[str, Tuple[str, ...]] = {
     # checkpoint/offload data-plane spans: staged blocks, moved
     # bytes, achieved shm throughput
     PHASE_KV_SHIP: ("blocks", "bytes", "throughput_gbps"),
-    # whose reply, and how much rode the per-position ring beside it (0
-    # for a model without per-position rows): the stall a finished
-    # request costs the loop grows with the second
-    PHASE_REPLY: ("req_id", "per_token_bytes"),
+    # whose reply, how much rode the per-position ring beside it (0 for
+    # a model without per-position rows) and how many bytes the loop's
+    # thread wrote for those rows between the scheduler's hand-over and
+    # the ring's publish: the stall a finished request costs the loop
+    # grows with the third, which is the second while the rows leave
+    # in one copy of their own length
+    PHASE_REPLY: ("req_id", "per_token_bytes", "copied_bytes"),
     # a start-up span without its stage is the blip the phase exists to
     # replace (``STARTUP_STAGES``, linted as a literal)
     PHASE_STARTUP: ("stage",),

@@ -55,6 +55,10 @@ from dlrover_tpu.common.env import (
     gen_timeout_s,
 )
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.common.parallel_io import (
+    input_copy_workers,
+    parallel_memcpy,
+)
 
 WORKER_SPEC_ENV = "DLROVER_TPU_GEN_SPEC"
 
@@ -552,8 +556,9 @@ def _resp_spec(max_total: int):
 def _per_token_spec(max_total: int, leaves: Dict):
     """The ring a replica's per-position arrays ride to the dispatcher
     on, one message a RESULT and just before it: ``leaves`` is the
-    scheduler's ``per_token`` (``{name: (shape, dtype)}``), each array
-    padded to ``max_total`` positions."""
+    scheduler's ``per_token`` (``{name: (shape, dtype)}``), a slot
+    ``max_total`` positions of each; a message is written in place and
+    holds ``meta[1]`` of them, what lies past those is never read."""
     from dlrover_tpu.data.shm_dataloader import BatchSpec
 
     return BatchSpec(
@@ -568,6 +573,14 @@ def _per_token_spec(max_total: int, leaves: Dict):
             },
         }
     )
+
+
+def _copied(rows: np.ndarray) -> np.ndarray:
+    """A copy of ``rows`` (contiguous) the way the ring itself copies:
+    chunked over the copy workers where it is large."""
+    out = np.empty_like(rows)
+    parallel_memcpy(out, rows, workers=input_copy_workers())
+    return out
 
 
 class _Ring:
@@ -587,14 +600,19 @@ class _Ring:
         from dlrover_tpu.data import shm_dataloader as sd
 
         if create:
-            self._ring = sd._ShmRing(name, spec, num_slots, create=True)
+            self._ring = sd._ShmRing(
+                name, spec, num_slots, create=True, touch=True
+            )
         else:
             self._ring = sd._attach_ring(name)
         self._next_w = 0
         self._next_r = 0
 
-    def try_put(self, msg: Dict[str, np.ndarray],
-                timeout: float = 0.0) -> bool:
+    def reserve(self, timeout: float = 0.0):
+        """Take the next slot for writing IN PLACE: its fields as
+        zero-copy views over the segment, or None when the ring stayed
+        full for ``timeout`` seconds.  The slot is WRITING — invisible
+        to the reader — until :meth:`publish`."""
         from dlrover_tpu.data import shm_dataloader as sd
 
         slot = self._next_w
@@ -602,25 +620,51 @@ class _Ring:
         delay = 0.0002
         while self._ring.slot_state(slot) != sd.SLOT_FREE:
             if time.monotonic() >= deadline:
-                return False
+                return None
             delay = sd._backoff_sleep(delay)
         self._ring.set_slot_state(slot, sd.SLOT_WRITING)
-        self._ring.write_slot(slot, msg)
+        return self._ring.slot_views(slot)
+
+    def publish(self):
+        """Hand the reserved slot to the reader: payload visible before
+        the FULL publication."""
+        from dlrover_tpu.data import shm_dataloader as sd
+
         sd._memory_fence()
-        self._ring.set_slot_state(slot, sd.SLOT_FULL)
-        self._next_w = (slot + 1) % self._ring.num_slots
+        self._ring.set_slot_state(self._next_w, sd.SLOT_FULL)
+        self._next_w = (self._next_w + 1) % self._ring.num_slots
+
+    def try_put(self, msg: Dict[str, np.ndarray],
+                timeout: float = 0.0) -> bool:
+        if self.reserve(timeout) is None:
+            return False
+        self._ring.write_slot(self._next_w, msg)
+        self.publish()
         return True
 
-    def try_get(self) -> Optional[Dict[str, np.ndarray]]:
+    def peek(self) -> Optional[Dict[str, np.ndarray]]:
+        """The oldest published message IN PLACE — its fields as
+        zero-copy views, the reader's until :meth:`release` — or None."""
         from dlrover_tpu.data import shm_dataloader as sd
 
         slot = self._next_r
         if self._ring.slot_state(slot) != sd.SLOT_FULL:
             return None
         sd._memory_fence()
-        msg = self._ring.read_slot(slot, copy=True)
-        self._ring.set_slot_state(slot, sd.SLOT_FREE)
-        self._next_r = (slot + 1) % self._ring.num_slots
+        return self._ring.slot_views(slot)
+
+    def release(self):
+        """Give the slot :meth:`peek` returned back to the writer."""
+        from dlrover_tpu.data import shm_dataloader as sd
+
+        self._ring.set_slot_state(self._next_r, sd.SLOT_FREE)
+        self._next_r = (self._next_r + 1) % self._ring.num_slots
+
+    def try_get(self) -> Optional[Dict[str, np.ndarray]]:
+        if self.peek() is None:
+            return None
+        msg = self._ring.read_slot(self._next_r, copy=True)
+        self.release()
         return msg
 
     def close(self, unlink: bool = False):
@@ -954,27 +998,33 @@ def _serving_worker_loop(spec) -> int:
     # the dispatcher creates from READY's description of it
     pt_ring = None
 
-    def _put_per_token(res):
+    def _put_per_token(res) -> int:
+        """The request's rows into the ring's slot, in place: ``[:n]``
+        of each name (``meta`` says ``n``; what an older, longer
+        request left past it in the slot is never read).  Returns the
+        bytes written."""
         nonlocal pt_ring
         if pt_ring is None:
             pt_ring = _Ring(f"{tag}-pt")
-        msg = {"meta": np.asarray([res.req_id, res.tokens.size], np.int64)}
-        for name, (shape, dtype) in scheduler.per_token.items():
-            dt = np.dtype(dtype)
-            buf = np.full(
-                (max_total,) + tuple(shape),
-                -1 if dt.kind == "i" else np.nan, dt,
-            )
-            buf[: res.tokens.size] = res.per_token[name]
-            msg[name] = buf
-        while not pt_ring.try_put(msg, timeout=5.0):
+        while (slot := pt_ring.reserve(timeout=5.0)) is None:
             if os.getppid() != parent_pid:
-                return
+                return 0
+        n = res.tokens.size
+        slot["meta"][:] = (res.req_id, n)
+        copied = sum(
+            parallel_memcpy(
+                slot[name][:n], rows, workers=input_copy_workers()
+            )
+            for name, rows in res.per_token.items()
+        )
+        pt_ring.publish()
+        return copied
 
-    def _flush_result(res):
+    def _flush_result(res) -> int:
+        """A finished request's way out; returns the bytes of
+        per-position rows this thread wrote on it."""
         ttft_hist.observe(res.stats.get("ttft_s", 0.0))
-        if scheduler.per_token:
-            _put_per_token(res)
+        copied = _put_per_token(res) if scheduler.per_token else 0
         _respond(
             _KIND_RESULT,
             req_id=res.req_id,
@@ -991,6 +1041,7 @@ def _serving_worker_loop(spec) -> int:
                 res.stats.get("queue_wait_s", 0.0),
             ),
         )
+        return copied
 
     # the mark of READY: "engine up", from inside
     events.instant(
@@ -1113,15 +1164,22 @@ def _serving_worker_loop(spec) -> int:
                 served += 1
                 window_tokens += res.new_tokens
                 # one record a finished request: what the leaf around
-                # it spends on the request's way out, over the whole run
-                with events.span(
+                # it spends on the request's way out, over the whole
+                # run, and what this thread wrote of its rows from the
+                # scheduler's hand-over to the ring's publish
+                reply_wall = anchored_now()
+                t0 = time.perf_counter()
+                copied = _flush_result(res)
+                events.complete(
                     "reply",
+                    reply_wall,
+                    time.perf_counter() - t0,
                     req_id=res.req_id,
                     per_token_bytes=sum(
                         a.nbytes for a in res.per_token.values()
                     ),
-                ):
-                    _flush_result(res)
+                    copied_bytes=copied,
+                )
             if scheduler.shipped:
                 # prefill worker: stage each completed prefill's KV
                 # blocks in its reserved arena slot and hand the manifest
@@ -1901,13 +1959,16 @@ class ServingEngine:
                     msg["logprobs"][: int(meta[3])].copy()
                 )
             if rep.pt_ring is not None:
-                # put before the RESULT, so it is there
-                rows = rep.pt_ring.try_get()
-                if rows is not None and int(rows["meta"][0]) == req_id:
-                    result["per_token"] = {
-                        name: a[:total].copy()
-                        for name, a in rows.items() if name != "meta"
-                    }
+                # put before the RESULT, so it is there: the request's
+                # own positions leave the slot in one copy
+                rows = rep.pt_ring.peek()
+                if rows is not None:
+                    if int(rows["meta"][0]) == req_id:
+                        result["per_token"] = {
+                            name: _copied(a[:total])
+                            for name, a in rows.items() if name != "meta"
+                        }
+                    rep.pt_ring.release()
             self._complete(
                 req_id,
                 {

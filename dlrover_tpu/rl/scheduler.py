@@ -135,6 +135,12 @@ def _empty_logprobs() -> np.ndarray:
     return np.zeros((0,), np.float32)
 
 
+def _never_computed(rows: np.ndarray):
+    """What a per-position array holds where no step program computed
+    the position: -1 in an integer array, NaN in a float one."""
+    return -1 if rows.dtype.kind == "i" else np.nan
+
+
 @dataclass
 class GenRequest:
     """One generation request (prompt in, sampled tail out).
@@ -241,10 +247,12 @@ class _Slot:
     # tokens sampled for this lane on the device that the host has not
     # read yet (0-2: the first token, or a decode step, or both)
     ahead: int = 0
-    # the model's per-position outputs as they came off the programs,
-    # ``(first position, rows, {name: array [>= rows, ...]})``: a
-    # prefill chunk's stay on the device until the request finishes
-    rows: List = field(default_factory=list)
+    # the model's per-position outputs where the result will carry
+    # them, ``{name: [prompt + max_new, ...]}`` made untouched at
+    # admission (``_lane_rows``): each commit writes its position's row
+    # in place, and positions ``[0, rows_upto)`` are laid
+    rows: Dict[str, np.ndarray] = field(default_factory=dict)
+    rows_upto: int = 0
 
 
 @dataclass
@@ -814,6 +822,10 @@ class ContinuousBatchingScheduler:
         self._keys = jnp.zeros((S, 2), jnp.uint32)
         # dispatches whose samples the host has not read, oldest first
         self._inflight: List[_InFlight] = []
+        # prefill chunks whose per-position rows are on their way to
+        # the host, oldest first: ``(iteration, slot, its _Slot, first
+        # position, rows, {name: device array})``
+        self._chunk_rows: List = []
         # why this scheduler may never dispatch ahead of its commits
         # (None: it may): the K-step window's positions depend on how
         # many drafts were accepted.  (A prefill worker never decodes
@@ -1571,6 +1583,7 @@ class ContinuousBatchingScheduler:
             # cached prefix blocks are already filled: prefill starts
             # past them
             sl.prefill_pos = n_hit * s.block_size
+            self._lane_rows(sl, sl.prefill_pos)
             sl.generated = [int(t) for t in req.resume_tokens]
             if self.capture_logprobs and sl.generated:
                 rlp = req.resume_logprobs
@@ -1624,6 +1637,7 @@ class ContinuousBatchingScheduler:
         self._admit_counter += 1
         sl = _Slot(req=req, phase="decode", prefill_len=plen,
                    admit_seq=self._admit_counter)
+        self._lane_rows(sl, plen)
         self._slots[slot] = sl
         self.block_pool.note_filled(req.req_id, plen)
         self.shipped_in += 1
@@ -1745,7 +1759,7 @@ class ContinuousBatchingScheduler:
                     np.asarray(sl.logprobs, np.float32)
                     if self.capture_logprobs else _empty_logprobs()
                 ),
-                per_token=self._per_token_rows(sl, tokens.size),
+                per_token=self._hand_over_rows(sl, tokens.size),
             )
         )
         self.block_pool.free(req.req_id)
@@ -1758,20 +1772,44 @@ class ContinuousBatchingScheduler:
         self._active[slot] = False
         self._slots[slot] = _Slot()
 
-    def _per_token_rows(self, sl: _Slot, n: int) -> Dict[str, np.ndarray]:
-        """The request's per-position arrays ``{name: [n, ...]}`` from
-        what its programs returned (``sl.rows``); -1 / NaN where a
-        position was never computed in this slot."""
-        out = {}
+    def _lane_rows(self, sl: _Slot, start: int):
+        """Give ``sl`` its request's per-position arrays ``{name:
+        [prompt + max_new, ...]}``: untouched memory (no fill, no page
+        faulted in at admission) but for the positions before ``start``,
+        which are never computed in this slot and read -1 / NaN."""
+        n = int(sl.req.prompt.size) + int(sl.req.max_new)
         for name, (shape, dtype) in self.per_token.items():
-            dt = np.dtype(dtype)
-            full = np.full(
-                (n,) + tuple(shape), -1 if dt.kind == "i" else np.nan, dt
-            )
-            for start, count, rows in sl.rows:
-                full[start:start + count] = np.asarray(rows[name])[:count]
-            out[name] = full
-        return out
+            sl.rows[name] = np.empty((n,) + tuple(shape), dtype)
+            sl.rows[name][:start] = _never_computed(sl.rows[name])
+        sl.rows_upto = start
+
+    def _hand_over_rows(self, sl: _Slot, n: int) -> Dict[str, np.ndarray]:
+        """The finished request's per-position arrays ``{name: [n,
+        ...]}``: the lane's own, as the commits laid them, -1 / NaN at
+        the positions no step computed (the last)."""
+        for rows in sl.rows.values():
+            rows[sl.rows_upto:n] = _never_computed(rows)
+        return {name: rows[:n] for name, rows in sl.rows.items()}
+
+    def _land_chunk_rows(self, before: Optional[int]):
+        """Write the per-position rows of the prefill chunks dispatched
+        in iterations ``before`` the given one (all of them without)
+        into their lanes' arrays, and let go of the device's: their
+        copies were started at the dispatch, and any decode step read
+        after this ran behind them."""
+        n = sum(
+            before is None or rec[0] < before for rec in self._chunk_rows
+        )
+        for _, slot, sl, start, count, rows in self._chunk_rows[:n]:
+            if self._slots[slot] is not sl:
+                continue  # the lane left (shipped, evicted) before
+            for name, leaf in rows.items():
+                with self._ph_wait:
+                    host = np.asarray(leaf)
+                with self._ph_commit:
+                    sl.rows[name][start:start + count] = host[:count]
+            sl.rows_upto = start + count
+        del self._chunk_rows[:n]
 
     def _note_selection(self, cached: int):
         """One decode lane's share of the step's ``sel_rows`` /
@@ -2066,12 +2104,14 @@ class ContinuousBatchingScheduler:
                     self._params, self._pool, *args
                 ))
             if self.per_token:
-                # the chunk's rows stay on the device until the
-                # request finishes; only their copy is started
+                # the chunk's rows: only their copy is started here, a
+                # later commit lays them (``_land_chunk_rows``)
                 rows = lp.pop()
                 for leaf in rows.values():
                     leaf.copy_to_host_async()
-                sl.rows.append((start, real, rows))
+                self._chunk_rows.append(
+                    (self.iterations, slot, sl, start, real, rows)
+                )
             self.dispatches += 1
             self.prefill_chunks += 1
             self._step_chunk = dict(rows=int(real), kv_len=int(start + real))
@@ -2244,6 +2284,7 @@ class ContinuousBatchingScheduler:
             for rec in self._inflight
         )
         self._step_commits += n
+        self._land_chunk_rows(before)
         sampled = 0
         for rec in self._inflight[:n]:
             with self._ph_wait:
@@ -2276,10 +2317,9 @@ class ContinuousBatchingScheduler:
                         sampled += 1
                         if rows:
                             # the step computed position ``pos - 1``
-                            sl.rows.append((
-                                pos - 1, 1,
-                                {n: a[slot][None] for n, a in rows.items()},
-                            ))
+                            for name, leaf in rows.items():
+                                sl.rows[name][pos - 1] = leaf[slot]
+                            sl.rows_upto = pos
                     self._next_token[slot] = tok
                     self._append_token(slot, tok, finished, lp=lp)
         del self._inflight[:n]

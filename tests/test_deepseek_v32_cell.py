@@ -85,7 +85,7 @@ def data_root(tmp_path_factory):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         real = json.load(f)
     for m in real["per_layer"]:  # those that read labels, not a trace
-        if m["name"].startswith(("moe.", "kv.selected")):
+        if m["name"].startswith(("moe.", "kv.selected", "engine.reply_c")):
             bench["per_layer"].append(dict(m, workloads=list(CELLS)))
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
@@ -118,6 +118,9 @@ def test_the_cell_is_correct_and_reads_its_labels(data_root):
     assert 5 < got["moe.local_rows_pct"] < 60
     assert 0 < got["moe.experts_hit_pct"] <= 100
     assert "rollout_tokens_per_s" not in got
+    # the rows of every reply in the window left in one copy of their
+    # own length (``copied_bytes`` over ``per_token_bytes``)
+    assert got["engine.reply_copied_pct"] == 100
     # a CPU trace has no device plane: the device readers find nothing
     assert not [k for k in got if k.startswith(("kernel.", "serve."))]
 

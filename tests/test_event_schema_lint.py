@@ -768,7 +768,8 @@ def test_lint_enforces_startup_stage_and_labels(tmp_path):
 
 
 def test_lint_enforces_reply_and_compile_labels(tmp_path):
-    """A ``reply`` span says whose reply it is and what rode beside it;
+    """A ``reply`` span says whose reply it is, what rode beside it and
+    what the loop's thread copied for it (``copied_bytes``, PR 62);
     a ``compile`` record's label set is closed (``program``, ``stage``,
     ``cache``), and a hand-made span around a whole compile may carry
     none of them."""
@@ -776,8 +777,11 @@ def test_lint_enforces_reply_and_compile_labels(tmp_path):
     bad.write_text(
         "events = None\n"
         "def f(events):\n"
-        "    events.span('reply', req_id=1)\n"
-        "    events.span('reply', req_id=1, per_token_bytes=0)\n"
+        "    events.span('reply', req_id=1, copied_bytes=0)\n"
+        "    events.complete('reply', 0.0, 1.0, req_id=1,\n"
+        "                    per_token_bytes=0)\n"
+        "    events.complete('reply', 0.0, 1.0, req_id=1,\n"
+        "                    per_token_bytes=0, copied_bytes=0)\n"
         "    events.complete('compile', 0.0, 1.0, program='f',\n"
         "                    stage='trace', cached=True)\n"
         "    events.complete('compile', 0.0, 1.0, program='f',\n"
@@ -786,8 +790,9 @@ def test_lint_enforces_reply_and_compile_labels(tmp_path):
     )
     proc = _run(str(bad))
     assert proc.returncode == 1, proc.stdout + proc.stderr
-    assert "event_schema_violations=2" in proc.stdout, proc.stdout
+    assert "event_schema_violations=3" in proc.stdout, proc.stdout
     assert (
         "missing required label(s) ['per_token_bytes']" in proc.stdout
     )
+    assert "missing required label(s) ['copied_bytes']" in proc.stdout
     assert "undeclared label(s) ['cached']" in proc.stdout

@@ -220,6 +220,9 @@ def test_a_finished_request_writes_one_reply_span(replicas, run):
     replies = _spans(events, "reply")
     assert sorted(s["labels"]["req_id"] for s in replies) == sorted(ids)
     assert {s["labels"]["per_token_bytes"] for s in replies} == {0}
+    # a model without per-position rows: nothing copied for them
+    assert {s["labels"]["copied_bytes"] for s in replies} == {0}
+    assert all(s["end"] > s["start"] for s in replies)
     done = {
         s["labels"]["req_id"]: s["end"]
         for s in _spans(events, "serve_request")
